@@ -1,13 +1,9 @@
-"""Compat shims for jax surfaces that moved or were renamed across releases.
+"""Instrumented jax entry points: the compile tap and the ledgered batch
+transfer that feed :mod:`ray_tpu.util.device_telemetry`.
 
-The codebase targets the current spellings — ``jax.shard_map`` (with
-``check_vma``/``axis_names``), ``jax.sharding.get_abstract_mesh``,
-``lax.axis_size`` — but must degrade to the older ones
-(``jax.experimental.shard_map`` with ``check_rep``/``auto``, the
-resource-env mesh installed by ``with mesh:``, ``psum(1, axis)``) instead
-of dying with an ImportError/AttributeError mid-task on an older install.
-Every shim resolves per call so these stay correct across jax reloads in
-tests.
+The package targets the installed jax (0.9.0) and calls ``jax.shard_map``,
+``jax.set_mesh``, ``jax.sharding.get_abstract_mesh`` and ``lax.axis_size``
+directly; nothing here translates between jax versions.
 """
 
 from __future__ import annotations
@@ -18,74 +14,10 @@ import time
 import jax
 
 
-def get_abstract_mesh():
-    """The ambient mesh (jax.set_mesh / `with mesh:`), or None."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        return get()
-    from jax._src.mesh import thread_resources
-
-    phys = thread_resources.env.physical_mesh
-    return phys if phys.devices.size else None
-
-
-def shard_map(f, *, mesh=None, in_specs, out_specs, check_vma=True,
-              axis_names=None):
-    """jax.shard_map when available; else jax.experimental.shard_map with
-    check_vma→check_rep and axis_names→auto (its complement) translated."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma, **kw)
-    from jax.experimental.shard_map import shard_map as esm
-
-    if mesh is None:
-        # Old shard_map cannot resolve the ambient mesh itself.
-        mesh = get_abstract_mesh()
-    # axis_names (partial-manual) is deliberately NOT translated to the old
-    # `auto=` complement: old partial-auto shard_map miscompiles bodies
-    # using axis_index (lowers to PartitionId — an XLA CPU CHECK-abort).
-    # Full manual is always correct — axes the specs don't mention just see
-    # replicated data — at the cost of intra-stage auto sharding here.
-    # check_rep unconditionally off: the old checker lacks replication
-    # rules for primitives these bodies use (axis_index among them) and
-    # it is a static check only — disabling it never changes results.
-    return esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False, auto=frozenset())
-
-
-def set_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.  jax.set_mesh
-    is recent; on older jax the Mesh object itself is the context manager
-    (it installs the resource-env mesh that old shard_map resolves)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-def reshard(x, mesh, spec):
-    """Host array -> device array sharded over ``mesh`` by ``spec`` — the
-    shard round-trip the checkpoint subsystem's elastic restore uses.
-    jax.device_put with an explicit NamedSharding is the one placement
-    spelling stable across every jax this repo supports; NamedSharding
-    itself moved modules over time, so resolve it defensively."""
-    try:
-        from jax.sharding import NamedSharding
-    except ImportError:  # ancient spelling
-        from jax.experimental.sharding import NamedSharding  # type: ignore
-
-    import numpy as np
-
-    return jax.device_put(np.asarray(x), NamedSharding(mesh, spec))
-
-
 def _telemetry():
     """The device-telemetry module iff something already imported it —
     the cross-layer probe idiom (train profiler hooks work the same way)
-    keeps this compat layer import-free and the no-observer cost at one
+    keeps this module import-free and the no-observer cost at one
     dict miss."""
     return sys.modules.get("ray_tpu.util.device_telemetry")
 
@@ -205,13 +137,3 @@ def _fit_sharding(sharding, ndim):
         return sharding
     return jax.sharding.NamedSharding(
         sharding.mesh, jax.sharding.PartitionSpec(*spec[:ndim]))
-
-
-def axis_size(axis_name):
-    """lax.axis_size is recent; psum of a constant 1 folds to a static int
-    under every version's shard_map/pmap."""
-    from jax import lax
-
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)

@@ -112,8 +112,12 @@ def _actor_task_context(actor_id):
 
 
 def _worker_main(conn, arena_path: Optional[str], back_conn=None) -> None:
-    # Keep workers off the TPU: the driver process owns the chips.
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # Keep workers off the TPU: the driver process owns the chips, and a
+    # second process that asks libtpu for them dies at its first jax call.
+    # Assigned, not defaulted: a TPU machine exports JAX_PLATFORMS=tpu,cpu
+    # and the child inherits it.  A task that really owns chips says so in
+    # its runtime_env env_vars, which are applied after this.
+    os.environ["JAX_PLATFORMS"] = "cpu"
     # On-demand stack dumps (`ray_tpu stack`, ref: py-spy via the reporter
     # agent): SIGUSR1 → faulthandler dump readable by the driver.
     from ray_tpu._private.stack_profiler import install_worker_dump_handler

@@ -3,9 +3,7 @@
 Shards on disk record the writer's world size, but assemble_tree already
 reconciles that into full host arrays — so restoring into a new topology
 is purely a placement problem: device_put every leaf with a sharding
-derived from the new mesh.  The device placement goes through the
-jax_compat shard round-trip (``jax_compat.reshard``) so old and new jax
-spellings of NamedSharding/device_put both work.
+derived from the new mesh.
 """
 
 from __future__ import annotations
@@ -39,8 +37,7 @@ def reshard_tree(host_tree: Any, mesh, pspec: Optional[Any] = None,
     — per-leaf control; neither — ``default_pspec``.
     """
     import jax
-
-    from ray_tpu._private import jax_compat
+    from jax.sharding import NamedSharding, PartitionSpec
 
     def place(leaf):
         a = np.asarray(leaf)
@@ -51,12 +48,10 @@ def reshard_tree(host_tree: Any, mesh, pspec: Optional[Any] = None,
         else:
             spec = default_pspec(a, mesh)
         try:
-            return jax_compat.reshard(a, mesh, spec)
+            return jax.device_put(a, NamedSharding(mesh, spec))
         except ValueError:
             # Spec does not divide this leaf (e.g. a scalar under a fixed
             # user pspec): replicate rather than fail the restore.
-            from jax.sharding import PartitionSpec
-
-            return jax_compat.reshard(a, mesh, PartitionSpec())
+            return jax.device_put(a, NamedSharding(mesh, PartitionSpec()))
 
     return jax.tree.map(place, host_tree)

@@ -134,7 +134,6 @@ class DCNCollectiveGroup:
     # --------------------------------------------------------- collectives
     def allreduce(self, rank: int, array: Any, op: str = ReduceOp.SUM) -> Any:
         import jax
-        from ray_tpu._private.jax_compat import shard_map
         from jax.sharding import PartitionSpec as P
 
         self._check_rank(rank)
@@ -147,7 +146,7 @@ class DCNCollectiveGroup:
         key = ("allreduce", op, x.shape, str(x.dtype))
 
         def build():
-            return jax.jit(shard_map(
+            return jax.jit(jax.shard_map(
                 lambda b: _lax_reduce(b, op, "ranks"), mesh=self._mesh,
                 in_specs=P("ranks"), out_specs=P("ranks")))
 
@@ -157,7 +156,6 @@ class DCNCollectiveGroup:
     def allgather(self, rank: int, array: Any) -> Any:
         import jax
         from jax import lax
-        from ray_tpu._private.jax_compat import shard_map
         from jax.sharding import PartitionSpec as P
 
         self._check_rank(rank)
@@ -167,7 +165,7 @@ class DCNCollectiveGroup:
         def build():
             # check_vma=False: the gathered output is replicated by
             # construction, which the static VMA check cannot infer.
-            return jax.jit(shard_map(
+            return jax.jit(jax.shard_map(
                 lambda b: lax.all_gather(b, "ranks", axis=0, tiled=True),
                 mesh=self._mesh, in_specs=P("ranks"), out_specs=P(),
                 check_vma=False))
@@ -178,7 +176,6 @@ class DCNCollectiveGroup:
     def reducescatter(self, rank: int, array: Any, op: str = ReduceOp.SUM) -> Any:
         import jax
         from jax import lax
-        from ray_tpu._private.jax_compat import shard_map
         from jax.sharding import PartitionSpec as P
 
         self._check_rank(rank)
@@ -203,7 +200,7 @@ class DCNCollectiveGroup:
                 idx = lax.axis_index("ranks")
                 return lax.dynamic_slice_in_dim(reduced, idx, 1, axis=0)
 
-            return jax.jit(shard_map(
+            return jax.jit(jax.shard_map(
                 body, mesh=self._mesh, in_specs=P("ranks"),
                 out_specs=P("ranks")))
 
@@ -214,7 +211,6 @@ class DCNCollectiveGroup:
         import jax
         import jax.numpy as jnp
         from jax import lax
-        from ray_tpu._private.jax_compat import shard_map
         from jax.sharding import PartitionSpec as P
 
         self._check_rank(rank)
@@ -227,7 +223,7 @@ class DCNCollectiveGroup:
                 contrib = jnp.where(idx == src_rank, b, jnp.zeros_like(b))
                 return lax.psum(contrib, "ranks")
 
-            return jax.jit(shard_map(
+            return jax.jit(jax.shard_map(
                 body, mesh=self._mesh, in_specs=P("ranks"), out_specs=P(),
                 check_vma=False))
 

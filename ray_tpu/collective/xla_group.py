@@ -214,7 +214,6 @@ class XLACollectiveGroup:
             key = ("allreduce", op, inputs[0].shape, str(inputs[0].dtype))
 
             def build():
-                from ray_tpu._private.jax_compat import shard_map
                 from jax.sharding import PartitionSpec as P
 
                 def body(x):
@@ -222,7 +221,7 @@ class XLACollectiveGroup:
                     return _lax_reduce(x, op, "ranks")
 
                 return jax.jit(
-                    shard_map(
+                    jax.shard_map(
                         body, mesh=mesh,
                         in_specs=P("ranks"), out_specs=P("ranks"),
                     )
@@ -260,7 +259,6 @@ class XLACollectiveGroup:
 
             def build():
                 from jax import lax
-                from ray_tpu._private.jax_compat import shard_map
                 from jax.sharding import PartitionSpec as P
 
                 def body(x):
@@ -270,7 +268,7 @@ class XLACollectiveGroup:
 
                 # check_vma=False: the gather output is replicated by
                 # construction, which the static VMA check cannot infer.
-                return jax.jit(shard_map(
+                return jax.jit(jax.shard_map(
                     body, mesh=mesh, in_specs=P("ranks"), out_specs=P(),
                     check_vma=False))
 
@@ -304,7 +302,6 @@ class XLACollectiveGroup:
 
             def build():
                 from jax import lax
-                from ray_tpu._private.jax_compat import shard_map
                 from jax.sharding import PartitionSpec as P
 
                 def body(x):
@@ -318,7 +315,7 @@ class XLACollectiveGroup:
                     idx = lax.axis_index("ranks")
                     return lax.dynamic_slice_in_dim(reduced, idx, 1, axis=0)
 
-                return jax.jit(shard_map(
+                return jax.jit(jax.shard_map(
                     body, mesh=mesh, in_specs=P("ranks"), out_specs=P("ranks")))
 
             fn = self._get_compiled(key, build)
@@ -344,7 +341,6 @@ class XLACollectiveGroup:
 
             def build():
                 from jax import lax
-                from ray_tpu._private.jax_compat import shard_map
                 from jax.sharding import PartitionSpec as P
 
                 def body(x):
@@ -355,7 +351,7 @@ class XLACollectiveGroup:
                     return lax.psum(contrib, "ranks")
 
                 # check_vma=False: psum output is replicated by construction.
-                return jax.jit(shard_map(
+                return jax.jit(jax.shard_map(
                     body, mesh=mesh, in_specs=P("ranks"), out_specs=P(),
                     check_vma=False))
 
@@ -406,7 +402,6 @@ class XLACollectiveGroup:
 
             def build():
                 from jax import lax
-                from ray_tpu._private.jax_compat import shard_map
                 from jax.sharding import PartitionSpec as P
 
                 def body(x):
@@ -414,7 +409,7 @@ class XLACollectiveGroup:
                     # move src->dst along the ring in one compiled op.
                     return lax.ppermute(x, "ranks", perm)
 
-                return jax.jit(shard_map(
+                return jax.jit(jax.shard_map(
                     body, mesh=mesh, in_specs=P("ranks"), out_specs=P("ranks")))
 
             fn = self._get_compiled(key, build)

@@ -34,7 +34,7 @@ class GPTConfig:
     #: measured best on v5e (recomputing attention in bwd is the one thing
     #: worth HBM); "full" rematerializes everything.
     remat_policy: str = "save_attn"
-    attn_impl: str = "auto"  # auto | xla | pallas | splash | ring | ulysses
+    attn_impl: str = "auto"  # auto | xla | splash | ring | ulysses
     #: Pipeline stages over the mesh's `pipe` axis (parallel/pipeline.py);
     #: 1 = no pipelining. n_layer % pp_stages must be 0.
     pp_stages: int = 1
@@ -176,23 +176,19 @@ def _attention(q, k, v, config: GPTConfig):
     the mesh via jax.set_mesh (parallel/train_state.py jit_train_step(mesh=)).
     """
     impl = config.attn_impl
-    if impl not in ("auto", "xla", "pallas", "splash", "ring", "ulysses"):
+    if impl not in ("auto", "xla", "splash", "ring", "ulysses"):
         raise ValueError(
             f"Unknown attn_impl: {impl!r} "
-            "(use auto|xla|pallas|splash|ring|ulysses)")
+            "(use auto|xla|splash|ring|ulysses)")
+    # "auto" is the splash kernel on TPU and the XLA path elsewhere (the CPU
+    # tests' reference).  A kernel the compiler refuses is an error, never a
+    # quiet switch to a slower path.
     if impl == "splash" or (impl == "auto" and jax.default_backend() == "tpu"):
-        try:
-            from ray_tpu.ops.attention import splash_attention
+        from ray_tpu.ops.attention import splash_attention
 
-            return splash_attention(q, k, v, causal=True,
-                                    block_q=config.attn_block_q,
-                                    block_kv=config.attn_block_kv)
-        except Exception as e:  # noqa: BLE001 — fall through to flash/xla
-            if impl == "splash":
-                raise
-            import warnings
-
-            warnings.warn(f"splash attention unavailable ({e}); falling back")
+        return splash_attention(q, k, v, causal=True,
+                                block_q=config.attn_block_q,
+                                block_kv=config.attn_block_kv)
     if impl == "ring":
         from ray_tpu.ops.ring_attention import ring_attention
 
@@ -201,17 +197,6 @@ def _attention(q, k, v, config: GPTConfig):
         from ray_tpu.ops.ring_attention import ulysses_attention
 
         return ulysses_attention(q, k, v, causal=True)
-    if impl == "pallas" or (impl == "auto" and jax.default_backend() == "tpu"):
-        try:
-            from ray_tpu.ops.attention import flash_attention
-
-            return flash_attention(q, k, v, causal=True)
-        except ImportError as e:
-            if impl == "pallas":
-                raise
-            import warnings
-
-            warnings.warn(f"flash attention unavailable ({e}); using XLA path")
     # XLA path: einsum softmax einsum; fp32 softmax.
     scale = 1.0 / math.sqrt(q.shape[-1])
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
@@ -379,10 +364,8 @@ def forward_hidden(params: Dict[str, Any], tokens, config: GPTConfig):
                 f"n_layer {config.n_layer} % pp_stages {config.pp_stages} != 0")
         # The mesh is authoritative for the stage count: a mismatched config
         # would silently run a different schedule than requested.
-        from ray_tpu._private.jax_compat import get_abstract_mesh
-
-        amesh = get_abstract_mesh()
-        if amesh is not None and "pipe" in getattr(amesh, "shape", {}) \
+        amesh = jax.sharding.get_abstract_mesh()
+        if "pipe" in amesh.shape \
                 and amesh.shape["pipe"] not in (1, config.pp_stages):
             raise ValueError(
                 f"config.pp_stages={config.pp_stages} but mesh pipe axis is "
@@ -419,15 +402,16 @@ def loss_fn(params, tokens, targets, config: GPTConfig):
     if impl not in ("auto", "fused", "dense"):
         raise ValueError(f"loss_impl must be auto|fused|dense, got {impl!r}")
     if impl == "auto":
-        from ray_tpu.ops.fused_ce import fused_ce_wins
+        # TPU-only flip (same gating as attn_impl): interpret-mode pallas
+        # off-TPU would be a silent orders-of-magnitude slowdown.
+        impl = "dense"
+        if jax.default_backend() == "tpu":
+            from ray_tpu._private.accelerators import device_peaks
+            from ray_tpu.ops.fused_ce import fused_ce_wins
 
-        # TPU-only flip (same gating as attn_impl): the roofline constants
-        # are v5e's, and interpret-mode pallas off-TPU would be a silent
-        # orders-of-magnitude slowdown.
-        import jax as _jax
-
-        impl = "fused" if (_jax.default_backend() == "tpu" and fused_ce_wins(
-            D, jnp.dtype(config.logits_dtype).itemsize)) else "dense"
+            if fused_ce_wins(D, jnp.dtype(config.logits_dtype).itemsize,
+                             device_peaks(jax.devices()[0].device_kind)):
+                impl = "fused"
     if impl == "fused":
         from ray_tpu.ops.fused_ce import fused_lm_head_ce
 

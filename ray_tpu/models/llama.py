@@ -37,7 +37,7 @@ class LlamaConfig:
     rms_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     remat: bool = True
-    attn_impl: str = "auto"  # auto | xla | pallas | splash | ring | ulysses
+    attn_impl: str = "auto"  # auto | xla | splash | ring | ulysses
     attn_block_q: int = 512
     attn_block_kv: int = 512
     logits_dtype: Any = jnp.bfloat16

@@ -1,38 +1,18 @@
-"""Fused causal flash attention for TPU.
+"""Fused causal flash attention for TPU: the splash kernel.
 
 Replaces the XLA einsum-softmax-einsum path, whose (B, H, S, S) fp32 score
-tensor is pure HBM traffic (805MB/layer for GPT-2-small at S=1024 — measured
-~10x over compute-bound time on v5e).  Flash attention keeps scores in VMEM
-tiles and never materializes them.
-
-Current implementation wraps jax's public pallas TPU flash kernel with block
-sizes tuned on v5e (defaults were 3.8x slower there: 58.6ms -> 15.3ms fwd for
-GPT-2-small's 12 layers).
+tensor is pure HBM traffic (805MB/layer for GPT-2-small at S=1024).  Splash
+keeps scores in VMEM tiles, never materializes them, and skips the blocks the
+causal mask empties.  head_dim=64 compiles unpadded under the 512x512 blocks
+on the v5e and agrees with the XLA path (chip_smoke.py, kernel phase).
 """
 
 from __future__ import annotations
 
 import math
-from functools import lru_cache
 from typing import Optional
 
-
-@lru_cache(maxsize=None)
-def _block_sizes(seq_len: int, block: int):
-    from jax.experimental.pallas.ops.tpu.flash_attention import BlockSizes
-
-    # The kernel requires block | seq_len: take the largest divisor <= block.
-    b = min(block, seq_len)
-    while seq_len % b != 0:
-        b -= 128 if b > 128 else 1
-        if b < 1:
-            b = seq_len
-            break
-    return BlockSizes(
-        block_q=b, block_k_major=b, block_k=b, block_b=1,
-        block_q_major_dkv=b, block_k_major_dkv=b, block_k_dkv=b, block_q_dkv=b,
-        block_k_major_dq=b, block_k_dq=b, block_q_dq=b,
-    )
+import jax
 
 
 def _splash_kernel(seq_len: int, n_heads: int, block_q: int, block_kv: int,
@@ -44,8 +24,6 @@ def _splash_kernel(seq_len: int, n_heads: int, block_q: int, block_kv: int,
         splash_attention_kernel as sk,
         splash_attention_mask as sm,
     )
-
-    import jax
 
     mask_cls = sm.CausalMask if causal else sm.FullMask
     mask = sm.MultiHeadMask(
@@ -64,87 +42,45 @@ def _splash_kernel(seq_len: int, n_heads: int, block_q: int, block_kv: int,
                               block_sizes=bs, interpret=interpret)
 
 
-_LANE_HEAD_REQUIRED: Optional[bool] = None
-
-
-def _head_pad_target(head_dim: int) -> int:
-    """Older splash kernels refuse head_dim % 128 != 0 (the lane tile) at
-    trace time; newer ones handle it internally.  Probe once with a shape
-    eval — when the restriction exists, callers zero-pad the head axis up
-    to the tile and slice the output back (zero k/v columns contribute
-    nothing to scores or outputs, so the math is unchanged)."""
-    global _LANE_HEAD_REQUIRED
-    if head_dim % 128 == 0:
-        return head_dim
-    if _LANE_HEAD_REQUIRED is None:
-        import jax
-        import jax.numpy as jnp
-
-        try:
-            kern = _splash_kernel(128, 1, 128, 128, True, True)
-            s = jax.ShapeDtypeStruct((1, 128, 64), jnp.float32)
-            jax.eval_shape(kern, s, s, s)
-            _LANE_HEAD_REQUIRED = False
-        except Exception:  # noqa: BLE001 — padding is always safe, just wider
-            _LANE_HEAD_REQUIRED = True
-    if not _LANE_HEAD_REQUIRED:
-        return head_dim
-    return -(-head_dim // 128) * 128
-
-
 def splash_attention(q, k, v, causal: bool = True,
                      sm_scale: Optional[float] = None,
                      block_q: int = 512, block_kv: int = 512,
                      fused_bwd: bool = True):
     """Production TPU attention (splash kernel): sparse over the causal
-    mask when causal (no wasted upper-triangle work, unlike the stock flash
-    kernel), full-mask bidirectional (ViT-style) otherwise, with a fused
-    dq/dkv backward.
+    mask when causal (no wasted upper-triangle work), full-mask
+    bidirectional (ViT-style) otherwise, with a fused dq/dkv backward.
 
     q, k, v: (B, S, H, head_dim) — the model's native layout.
-    """
-    import jax
 
-    B, S, H, hd = q.shape
+    On more than one device the kernel must run inside a ``shard_map`` that
+    makes every mesh axis manual: the SPMD partitioner cannot split a Mosaic
+    custom call, and jax refuses to lower one it would have to ("Mosaic
+    kernels cannot be automatically partitioned").  So under an ambient mesh
+    (``jax.set_mesh``; ``jit_train_step(mesh=)`` installs it) the batch is
+    divided over its `data` and `fsdp` axes and the heads over `tensor`;
+    axes the spec does not name see replicated data.
+    """
+    _, S, _, hd = q.shape
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(hd)
-    kernel = _splash_kernel(S, H, block_q, block_kv, fused_bwd, causal)
-    # Splash takes (H, S, hd) per example; scale q up front (no scale arg).
-    qt = (q * sm_scale).transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
-    hp = _head_pad_target(hd)
-    if hp != hd:
-        import jax.numpy as jnp
 
-        pad = ((0, 0), (0, 0), (0, 0), (0, hp - hd))
-        qt, kt, vt = (jnp.pad(x, pad) for x in (qt, kt, vt))
-    out = jax.vmap(kernel)(qt, kt, vt)  # (B, H, S, hp)
-    if hp != hd:
-        out = out[..., :hd]
-    return out.transpose(0, 2, 1, 3)
+    def local(q, k, v):
+        kernel = _splash_kernel(S, q.shape[2], block_q, block_kv, fused_bwd,
+                                causal)
+        # Splash takes (H, S, hd) per example; scale q up front (no scale arg).
+        qt = (q * sm_scale).transpose(0, 2, 1, 3)
+        kt = k.transpose(0, 2, 1, 3)
+        vt = v.transpose(0, 2, 1, 3)
+        return jax.vmap(kernel)(qt, kt, vt).transpose(0, 2, 1, 3)
 
-
-def flash_attention(q, k, v, causal: bool = True, sm_scale: Optional[float] = None,
-                    block: int = 1024):
-    """q, k, v: (B, S, H, head_dim) — the model's native layout.
-
-    Scaling matches the unfused path: 1/sqrt(head_dim) unless given.
-    """
-    from jax.experimental.pallas.ops.tpu.flash_attention import (
-        flash_attention as _pallas_flash,
-    )
-
-    if sm_scale is None:
-        sm_scale = 1.0 / math.sqrt(q.shape[-1])
-    # Pallas kernel wants (B, H, S, D).
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
-    out = _pallas_flash(
-        qt, kt, vt,
-        causal=causal,
-        sm_scale=sm_scale,
-        block_sizes=_block_sizes(q.shape[1], block),
-    )
-    return out.transpose(0, 2, 1, 3)
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return local(q, k, v)
+    batch_axes = tuple(a for a in ("data", "fsdp") if a in mesh.axis_names)
+    spec = jax.sharding.PartitionSpec(
+        batch_axes or None, None,
+        "tensor" if "tensor" in mesh.axis_names else None, None)
+    # check_vma off: the splash pallas_call does not declare vma on its
+    # output avals, which the vma checker rejects.
+    return jax.shard_map(local, in_specs=(spec, spec, spec), out_specs=spec,
+                         check_vma=False)(q, k, v)

@@ -246,10 +246,11 @@ def _fused_ce_bwd(block_rows: int, bwd_impl: str, res, g):
 _fused_ce.defvjp(_fused_ce_fwd, _fused_ce_bwd)
 
 
-def fused_ce_wins(d_model: int, logits_dtype_bytes: int = 2,
-                  matmul_eff: float = 0.5, peak_flops: float = 197e12,
-                  hbm_bw: float = 819e9) -> bool:
-    """Roofline cost model, overlap-aware (measured r5, BENCH_FUSED_CE):
+def fused_ce_wins(d_model: int, logits_dtype_bytes: int, peaks,
+                  matmul_eff: float = 0.5) -> bool:
+    """Roofline cost model, overlap-aware (measured r5, BENCH_FUSED_CE),
+    against the ``peaks`` of the device the step runs on
+    (``_private.accelerators.device_peaks(device_kind)``):
     XLA overlaps the dense path's logits traffic with its matmuls, so per
     (token, vocab) element dense costs max(3 matmul passes, ~5
     bytes-per-logit of HBM) while fused costs 5 matmul passes (fwd + 2x
@@ -260,8 +261,8 @@ def fused_ce_wins(d_model: int, logits_dtype_bytes: int = 2,
     the absolute win when logits cannot materialize at all.
     GPT-2-small's D=768 correctly stays dense.  `auto` loss dispatch
     (models/gpt2.py loss_fn) flips on this."""
-    per_elem = 2.0 * d_model / (matmul_eff * peak_flops)  # one matmul pass
-    dense_s = max(3.0 * per_elem, 5.0 * logits_dtype_bytes / hbm_bw)
+    per_elem = 2.0 * d_model / (matmul_eff * peaks.flops)  # one matmul pass
+    dense_s = max(3.0 * per_elem, 5.0 * logits_dtype_bytes / peaks.hbm_bw)
     fused_s = 5.0 * per_elem
     return fused_s < dense_s
 

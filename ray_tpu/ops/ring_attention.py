@@ -39,14 +39,6 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _axis_size(axis_name: str) -> int:
-    """lax.axis_size is a recent addition; psum of a constant 1 is the
-    long-standing spelling and folds to a static int on every version."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
-
 def _ring_block(seq_len: int) -> Optional[int]:
     """Largest multiple-of-128 divisor of seq_len, capped at the v5e-tuned
     512 (ops/attention.py) — None when no legal splash block exists."""
@@ -198,7 +190,7 @@ def _fused_ring_core(q, k, v, axis_name: str, causal: bool, block: int):
 
 
 def _fused_ring_fwd(q, k, v, axis_name: str, causal: bool, block: int):
-    world = _axis_size(axis_name)
+    world = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     B, H, S, D = q.shape
     interp = _interpret()
@@ -245,7 +237,7 @@ def _fused_ring_bwd(axis_name: str, causal: bool, block: int, res, do):
     )
 
     q, k, v, o, lse = res
-    world = _axis_size(axis_name)
+    world = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     B, H, S, D = q.shape
     interp = _interpret()
@@ -336,18 +328,7 @@ def fused_ring_attention_local(q, k, v, *, axis_name: str = "seq",
     qt = (q * scale).transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    # Old splash kernels require head_dim % 128 == 0: zero-pad the head
-    # axis (padding is exact — zero k/v columns add nothing) and slice
-    # back.  Outside the custom VJP, so the backward sees padded shapes too.
-    from ray_tpu.ops.attention import _head_pad_target
-
-    hp = _head_pad_target(D)
-    if hp != D:
-        pad = ((0, 0), (0, 0), (0, 0), (0, hp - D))
-        qt, kt, vt = (jnp.pad(x, pad) for x in (qt, kt, vt))
     out = _fused_ring_core(qt, kt, vt, axis_name, causal, block)
-    if hp != D:
-        out = out[..., :D]
     return out.transpose(0, 2, 1, 3)
 
 
@@ -374,7 +355,7 @@ def ring_attention_local(q, k, v, *, axis_name: str = "seq",
     if impl == "fused":
         return fused_ring_attention_local(q, k, v, axis_name=axis_name,
                                           causal=causal, sm_scale=sm_scale)
-    world = _axis_size(axis_name)
+    world = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     B, S, H, D = q.shape
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(D)
@@ -429,7 +410,7 @@ def ulysses_attention_local(q, k, v, *, axis_name: str = "seq",
                             attn_fn=None):
     """Body for shard_map: all_to_all (B, S/w, H, D) -> (B, S, H/w, D),
     full-sequence attention per head shard, then the inverse reshard."""
-    world = _axis_size(axis_name)
+    world = lax.axis_size(axis_name)
     H = q.shape[2]
     if H % world != 0:
         raise ValueError(f"Ulysses needs heads ({H}) % seq axis ({world}) == 0")
@@ -460,16 +441,6 @@ def _xla_attention(q, k, v, causal: bool = True,
 
 
 # ------------------------------------------------------------ shard_map APIs
-def _shard_map(fn, *, mesh, in_specs, out_specs, check_vma=True):
-    """shard_map moved (jax.experimental.shard_map → jax.shard_map) and
-    renamed its replication-check kwarg (check_rep → check_vma) across jax
-    releases; jax_compat resolves whichever spelling this jax ships."""
-    from ray_tpu._private.jax_compat import shard_map as _sm
-
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_vma=check_vma)
-
-
 def _specs(axis_name: str, batch_axes):
     P = jax.sharding.PartitionSpec
     return P(batch_axes, axis_name, "tensor", None)
@@ -488,8 +459,8 @@ def ring_attention(q, k, v, *, mesh=None, axis_name: str = "seq",
                  sm_scale=sm_scale, impl=impl)
     # check_vma off: the splash pallas_call inside the fused body does not
     # declare vma on its output avals, which the vma checker rejects.
-    return _shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                      out_specs=spec, check_vma=False)(q, k, v)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 def ulysses_attention(q, k, v, *, mesh=None, axis_name: str = "seq",
@@ -499,5 +470,5 @@ def ulysses_attention(q, k, v, *, mesh=None, axis_name: str = "seq",
     spec = _specs(axis_name, batch_axes)
     fn = partial(ulysses_attention_local, axis_name=axis_name, causal=causal,
                  sm_scale=sm_scale, attn_fn=attn_fn)
-    return _shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                      out_specs=spec)(q, k, v)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec)(q, k, v)

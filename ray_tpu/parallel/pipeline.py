@@ -41,9 +41,7 @@ def _pipeline_local(stage_fn: Callable[[Any, Any], Any],
     import jax.numpy as jnp
     from jax import lax
 
-    from ray_tpu._private.jax_compat import axis_size
-
-    n_stages = axis_size(axis_name)
+    n_stages = lax.axis_size(axis_name)
     stage = lax.axis_index(axis_name)
     ticks = n_microbatches + n_stages - 1
     # Shift chain toward the next stage; the final stage's output is dropped
@@ -114,11 +112,8 @@ def pipeline_apply(stage_fn: Callable[[Any, Any], Any],
 
     # Validate the layer stack against the ACTUAL pipe axis size (the mesh is
     # authoritative — a config's stage count can silently disagree with it).
-    from ray_tpu._private.jax_compat import get_abstract_mesh
-    from ray_tpu._private.jax_compat import shard_map as _shard_map
-
-    resolved = mesh if mesh is not None else get_abstract_mesh()
-    if resolved is not None and axis_name in getattr(resolved, "shape", {}):
+    resolved = mesh if mesh is not None else jax.sharding.get_abstract_mesh()
+    if axis_name in resolved.shape:
         n_stages = resolved.shape[axis_name]
         for leaf in jax.tree.leaves(stage_params):
             if leaf.shape[0] % n_stages:
@@ -142,8 +137,8 @@ def pipeline_apply(stage_fn: Callable[[Any, Any], Any],
     params_spec = jax.tree.map(lambda _: P(axis_name), stage_params)
     fn = partial(_pipeline_local, stage_fn, axis_name=axis_name,
                  n_microbatches=n_microbatches)
-    out = _shard_map(fn, mesh=mesh,
-                     in_specs=(params_spec, P()),
-                     out_specs=P(),
-                     axis_names={axis_name})(stage_params, x_mb)
+    out = jax.shard_map(fn, mesh=mesh,
+                        in_specs=(params_spec, P()),
+                        out_specs=P(),
+                        axis_names={axis_name})(stage_params, x_mb)
     return out.reshape(B, *x.shape[1:]).astype(compute_dtype)

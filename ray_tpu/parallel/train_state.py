@@ -11,7 +11,6 @@ written by hand.
 
 from __future__ import annotations
 
-from contextlib import nullcontext
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -31,9 +30,7 @@ def create_sharded_state(
     trip: init runs under jit with out_shardings so each device materializes
     only its shard) and derive optimizer state with propagated shardings."""
     shardings = pytree_sharding(logical, mesh, rules)
-    from ray_tpu._private.jax_compat import set_mesh as _set_mesh
-
-    with _set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = jax.jit(init_fn, out_shardings=shardings)(key)
         opt_state = None
         if optimizer is not None:
@@ -45,19 +42,20 @@ def jit_train_step(step_fn, donate_state: bool = True, mesh=None):
     """jit with donated (params, opt_state) so updates reuse their buffers —
     the HBM discipline that makes big models fit.
 
-    Pass ``mesh`` when the model uses context-parallel attention
-    (attn_impl="ring"/"ulysses"): those ops shard_map over the AMBIENT mesh,
-    which this wrapper installs around trace/execute via jax.set_mesh.
+    Pass ``mesh`` whenever the state lives on more than one device: the
+    Pallas attention kernel (splash, what attn_impl="auto" means on TPU) and
+    the context-parallel paths (attn_impl="ring"/"ulysses") shard_map over
+    the AMBIENT mesh, which this wrapper installs around trace/execute via
+    jax.set_mesh.  Without it jax refuses to lower the kernel for several
+    devices.
     """
     donate = (0, 1) if donate_state else ()
     jitted = jax.jit(step_fn, donate_argnums=donate)
     if mesh is None:
         return jitted
 
-    from ray_tpu._private.jax_compat import set_mesh as _set_mesh
-
     def call(*args, **kwargs):
-        with _set_mesh(mesh):
+        with jax.set_mesh(mesh):
             return jitted(*args, **kwargs)
 
     return call

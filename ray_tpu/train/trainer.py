@@ -215,7 +215,9 @@ class JaxDistTrainWorker:
         """Join the multi-controller cluster; returns topology for sanity
         checks.  Called CONCURRENTLY on all ranks (initialize barriers)."""
         from ray_tpu.collective import distributed
+        from ray_tpu.parallel.compile_cache import configure_compile_cache
 
+        configure_compile_cache()
         distributed.initialize(coordinator, self.world, self.rank)
         collective.init_collective_group(self.world, self.rank, backend="xla",
                                          group_name=self.group_name)
@@ -1186,4 +1188,14 @@ class JaxTrainer(DataParallelTrainer):
     ``ScalingConfig(worker_mode="processes")``), each worker becomes its own
     OS process joined into one jax.distributed cluster: jax.devices() spans
     every worker's chips, meshes ride ICI within a host and DCN across, and
-    the same train_loop runs unchanged (multi-controller SPMD)."""
+    the same train_loop runs unchanged (multi-controller SPMD).  That tier
+    has only run on CPU devices: several processes dividing one host's chips
+    need per-process chip visibility that nothing here sets."""
+
+    def fit(self) -> Result:
+        # Thread-tier workers compile in this process; process-tier ranks
+        # configure their own in JaxDistTrainWorker.setup.
+        from ray_tpu.parallel.compile_cache import configure_compile_cache
+
+        configure_compile_cache()
+        return super().fit()

@@ -56,13 +56,15 @@ def test_cost_model_and_gpt2_auto_dispatch():
     roofline model predicts a win (small D / fp32 logits), and the fused
     GPT-2 loss matches the dense path."""
     from ray_tpu.models import gpt2
+    from ray_tpu._private.accelerators import device_peaks
     from ray_tpu.ops.fused_ce import fused_ce_wins
 
     # The model's documented regime boundaries (v5e constants).
-    assert not fused_ce_wins(768, 2)   # GPT-2-small bf16: dense
-    assert not fused_ce_wins(768, 4)   # GPT-2-small fp32: dense
-    assert fused_ce_wins(128, 4)       # small head, exact softmax: fused
-    assert not fused_ce_wins(512, 2)
+    v5e = device_peaks("TPU v5 lite")
+    assert not fused_ce_wins(768, 2, v5e)   # GPT-2-small bf16: dense
+    assert not fused_ce_wins(768, 4, v5e)   # GPT-2-small fp32: dense
+    assert fused_ce_wins(128, 4, v5e)       # small head, exact softmax: fused
+    assert not fused_ce_wins(512, 2, v5e)
 
     rng = np.random.default_rng(0)
     tokens = jnp.asarray(rng.integers(0, 128, (2, 32)), jnp.int32)

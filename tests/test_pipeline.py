@@ -10,7 +10,6 @@ from ray_tpu.models import gpt2, moe
 from ray_tpu.parallel import (MeshSpec, batch_sharding, make_mesh,
                               pipeline_apply, pytree_sharding)
 from ray_tpu.parallel.train_state import create_sharded_state, jit_train_step
-from ray_tpu._private.jax_compat import set_mesh
 
 
 @pytest.fixture(scope="module")
@@ -32,7 +31,7 @@ def test_pipeline_matches_sequential(pipe_mesh):
         return h
 
     expect = stage_fn(w, x)  # all layers in one scan
-    with set_mesh(pipe_mesh):
+    with jax.set_mesh(pipe_mesh):
         got = jax.jit(
             lambda w, x: pipeline_apply(stage_fn, w, x, n_microbatches=4)
         )(w, x)
@@ -58,7 +57,7 @@ def test_pipeline_gradients_match(pipe_mesh):
         return jnp.sum(pipeline_apply(stage_fn, w, x, n_microbatches=2) ** 2)
 
     g_seq = jax.grad(seq_loss)(w)
-    with set_mesh(pipe_mesh):
+    with jax.set_mesh(pipe_mesh):
         g_pipe = jax.jit(jax.grad(pipe_loss))(w)
     np.testing.assert_allclose(np.asarray(g_pipe), np.asarray(g_seq),
                                rtol=2e-4, atol=2e-4)
@@ -77,7 +76,7 @@ def test_gpt2_pipelined_forward_matches_unpipelined():
         np.random.default_rng(0).integers(0, 512, (4, 32)), jnp.int32)
 
     ref = gpt2.forward(params, tokens, base)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         sharded = jax.device_put(
             params, pytree_sharding(gpt2.logical_axes(pp), mesh))
         got = jax.jit(lambda p, t: gpt2.forward(p, t, pp))(sharded, tokens)
@@ -169,7 +168,7 @@ def test_moe_expert_parallel_matches_replicated():
     ref, aux_ref = moe.forward(params, tokens, config)
 
     mesh = make_mesh(MeshSpec(expert=4, data=2))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         sharded = jax.device_put(
             params, pytree_sharding(moe.logical_axes(config), mesh))
         got, aux = jax.jit(lambda p, t: moe.forward(p, t, config))(
