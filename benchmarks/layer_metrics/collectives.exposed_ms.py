@@ -1,0 +1,18 @@
+"""Per step, the time a collective instruction runs on a chip while no other
+instruction does there; the worst chip, over the trace's steady stretch.
+Collective are the instructions named as one (``all-gather``,
+``async-collective-done``, ...) and the fusions that wrap one
+(``calls=%all-reduce-scatter``, ``calls=%async_collective_fusion``; the second
+kind overlaps its own compute with its transfer and counts whole)."""
+from benchmarks.lib import trace_reduce
+
+LAYER, UNIT, SOURCE, MOVES = "collectives", "ms/step", "device_trace", \
+    "tokens_per_s_per_chip"
+
+
+def read(run):
+    if not run.steady or not run.trace.devices:
+        return None
+    lo, hi, steps = run.steady[:3]
+    return 1e3 * max(trace_reduce.exposed_collective_seconds(d.ops, lo, hi)
+                     for d in run.trace.devices.values()) / steps
