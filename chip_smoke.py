@@ -283,8 +283,8 @@ def train_phase(config, seqs_per_chip: int, peak_flops_per_chip: float,
 
         # The same step, traced afresh for the first call's arguments (the
         # step's outputs carry other, equivalent sharding specs, which spell
-        # another module), through the AOT route that InstrumentedJit takes
-        # (lower().compile()): it should find in the persistent cache the
+        # another module), through the AOT route that TrainStep.anatomy()
+        # takes (lower().compile()): it should find in the persistent cache the
         # executable the first call put there, and its text shows what each
         # chip really runs.
         before, t0 = watch.snapshot(), time.perf_counter()

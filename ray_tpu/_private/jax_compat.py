@@ -1,5 +1,7 @@
-"""Instrumented jax entry points: the compile tap and the ledgered batch
-transfer that feed :mod:`ray_tpu.util.device_telemetry`.
+"""Instrumented jax entry point: the ledgered batch transfer that feeds
+:mod:`ray_tpu.util.device_telemetry` and the step profiler's ``h2d_bytes``.
+(The compile tap is jax's own monitoring events, see
+``device_telemetry.listen_for_compiles``.)
 
 The package targets the installed jax (0.9.0) and calls ``jax.shard_map``,
 ``jax.set_mesh``, ``jax.sharding.get_abstract_mesh`` and ``lax.axis_size``
@@ -9,7 +11,6 @@ directly; nothing here translates between jax versions.
 from __future__ import annotations
 
 import sys
-import time
 
 import jax
 
@@ -20,76 +21,6 @@ def _telemetry():
     keeps this module import-free and the no-observer cost at one
     dict miss."""
     return sys.modules.get("ray_tpu.util.device_telemetry")
-
-
-class InstrumentedJit:
-    """``jax.jit`` with a compile tap: every trace/lower/compile is timed
-    and recorded into :mod:`ray_tpu.util.device_telemetry` with a
-    classified trigger (first_compile / shape_change / sharding_change /
-    donation_change).
-
-    Uses the AOT path — ``jitted.lower(*args)`` (trace+lower wall) then
-    ``.compile()`` (compile wall) — cached per abstract signature, so the
-    steady-state call is one tuple-build + dict hit + compiled dispatch
-    (the bench_profiler A/B gates this at <=1% of a GPT-2 train step).
-    Positional args only, matching how the repo calls its jitted steps.
-    """
-
-    def __init__(self, fn, *, label=None, donate_argnums=(), **jit_kwargs):
-        self._jitted = jax.jit(fn, donate_argnums=donate_argnums,
-                               **jit_kwargs)
-        self.label = label or getattr(fn, "__name__", "jit_fn")
-        self._donation = tuple(donate_argnums) if donate_argnums else ()
-        self._cache = {}
-
-    @staticmethod
-    def _signature(args):
-        """(shapes, shardings) abstract signature of positional args:
-        array leaves key by shape+dtype (+ the pytree structure), python
-        scalars by type (jit traces them — a changed value is not a
-        changed signature), shardings by the sharding objects themselves
-        (hashable, equality = same committed placement).  Raw objects,
-        not reprs — repr of a sharding walks its device list and would
-        dominate the steady-state dispatch the bench gates at <=1%."""
-        leaves, treedef = jax.tree_util.tree_flatten(args)
-        shapes = []
-        shardings = []
-        for leaf in leaves:
-            shape = getattr(leaf, "shape", None)
-            dtype = getattr(leaf, "dtype", None)
-            if shape is None or dtype is None:
-                shapes.append(type(leaf).__name__)
-                shardings.append(None)
-            else:
-                shapes.append((tuple(shape), dtype))
-                shardings.append(getattr(leaf, "sharding", None))
-        return (tuple(shapes), treedef), tuple(shardings)
-
-    def __call__(self, *args):
-        shapes, shardings = self._signature(args)
-        key = (shapes, shardings)
-        compiled = self._cache.get(key)
-        if compiled is None:
-            t0 = time.perf_counter()
-            lowered = self._jitted.lower(*args)
-            t1 = time.perf_counter()
-            compiled = lowered.compile()
-            t2 = time.perf_counter()
-            self._cache[key] = compiled
-            from ray_tpu.util import device_telemetry
-
-            device_telemetry.record_compile(
-                self.label, shapes=shapes, shardings=shardings,
-                donation=self._donation, trace_s=t1 - t0,
-                compile_s=t2 - t1)
-        return compiled(*args)
-
-
-def instrumented_jit(fn, *, label=None, donate_argnums=(), **jit_kwargs):
-    """Drop-in for ``jax.jit(fn, donate_argnums=...)`` that records every
-    compile into the device-telemetry plane (see :class:`InstrumentedJit`)."""
-    return InstrumentedJit(fn, label=label, donate_argnums=donate_argnums,
-                           **jit_kwargs)
 
 
 def device_put_batch(batch, sharding=None, *, transfer_src="device_put_batch"):
@@ -106,7 +37,9 @@ def device_put_batch(batch, sharding=None, *, transfer_src="device_put_batch"):
 
     Numeric columns dispatched are ledgered (direction h2d, bytes,
     ``transfer_src``) into the device-telemetry plane when it is loaded —
-    probed, not imported, so the no-observer cost is one dict miss."""
+    probed, not imported, so the no-observer cost is one dict miss — and
+    counted into the calling train worker's ``StepProfiler`` row
+    (``h2d_bytes``), probed the same way."""
     import numpy as np
 
     out = {}
@@ -126,6 +59,9 @@ def device_put_batch(batch, sharding=None, *, transfer_src="device_put_batch"):
     telemetry = _telemetry()
     if telemetry is not None and nbytes:
         telemetry.record_transfer("h2d", nbytes, src=transfer_src)
+    profiler = sys.modules.get("ray_tpu.train.profiler")
+    if profiler is not None:
+        profiler.count("h2d_bytes", nbytes)
     return out
 
 

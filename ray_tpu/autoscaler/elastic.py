@@ -98,9 +98,9 @@ def pick_preemptible_node(exclude_head: bool = True) -> Optional[str]:
 
 def simulate_preemption(node_id: Optional[str] = None,
                         exclude_head: bool = True) -> Optional[str]:
-    """Preempt one node: kill every actor it hosts (no restart — a
-    preempted slice does not come back as the same node), then remove the
-    node from the scheduler.  Returns the preempted node id, or None when
+    """Preempt one node: remove it from the scheduler, then kill every
+    actor it hosted (no restart — a preempted slice does not come back as
+    the same node).  Returns the preempted node id, or None when
     no candidate node exists (e.g. a single-head cluster with
     ``exclude_head``)."""
     from ray_tpu._private.ids import NodeID
@@ -112,15 +112,19 @@ def simulate_preemption(node_id: Optional[str] = None,
         if node_id is None:
             return None
     victims = actors_on_node(node_id)
+    # The node goes first: a controller that sees a dead worker and asks
+    # worker_capacity() must not still count the node that took it (it
+    # would "recover" at the old world size instead of shrinking; the
+    # window was the 10-50 ms the kills take, against a 250 ms health poll).
+    try:
+        runtime.scheduler.remove_node(NodeID(str(node_id)))
+    except Exception:
+        pass
     for aid in victims:
         try:
             runtime.kill_actor(aid, no_restart=True)
         except Exception:  # already dying — the node removal still counts
             pass
-    try:
-        runtime.scheduler.remove_node(NodeID(str(node_id)))
-    except Exception:
-        pass
     from ray_tpu.train import metrics as train_metrics
 
     train_metrics.PREEMPTIONS.inc()

@@ -66,7 +66,14 @@ class HostPrefetcher:
 
     def _pump(self, src: Iterator[Any]) -> None:
         try:
-            for item in src:
+            while True:
+                # This thread shares the GIL with the step loop: its pull
+                # is a span, so a profile shows what the host was doing
+                # while the worker's thread stalled.
+                with tracing.annotate("data.pump"):
+                    item = next(src, _END)
+                if item is _END:
+                    break
                 if not self._put(("item", item)):
                     return
                 ingest_metrics.PREFETCH_OCCUPANCY.set(self._q.qsize())
@@ -98,21 +105,8 @@ class HostPrefetcher:
                     # gave up on (a graceful grow drain keeps yielding, so
                     # it never trips this).
                     t0 = time.monotonic()
-                    while True:
-                        try:
-                            kind, item = self._q.get(timeout=0.5)
-                            break
-                        except queue.Empty:
-                            if (self._should_stop is not None
-                                    and self._should_stop()
-                                    and time.monotonic() - t0 > 5.0):
-                                from ray_tpu.data.ingest.executor import (
-                                    IngestAborted,
-                                )
-
-                                raise IngestAborted(
-                                    "session stopped while the prefetch "
-                                    "queue was starved")
+                    with tracing.annotate("train.data_wait"):
+                        kind, item = self._starved_get(t0)
                     starved = time.monotonic() - t0
                     ingest_metrics.STARVED_SECONDS.inc(starved)
                     w1 = time.time()
@@ -125,6 +119,20 @@ class HostPrefetcher:
                 yield item
         finally:
             self.close()
+
+    def _starved_get(self, t0: float):
+        while True:
+            try:
+                return self._q.get(timeout=0.5)
+            except queue.Empty:
+                if (self._should_stop is not None
+                        and self._should_stop()
+                        and time.monotonic() - t0 > 5.0):
+                    from ray_tpu.data.ingest.executor import IngestAborted
+
+                    raise IngestAborted(
+                        "session stopped while the prefetch queue was "
+                        "starved")
 
     def close(self) -> None:
         self._stop.set()

@@ -9,10 +9,15 @@ an operator's dashboard:
   call sites must name a key of ``fault_injection.FAULT_POINTS`` (and,
   scanning the whole package, every registered point must be consulted
   somewhere: a dead registry row is a lie about coverage);
-* span names — ``tracing.span("x")`` / ``record_span[_batch]("x")`` must
-  name a key of ``tracing.SPAN_REGISTRY``; dynamic f-string names must
-  start with a registered prefix entry (``...::`` or trailing-``_``
-  families like ``serve.ttft_``);
+* span names — ``tracing.span("x")`` / ``annotate("x")`` /
+  ``record_span[_batch]("x")`` must name a key of
+  ``tracing.SPAN_REGISTRY``; dynamic f-string names must start with a
+  registered prefix entry (``...::`` or trailing-``_`` families like
+  ``serve.ttft_``);
+* scope names — ``jax.named_scope("x")`` (the names inside the compiled
+  step that ``TrainStep.anatomy()`` and the benchmark's ``step.*_ms``
+  metrics read) must name a key of ``tracing.SCOPE_REGISTRY``, and every
+  registered scope must be opened somewhere;
 * SLO objectives — ``SLOObjective("x", ...)`` call sites must name a key
   of ``serve.slo.SLO_OBJECTIVES``, and every registered objective must be
   wired into the watchdog's evaluation path (an objective nobody can
@@ -40,7 +45,7 @@ METRIC_CTORS = ("Counter", "Gauge", "Histogram")
 #: the metric library itself declares no metrics; skip it and the analyzer
 _METRIC_EXEMPT = ("ray_tpu/util/metrics.py", "ray_tpu/devtools/")
 _FAULT_RECEIVERS = ("fault_injection", "injector", "inj")
-_SPAN_FUNCS = ("span", "record_span", "record_span_batch")
+_SPAN_FUNCS = ("span", "annotate", "record_span", "record_span_batch")
 
 
 def _first_arg_str(call: ast.Call) -> Optional[str]:
@@ -75,6 +80,7 @@ class RegistryConsistencyChecker(core.Checker):
         consulted: Set[str] = ctx.scratch.setdefault(
             "fault_points_consulted", set())
         spans_used: Set[str] = ctx.scratch.setdefault("spans_used", set())
+        scopes_used: Set[str] = ctx.scratch.setdefault("scopes_used", set())
         metric_sites: Dict[str, List[Tuple[str, int]]] = ctx.scratch.setdefault(
             "metric_sites", {})
         in_fault_module = module.path.endswith("fault_injection.py")
@@ -193,6 +199,22 @@ class RegistryConsistencyChecker(core.Checker):
                                      f"matches no prefix entry ('::' or "
                                      f"trailing '_') in "
                                      f"tracing.SPAN_REGISTRY"))
+            # --- scopes inside the compiled step ------------------------
+            if ctx.scope_names is not None and (
+                    (isinstance(func, ast.Attribute)
+                     and func.attr == "named_scope")
+                    or (isinstance(func, ast.Name)
+                        and func.id == "named_scope")):
+                scope = _first_arg_str(node)
+                if scope is not None:
+                    scopes_used.add(scope)
+                    if scope not in ctx.scope_names:
+                        yield core.Finding(
+                            check=self.name, path=module.path,
+                            line=node.lineno, symbol="<scope>",
+                            detail=f"scope:{scope}",
+                            message=(f"named_scope '{scope}' is not "
+                                     f"declared in tracing.SCOPE_REGISTRY"))
             # --- metric declarations -----------------------------------
             ctor = None
             if isinstance(func, ast.Name) and func.id in METRIC_CTORS:
@@ -242,6 +264,13 @@ class RegistryConsistencyChecker(core.Checker):
                     symbol="<span>", detail=f"span-unused:{span}",
                     message=(f"SPAN_REGISTRY entry '{span}' is never opened "
                              f"by any span()/record_span call site"))
+        scopes_used = ctx.scratch.get("scopes_used", set())
+        for scope in sorted((ctx.scope_names or set()) - scopes_used):
+            yield core.Finding(
+                check=self.name, path="ray_tpu/util/tracing.py", line=1,
+                symbol="<scope>", detail=f"scope-unused:{scope}",
+                message=(f"SCOPE_REGISTRY entry '{scope}' is never opened "
+                         f"by any named_scope call site"))
         slo_used = ctx.scratch.get("slo_objectives_used", set())
         if ctx.slo_objectives:
             for name in sorted(ctx.slo_objectives - slo_used):

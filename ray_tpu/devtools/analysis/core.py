@@ -236,6 +236,7 @@ class AnalysisContext:
     fault_points: Optional[Set[str]] = None
     span_names: Optional[Set[str]] = None
     span_prefixes: Optional[Tuple[str, ...]] = None
+    scope_names: Optional[Set[str]] = None
     slo_objectives: Optional[Set[str]] = None
     metric_prefixes: Tuple[str, ...] = ("ray_tpu_", "serve_")
     #: set when the scan covers the whole package — enables aggregate
@@ -258,7 +259,7 @@ def _extract_literal_dict_keys(tree: ast.AST, var_name: str) -> Set[str]:
 
 
 def load_registries(ctx: AnalysisContext, package_dir: str) -> None:
-    """Fill ctx's fault-point, span and SLO-objective registries from the
+    """Fill ctx's fault-point, span, scope and SLO-objective registries from the
     package sources (AST only — the analyzer never imports the analyzed
     code)."""
     fi = os.path.join(package_dir, "_private", "fault_injection.py")
@@ -268,6 +269,10 @@ def load_registries(ctx: AnalysisContext, package_dir: str) -> None:
         with open(fi, encoding="utf-8") as f:
             ctx.fault_points = _extract_literal_dict_keys(
                 ast.parse(f.read()), "FAULT_POINTS")
+    if ctx.scope_names is None and os.path.exists(tr):
+        with open(tr, encoding="utf-8") as f:
+            ctx.scope_names = _extract_literal_dict_keys(
+                ast.parse(f.read()), "SCOPE_REGISTRY")
     if ctx.span_names is None and os.path.exists(tr):
         with open(tr, encoding="utf-8") as f:
             names = _extract_literal_dict_keys(ast.parse(f.read()),

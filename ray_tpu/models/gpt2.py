@@ -175,6 +175,11 @@ def _attention(q, k, v, config: GPTConfig):
     attention runs seq-sharded over the mesh's `seq` axis — callers install
     the mesh via jax.set_mesh (parallel/train_state.py jit_train_step(mesh=)).
     """
+    with jax.named_scope("attn_kernel"):
+        return _attention_impl(q, k, v, config)
+
+
+def _attention_impl(q, k, v, config: GPTConfig):
     impl = config.attn_impl
     if impl not in ("auto", "xla", "splash", "ring", "ulysses"):
         raise ValueError(
@@ -212,10 +217,11 @@ def _block_pre_attn(x, blk, config: GPTConfig):
     from jax.ad_checkpoint import checkpoint_name
 
     dt = config.dtype
-    h = _layernorm(x, blk["ln1_scale"], blk["ln1_bias"]).astype(dt)
-    h = checkpoint_name(h, "ln1_out")
-    qkv = h @ blk["qkv_w"].astype(dt) + blk["qkv_b"].astype(dt)
-    return checkpoint_name(qkv, "qkv")
+    with jax.named_scope("attn"):
+        h = _layernorm(x, blk["ln1_scale"], blk["ln1_bias"]).astype(dt)
+        h = checkpoint_name(h, "ln1_out")
+        qkv = h @ blk["qkv_w"].astype(dt) + blk["qkv_b"].astype(dt)
+        return checkpoint_name(qkv, "qkv")
 
 
 def _block_post_attn(x, attn, blk, config: GPTConfig):
@@ -223,12 +229,15 @@ def _block_post_attn(x, attn, blk, config: GPTConfig):
     from jax.ad_checkpoint import checkpoint_name
 
     dt = config.dtype
-    x = x + attn @ blk["out_w"].astype(dt) + blk["out_b"].astype(dt)
-    h = _layernorm(x, blk["ln2_scale"], blk["ln2_bias"]).astype(dt)
-    h = checkpoint_name(h, "ln2_out")
-    h = jax.nn.gelu(h @ blk["mlp_in_w"].astype(dt) + blk["mlp_in_b"].astype(dt))
-    h = checkpoint_name(h, "mlp_act")
-    return x + h @ blk["mlp_out_w"].astype(dt) + blk["mlp_out_b"].astype(dt)
+    with jax.named_scope("attn"):
+        x = x + attn @ blk["out_w"].astype(dt) + blk["out_b"].astype(dt)
+    with jax.named_scope("mlp"):
+        h = _layernorm(x, blk["ln2_scale"], blk["ln2_bias"]).astype(dt)
+        h = checkpoint_name(h, "ln2_out")
+        h = jax.nn.gelu(h @ blk["mlp_in_w"].astype(dt)
+                        + blk["mlp_in_b"].astype(dt))
+        h = checkpoint_name(h, "mlp_act")
+        return x + h @ blk["mlp_out_w"].astype(dt) + blk["mlp_out_b"].astype(dt)
 
 
 def _block(x, blk, config: GPTConfig):
@@ -240,18 +249,26 @@ def _block(x, blk, config: GPTConfig):
     H, hd = config.n_head, config.head_dim
 
     qkv = _block_pre_attn(x, blk, config)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    attn = _attention(q.reshape(B, S, H, hd), k.reshape(B, S, H, hd),
-                      v.reshape(B, S, H, hd), config).reshape(B, S, D)
-    attn = checkpoint_name(attn, "attn_out")
+    with jax.named_scope("attn"):
+        q, k, v = jnp.split(qkv, 3, axis=-1)
+        attn = _attention(q.reshape(B, S, H, hd), k.reshape(B, S, H, hd),
+                          v.reshape(B, S, H, hd), config).reshape(B, S, D)
+        attn = checkpoint_name(attn, "attn_out")
     return _block_post_attn(x, attn, blk, config)
+
+
+def _final_norm(x, params, dt):
+    with jax.named_scope("lm_head"):
+        return _layernorm(x, params["lnf_scale"],
+                          params["lnf_bias"]).astype(dt)
 
 
 def forward_hidden(params: Dict[str, Any], tokens, config: GPTConfig):
     """tokens (B, S) int32 -> final-layernormed hidden states (B, S, D)."""
     B, S = tokens.shape
     dt = config.dtype
-    x = params["wte"][tokens].astype(dt) + params["wpe"][:S].astype(dt)
+    with jax.named_scope("embed"):
+        x = params["wte"][tokens].astype(dt) + params["wpe"][:S].astype(dt)
 
     block_fn = partial(_block, config=config)
     if config.save_mlp_act and config.remat_policy != "attn_outside":
@@ -290,11 +307,12 @@ def forward_hidden(params: Dict[str, Any], tokens, config: GPTConfig):
         def split_body(carry, blk):
             x0 = carry
             qkv = pre(x0, blk)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            Bq, Sq = q.shape[0], q.shape[1]
-            attn = _attention(
-                q.reshape(Bq, Sq, H, hd), k.reshape(Bq, Sq, H, hd),
-                v.reshape(Bq, Sq, H, hd), config).reshape(Bq, Sq, -1)
+            with jax.named_scope("attn"):
+                q, k, v = jnp.split(qkv, 3, axis=-1)
+                Bq, Sq = q.shape[0], q.shape[1]
+                attn = _attention(
+                    q.reshape(Bq, Sq, H, hd), k.reshape(Bq, Sq, H, hd),
+                    v.reshape(Bq, Sq, H, hd), config).reshape(Bq, Sq, -1)
             return post(x0, attn, blk), None
 
         if config.pp_stages > 1:
@@ -309,8 +327,7 @@ def forward_hidden(params: Dict[str, Any], tokens, config: GPTConfig):
                 blk_i = jax.tree_util.tree_map(lambda a: a[i],
                                                params["blocks"])
                 x, _ = split_body(x, blk_i)
-        x = _layernorm(x, params["lnf_scale"], params["lnf_bias"]).astype(dt)
-        return x
+        return _final_norm(x, params, dt)
     if config.remat:
         policies = {
             "save_attn": lambda: jax.checkpoint_policies.save_only_these_names(
@@ -351,8 +368,7 @@ def forward_hidden(params: Dict[str, Any], tokens, config: GPTConfig):
         for i in range(config.n_layer):
             blk_i = jax.tree_util.tree_map(lambda a: a[i], params["blocks"])
             x, _ = scan_body(x, blk_i)
-        x = _layernorm(x, params["lnf_scale"], params["lnf_bias"]).astype(dt)
-        return x
+        return _final_norm(x, params, dt)
 
     if config.pp_stages > 1:
         # GPipe over the `pipe` mesh axis: each stage scans its local slice
@@ -381,21 +397,28 @@ def forward_hidden(params: Dict[str, Any], tokens, config: GPTConfig):
     else:
         x, _ = lax.scan(scan_body, x, params["blocks"],
                         unroll=config.scan_unroll)
-    x = _layernorm(x, params["lnf_scale"], params["lnf_bias"]).astype(dt)
-    return x
+    return _final_norm(x, params, dt)
 
 
 def forward(params: Dict[str, Any], tokens, config: GPTConfig):
     """tokens (B, S) int32 -> logits (B, S, V) fp32."""
     x = forward_hidden(params, tokens, config)
     # Tied LM head; logits accumulate in fp32 for a stable loss.
-    return jnp.einsum("bsd,vd->bsv", x, params["wte"].astype(config.dtype),
-                      preferred_element_type=jnp.float32)
+    with jax.named_scope("lm_head"):
+        return jnp.einsum("bsd,vd->bsv", x,
+                          params["wte"].astype(config.dtype),
+                          preferred_element_type=jnp.float32)
 
 
 def loss_fn(params, tokens, targets, config: GPTConfig):
     x = forward_hidden(params, tokens, config)
-    wte = params["wte"].astype(config.dtype)
+    with jax.named_scope("lm_head"):
+        return _lm_head_loss(x, params["wte"], targets, config)
+
+
+def _lm_head_loss(x, wte, targets, config: GPTConfig):
+    """Tied LM head + cross-entropy on final hidden states (B, S, D)."""
+    wte = wte.astype(config.dtype)
     B, S, D = x.shape
     C = config.loss_chunk
     impl = config.loss_impl
@@ -480,10 +503,11 @@ def make_train_step(config: GPTConfig, optimizer):
 
     def step(params, opt_state, tokens, targets):
         loss, grads = jax.value_and_grad(loss_fn)(params, tokens, targets, config)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
         import optax
 
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, loss
 
     return step

@@ -156,31 +156,34 @@ def _block(x, blk, config: LlamaConfig):
     B, S, D = x.shape
     H, KV, hd = config.n_head, config.n_kv_head, config.head_dim
 
-    h = _rmsnorm(x, blk["attn_norm"], config.rms_eps).astype(dt)
-    q = (h @ blk["wq"].astype(dt)).reshape(B, S, H, hd)
-    k = (h @ blk["wk"].astype(dt)).reshape(B, S, KV, hd)
-    v = (h @ blk["wv"].astype(dt)).reshape(B, S, KV, hd)
-    q = _rope(q, config.rope_theta)
-    k = _rope(k, config.rope_theta)
-    if KV != H:
-        # GQA: each kv head serves q_per_kv query heads.
-        k = jnp.repeat(k, config.q_per_kv, axis=2)
-        v = jnp.repeat(v, config.q_per_kv, axis=2)
-    # Reuse the GPT-2 attention dispatcher (xla/pallas/splash/ring/ulysses):
-    # it only reads attn_impl/blocks/head-shape from the config.
-    attn = _g._attention(q, k, v, config).astype(dt).reshape(B, S, H * hd)
-    x = x + attn @ blk["wo"].astype(dt)
+    with jax.named_scope("attn"):
+        h = _rmsnorm(x, blk["attn_norm"], config.rms_eps).astype(dt)
+        q = (h @ blk["wq"].astype(dt)).reshape(B, S, H, hd)
+        k = (h @ blk["wk"].astype(dt)).reshape(B, S, KV, hd)
+        v = (h @ blk["wv"].astype(dt)).reshape(B, S, KV, hd)
+        q = _rope(q, config.rope_theta)
+        k = _rope(k, config.rope_theta)
+        if KV != H:
+            # GQA: each kv head serves q_per_kv query heads.
+            k = jnp.repeat(k, config.q_per_kv, axis=2)
+            v = jnp.repeat(v, config.q_per_kv, axis=2)
+        # Reuse the GPT-2 attention dispatcher (xla/splash/ring/ulysses): it
+        # only reads attn_impl/blocks/head-shape from the config.
+        attn = _g._attention(q, k, v, config).astype(dt).reshape(B, S, H * hd)
+        x = x + attn @ blk["wo"].astype(dt)
 
-    h = _rmsnorm(x, blk["mlp_norm"], config.rms_eps).astype(dt)
-    gate = jax.nn.silu((h @ blk["w_gate"].astype(dt)).astype(jnp.float32))
-    up = (h @ blk["w_up"].astype(dt)).astype(jnp.float32)
-    x = x + ((gate * up).astype(dt) @ blk["w_down"].astype(dt))
+    with jax.named_scope("mlp"):
+        h = _rmsnorm(x, blk["mlp_norm"], config.rms_eps).astype(dt)
+        gate = jax.nn.silu((h @ blk["w_gate"].astype(dt)).astype(jnp.float32))
+        up = (h @ blk["w_up"].astype(dt)).astype(jnp.float32)
+        x = x + ((gate * up).astype(dt) @ blk["w_down"].astype(dt))
     return x
 
 
 def forward_hidden(params: Dict[str, Any], tokens, config: LlamaConfig):
     dt = config.dtype
-    x = params["wte"][tokens].astype(dt)
+    with jax.named_scope("embed"):
+        x = params["wte"][tokens].astype(dt)
 
     def layer(x, blk):
         out = _block(x, blk, config)
@@ -189,24 +192,28 @@ def forward_hidden(params: Dict[str, Any], tokens, config: LlamaConfig):
     if config.remat:
         layer = jax.checkpoint(layer)
     x, _ = lax.scan(layer, x, params["blocks"])
-    return _rmsnorm(x, params["final_norm"], config.rms_eps).astype(dt)
+    with jax.named_scope("lm_head"):
+        return _rmsnorm(x, params["final_norm"], config.rms_eps).astype(dt)
 
 
 def forward(params: Dict[str, Any], tokens, config: LlamaConfig):
     x = forward_hidden(params, tokens, config)
-    return jnp.einsum("bsd,vd->bsv", x, params["lm_head"].astype(config.dtype),
-                      preferred_element_type=jnp.float32)
+    with jax.named_scope("lm_head"):
+        return jnp.einsum("bsd,vd->bsv", x,
+                          params["lm_head"].astype(config.dtype),
+                          preferred_element_type=jnp.float32)
 
 
 def loss_fn(params, tokens, targets, config: LlamaConfig):
     x = forward_hidden(params, tokens, config)
-    logits = jnp.einsum("bsd,vd->bsv", x,
-                        params["lm_head"].astype(config.dtype),
-                        preferred_element_type=config.logits_dtype)
-    lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
-    tgt = jnp.take_along_axis(
-        logits, targets[..., None], axis=-1)[..., 0].astype(jnp.float32)
-    return jnp.mean(lse - tgt)
+    with jax.named_scope("lm_head"):
+        logits = jnp.einsum("bsd,vd->bsv", x,
+                            params["lm_head"].astype(config.dtype),
+                            preferred_element_type=config.logits_dtype)
+        lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
+        tgt = jnp.take_along_axis(
+            logits, targets[..., None], axis=-1)[..., 0].astype(jnp.float32)
+        return jnp.mean(lse - tgt)
 
 
 def make_optimizer(learning_rate=3e-4, weight_decay=0.1, b1=0.9, b2=0.95,
@@ -224,8 +231,9 @@ def make_train_step(config: LlamaConfig, optimizer):
     def step(params, opt_state, tokens, targets):
         loss, grads = jax.value_and_grad(loss_fn)(params, tokens, targets,
                                                   config)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, loss
 
     return step

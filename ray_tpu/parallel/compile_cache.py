@@ -28,6 +28,12 @@ def configure_compile_cache() -> str:
     """
     import jax
 
+    # jax leaves HLO metadata out of the cache's key by default, so an entry
+    # written by another tree (the same instructions under other names, or
+    # none) would hand that tree's names to profiles and to
+    # ``TrainStep.anatomy()``.  The names are part of what this program
+    # caches; the price is a recompile when a traced line moves.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     if jax.config.jax_compilation_cache_dir is None:
         jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
         # jax latches "is the cache in use" at the first compile of the

@@ -7,15 +7,30 @@ parameters are placed with their logical shardings, optimizer state is
 Adam moments (optimizer sharding = ZeRO), and the train step is jitted with
 donated state — gradient synchronization is derived by the partitioner, not
 written by hand.
+
+The jitted step is a :class:`TrainStep`: the same ``jax.jit`` dispatch, which
+also names its host side (``train.dispatch`` on the profiler's clock, the
+``dispatch`` counter of the step profiler's row), labels what it compiles in
+the device-telemetry compile registry (``train_step``), and can say which
+phase and model part every instruction of its compiled program belongs to
+(:meth:`TrainStep.anatomy`), from the ``jax.named_scope`` names the models
+put into the step (``tracing.SCOPE_REGISTRY``).
 """
 
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, Optional, Tuple
+import collections
+import contextlib
+import re
+import sys
+import time
+from dataclasses import dataclass, field
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 
 from ray_tpu.parallel.mesh import pytree_sharding
+from ray_tpu.util import device_telemetry, tracing
 
 
 def create_sharded_state(
@@ -30,32 +45,267 @@ def create_sharded_state(
     trip: init runs under jit with out_shardings so each device materializes
     only its shard) and derive optimizer state with propagated shardings."""
     shardings = pytree_sharding(logical, mesh, rules)
+    device_telemetry.listen_for_compiles()
     with jax.set_mesh(mesh):
-        params = jax.jit(init_fn, out_shardings=shardings)(key)
+        with tracing.span("train.init_params"), \
+                device_telemetry.compile_label("init_params"):
+            params = jax.jit(init_fn, out_shardings=shardings)(key)
         opt_state = None
         if optimizer is not None:
-            opt_state = jax.jit(optimizer.init)(params)
+            with tracing.span("train.init_opt_state"), \
+                    device_telemetry.compile_label("init_opt_state"):
+                opt_state = jax.jit(optimizer.init)(params)
     return params, opt_state
 
 
 def jit_train_step(step_fn, donate_state: bool = True, mesh=None):
     """jit with donated (params, opt_state) so updates reuse their buffers —
-    the HBM discipline that makes big models fit.
+    the HBM discipline that makes big models fit.  Returns a
+    :class:`TrainStep`.
 
     Pass ``mesh`` whenever the state lives on more than one device: the
     Pallas attention kernel (splash, what attn_impl="auto" means on TPU) and
     the context-parallel paths (attn_impl="ring"/"ulysses") shard_map over
-    the AMBIENT mesh, which this wrapper installs around trace/execute via
+    the AMBIENT mesh, which the step installs around trace/execute via
     jax.set_mesh.  Without it jax refuses to lower the kernel for several
     devices.
     """
-    donate = (0, 1) if donate_state else ()
-    jitted = jax.jit(step_fn, donate_argnums=donate)
-    if mesh is None:
-        return jitted
+    return TrainStep(step_fn, donate_state=donate_state, mesh=mesh)
 
-    def call(*args, **kwargs):
-        with jax.set_mesh(mesh):
-            return jitted(*args, **kwargs)
 
-    return call
+class TrainStep:
+    """A jitted train step that names its own work.
+
+    Calling it is ``jax.jit``'s dispatch (not the AOT path: the step's
+    outputs come back under other sharding specs than its inputs, which an
+    executable keyed on shardings would take for a new program).  Around
+    the dispatch: the ``train.dispatch`` annotation, the ``dispatch``
+    counter of the calling worker's step-profiler row, and a compile label,
+    so that an executable jax builds or loads in here is recorded under
+    ``train_step`` with a trigger classified from the call's signature.
+    The signature is taken only when a compile event fired, before the
+    arguments are donated; the first one is kept for :meth:`anatomy`.
+    A call that compiled is a ``train.first_call`` span and a first-call
+    record of the registry.
+    """
+
+    label = "train_step"
+
+    def __init__(self, step_fn, donate_state: bool = True, mesh=None):
+        self._donation = (0, 1) if donate_state else ()
+        self._jitted = jax.jit(step_fn, donate_argnums=self._donation)
+        #: the ambient mesh around trace, lower and execute
+        self._in_mesh = (contextlib.nullcontext if mesh is None
+                         else lambda: jax.set_mesh(mesh))
+        #: (args, kwargs) of the first call that compiled, as
+        #: ShapeDtypeStructs with shardings
+        self._abstract: Optional[Tuple[Any, Any]] = None
+        self._anatomy: Optional[Dict[str, Tuple[Optional[str],
+                                                Optional[str]]]] = None
+        device_telemetry.listen_for_compiles()
+        device_telemetry.register_program(self.label, self)
+
+    def __call__(self, *args, **kwargs):
+        t0 = time.perf_counter()
+        label = device_telemetry.compile_label(
+            self.label, lambda: self._sign(args, kwargs))
+        with tracing.annotate("train.dispatch"), label, self._in_mesh():
+            out = self._jitted(*args, **kwargs)
+        seconds = time.perf_counter() - t0
+        profiler = sys.modules.get("ray_tpu.train.profiler")
+        if profiler is not None:
+            profiler.count("dispatch", seconds)
+        if label.compiles:
+            end = time.time()
+            device_telemetry.record_first_call(self.label, seconds, ts=end)
+            tracing.record_span(
+                "train.first_call", end - seconds, end,
+                attributes={"label": self.label,
+                            "compile_s": label.compile_s})
+        return out
+
+    def _sign(self, args, kwargs):
+        """(shapes, shardings, donation) of a call, for the compile
+        registry; runs inside the compile event, so the arguments are
+        still whole."""
+        abstract = jax.tree.map(_abstract_leaf, (args, kwargs))
+        if self._abstract is None:
+            self._abstract = abstract
+        leaves, treedef = jax.tree.flatten(abstract)
+        shapes = tuple((leaf.shape, leaf.dtype) if hasattr(leaf, "shape")
+                       else type(leaf).__name__ for leaf in leaves)
+        shardings = tuple(getattr(leaf, "sharding", None) for leaf in leaves)
+        return (shapes, treedef), shardings, self._donation
+
+    def anatomy(self) -> Dict[str, Tuple[Optional[str], Optional[str]]]:
+        """``{instruction name: (phase, part)}`` of the compiled step, for
+        the signature of the first call that compiled.
+
+        On demand and cached: lowers and compiles that signature again —
+        jax hands back the first call's executable where it still holds it,
+        else it is a persistent-cache load — and reads the compiled
+        module's text with :func:`parse_anatomy`.  The instruction names
+        are the ones a device trace's ``XLA Ops`` events carry.  The scope
+        names are those of the tree that *compiled* the executable: a
+        persistent cache keyed without HLO metadata (jax's default) can
+        hand back another tree's, which is why
+        ``configure_compile_cache()`` puts the metadata into the key."""
+        if self._anatomy is None:
+            if self._abstract is None:
+                raise RuntimeError(
+                    "TrainStep.anatomy() needs a call that compiled first")
+            args, kwargs = self._abstract
+            with device_telemetry.compile_label(self.label + ".anatomy"), \
+                    self._in_mesh():
+                text = self._jitted.lower(*args, **kwargs).compile() \
+                    .as_text()
+            self._anatomy = parse_anatomy(text)
+        return self._anatomy
+
+
+def _abstract_leaf(x):
+    if not hasattr(x, "shape") or not hasattr(x, "dtype"):
+        return x  # a python scalar: jit traces it, lower() takes it as is
+    return jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                sharding=getattr(x, "sharding", None))
+
+
+# ------------------------------------------------------------------ anatomy
+# Read off the compiled v5e step of both model families (compile only,
+# ``benchmarks/tools/compile_only.py --hlo``; a sample is kept in
+# tests/data/v5e_step_op_names.json).  jax spells the transforms into the
+# path: ``jit(step)/jvp(lm_head)/...`` and, inside the layer scan,
+# ``jit(step)/jvp()/while/body/closed_call/attn/...`` are the forward;
+# ``jit(step)/transpose(jvp())/while/body/closed_call/checkpoint/mlp/...`` the
+# backward; ``.../checkpoint/rematted_computation/attn/...`` remat's second
+# forward inside it; ``jit(step)/optimizer/mul`` the update.  A
+# ``jax.named_scope`` lands inside the transform's brackets outside the scan
+# and as a path element of its own inside it.  (``.../attn_kernel/transpose``
+# ends in the *primitive* transpose: only ``transpose(jvp(`` is the
+# transform.)
+
+PHASES = ("forward", "backward", "recompute", "update")
+_PATH_TOKEN = re.compile(r"[^/()]+")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = .*? ([a-z\-]+)\(")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLS = re.compile(r"calls=%?([\w.\-]+)")
+_REFERENCE = re.compile(r"%([\w.\-]+)")
+
+
+@dataclass
+class _Instruction:
+    name: str
+    opcode: str
+    op_name: str = ""
+    calls: Optional[str] = None  # the computation a fusion fuses
+    refers_to: List[str] = field(default_factory=list)  # %names in its text
+
+
+def classify_op_name(op_name: str
+                     ) -> Tuple[Optional[str], Optional[str]]:
+    """(phase, part) of one ``op_name``: the phase from jax's transform
+    path, the part the innermost registered scope on the path; ``None``
+    where the string has neither."""
+    tokens = _PATH_TOKEN.findall(op_name)
+    part = next((t for t in reversed(tokens)
+                 if t in tracing.SCOPE_REGISTRY), None)
+    if "optimizer" in tokens:
+        phase = "update"
+    elif "rematted_computation" in tokens:
+        phase = "recompute"
+    elif "transpose(jvp(" in op_name:
+        phase = "backward"
+    elif "jvp(" in op_name:
+        phase = "forward"
+    else:
+        phase = None
+    return phase, part
+
+
+def parse_anatomy(hlo_text: str
+                  ) -> Dict[str, Tuple[Optional[str], Optional[str]]]:
+    """Every instruction of a compiled module's text ->
+    ``classify_op_name`` of its own ``op_name``.  A fusion that lacks a
+    phase or a part there (the compiler gives one the metadata of its root,
+    often a slice update or a layout change around the matmul that is the
+    work) takes the commonest among the instructions it fuses, nested
+    fusions included.  A fusion that spans two parts counts to one.  What
+    the compiler emits with no metadata at all (async copies and slices
+    into fast memory, layout copies, converts) is data movement for
+    whoever reads it: it takes the commonest (phase, part) of the
+    instructions that use its result, through a few hops
+    (``copy-start`` -> ``copy-done`` -> the kernel)."""
+    computations: Dict[str, List[_Instruction]] = {}
+    current = None
+    for line in hlo_text.splitlines():
+        header = _COMPUTATION.match(line)
+        if header:
+            current = computations.setdefault(header.group(1), [])
+            continue
+        found = _INSTRUCTION.match(line)
+        if found and current is not None:
+            current.append(_Instruction(found.group(1), found.group(2)))
+        elif line.rstrip() == "}":
+            current = None
+        if current:  # an instruction's text may run over several lines
+            last = current[-1]
+            op_name, calls = _OP_NAME.search(line), _CALLS.search(line)
+            if op_name and not last.op_name:
+                last.op_name = op_name.group(1)
+            if calls and last.calls is None:
+                last.calls = calls.group(1)
+            last.refers_to += _REFERENCE.findall(line)
+
+    def fused_votes(name, seen):
+        votes = collections.Counter()
+        if name in seen:
+            return votes
+        seen.add(name)
+        for i in computations.get(name, ()):
+            if i.opcode == "fusion" and i.calls:
+                votes.update(fused_votes(i.calls, seen))
+            elif i.op_name:
+                votes[classify_op_name(i.op_name)] += 1
+        return votes
+
+    out = {}
+    for rows in computations.values():
+        for i in rows:
+            phase, part = classify_op_name(i.op_name)
+            if i.opcode == "fusion" and i.calls and (phase is None
+                                                     or part is None):
+                votes = fused_votes(i.calls, set())
+                if phase is None:
+                    phase = _commonest(votes, 0)
+                if part is None:
+                    part = _commonest(votes, 1)
+            out[i.name] = (phase, part)
+    nameless = (None, None)
+    for rows in computations.values():
+        users: Dict[str, List[str]] = {}
+        for i in rows:
+            for operand in i.refers_to:
+                if operand != i.name:
+                    users.setdefault(operand, []).append(i.name)
+        for _ in range(4):  # hops
+            found = {}
+            for i in rows:
+                if out[i.name] == nameless and i.opcode != "parameter":
+                    votes = collections.Counter(
+                        out[u] for u in users.get(i.name, ())
+                        if out[u] != nameless)
+                    if votes:
+                        found[i.name] = votes.most_common(1)[0][0]
+            if not found:
+                break
+            out.update(found)
+    return out
+
+
+def _commonest(votes, index: int) -> Optional[str]:
+    tally = collections.Counter()
+    for key, n in votes.items():
+        if key[index] is not None:
+            tally[key[index]] += n
+    return tally.most_common(1)[0][0] if tally else None

@@ -70,10 +70,12 @@ def recent_ttft() -> List[Dict[str, Any]]:
 
 
 def _ltr_sum(split: Dict[str, float]) -> float:
-    total = 0.0
-    for name in (*TTFT_BUCKETS, "residual"):
-        total += split[name]
-    return total
+    """The split's sum as a reader of the record takes it: the builtin
+    ``sum`` over the buckets in order.  (Not a hand-rolled ``+=`` loop:
+    from Python 3.12 ``sum`` compensates its float additions, so the two
+    can differ by an ulp, and the contract is equality with the reader's
+    sum.)"""
+    return sum(split[name] for name in (*TTFT_BUCKETS, "residual"))
 
 
 def split_wall(wall: float, buckets: Dict[str, float]) -> Dict[str, float]:

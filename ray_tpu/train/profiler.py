@@ -16,6 +16,13 @@ step's wall clock — one ``report()`` to the next — is attributed into
   makes the buckets sum to the measured wall time *by construction* —
   un-instrumented host work lands in compute rather than vanishing.
 
+Beside the wait buckets each row carries flat **counters** (:data:`COUNTERS`)
+that are *not* waits and stay out of the sum: ``dispatch`` and ``report``
+(host seconds inside ``TrainStep.__call__`` and ``train.report``),
+``h2d_bytes`` (what ``device_put_batch`` sent), ``compiles`` and
+``compile_s`` (jax compile events on the worker's thread, via
+``device_telemetry``).  A steady step has ``compiles == 0``.
+
 The profiler is **per worker thread** (thread-local, like the session it
 belongs to), so ``record()`` needs no lock: every hook site — prefetcher
 consumption, device transfer, collective contribute, snapshot — runs on
@@ -44,6 +51,11 @@ from ray_tpu.util import tracing, watchdog
 
 #: Attribution buckets measured by hooks; ``compute`` is the residual.
 BUCKETS = ("data_wait", "h2d", "collective", "ckpt_block")
+
+#: Host-busy seconds and counts a row carries beside the buckets; they are
+#: no part of ``compute = wall - sum(buckets)``.  ``report`` is written by
+#: ``TrainSession.report`` onto the row its own step boundary closed.
+COUNTERS = ("dispatch", "report", "h2d_bytes", "compiles", "compile_s")
 
 #: Per-bucket cap on *span* intervals kept per step — totals always
 #: accumulate, but a step with thousands of tiny waits must not emit
@@ -87,6 +99,7 @@ class StepProfiler:
         self._totals: Dict[str, float] = {b: 0.0 for b in BUCKETS}  # owned_by_thread: worker thread (thread-local _local)
         self._intervals: Dict[str, List[Tuple[float, float]]] = {  # owned_by_thread: worker thread (thread-local _local)
             b: [] for b in BUCKETS}
+        self._counts: Dict[str, float] = {c: 0 for c in COUNTERS}  # owned_by_thread: worker thread (thread-local _local)
         self._recent_walls: "deque" = deque(maxlen=_PCTL_WINDOW)  # owned_by_thread: worker thread (thread-local _local)
 
     # ------------------------------------------------------------- config
@@ -123,6 +136,10 @@ class StepProfiler:
         if self._step_start is None:
             self._step_start = start
 
+    def count(self, counter: str, amount: float) -> None:
+        """Add to one of the current step's :data:`COUNTERS`."""
+        self._counts[counter] += amount
+
     # ----------------------------------------------------------- boundary
     def step_boundary(self, now: Optional[float] = None) -> Optional[dict]:
         """Close the current step: attribute its wall, emit spans, refresh
@@ -137,7 +154,7 @@ class StepProfiler:
         totals = {b: min(self._totals[b], wall) for b in BUCKETS}
         compute = max(0.0, wall - sum(totals.values()))
         row = {"step": self._step, "wall": wall, "compute": compute,
-               **totals}
+               **totals, **self._counts}
         self.history.append(row)
         # Progress heartbeat: step closure feeds the hang watchdog (stall
         # = beats stop) and the straggler check (cross-worker dispersion
@@ -154,6 +171,8 @@ class StepProfiler:
         for b in BUCKETS:
             self._totals[b] = 0.0
             self._intervals[b].clear()
+        for c in COUNTERS:
+            self._counts[c] = 0
 
     # -------------------------------------------------------------- spans
     def _emit_spans(self, t0: float, t1: float, compute: float,
@@ -235,6 +254,15 @@ def record(bucket: str, start: float, end: float) -> None:
     p = getattr(_local, "profiler", None)
     if p is not None:
         p.record(bucket, start, end)
+
+
+def count(counter: str, amount: float) -> None:
+    """Hook entry point for the row's :data:`COUNTERS`; same probing rule
+    and the same no-op off a profiled train worker's thread as
+    :func:`record`."""
+    p = getattr(_local, "profiler", None)
+    if p is not None:
+        p.count(counter, amount)
 
 
 def configure(**kwargs: Any) -> None:

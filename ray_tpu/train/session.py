@@ -10,10 +10,12 @@ from __future__ import annotations
 
 import queue
 import threading
+import time
 from typing import Any, Dict, Optional
 
 from ray_tpu.train import profiler as _profiler
 from ray_tpu.train.checkpoint import Checkpoint
+from ray_tpu.util import tracing
 
 _local = threading.local()
 
@@ -93,6 +95,18 @@ class TrainSession:
 
     def report(self, metrics: Dict[str, Any],
                checkpoint: Optional[Any] = None) -> None:
+        t0 = time.perf_counter()
+        with tracing.annotate("train.report"):
+            row = self._report(metrics, checkpoint)
+        if row is not None:
+            # The call closed its own step, so its host seconds go onto
+            # the row it just closed (boundary work included).
+            row["report"] = time.perf_counter() - t0
+        if self.stop_requested.is_set():
+            raise StopIteration("Training stopped by the controller")
+
+    def _report(self, metrics: Dict[str, Any],
+                checkpoint: Optional[Any]) -> Optional[dict]:
         # Chaos: the per-step worker-crash point (also consulted at run()
         # entry by TrainWorker) — an InjectedFailure here is a worker
         # dying mid-training, which the elastic controller must survive.
@@ -116,9 +130,8 @@ class TrainSession:
         # report() IS the step boundary: close the profiled step (spans +
         # live gauges) now that its checkpoint-block time is recorded.
         if self.profiler is not None:
-            self.profiler.step_boundary()
-        if self.stop_requested.is_set():
-            raise StopIteration("Training stopped by the controller")
+            return self.profiler.step_boundary()
+        return None
 
 
 def init_session(session: TrainSession) -> None:

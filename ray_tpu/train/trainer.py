@@ -1124,21 +1124,25 @@ class DataParallelTrainer:
                         last_metrics: Optional[Dict[str, Any]]):
         history = []
         drained = False
-        for session in sessions:
-            while True:
-                try:
-                    item = session.results.get_nowait()
-                except queue.Empty:
-                    break
-                drained = True
-                # Metrics history follows rank 0 (the reference's convention),
-                # but checkpoints from ANY rank are registered — a loop where a
-                # non-zero rank carries the checkpoint must not lose progress.
-                if item["checkpoint"] is not None:
-                    manager.register(item["checkpoint"], item["metrics"])
-                if item["rank"] == 0:
-                    last_metrics = item["metrics"]
-                    history.append(item["metrics"])
+        # The controller's thread shares the GIL with thread-tier workers'
+        # step loops: each pass is on the profiler's timeline.
+        with tracing.annotate("train.result_drain"):
+            for session in sessions:
+                while True:
+                    try:
+                        item = session.results.get_nowait()
+                    except queue.Empty:
+                        break
+                    drained = True
+                    # Metrics history follows rank 0 (the reference's
+                    # convention), but checkpoints from ANY rank are
+                    # registered — a loop where a non-zero rank carries the
+                    # checkpoint must not lose progress.
+                    if item["checkpoint"] is not None:
+                        manager.register(item["checkpoint"], item["metrics"])
+                    if item["rank"] == 0:
+                        last_metrics = item["metrics"]
+                        history.append(item["metrics"])
         # First report after an elastic recovery = training resumed: close
         # the kill->resumed clock.
         if drained and self._recovery_t0 is not None:

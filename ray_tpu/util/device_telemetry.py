@@ -4,11 +4,19 @@ Every observability layer so far (tracing PR 4, train profiler PR 10, TTFT
 attribution PR 12, flight recorder PR 15) measures host-side wall time;
 this module watches the XLA/device layer those planes cannot see:
 
-* **Compile tracking** — :func:`record_compile` (fed by
-  ``jax_compat.instrumented_jit``) keeps a per-process registry of every
-  trace/lower/compile with a function label, abstract shape+sharding
-  signature, wall time, and a classified trigger (first_compile /
-  shape_change / sharding_change / donation_change / recompile).  Rolled
+* **Compile tracking** — jax's own monitoring events
+  (:func:`listen_for_compiles`: ``backend_compile_duration``, persistent
+  cache hits and misses) feed :func:`record_compile`, a per-process
+  registry of every executable the process builds or loads, with the label
+  the compiling thread set (:class:`compile_label`; ``TrainStep`` and
+  ``create_sharded_state`` set one, everything else is ``unlabelled``),
+  the abstract shape+sharding signature, seconds, and a classified trigger
+  (first_compile / shape_change / sharding_change / donation_change /
+  recompile; ``unclassified`` without a signature).  The signature is
+  computed only when an event fired, never per step.  Programs that want
+  to be found after the run (``TrainStep``: label ``train_step``) register
+  themselves with :func:`register_program`; the registry is module state
+  and survives ``ray_tpu.shutdown()``.  Rolled
   up cluster-wide through the PR 10 :class:`TimeSeriesCollector` via
   :func:`publish` — N workers compiling the same signature show up as
   duplicated compile-seconds.  A **recompile-storm detector** (recompiles
@@ -37,6 +45,7 @@ profiler) so no data/serve/checkpoint layer gains an import dependency.
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -64,15 +73,24 @@ TRIGGER_SHARDING = "sharding_change"
 TRIGGER_DONATION = "donation_change"
 #: Same signature compiled again (cache eviction, duplicated wrapper).
 TRIGGER_RECOMPILE = "recompile"
+#: An event with no signature to compare (no :class:`compile_label` on the
+#: compiling thread, or one without a signature): never a storm's fuel.
+TRIGGER_UNCLASSIFIED = "unclassified"
+
+#: jax's monitoring events this module listens to.
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
+                 "/jax/compilation_cache/cache_misses": "miss"}
+UNLABELLED = "unlabelled"
 
 COMPILES_TOTAL = metrics.Counter(
     "ray_tpu_xla_compiles_total",
-    "XLA trace/lower/compile events recorded by the instrumented-jit tap, "
-    "by function label and classified trigger.",
+    "Executables built or loaded (jax compile events), by the compiling "
+    "thread's label and classified trigger.",
     ("label", "trigger"))
 COMPILE_SECONDS = metrics.Counter(
     "ray_tpu_xla_compile_seconds_total",
-    "Wall seconds spent tracing+compiling, by function label — summed "
+    "Wall seconds spent building or loading executables, by label — summed "
     "across workers via the collector, duplicated signatures show up as "
     "duplicated compile-seconds.",
     ("label",))
@@ -117,6 +135,14 @@ _compile_tail: "deque" = deque(maxlen=_COMPILE_TAIL)  # guarded_by: _lock
 #: Timestamps of recent non-first compiles, for the storm window.
 _recompile_ts: "deque" = deque(maxlen=4096)  # guarded_by: _lock
 _storms = 0  # guarded_by: _lock
+#: label -> the program object that registered itself (TrainStep).
+_programs: Dict[str, Any] = {}  # guarded_by: _lock
+#: Bounded tail of first-call records ({"label", "ts", "seconds"}).
+_first_calls: "deque" = deque(maxlen=_COMPILE_TAIL)  # guarded_by: _lock
+_listening = False  # guarded_by: _lock
+#: The compiling thread's :class:`compile_label` and the cache event of the
+#: compile in flight on it.
+_thread = threading.local()
 #: pool -> [live_bytes, peak_bytes]
 _pools: Dict[str, List[float]] = {}  # guarded_by: _lock
 #: Bounded tail of transfer records.
@@ -138,6 +164,8 @@ def _classify(prev: Optional[Dict[str, Any]], shapes: Any, shardings: Any,
               donation: Any) -> str:
     """Pure classification against one previous-signature row (callers
     read ``_last_sig`` under the lock themselves)."""
+    if shapes is None:
+        return TRIGGER_UNCLASSIFIED
     if prev is None:
         return TRIGGER_FIRST
     if shapes != prev["shapes"]:
@@ -151,25 +179,30 @@ def _classify(prev: Optional[Dict[str, Any]], shapes: Any, shardings: Any,
 
 def record_compile(label: str, *, shapes: Any, shardings: Any = None,
                    donation: Any = (), trace_s: float = 0.0,
-                   compile_s: float = 0.0,
+                   compile_s: float = 0.0, cache: Optional[str] = None,
                    ts: Optional[float] = None) -> str:
-    """Record one trace/lower/compile event; returns the classified
+    """Record one executable built or loaded; returns the classified
     trigger.  ``shapes``/``shardings``/``donation`` are opaque hashable
     signature components — classification only compares them against the
-    label's previous compile."""
+    label's previous compile; ``shapes=None`` is an event nobody could
+    sign (``unclassified``).  ``cache`` is the persistent cache's answer
+    for this compile ("hit" / "miss" / None when it was not asked)."""
     t = time.time() if ts is None else ts
     with _lock:
         trigger = _classify(_last_sig.get(label), shapes, shardings,
                             donation)
-        _last_sig[label] = {"shapes": shapes, "shardings": shardings,
-                            "donation": donation}
+        if shapes is not None:
+            _last_sig[label] = {"shapes": shapes, "shardings": shardings,
+                                "donation": donation}
         _compile_tail.append({
             "label": label, "trigger": trigger, "ts": t,
             "trace_s": round(float(trace_s), 6),
             "compile_s": round(float(compile_s), 6),
+            "cache": cache,
             "signature": repr(shapes)[:200],
         })
-        if trigger != TRIGGER_FIRST:
+        recompiled = trigger not in (TRIGGER_FIRST, TRIGGER_UNCLASSIFIED)
+        if recompiled:
             _recompile_ts.append(t)
     COMPILES_TOTAL.inc(tags={"label": label, "trigger": trigger})
     COMPILE_SECONDS.inc(trace_s + compile_s, tags={"label": label})
@@ -178,9 +211,112 @@ def record_compile(label: str, *, shapes: Any, shardings: Any = None,
                         attributes={"label": label, "trigger": trigger,
                                     "trace_s": trace_s,
                                     "compile_s": compile_s})
-    if trigger != TRIGGER_FIRST:
+    if recompiled:
         storm_tick(now=t)
     return trigger
+
+
+class compile_label:
+    """``with compile_label("train_step", signature):`` — compile events
+    jax fires on this thread inside the block are recorded under the
+    label.  ``signature`` is a zero-argument callable returning
+    ``(shapes, shardings, donation)``; it runs only when an event fired,
+    so a steady step pays two thread-local stores and nothing else.
+    ``compiles`` / ``compile_s`` say afterwards what the block built."""
+
+    __slots__ = ("label", "signature", "compiles", "compile_s", "_outer")
+
+    def __init__(self, label: str, signature=None):
+        self.label = label
+        self.signature = signature
+        self.compiles = 0
+        self.compile_s = 0.0
+
+    def __enter__(self):
+        self._outer = getattr(_thread, "label", None)
+        _thread.label = self
+        return self
+
+    def __exit__(self, et, ev, tb):
+        _thread.label = self._outer
+        return False
+
+
+def listen_for_compiles() -> None:
+    """Register this module with jax's monitoring events, once per
+    process.  Callers have imported jax already (``TrainStep``,
+    ``create_sharded_state``): the telemetry plane never imports it."""
+    global _listening
+    with _lock:
+        if _listening:
+            return
+        _listening = True
+    from jax import monitoring
+
+    monitoring.register_event_listener(_on_event)
+    monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+def _on_event(event: str, **_: Any) -> None:
+    # Fires inside the compile, before its duration event, same thread.
+    answer = _CACHE_EVENTS.get(event)
+    if answer is not None:
+        _thread.cache = answer
+
+
+def _on_duration(event: str, seconds: float, **_: Any) -> None:
+    if event != _COMPILE_EVENT:
+        return
+    cache, _thread.cache = getattr(_thread, "cache", None), None
+    ctx = getattr(_thread, "label", None)
+    shapes, shardings, donation = None, None, ()
+    if ctx is not None:
+        ctx.compiles += 1
+        ctx.compile_s += seconds
+        if ctx.signature is not None:
+            shapes, shardings, donation = ctx.signature()
+    record_compile(UNLABELLED if ctx is None else ctx.label, shapes=shapes,
+                   shardings=shardings, donation=donation,
+                   compile_s=seconds, cache=cache)
+    # The step profiler's row counts what its own thread compiled (probed:
+    # no profiler module, no train worker in the process).
+    profiler = sys.modules.get("ray_tpu.train.profiler")
+    if profiler is not None:
+        profiler.count("compiles", 1)
+        profiler.count("compile_s", seconds)
+
+
+def register_program(label: str, program: Any) -> None:
+    """Keep ``program`` findable under ``label`` after the run that built
+    it (the newest wins): readers reach ``TrainStep.anatomy()`` through
+    :func:`program` once ``fit()`` has returned."""
+    with _lock:
+        _programs[label] = program
+
+
+def program(label: str) -> Optional[Any]:
+    with _lock:
+        return _programs.get(label)
+
+
+def record_first_call(label: str, seconds: float,
+                      ts: Optional[float] = None) -> None:
+    """One call of a labelled program that built or loaded its executable
+    (trace, lower, compile or cache load, dispatch), ``ts`` its end."""
+    row = {"label": label, "ts": time.time() if ts is None else ts,
+           "seconds": round(float(seconds), 6)}
+    with _lock:
+        _first_calls.append(row)
+
+
+def first_calls(label: Optional[str] = None) -> List[dict]:
+    """Retained first-call records (optionally one label's), oldest
+    first."""
+    with _lock:
+        rows = list(_first_calls)
+    if label is not None:
+        rows = [r for r in rows if r["label"] == label]
+    return rows
 
 
 def compile_records(label: Optional[str] = None) -> List[dict]:
@@ -397,20 +533,6 @@ def transfer_bw(direction: Optional[str] = None, *, src: Optional[str] = None,
                            tags or None, window_s, now)
 
 
-# ---------------------------------------------------------------------- burns
-
-def record_burn(label: str, start: float, end: float,
-                attributes: Optional[Dict[str, Any]] = None) -> None:
-    """Timeline a device compute burn (one jitted step execution, a decode
-    burn) into the Perfetto device lane.  Pure span sugar — cheap no-op
-    when tracing is off."""
-    if not tracing.is_tracing_enabled():
-        return
-    attrs = dict(attributes or {})
-    attrs["label"] = label
-    tracing.record_span("device.burn", start, end, attributes=attrs)
-
-
 # ------------------------------------------------------------------- snapshot
 
 def snapshot(*, transfer_window_s: float = 60.0,
@@ -429,6 +551,7 @@ def snapshot(*, transfer_window_s: float = 60.0,
         "compiles": {
             "totals": totals,
             "tail": compile_records()[-50:],
+            "first_calls": first_calls()[-50:],
         },
         "pools": pool_bytes(),
         "transfers": {
@@ -463,12 +586,15 @@ def publish(collector: Any, source: str = "", *,
 
 
 def reset() -> None:
-    """Drop all retained state (tests / bench arms): compile registry,
-    storm window, pools (gauges cleared), transfer tail."""
+    """Drop all retained state (tests / bench arms): compile registry with
+    its programs and first calls, storm window, pools (gauges cleared),
+    transfer tail.  The jax listeners stay registered."""
     with _lock:
         _last_sig.clear()
         _compile_tail.clear()
         _recompile_ts.clear()
+        _programs.clear()
+        _first_calls.clear()
         _transfer_tail.clear()
         _pools.clear()
         global _storms

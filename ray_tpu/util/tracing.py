@@ -8,6 +8,15 @@ parented on the submitter's span — the trace context rides inside the
 TaskSpec exactly like the reference propagates it in its TaskSpec proto.
 Spans go to a pluggable exporter (default: in-memory buffer; any callable
 taking a span dict works, e.g. one that forwards to an OTLP client).
+
+Every ``span()`` is also a ``jax.profiler.TraceAnnotation`` once jax is in
+the process, whether or not ``enable_tracing()`` was called: jax makes the
+annotation a no-op while no profile is open, and with one open (the
+benchmark's, ``jax.profiler.trace``, an operator's xprof) the span lands on
+its thread's line of ``/host:CPU``, on the device trace's clock.
+``annotate()`` is the same annotation without the exporter's span.  Work
+*inside* the compiled step is named by ``jax.named_scope`` with the names of
+:data:`SCOPE_REGISTRY`.
 """
 
 from __future__ import annotations
@@ -15,6 +24,7 @@ from __future__ import annotations
 import contextvars
 import itertools
 import random
+import sys
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
@@ -81,8 +91,25 @@ SPAN_REGISTRY: Dict[str, str] = {
     "data.locality_claim": "ingest: one locality-aware shard claim "
                            "(attrs: preferred, local)",
     "data.prefetch": "ingest: host->device transfer dispatch, per batch",
+    "data.pump": "ingest: one batch pulled out of the pipeline by the "
+                 "ingest-prefetch pump thread",
     "train.step": "profiler: one training step, report() to report()",
-    "train.data_wait": "profiler: step blocked on the input pipeline",
+    "train.data_wait": "profiler: step blocked on the input pipeline (live "
+                       "around the starved wait in HostPrefetcher)",
+    "train.dispatch": "TrainStep: one call of the jitted train step, host "
+                      "side (enqueue; trace+compile on a first call)",
+    "train.first_call": "TrainStep: a call that built or loaded an "
+                        "executable (trace, lower, compile or cache load, "
+                        "dispatch), recorded after the fact",
+    "train.report": "session: one train.report() call, step boundary "
+                    "included",
+    "train.init_params": "create_sharded_state: parameters initialised "
+                         "into their sharded layout",
+    "train.init_opt_state": "create_sharded_state: optimizer state derived "
+                            "from the parameters",
+    "train.result_drain": "controller: one pass over the workers' report "
+                          "queues (its thread shares the GIL with the step "
+                          "loop)",
     "train.h2d": "profiler: host->device batch transfer within a step",
     "train.compute": "profiler: step compute residual (wall - waits)",
     "train.collective": "profiler: gradient-sync rendezvous within a step",
@@ -92,16 +119,36 @@ SPAN_REGISTRY: Dict[str, str] = {
                    "detection (status ERROR)",
     "forensics.dump": "flight recorder: one postmortem dump, trigger -> "
                       "file written",
-    "xla.compile": "device telemetry: one trace/lower/compile through the "
-                   "instrumented-jit tap (attrs: label, trigger)",
+    "watchdog.tick": "hang watchdog: one detection pass on its thread",
+    "xla.compile": "device telemetry: one executable built or loaded, from "
+                   "jax's compile events (attrs: label, trigger)",
     "xla.compile_storm": "device telemetry: recompile storm episode, first "
                          "windowed recompile -> detection (status ERROR)",
     "device.transfer": "device telemetry: one timed host<->device "
                        "transfer (attrs: direction, src, bytes)",
-    "device.burn": "device telemetry: one device compute burn (a jitted "
-                   "step / decode execution) in the Perfetto device lane",
     "cluster.autoscale": "cluster autoscaler: one control tick, signal "
                          "collection -> reconcile",
+}
+
+
+#: Names the program gives its own work *inside* the compiled train step
+#: (``jax.named_scope``): they reach the compiled module's ``op_name``
+#: metadata and nothing else — no instruction changes.  ``TrainStep.
+#: anatomy()`` (parallel/train_state.py) maps every instruction to the
+#: innermost of these on its path, and the benchmark's ``step.*_ms`` metrics
+#: sum device time by them.  Same static check as the spans: every
+#: ``named_scope("x")`` under ``ray_tpu/`` names an entry, and no entry is
+#: dead.
+SCOPE_REGISTRY: Dict[str, str] = {
+    "embed": "token (and position) embedding lookup",
+    "attn": "attention part of a block: norm, qkv, RoPE, kernel, "
+            "out-projection",
+    "attn_kernel": "the attention kernel call itself, nested inside attn "
+                   "(splash/ring/ulysses/XLA, with its layout changes)",
+    "mlp": "MLP part of a block: norm to down-projection",
+    "lm_head": "final norm, logits, loss",
+    "optimizer": "optimizer.update + apply_updates (gradient clipping is "
+                 "inside the optax chain, so inside the scope)",
 }
 
 
@@ -204,16 +251,62 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+#: ``jax.profiler.TraceAnnotation`` once jax is in the process (probed,
+#: never imported: tracing must not pull jax into a process without it).
+_annotation: Optional[Callable[[str], Any]] = None
+
+
+def _find_annotation() -> Optional[Callable[[str], Any]]:
+    global _annotation
+    _annotation = getattr(sys.modules.get("jax.profiler"),
+                          "TraceAnnotation", None)
+    return _annotation
+
+
+def annotate(name: str):
+    """``span()``'s sibling for the profiler's clock alone: a
+    ``jax.profiler.TraceAnnotation`` (jax's own no-op while no profile is
+    open; a shared no-op before jax is in the process) and nothing for the
+    exporter.  For per-step and per-tick sites whose host-clock story the
+    step profiler already tells after the fact (``train.step`` and its
+    children) or that would only flood the span buffer: ``train.dispatch``,
+    ``train.report``, ``train.data_wait``, the pump, drain and watchdog
+    threads."""
+    ann = _annotation or _find_annotation()
+    return _NULL_SPAN if ann is None else ann(name)
+
+
+class _ProfilerSpan:
+    """``span()`` with tracing off and jax loaded: the profiler annotation
+    under the disabled span's contract (``with ... as s`` gives None)."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, ann):
+        self._ann = ann
+
+    def __enter__(self):
+        self._ann.__enter__()
+        return None
+
+    def __exit__(self, et, ev, tb):
+        self._ann.__exit__(et, ev, tb)
+        return False
+
+
 class _SpanCtx:
     """Class-based span context manager: ~2x cheaper to enter/exit than a
     generator @contextmanager, which matters at several spans per request."""
 
-    __slots__ = ("_s", "_token")
+    __slots__ = ("_s", "_token", "_ann")
 
-    def __init__(self, s: dict):
+    def __init__(self, s: dict, ann=None):
         self._s = s
+        self._ann = ann
 
     def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
         self._token = _current_span.set(self._s)
         return self._s
 
@@ -223,6 +316,8 @@ class _SpanCtx:
             s["status"] = f"ERROR: {et.__name__}"
         s["end"] = _now()
         _current_span.reset(self._token)
+        if self._ann is not None:
+            self._ann.__exit__(et, ev, tb)
         _export(s)
         return False
 
@@ -235,9 +330,11 @@ def span(name: str, parent: Optional[dict] = None,
     """Open a span; nests under the active span unless `parent` is given.
 
     The span takes ownership of `attributes` — callers must not mutate the
-    dict afterwards (hot path: no defensive copy)."""
+    dict afterwards (hot path: no defensive copy).  With tracing off the
+    span is still :func:`annotate`'s profiler annotation."""
+    ann = _annotation or _find_annotation()
     if not _enabled:
-        return _NULL_SPAN
+        return _NULL_SPAN if ann is None else _ProfilerSpan(ann(name))
     if parent is None:
         # The active span dict itself carries trace_id/span_id — no need to
         # build the {"trace_id", "span_id"} projection on the hot path.
@@ -258,7 +355,7 @@ def span(name: str, parent: Optional[dict] = None,
         "attributes": attributes if attributes is not None else {},
         "status": "OK",
     }
-    return _SpanCtx(s)
+    return _SpanCtx(s, None if ann is None else ann(name))
 
 
 def record_span(name: str, start: float, end: float, *,

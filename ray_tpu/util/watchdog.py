@@ -247,7 +247,8 @@ class HangWatchdog:
         while True:
             time.sleep(interval)
             try:
-                self.tick()
+                with tracing.annotate("watchdog.tick"):
+                    self.tick()
             except Exception:
                 pass  # detection is best-effort; never kill the thread
 

@@ -102,14 +102,14 @@ def _telemetry_times(step_tel, params, opt_state, tokens, targets, n):
     """Device-telemetry A/B: both arms execute the SAME compiled
     executable (two independent XLA compilations of one function can
     differ by more than the gate, which would read as tap overhead);
-    odd iterations go through the instrumented-jit wrapper on top of it
-    (abstract-signature computation + compile-cache hit — zero compiles
+    odd iterations go through the ``TrainStep`` wrapper on top of it
+    (dispatch annotation, compile label, profiler counter — zero compiles
     in steady state) and ledger one transfer, even iterations call the
-    executable directly.  Same interleaving rationale as above."""
+    jitted function under it directly.  Same interleaving rationale as
+    above."""
     from ray_tpu.util import device_telemetry
 
-    # The warmup call left exactly one signature in the wrapper's cache.
-    (compiled,) = step_tel._cache.values()
+    compiled = step_tel._jitted
     bare, telem = [], []
     nbytes = int(tokens.size) * 4
     for i in range(2 * n):
@@ -140,8 +140,8 @@ def main(argv=None) -> int:
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu._private import jax_compat
     from ray_tpu.models import gpt2
+    from ray_tpu.parallel.train_state import jit_train_step
     from ray_tpu.train.profiler import StepProfiler
     from ray_tpu.util import device_telemetry, tracing
 
@@ -153,8 +153,7 @@ def main(argv=None) -> int:
     opt_state = opt.init(params)
     fn = gpt2.make_train_step(config, opt)
     step = jax.jit(fn, donate_argnums=(0, 1))
-    step_tel = jax_compat.instrumented_jit(fn, label="bench_step",
-                                           donate_argnums=(0, 1))
+    step_tel = jit_train_step(fn)
     rng = np.random.default_rng(0)
     toks = rng.integers(0, config.vocab_size, (B, S + 1), dtype=np.int64)
     t = jnp.asarray(toks, jnp.int32)

@@ -64,16 +64,14 @@ def _build_config(args):
 def run_step_mode(args) -> None:
     """Profiler-driven: the numbers here are the ones a Trainer run
     exports live as ray_tpu_train_* gauges — same code path.  The step
-    is dispatched through the instrumented-jit compile tap, so the run
-    also exercises the device-telemetry plane: exactly one first-compile
-    should land in ``device_telemetry.compile_records()`` and each
-    profiled step is marked as a ``device.burn`` interval (visible on
-    the Perfetto "device" lane when tracing is enabled)."""
+    is a ``jit_train_step`` ``TrainStep``, so the run also exercises the
+    device-telemetry plane: exactly one first-compile should land in
+    ``device_telemetry.compile_records("train_step")``."""
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu._private import jax_compat
     from ray_tpu.models import gpt2
+    from ray_tpu.parallel.train_state import jit_train_step
     from ray_tpu.train import profiler as train_profiler
     from ray_tpu.util import device_telemetry
 
@@ -87,9 +85,7 @@ def run_step_mode(args) -> None:
     opt = gpt2.make_optimizer(learning_rate=3e-4)
     params = gpt2.init_params(config, jax.random.key(0))
     opt_state = opt.init(params)
-    step = jax_compat.instrumented_jit(gpt2.make_train_step(config, opt),
-                                       label="train_step",
-                                       donate_argnums=(0, 1))
+    step = jit_train_step(gpt2.make_train_step(config, opt))
 
     rng = np.random.default_rng(0)
     toks = rng.integers(0, config.vocab_size, (B, S + 1), dtype=np.int64)
@@ -107,12 +103,8 @@ def run_step_mode(args) -> None:
         float(loss)
         prof.step_boundary()  # discard the warmup window
         for _ in range(args.steps):
-            w0 = time.time()
             params, opt_state, loss = step(params, opt_state, tokens, targets)
             float(loss)  # device sync = the step's true end
-            # Batch stays device-resident (no h2d to attribute); the
-            # whole interval is device burn.
-            device_telemetry.record_burn("train_step", w0, time.time())
             prof.step_boundary()
     finally:
         train_profiler.activate(None)

@@ -10,6 +10,7 @@ not O(N).
 
 import socket
 import struct
+import time
 
 import numpy as np
 import pytest
@@ -182,7 +183,13 @@ def test_end_to_end_redirected_pull_and_egress(tree_cfg):
         before = owner_srv.stats()["by_object"].get(str(oid), 0)
         pm_c.pull_blocking(oid, owner_srv.addr, timeout=30)
         np.testing.assert_array_equal(c_store.get(oid, timeout=5), payload)
-        # C's bytes came from B, not the owner.
+        # C's bytes came from B, not the owner.  (B's serving thread books
+        # the egress after its last send returns, which C's receive can
+        # beat: wait for the booking, not for the bytes.)
+        deadline = time.monotonic() + 5.0
+        while b_srv.stats()["by_object"].get(str(oid), 0) < payload.nbytes \
+                and time.monotonic() < deadline:
+            time.sleep(0.01)
         assert b_srv.stats()["by_object"].get(str(oid), 0) \
             >= payload.nbytes
         assert owner_srv.stats()["by_object"].get(str(oid), 0) == before

@@ -12,9 +12,11 @@ Bottom-up:
   kv_blocks hook site inside BlockAllocator),
 * the transfer ledger + windowed ``transfer_bw`` accessor and the
   ``device_put_batch`` h2d hook,
-* the instrumented-jit compile tap on REAL jitted functions — including
-  ``scripts/mfu_probe.py --mode step`` end-to-end on a GPT-2 step
-  (exactly one first-compile, zero recompiles),
+* the compile listener on REAL jitted functions (jax's monitoring events
+  under a ``compile_label``) — including ``scripts/mfu_probe.py --mode
+  step`` end-to-end on a GPT-2 ``TrainStep`` (exactly one first-compile,
+  zero recompiles); the ``TrainStep`` cases proper are in
+  ``tests/test_step_names.py``,
 * snapshot/bundle embedding, the ``device_telemetry_snapshot`` fault
   point absorption, collector rollup, the Perfetto "device" lane, and
   the serve accessor / reason-label satellites,
@@ -262,42 +264,82 @@ class TestTransfers:
 
 
 # --------------------------------------------------------------------------
-# Instrumented jit: the compile tap on real jitted functions
+# The compile listener: jax's monitoring events under a compile label
 # --------------------------------------------------------------------------
-class TestInstrumentedJit:
-    def test_real_jit_compiles_once_then_classifies_shape_change(self):
+class TestCompileListener:
+    def test_labelled_jit_compiles_once_then_classifies_shape_change(self):
+        import jax
         import jax.numpy as jnp
 
-        from ray_tpu._private import jax_compat
+        dt.listen_for_compiles()
+        fn = jax.jit(lambda x: x * 2 + 1)
 
-        step = jax_compat.instrumented_jit(lambda x: x * 2 + 1,
-                                           label="unit_fn")
+        def call(x):
+            sign = lambda: ((tuple(x.shape), x.dtype), None, ())  # noqa: E731
+            with dt.compile_label("unit_fn", sign) as label:
+                out = fn(x)
+            return out, label
+
         x3 = jnp.arange(3, dtype=jnp.float32)
-        out = step(x3)
+        out, label = call(x3)
         np.testing.assert_allclose(np.asarray(out), [1.0, 3.0, 5.0])
-        step(x3)  # warm: cache hit, no new compile
+        assert label.compiles == 1 and label.compile_s > 0
+        _, label = call(x3)  # warm: jit's cache, no compile event
+        assert label.compiles == 0
         rows = dt.compile_records("unit_fn")
         assert [r["trigger"] for r in rows] == [dt.TRIGGER_FIRST]
-        assert rows[0]["compile_s"] >= 0 and rows[0]["trace_s"] >= 0
+        assert rows[0]["compile_s"] > 0
 
         # A deliberate shape change recompiles and classifies as such.
-        step(jnp.arange(4, dtype=jnp.float32))
+        call(jnp.arange(4, dtype=jnp.float32))
         rows = dt.compile_records("unit_fn")
         assert [r["trigger"] for r in rows] == [dt.TRIGGER_FIRST,
                                                 dt.TRIGGER_SHAPE]
-        assert len(step._cache) == 2
 
-    def test_python_scalars_do_not_recompile(self):
+    def test_unlabelled_compiles_are_unclassified_and_feed_no_storm(
+            self, monkeypatch):
+        import jax
         import jax.numpy as jnp
 
-        from ray_tpu._private import jax_compat
+        monkeypatch.setenv("RAY_TPU_COMPILE_STORM_THRESHOLD", "2")
+        dt.listen_for_compiles()
+        for n in (5, 6, 7):
+            jax.jit(lambda x: x - 3)(jnp.ones(n))
+        rows = dt.compile_records(dt.UNLABELLED)
+        assert len(rows) >= 3
+        assert {r["trigger"] for r in rows} == {dt.TRIGGER_UNCLASSIFIED}
+        assert dt.compile_totals()["storms"] == 0
 
-        step = jax_compat.instrumented_jit(lambda x, s: x * s,
-                                           label="unit_scalar")
-        x = jnp.ones(4)
-        step(x, 2.0)
-        step(x, 3.0)  # traced value, same abstract signature
-        assert len(dt.compile_records("unit_scalar")) == 1
+    def test_listener_registers_once(self):
+        import jax
+        import jax.numpy as jnp
+
+        dt.listen_for_compiles()
+        dt.listen_for_compiles()
+        x = jnp.ones(9)  # its own compile, outside the label
+        with dt.compile_label("unit_once", lambda: (("s",), None, ())):
+            jax.jit(lambda x: x * 5 - 1)(x)
+        assert len(dt.compile_records("unit_once")) == 1
+
+    def test_labels_nest_and_restore(self):
+        with dt.compile_label("outer") as outer:
+            with dt.compile_label("inner"):
+                assert dt._thread.label.label == "inner"
+            assert dt._thread.label is outer
+        assert dt._thread.label is None
+
+    def test_program_registry_and_first_calls(self):
+        marker = object()
+        dt.register_program("unit_prog", marker)
+        assert dt.program("unit_prog") is marker
+        assert dt.program("nobody") is None
+        dt.record_first_call("unit_prog", 1.25, ts=10.0)
+        assert dt.first_calls("unit_prog") == [
+            {"label": "unit_prog", "ts": 10.0, "seconds": 1.25}]
+        assert dt.snapshot()["compiles"]["first_calls"][-1]["seconds"] \
+            == 1.25
+        dt.reset()
+        assert dt.program("unit_prog") is None and dt.first_calls() == []
 
     def test_mfu_probe_step_mode_end_to_end(self):
         """scripts/mfu_probe.py --mode step on a real GPT-2 train step:
@@ -374,20 +416,18 @@ class TestDeviceLane:
             dt.record_compile("f", shapes=("a",), trace_s=0.1, compile_s=0.2,
                               ts=t)
             dt.record_transfer("h2d", 64, src="unit", start=t - 0.5, end=t)
-            dt.record_burn("train_step", t - 0.2, t)
             spans = tracing.exported_spans()
         finally:
             tracing.disable_tracing()
             tracing.clear_spans()
         events = {e["name"]: e for e in spans_to_chrome_events(spans)}
-        for name in ("xla.compile", "device.transfer", "device.burn"):
+        for name in ("xla.compile", "device.transfer"):
             assert events[name]["pid"] == "device"
         assert events["device.transfer"]["args"]["bytes"] == 64
 
-    def test_burn_is_noop_when_tracing_disabled(self):
-        tracing.clear_spans()
-        dt.record_burn("train_step", 1.0, 2.0)
-        assert tracing.exported_spans() == []
+    def test_burn_lane_entry_is_gone(self):
+        assert "device.burn" not in tracing.SPAN_REGISTRY
+        assert not hasattr(dt, "record_burn")
 
 
 # --------------------------------------------------------------------------
