@@ -282,8 +282,8 @@ def forward_hidden(params: Dict[str, Any], tokens, config: GPTConfig):
         # custom-vjp residuals (lse), so the recompute regenerated them
         # (~10.8 ms/step).  Splitting the block into two checkpointed
         # halves with attention between them lets jax save q,k,v + lse
-        # (~1.2 GB at B=16) and skip the re-forward entirely.
-        #
+        # (~1.2 GB at B=16) and skip the re-forward entirely.  (The policy
+        # that does save output + lse, on a whole block: models/llama.py.)
         # Only sound with flash-style attention kernels whose custom-vjp
         # residuals are VMEM-scale: the plain XLA path would instead save
         # the full (B, H, S, S) probs per layer for the backward (~5 GB

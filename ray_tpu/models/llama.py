@@ -7,6 +7,16 @@ module mirrors ``models/gpt2.py``'s functional contract exactly —
 init_params / logical_axes / forward / loss_fn / make_train_step — so every
 mesh axis (data/fsdp/tensor/seq via logical-axis rules, ring/ulysses
 attention for long context) composes without model-specific glue.
+
+What the layer's ``jax.checkpoint`` keeps: the layer's input ``x`` (whole-block
+remat) and, where the splash kernel runs, the kernel's attention output and
+log-sum-exp (``ops.attention.save_splash_residuals``), one more (B, S, D)
+activation a layer.  The kernel is a ``custom_vjp`` whose backward reads those
+two arrays; under a bare checkpoint the backward ran the forward kernel a
+second time to get them back (11.5 of 305 ms a step at 8192 tokens, PERF.md PR
+24).  q, k and v are recomputed either way, since the projections' own
+backward needs ``h``.  With the XLA attention path the policy finds nothing to
+keep.
 """
 
 from __future__ import annotations
@@ -20,6 +30,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models import gpt2 as _g
+from ray_tpu.ops.attention import save_splash_residuals
 
 
 @dataclass(frozen=True)
@@ -190,7 +201,7 @@ def forward_hidden(params: Dict[str, Any], tokens, config: LlamaConfig):
         return out, None
 
     if config.remat:
-        layer = jax.checkpoint(layer)
+        layer = jax.checkpoint(layer, policy=save_splash_residuals)
     x, _ = lax.scan(layer, x, params["blocks"])
     with jax.named_scope("lm_head"):
         return _rmsnorm(x, params["final_norm"], config.rms_eps).astype(dt)

@@ -14,6 +14,27 @@ from typing import Optional
 
 import jax
 
+#: The name the kernel's forward gives (``jax.ad_checkpoint.checkpoint_name``)
+#: to the two arrays its backward kernels read besides q, k and v: the
+#: attention output and its log-sum-exp, (B, H, S, head_dim) in the compute
+#: dtype and (B, H, S) float32.
+SPLASH_RESIDUALS = "splash_residuals"
+
+#: Remat policy for a ``jax.checkpoint`` around a block that calls
+#: :func:`splash_attention`: keep those two arrays and nothing else.  Splash is
+#: a ``custom_vjp``; under a bare checkpoint nothing tells remat that its
+#: backward wants them, so the backward runs the forward kernel a second time
+#: only to get them back.  With the XLA path (or any block without the kernel)
+#: there is no such name in the jaxpr and the policy saves nothing.
+#:
+#: It keys on the name and not on the ``pallas_call`` equation: the call's own
+#: log-sum-exp output is padded to 128 lanes, (B, H, S, 128) float32, twice the
+#: bytes of the attention output, where the named value is the one lane the
+#: backward reads.  It also leaves every other kernel alone (a ring step that
+#: kept each partial output would multiply its memory by the ring's length).
+save_splash_residuals = jax.checkpoint_policies.save_only_these_names(
+    SPLASH_RESIDUALS)
+
 
 def _splash_kernel(seq_len: int, n_heads: int, block_q: int, block_kv: int,
                    fused_bwd: bool, causal: bool = True):
@@ -39,7 +60,8 @@ def _splash_kernel(seq_len: int, n_heads: int, block_q: int, block_kv: int,
         use_fused_bwd_kernel=fused_bwd,
     )
     return sk.make_splash_mha(mask, head_shards=1, q_seq_shards=1,
-                              block_sizes=bs, interpret=interpret)
+                              block_sizes=bs, interpret=interpret,
+                              residual_checkpoint_name=SPLASH_RESIDUALS)
 
 
 def splash_attention(q, k, v, causal: bool = True,
@@ -84,3 +106,4 @@ def splash_attention(q, k, v, causal: bool = True,
     # output avals, which the vma checker rejects.
     return jax.shard_map(local, in_specs=(spec, spec, spec), out_specs=spec,
                          check_vma=False)(q, k, v)
+

@@ -199,6 +199,37 @@ def test_splash_lowers_for_the_tpu_only_inside_the_mesh_shard_map(
         assert "tpu_custom_call" in lower().as_text()
 
 
+def test_splash_remat_policy_keeps_the_named_residuals_alone():
+    """``save_splash_residuals`` marks the two arrays the kernel names and
+    nothing else: not a kernel call (the splash forward's own log-sum-exp
+    output is 128 lanes wide; ring attention and the fused loss run kernels
+    too), not another name, not a matmul."""
+    from jax.ad_checkpoint import checkpoint_name
+    from jax.experimental import pallas as pl
+
+    from ray_tpu.ops.attention import SPLASH_RESIDUALS, save_splash_residuals
+
+    def copy(x_ref, o_ref):
+        o_ref[...] = x_ref[...]
+
+    def body(x):
+        for kernel in ("splash_mha_fwd_residuals", "ring_attention_step"):
+            x = pl.pallas_call(copy, out_shape=x, name=kernel,
+                               interpret=True)(x)
+        x = checkpoint_name(x @ x, "attn_out")
+        return checkpoint_name(x, SPLASH_RESIDUALS)
+
+    eqns = jax.make_jaxpr(body)(jnp.ones((8, 8))).eqns
+    saveable = [
+        (eqn.primitive.name, eqn.params.get("name"))
+        for eqn in eqns
+        if save_splash_residuals(eqn.primitive,
+                                 *(v.aval for v in eqn.invars), **eqn.params)]
+    assert [eqn.primitive.name for eqn in eqns] == [
+        "pallas_call", "pallas_call", "dot_general", "name", "name"]
+    assert saveable == [("name", SPLASH_RESIDUALS)]
+
+
 # ------------------------------------------------------------ process pool
 def _worker_platform():
     return os.environ.get("JAX_PLATFORMS")
