@@ -1479,6 +1479,11 @@ class Runtime:
             error = TaskError(error, task_repr=spec.name)
         for i in range(max(spec.num_returns, 1)):
             object_id = ObjectID.for_task_return(spec.task_id, i)
+            # A streaming task's return index 0 is its first yielded item:
+            # one that has landed stays what it is (a consumer may not have
+            # read it yet); the error reaches the stream through the handle.
+            if spec.generator and self.store.contains(object_id):
+                continue
             self.store.put_error(object_id, error)
             self._on_object_ready(object_id)
         gen_handle = self._generators.pop(spec.task_id, None)

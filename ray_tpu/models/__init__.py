@@ -1,3 +1,9 @@
+"""Model files on the shared parallel substrate.  ``gpt2``, ``llama`` and
+``vit`` are models (init_params / logical_axes / loss_fn / make_train_step);
+``moe`` is not a model but the dropless mixture-of-experts layer that
+``llama`` puts in place of its SwiGLU MLP when the configuration has experts
+(OLMoE-1B-7B)."""
+
 from ray_tpu.models import gpt2, llama, moe, vit
 
 __all__ = ["gpt2", "llama", "moe", "vit"]

@@ -8,6 +8,15 @@ init_params / logical_axes / forward / loss_fn / make_train_step — so every
 mesh axis (data/fsdp/tensor/seq via logical-axis rules, ring/ulysses
 attention for long context) composes without model-specific glue.
 
+A configuration may also carry the published facts of a mixture-of-experts
+model of the same family (OLMoE-1B-7B: ``n_experts``, ``experts_per_token``,
+``norm_topk_prob``, ``qk_norm``, the two router-loss coefficients).  The
+block's attention half then normalises the whole q and k projections
+(QK-norm), its second half is ``models/moe.py``'s dropless expert layer in
+place of the SwiGLU MLP, the layer scan carries each layer's two router
+losses out, and ``loss_fn`` adds them to the cross-entropy.  Without experts
+none of this is traced: Mistral's program is what it was.
+
 What the layer's ``jax.checkpoint`` keeps: the layer's input ``x`` (whole-block
 remat) and, where the splash kernel runs, the kernel's attention output and
 log-sum-exp (``ops.attention.save_splash_residuals``), one more (B, S, D)
@@ -30,6 +39,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models import gpt2 as _g
+from ray_tpu.models import moe as _moe
 from ray_tpu.ops.attention import save_splash_residuals
 
 
@@ -56,6 +66,21 @@ class LlamaConfig:
     # stack rides the same `layers` axis; GPipe wiring arrives with demand)
     pp_stages: int = 1
     pp_microbatches: int = 0
+    # Published facts of a mixture-of-experts model (OLMoE-1B-7B); Mistral
+    # has none of them.  With experts the block's second half is
+    # models/moe.py's dropless layer over n_experts SwiGLU experts of width
+    # d_ff, experts_per_token of them a token.
+    n_experts: int = 0
+    experts_per_token: int = 0
+    #: renormalise the chosen experts' probabilities to sum to one
+    norm_topk_prob: bool = False
+    #: RMSNorm over the whole q and the whole k projection, before the heads
+    #: are split and RoPE is applied
+    qk_norm: bool = False
+    #: loss = CE + router_aux_loss_coef x load-balance + router_z_loss_coef x
+    #: z, each summed over the layers (moe.router_losses)
+    router_aux_loss_coef: float = 0.0
+    router_z_loss_coef: float = 0.0
 
     @property
     def head_dim(self) -> int:
@@ -70,9 +95,20 @@ class LlamaConfig:
         return LlamaConfig(vocab_size=1024, n_layer=2, n_head=4, n_kv_head=2,
                            d_model=128, d_ff=384, seq_len=128)
 
+    @staticmethod
+    def tiny_moe() -> "LlamaConfig":
+        """OLMoE's shape in small: MHA, QK-norm, 8 experts of 64, 2 a token."""
+        return LlamaConfig(vocab_size=1024, n_layer=2, n_head=4, n_kv_head=4,
+                           d_model=128, d_ff=64, seq_len=128, n_experts=8,
+                           experts_per_token=2, qk_norm=True,
+                           router_aux_loss_coef=0.01,
+                           router_z_loss_coef=0.001)
+
     def __post_init__(self):
         assert self.d_model % self.n_head == 0
         assert self.n_head % self.n_kv_head == 0
+        assert 0 <= self.experts_per_token <= self.n_experts
+        assert (self.n_experts > 0) == (self.experts_per_token > 0)
 
 
 def init_params(config: LlamaConfig, key) -> Dict[str, Any]:
@@ -86,20 +122,28 @@ def init_params(config: LlamaConfig, key) -> Dict[str, Any]:
     def norm(key, shape, s):
         return jax.random.normal(key, shape, jnp.float32) * s
 
-    ks = jax.random.split(k_blocks, 7)
+    ks = jax.random.split(k_blocks, 8)
+    # With experts the three MLP matrices gain a leading expert axis.
+    E = (config.n_experts,) if config.n_experts else ()
+    blocks = {
+        "attn_norm": jnp.ones((L, D)),
+        "wq": norm(ks[0], (L, D, H * hd), std),
+        "wk": norm(ks[1], (L, D, KV * hd), std),
+        "wv": norm(ks[2], (L, D, KV * hd), std),
+        "wo": norm(ks[3], (L, H * hd, D), resid_std),
+        "mlp_norm": jnp.ones((L, D)),
+        "w_gate": norm(ks[4], (L, *E, D, F), std),
+        "w_up": norm(ks[5], (L, *E, D, F), std),
+        "w_down": norm(ks[6], (L, *E, F, D), resid_std),
+    }
+    if config.n_experts:
+        blocks["router"] = norm(ks[7], (L, D, config.n_experts), std)
+    if config.qk_norm:
+        blocks["q_norm"] = jnp.ones((L, H * hd))
+        blocks["k_norm"] = jnp.ones((L, KV * hd))
     return {
         "wte": norm(k_wte, (V, D), std),
-        "blocks": {
-            "attn_norm": jnp.ones((L, D)),
-            "wq": norm(ks[0], (L, D, H * hd), std),
-            "wk": norm(ks[1], (L, D, KV * hd), std),
-            "wv": norm(ks[2], (L, D, KV * hd), std),
-            "wo": norm(ks[3], (L, H * hd, D), resid_std),
-            "mlp_norm": jnp.ones((L, D)),
-            "w_gate": norm(ks[4], (L, D, F), std),
-            "w_up": norm(ks[5], (L, D, F), std),
-            "w_down": norm(ks[6], (L, F, D), resid_std),
-        },
+        "blocks": blocks,
         "final_norm": jnp.ones((D,)),
         # Untied LM head (Llama convention; GPT-2 ties to wte).
         "lm_head": norm(k_head, (V, D), std),
@@ -108,19 +152,26 @@ def init_params(config: LlamaConfig, key) -> Dict[str, Any]:
 
 def logical_axes(config: LlamaConfig) -> Dict[str, Any]:
     L = "layers"
+    E = ("expert",) if config.n_experts else ()
+    blocks = {
+        "attn_norm": (L, "norm"),
+        "wq": (L, "embed", "heads"),
+        "wk": (L, "embed", "heads"),
+        "wv": (L, "embed", "heads"),
+        "wo": (L, "heads", "embed"),
+        "mlp_norm": (L, "norm"),
+        "w_gate": (L, *E, "embed", "mlp"),
+        "w_up": (L, *E, "embed", "mlp"),
+        "w_down": (L, *E, "mlp", "embed"),
+    }
+    if config.n_experts:
+        blocks["router"] = (L, "embed", None)
+    if config.qk_norm:
+        blocks["q_norm"] = (L, "norm")
+        blocks["k_norm"] = (L, "norm")
     return {
         "wte": ("vocab", "embed"),
-        "blocks": {
-            "attn_norm": (L, "norm"),
-            "wq": (L, "embed", "heads"),
-            "wk": (L, "embed", "heads"),
-            "wv": (L, "embed", "heads"),
-            "wo": (L, "heads", "embed"),
-            "mlp_norm": (L, "norm"),
-            "w_gate": (L, "embed", "mlp"),
-            "w_up": (L, "embed", "mlp"),
-            "w_down": (L, "mlp", "embed"),
-        },
+        "blocks": blocks,
         "final_norm": ("norm",),
         "lm_head": ("vocab", "embed"),
     }
@@ -133,12 +184,20 @@ def num_params(config: LlamaConfig) -> int:
     attn = D * config.n_head * hd + 2 * D * config.n_kv_head * hd \
         + config.n_head * hd * D
     mlp = 3 * D * F
+    if config.n_experts:
+        mlp = config.n_experts * mlp + D * config.n_experts
+    if config.qk_norm:
+        attn += (config.n_head + config.n_kv_head) * hd
     per_block = 2 * D + attn + mlp
     return 2 * V * D + L * per_block + D
 
 
 def flops_per_token(config: LlamaConfig) -> float:
-    return 6.0 * num_params(config) \
+    """6 x the parameters a token meets (of the experts, its own) plus
+    causal attention."""
+    idle = (config.n_experts - config.experts_per_token) * 3 \
+        * config.d_model * config.d_ff * config.n_layer
+    return 6.0 * (num_params(config) - idle) \
         + 12.0 * config.n_layer * config.d_model * config.seq_len
 
 
@@ -163,17 +222,22 @@ def _rope(x, theta: float):
 
 
 def _block(x, blk, config: LlamaConfig):
+    """One layer.  -> (x, router losses): the second is ``None`` for a dense
+    MLP and moe.router_losses' pair with experts."""
     dt = config.dtype
     B, S, D = x.shape
     H, KV, hd = config.n_head, config.n_kv_head, config.head_dim
 
     with jax.named_scope("attn"):
         h = _rmsnorm(x, blk["attn_norm"], config.rms_eps).astype(dt)
-        q = (h @ blk["wq"].astype(dt)).reshape(B, S, H, hd)
-        k = (h @ blk["wk"].astype(dt)).reshape(B, S, KV, hd)
+        q = h @ blk["wq"].astype(dt)
+        k = h @ blk["wk"].astype(dt)
+        if config.qk_norm:
+            q = _rmsnorm(q, blk["q_norm"], config.rms_eps).astype(dt)
+            k = _rmsnorm(k, blk["k_norm"], config.rms_eps).astype(dt)
         v = (h @ blk["wv"].astype(dt)).reshape(B, S, KV, hd)
-        q = _rope(q, config.rope_theta)
-        k = _rope(k, config.rope_theta)
+        q = _rope(q.reshape(B, S, H, hd), config.rope_theta)
+        k = _rope(k.reshape(B, S, KV, hd), config.rope_theta)
         if KV != H:
             # GQA: each kv head serves q_per_kv query heads.
             k = jnp.repeat(k, config.q_per_kv, axis=2)
@@ -184,31 +248,47 @@ def _block(x, blk, config: LlamaConfig):
         x = x + attn @ blk["wo"].astype(dt)
 
     with jax.named_scope("mlp"):
-        h = _rmsnorm(x, blk["mlp_norm"], config.rms_eps).astype(dt)
+        h = _rmsnorm(x, blk["mlp_norm"], config.rms_eps)
+        if config.n_experts:
+            # the router reads the norm's float32 output, the experts its
+            # cast to the compute dtype
+            y, router_losses = _moe.moe_mlp(
+                h, blk, experts_per_token=config.experts_per_token,
+                norm_topk_prob=config.norm_topk_prob, dtype=dt)
+            return x + y, router_losses
+        h = h.astype(dt)
         gate = jax.nn.silu((h @ blk["w_gate"].astype(dt)).astype(jnp.float32))
         up = (h @ blk["w_up"].astype(dt)).astype(jnp.float32)
         x = x + ((gate * up).astype(dt) @ blk["w_down"].astype(dt))
-    return x
+    return x, None
 
 
 def forward_hidden(params: Dict[str, Any], tokens, config: LlamaConfig):
+    """-> (final hidden states (B, S, D), router loss): the second is the
+    coefficient-weighted sum of the layers' router losses, ``None`` (no scan
+    output behind it) for a model without experts."""
     dt = config.dtype
     with jax.named_scope("embed"):
         x = params["wte"][tokens].astype(dt)
 
     def layer(x, blk):
-        out = _block(x, blk, config)
-        return out, None
+        return _block(x, blk, config)
 
     if config.remat:
         layer = jax.checkpoint(layer, policy=save_splash_residuals)
-    x, _ = lax.scan(layer, x, params["blocks"])
+    x, router_losses = lax.scan(layer, x, params["blocks"])
+    router_loss = None
+    if router_losses is not None:
+        balance, z = router_losses  # (L,) each
+        router_loss = config.router_aux_loss_coef * jnp.sum(balance) \
+            + config.router_z_loss_coef * jnp.sum(z)
     with jax.named_scope("lm_head"):
-        return _rmsnorm(x, params["final_norm"], config.rms_eps).astype(dt)
+        x = _rmsnorm(x, params["final_norm"], config.rms_eps).astype(dt)
+    return x, router_loss
 
 
 def forward(params: Dict[str, Any], tokens, config: LlamaConfig):
-    x = forward_hidden(params, tokens, config)
+    x, _ = forward_hidden(params, tokens, config)
     with jax.named_scope("lm_head"):
         return jnp.einsum("bsd,vd->bsv", x,
                           params["lm_head"].astype(config.dtype),
@@ -216,7 +296,7 @@ def forward(params: Dict[str, Any], tokens, config: LlamaConfig):
 
 
 def loss_fn(params, tokens, targets, config: LlamaConfig):
-    x = forward_hidden(params, tokens, config)
+    x, router_loss = forward_hidden(params, tokens, config)
     with jax.named_scope("lm_head"):
         logits = jnp.einsum("bsd,vd->bsv", x,
                             params["lm_head"].astype(config.dtype),
@@ -224,7 +304,8 @@ def loss_fn(params, tokens, targets, config: LlamaConfig):
         lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
         tgt = jnp.take_along_axis(
             logits, targets[..., None], axis=-1)[..., 0].astype(jnp.float32)
-        return jnp.mean(lse - tgt)
+        ce = jnp.mean(lse - tgt)
+    return ce if router_loss is None else ce + router_loss
 
 
 def make_optimizer(learning_rate=3e-4, weight_decay=0.1, b1=0.9, b2=0.95,

@@ -1,255 +1,181 @@
-"""Mixture-of-Experts transformer with native expert parallelism.
+"""The dropless mixture-of-experts layer: what replaces the SwiGLU MLP in a
+``models/llama.py`` block when the configuration has experts (OLMoE-1B-7B: 64
+SwiGLU experts of width 1024, 8 a token).  This file is the layer and nothing
+else; embedding, attention, the layer scan, the loss and the train step are
+``llama.py``'s.
 
-The reference has NO native MoE/expert parallelism (SURVEY §2.3 — EP only via
-integrated frameworks on Ray-provided process groups).  Here it is native and
-TPU-shaped, the GShard recipe: top-k token-choice routing with a fixed expert
-capacity, dispatch/combine expressed as einsums against a one-hot dispatch
-tensor — everything is dense, static-shaped, and MXU-friendly, and when the
-leading expert axis of the expert weights is sharded over the `expert` mesh
-axis XLA lowers the dispatch einsums to all_to_all over ICI.  No
-data-dependent shapes anywhere: over-capacity tokens are dropped (their
-combine weight is zero), exactly as in GShard/Switch.
+Per token: router logits and their softmax in float32, the ``k`` largest
+probabilities pick the experts and are the combine weights (renormalised only
+where the model's ``norm_topk_prob`` says so).  Then, with N tokens:
 
-Reuses the GPT-2 attention block (models/gpt2.py); only the MLP is replaced
-by the MoE layer.  An auxiliary load-balance loss (Switch §2.2 form:
-E * sum_e f_e * p_e) keeps routing uniform.
+1. the N x k (token, slot) pairs are sorted by expert (stable) and the E group
+   sizes counted;
+2. the pairs' rows are gathered into that order, (N x k, D);
+3. three grouped matmuls over the ragged groups (``ops/grouped_matmul.py``):
+   gate and up, ``silu(gate) * up``, down;
+4. the rows go back to token order and each token sums its k rows times their
+   weights.
+
+Static shapes throughout (exactly N x k rows), no capacity and no pair
+dropped.  Both gathers are permutations with hand-written transposes (the
+inverse permutation), so neither direction holds a scatter-add.
+
+Two router losses come back with the output, for ``llama.loss_fn`` to weigh
+(:func:`router_losses`).
+
+Under a mesh tokens never leave their chip: the whole layer runs inside a
+``shard_map`` over the mesh's batch axes, as ``ops.attention.splash_attention``
+does, because a global sort over a sharded batch would gather the batch and a
+Mosaic call cannot be partitioned.  The only numbers that cross chips are the
+router losses' 2E + 1 means (one ``pmean``).  The router's and the experts'
+weights enter that ``shard_map`` replicated: however they are stored (``embed`` over `fsdp`, the expert axis
+over `expert`), XLA gathers them on the way in and reduces their gradients on
+the way out, which is FSDP's meaning.  True expert parallelism (experts
+resident on their own chips, tokens exchanged by a ragged all-to-all) is not
+built.
 """
 
 from __future__ import annotations
 
-import math
-from dataclasses import dataclass
-from functools import partial
-from typing import Any, Dict, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models import gpt2
-from ray_tpu.models.gpt2 import _attention, _layernorm
+from ray_tpu.ops.grouped_matmul import grouped_matmul
 
 
-@dataclass(frozen=True)
-class MoEConfig:
-    vocab_size: int = 32768
-    n_layer: int = 8
-    n_head: int = 8
-    d_model: int = 512
-    seq_len: int = 1024
-    n_experts: int = 8
-    expert_mlp: int = 1024  # per-expert hidden width
-    top_k: int = 2
-    capacity_factor: float = 1.25
-    aux_loss_weight: float = 0.01
-    dtype: Any = jnp.bfloat16
-    remat: bool = True
-    attn_impl: str = "auto"
+def router_losses(logits, probs, experts, batch_axes=()
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """One layer's (load-balance, z) losses.  ``logits``, ``probs``:
+    (..., E) float32; ``experts``: (..., k) the chosen ids.  Inside a
+    ``shard_map`` whose ``batch_axes`` divide the tokens evenly, the means
+    are taken over all of them.
 
-    @property
-    def head_dim(self) -> int:
-        return self.d_model // self.n_head
-
-    def capacity(self, n_tokens: int) -> int:
-        """Per-expert token capacity C, padded to a multiple of 8 for tiling."""
-        c = int(math.ceil(self.capacity_factor * self.top_k * n_tokens
-                          / self.n_experts))
-        return max(8, -(-c // 8) * 8)
-
-    @staticmethod
-    def tiny() -> "MoEConfig":
-        return MoEConfig(vocab_size=1024, n_layer=2, n_head=4, d_model=128,
-                         seq_len=64, n_experts=4, expert_mlp=256)
-
-    def _attn_view(self) -> gpt2.GPTConfig:
-        """GPTConfig view so the attention kernel selection is shared."""
-        return gpt2.GPTConfig(
-            vocab_size=self.vocab_size, n_layer=self.n_layer,
-            n_head=self.n_head, d_model=self.d_model, seq_len=self.seq_len,
-            dtype=self.dtype, attn_impl=self.attn_impl)
+    load-balance = E x sum_e f_e P_e, with f_e the share of the (token,
+    slot) pairs that went to expert e and P_e the mean router probability of
+    e over the tokens: 1.0 under a uniform router, E when one expert takes
+    everything.  z = mean over tokens of logsumexp(logits)^2."""
+    E = logits.shape[-1]
+    chosen = experts[..., None] == jnp.arange(E, dtype=experts.dtype)
+    f = jnp.mean(chosen.astype(jnp.float32),
+                 axis=tuple(range(chosen.ndim - 1)))
+    p = jnp.mean(probs, axis=tuple(range(probs.ndim - 1)))
+    z = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
+    if batch_axes:
+        f, p, z = lax.pmean((f, p, z), batch_axes)
+    return E * jnp.sum(f * p), z
 
 
-def init_params(config: MoEConfig, key) -> Dict[str, Any]:
-    D, L, V, S = config.d_model, config.n_layer, config.vocab_size, config.seq_len
-    E, F = config.n_experts, config.expert_mlp
-    std = 0.02
-    resid_std = std / math.sqrt(2 * L)
-    ks = jax.random.split(key, 8)
-
-    def norm(key, shape, s):
-        return jax.random.normal(key, shape, jnp.float32) * s
-
-    return {
-        "wte": norm(ks[0], (V, D), std),
-        "wpe": norm(ks[1], (S, D), std / 2),
-        "blocks": {
-            "ln1_scale": jnp.ones((L, D)),
-            "ln1_bias": jnp.zeros((L, D)),
-            "qkv_w": norm(ks[2], (L, D, 3 * D), std),
-            "qkv_b": jnp.zeros((L, 3 * D)),
-            "out_w": norm(ks[3], (L, D, D), resid_std),
-            "out_b": jnp.zeros((L, D)),
-            "ln2_scale": jnp.ones((L, D)),
-            "ln2_bias": jnp.zeros((L, D)),
-            "router_w": norm(ks[4], (L, D, E), std),
-            "expert_in_w": norm(ks[5], (L, E, D, F), std),
-            "expert_in_b": jnp.zeros((L, E, F)),
-            "expert_out_w": norm(ks[6], (L, E, F, D), resid_std),
-            "expert_out_b": jnp.zeros((L, E, D)),
-        },
-        "lnf_scale": jnp.ones((D,)),
-        "lnf_bias": jnp.zeros((D,)),
-    }
+def route(h32, router_w, k: int, norm_topk_prob: bool, batch_axes=()):
+    """h32: (..., D) float32.  -> combine weights (..., k) float32, expert ids
+    (..., k) int32, (load-balance, z).  The matmul runs at full float32
+    precision (the TPU's default would round its operands to bfloat16, and a
+    token's k-th expert is decided by the last bits)."""
+    logits = jnp.einsum("...d,de->...e", h32, router_w.astype(jnp.float32),
+                        precision=lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    weights, experts = lax.top_k(probs, k)
+    losses = router_losses(logits, probs, experts, batch_axes)
+    if norm_topk_prob:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return weights, experts, losses
 
 
-def logical_axes(config: MoEConfig) -> Dict[str, Any]:
-    La = "layers"
-    return {
-        "wte": ("vocab", "embed"),
-        "wpe": (None, "embed"),
-        "blocks": {
-            "ln1_scale": (La, "norm"),
-            "ln1_bias": (La, "norm"),
-            "qkv_w": (La, "embed", "heads"),
-            "qkv_b": (La, "heads"),
-            "out_w": (La, "heads", "embed"),
-            "out_b": (La, "norm"),
-            "ln2_scale": (La, "norm"),
-            "ln2_bias": (La, "norm"),
-            "router_w": (La, "embed", None),
-            "expert_in_w": (La, "expert", "embed", "mlp"),
-            "expert_in_b": (La, "expert", "mlp"),
-            "expert_out_w": (La, "expert", "mlp", "embed"),
-            "expert_out_b": (La, "expert", "norm"),
-        },
-        "lnf_scale": ("norm",),
-        "lnf_bias": ("norm",),
-    }
+def sort_pairs(experts, n_experts: int):
+    """experts: (N, k) ids.  -> ``order`` (N x k,): the flat (token, slot)
+    pairs in expert order, ties in token order; ``inverse`` (N, k): where
+    each pair went; ``group_sizes`` (E,) int32, summing to N x k."""
+    flat = experts.reshape(-1)
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    inverse = jnp.argsort(order).astype(jnp.int32).reshape(experts.shape)
+    group_sizes = jnp.sum(
+        flat[:, None] == jnp.arange(n_experts, dtype=flat.dtype), axis=0,
+        dtype=jnp.int32)
+    return order, inverse, group_sizes
 
 
-def num_params(config: MoEConfig) -> int:
-    D, L, V, S = config.d_model, config.n_layer, config.vocab_size, config.seq_len
-    E, F = config.n_experts, config.expert_mlp
-    attn = 4 * D + 3 * D * D + 3 * D + D * D + D
-    moe = D * E + E * D * F + E * F + E * F * D + E * D
-    return V * D + S * D + L * (attn + moe) + 2 * D
+@jax.custom_vjp
+def _to_expert_order(x, order, inverse):
+    """x: (N, D) -> (N x k, D), row j the token of pair ``order[j]``."""
+    return x[order // inverse.shape[1]]
 
 
-def _route(x32, router_w, config: MoEConfig):
-    """Top-k token-choice routing.  x32: (N, D) fp32 tokens.
-
-    Returns (dispatch (N, E, C) one-hot*bool, combine (N, E, C) weights,
-    aux load-balance loss).  All static shapes.
-    """
-    N = x32.shape[0]
-    E, K = config.n_experts, config.top_k
-    C = config.capacity(N)
-
-    logits = x32 @ router_w  # (N, E)
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-
-    # Switch-style aux loss: E * sum_e (token fraction)_e * (mean prob)_e,
-    # computed on the top-1 assignment.
-    top1 = jnp.argmax(probs, axis=-1)
-    frac = jnp.mean(jax.nn.one_hot(top1, E, dtype=jnp.float32), axis=0)
-    aux = E * jnp.sum(frac * jnp.mean(probs, axis=0))
-
-    dispatch = jnp.zeros((N, E, C), jnp.float32)
-    combine = jnp.zeros((N, E, C), jnp.float32)
-    # Running per-expert fill count; carried across the k selections so the
-    # 2nd choice lands after all 1st choices of the same expert.
-    fill = jnp.zeros((E,), jnp.int32)
-    masked = probs
-    for _ in range(K):
-        choice = jnp.argmax(masked, axis=-1)              # (N,)
-        gate = jnp.take_along_axis(masked, choice[:, None], axis=-1)[:, 0]
-        onehot = jax.nn.one_hot(choice, E, dtype=jnp.int32)   # (N, E)
-        # Position of each token within its chosen expert's buffer.
-        pos_in = jnp.cumsum(onehot, axis=0) - onehot + fill[None, :]
-        pos = jnp.sum(pos_in * onehot, axis=-1)           # (N,)
-        keep = pos < C
-        oh_pos = jax.nn.one_hot(pos, C, dtype=jnp.float32) * keep[:, None]
-        d = onehot.astype(jnp.float32)[:, :, None] * oh_pos[:, None, :]
-        dispatch = dispatch + d
-        combine = combine + gate[:, None, None] * d
-        fill = fill + jnp.sum(onehot * keep[:, None], axis=0)
-        masked = masked * (1.0 - onehot.astype(probs.dtype))
-    # Renormalize combine weights over the kept choices per token.
-    denom = jnp.sum(combine, axis=(1, 2), keepdims=True)
-    combine = combine / jnp.maximum(denom, 1e-9)
-    return dispatch, combine, aux
+def _to_expert_order_fwd(x, order, inverse):
+    return _to_expert_order(x, order, inverse), inverse
 
 
-def _moe_mlp(x, blk, config: MoEConfig):
-    """MoE feed-forward.  x: (B, S, D) -> (B, S, D), plus aux loss."""
-    B, S, D = x.shape
-    dt = config.dtype
-    x32 = x.reshape(B * S, D).astype(jnp.float32)
-    dispatch, combine, aux = _route(x32, blk["router_w"], config)
-
-    # Dispatch: (N,E,C) x (N,D) -> (E,C,D); sharded over `expert` this is the
-    # all_to_all that sends tokens to their expert's devices.
-    xe = jnp.einsum("nec,nd->ecd", dispatch.astype(dt), x.reshape(B * S, D))
-    h = jnp.einsum("ecd,edf->ecf", xe, blk["expert_in_w"].astype(dt))
-    h = jax.nn.gelu(h + blk["expert_in_b"].astype(dt)[:, None, :])
-    ye = jnp.einsum("ecf,efd->ecd", h, blk["expert_out_w"].astype(dt))
-    ye = ye + blk["expert_out_b"].astype(dt)[:, None, :]
-    y = jnp.einsum("nec,ecd->nd", combine.astype(dt), ye)
-    return y.reshape(B, S, D), aux
+def _to_expert_order_bwd(inverse, g):
+    dx = jnp.sum(g[inverse].astype(jnp.float32), axis=1).astype(g.dtype)
+    return dx, None, None
 
 
-def _block(x, blk, config: MoEConfig):
-    B, S, D = x.shape
-    H, hd = config.n_head, config.head_dim
-    dt = config.dtype
-
-    h = _layernorm(x, blk["ln1_scale"], blk["ln1_bias"]).astype(dt)
-    qkv = h @ blk["qkv_w"].astype(dt) + blk["qkv_b"].astype(dt)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    attn = _attention(q.reshape(B, S, H, hd), k.reshape(B, S, H, hd),
-                      v.reshape(B, S, H, hd), config._attn_view())
-    x = x + attn.reshape(B, S, D) @ blk["out_w"].astype(dt) + blk["out_b"].astype(dt)
-
-    h = _layernorm(x, blk["ln2_scale"], blk["ln2_bias"]).astype(dt)
-    y, aux = _moe_mlp(h, blk, config)
-    return x + y, aux
+_to_expert_order.defvjp(_to_expert_order_fwd, _to_expert_order_bwd)
 
 
-def forward(params: Dict[str, Any], tokens, config: MoEConfig):
-    """tokens (B, S) int32 -> (logits (B, S, V) fp32, total aux loss)."""
-    B, S = tokens.shape
-    dt = config.dtype
-    x = params["wte"][tokens].astype(dt) + params["wpe"][:S].astype(dt)
-
-    block_fn = partial(_block, config=config)
-    if config.remat:
-        block_fn = jax.checkpoint(block_fn)
-
-    def scan_body(carry, blk):
-        x, aux = block_fn(carry, blk)
-        return x, aux
-
-    x, auxes = lax.scan(scan_body, x, params["blocks"])
-    x = _layernorm(x, params["lnf_scale"], params["lnf_bias"]).astype(dt)
-    logits = jnp.einsum("bsd,vd->bsv", x, params["wte"].astype(dt),
-                        preferred_element_type=jnp.float32)
-    return logits, jnp.sum(auxes)
+@jax.custom_vjp
+def _to_token_order(rows, order, inverse):
+    """rows: (N x k, D) in expert order -> (N, k, D)."""
+    return rows[inverse]
 
 
-def loss_fn(params, tokens, targets, config: MoEConfig):
-    logits, aux = forward(params, tokens, config)
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    return jnp.mean(lse - tgt) + config.aux_loss_weight * aux
+def _to_token_order_fwd(rows, order, inverse):
+    return rows[inverse], order
 
 
-def make_train_step(config: MoEConfig, optimizer):
-    def step(params, opt_state, tokens, targets):
-        import optax
+def _to_token_order_bwd(order, g):
+    return g.reshape(order.shape[0], g.shape[-1])[order], None, None
 
-        loss, grads = jax.value_and_grad(loss_fn)(params, tokens, targets, config)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
-        return params, opt_state, loss
 
-    return step
+_to_token_order.defvjp(_to_token_order_fwd, _to_token_order_bwd)
+
+
+def expert_mlp(x, weights, experts, w_gate, w_up, w_down):
+    """One chip's tokens through their experts.  x: (N, D); weights,
+    experts: (N, k); w_gate, w_up: (E, D, F); w_down: (E, F, D), all in the
+    compute dtype.  -> (N, D)."""
+    with jax.named_scope("moe_dispatch"):
+        order, inverse, group_sizes = sort_pairs(experts, w_gate.shape[0])
+        rows = _to_expert_order(x, order, inverse)
+    with jax.named_scope("experts"):
+        gate = grouped_matmul(rows, w_gate, group_sizes)
+        up = grouped_matmul(rows, w_up, group_sizes)
+        act = (jax.nn.silu(gate.astype(jnp.float32))
+               * up.astype(jnp.float32)).astype(x.dtype)
+        out = grouped_matmul(act, w_down, group_sizes)
+    with jax.named_scope("moe_dispatch"):
+        out = _to_token_order(out, order, inverse).astype(jnp.float32)
+        return jnp.sum(out * weights[..., None], axis=1).astype(x.dtype)
+
+
+def moe_mlp(h32, blk, *, experts_per_token: int, norm_topk_prob: bool, dtype):
+    """The layer.  h32: (B, S, D) float32, the block's normed input (the
+    router reads it as it is, the experts its cast to ``dtype``); ``blk``
+    holds ``router`` (D, E), ``w_gate``, ``w_up`` (E, D, F), ``w_down``
+    (E, F, D).  -> (y (B, S, D) in ``dtype``, (load-balance, z))."""
+    mesh = jax.sharding.get_abstract_mesh()
+    sharded = not (mesh.empty or mesh.size == 1)
+    batch_axes = tuple(a for a in ("data", "fsdp")
+                       if sharded and a in mesh.axis_names)
+
+    def local(h32, router, w_gate, w_up, w_down):
+        tokens = h32.reshape(-1, h32.shape[-1])
+        with jax.named_scope("router"):
+            weights, experts, losses = route(
+                tokens, router, experts_per_token, norm_topk_prob, batch_axes)
+        y = expert_mlp(tokens.astype(dtype), weights, experts, w_gate, w_up,
+                       w_down)
+        return y.reshape(h32.shape), losses
+
+    args = (h32, blk["router"], blk["w_gate"].astype(dtype),
+            blk["w_up"].astype(dtype), blk["w_down"].astype(dtype))
+    if not sharded:
+        return local(*args)
+    P = jax.sharding.PartitionSpec
+    rows = P(batch_axes or None, None, None)
+    # check_vma off as for splash: a pallas_call declares no vma on its
+    # outputs.  Axes a spec does not name (the weights' every axis) see
+    # whole arrays.
+    return jax.shard_map(local, in_specs=(rows, P(), P(), P(), P()),
+                         out_specs=(rows, (P(), P())), check_vma=False)(*args)

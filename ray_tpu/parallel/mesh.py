@@ -14,7 +14,9 @@ Axes (outermost → innermost = slowest → fastest links):
   data   — pure data parallel (gradient psum)
   fsdp   — data parallel with parameter/optimizer sharding (ZeRO-3 equiv:
            XLA all-gathers params per layer, reduce-scatters grads)
-  expert — expert parallel for MoE layers (token dispatch = all_to_all)
+  expert — where MoE expert weights are stored (sharded on their expert
+           axis; models/moe.py gathers them to compute, tokens stay put:
+           no all_to_all dispatch is built)
   tensor — megatron-style tensor parallel (activations psum)
   seq    — sequence/context parallel (ring attention / all-to-all)
 """
@@ -116,7 +118,8 @@ DEFAULT_RULES: Dict[str, Optional[Tuple[str, ...]]] = {
     # Leading stacked-layer axis of a pipelined block stack: sharding it over
     # `pipe` gives each stage its slice of layers (parallel/pipeline.py).
     "layers": ("pipe",),
-    # Leading expert axis of MoE expert weights (models/moe.py).
+    # Leading expert axis of the expert weights (models/llama.py with
+    # experts): where they are stored; models/moe.py gathers them to compute.
     "expert": ("expert",),
 }
 

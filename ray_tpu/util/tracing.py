@@ -145,7 +145,16 @@ SCOPE_REGISTRY: Dict[str, str] = {
             "out-projection",
     "attn_kernel": "the attention kernel call itself, nested inside attn "
                    "(splash/ring/ulysses/XLA, with its layout changes)",
-    "mlp": "MLP part of a block: norm to down-projection",
+    "mlp": "MLP part of a block: norm to down-projection (with experts: "
+           "the norm, the weights' casts and the residual add around the "
+           "three scopes below)",
+    "router": "expert layer (models/moe.py), nested inside mlp: router "
+              "logits, softmax, top-k, the two router losses",
+    "moe_dispatch": "expert layer, nested inside mlp: sort of the (token, "
+                    "slot) pairs, group sizes, the gathers into expert "
+                    "order and back, the weighted sum",
+    "experts": "expert layer, nested inside mlp: the grouped matmuls and "
+               "the activation between them",
     "lm_head": "final norm, logits, loss",
     "optimizer": "optimizer.update + apply_updates (gradient clipping is "
                  "inside the optax chain, so inside the scope)",

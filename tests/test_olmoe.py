@@ -1,0 +1,368 @@
+"""OLMoE through ``models/llama.py``: QK-norm, ``models/moe.py``'s dropless
+expert layer, ``ops/grouped_matmul.py``, the router losses.
+
+The plain reference is ``benchmarks/reference/olmoe.py``, the one copy (float32,
+every expert applied to every token, no sort, no grouped matmul).  Everything
+runs on the CPU with seeded random weights at the tiny preset; the grouped
+matmul is the Pallas kernel in interpret mode.
+"""
+
+import dataclasses
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.reference import olmoe as reference
+from ray_tpu.models import llama, moe
+from ray_tpu.ops.grouped_matmul import grouped_matmul
+from ray_tpu.parallel import MeshSpec, batch_sharding, make_mesh
+from ray_tpu.parallel.mesh import pytree_sharding
+
+#: benchmarks/lib/correct.py's, which the bf16 program is held to on the chip
+LOSS_TOL, GRAD_TOL = 1e-3, 0.75
+
+
+def _published(config: llama.LlamaConfig):
+    """The keys the reference reads, as a published config.json names them."""
+    return {"hidden_size": config.d_model,
+            "num_attention_heads": config.n_head,
+            "num_key_value_heads": config.n_kv_head,
+            "num_experts": config.n_experts,
+            "num_experts_per_tok": config.experts_per_token,
+            "norm_topk_prob": config.norm_topk_prob,
+            "rms_norm_eps": config.rms_eps, "rope_theta": config.rope_theta,
+            "router_aux_loss_coef": config.router_aux_loss_coef,
+            "router_z_loss_coef": config.router_z_loss_coef}
+
+
+def _tiny(**kw):
+    return dataclasses.replace(llama.LlamaConfig.tiny_moe(), attn_impl="xla",
+                               **kw)
+
+
+def _batch(config, rows=2, seed=1):
+    tokens = jax.random.randint(jax.random.key(seed),
+                                (rows, config.seq_len + 1), 0,
+                                config.vocab_size)
+    return tokens[:, :-1], tokens[:, 1:]
+
+
+def _rel_err(a, b):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
+
+
+# ------------------------------------------------ (a) against the reference
+@pytest.mark.parametrize("dtype,loss_tol,grad_tol", [
+    # float32 program against float32 reference: the same mathematics in
+    # another order (sorted rows and a grouped product against a masked sum
+    # over all experts), so only float32 summation order separates them.
+    ("float32", 1e-5, 1e-4),
+    # bf16 program (bf16 matmul operands, residual stream and logits;
+    # float32 router): inside the tolerances lib/correct.py holds the chip
+    # run to.  Its GRAD_TOL guards the mathematics, not the precision.
+    ("bfloat16", LOSS_TOL, GRAD_TOL),
+], ids=["float32", "bfloat16"])
+def test_loss_and_gradients_match_the_plain_reference(dtype, loss_tol,
+                                                      grad_tol):
+    dt = jnp.dtype(dtype)
+    config = _tiny(dtype=dt, logits_dtype=dt)
+    params = llama.init_params(config, jax.random.key(0))
+    # a router that prefers some experts, so the load is uneven
+    params["blocks"]["router"] = params["blocks"]["router"] * 20.0
+    tokens, targets = _batch(config)
+    loss, grads = jax.jit(jax.value_and_grad(
+        lambda p: llama.loss_fn(p, tokens, targets, config)))(params)
+    ref_loss, ref_grads = jax.jit(jax.value_and_grad(
+        lambda p: reference.loss(p, tokens, targets, _published(config),
+                                 q_block=config.seq_len)))(params)
+    assert _rel_err(loss, ref_loss) <= loss_tol
+    errs = jax.tree.map(_rel_err, grads, ref_grads)
+    assert set(errs["blocks"]) == {"attn_norm", "wq", "wk", "wv", "wo",
+                                   "q_norm", "k_norm", "mlp_norm", "router",
+                                   "w_gate", "w_up", "w_down"}
+    worst = max(jax.tree.leaves(errs))
+    assert worst <= grad_tol, errs
+    # the router losses are in the loss: without them it is smaller
+    bare = dataclasses.replace(config, router_aux_loss_coef=0.0,
+                               router_z_loss_coef=0.0)
+    assert float(llama.loss_fn(params, tokens, targets, bare)) \
+        < float(loss) - 0.009
+
+
+# ------------------------------------- (b) the layer against a Python loop
+def _layer_inputs(n_experts, k, n_tokens=48, d=32, f=16, seed=0):
+    ks = jax.random.split(jax.random.key(seed), 5)
+    x = jax.random.normal(ks[0], (n_tokens, d))
+    router = jax.random.normal(ks[1], (d, n_experts))
+    # skewed: expert 0 is out of reach for every token (no row), the last
+    # expert is every token's first choice (most rows)
+    logits_bias = jnp.zeros((n_experts,)).at[0].set(-1e4).at[-1].set(50.0)
+    blk = {"router": router,
+           "w_gate": jax.random.normal(ks[2], (n_experts, d, f)) * 0.2,
+           "w_up": jax.random.normal(ks[3], (n_experts, d, f)) * 0.2,
+           "w_down": jax.random.normal(ks[4], (n_experts, f, d)) * 0.2}
+    return x, blk, logits_bias
+
+
+def _with_bias(x, router, bias):
+    """The layer's router has no bias; the tests force the skew through one
+    more input feature, constant 1, whose row of the matrix is the bias."""
+    return (jnp.concatenate([x, jnp.ones((x.shape[0], 1))], axis=1),
+            jnp.concatenate([router, bias[None, :]], axis=0))
+
+
+@pytest.mark.parametrize("n_experts,k", [(8, 2), (64, 8)])
+def test_expert_layer_matches_a_per_token_loop(n_experts, k):
+    x, blk, bias = _layer_inputs(n_experts, k)
+    x1, router1 = _with_bias(x, blk["router"], bias)
+    weights, experts, _ = moe.route(x1, router1, k, False)
+    _, _, group_sizes = moe.sort_pairs(experts, n_experts)
+    sizes = np.asarray(group_sizes)
+    assert sizes[0] == 0 and sizes[-1] == x.shape[0] == sizes.max()
+    assert sizes.sum() == x.shape[0] * k
+
+    got = np.asarray(moe.expert_mlp(x, weights, experts, blk["w_gate"],
+                                    blk["w_up"], blk["w_down"]))
+    x64 = np.asarray(x, np.float64)
+    want = np.zeros_like(x64)
+    for t in range(x.shape[0]):
+        for slot in range(k):
+            e = int(experts[t, slot])
+            g = x64[t] @ np.asarray(blk["w_gate"][e], np.float64)
+            u = x64[t] @ np.asarray(blk["w_up"][e], np.float64)
+            h = g / (1.0 + np.exp(-g)) * u
+            want[t] += float(weights[t, slot]) \
+                * (h @ np.asarray(blk["w_down"][e], np.float64))
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+
+
+# ----------------------------------------------------------- (c) dropless
+def test_dropless_and_token_order_independent():
+    n_experts, k = 8, 2
+    x, blk, bias = _layer_inputs(n_experts, k, n_tokens=40)
+    x1, router1 = _with_bias(x, blk["router"], bias)
+    weights, experts, _ = moe.route(x1, router1, k, False)
+    order, inverse, group_sizes = moe.sort_pairs(experts, n_experts)
+    n_pairs = x.shape[0] * k
+    assert int(group_sizes.sum()) == n_pairs
+    # no pair lost: the order is a permutation and the inverse inverts it
+    np.testing.assert_array_equal(np.sort(np.asarray(order)),
+                                  np.arange(n_pairs))
+    np.testing.assert_array_equal(
+        np.asarray(order)[np.asarray(inverse).reshape(-1)], np.arange(n_pairs))
+
+    def layer(x, weights, experts):
+        return moe.expert_mlp(x, weights, experts, blk["w_gate"],
+                              blk["w_up"], blk["w_down"])
+    base = np.asarray(layer(x, weights, experts))
+    perm = np.random.default_rng(0).permutation(x.shape[0])
+    shuffled = np.asarray(layer(x[perm], weights[perm], experts[perm]))
+    np.testing.assert_allclose(shuffled, base[perm], rtol=1e-6, atol=1e-7)
+    # a token whose weights are zeroed contributes nothing and disturbs none
+    zeroed = np.asarray(layer(x, weights.at[3].set(0.0), experts))
+    assert np.all(zeroed[3] == 0.0)
+    np.testing.assert_allclose(np.delete(zeroed, 3, 0),
+                               np.delete(base, 3, 0), rtol=1e-6, atol=1e-7)
+
+
+# ------------------------------------------------- (d) the grouped matmul
+@pytest.mark.parametrize("sizes", [(5, 0, 11, 8), (0, 0, 24, 0), (6, 6, 6, 6),
+                                   (24, 0, 0, 0)],
+                         ids=["ragged", "one-group", "even", "first-only"])
+def test_grouped_matmul_three_products_match_einsum_per_group(sizes):
+    M, K, N = sum(sizes), 16, 24
+    ks = jax.random.split(jax.random.key(2), 3)
+    lhs = jax.random.normal(ks[0], (M, K))
+    rhs = jax.random.normal(ks[1], (len(sizes), K, N))
+    dout = jax.random.normal(ks[2], (M, N))
+    group_sizes = jnp.asarray(sizes, jnp.int32)
+
+    out, vjp = jax.vjp(lambda a, b: grouped_matmul(a, b, group_sizes),
+                       lhs, rhs)
+    dlhs, drhs = vjp(dout)
+    starts = np.concatenate([[0], np.cumsum(sizes)])
+    for g, (lo, hi) in enumerate(zip(starts, starts[1:])):
+        a, d = np.asarray(lhs[lo:hi]), np.asarray(dout[lo:hi])
+        np.testing.assert_allclose(
+            out[lo:hi], np.einsum("mk,kn->mn", a, rhs[g]), rtol=1e-5,
+            atol=1e-5)
+        np.testing.assert_allclose(
+            dlhs[lo:hi], np.einsum("mn,kn->mk", d, rhs[g]), rtol=1e-5,
+            atol=1e-5)
+        # an empty group's matrix gets a zero gradient
+        np.testing.assert_allclose(
+            drhs[g], np.einsum("mk,mn->kn", a, d), rtol=1e-5, atol=1e-5)
+
+
+def test_grouped_matmul_refuses_a_backend_without_the_kernel(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(NotImplementedError, match="megablox"):
+        grouped_matmul(jnp.ones((8, 8)), jnp.ones((1, 8, 8)),
+                       jnp.asarray([8], jnp.int32))
+
+
+def test_grouped_matmul_lowers_for_the_tpu_at_olmoe_shapes(monkeypatch):
+    """Lowering for the TPU needs no TPU: at the cell's shapes (8192 tokens x
+    8 experts a token, 64 groups, 2048 -> 1024 and back) the three products
+    become three Mosaic calls, in bf16 with the tiles ops/grouped_matmul.py
+    was timed with."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    M, G, D, F = 65536, 64, 2048, 1024
+    group_sizes = jax.ShapeDtypeStruct((G,), jnp.int32)
+    for K, N in ((D, F), (F, D)):
+        lhs = jax.ShapeDtypeStruct((M, K), jnp.bfloat16)
+        rhs = jax.ShapeDtypeStruct((G, K, N), jnp.bfloat16)
+        text = jax.jit(jax.value_and_grad(
+            lambda a, b, s: jnp.sum(grouped_matmul(a, b, s).astype(
+                jnp.float32)), (0, 1))).trace(lhs, rhs, group_sizes).lower(
+                    lowering_platforms=("tpu",)).as_text()
+        assert text.count("tpu_custom_call") == 3
+
+
+# ------------------------------------------------- (e) the router losses
+def test_router_losses_against_closed_forms():
+    E = 4
+    # six tokens, k = 2; pairs per expert: 0 -> 5, 1 -> 4, 2 -> 2, 3 -> 1
+    experts = jnp.asarray([[0, 1], [0, 1], [0, 1], [0, 1], [0, 2], [3, 2]])
+    logits = jnp.log(jnp.asarray([[0.4, 0.3, 0.2, 0.1]] * 5
+                                 + [[0.1, 0.2, 0.3, 0.4]])) + 2.0
+    probs = jax.nn.softmax(logits, axis=-1)
+    balance, z = moe.router_losses(logits, probs, experts)
+    f = np.array([5, 4, 2, 1]) / 12.0
+    p = (5 * np.array([0.4, 0.3, 0.2, 0.1]) + np.array([0.1, 0.2, 0.3, 0.4])) \
+        / 6.0
+    assert float(balance) == pytest.approx(E * float(f @ p), rel=1e-6)
+    # every row sums to one before the shift, so logsumexp is the shift
+    assert float(z) == pytest.approx(4.0, rel=1e-5)
+    # uniform router, even load: exactly 1; everything on one expert: E
+    even = jnp.asarray([[0, 1], [2, 3]])
+    flat = jnp.zeros((2, E))
+    assert float(moe.router_losses(
+        flat, jax.nn.softmax(flat), even)[0]) == pytest.approx(1.0)
+    one = jnp.asarray([[50.0, 0, 0, 0]] * 2)
+    assert float(moe.router_losses(
+        one, jax.nn.softmax(one), jnp.zeros((2, 1), jnp.int32))[0]) \
+        == pytest.approx(E, rel=1e-6)
+
+
+# -------------------------------------------------------- (f) under a mesh
+def _gathered_shapes(hlo_text):
+    found = re.findall(r"= \w+\[([\d,]*)\][^\n]* all-gather(?:-start)?\(",
+                       hlo_text)
+    return sorted(tuple(int(d) for d in dims.split(",") if d)
+                  for dims in found)
+
+
+@pytest.mark.parametrize("axis", ["data", "fsdp"])
+def test_four_devices_equal_one_and_only_weights_are_gathered(axis):
+    # 8 rows of 96: no count of rows, tokens (768; 192 a device) or pairs is
+    # also a dimension of a parameter (8, 64, 128, 1024)
+    config = _tiny(dtype=jnp.float32, logits_dtype=jnp.float32, seq_len=96)
+    dense = dataclasses.replace(
+        config, n_experts=0, experts_per_token=0, qk_norm=False)
+    params = llama.init_params(config, jax.random.key(0))
+    tokens, targets = _batch(config, rows=8)
+    loss1, grads1 = jax.jit(jax.value_and_grad(
+        lambda p, t, y: llama.loss_fn(p, t, y, config)))(
+            params, tokens, targets)
+
+    mesh = make_mesh(MeshSpec(**{axis: 4}), jax.devices()[:4])
+
+    def compiled_on_mesh(config, params):
+        with jax.set_mesh(mesh):
+            sharded = jax.device_put(
+                params, pytree_sharding(llama.logical_axes(config), mesh))
+            t4, y4 = (jax.device_put(a, batch_sharding(mesh))
+                      for a in (tokens, targets))
+            compiled = jax.jit(jax.value_and_grad(
+                lambda p, t, y: llama.loss_fn(p, t, y, config))).lower(
+                    sharded, t4, y4).compile()
+            return compiled, (sharded, t4, y4)
+
+    compiled, args = compiled_on_mesh(config, params)
+    loss4, grads4 = compiled(*args)
+    assert float(loss4) == pytest.approx(float(loss1), rel=1e-5)
+    errs = jax.tree.map(_rel_err, grads4, grads1)
+    assert max(jax.tree.leaves(errs)) <= 1e-4, errs
+
+    # What the expert layer and QK-norm add to the dense program's
+    # all-gathers: parameters only (under fsdp; a layer's slice of a stacked
+    # one keeps a leading 1), never the batch or anything cut like it.
+    added = _gathered_shapes(compiled.as_text())
+    for shape in _gathered_shapes(compiled_on_mesh(
+            dense, llama.init_params(dense, jax.random.key(0)))[0].as_text()):
+        if shape in added:
+            added.remove(shape)
+    if axis == "fsdp":
+        assert added, "fsdp=4 stores the experts cut: they have to be gathered"
+    weights = {v.shape[1:] for v in params["blocks"].values()} \
+        | {params["wte"].shape}
+    for shape in added:
+        assert shape in weights or (
+            shape[0] == 1 and shape[1:] in weights), (
+            f"all-gather of {shape}: not a parameter's shape {weights}")
+
+
+# ------------------------------------------- (g) Mistral's program unchanged
+def test_without_experts_the_program_has_no_trace_of_them():
+    config = dataclasses.replace(llama.LlamaConfig.tiny(), attn_impl="xla")
+    params = jax.eval_shape(
+        lambda k: llama.init_params(config, k), jax.random.key(0))
+    assert set(params["blocks"]) == {"attn_norm", "wq", "wk", "wv", "wo",
+                                     "mlp_norm", "w_gate", "w_up", "w_down"}
+    tokens = jax.ShapeDtypeStruct((2, config.seq_len), jnp.int32)
+    jaxpr = jax.make_jaxpr(
+        lambda p, t: llama.loss_fn(p, t, t, config))(params, tokens)
+
+    def equations(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from equations(sub)
+
+    def names(jaxpr):
+        return [e.primitive.name for e in equations(jaxpr.jaxpr)]
+
+    dense = names(jaxpr)
+    for primitive in ("sort", "top_k", "pallas_call", "shard_map"):
+        assert primitive not in dense
+    # the embedding lookup and the target pick, nothing else
+    assert dense.count("gather") == 2
+    def layer_scans(jaxpr):  # the loss's own equations, nothing nested
+        return [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
+
+    # the carry alone: no per-layer output
+    assert [len(e.outvars) for e in layer_scans(jaxpr)] == [1]
+
+    moe_config = _tiny()
+    moe_params = jax.eval_shape(
+        lambda k: llama.init_params(moe_config, k), jax.random.key(0))
+    moe_jaxpr = jax.make_jaxpr(
+        lambda p, t: llama.loss_fn(p, t, t, moe_config))(moe_params, tokens)
+    assert {"sort", "top_k", "pallas_call"} <= set(names(moe_jaxpr))
+    # carry, load-balance (L,), z (L,)
+    assert [len(e.outvars) for e in layer_scans(moe_jaxpr)] == [3]
+
+
+def test_sizes_follow_the_published_model():
+    olmoe = llama.LlamaConfig(
+        vocab_size=50304, n_layer=1, n_head=16, n_kv_head=16, d_model=2048,
+        d_ff=1024, seq_len=4096, n_experts=64, experts_per_token=8,
+        qk_norm=True)
+    assert llama.num_params(olmoe) == 625_616_896  # 625.6 M
+    shapes = jax.eval_shape(lambda k: llama.init_params(olmoe, k),
+                            jax.random.key(0))
+    assert sum(int(np.prod(a.shape)) for a in jax.tree.leaves(shapes)) \
+        == llama.num_params(olmoe)
+    axes = llama.logical_axes(olmoe)
+    assert jax.tree.map(len, axes, is_leaf=lambda a: isinstance(a, tuple)) \
+        == jax.tree.map(lambda a: len(a.shape), shapes)
+    assert axes["blocks"]["w_gate"] == ("layers", "expert", "embed", "mlp")
+    # 6 x (attention, router, 8 of 64 experts, embedding + head, norms) +
+    # causal attention at S=4096
+    active = llama.num_params(olmoe) - 56 * 3 * 2048 * 1024
+    assert llama.flops_per_token(olmoe) == 6.0 * active + 12.0 * 2048 * 4096

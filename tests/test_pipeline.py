@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import gpt2, moe
+from ray_tpu.models import gpt2, llama, moe
 from ray_tpu.parallel import (MeshSpec, batch_sharding, make_mesh,
                               pipeline_apply, pytree_sharding)
 from ray_tpu.parallel.train_state import create_sharded_state, jit_train_step
@@ -109,41 +109,61 @@ def test_gpt2_pipelined_train_step():
 
 
 # ------------------------------------------------------------------- MoE/EP
+def _moe_config(**kw):
+    base = dict(vocab_size=256, n_layer=2, n_head=4, n_kv_head=4, d_model=64,
+                d_ff=128, seq_len=32, n_experts=4, experts_per_token=2,
+                qk_norm=True, router_aux_loss_coef=0.01,
+                router_z_loss_coef=0.001, dtype=jnp.float32,
+                logits_dtype=jnp.float32, attn_impl="xla")
+    base.update(kw)
+    return llama.LlamaConfig(**base)
+
+
 def test_moe_routing_capacity_and_weights():
-    config = moe.MoEConfig.tiny()
-    x = jax.random.normal(jax.random.key(0), (64, config.d_model))
-    w = jax.random.normal(jax.random.key(1),
-                          (config.d_model, config.n_experts))
-    dispatch, combine, aux = moe._route(x, w, config)
-    N, E, C = dispatch.shape
-    # No expert over capacity; each token dispatched <= top_k times.
-    assert np.all(np.asarray(dispatch.sum(axis=(0, 2))) <= C + 1e-6)
-    per_token = np.asarray(dispatch.sum(axis=(1, 2)))
-    assert np.all(per_token <= config.top_k + 1e-6)
-    # Combine weights of a dispatched token sum to ~1.
-    kept = per_token > 0
-    csum = np.asarray(combine.sum(axis=(1, 2)))
-    np.testing.assert_allclose(csum[kept], 1.0, rtol=1e-5)
-    assert float(aux) > 0
+    """Dropless: there is no capacity.  Group sizes sum to N x k, every
+    (token, slot) pair has exactly one row, and the combine weights are the
+    chosen probabilities as they stand."""
+    N, D, E, K = 64, 128, 4, 2
+    x = jax.random.normal(jax.random.key(0), (N, D))
+    w = jax.random.normal(jax.random.key(1), (D, E))
+    weights, experts, (balance, z) = moe.route(x, w, K, False)
+    order, inverse, group_sizes = moe.sort_pairs(experts, E)
+    assert int(group_sizes.sum()) == N * K
+    np.testing.assert_array_equal(np.sort(np.asarray(order)),
+                                  np.arange(N * K))
+    np.testing.assert_array_equal(
+        np.asarray(order)[np.asarray(inverse).reshape(-1)], np.arange(N * K))
+    # rows in expert order, each expert's rows as many as its group size
+    sorted_ids = np.asarray(experts).reshape(-1)[np.asarray(order)]
+    assert np.all(np.diff(sorted_ids) >= 0)
+    np.testing.assert_array_equal(np.bincount(sorted_ids, minlength=E),
+                                  np.asarray(group_sizes))
+    # a token's k experts differ, and its weights are their probabilities
+    assert np.all(np.asarray(experts[:, 0]) != np.asarray(experts[:, 1]))
+    probs = np.asarray(jax.nn.softmax(
+        jnp.dot(x, w, precision=jax.lax.Precision.HIGHEST), axis=-1))
+    np.testing.assert_allclose(
+        np.asarray(weights),
+        np.take_along_axis(probs, np.asarray(experts), axis=-1), rtol=1e-5)
+    renormed, _, _ = moe.route(x, w, K, True)
+    np.testing.assert_allclose(np.asarray(renormed.sum(-1)), 1.0, rtol=1e-5)
+    assert float(balance) > 0 and float(z) > 0
 
 
 def test_moe_forward_and_train_step_expert_parallel():
     mesh = make_mesh(MeshSpec(data=2, expert=4))
-    config = moe.MoEConfig(vocab_size=256, n_layer=2, n_head=4, d_model=64,
-                           seq_len=32, n_experts=4, expert_mlp=128,
-                           dtype=jnp.float32, attn_impl="xla")
+    config = _moe_config()
     import optax
 
     opt = optax.adam(1e-2)
     params, opt_state = create_sharded_state(
-        lambda k: moe.init_params(config, k), moe.logical_axes(config),
+        lambda k: llama.init_params(config, k), llama.logical_axes(config),
         mesh, jax.random.key(0), opt)
-    # Expert weights actually sharded over the expert axis.
-    sh = params["blocks"]["expert_in_w"].sharding
-    assert "expert" in (sh.spec[1] if isinstance(sh.spec[1], str) else "") \
-        or sh.spec[1] == "expert"
+    # Expert weights are stored sharded over the expert axis (the layer
+    # gathers them on the way in).
+    assert params["blocks"]["w_gate"].sharding.spec[1] == "expert"
 
-    step = jit_train_step(moe.make_train_step(config, opt), mesh=mesh)
+    step = jit_train_step(llama.make_train_step(config, opt), mesh=mesh)
     rng = np.random.default_rng(0)
     tokens = jax.device_put(
         jnp.asarray(rng.integers(0, 256, (8, 32)), jnp.int32),
@@ -158,21 +178,24 @@ def test_moe_forward_and_train_step_expert_parallel():
 
 
 def test_moe_expert_parallel_matches_replicated():
-    """Same params: EP-sharded forward == unsharded forward."""
-    config = moe.MoEConfig(vocab_size=128, n_layer=2, n_head=2, d_model=32,
-                           seq_len=16, n_experts=4, expert_mlp=64,
-                           dtype=jnp.float32, remat=False, attn_impl="xla")
-    params = moe.init_params(config, jax.random.key(0))
+    """Same params: forward and loss with the expert weights stored over the
+    expert axis == unsharded."""
+    config = _moe_config(vocab_size=128, n_head=2, n_kv_head=2, d_model=32,
+                         d_ff=64, seq_len=16, remat=False)
+    params = llama.init_params(config, jax.random.key(0))
     tokens = jnp.asarray(
         np.random.default_rng(1).integers(0, 128, (4, 16)), jnp.int32)
-    ref, aux_ref = moe.forward(params, tokens, config)
+    ref = llama.forward(params, tokens, config)
+    loss_ref = llama.loss_fn(params, tokens, tokens, config)
 
     mesh = make_mesh(MeshSpec(expert=4, data=2))
     with jax.set_mesh(mesh):
         sharded = jax.device_put(
-            params, pytree_sharding(moe.logical_axes(config), mesh))
-        got, aux = jax.jit(lambda p, t: moe.forward(p, t, config))(
+            params, pytree_sharding(llama.logical_axes(config), mesh))
+        got = jax.jit(lambda p, t: llama.forward(p, t, config))(
+            sharded, tokens)
+        loss = jax.jit(lambda p, t: llama.loss_fn(p, t, t, config))(
             sharded, tokens)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
-    np.testing.assert_allclose(float(aux), float(aux_ref), rtol=1e-4)
+    np.testing.assert_allclose(float(loss), float(loss_ref), rtol=1e-4)

@@ -3,7 +3,8 @@ step): ``jax.named_scope`` names in the compiled train step, ``TrainStep``
 (anatomy, compile records, first call), the span API on the profiler's
 clock, and the step profiler's counters.
 
-* both model families' lowered step holds every registered scope;
+* each model family's lowered step holds every registered scope that is its
+  own (the three expert-layer scopes are the llama step's with experts);
 * ``classify_op_name`` on real ``op_name`` strings of the compiled v5e steps
   (``tests/data/v5e_step_op_names.json``) and ``parse_anatomy`` on an
   excerpt of that module's text (``tests/data/v5e_step_excerpt.hlo.txt``);
@@ -44,11 +45,22 @@ def clean_telemetry():
     train_profiler.activate(None)
 
 
+#: scopes only a step with models/moe.py's expert layer opens
+MOE_SCOPES = {"router", "moe_dispatch", "experts"}
+
+
+def _scopes_of(family):
+    return set(tracing.SCOPE_REGISTRY) - (
+        set() if family == "llama-moe" else MOE_SCOPES)
+
+
 def _family(name):
     from ray_tpu.models import gpt2, llama
 
     if name == "llama":
         return llama, llama.LlamaConfig.tiny()
+    if name == "llama-moe":  # what olmoe-s4096 runs
+        return llama, llama.LlamaConfig.tiny_moe()
     config = gpt2.GPTConfig.tiny()
     if name == "gpt2-attn-outside-unrolled":  # what gpt2xl-s1024 runs
         import dataclasses
@@ -81,7 +93,7 @@ def _tiny_step(name="llama"):
 
 
 # ------------------------------------------------------- names in the step
-@pytest.mark.parametrize("family", ["llama", "gpt2",
+@pytest.mark.parametrize("family", ["llama", "llama-moe", "gpt2",
                                     "gpt2-attn-outside-unrolled"])
 def test_lowered_step_holds_every_registered_scope(family):
     import jax
@@ -97,8 +109,9 @@ def test_lowered_step_holds_every_registered_scope(family):
         params, opt_state, tokens, tokens)
     text = lowered.as_text(debug_info=True)
     for scope in tracing.SCOPE_REGISTRY:
-        assert f"/{scope}/" in text or f"({scope})" in text, (
-            f"{family}: no op of the lowered step is named {scope!r}")
+        assert (f"/{scope}/" in text or f"({scope})" in text) \
+            == (scope in _scopes_of(family)), (
+            f"{family}: the lowered step and the scope {scope!r}")
 
 
 def test_scopes_are_metadata_only():
@@ -160,7 +173,8 @@ def test_parse_anatomy_on_v5e_module_excerpt():
     assert anatomy["tuple.9"] == (None, None)
 
 
-@pytest.mark.parametrize("family", ["llama", "gpt2-attn-outside-unrolled"])
+@pytest.mark.parametrize("family", ["llama", "llama-moe",
+                                    "gpt2-attn-outside-unrolled"])
 def test_anatomy_of_a_tiny_cpu_step_end_to_end(family):
     from ray_tpu.parallel.train_state import PHASES
 
@@ -176,7 +190,8 @@ def test_anatomy_of_a_tiny_cpu_step_end_to_end(family):
     for phase in PHASES:
         assert by_phase[phase] > 0, (family, phase, by_phase)
     for part in tracing.SCOPE_REGISTRY:
-        assert by_part[part] > 0, (family, part, by_part)
+        assert (by_part[part] > 0) == (part in _scopes_of(family)), (
+            family, part, by_part)
     # the anatomy's own compile, where jax does not find the first call's
     # executable still in memory, is labelled apart from the step's
     assert [r["trigger"] for r in dt.compile_records("train_step")] \
