@@ -169,7 +169,8 @@ def _layernorm(x, scale, bias, eps=1e-5):
 
 
 def _attention(q, k, v, config: GPTConfig):
-    """Causal multi-head attention.  q,k,v: (B, S, H, hd).
+    """Causal attention.  q: (B, S, H, hd); k, v: (B, S, KV, hd) with H a
+    multiple of KV (grouped-query attention; KV == H for GPT-2).
 
     "ring"/"ulysses" are the context-parallel paths (ops/ring_attention.py):
     attention runs seq-sharded over the mesh's `seq` axis — callers install
@@ -194,6 +195,12 @@ def _attention_impl(q, k, v, config: GPTConfig):
         return splash_attention(q, k, v, causal=True,
                                 block_q=config.attn_block_q,
                                 block_kv=config.attn_block_kv)
+    if k.shape[2] != q.shape[2]:
+        # Every other path wants as many K/V heads as query heads: each K/V
+        # head serves a group of consecutive query heads.
+        group = q.shape[2] // k.shape[2]
+        k = jnp.repeat(k, group, axis=2)
+        v = jnp.repeat(v, group, axis=2)
     if impl == "ring":
         from ray_tpu.ops.ring_attention import ring_attention
 

@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
+from functools import partial
 from typing import Any, Dict
 
 import jax
@@ -207,18 +208,55 @@ def _rmsnorm(x, scale, eps):
     return x32 * lax.rsqrt(ms + eps) * scale
 
 
-def _rope(x, theta: float):
-    """Rotary position embedding over (B, S, H, hd) — rotate-half form."""
-    B, S, H, hd = x.shape
+def _rope_pass(x, theta: float, direction: float):
+    """x * cos + swap_halves(x) * (-sin | +sin) over (B, S, H, hd): the
+    rotate-half pairing (i, i + hd/2) as published, cos and sin from float32
+    angles, products and sum in float32, one rounding to x's dtype.
+    ``direction`` 1.0 rotates each pair by its position's angle, -1.0 back.
+
+    Everything stays hd wide so that the compiler makes it one pass, x read
+    once and the result written once in x's dtype.  Slicing the two halves
+    (or ``jnp.roll``, which is two slices) gave arrays of 64 lanes padded to
+    128 and a float32 copy of x in HBM: three passes forward and three
+    backward, 0.6 and 0.95 GB a layer for Mistral's q at 8192 tokens where
+    this needs 0.2 and 0.13 (PERF.md, PR 27).  The halves are swapped by a
+    product with a 0/1 permutation instead: each output is one input times
+    one, so it is exact, and cos, sin and the cast fuse into its output.
+    """
+    hd = x.shape[-1]
     half = hd // 2
-    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
-    angles = jnp.arange(S, dtype=jnp.float32)[:, None] * freqs[None, :]
-    cos = jnp.cos(angles)[None, :, None, :]  # (1, S, 1, half)
-    sin = jnp.sin(angles)[None, :, None, :]
-    x32 = x.astype(jnp.float32)
-    x1, x2 = x32[..., :half], x32[..., half:]
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
+    lane = jnp.arange(hd)
+    # (S, hd): lanes i and i + hd/2 share a frequency, so an angle
+    freqs = 1.0 / (theta ** ((lane % half).astype(jnp.float32) / half))
+    angles = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * freqs[None, :]
+    sign = jnp.where(lane < half, -direction, direction)
+    cos = jnp.cos(angles)[None, :, None, :]
+    sin = (jnp.sin(angles) * sign)[None, :, None, :]
+    swap = (lane[:, None] == (lane[None, :] + half) % hd).astype(x.dtype)
+    swapped = jnp.einsum("bshd,de->bshe", x, swap,
+                         preferred_element_type=jnp.float32,
+                         precision=lax.Precision.HIGHEST)
+    return (x.astype(jnp.float32) * cos + swapped * sin).astype(x.dtype)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _rope(x, theta: float):
+    """Rotary position embedding over (B, S, H, hd), rotate-half form, in
+    and out in x's dtype.  The backward is the same pass with the sine
+    negated (a rotation's transpose is the rotation back), not autodiff's
+    sum over sliced halves."""
+    return _rope_pass(x, theta, 1.0)
+
+
+def _rope_fwd(x, theta):
+    return _rope_pass(x, theta, 1.0), None
+
+
+def _rope_bwd(theta, _, g):
+    return (_rope_pass(g, theta, -1.0),)
+
+
+_rope.defvjp(_rope_fwd, _rope_bwd)
 
 
 def _block(x, blk, config: LlamaConfig):
@@ -238,12 +276,10 @@ def _block(x, blk, config: LlamaConfig):
         v = (h @ blk["wv"].astype(dt)).reshape(B, S, KV, hd)
         q = _rope(q.reshape(B, S, H, hd), config.rope_theta)
         k = _rope(k.reshape(B, S, KV, hd), config.rope_theta)
-        if KV != H:
-            # GQA: each kv head serves q_per_kv query heads.
-            k = jnp.repeat(k, config.q_per_kv, axis=2)
-            v = jnp.repeat(v, config.q_per_kv, axis=2)
         # Reuse the GPT-2 attention dispatcher (xla/splash/ring/ulysses): it
-        # only reads attn_impl/blocks/head-shape from the config.
+        # only reads attn_impl/blocks/head-shape from the config.  GQA: k and
+        # v go in at KV heads; the splash kernel takes them so, and the
+        # dispatcher repeats them for the paths that cannot.
         attn = _g._attention(q, k, v, config).astype(dt).reshape(B, S, H * hd)
         x = x + attn @ blk["wo"].astype(dt)
 

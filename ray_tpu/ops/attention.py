@@ -5,6 +5,16 @@ tensor is pure HBM traffic (805MB/layer for GPT-2-small at S=1024).  Splash
 keeps scores in VMEM tiles, never materializes them, and skips the blocks the
 causal mask empties.  head_dim=64 compiles unpadded under the 512x512 blocks
 on the v5e and agrees with the XLA path (chip_smoke.py, kernel phase).
+
+:func:`splash_attention` takes q of (B, S, H, head_dim) and k, v of
+(B, S, KV, head_dim) with H a multiple of KV: grouped-query attention goes to
+the kernel at its own head count.  The kernel reads the K/V head ``h // (H //
+KV)`` for query head ``h`` and sums dk and dv over the group in VMEM, so
+nothing copies K and V out to H heads in HBM, forward or backward (0.55 GB a
+layer for Mistral-7B at 8192 tokens, PERF.md PR 27).  The repeat lives with
+the paths that still need equal head counts, the XLA einsum and ring/ulysses
+(``models/gpt2.py:_attention_impl``), which decides from the implementation it
+is about to call and the shapes it holds.
 """
 
 from __future__ import annotations
@@ -72,7 +82,9 @@ def splash_attention(q, k, v, causal: bool = True,
     mask when causal (no wasted upper-triangle work), full-mask
     bidirectional (ViT-style) otherwise, with a fused dq/dkv backward.
 
-    q, k, v: (B, S, H, head_dim) — the model's native layout.
+    q: (B, S, H, head_dim), k and v: (B, S, KV, head_dim), the model's native
+    layout; H is a multiple of KV, and query head ``h`` attends to K/V head
+    ``h // (H // KV)`` (``jnp.repeat``'s order).  KV == H is plain MHA.
 
     On more than one device the kernel must run inside a ``shard_map`` that
     makes every mesh axis manual: the SPMD partitioner cannot split a Mosaic
@@ -80,9 +92,10 @@ def splash_attention(q, k, v, causal: bool = True,
     kernels cannot be automatically partitioned").  So under an ambient mesh
     (``jax.set_mesh``; ``jit_train_step(mesh=)`` installs it) the batch is
     divided over its `data` and `fsdp` axes and the heads over `tensor`;
-    axes the spec does not name see replicated data.
+    axes the spec does not name see replicated data.  `tensor` must divide
+    KV as it must divide H, so that each chip holds whole groups.
     """
-    _, S, _, hd = q.shape
+    _, S, H, hd = q.shape
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(hd)
 
@@ -98,6 +111,12 @@ def splash_attention(q, k, v, causal: bool = True,
     mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or mesh.size == 1:
         return local(q, k, v)
+    KV, tensor = k.shape[2], mesh.shape.get("tensor", 1)
+    if H % tensor or KV % tensor:
+        raise ValueError(
+            f"splash_attention: the mesh's tensor axis ({tensor}) must divide "
+            f"the {KV} K/V heads as it must divide the {H} query heads: a "
+            "chip's query heads read only the K/V heads it holds")
     batch_axes = tuple(a for a in ("data", "fsdp") if a in mesh.axis_names)
     spec = jax.sharding.PartitionSpec(
         batch_axes or None, None,

@@ -5,6 +5,7 @@ sharded multi-device step (same contract as tests/test_models.py for GPT-2).
 import contextlib
 import dataclasses
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -26,17 +27,20 @@ def test_forward_shapes_and_param_count():
     assert jnp.isfinite(logits).all()
 
 
-def test_gqa_equivalent_to_mha_with_tiled_kv():
-    """GQA with kv projections TILED to full heads must equal MHA exactly:
-    the repeat path shares each kv head across its query group, so an MHA
-    model whose wk/wv duplicate the kv heads per group is the same function.
-    """
-    gqa = llama.LlamaConfig(vocab_size=256, n_layer=1, n_head=4, n_kv_head=2,
-                            d_model=64, d_ff=128, seq_len=32,
-                            dtype=jnp.float32, attn_impl="xla")
-    mha = llama.LlamaConfig(vocab_size=256, n_layer=1, n_head=4, n_kv_head=4,
-                            d_model=64, d_ff=128, seq_len=32,
-                            dtype=jnp.float32, attn_impl="xla")
+@pytest.mark.parametrize("impl, heads, seq", [("xla", (4, 2), 32),
+                                              ("splash", (8, 2), 128)])
+def test_gqa_equivalent_to_mha_with_tiled_kv(impl, heads, seq):
+    """GQA must equal an MHA model whose wk/wv duplicate each kv head over its
+    query group, loss and every gradient leaf: on the XLA path, where the
+    dispatcher repeats k and v, and on the splash path (interpret mode here;
+    128 is the kernel's smallest block), where the kernel gets them at KV
+    heads and nothing repeats them."""
+    H, KV = heads
+    gqa = llama.LlamaConfig(vocab_size=256, n_layer=1, n_head=H, n_kv_head=KV,
+                            d_model=16 * H, d_ff=128, seq_len=seq,
+                            dtype=jnp.float32, logits_dtype=jnp.float32,
+                            attn_impl=impl)
+    mha = dataclasses.replace(gqa, n_kv_head=H)
     params = llama.init_params(gqa, jax.random.key(1))
     hd, D = gqa.head_dim, gqa.d_model
 
@@ -44,19 +48,40 @@ def test_gqa_equivalent_to_mha_with_tiled_kv():
         # (L, D, KV*hd) -> (L, D, KV, hd) -> repeat each kv head q_per_kv
         # times along the head axis -> (L, D, H*hd).
         L = w.shape[0]
-        heads = w.reshape(L, D, gqa.n_kv_head, hd)
+        heads = w.reshape(L, D, KV, hd)
         return jnp.repeat(heads, gqa.q_per_kv, axis=2).reshape(L, D, -1)
+
+    def untile_kv(g):
+        # the tiled model's gradient, summed back over each group's copies
+        L = g.shape[0]
+        return g.reshape(L, D, KV, gqa.q_per_kv, hd).sum(3).reshape(L, D, -1)
 
     params_mha = dict(params)
     params_mha["blocks"] = dict(params["blocks"])
     params_mha["blocks"]["wk"] = tile_kv(params["blocks"]["wk"])
     params_mha["blocks"]["wv"] = tile_kv(params["blocks"]["wv"])
 
-    tokens = jax.random.randint(jax.random.key(2), (2, 32), 0, 256)
+    toks = jax.random.randint(jax.random.key(2), (2, seq + 1), 0, 256)
+    tokens, targets = toks[:, :-1], toks[:, 1:]
     out_gqa = llama.forward(params, tokens, gqa)
     out_mha = llama.forward(params_mha, tokens, mha)
     np.testing.assert_allclose(np.asarray(out_gqa), np.asarray(out_mha),
                                rtol=1e-5, atol=1e-5)
+
+    loss_gqa, grads_gqa = jax.value_and_grad(llama.loss_fn)(
+        params, tokens, targets, gqa)
+    loss_mha, grads_mha = jax.value_and_grad(llama.loss_fn)(
+        params_mha, tokens, targets, mha)
+    assert float(loss_gqa) == pytest.approx(float(loss_mha), rel=1e-6)
+    grads_mha["blocks"]["wk"] = untile_kv(grads_mha["blocks"]["wk"])
+    grads_mha["blocks"]["wv"] = untile_kv(grads_mha["blocks"]["wv"])
+    for (path, got), want in zip(
+            jax.tree_util.tree_leaves_with_path(grads_gqa),
+            jax.tree.leaves(grads_mha)):
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), rtol=0,
+            atol=1e-5 * float(jnp.max(jnp.abs(want))),
+            err_msg=jax.tree_util.keystr(path))
 
 
 def test_rope_is_position_sensitive():
@@ -66,6 +91,53 @@ def test_rope_is_position_sensitive():
     assert not jnp.allclose(rotated[0, 0], rotated[0, 5])
     # Position 0 rotates by angle 0: unchanged.
     np.testing.assert_allclose(rotated[0, 0], x[0, 0], rtol=1e-6)
+
+
+def _sliced_rope(x, theta):
+    """The formula as published and as ``_rope`` computed it before it became
+    one pass: float32 halves, sliced, rotated, concatenated, rounded once."""
+    S, half = x.shape[1], x.shape[3] // 2
+    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    angles = jnp.arange(S, dtype=jnp.float32)[:, None] * freqs[None, :]
+    cos = jnp.cos(angles)[None, :, None, :]
+    sin = jnp.sin(angles)[None, :, None, :]
+    x32 = x.astype(jnp.float32)
+    x1, x2 = x32[..., :half], x32[..., half:]
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
+
+
+@pytest.mark.parametrize("hd", [16, 64, 128])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_rope_equals_the_sliced_float32_formula(dtype, hd):
+    """Values and gradients (the custom backward: the same pass, sine negated)
+    equal the sliced formula's and autodiff's through it bit for bit: the
+    swap is exact and the float32 products, sum and single rounding are the
+    same.  The benchmark's plain reference agrees to float32 rounding."""
+    from benchmarks.reference import llama as reference
+
+    theta = 10000.0
+    x = jax.random.normal(jax.random.key(hd), (2, 48, 3, hd)).astype(dtype)
+    weight = jax.random.normal(jax.random.key(1), x.shape)
+
+    def weighted(rope):
+        return lambda x: jnp.sum(rope(x, theta).astype(jnp.float32) * weight)
+
+    got, want = llama._rope(x, theta), _sliced_rope(x, theta)
+    assert got.dtype == want.dtype == dtype
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    got_grad = jax.grad(weighted(llama._rope))(x)
+    assert got_grad.dtype == dtype
+    np.testing.assert_array_equal(
+        np.asarray(got_grad, np.float32),
+        np.asarray(jax.grad(weighted(_sliced_rope))(x), np.float32))
+
+    x32 = x.astype(jnp.float32)
+    np.testing.assert_allclose(
+        np.asarray(llama._rope(x32, theta)),
+        np.asarray(reference._rope(x32, theta)), rtol=0, atol=1e-5)
 
 
 def test_tiny_training_step_reduces_loss():
@@ -221,3 +293,71 @@ def test_policy_saves_nothing_without_the_kernel(monkeypatch):
     assert _kernel_names(kept) == []
     assert _stacked_by_scans(kept) == _stacked_by_scans(bare)
     assert _stacked_by_scans(kept)[0] == 1  # x alone
+
+
+# ------------------------------ how q, k and v reach the attention kernel
+def _kv_repeats(jaxpr, config):
+    """The ``broadcast_in_dim`` equations that copy (.., KV, hd) out to
+    (.., KV, H // KV, hd): what ``jnp.repeat`` over the head axis traces to."""
+    want = (config.n_kv_head, config.q_per_kv, config.head_dim)
+    return [eqn for eqn in _equations(jaxpr)
+            if eqn.primitive.name == "broadcast_in_dim"
+            and eqn.outvars[0].aval.shape[-3:] == want]
+
+
+def test_only_the_paths_that_need_equal_heads_repeat_k_and_v():
+    """The splash kernel takes k and v at KV heads, so the layer's jaxpr has
+    no KV-to-H broadcast, forward or backward; the XLA path still repeats
+    (k and v, once each: the backward's remat is a separate jaxpr that
+    repeats them again)."""
+    config = _splash_config()
+    assert config.n_kv_head < config.n_head
+    assert _kv_repeats(_grad_jaxpr(config), config) == []
+    xla = dataclasses.replace(config, attn_impl="xla")
+    assert len(_kv_repeats(_grad_jaxpr(xla), xla)) >= 2
+
+
+def test_splash_refuses_a_tensor_axis_that_splits_a_kv_group():
+    """Under a mesh the heads are divided over `tensor`: it must divide the
+    K/V heads as it must divide the query heads, and the error says so."""
+    from ray_tpu.parallel import MeshSpec, make_mesh
+
+    config = _splash_config()  # 4 query heads, 2 K/V heads
+    with jax.set_mesh(make_mesh(MeshSpec(tensor=4), jax.devices()[:4])):
+        with pytest.raises(ValueError, match="tensor axis.*2 K/V heads"):
+            _grad_jaxpr(config)
+
+
+def test_gqa_step_lowers_for_the_tpu_with_k_and_v_at_their_own_heads(
+        monkeypatch):
+    """Lowering for the TPU needs no TPU.  A two-layer GQA step at head 128:
+    what the byte count of PERF.md (PR 27) rests on, read off the text.  The
+    two kernels (forward, fused backward) take q at H heads and k, v at KV
+    heads and give dk, dv back at KV heads; and nothing between the
+    projections and the kernel is a float32 array half a head wide (the
+    sliced RoPE's halves, 64 lanes padded to 128 on the chip)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    B, S, H, KV, hd = 2, 512, 8, 2, 128
+    config = llama.LlamaConfig(vocab_size=1024, n_layer=2, n_head=H,
+                               n_kv_head=KV, d_model=H * hd, d_ff=1536,
+                               seq_len=S)
+    optimizer = llama.make_optimizer()
+    params = jax.eval_shape(lambda k: llama.init_params(config, k),
+                            jax.random.key(0))
+    opt_state = jax.eval_shape(optimizer.init, params)
+    batch = jax.ShapeDtypeStruct((B, S), jnp.int32)
+    text = jax.jit(llama.make_train_step(config, optimizer)).trace(
+        params, opt_state, batch, batch).lower(
+            lowering_platforms=("tpu",)).as_text()
+
+    q, kv = f"tensor<{B}x{H}x{S}x{hd}xbf16>", f"tensor<{B}x{KV}x{S}x{hd}xbf16>"
+    calls = [line[line.rindex(" : ("):] for line in text.splitlines()
+             if "@tpu_custom_call" in line]
+    assert len(calls) == 2
+    forward, backward = [c.split(") -> (") for c in sorted(calls, key=len)]
+    # forward: q, k, v; backward: q, k, v and do in, dq partials, dk, dv out
+    assert (forward[0].count(q), forward[0].count(kv)) == (1, 2)
+    assert (backward[0].count(q), backward[0].count(kv)) == (2, 2)
+    assert backward[1].count(kv) == 2
+    halves = re.findall(rf"tensor<(?:\d+x)*{hd // 2}xf32>", text)
+    assert halves == []
