@@ -23,6 +23,8 @@ entry points a user calls and stops at the first failure:
    reference.
 
 The times it prints are smoke output for orientation, not benchmark metrics.
+What it counts of jax's compiles it counts with the benchmark's own
+``benchmarks/lib/compile_watch.CompileWatch``.
 The last line of stdout is ``{"ok": true, "device": {...}}``; a longer report
 goes to ``chiprun_out/chip_smoke.json``.
 """
@@ -37,14 +39,16 @@ import time
 
 import numpy as np
 
+from benchmarks.lib.compile_watch import CompileWatch
+
 SEQS_PER_CHIP = 16
 WARMUP_STEPS = 3
 TIMED_STEPS = 10
 #: Least fall of the loss from step 1 to step 13 that counts as training.
 #: On one repeated batch of 16 sequences the old records show 10.43 after 3
-#: steps and 9.56 after 13 (gpt2.make_optimizer docstring), and this script
-#: reproduces them (10.98 -> 10.43 -> 9.56); a repeated batch of 64 on four
-#: chips falls 0.85 in all (PERF.md, Bring-up).  Half of the smaller fall.
+#: steps and 9.56 after 13 (train_state.make_optimizer's docstring), and this
+#: script reproduces them (10.98 -> 10.43 -> 9.56); a repeated batch of 64 on
+#: four chips falls 0.85 in all (PERF.md, Bring-up).  Half of the smaller fall.
 MIN_LOSS_FALL = 0.4
 #: bf16 carries 8 mantissa bits (eps 3.9e-3).  A kernel and its XLA reference
 #: round in different places, so outputs may differ by a few eps of the
@@ -57,44 +61,6 @@ REPORT_DIR = "chiprun_out"
 
 def log(msg: str) -> None:
     print(msg, flush=True)
-
-
-class CompileWatch:
-    """Counts what jax compiles, from jax's own monitoring events: every
-    executable built or loaded, and every persistent-cache hit and miss."""
-
-    _COMPILE = "/jax/core/compile/backend_compile_duration"
-    _HIT = "/jax/compilation_cache/cache_hits"
-    _MISS = "/jax/compilation_cache/cache_misses"
-
-    def __init__(self):
-        from jax import monitoring
-
-        self.compiles = 0
-        self.compile_s = 0.0
-        self.hits = 0
-        self.misses = 0
-        monitoring.register_event_duration_secs_listener(self._on_duration)
-        monitoring.register_event_listener(self._on_event)
-
-    def _on_duration(self, event: str, seconds: float, **_):
-        if event == self._COMPILE:
-            self.compiles += 1
-            self.compile_s += seconds
-
-    def _on_event(self, event: str, **_):
-        if event == self._HIT:
-            self.hits += 1
-        elif event == self._MISS:
-            self.misses += 1
-
-    def snapshot(self) -> dict:
-        return {"compiles": self.compiles, "compile_s": self.compile_s,
-                "hits": self.hits, "misses": self.misses}
-
-    def since(self, before: dict) -> dict:
-        now = self.snapshot()
-        return {k: now[k] - before[k] for k in now}
 
 
 def cache_verdict(delta: dict) -> str:
@@ -476,9 +442,9 @@ def kernels_phase(attn_shape, ce_shape, ring_shape) -> list:
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.models import gpt2
-    from ray_tpu.ops.attention import splash_attention
+    from ray_tpu.ops.attention import causal_attention, splash_attention
     from ray_tpu.ops.fused_ce import fused_lm_head_ce
+    from ray_tpu.ops.lm_head import lm_head_cross_entropy
     from ray_tpu.ops.ring_attention import ring_attention
     from ray_tpu.parallel import MeshSpec, make_mesh
 
@@ -496,13 +462,11 @@ def kernels_phase(attn_shape, ce_shape, ring_shape) -> list:
     rows = []
 
     # splash attention, forward and gradient, against attn_impl="xla"
-    xla = gpt2.GPTConfig(attn_impl="xla")
-
     qkv = [normal(i, attn_shape) for i in range(3)]
     rows.append(run_kernel(
         f"splash_attention fwd+grad {attn_shape}",
         with_grads(splash_attention),
-        with_grads(lambda q, k, v: gpt2._attention(q, k, v, xla)),
+        with_grads(lambda q, k, v: causal_attention(q, k, v, "xla")),
         qkv, (FWD_TOL, GRAD_TOL, GRAD_TOL, GRAD_TOL), want_mosaic=2))
 
     # fused LM-head cross-entropy, forward and both backwards, against the
@@ -514,11 +478,7 @@ def kernels_phase(attn_shape, ce_shape, ring_shape) -> list:
     targets = jax.random.randint(jax.random.key(5), (B, S), 0, V)
 
     def dense_ce(x, wte, targets):
-        logits = jnp.einsum("bsd,vd->bsv", x, wte,
-                            preferred_element_type=jnp.float32)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-        return jnp.mean(lse - tgt)
+        return lm_head_cross_entropy(x, wte, targets, jnp.float32)
 
     def ce_with(fn):
         def run(x, wte, targets):

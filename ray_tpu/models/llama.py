@@ -39,9 +39,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models import gpt2 as _g
 from ray_tpu.models import moe as _moe
-from ray_tpu.ops.attention import save_splash_residuals
+from ray_tpu.ops.attention import causal_attention, save_splash_residuals
+from ray_tpu.ops.lm_head import lm_head_cross_entropy
+from ray_tpu.parallel.train_state import make_optimizer  # noqa: F401
+from ray_tpu.parallel.train_state import make_train_step as _make_train_step
 
 
 @dataclass(frozen=True)
@@ -59,14 +61,8 @@ class LlamaConfig:
     rms_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     remat: bool = True
-    attn_impl: str = "auto"  # auto | xla | splash | ring | ulysses
-    attn_block_q: int = 512
-    attn_block_kv: int = 512
+    attn_impl: str = "auto"  # ops.attention.ATTN_IMPLS
     logits_dtype: Any = jnp.bfloat16
-    # kept for MeshSpec probe parity with GPTConfig (pipelining of the llama
-    # stack rides the same `layers` axis; GPipe wiring arrives with demand)
-    pp_stages: int = 1
-    pp_microbatches: int = 0
     # Published facts of a mixture-of-experts model (OLMoE-1B-7B); Mistral
     # has none of them.  With experts the block's second half is
     # models/moe.py's dropless layer over n_experts SwiGLU experts of width
@@ -276,11 +272,10 @@ def _block(x, blk, config: LlamaConfig):
         v = (h @ blk["wv"].astype(dt)).reshape(B, S, KV, hd)
         q = _rope(q.reshape(B, S, H, hd), config.rope_theta)
         k = _rope(k.reshape(B, S, KV, hd), config.rope_theta)
-        # Reuse the GPT-2 attention dispatcher (xla/splash/ring/ulysses): it
-        # only reads attn_impl/blocks/head-shape from the config.  GQA: k and
-        # v go in at KV heads; the splash kernel takes them so, and the
-        # dispatcher repeats them for the paths that cannot.
-        attn = _g._attention(q, k, v, config).astype(dt).reshape(B, S, H * hd)
+        # GQA: k and v go in at KV heads; the splash kernel takes them so,
+        # and the dispatcher repeats them for the paths that cannot.
+        attn = causal_attention(q, k, v, config.attn_impl).astype(dt) \
+            .reshape(B, S, H * hd)
         x = x + attn @ blk["wo"].astype(dt)
 
     with jax.named_scope("mlp"):
@@ -334,34 +329,12 @@ def forward(params: Dict[str, Any], tokens, config: LlamaConfig):
 def loss_fn(params, tokens, targets, config: LlamaConfig):
     x, router_loss = forward_hidden(params, tokens, config)
     with jax.named_scope("lm_head"):
-        logits = jnp.einsum("bsd,vd->bsv", x,
-                            params["lm_head"].astype(config.dtype),
-                            preferred_element_type=config.logits_dtype)
-        lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
-        tgt = jnp.take_along_axis(
-            logits, targets[..., None], axis=-1)[..., 0].astype(jnp.float32)
-        ce = jnp.mean(lse - tgt)
+        ce = lm_head_cross_entropy(x, params["lm_head"].astype(config.dtype),
+                                   targets, config.logits_dtype)
     return ce if router_loss is None else ce + router_loss
 
 
-def make_optimizer(learning_rate=3e-4, weight_decay=0.1, b1=0.9, b2=0.95,
-                   grad_clip=1.0):
-    return _g.make_optimizer(learning_rate=learning_rate,
-                             weight_decay=weight_decay, b1=b1, b2=b2,
-                             grad_clip=grad_clip)
-
-
 def make_train_step(config: LlamaConfig, optimizer):
-    """Same contract as gpt2.make_train_step: XLA derives all gradient
-    collectives from the shardings."""
-    import optax
-
-    def step(params, opt_state, tokens, targets):
-        loss, grads = jax.value_and_grad(loss_fn)(params, tokens, targets,
-                                                  config)
-        with jax.named_scope("optimizer"):
-            updates, opt_state = optimizer.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
-        return params, opt_state, loss
-
-    return step
+    """Pure (params, opt_state, tokens, targets) -> (params, opt_state, loss):
+    parallel.train_state.make_train_step over this model's loss."""
+    return _make_train_step(partial(loss_fn, config=config), optimizer)

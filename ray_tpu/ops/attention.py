@@ -1,20 +1,32 @@
-"""Fused causal flash attention for TPU: the splash kernel.
+"""Causal attention for the decoders: one dispatcher, and the splash kernel.
 
-Replaces the XLA einsum-softmax-einsum path, whose (B, H, S, S) fp32 score
-tensor is pure HBM traffic (805MB/layer for GPT-2-small at S=1024).  Splash
-keeps scores in VMEM tiles, never materializes them, and skips the blocks the
-causal mask empties.  head_dim=64 compiles unpadded under the 512x512 blocks
-on the v5e and agrees with the XLA path (chip_smoke.py, kernel phase).
+:func:`causal_attention` is what a model's block calls, with its
+``attn_impl`` string.  It decides the implementation, and everything that
+follows from the choice lives here with it:
 
-:func:`splash_attention` takes q of (B, S, H, head_dim) and k, v of
-(B, S, KV, head_dim) with H a multiple of KV: grouped-query attention goes to
-the kernel at its own head count.  The kernel reads the K/V head ``h // (H //
+- ``"splash"`` is the fused flash kernel below; ``"auto"`` is splash on the
+  TPU and the einsum elsewhere (the CPU tests' reference).  A kernel the
+  compiler refuses is an error, never a quiet switch to a slower path.
+- ``"xla"`` is einsum, float32 softmax, einsum.  Its (B, H, S, S) score
+  tensor is pure HBM traffic (805 MB a layer for GPT-2 124M at S=1024), which
+  is what the kernel exists to avoid.
+- ``"ring"`` / ``"ulysses"`` are the context-parallel paths
+  (``ops/ring_attention.py``): attention runs seq-sharded over the ambient
+  mesh's `seq` axis (``jit_train_step(mesh=)`` installs the mesh).
+
+q is (B, S, H, head_dim) and k, v are (B, S, KV, head_dim) with H a multiple
+of KV: grouped-query attention arrives at its own head count (KV == H is plain
+MHA).  The splash kernel takes K and V so: it reads the K/V head ``h // (H //
 KV)`` for query head ``h`` and sums dk and dv over the group in VMEM, so
 nothing copies K and V out to H heads in HBM, forward or backward (0.55 GB a
-layer for Mistral-7B at 8192 tokens, PERF.md PR 27).  The repeat lives with
-the paths that still need equal head counts, the XLA einsum and ring/ulysses
-(``models/gpt2.py:_attention_impl``), which decides from the implementation it
-is about to call and the shapes it holds.
+layer for Mistral-7B at 8192 tokens, PERF.md PR 27).  The other three paths
+want equal head counts, and the dispatcher repeats K and V for them, deciding
+from the implementation it is about to call and the shapes it holds.
+
+Splash keeps scores in VMEM tiles, never materializes them, and skips the
+blocks the causal mask empties.  head_dim=64 compiles unpadded under the
+512x512 blocks on the v5e and agrees with the einsum (chip_smoke.py, kernel
+phase).
 """
 
 from __future__ import annotations
@@ -23,6 +35,9 @@ import math
 from typing import Optional
 
 import jax
+import jax.numpy as jnp
+
+ATTN_IMPLS = ("auto", "xla", "splash", "ring", "ulysses")
 
 #: The name the kernel's forward gives (``jax.ad_checkpoint.checkpoint_name``)
 #: to the two arrays its backward kernels read besides q, k and v: the
@@ -46,8 +61,42 @@ save_splash_residuals = jax.checkpoint_policies.save_only_these_names(
     SPLASH_RESIDUALS)
 
 
+def causal_attention(q, k, v, impl: str):
+    """Causal attention by the implementation ``impl`` names (a model's
+    ``attn_impl``; see the module docstring).  q: (B, S, H, head_dim); k, v:
+    (B, S, KV, head_dim), H a multiple of KV.  -> (B, S, H, head_dim)."""
+    if impl not in ATTN_IMPLS:
+        raise ValueError(
+            f"Unknown attn_impl: {impl!r} (use {'|'.join(ATTN_IMPLS)})")
+    with jax.named_scope("attn_kernel"):
+        if impl == "splash" or (impl == "auto"
+                                and jax.default_backend() == "tpu"):
+            return splash_attention(q, k, v, causal=True)
+        if k.shape[2] != q.shape[2]:
+            # Each K/V head serves a group of consecutive query heads.
+            group = q.shape[2] // k.shape[2]
+            k = jnp.repeat(k, group, axis=2)
+            v = jnp.repeat(v, group, axis=2)
+        if impl == "ring":
+            from ray_tpu.ops.ring_attention import ring_attention
+
+            return ring_attention(q, k, v, causal=True)
+        if impl == "ulysses":
+            from ray_tpu.ops.ring_attention import ulysses_attention
+
+            return ulysses_attention(q, k, v, causal=True)
+        scale = 1.0 / math.sqrt(q.shape[-1])
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) \
+            * scale
+        S = q.shape[1]
+        mask = jnp.tril(jnp.ones((S, S), jnp.bool_))
+        scores = jnp.where(mask, scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+        return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
 def _splash_kernel(seq_len: int, n_heads: int, block_q: int, block_kv: int,
-                   fused_bwd: bool, causal: bool = True):
+                   causal: bool):
     # NOT cached: the kernel object built during one jit trace captures that
     # trace's context — reusing it from a later trace raises
     # UnexpectedTracerError.  Construction is cheap (lazy mask, no arrays).
@@ -65,9 +114,7 @@ def _splash_kernel(seq_len: int, n_heads: int, block_q: int, block_kv: int,
     bs = sk.BlockSizes(
         block_q=bq, block_kv=bkv, block_kv_compute=bkv,
         block_q_dkv=bq, block_kv_dkv=bkv, block_kv_dkv_compute=bkv,
-        block_q_dq=None if fused_bwd else bq,
-        block_kv_dq=None if fused_bwd else bkv,
-        use_fused_bwd_kernel=fused_bwd,
+        use_fused_bwd_kernel=True,
     )
     return sk.make_splash_mha(mask, head_shards=1, q_seq_shards=1,
                               block_sizes=bs, interpret=interpret,
@@ -76,8 +123,7 @@ def _splash_kernel(seq_len: int, n_heads: int, block_q: int, block_kv: int,
 
 def splash_attention(q, k, v, causal: bool = True,
                      sm_scale: Optional[float] = None,
-                     block_q: int = 512, block_kv: int = 512,
-                     fused_bwd: bool = True):
+                     block_q: int = 512, block_kv: int = 512):
     """Production TPU attention (splash kernel): sparse over the causal
     mask when causal (no wasted upper-triangle work), full-mask
     bidirectional (ViT-style) otherwise, with a fused dq/dkv backward.
@@ -100,8 +146,7 @@ def splash_attention(q, k, v, causal: bool = True,
         sm_scale = 1.0 / math.sqrt(hd)
 
     def local(q, k, v):
-        kernel = _splash_kernel(S, q.shape[2], block_q, block_kv, fused_bwd,
-                                causal)
+        kernel = _splash_kernel(S, q.shape[2], block_q, block_kv, causal)
         # Splash takes (H, S, hd) per example; scale q up front (no scale arg).
         qt = (q * sm_scale).transpose(0, 2, 1, 3)
         kt = k.transpose(0, 2, 1, 3)

@@ -28,6 +28,7 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 
 from ray_tpu.parallel.mesh import pytree_sharding
 from ray_tpu.util import device_telemetry, tracing
@@ -56,6 +57,45 @@ def create_sharded_state(
                     device_telemetry.compile_label("init_opt_state"):
                 opt_state = jax.jit(optimizer.init)(params)
     return params, opt_state
+
+
+def make_optimizer(learning_rate=3e-4, weight_decay=0.1, b1=0.9, b2=0.95,
+                   grad_clip=1.0):
+    """AdamW behind a global-norm clip, the decoders' optimizer.  The first
+    moment is stored in bf16: the momentum is noise-tolerant (unlike nu,
+    which stays fp32) and halving its HBM read+write is worth ~+0.8 MFU on
+    v5e (r5 sweep on GPT-2 124M: 47.5 -> 48.2; 13-step loss 9.562 vs
+    9.565)."""
+    import optax
+
+    return optax.chain(
+        optax.clip_by_global_norm(grad_clip),
+        optax.adamw(learning_rate, b1=b1, b2=b2, weight_decay=weight_decay,
+                    mu_dtype=jnp.bfloat16),
+    )
+
+
+def make_train_step(loss_fn, optimizer):
+    """Pure (params, opt_state, tokens, targets) -> (params, opt_state, loss)
+    for a decoder's ``loss_fn(params, tokens, targets)``.
+
+    Under jit with sharded inputs this is the whole distributed step: XLA
+    derives the gradient psum/reduce-scatter from the shardings — there is no
+    hand-written gradient sync (the DDP allreduce of the reference's
+    _TorchBackend lives inside the compiled program here).  The update runs
+    under the ``optimizer`` scope, which :func:`classify_op_name` reads as
+    the step's ``update`` phase.
+    """
+    import optax
+
+    def step(params, opt_state, tokens, targets):
+        loss, grads = jax.value_and_grad(loss_fn)(params, tokens, targets)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
+        return params, opt_state, loss
+
+    return step
 
 
 def jit_train_step(step_fn, donate_state: bool = True, mesh=None):

@@ -29,7 +29,7 @@ Config (``analysis.cfg`` at the repo root, INI)::
 
     [analyze]
     exclude =
-        scripts/mfu_probe*.py
+        scripts/some_probe*.py
 
 Excludes are fnmatch patterns against '/'-separated relative paths (or
 bare file names).

@@ -13,9 +13,8 @@ Bottom-up:
 * the transfer ledger + windowed ``transfer_bw`` accessor and the
   ``device_put_batch`` h2d hook,
 * the compile listener on REAL jitted functions (jax's monitoring events
-  under a ``compile_label``) — including ``scripts/mfu_probe.py --mode
-  step`` end-to-end on a GPT-2 ``TrainStep`` (exactly one first-compile,
-  zero recompiles); the ``TrainStep`` cases proper are in
+  under a ``compile_label``); the ``TrainStep`` cases (exactly one
+  first-compile, zero recompiles over the steps that follow) are in
   ``tests/test_step_names.py``,
 * snapshot/bundle embedding, the ``device_telemetry_snapshot`` fault
   point absorption, collector rollup, the Perfetto "device" lane, and
@@ -26,8 +25,6 @@ Bottom-up:
 
 import json
 import os
-import subprocess
-import sys
 import time
 
 import numpy as np
@@ -340,18 +337,6 @@ class TestCompileListener:
             == 1.25
         dt.reset()
         assert dt.program("unit_prog") is None and dt.first_calls() == []
-
-    def test_mfu_probe_step_mode_end_to_end(self):
-        """scripts/mfu_probe.py --mode step on a real GPT-2 train step:
-        exactly one first-compile through the tap, zero recompiles."""
-        probe = os.path.join(REPO, "scripts", "mfu_probe.py")
-        env = dict(os.environ, JAX_PLATFORMS="cpu")
-        proc = subprocess.run(
-            [sys.executable, probe, "--mode", "step", "--config", "tiny",
-             "--steps", "2", "--batch-per-chip", "2"],
-            capture_output=True, text=True, timeout=300, env=env)
-        assert proc.returncode == 0, proc.stderr
-        assert "xla compiles: 1 (first_compile)" in proc.stdout, proc.stdout
 
 
 # --------------------------------------------------------------------------

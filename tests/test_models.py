@@ -125,34 +125,80 @@ def test_graft_entry_dryrun():
     __graft_entry__.dryrun_multichip(8)
 
 
-def test_attn_outside_and_unrolled_match_scan_save_attn():
-    """remat_policy='attn_outside' (split-block checkpointing, the r3 MFU
-    win) and scan_layers=False (unrolled layers) are pure schedule changes:
-    loss and grads must match the save_attn scan path exactly."""
-    import jax
-    import jax.numpy as jnp
-
-    from ray_tpu.models import gpt2
+@pytest.mark.parametrize("kw", [
+    {"remat_policy": "attn_outside"},
+    {"remat_policy": "attn_outside", "scan_layers": False},
+    {"scan_layers": False},
+    {"remat": False},
+], ids=lambda kw: ",".join(f"{k}={v}" for k, v in kw.items()))
+def test_attn_outside_unrolled_and_no_remat_match_scan_block(kw):
+    """remat_policy='attn_outside' (the two halves of a block checkpointed
+    around attention), scan_layers=False (the Python loop over the layers)
+    and remat=False are pure schedule changes: loss and grads must match
+    the default, remat_policy='block' under lax.scan."""
+    import dataclasses
 
     base = gpt2.GPTConfig.tiny()
+    assert base.remat and base.remat_policy == "block" and base.scan_layers
     key = jax.random.PRNGKey(0)
     params = gpt2.init_params(base, key)
     tok = jax.random.randint(key, (2, base.seq_len), 0, base.vocab_size)
     tgt = jax.random.randint(key, (2, base.seq_len), 0, base.vocab_size)
 
     ref_l, ref_g = jax.value_and_grad(gpt2.loss_fn)(params, tok, tgt, base)
+    cfg = dataclasses.replace(base, **kw)
+    loss, grads = jax.value_and_grad(gpt2.loss_fn)(params, tok, tgt, cfg)
+    assert abs(float(loss) - float(ref_l)) < 1e-5
+    err = max(jax.tree_util.tree_leaves(jax.tree_util.tree_map(
+        lambda a, b: float(jnp.max(jnp.abs(a - b))), grads, ref_g)))
+    # bf16 activations quantize grads to ~2^-10 ULPs at these magnitudes
+    # and the schedules reorder bf16 reductions (9.8e-4 observed on
+    # installed jax); fp32 would hold the original 1e-4.
+    assert err < 2e-3, err
+
+
+def test_remat_policy_is_one_of_two(tiny):
     import dataclasses
 
-    for kw in ({"remat_policy": "attn_outside"},
-               {"remat_policy": "attn_outside", "scan_layers": False},
-               {"scan_layers": False}):  # unrolled save_attn path
-        cfg = dataclasses.replace(base, **kw)
-        loss, grads = jax.value_and_grad(gpt2.loss_fn)(params, tok, tgt, cfg)
-        assert abs(float(loss) - float(ref_l)) < 1e-5, kw
-        err = max(jax.tree_util.tree_leaves(jax.tree_util.tree_map(
-            lambda a, b: float(jnp.max(jnp.abs(a - b))), grads, ref_g)))
-        # bf16 activations quantize grads to ~2^-10 ULPs at these
-        # magnitudes and the schedules reorder bf16 reductions (9.8e-4
-        # observed on installed jax); fp32 would hold the original 1e-4.
-        tol = 1e-4 if base.dtype == jnp.float32 else 2e-3
-        assert err < tol, (kw, err)
+    config = dataclasses.replace(tiny, remat_policy="dots")
+    assert gpt2.REMAT_POLICIES == ("block", "attn_outside")
+    with pytest.raises(ValueError, match="'block' or 'attn_outside'"):
+        gpt2.forward_hidden(gpt2.init_params(config, jax.random.key(0)),
+                            jnp.zeros((1, config.seq_len), jnp.int32), config)
+
+
+@pytest.mark.parametrize("package,forbidden", [
+    ("models", ("ray_tpu.models.gpt2", "ray_tpu.models.llama")),
+    ("ops", ("ray_tpu.models",)),
+])
+def test_imports_point_down(package, forbidden):
+    """No model file imports another decoder (llama -> moe is the one arrow
+    inside the package), and nothing under ops/ reaches up into models/."""
+    import ast
+    import os
+
+    root = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "ray_tpu", package)
+    seen = 0
+    for name in sorted(os.listdir(root)):
+        if not name.endswith(".py") or name == "__init__.py":
+            continue
+        seen += 1
+        with open(os.path.join(root, name)) as f:
+            tree = ast.parse(f.read())
+        imported = set()
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Import):
+                imported.update(a.name for a in node.names)
+            elif isinstance(node, ast.ImportFrom):
+                here = ["ray_tpu", package][:3 - node.level] \
+                    if node.level else []
+                module = ".".join(here + ([node.module] if node.module
+                                          else []))
+                imported.add(module)
+                imported.update(f"{module}.{a.name}" for a in node.names)
+        bad = sorted(m for m in imported
+                     if any(m == f or m.startswith(f + ".")
+                            for f in forbidden))
+        assert not bad, f"ray_tpu/{package}/{name} imports {bad}"
+    assert seen >= 4

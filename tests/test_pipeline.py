@@ -63,14 +63,18 @@ def test_pipeline_gradients_match(pipe_mesh):
                                rtol=2e-4, atol=2e-4)
 
 
-def test_gpt2_pipelined_forward_matches_unpipelined():
+@pytest.mark.parametrize("scan_layers", [True, False])
+def test_gpt2_pipelined_forward_matches_unpipelined(scan_layers):
+    """A stage runs its slice of the layers as the model runs all of them:
+    under lax.scan or, with scan_layers=False, the Python loop."""
     mesh = make_mesh(MeshSpec(pipe=2, data=2, tensor=2))
     base = gpt2.GPTConfig(vocab_size=512, n_layer=4, n_head=4, d_model=64,
                           seq_len=32, dtype=jnp.float32, remat=False,
                           attn_impl="xla")
     pp = gpt2.GPTConfig(vocab_size=512, n_layer=4, n_head=4, d_model=64,
                         seq_len=32, dtype=jnp.float32, remat=False,
-                        attn_impl="xla", pp_stages=2, pp_microbatches=2)
+                        attn_impl="xla", pp_stages=2, pp_microbatches=2,
+                        scan_layers=scan_layers)
     params = gpt2.init_params(base, jax.random.key(0))
     tokens = jnp.asarray(
         np.random.default_rng(0).integers(0, 512, (4, 32)), jnp.int32)
