@@ -10,15 +10,26 @@ where the model's ``norm_topk_prob`` says so).  Then, with N tokens:
 
 1. the N x k (token, slot) pairs are sorted by expert (stable) and the E group
    sizes counted;
-2. the pairs' rows are gathered into that order, (N x k, D);
+2. the pairs' rows are gathered into that order, (N x k, D), and their
+   combine weights brought into it, (N x k,);
 3. three grouped matmuls over the ragged groups (``ops/grouped_matmul.py``):
-   gate and up, ``silu(gate) * up``, down;
-4. the rows go back to token order and each token sums its k rows times their
-   weights.
+   gate and up, ``silu(gate) * up * weight`` in one float32 pass, down.  The
+   weight multiplies before the down-projection, not after it: the same sum
+   reassociated, ``w (h W) = (w h) W``;
+4. the weighted rows go back to token order and each token sums its k rows in
+   float32.
 
 Static shapes throughout (exactly N x k rows), no capacity and no pair
-dropped.  Both gathers are permutations with hand-written transposes (the
-inverse permutation), so neither direction holds a scatter-add.
+dropped.  The dispatch (2) and the combine (4) are each other's transposes,
+one a row gather by ``order // k`` and the other a gather by ``inverse``
+summed over k, and each is written as the other's backward (``custom_vjp``):
+neither direction holds a scatter-add, both save the two index vectors and
+nothing else, and both row gathers by ``order // k`` read an (N, D) source.
+The weights' permutation and its transpose are sorts.  Because the weights
+meet the rows in expert order, nothing in the backward reads the
+down-projection's output: the weights' gradient is a row sum inside the
+activation's backward pass, and under ``jax.checkpoint`` the down-projection
+and the combine are not run a second time.
 
 Two router losses come back with the output, for ``llama.loss_fn`` to weigh
 (:func:`router_losses`).
@@ -102,33 +113,49 @@ def _to_expert_order(x, order, inverse):
     return x[order // inverse.shape[1]]
 
 
-def _to_expert_order_fwd(x, order, inverse):
-    return _to_expert_order(x, order, inverse), inverse
+@jax.custom_vjp
+def _combine(rows, order, inverse):
+    """rows: (N x k, D) in expert order, already weighted -> (N, D), each
+    token the float32 sum of its k rows."""
+    return jnp.sum(rows[inverse].astype(jnp.float32),
+                   axis=1).astype(rows.dtype)
 
 
-def _to_expert_order_bwd(inverse, g):
-    dx = jnp.sum(g[inverse].astype(jnp.float32), axis=1).astype(g.dtype)
-    return dx, None, None
-
-
-_to_expert_order.defvjp(_to_expert_order_fwd, _to_expert_order_bwd)
+# The two are each other's transposes, so each is the other's backward.
+_to_expert_order.defvjp(
+    lambda x, order, inverse: (_to_expert_order(x, order, inverse),
+                               (order, inverse)),
+    lambda saved, g: (_combine(g, *saved), None, None))
+_combine.defvjp(
+    lambda rows, order, inverse: (_combine(rows, order, inverse),
+                                  (order, inverse)),
+    lambda saved, g: (_to_expert_order(g, *saved), None, None))
 
 
 @jax.custom_vjp
-def _to_token_order(rows, order, inverse):
-    """rows: (N x k, D) in expert order -> (N, k, D)."""
-    return rows[inverse]
+def _weights_to_expert_order(weights, order, inverse):
+    """weights: (N, k) -> (N x k,), entry j the weight of pair ``order[j]``.
+    Each direction applies its permutation as a sort keyed by the other one:
+    0.06 ms for the cell's 65,536 scalars on a v5e, where the gather
+    ``weights.reshape(-1)[order]`` and its transpose, as a gather by
+    ``inverse`` or as a scatter-add, take 0.47-0.57 each (``PERF.md``,
+    PR 29)."""
+    return lax.sort((inverse.reshape(-1), weights.reshape(-1)),
+                    num_keys=1)[1]
 
 
-def _to_token_order_fwd(rows, order, inverse):
-    return rows[inverse], order
+def _weights_to_expert_order_fwd(weights, order, inverse):
+    return _weights_to_expert_order(weights, order, inverse), (order, inverse)
 
 
-def _to_token_order_bwd(order, g):
-    return g.reshape(order.shape[0], g.shape[-1])[order], None, None
+def _weights_to_expert_order_bwd(saved, g):
+    order, inverse = saved
+    return (lax.sort((order, g), num_keys=1)[1].reshape(inverse.shape),
+            None, None)
 
 
-_to_token_order.defvjp(_to_token_order_fwd, _to_token_order_bwd)
+_weights_to_expert_order.defvjp(_weights_to_expert_order_fwd,
+                                _weights_to_expert_order_bwd)
 
 
 def expert_mlp(x, weights, experts, w_gate, w_up, w_down):
@@ -138,15 +165,16 @@ def expert_mlp(x, weights, experts, w_gate, w_up, w_down):
     with jax.named_scope("moe_dispatch"):
         order, inverse, group_sizes = sort_pairs(experts, w_gate.shape[0])
         rows = _to_expert_order(x, order, inverse)
+        w_rows = _weights_to_expert_order(weights, order, inverse)
     with jax.named_scope("experts"):
         gate = grouped_matmul(rows, w_gate, group_sizes)
         up = grouped_matmul(rows, w_up, group_sizes)
         act = (jax.nn.silu(gate.astype(jnp.float32))
-               * up.astype(jnp.float32)).astype(x.dtype)
+               * up.astype(jnp.float32)
+               * w_rows[:, None].astype(jnp.float32)).astype(x.dtype)
         out = grouped_matmul(act, w_down, group_sizes)
     with jax.named_scope("moe_dispatch"):
-        out = _to_token_order(out, order, inverse).astype(jnp.float32)
-        return jnp.sum(out * weights[..., None], axis=1).astype(x.dtype)
+        return _combine(out, order, inverse)
 
 
 def moe_mlp(h32, blk, *, experts_per_token: int, norm_topk_prob: bool, dtype):

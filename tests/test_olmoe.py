@@ -115,8 +115,37 @@ def _with_bias(x, router, bias):
             jnp.concatenate([router, bias[None, :]], axis=0))
 
 
-@pytest.mark.parametrize("n_experts,k", [(8, 2), (64, 8)])
-def test_expert_layer_matches_a_per_token_loop(n_experts, k):
+def _per_token_loop(x, weights, experts, blk, dy):
+    """The layer and the gradients of ``sum(y * dy)``, one (token, slot) pair
+    at a time in float64, derived by hand: no sort, no autodiff."""
+    x, weights, dy = (np.asarray(a, np.float64) for a in (x, weights, dy))
+    w_gate, w_up, w_down = (np.asarray(blk[name], np.float64)
+                            for name in ("w_gate", "w_up", "w_down"))
+    y = np.zeros_like(x)
+    grads = {"x": np.zeros_like(x), "weights": np.zeros_like(weights),
+             "w_gate": np.zeros_like(w_gate), "w_up": np.zeros_like(w_up),
+             "w_down": np.zeros_like(w_down)}
+    for t in range(x.shape[0]):
+        for slot in range(weights.shape[1]):
+            e, w = int(experts[t, slot]), weights[t, slot]
+            g, u = x[t] @ w_gate[e], x[t] @ w_up[e]
+            sig = 1.0 / (1.0 + np.exp(-g))
+            h = g * sig * u
+            out = h @ w_down[e]
+            y[t] += w * out
+            grads["weights"][t, slot] = out @ dy[t]
+            grads["w_down"][e] += w * np.outer(h, dy[t])
+            dh = w * (w_down[e] @ dy[t])
+            dg, du = dh * u * sig * (1.0 + g * (1.0 - sig)), dh * g * sig
+            grads["w_gate"][e] += np.outer(x[t], dg)
+            grads["w_up"][e] += np.outer(x[t], du)
+            grads["x"][t] += w_gate[e] @ dg + w_up[e] @ du
+    return y, grads
+
+
+def _skewed_layer(n_experts, k):
+    """-> x, weights, experts, blk: expert 0 empty, the last with every
+    token, and one combine weight exactly 0.0."""
     x, blk, bias = _layer_inputs(n_experts, k)
     x1, router1 = _with_bias(x, blk["router"], bias)
     weights, experts, _ = moe.route(x1, router1, k, False)
@@ -124,20 +153,78 @@ def test_expert_layer_matches_a_per_token_loop(n_experts, k):
     sizes = np.asarray(group_sizes)
     assert sizes[0] == 0 and sizes[-1] == x.shape[0] == sizes.max()
     assert sizes.sum() == x.shape[0] * k
+    return x, weights.at[5, 1].set(0.0), experts, blk
 
+
+@pytest.mark.parametrize("n_experts,k", [(8, 2), (64, 8)])
+def test_expert_layer_matches_a_per_token_loop(n_experts, k):
+    x, weights, experts, blk = _skewed_layer(n_experts, k)
     got = np.asarray(moe.expert_mlp(x, weights, experts, blk["w_gate"],
                                     blk["w_up"], blk["w_down"]))
-    x64 = np.asarray(x, np.float64)
-    want = np.zeros_like(x64)
-    for t in range(x.shape[0]):
-        for slot in range(k):
-            e = int(experts[t, slot])
-            g = x64[t] @ np.asarray(blk["w_gate"][e], np.float64)
-            u = x64[t] @ np.asarray(blk["w_up"][e], np.float64)
-            h = g / (1.0 + np.exp(-g)) * u
-            want[t] += float(weights[t, slot]) \
-                * (h @ np.asarray(blk["w_down"][e], np.float64))
+    want, _ = _per_token_loop(x, weights, experts, blk, np.zeros_like(x))
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("n_experts,k", [(8, 2), (64, 8)])
+def test_expert_layer_gradients_match_a_per_token_loop(n_experts, k):
+    x, weights, experts, blk = _skewed_layer(n_experts, k)
+    dy = jax.random.normal(jax.random.key(7), x.shape)
+    got = jax.grad(
+        lambda x, weights, w_gate, w_up, w_down: jnp.sum(dy * moe.expert_mlp(
+            x, weights, experts, w_gate, w_up, w_down)),
+        argnums=(0, 1, 2, 3, 4))(x, weights, blk["w_gate"], blk["w_up"],
+                                 blk["w_down"])
+    _, want = _per_token_loop(x, weights, experts, blk, dy)
+    for name, g in zip(("x", "weights", "w_gate", "w_up", "w_down"), got):
+        assert np.all(np.isfinite(g)), name
+        assert _rel_err(g, want[name]) <= 1e-5, name
+    # the zero weight's pair gives its expert's matrices and its token's
+    # input nothing, and still has a gradient of its own: <out, dy>
+    assert want["weights"][5, 1] != 0.0
+    # the empty expert's matrices get exactly zero
+    assert all(not np.any(np.asarray(g[0])) for g in got[2:])
+
+
+def _equations(jaxpr, path=()):
+    """Every equation, nested ones too, with the primitives it lies under."""
+    for eqn in jaxpr.eqns:
+        yield path, eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub, path + (eqn.primitive.name,))
+
+
+def test_remat_recomputes_neither_the_down_projection_nor_the_combine():
+    """The guard against the combine weights drifting back into token order:
+    then their gradient would read the down-projection's output, and remat
+    would run that product and the combine's gather a second time."""
+    n_experts, k = 8, 2
+    x, weights, experts, blk = _skewed_layer(n_experts, k)
+    n, d = x.shape
+    layer = jax.checkpoint(lambda *a: moe.expert_mlp(a[0], a[1], experts,
+                                                     *a[2:]))
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(layer(*a) ** 2), argnums=(0, 1, 2, 3, 4)))(
+            x, weights, blk["w_gate"], blk["w_up"], blk["w_down"])
+    found = list(_equations(jaxpr.jaxpr))
+    kernels = [path for path, e in found if e.primitive.name == "pallas_call"]
+    # 3 forward; under remat 2 recomputed (gate, up), 3 dx, 3 dW
+    assert len(kernels) == 11
+    assert sum("remat2" in path for path in kernels) == 8
+
+    def row_gathers(under):
+        return sorted(e.outvars[0].aval.shape for path, e in found
+                      if e.primitive.name == "gather" and (
+                          "remat2" in path) == under
+                      and e.outvars[0].aval.shape[-1] == d)
+    # forward: the dispatch, the combine
+    assert row_gathers(False) == [(n, k, d), (n * k, d)]
+    # backward: the recomputed dispatch and the combine's transpose, both
+    # reading an (N, D) source; of the (N, k, D) kind only the dispatch's
+    # transpose, no recomputed combine
+    assert row_gathers(True) == [(n, k, d), (n * k, d), (n * k, d)]
+    assert not any(e.primitive.name.startswith("scatter")
+                   and e.outvars[0].aval.shape[0] in (n, n * k)
+                   for _, e in found)
 
 
 # ----------------------------------------------------------- (c) dropless
@@ -318,14 +405,8 @@ def test_without_experts_the_program_has_no_trace_of_them():
     jaxpr = jax.make_jaxpr(
         lambda p, t: llama.loss_fn(p, t, t, config))(params, tokens)
 
-    def equations(jaxpr):
-        for eqn in jaxpr.eqns:
-            yield eqn
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                yield from equations(sub)
-
     def names(jaxpr):
-        return [e.primitive.name for e in equations(jaxpr.jaxpr)]
+        return [e.primitive.name for _, e in _equations(jaxpr.jaxpr)]
 
     dense = names(jaxpr)
     for primitive in ("sort", "top_k", "pallas_call", "shard_map"):
