@@ -24,8 +24,8 @@ from typing import Dict
 
 import numpy as np
 
-from benchmarks.lib import correct, spec, traffic as traffic_lib
-from benchmarks.lib import trace_reduce
+from benchmarks.lib import correct, spec, state, traffic as traffic_lib
+from benchmarks.lib import anatomy, trace_reduce
 from benchmarks.lib.compile_watch import CompileWatch, hlo_report
 from benchmarks.lib.peaks import peaks_for
 from benchmarks.lib.record import RunRecord
@@ -128,7 +128,11 @@ def run(cell: Dict, args, t_process: float) -> Dict:
     def train_loop():
         mark("train_loop_entered")
         mesh = make_mesh(MeshSpec(**mesh_axes), devices)
-        optimizer = family.make_optimizer()
+        expected = pytree_sharding(family.logical_axes, mesh)
+        inner = family.make_optimizer()
+        optimizer = state.born_sharded(inner, expected)
+        out["state_sharded_by"] = "the program" if optimizer is inner else \
+            "the harness (benchmarks/lib/state.py), not yet the program"
         t0 = time.perf_counter()
         params, opt_state = create_sharded_state(
             family.init_fn, family.logical_axes, mesh,
@@ -225,7 +229,6 @@ def run(cell: Dict, args, t_process: float) -> Dict:
         out["losses"] = [float(x) for x in jax.device_get(losses)]
 
         # ------------------------------------- outside the window: correct
-        expected = pytree_sharding(family.logical_axes, mesh)
         out["placement"] = correct.placement(
             params, opt_state, batch["tokens"], expected, seqs_per_chip,
             chips)
@@ -241,7 +244,11 @@ def run(cell: Dict, args, t_process: float) -> Dict:
                 compiled = jax.jit(
                     family.make_train_step(optimizer), donate_argnums=(0, 1)
                 ).lower(*first_args).compile()
-            record.hlo = hlo_report(compiled.as_text())
+            text = compiled.as_text()
+            record.hlo = hlo_report(text)
+            names = anatomy.of_text(text)
+            if names is not None:
+                record.anatomy = names
             memory = compiled.memory_analysis()
             record.step_memory = {
                 k: getattr(memory, k + "_size_in_bytes")
@@ -251,8 +258,13 @@ def run(cell: Dict, args, t_process: float) -> Dict:
         rows = gen.check_rows(n_check)
         tokens, targets = (jax.device_put(a, batch_sharding(mesh))
                            for a in (rows[:, :-1], rows[:, 1:]))
+        limits = config.get("check", {})
         out["reference"] = correct.compare(family, params, tokens, targets,
-                                           mesh)
+                                           mesh, limits.get("loss_tol"))
+        if "seed_grad_tol" in limits:
+            del params  # room for the seed's
+            out["reference_at_seed"] = correct.at_the_seed(
+                family, mesh, args.seed, rows, limits["seed_grad_tol"])
 
     mark("imports_done")
     if platform == "tpu":
@@ -301,13 +313,31 @@ def finish(cell, args, record: RunRecord, out: Dict, gen, devices) -> Dict:
     failed += 1 if "raised" in out else 0
     fall = float(losses[0] - np.mean(losses[-5:])) if finite else float("nan")
     checks = {
-        "reference": bool(out.get("reference", {}).get("ok")),
+        "reference": bool(out.get("reference", {}).get("ok")
+                          and out.get("reference_at_seed", {"ok": 1})["ok"]),
         "losses_finite": finite,
         "loss_fell": bool(finite and (args.rehearse
                                       or fall >= traffic["min_loss_fall"])),
         "placement": bool(out.get("placement", {}).get("ok")),
         "no_failed_step": failed == 0,
     }
+
+    ref, at_seed = out.get("reference"), out.get("reference_at_seed")
+    if ref:  # every number compared, beside its limit
+        log(f"correct: loss error {ref['loss_err']:.3g} at S={ref['seq_len']}"
+            f" and {ref['grad_loss_err']:.3g} at S={ref['grad_seq_len']} "
+            f"(limit {ref['loss_tol']:g}), largest gradient-leaf error "
+            f"{ref['grad_err_max']:.3g} (limit {ref['grad_tol']:g})"
+            + (f"; at the seed's parameters the leaves' median "
+               f"{at_seed['grad_norm_err_median']:.3g} (limit "
+               f"{at_seed['seed_grad_tol']:g}) and largest "
+               f"{at_seed['grad_norm_err_max']:.3g} (limit "
+               f"{at_seed['leaf_tol']:g})" if at_seed else "")
+            + f"; loss fell {fall:.3g} (at least "
+            f"{traffic['min_loss_fall']:g}), placement "
+            f"{out.get('placement', {}).get('wrong') or 'ok'} (state "
+            f"sharded by {out.get('state_sharded_by')}), failed steps "
+            f"{failed} (limit 0)")
 
     rate = record.steps * record.tokens_per_step / record.window_s \
         / record.chips if record.window_s else 0.0
@@ -358,7 +388,10 @@ def finish(cell, args, record: RunRecord, out: Dict, gen, devices) -> Dict:
         "checks": checks, "loss_first": float(losses[0]) if finite else None,
         "loss_last5": float(np.mean(losses[-5:])) if finite else None,
         "loss_fall": fall, "losses": [float(x) for x in losses],
-        "reference": out.get("reference"), "placement": out.get("placement"),
+        "state_sharded_by": out.get("state_sharded_by"),
+        "reference": out.get("reference"),
+        "reference_at_seed": out.get("reference_at_seed"),
+        "placement": out.get("placement"),
         "raised": out.get("raised"), "steps": record.steps,
         "setup_marks_s": out.get("marks"),
         "window_s": record.window_s, "init_state_s": record.init_state_s,

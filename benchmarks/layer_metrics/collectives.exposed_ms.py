@@ -1,9 +1,12 @@
 """Per step, the time a collective instruction runs on a chip while no other
 instruction does there; the worst chip, over the trace's steady stretch.
 Collective are the instructions named as one (``all-gather``,
-``async-collective-done``, ...) and the fusions that wrap one
-(``calls=%all-reduce-scatter``, ``calls=%async_collective_fusion``; the second
-kind overlaps its own compute with its transfer and counts whole)."""
+``async-collective-start`` / ``-done``, ...) and the fusions that are one and
+nothing else (``kind=kCustom, calls=%all-reduce-scatter``).  A fusion of
+arithmetic that carries an asynchronous collective along
+(``calls=%async_collective_fusion``) is not: the transfer runs in its shadow,
+and what of it outlasts the arithmetic shows in ``collectives.carrier_ms``,
+not here.  So this is the time that is certainly exposed, a lower figure."""
 from benchmarks.lib import trace_reduce
 
 LAYER, UNIT, SOURCE, MOVES = "collectives", "ms/step", "device_trace", \

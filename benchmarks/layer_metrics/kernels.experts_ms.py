@@ -1,8 +1,9 @@
 """Per step, the summed device time of the grouped-matmul kernel's Mosaic
 calls on the first chip (the megablox ``gmm`` and ``tgmm``: per layer three
-forward products, their recomputation under remat, three dx and three dW), by
-the instruction names the compiled step and the trace share.  ``describe``
-gives the time by call."""
+forward products, the recomputation of two of them under remat, gate and up
+(the backward does not read the down-projection's output since PR 29), three
+dx and three dW: eleven calls), by the instruction names the compiled step
+and the trace share.  ``describe`` gives the time by call."""
 LAYER, UNIT, SOURCE, MOVES = "kernels", "ms/step", "device_trace", \
     "tokens_per_s_per_chip"
 

@@ -32,12 +32,26 @@ ATTN = ("attn", "attn_kernel")
 
 
 def step_anatomy(run) -> Optional[Dict[str, Key]]:
-    """``anatomy()`` of the program's train step, asked once per run however
-    many readers want it (it lowers and loads the step again); a test sets
-    ``run.anatomy`` itself."""
+    """What the run holds (``kinds/train.py`` sets ``run.anatomy`` from
+    :func:`of_text`, a test from a recording); else ``anatomy()`` of the
+    program's train step, asked once per run however many readers want it
+    (it lowers and loads the step again)."""
     if "anatomy" not in run.__dict__:
         run.anatomy = _ask_the_program()
     return run.anatomy
+
+
+def of_text(hlo_text: str) -> Optional[Dict[str, Key]]:
+    """The program's own ``parse_anatomy`` on the compiled text of the
+    signature that ran in the window.  ``TrainStep.anatomy()`` keeps the
+    first call's, and under a mesh the step's second call can compile anew
+    (on four virtual CPU devices its state comes back cut finer than it went
+    in): another program, with other names (PERF.md, section 7, PR 30)."""
+    module = sys.modules.get("ray_tpu.parallel.train_state")
+    parse = getattr(module, "parse_anatomy", None)
+    if parse is None:
+        return None
+    return {name: tuple(key) for name, key in parse(hlo_text).items()}
 
 
 def _ask_the_program() -> Optional[Dict[str, Key]]:

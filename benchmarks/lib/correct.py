@@ -27,11 +27,26 @@ tolerance is a little over twice the largest seen: it fails an error of the
 size of the leaf itself (a lost term, a wrong mask, a doubled gradient) and
 passes bfloat16.  A tighter gradient check needs a quieter comparison
 (PERF.md, Open questions).
+
+**A configuration may state its own** (its file's ``check``; PR 30, readings
+in PERF.md section 2).  The loss error grows with depth and with how sharp a
+model the window left: twelve layers read 1.6e-4 to 4.6e-4 where two read
+1e-6 to 1.6e-4, and a run that ended inside a loss spike 4.7e-3, so on such a
+configuration ``loss_tol`` guards the mathematics on the trained parameters
+and no longer the precision.  That is then the work of ``seed_grad_tol``: a
+second comparison of the gradients, on the parameters the seed gives
+(:func:`at_the_seed`), where a leaf's error ``|g - g_ref|_2 / |g_ref|_2``
+depends on the seed by a few percent; its limit is on the median over the
+leaves, and no leaf may read over ``LEAF_FACTOR`` times it.  A configuration
+that states nothing is compared as it always was.
 """
 
 from __future__ import annotations
 
-from typing import Dict
+import statistics
+from typing import Dict, Optional
+
+from benchmarks.lib import state
 
 LOSS_TOL = 1e-3
 GRAD_TOL = 0.75
@@ -40,40 +55,48 @@ GRAD_TOL = 0.75
 GRAD_SEQ = 1024
 #: Query block of the reference's forward-only attention at the cell's S.
 Q_BLOCK = 512
+#: at the seed no leaf may read more than this many times ``seed_grad_tol``
+LEAF_FACTOR = 3
 
 
-def compare(family, params, tokens, targets, mesh) -> Dict:
-    """``tokens``/``targets``: one sequence per batch shard, at the cell's S,
-    already placed with the mesh's batch sharding."""
+def value_and_grad(loss_fn, params):
+    """``jax.value_and_grad(loss_fn)`` jitted, with the gradients cut as the
+    parameters are.  Left to itself the compiler hands the program's
+    gradients back whole on every chip (10.75 GiB at 2885.8 M parameters on
+    four chips, compile-only, PR 30): nothing after them says otherwise."""
     import jax
+
+    return jax.jit(jax.value_and_grad(loss_fn), out_shardings=(
+        None, jax.tree.map(lambda p: p.sharding, params)))
+
+
+def _rel_err(a, b):
     import jax.numpy as jnp
 
-    def rel_err(a, b):
-        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
-        return jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b))
+    a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+    return jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b))
 
-    def reference(q_block):
-        return lambda p, t, y: family.reference_loss(p, t, y, q_block)
 
-    # Forward only, at the cell's own sequence length.
-    with jax.set_mesh(mesh):
-        system = jax.jit(family.loss_fn)(params, tokens, targets)
-    ref = jax.jit(reference(Q_BLOCK))(params, tokens, targets)
-    loss_err = float(rel_err(system, ref))
-    out = {"seq_len": int(tokens.shape[1]), "system_loss": float(system),
-           "reference_loss": float(ref), "loss_err": loss_err}
+def _norm_err(a, b):
+    import jax.numpy as jnp
 
-    # Loss and every gradient leaf, on the sequence cut short.
+    a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+    return jnp.sqrt(jnp.sum((a - b) ** 2) / jnp.sum(b ** 2))
+
+
+def gradients(family, params, tokens, targets, mesh) -> Dict:
+    """Loss and every gradient leaf, program against reference, on the
+    sequence cut to ``GRAD_SEQ``."""
+    import jax
+
     S = min(int(tokens.shape[1]), GRAD_SEQ)
     tokens, targets = tokens[:, :S], targets[:, :S]
     with jax.set_mesh(mesh):
-        sys_loss, sys_grads = jax.jit(jax.value_and_grad(family.loss_fn))(
+        sys_loss, sys_grads = value_and_grad(family.loss_fn, params)(
             params, tokens, targets)
-    ref_loss, ref_grads = jax.jit(jax.value_and_grad(reference(S)))(
+    ref_loss, ref_grads = value_and_grad(
+        lambda p, t, y: family.reference_loss(p, t, y, S), params)(
         params, tokens, targets)
-    def norm_err(a, b):
-        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
-        return jnp.sqrt(jnp.sum((a - b) ** 2) / jnp.sum(b ** 2))
 
     def by_leaf(fn):
         errs = jax.jit(lambda a, b: jax.tree.map(fn, a, b))(
@@ -81,17 +104,58 @@ def compare(family, params, tokens, targets, mesh) -> Dict:
         return {jax.tree_util.keystr(path): float(err) for path, err
                 in jax.tree_util.tree_flatten_with_path(errs)[0]}
 
-    leaves = by_leaf(rel_err)
-    out["grad_norm_err_by_leaf"] = by_leaf(norm_err)
-    out.update({
-        "grad_seq_len": S, "grad_system_loss": float(sys_loss),
-        "grad_reference_loss": float(ref_loss),
-        "grad_loss_err": float(rel_err(sys_loss, ref_loss)),
-        "grad_err_by_leaf": leaves, "grad_err_max": max(leaves.values()),
-        "loss_tol": LOSS_TOL, "grad_tol": GRAD_TOL})
-    out["ok"] = bool(out["loss_err"] <= LOSS_TOL
-                     and out["grad_loss_err"] <= LOSS_TOL
+    leaves, norms = by_leaf(_rel_err), by_leaf(_norm_err)
+    return {"grad_seq_len": S, "grad_system_loss": float(sys_loss),
+            "grad_reference_loss": float(ref_loss),
+            "grad_loss_err": float(_rel_err(sys_loss, ref_loss)),
+            "grad_err_by_leaf": leaves, "grad_err_max": max(leaves.values()),
+            "grad_norm_err_by_leaf": norms,
+            "grad_norm_err_max": max(norms.values()),
+            "grad_norm_err_median": statistics.median(norms.values())}
+
+
+def compare(family, params, tokens, targets, mesh,
+            loss_tol: Optional[float] = None) -> Dict:
+    """``tokens``/``targets``: one sequence per batch shard, at the cell's S,
+    already placed with the mesh's batch sharding.  ``loss_tol``: the
+    configuration's own, else ``LOSS_TOL``."""
+    import jax
+
+    # Forward only, at the cell's own sequence length.
+    with jax.set_mesh(mesh):
+        system = jax.jit(family.loss_fn)(params, tokens, targets)
+    ref = jax.jit(lambda p, t, y: family.reference_loss(p, t, y, Q_BLOCK))(
+        params, tokens, targets)
+    out = {"seq_len": int(tokens.shape[1]), "system_loss": float(system),
+           "reference_loss": float(ref),
+           "loss_err": float(_rel_err(system, ref))}
+    out.update(gradients(family, params, tokens, targets, mesh))
+    out.update(loss_tol=loss_tol or LOSS_TOL, grad_tol=GRAD_TOL)
+    out["ok"] = bool(out["loss_err"] <= out["loss_tol"]
+                     and out["grad_loss_err"] <= out["loss_tol"]
                      and out["grad_err_max"] <= GRAD_TOL)
+    return out
+
+
+def at_the_seed(family, mesh, seed: int, rows, seed_grad_tol: float) -> Dict:
+    """:func:`gradients` on the parameters the program's ``init_fn`` draws
+    from ``seed``, made anew on the mesh, and on ``rows`` (n x S+1 ids, one
+    row a batch shard): numbers that depend on the seed and on nothing a run
+    did."""
+    import jax
+
+    from ray_tpu.parallel import batch_sharding
+    from ray_tpu.parallel.train_state import create_sharded_state
+
+    params, _ = create_sharded_state(family.init_fn, family.logical_axes,
+                                     mesh, jax.random.key(seed))
+    tokens, targets = (jax.device_put(a, batch_sharding(mesh))
+                       for a in (rows[:, :-1], rows[:, 1:]))
+    out = gradients(family, params, tokens, targets, mesh)
+    out.update(seed_grad_tol=seed_grad_tol,
+               leaf_tol=LEAF_FACTOR * seed_grad_tol)
+    out["ok"] = bool(out["grad_norm_err_median"] <= seed_grad_tol
+                     and out["grad_norm_err_max"] <= out["leaf_tol"])
     return out
 
 
@@ -108,7 +172,6 @@ def placement(params, opt_state, batch, expected, seqs_per_chip: int,
     import numpy as np
 
     wrong, finer = [], []
-    treedef = jax.tree.structure(params)
 
     def check(tree, label):
         for (path, leaf), want in zip(
@@ -130,16 +193,8 @@ def placement(params, opt_state, batch, expected, seqs_per_chip: int,
                     wrong.append(f"{name}: {leaf.sharding.spec}, layout "
                                  f"says {want.spec}")
 
-    def mirrors(node):
-        """Sub-trees of the optimizer state shaped like the parameters."""
-        if jax.tree.structure(node) == treedef:
-            yield node
-        elif isinstance(node, (tuple, list)):
-            for child in node:
-                yield from mirrors(child)
-
     check(params, "params")
-    moments = list(mirrors(opt_state))
+    moments = state.mirrors(opt_state, params)
     for i, tree in enumerate(moments):
         check(tree, f"opt_state[{i}]")
     rows = sorted((s.device.id, s.data.shape[0])

@@ -21,17 +21,8 @@ def load_json(*parts: str) -> Dict:
         return json.load(f)
 
 
-def load_benchmark(with_pending: bool = False) -> Dict:
-    """``BENCHMARK.json``; ``with_pending`` adds the entries that wait in
-    ``benchmarks/pending/*.json`` for a repair of the program (tools and
-    tests look at those cells too; ``run.py`` never does)."""
-    bench = load_json(ROOT, "BENCHMARK.json")
-    if with_pending:
-        for name in sorted(os.listdir(os.path.join(BENCH_DIR, "pending"))):
-            waiting = load_json(BENCH_DIR, "pending", name)
-            for group in ("configs", "workloads", "per_layer"):
-                bench[group] = bench[group] + waiting[group]
-    return bench
+def load_benchmark() -> Dict:
+    return load_json(ROOT, "BENCHMARK.json")
 
 
 def load_module(directory: str, name: str):

@@ -34,8 +34,9 @@ COLLECTIVE = re.compile(
     r"^(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute"
     r"|async-collective)")
 COLLECTIVE_FUSION = re.compile(
-    r"calls=%?(all-reduce-scatter|async_collective_fusion|all-gather"
-    r"|all-reduce|reduce-scatter|all-to-all|collective-permute)")
+    r"calls=%?(all-reduce-scatter|all-gather|all-reduce|reduce-scatter"
+    r"|all-to-all|collective-permute)")
+CARRIER_FUSION = re.compile(r"calls=%?async_collective_fusion")
 
 
 @dataclass
@@ -45,9 +46,16 @@ class Event:
     dur: float
     #: an instruction's result type without layouts; else empty
     detail: str = ""
-    #: a fusion that wraps a collective (``calls=%all-reduce-scatter.5``,
-    #: how the TPU compiler emits a reduce-scatter); its name does not say so
+    #: a fusion that is a collective and nothing else (``kind=kCustom,
+    #: calls=%all-reduce-scatter.5``, how the TPU compiler emits a
+    #: reduce-scatter); its name does not say so
     collective: bool = False
+    #: a fusion of arithmetic (``kind=kOutput, calls=%async_collective_
+    #: fusion.599``: a matmul, as a rule) that carries an asynchronous
+    #: collective along between its ``-start`` and its ``-done``: the
+    #: transfer runs in its shadow, and the trace does not say which of the
+    #: two set its length
+    carrier: bool = False
 
     @property
     def end(self) -> float:
@@ -95,6 +103,7 @@ def load(path: str, span_prefix: str = "bench.") -> Trace:
                     parsed = INSTRUCTION.match(e.name)
                     if parsed:
                         e.collective = bool(COLLECTIVE_FUSION.search(e.name))
+                        e.carrier = bool(CARRIER_FUSION.search(e.name))
                         e.name = parsed.group(1)
                         e.detail = LAYOUT.sub("", parsed.group(2))[:60]
             if match:
@@ -234,11 +243,20 @@ def is_collective(event: Event) -> bool:
 def exposed_collective_seconds(ops: Sequence[Event], lo: float,
                                hi: float) -> float:
     """Time in the window during which a collective instruction runs on the
-    device and no other instruction does."""
+    device and no other instruction does.  A carrier is another instruction:
+    what it hides is hidden."""
     leaves, _ = leaves_and_self_times(in_window(ops, lo, hi))
     coll = merge(spans_of(e for e in leaves if is_collective(e)))
     compute = merge(spans_of(e for e in leaves if not is_collective(e)))
     return total(clip(subtract(coll, compute), lo, hi))
+
+
+def carrier_seconds(ops: Sequence[Event], lo: float, hi: float) -> float:
+    """Time in the window inside fusions that carry an asynchronous
+    collective beside their own arithmetic."""
+    leaves, _ = leaves_and_self_times(in_window(ops, lo, hi))
+    return total(clip(merge(spans_of(e for e in leaves if e.carrier)),
+                      lo, hi))
 
 
 def top_ops(ops: Sequence[Event], lo: float, hi: float, n: int = 10

@@ -14,7 +14,12 @@ against all keys, no kernel, no cache, nothing imported from the program.
 It reads the program's parameter pytree (matrices stored input-major and
 stacked on a leading layer axis), which is layout, not arithmetic; layers are
 walked with ``lax.scan`` so that under a sharded layout one layer's weights
-are gathered at a time.  ``cfg`` holds the published keys.
+are gathered at a time, and each layer is recomputed in the backward
+(``jax.checkpoint`` around the plain layer: the same float32 operations a
+second time), so that the gradient keeps one layer's scores, probabilities
+and MLP intermediates and not every layer's.  At 12 layers on four chips the
+stacked ones were refused by the v5e compiler ("Used 32.32G of 15.75G hbm",
+ISSUE 30).  ``cfg`` holds the published keys.
 """
 
 from __future__ import annotations
@@ -78,7 +83,7 @@ def loss(params, tokens, targets, cfg, q_block=512):
     with jax.default_matmul_precision("highest"):
         params = jax.tree.map(lambda a: a.astype(jnp.float32), params)
         x = params["wte"][tokens]
-        x, _ = lax.scan(layer, x, params["blocks"])
+        x, _ = lax.scan(jax.checkpoint(layer), x, params["blocks"])
         x = _rmsnorm(x, params["final_norm"], eps)
         logits = x @ params["lm_head"].T
         lse = jax.nn.logsumexp(logits, axis=-1)

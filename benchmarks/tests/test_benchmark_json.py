@@ -13,12 +13,9 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 
 
-@pytest.fixture(scope="module", params=[False, True],
-                ids=["as-is", "with-pending"])
-def bench(request):
-    """The file as it stands, and as it will be once the cells that wait in
-    ``benchmarks/pending/`` are moved in: both have to meet the contract."""
-    return spec.load_benchmark(with_pending=request.param)
+@pytest.fixture(scope="module")
+def bench():
+    return spec.load_benchmark()
 
 
 def test_top_level(bench):
@@ -50,6 +47,11 @@ def test_configs(bench):
             assert NAME.match(key)
             assert not re.search(r"(_dim|_rank|_size|n_embd|n_head)$", key)
         assert held["layout"]["chips"] in (1, 4)
+        # limits of its own for lib/correct.py come with their readings
+        if "check" in held:
+            assert set(held["check"]) <= {"loss_tol", "seed_grad_tol"}
+            assert all(0 < v < 0.5 for v in held["check"].values())
+            assert held["check_why"]
         spec.load_module("models", held["family"])
         spec.load_module("reference", held["family"])
 
@@ -97,8 +99,7 @@ def test_metrics(bench):
 
 def test_every_reader_is_listed():
     """A reader file nobody lists is dead; a later PR adds both together."""
-    listed = {m["name"] for m in
-              spec.load_benchmark(with_pending=True)["per_layer"]}
+    listed = {m["name"] for m in spec.load_benchmark()["per_layer"]}
     on_disk = {f[:-3] for f in os.listdir(
         os.path.join(spec.BENCH_DIR, "layer_metrics")) if f.endswith(".py")}
     assert on_disk == listed

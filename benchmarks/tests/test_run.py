@@ -64,14 +64,10 @@ def test_refuses_without_the_program(tmp_path):
 @pytest.mark.parametrize("cell, trace, devices", [
     ("mistral7b-s8192", 0, 1),
     ("gpt2xl-s1024", 1, 1),
-    # waits in benchmarks/pending/: run from a checkout that has it moved in
     ("mistral7b-fsdp4-s4096", 1, 4),
 ])
-def test_rehearsal_prints_counts_only(cell, trace, devices, tmp_path):
-    root = spec.ROOT
-    if devices == 4:
-        root = tree(tmp_path, spec.load_benchmark(with_pending=True))
-    line = result_line(run(root, "--workload", cell, "--seed", "3",
+def test_rehearsal_prints_counts_only(cell, trace, devices):
+    line = result_line(run(spec.ROOT, "--workload", cell, "--seed", "3",
                            "--seconds", "2", "--trace", str(trace),
                            "--rehearse"))
     assert set(line) == LINE_KEYS

@@ -51,6 +51,11 @@ def test_exposed_collective_time():
     wrapped = ops()
     wrapped[4] = E("fusion.7", 12, 1, collective=True)  # c, alone
     assert tr.exposed_collective_seconds(wrapped, 0, 20) == 4
+    # d is arithmetic that carries a collective along: what it hides is hidden
+    wrapped[6] = E("fusion.8", 14, 3, carrier=True)
+    assert tr.exposed_collective_seconds(wrapped, 0, 20) == 4
+    assert tr.carrier_seconds(wrapped, 0, 20) == 3
+    assert tr.carrier_seconds(wrapped, 0, 15) == 1
     assert tr.exposed_collective_seconds(ops(), 0, 12) == 2
     assert tr.exposed_collective_seconds(
         [E("fusion.1", 0, 5)], 0, 5) == 0
@@ -137,16 +142,20 @@ def test_recorded_trace_four_chips():
     trace = tr.load(os.path.join(spec.BENCH_DIR, "testdata",
                                  "tiny-llama-fsdp4.xplane.pb.gz"))
     assert sorted(trace.devices) == [0, 1, 2, 3]
-    # per chip: window start, busy and exposed-collective nanoseconds, the
-    # last two recounted on a nanosecond grid from the leaf events
-    want = {0: (154976305, 2157314, 1724659), 1: (154971890, 2151202, 1718358),
-            2: (154966575, 2151893, 1721172), 3: (154913421, 2145308, 1713995)}
+    # per chip: window start, busy, exposed-collective and carrier
+    # nanoseconds, the last three recounted on a nanosecond grid from the
+    # leaf events (exposed + carrier is what PR 22 counted as exposed)
+    want = {0: (154976305, 2157314, 1385510, 339149),
+            1: (154971890, 2151202, 1379394, 338964),
+            2: (154966575, 2151893, 1382721, 338451),
+            3: (154913421, 2145308, 1375381, 338614)}
     for ordinal, dev in trace.devices.items():
         lo, hi, steps, _ = tr.steady_window(dev.modules,
                                             tr.step_module(dev.modules))
         assert steps == 6
         got = (round(lo * 1e9), round(tr.busy_seconds(dev.ops, lo, hi) * 1e9),
-               round(tr.exposed_collective_seconds(dev.ops, lo, hi) * 1e9))
+               round(tr.exposed_collective_seconds(dev.ops, lo, hi) * 1e9),
+               round(tr.carrier_seconds(dev.ops, lo, hi) * 1e9))
         assert got == want[ordinal]
     ops0 = trace.devices[0].ops
     kinds = {tr.COLLECTIVE.match(e.name).group(1)
@@ -157,23 +166,28 @@ def test_recorded_trace_four_chips():
     wrapped = {e.name.split(".")[0] for e in ops0 if e.collective}
     assert wrapped == {"fusion"}
     assert sum(e.collective for e in ops0) > 0
+    # a matmul between a gather's start and its done carries it along
+    assert {e.name.split(".")[0] for e in ops0 if e.carrier} == {"fusion"}
+    assert not any(e.carrier and tr.is_collective(e) for e in ops0)
 
 
 def test_collective_readers_on_the_four_chip_trace():
-    """``collectives.*`` wait in ``benchmarks/pending/`` with their cell; the
-    readers are exercised here so that they arrive tested."""
+    """``collectives.*`` and ``device.idle`` on a trace of four chips (the
+    tiny preset's, recorded on the chip in PR 22)."""
     trace = tr.load(os.path.join(spec.BENCH_DIR, "testdata",
                                  "tiny-llama-fsdp4.xplane.pb.gz"))
     run = record(trace, chips=4, collectives={
         "all-gather": 55, "all-reduce": 4, "reduce-scatter": 0,
         "all-to-all": 2, "collective-permute": 0})
     exposed = spec.load_module("layer_metrics", "collectives.exposed_ms")
-    # the worst chip is chip 0: 1724659 ns over six steps
-    assert exposed.read(run) == pytest.approx(1724659e-6 / 6, rel=1e-6)
+    # the worst chip is chip 0: 1385510 ns over six steps
+    assert exposed.read(run) == pytest.approx(1385510e-6 / 6, rel=1e-6)
+    carrier = spec.load_module("layer_metrics", "collectives.carrier_ms")
+    assert carrier.read(run) == pytest.approx(339149e-6 / 6, rel=1e-6)
     assert spec.load_module("layer_metrics", "collectives.count").read(run) \
         == 59
     idle = spec.load_module("layer_metrics", "device.idle").read(run)
     # the least busy chip, 3, inside the first chip's window of 19829063 ns
     assert idle == pytest.approx(100 * (1 - 2145308 / 19829063), abs=1e-3)
     run.trace = run.steady = None
-    assert exposed.read(run) is None
+    assert exposed.read(run) is None and carrier.read(run) is None

@@ -42,7 +42,7 @@ def main() -> int:
     import jax.numpy as jnp
     from jax.experimental import topologies
 
-    from benchmarks.lib import correct, spec
+    from benchmarks.lib import correct, spec, state
     from benchmarks.lib.compile_watch import hlo_report
     from ray_tpu.parallel import MeshSpec, batch_sharding, make_mesh
     from ray_tpu.parallel.mesh import pytree_sharding
@@ -53,11 +53,12 @@ def main() -> int:
     jax.default_backend = lambda: "tpu"
     jax.devices = lambda *a: list(topo.devices)
 
-    bench = spec.load_benchmark(with_pending=True)
+    bench = spec.load_benchmark()
     names = args.cells or [w["name"] for w in bench["workloads"]]
-    print("| program | params | arguments GiB | temp GiB | Mosaic calls | "
-          "all-gather / all-reduce / reduce-scatter | compile s |")
-    print("|---|---|---|---|---|---|---|")
+    print("| program | params | arguments GiB | output GiB | temp GiB | "
+          "Mosaic calls | all-gather / all-reduce / reduce-scatter | "
+          "compile s |")
+    print("|---|---|---|---|---|---|---|---|")
     for name in names:
         cell = spec.load_cell(bench, name)
         config, traffic = cell["config_file"], cell["traffic_file"]
@@ -71,24 +72,27 @@ def main() -> int:
             lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
             jax.eval_shape(family.init_fn, jax.random.key(0)), shardings)
         n_params = sum(a.size for a in jax.tree.leaves(params))
-        optimizer = family.make_optimizer()
+        # the optimizer the train loop hands the program (kinds/train.py)
+        optimizer = state.born_sharded(family.make_optimizer(), shardings)
 
-        def row(label, fn, *abstract, donate=()):
+        def row(label, jitted, *abstract):
             t0 = time.perf_counter()
             with jax.set_mesh(mesh):
-                compiled = jax.jit(fn, donate_argnums=donate).lower(
-                    *abstract).compile()
+                compiled = jitted.lower(*abstract).compile()
             seconds = time.perf_counter() - t0
             mem, hlo = compiled.memory_analysis(), compiled.as_text()
             rep = hlo_report(hlo)
             c = rep["collectives"]
             print(f"| {name} {label} | {n_params / 1e6:.1f} M | "
                   f"{gib(mem.argument_size_in_bytes)} | "
+                  f"{gib(mem.output_size_in_bytes)} | "
                   f"{gib(mem.temp_size_in_bytes)} | {len(rep['mosaic'])} | "
                   f"{c['all-gather']} / {c['all-reduce']} / "
                   f"{c['reduce-scatter']} | {seconds:.0f} |", flush=True)
             return compiled, hlo
 
+        # create_sharded_state's own call, so the state lies here as the
+        # program leaves it, not as this tool would like it
         with jax.set_mesh(mesh):
             init = jax.jit(optimizer.init).lower(params).compile()
         opt_state = jax.tree.map(
@@ -96,8 +100,10 @@ def main() -> int:
             jax.eval_shape(optimizer.init, params), init.output_shardings)
         batch = jax.ShapeDtypeStruct((B, S), jnp.int32,
                                      sharding=batch_sharding(mesh))
-        _, hlo = row(f"step {B}x{S}", family.make_train_step(optimizer),
-                     params, opt_state, batch, batch, donate=(0, 1))
+        _, hlo = row(f"step {B}x{S}",
+                     jax.jit(family.make_train_step(optimizer),
+                             donate_argnums=(0, 1)),
+                     params, opt_state, batch, batch)
         if args.hlo:
             os.makedirs(args.hlo, exist_ok=True)
             with open(os.path.join(args.hlo, name + ".step.hlo.txt"),
@@ -110,16 +116,15 @@ def main() -> int:
                                     sharding=batch_sharding(mesh))
         cut = jax.ShapeDtypeStruct((n, Sg), jnp.int32,
                                    sharding=batch_sharding(mesh))
-        row(f"check: program loss {n}x{S}", family.loss_fn, params, full,
-            full)
-        row(f"check: reference loss {n}x{S}",
-            lambda p, t, y: family.reference_loss(p, t, y, correct.Q_BLOCK),
+        row(f"check: program loss {n}x{S}", jax.jit(family.loss_fn), params,
+            full, full)
+        row(f"check: reference loss {n}x{S}", jax.jit(
+            lambda p, t, y: family.reference_loss(p, t, y, correct.Q_BLOCK)),
             params, full, full)
         row(f"check: program grads {n}x{Sg}",
-            jax.value_and_grad(family.loss_fn), params, cut, cut)
-        row(f"check: reference grads {n}x{Sg}",
-            jax.value_and_grad(
-                lambda p, t, y: family.reference_loss(p, t, y, Sg)),
+            correct.value_and_grad(family.loss_fn, params), params, cut, cut)
+        row(f"check: reference grads {n}x{Sg}", correct.value_and_grad(
+            lambda p, t, y: family.reference_loss(p, t, y, Sg), params),
             params, cut, cut)
     return 0
 
