@@ -54,7 +54,7 @@ def multiprocess_world() -> int:
         return 0
 
 
-def _kv_client():
+def kv_client():
     from jax._src import distributed as jdist
 
     client = jdist.global_state.client
@@ -246,7 +246,7 @@ class DCNCollectiveGroup:
         participants = sorted({r for pair in perm for r in pair})
         if rank not in participants:
             raise ValueError(f"rank {rank} is not part of perm {perm}")
-        client = _kv_client()
+        client = kv_client()
         timeout_ms = int(self.timeout_s * 1000)
         out: Any = np.zeros_like(np.asarray(array))
         for src, dst in perm:

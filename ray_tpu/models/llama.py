@@ -23,9 +23,19 @@ log-sum-exp (``ops.attention.save_splash_residuals``), one more (B, S, D)
 activation a layer.  The kernel is a ``custom_vjp`` whose backward reads those
 two arrays; under a bare checkpoint the backward ran the forward kernel a
 second time to get them back (11.5 of 305 ms a step at 8192 tokens, PERF.md PR
-24).  q, k and v are recomputed either way, since the projections' own
-backward needs ``h``.  With the XLA attention path the policy finds nothing to
+24).  With the XLA attention path the policy finds nothing of the kernel's to
 keep.
+
+Where the chip has room, the layer keeps more (``ops/remat.py``, PR 31): q, k
+and v as they enter the attention call, then the MLP's gate and up products
+(with experts, the two grouped matmuls' outputs), each named with
+``checkpoint_name`` and kept only if the bytes fit beside the step's own
+temporaries and a reserve.  The choice is made in :func:`forward_hidden` when
+the loss is traced, from the devices' ``memory_stats()`` and the shapes at
+hand; no field of the configuration steers it, and a backend that reports no
+memory (the CPU) gets the plain policy.  What the backward still re-does from
+the kept arrays is elementwise: the two RMSNorms, the casts, ``silu(gate) *
+up``, and with QK-norm the q and k products the norm's backward reads.
 """
 
 from __future__ import annotations
@@ -33,15 +43,18 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass
 from functools import partial
-from typing import Any, Dict
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models import moe as _moe
-from ray_tpu.ops.attention import causal_attention, save_splash_residuals
+from ray_tpu.ops import remat
+from ray_tpu.ops.attention import causal_attention
 from ray_tpu.ops.lm_head import lm_head_cross_entropy
+from ray_tpu.parallel.mesh import DEFAULT_RULES
 from ray_tpu.parallel.train_state import make_optimizer  # noqa: F401
 from ray_tpu.parallel.train_state import make_train_step as _make_train_step
 
@@ -272,6 +285,7 @@ def _block(x, blk, config: LlamaConfig):
         v = (h @ blk["wv"].astype(dt)).reshape(B, S, KV, hd)
         q = _rope(q.reshape(B, S, H, hd), config.rope_theta)
         k = _rope(k.reshape(B, S, KV, hd), config.rope_theta)
+        q, k, v = (checkpoint_name(a, remat.QKV) for a in (q, k, v))
         # GQA: k and v go in at KV heads; the splash kernel takes them so,
         # and the dispatcher repeats them for the paths that cannot.
         attn = causal_attention(q, k, v, config.attn_impl).astype(dt) \
@@ -288,10 +302,66 @@ def _block(x, blk, config: LlamaConfig):
                 norm_topk_prob=config.norm_topk_prob, dtype=dt)
             return x + y, router_losses
         h = h.astype(dt)
-        gate = jax.nn.silu((h @ blk["w_gate"].astype(dt)).astype(jnp.float32))
-        up = (h @ blk["w_up"].astype(dt)).astype(jnp.float32)
-        x = x + ((gate * up).astype(dt) @ blk["w_down"].astype(dt))
+        gate = checkpoint_name(h @ blk["w_gate"].astype(dt), remat.GATE_UP)
+        up = checkpoint_name(h @ blk["w_up"].astype(dt), remat.GATE_UP)
+        act = jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+        x = x + (act.astype(dt) @ blk["w_down"].astype(dt))
     return x, None
+
+
+def _layer_policy(params, x_shape, config: LlamaConfig):
+    """The layer's checkpoint policy: ``ops.remat.layer_policy`` over this
+    model's sizes a chip."""
+    return remat.layer_policy(*_layer_sizes(params, x_shape, config))
+
+
+def _layer_sizes(params, x_shape, config: LlamaConfig):
+    """What ``ops.remat`` needs to know of ``params`` (arrays or their
+    shapes) and activations of ``x_shape`` (B, S, D), every size a chip's:
+    (the ladder's candidates as (name, bytes), the bound on the step's own
+    temporaries).  The ambient mesh cuts the tokens over its batch and `seq`
+    axes and the heads and the MLP's width over `tensor`.  The parameters
+    are taken to lie as ``logical_axes`` and the default rules put them: a
+    trace sees no shardings, and a caller who hands ``create_sharded_state``
+    rules of their own gets the chip's share mis-sized, which the compiler's
+    refusal and ``TrainStep``'s fallback then have to catch.  The scan stacks
+    whatever is kept, so a candidate's bytes are a layer's times
+    ``n_layer``."""
+    mesh = jax.sharding.get_abstract_mesh()
+    tensor = remat.axis_shards(mesh, "tensor")
+    tokens = math.prod(x_shape[:2]) // remat.axis_shards(
+        mesh, "data", "fsdp", "seq")
+    item = jnp.dtype(config.dtype).itemsize
+    qkv_width = (config.n_head + 2 * config.n_kv_head) * config.head_dim \
+        // tensor
+    # a token meets experts_per_token experts of width d_ff
+    mlp_width = config.d_ff * max(config.experts_per_token, 1) // tensor
+    whole = jax.tree.map(lambda a: 4 * a.size, params)
+    chips = jax.tree.map(
+        lambda nbytes, axes: nbytes // remat.axis_shards(
+            mesh, *_mesh_axes(axes)),
+        whole, logical_axes(config))
+    total, block_bytes = (sum(jax.tree.leaves(t)) for t in
+                          (chips, chips["blocks"]))
+    temporaries = remat.own_temporaries(
+        block_bytes=block_bytes, other_bytes=total - block_bytes,
+        layer_bytes=sum(jax.tree.leaves(whole["blocks"])) // config.n_layer,
+        sharded=total < sum(jax.tree.leaves(whole)), tokens=tokens,
+        d_model=config.d_model, n_layer=config.n_layer,
+        attn_width=config.d_model // tensor, n_head=config.n_head // tensor,
+        mlp_width=mlp_width, vocab=config.vocab_size // tensor,
+        itemsize=item,
+        logits_itemsize=jnp.dtype(config.logits_dtype).itemsize)
+    per_layer = {remat.QKV: tokens * qkv_width * item,
+                 remat.GATE_UP: 2 * tokens * mlp_width * item}
+    return ([(name, config.n_layer * per_layer[name])
+             for name in remat.LADDER], temporaries)
+
+
+def _mesh_axes(logical) -> Tuple[str, ...]:
+    """The mesh axes a parameter with these logical axes is cut over."""
+    return tuple(a for name in logical if name is not None
+                 for a in DEFAULT_RULES.get(name) or ())
 
 
 def forward_hidden(params: Dict[str, Any], tokens, config: LlamaConfig):
@@ -306,7 +376,8 @@ def forward_hidden(params: Dict[str, Any], tokens, config: LlamaConfig):
         return _block(x, blk, config)
 
     if config.remat:
-        layer = jax.checkpoint(layer, policy=save_splash_residuals)
+        layer = jax.checkpoint(
+            layer, policy=_layer_policy(params, x.shape, config))
     x, router_losses = lax.scan(layer, x, params["blocks"])
     router_loss = None
     if router_losses is not None:

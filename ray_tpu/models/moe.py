@@ -53,7 +53,9 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
+from ray_tpu.ops import remat
 from ray_tpu.ops.grouped_matmul import grouped_matmul
 
 
@@ -167,8 +169,10 @@ def expert_mlp(x, weights, experts, w_gate, w_up, w_down):
         rows = _to_expert_order(x, order, inverse)
         w_rows = _weights_to_expert_order(weights, order, inverse)
     with jax.named_scope("experts"):
-        gate = grouped_matmul(rows, w_gate, group_sizes)
-        up = grouped_matmul(rows, w_up, group_sizes)
+        gate = checkpoint_name(grouped_matmul(rows, w_gate, group_sizes),
+                               remat.GATE_UP)
+        up = checkpoint_name(grouped_matmul(rows, w_up, group_sizes),
+                             remat.GATE_UP)
         act = (jax.nn.silu(gate.astype(jnp.float32))
                * up.astype(jnp.float32)
                * w_rows[:, None].astype(jnp.float32)).astype(x.dtype)

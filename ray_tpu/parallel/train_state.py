@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import functools
 import re
 import sys
 import time
@@ -30,6 +31,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops import remat
 from ray_tpu.parallel.mesh import pytree_sharding
 from ray_tpu.util import device_telemetry, tracing
 
@@ -44,7 +46,11 @@ def create_sharded_state(
 ) -> Tuple[Any, Any]:
     """Initialize params directly into their sharded layout (no host round
     trip: init runs under jit with out_shardings so each device materializes
-    only its shard) and derive optimizer state with propagated shardings."""
+    only its shard) and build the optimizer state beside them: every
+    sub-tree of it shaped like the parameters (Adam's moments) is born under
+    the parameters' shardings, every other leaf (the counts) replicated.
+    optax makes the moments from zeros, which carry no sharding for the
+    compiler to propagate; left to it they come out whole on every chip."""
     shardings = pytree_sharding(logical, mesh, rules)
     device_telemetry.listen_for_compiles()
     with jax.set_mesh(mesh):
@@ -55,8 +61,31 @@ def create_sharded_state(
         if optimizer is not None:
             with tracing.span("train.init_opt_state"), \
                     device_telemetry.compile_label("init_opt_state"):
-                opt_state = jax.jit(optimizer.init)(params)
+                # On one device there is nothing to say, and the executable
+                # stays the one it was.
+                init = jax.jit(optimizer.init) if mesh.size == 1 else \
+                    jax.jit(optimizer.init, out_shardings=_state_shardings(
+                        optimizer, params, shardings, mesh))
+                opt_state = init(params)
     return params, opt_state
+
+
+def _state_shardings(optimizer, params, shardings, mesh):
+    """Shardings for ``optimizer.init(params)``: ``shardings`` (the
+    parameters') for every sub-tree with their structure and shapes, a
+    replicated one for every other leaf."""
+    like = jax.tree.structure(params)
+    shapes = [p.shape for p in jax.tree.leaves(params)]
+
+    def mirrors(node) -> bool:
+        return jax.tree.structure(node) == like and \
+            [leaf.shape for leaf in jax.tree.leaves(node)] == shapes
+
+    replicated = jax.sharding.NamedSharding(mesh,
+                                            jax.sharding.PartitionSpec())
+    return jax.tree.map(
+        lambda node: shardings if mirrors(node) else replicated,
+        jax.eval_shape(optimizer.init, params), is_leaf=mirrors)
 
 
 def make_optimizer(learning_rate=3e-4, weight_decay=0.1, b1=0.9, b2=0.95,
@@ -126,13 +155,24 @@ class TrainStep:
     The signature is taken only when a compile event fired, before the
     arguments are donated; the first one is kept for :meth:`anatomy`.
     A call that compiled is a ``train.first_call`` span and a first-call
-    record of the registry.
+    record of the registry; both say what the model's layers keep for the
+    backward there (``remat_kept``, ``remat_kept_bytes``, ``remat_room_bytes``:
+    ``ops/remat.py`` decides it while the step is traced).
+
+    A step that was traced in this call, keeps more than the plain policy
+    would and is refused for memory (``RESOURCE_EXHAUSTED``, at compile or
+    at its first execution) is rebuilt once under the plain policy, with a
+    warning, the ``ray_tpu_train_remat_fallback_total`` counter and
+    ``remat_fallback`` on the span.  A step of several processes is rebuilt
+    only on the compiler's refusal, which all of them get alike.  A later
+    call that fails, fails.
     """
 
     label = "train_step"
 
     def __init__(self, step_fn, donate_state: bool = True, mesh=None):
         self._donation = (0, 1) if donate_state else ()
+        self._step_fn = step_fn
         self._jitted = jax.jit(step_fn, donate_argnums=self._donation)
         #: the ambient mesh around trace, lower and execute
         self._in_mesh = (contextlib.nullcontext if mesh is None
@@ -149,20 +189,59 @@ class TrainStep:
         t0 = time.perf_counter()
         label = device_telemetry.compile_label(
             self.label, lambda: self._sign(args, kwargs))
-        with tracing.annotate("train.dispatch"), label, self._in_mesh():
-            out = self._jitted(*args, **kwargs)
+        fell_back = False
+        with tracing.annotate("train.dispatch"), label, self._in_mesh(), \
+                remat.recording() as decided:
+            try:
+                out = self._jitted(*args, **kwargs)
+            except RuntimeError as e:  # XLA's; re-raised unless remat's
+                if not self._refused_for_remat(e, decided, args, kwargs):
+                    raise
+                remat.fall_back(decided[-1], str(e).splitlines()[0][:200])
+                fell_back = True
+                # a new function object: jax keeps the refused program's
+                # trace under the old one and would hand it back
+                self._jitted = jax.jit(functools.partial(self._step_fn),
+                                       donate_argnums=self._donation)
+                out = self._jitted(*args, **kwargs)
         seconds = time.perf_counter() - t0
         profiler = sys.modules.get("ray_tpu.train.profiler")
         if profiler is not None:
             profiler.count("dispatch", seconds)
         if label.compiles:
             end = time.time()
-            device_telemetry.record_first_call(self.label, seconds, ts=end)
+            attributes = dict(decided[-1].attributes() if decided else {},
+                              remat_fallback=fell_back)
+            device_telemetry.record_first_call(self.label, seconds, ts=end,
+                                               **attributes)
             tracing.record_span(
                 "train.first_call", end - seconds, end,
                 attributes={"label": self.label,
-                            "compile_s": label.compile_s})
+                            "compile_s": label.compile_s, **attributes})
         return out
+
+    def _refused_for_remat(self, error, decided, args, kwargs) -> bool:
+        """Whether ``error`` is the memory's refusal of a program that this
+        call traced with more kept than the plain policy keeps (``decided``:
+        what the rule said while it traced), and the (donated) arguments are
+        still whole for a second try.  A step of several processes is rebuilt
+        only if the compiler refused it, which every process sees alike (the
+        compile is asked once more, alone, to tell): a process that ran out
+        of memory on its own while its peers launched the program cannot
+        leave them, and the error stands."""
+        if not ("RESOURCE_EXHAUSTED" in str(error)
+                and decided and decided[-1].kept
+                and not any(leaf.is_deleted()
+                            for leaf in jax.tree.leaves((args, kwargs))
+                            if isinstance(leaf, jax.Array))):
+            return False
+        if decided[-1].processes == 1:
+            return True
+        try:
+            self._jitted.lower(*args, **kwargs).compile()
+        except RuntimeError as again:
+            return "RESOURCE_EXHAUSTED" in str(again)
+        return False
 
     def _sign(self, args, kwargs):
         """(shapes, shardings, donation) of a call, for the compile

@@ -300,11 +300,13 @@ def program(label: str) -> Optional[Any]:
 
 
 def record_first_call(label: str, seconds: float,
-                      ts: Optional[float] = None) -> None:
+                      ts: Optional[float] = None, **attributes: Any) -> None:
     """One call of a labelled program that built or loaded its executable
-    (trace, lower, compile or cache load, dispatch), ``ts`` its end."""
+    (trace, lower, compile or cache load, dispatch), ``ts`` its end;
+    ``attributes`` are the caller's own (``TrainStep``: what the layers keep
+    for the backward, ``remat_*``)."""
     row = {"label": label, "ts": time.time() if ts is None else ts,
-           "seconds": round(float(seconds), 6)}
+           "seconds": round(float(seconds), 6), **attributes}
     with _lock:
         _first_calls.append(row)
 

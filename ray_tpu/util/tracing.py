@@ -100,7 +100,9 @@ SPAN_REGISTRY: Dict[str, str] = {
                       "side (enqueue; trace+compile on a first call)",
     "train.first_call": "TrainStep: a call that built or loaded an "
                         "executable (trace, lower, compile or cache load, "
-                        "dispatch), recorded after the fact",
+                        "dispatch), recorded after the fact (attrs: label, "
+                        "compile_s, remat_kept, remat_kept_bytes, "
+                        "remat_room_bytes, remat_fallback)",
     "train.report": "session: one train.report() call, step boundary "
                     "included",
     "train.init_params": "create_sharded_state: parameters initialised "

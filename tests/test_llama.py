@@ -242,7 +242,7 @@ def test_backward_runs_the_splash_forward_once(mesh_axes, monkeypatch):
     with mesh:
         kept = _grad_jaxpr(config)
         # a bare jax.checkpoint(layer), the layer as it was: two forwards
-        monkeypatch.setattr(llama, "save_splash_residuals", None)
+        monkeypatch.setattr(llama, "_layer_policy", lambda *a: None)
         bare = _grad_jaxpr(config)
     assert ("shard_map" in {e.primitive.name for e in _equations(kept)}) \
         == (mesh_axes is not None)
@@ -276,7 +276,7 @@ def test_saved_splash_residuals_change_no_number(monkeypatch):
             params, *batch, config)
 
     loss, grads = run()
-    monkeypatch.setattr(llama, "save_splash_residuals", None)
+    monkeypatch.setattr(llama, "_layer_policy", lambda *a: None)
     bare_loss, bare_grads = run()
     assert float(loss) == float(bare_loss) and np.isfinite(float(loss))
     for got, want in zip(jax.tree.leaves(grads), jax.tree.leaves(bare_grads)):
@@ -288,7 +288,7 @@ def test_policy_saves_nothing_without_the_kernel(monkeypatch):
     scans stack what they stacked under a bare checkpoint."""
     config = dataclasses.replace(llama.LlamaConfig.tiny(), attn_impl="xla")
     kept = _grad_jaxpr(config)
-    monkeypatch.setattr(llama, "save_splash_residuals", None)
+    monkeypatch.setattr(llama, "_layer_policy", lambda *a: None)
     bare = _grad_jaxpr(config)
     assert _kernel_names(kept) == []
     assert _stacked_by_scans(kept) == _stacked_by_scans(bare)

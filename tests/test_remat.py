@@ -1,0 +1,488 @@
+"""``ops/remat.py``: what the scanned layer keeps for the backward is chosen at
+trace time from the device's free bytes.  The CPU reports no memory, so every
+test that wants a richer program hands the rule a device of its own."""
+
+import contextlib
+import os
+import re
+import socket
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax._src.ad_checkpoint import saved_residuals  # not re-exported in 0.9
+
+from ray_tpu.models import llama, moe
+from ray_tpu.ops import remat
+from ray_tpu.ops.attention import save_splash_residuals
+from ray_tpu.parallel import MeshSpec, make_mesh
+from ray_tpu.parallel.train_state import (create_sharded_state,
+                                          jit_train_step)
+from ray_tpu.util import device_telemetry
+
+GiB = 2 ** 30
+#: a v5e chip's ``bytes_limit``
+V5E = int(15.75 * GiB)
+ROOMY = (1 << 50, 0)
+
+
+@pytest.fixture(autouse=True)
+def fresh_rule(monkeypatch):
+    """No fallback pinned by a test before."""
+    monkeypatch.setattr(remat, "_plain_only", False)
+
+
+def on_device(monkeypatch, memory):
+    monkeypatch.setattr(remat, "device_memory", lambda: memory)
+
+
+def _policy(config, B=2):
+    """What ``forward_hidden`` asks for a batch of B rows, from shapes."""
+    shapes = jax.eval_shape(lambda: llama.init_params(config,
+                                                      jax.random.key(0)))
+    return llama._layer_policy(shapes, (B, config.seq_len, config.d_model),
+                               config)
+
+
+def _decide(config, B=2):
+    """The rule's decision for that batch."""
+    with remat.recording() as decided:
+        _policy(config, B)
+    (decision,) = decided
+    return decision
+
+
+MISTRAL = dict(vocab_size=32768, n_head=32, n_kv_head=8, d_model=4096,
+               d_ff=14336)
+OLMOE = dict(vocab_size=50304, n_head=16, n_kv_head=16, d_model=2048,
+             d_ff=1024, n_experts=64, experts_per_token=8, qk_norm=True)
+BOTH = (remat.QKV, remat.GATE_UP)
+
+#: The benchmark's cells and two jobs that are none: the model, the batch,
+#: the mesh, the resident bytes on a chip when the step is traced (the
+#: compiled step's arguments, compile-only for the v5e, PERF.md PR 31), and
+#: what the rule must say.
+CELLS = {
+    "mistral7b-s8192": (dict(MISTRAL, n_layer=2), (1, 8192), {}, 6.563, BOTH),
+    "mistral7b-s1024": (dict(MISTRAL, n_layer=2), (8, 1024), {}, 6.563, BOTH),
+    "olmoe-s4096": (dict(OLMOE, n_layer=1), (2, 4096), {}, 5.827, BOTH),
+    # twelve layers over four chips: room for q/k/v, not for gate and up
+    "mistral7b-fsdp4-s4096": (dict(MISTRAL, n_layer=12), (4, 4096),
+                              {"fsdp": 4}, 6.720, (remat.QKV,)),
+    # a third layer on one chip, a thirteenth over four: full, today's program
+    "mistral7b-l3": (dict(MISTRAL, n_layer=3), (1, 8192), {}, 8.594, ()),
+    "mistral7b-fsdp4-l13": (dict(MISTRAL, n_layer=13), (4, 4096),
+                            {"fsdp": 4}, 7.230, ()),
+}
+
+
+@pytest.mark.parametrize("nudge_mb", [0, -64, 64])
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_the_rule_decides_each_cell(cell, nudge_mb, monkeypatch):
+    """Each Llama-path cell gets the decision of ISSUE 31's table, from the
+    sizes ``llama._layer_policy`` computes, and a batch more or less in
+    ``bytes_in_use`` does not move it."""
+    sizes, (B, S), mesh_axes, resident, want = CELLS[cell]
+    config = llama.LlamaConfig(seq_len=S, **sizes)
+    on_device(monkeypatch,
+              (V5E, int(resident * GiB) + nudge_mb * 2 ** 20))
+    mesh = jax.set_mesh(make_mesh(MeshSpec(**mesh_axes), jax.devices()[:4])) \
+        if mesh_axes else contextlib.nullcontext()
+    with mesh:
+        decision = _decide(config, B)
+    assert decision.kept == want
+    assert decision.room_bytes is not None and decision.processes == 1
+
+
+@pytest.mark.parametrize("limit, in_use, candidates, temporaries, want", [
+    # of 1000, 100 are the reserve; 500 in use leave 400 less the temporaries
+    (1000, 500, [("a", 100), ("b", 100)], 301, ()),
+    # the first rung fits exactly, the second does not
+    (1000, 500, [("a", 100), ("b", 100)], 300, ("a",)),
+    (1000, 500, [("a", 100), ("b", 100)], 200, ("a", "b")),
+    # fixed order: a second rung that would fit is not taken past a first
+    # that does not
+    (1000, 500, [("a", 300), ("b", 50)], 200, ()),
+    # a model with nothing named (GPT-2 XL's cell)
+    (V5E, int(4.22 * GiB), [], int(9 * GiB), ()),
+])
+def test_choose(limit, in_use, candidates, temporaries, want):
+    decision = remat.choose(limit, in_use, candidates, temporaries)
+    assert decision.kept == want
+    assert decision.kept_bytes == sum(n for name, n in candidates
+                                      if name in want)
+    assert decision.room_bytes == limit - in_use - temporaries \
+        - int(remat.RESERVE_SHARE * limit)
+
+
+def test_no_memory_statistics_is_the_plain_policy():
+    """The CPU's devices report nothing; the reader says so and the rule
+    keeps the splash residuals alone."""
+    assert remat.device_memory() is None
+    assert _decide(llama.LlamaConfig.tiny()) == remat.Decision()
+
+
+# ------------------------------------------------- one program on every host
+def test_fullest_is_the_smallest_limit_with_the_most_in_use():
+    assert remat.fullest([(100, 10), (90, 30), (100, 20)]) == (90, 30)
+    assert remat.fullest([(100, 10), None]) is None
+    assert remat.fullest([]) is None
+
+
+#: two processes of one job: rank 0 holds 2 GiB more than rank 1 (an
+#: evaluation program, a checkpoint's staging), which alone would cost it
+#: the second rung
+RANKS = [(V5E, int(8.5 * GiB)), (V5E, int(6.563 * GiB))]
+
+
+def _as_process(monkeypatch, rank, world=2, exchanged=None):
+    """This process is ``rank`` of ``world``, each with 2 of the job's
+    chips; its peers answer ``RANKS``."""
+    monkeypatch.setattr(remat, "multiprocess_world", lambda: world)
+    monkeypatch.setattr(jax, "local_device_count", lambda: 2)
+    on_device(monkeypatch, RANKS[rank])
+
+    def every_process(report, question):
+        assert report == RANKS[rank]
+        if exchanged is not None:
+            exchanged.append(question)
+        return RANKS
+
+    monkeypatch.setattr(remat, "every_process", every_process)
+
+
+def test_processes_with_different_memory_build_one_program(monkeypatch):
+    """Under a mesh that spans processes each decides from the fullest chip
+    of the job, whatever its own report: the same answer, asked under the
+    same name, on both; alone they would have differed."""
+    config = llama.LlamaConfig(seq_len=8192, **dict(MISTRAL, n_layer=2))
+    mesh = make_mesh(MeshSpec(data=4), jax.devices()[:4])
+    got, alone, asked = [], [], []
+    for rank in range(2):
+        with monkeypatch.context() as m:
+            _as_process(m, rank, exchanged=asked)
+            with jax.set_mesh(mesh):
+                got.append(_decide(config, B=4))
+        with monkeypatch.context() as m:  # the same chips, a job of its own
+            on_device(m, RANKS[rank])
+            alone.append(_decide(config, B=1))
+    assert got[0] == got[1]
+    assert got[0].kept == (remat.QKV,) and got[0].processes == 2
+    assert [d.kept for d in alone] == [(remat.QKV,), BOTH]
+    assert len(asked) == 2 and asked[0] == asked[1]
+
+
+def test_a_program_of_the_process_s_own_chips_asks_nobody(monkeypatch):
+    """In a multi-process job a mesh no larger than the process's devices is
+    its own program (no peer traces it: asking would wait for ever); with no
+    mesh at all nothing says whose program it is, and it stays plain."""
+    config = llama.LlamaConfig(seq_len=8192, **dict(MISTRAL, n_layer=2))
+    asked = []
+    _as_process(monkeypatch, 1, exchanged=asked)
+    with jax.set_mesh(make_mesh(MeshSpec(data=2), jax.devices()[:2])):
+        own = _decide(config, B=2)
+    assert own.kept == BOTH and own.processes == 1
+    assert _decide(config) == remat.Decision(processes=2)
+    assert asked == []
+
+
+def _free_port() -> int:
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
+def test_two_real_processes_agree_and_train():
+    """Two OS processes, one jax.distributed job, one Llama step over the
+    global ``fsdp`` mesh; rank 0's chips report room for q/k/v alone, rank
+    1's for everything.  Both keep what the fullest chip allows, through
+    the real key-value store, and the step runs to the same loss on both."""
+    port, procs = _free_port(), []
+    child = os.path.join(os.path.dirname(__file__),
+                         "_remat_multihost_child.py")
+    for rank in range(2):
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   COORD=f"127.0.0.1:{port}", NPROC="2", RANK=str(rank),
+                   CHILD_DEVICES="2")
+        env.pop("PYTHONPATH", None)
+        procs.append(subprocess.Popen(
+            [sys.executable, child], env=env, stdout=subprocess.PIPE,
+            stderr=subprocess.PIPE, text=True))
+    rows = []
+    for p in procs:
+        stdout, stderr = p.communicate(timeout=570)
+        assert p.returncode == 0, f"child failed:\n{stderr[-3000:]}"
+        (line,) = [l for l in stdout.splitlines() if l.startswith("RESULT")]
+        rows.append(line.split()[1:])
+    rows.sort()
+    # RESULT <rank> <kept> <processes> <alone> <loss>
+    assert [r[0] for r in rows] == ["0", "1"]
+    assert [r[1] for r in rows] == [remat.QKV, remat.QKV]
+    assert [r[2] for r in rows] == ["2", "2"]
+    assert [r[3] for r in rows] == [remat.QKV, ",".join(BOTH)]
+    assert rows[0][4] == rows[1][4] and np.isfinite(float(rows[0][4]))
+
+
+def _batch(config, B=2):
+    tokens = jax.random.randint(jax.random.key(1), (B, config.seq_len + 1),
+                                0, config.vocab_size)
+    return tokens[:, :-1], tokens[:, 1:]
+
+
+@pytest.mark.parametrize("preset", ["tiny", "tiny_moe"])
+def test_keeping_more_changes_no_number(preset, monkeypatch):
+    """Loss and every gradient leaf agree between the plain policy and the
+    richest one to 1e-6 of the leaf's largest entry.  The kept arrays are
+    the very values the backward would compute again, so nothing but the
+    compiler's choice of fusions (the order of a float32 sum) may differ."""
+    config = getattr(llama.LlamaConfig, preset)()
+    params = llama.init_params(config, jax.random.key(0))
+    batch = _batch(config)
+
+    def run(kept):
+        with remat.recording() as decided:
+            out = jax.jit(jax.value_and_grad(llama.loss_fn),
+                          static_argnums=3)(params, *batch, config)
+        assert [d.kept for d in decided] == [kept]
+        return out
+
+    plain_loss, plain = run(())
+    on_device(monkeypatch, ROOMY)
+    loss, grads = run(BOTH)
+    np.testing.assert_allclose(float(loss), float(plain_loss), rtol=1e-6)
+    for got, want in zip(jax.tree.leaves(grads), jax.tree.leaves(plain)):
+        got, want = np.asarray(got), np.asarray(want)
+        assert np.max(np.abs(got - want)) <= 1e-6 * np.max(np.abs(want))
+
+
+def _layer_residuals(config, policy):
+    """(dtype, shape) of what a checkpointed layer saves for its backward."""
+    params = llama.init_params(config, jax.random.key(0))
+    blk = jax.tree.map(lambda a: a[0], params["blocks"])
+    x = jnp.zeros((2, config.seq_len, config.d_model), config.dtype)
+    layer = jax.checkpoint(lambda x, blk: llama._block(x, blk, config),
+                           policy=policy)
+    return sorted((str(aval.dtype), tuple(aval.shape))
+                  for aval, _ in saved_residuals(layer, x, blk))
+
+
+@pytest.mark.parametrize("preset", ["tiny", "tiny_moe"])
+def test_the_layer_saves_the_named_arrays(preset, monkeypatch):
+    """Under the richest policy the layer's residuals are today's plus q, k,
+    v and the two MLP products; with no device memory they are exactly what
+    ``save_splash_residuals`` gives."""
+    config = getattr(llama.LlamaConfig, preset)()
+    today = _layer_residuals(config, save_splash_residuals)
+    assert _layer_residuals(config, _policy(config)) == today
+    on_device(monkeypatch, ROOMY)
+    rich = _layer_residuals(config, _policy(config))
+    B, S = 2, config.seq_len
+    H, KV, hd = config.n_head, config.n_kv_head, config.head_dim
+    rows = B * S * max(config.experts_per_token, 1)
+    mlp_out = (rows, config.d_ff) if config.n_experts \
+        else (B, S, config.d_ff)
+    named = [("bfloat16", (B, S, H, hd)), ("bfloat16", (B, S, KV, hd)),
+             ("bfloat16", (B, S, KV, hd)), ("bfloat16", mlp_out),
+             ("bfloat16", mlp_out)]
+    assert rich == sorted(today + named)
+
+
+_METADATA = re.compile(r", metadata=\{[^}]*\}")
+_TABLES = ("FileNames", "FunctionNames", "FileLocations", "StackFrames")
+
+
+def _without_locations(text):
+    """A compiled module's text without where in the source each instruction
+    came from (what ``scripts/hlo_strip.py`` drops, and ``op_name`` too)."""
+    kept, in_table = [], False
+    for line in _METADATA.sub("", text).splitlines():
+        if line.strip() in _TABLES:
+            in_table = True
+        elif in_table:
+            in_table = bool(line.strip())
+        else:
+            kept.append(line)
+    return "\n".join(kept)
+
+
+def _tiny_step_text(config):
+    optimizer = llama.make_optimizer()
+    params = llama.init_params(config, jax.random.key(0))
+    step = jax.jit(llama.make_train_step(config, optimizer))
+    text = step.lower(params, optimizer.init(params), *_batch(config)) \
+        .compile().as_text()
+    return _without_locations(text)
+
+
+@pytest.mark.parametrize("preset", ["tiny", "tiny_moe"])
+def test_without_device_memory_the_step_is_the_parents(preset, monkeypatch):
+    """With no memory statistics the compiled tiny step is, instruction for
+    instruction, the one of a tree without the rule: a layer under
+    ``save_splash_residuals`` in which nothing is named."""
+    config = getattr(llama.LlamaConfig, preset)()
+    ours = _tiny_step_text(config)
+    monkeypatch.setattr(llama, "_layer_policy",
+                        lambda *a: save_splash_residuals)
+    for module in (llama, moe):
+        monkeypatch.setattr(module, "checkpoint_name", lambda x, name: x)
+    assert _tiny_step_text(config) == ours
+
+
+# ---------------------------------------------------------------- TrainStep
+def _step_and_state(config):
+    optimizer = llama.make_optimizer()
+    params = llama.init_params(config, jax.random.key(0))
+    return (llama.make_train_step(config, optimizer), params,
+            optimizer.init(params))
+
+
+def test_first_call_says_what_was_kept(monkeypatch):
+    device_telemetry.reset()
+    on_device(monkeypatch, ROOMY)
+    config = llama.LlamaConfig.tiny()
+    step_fn, params, opt_state = _step_and_state(config)
+    step = jit_train_step(step_fn)
+    batch = _batch(config)
+    params, opt_state, _ = step(params, opt_state, *batch)
+    step(params, opt_state, *batch)
+    (row,) = device_telemetry.first_calls("train_step")
+    assert row["remat_kept"] == list(BOTH)
+    assert row["remat_kept_bytes"] > 0 and row["remat_room_bytes"] > 0
+    assert row["remat_fallback"] is False
+
+
+def test_a_refused_richer_step_falls_back_once(monkeypatch, caplog):
+    """A step that kept more and is refused for memory is rebuilt under the
+    plain policy, loudly; the process then stays with it, and a refusal of
+    the plain program is the caller's to see."""
+    device_telemetry.reset()
+    on_device(monkeypatch, ROOMY)
+    config = llama.LlamaConfig.tiny()
+    inner, params, opt_state = _step_and_state(config)
+    refusals = []
+
+    def step_fn(*args):
+        with remat.recording() as decided:
+            out = inner(*args)
+        if decided[-1].kept or refusals == ["always"]:
+            refusals.append(decided[-1].kept)
+            raise RuntimeError("RESOURCE_EXHAUSTED: XLA:TPU compile "
+                               "permanent error. Ran out of memory in hbm.")
+        return out
+
+    before = remat.REMAT_FALLBACKS.get()
+    step = jit_train_step(step_fn)
+    with caplog.at_level("WARNING", logger=remat.__name__):
+        _, _, loss = step(params, opt_state, *_batch(config))
+    assert np.isfinite(float(loss))
+    assert refusals == [BOTH]
+    assert remat.REMAT_FALLBACKS.get() == before + 1
+    assert "refused for memory" in caplog.text
+    (row,) = device_telemetry.first_calls("train_step")
+    assert row["remat_fallback"] is True and row["remat_kept"] == []
+    # a second trace in this process (a tool lowering the step again) gets
+    # the program that ran
+    assert _decide(config).kept == ()
+    # and a plain program that is refused fails
+    refusals[:] = ["always"]
+    inner, params, opt_state = _step_and_state(config)
+    with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+        jit_train_step(step_fn)(params, opt_state, *_batch(config))
+    assert remat.REMAT_FALLBACKS.get() == before + 1
+
+
+class _Refused:
+    """A jitted step whose call is refused for memory; ``by_compiler`` says
+    whether compiling it alone is refused too."""
+
+    def __init__(self, jitted, by_compiler):
+        self.jitted, self.by_compiler = jitted, by_compiler
+
+    def __call__(self, *args):
+        self.jitted.lower(*args)  # the call traces: the rule decides
+        raise RuntimeError("RESOURCE_EXHAUSTED: out of memory")
+
+    def lower(self, *args):
+        return self
+
+    def compile(self):
+        if self.by_compiler:
+            raise RuntimeError("RESOURCE_EXHAUSTED: XLA:TPU compile "
+                               "permanent error.")
+
+
+@pytest.mark.parametrize("by_compiler", [True, False])
+def test_across_processes_only_the_compilers_refusal_falls_back(
+        by_compiler, monkeypatch):
+    """A step of several processes is rebuilt only when the compiler refused
+    it, which every process sees alike; a process that ran out of memory on
+    its own raises, since its peers have launched the program."""
+    monkeypatch.setattr(remat, "_job_memory", lambda *a: (ROOMY, 2))
+    config = llama.LlamaConfig.tiny()
+    step_fn, params, opt_state = _step_and_state(config)
+    step = jit_train_step(step_fn)
+    step._jitted = _Refused(step._jitted, by_compiler)
+    before = remat.REMAT_FALLBACKS.get()
+    if by_compiler:
+        device_telemetry.reset()
+        _, _, loss = step(params, opt_state, *_batch(config))
+        assert np.isfinite(float(loss))
+        # the step was traced again (jax keeps the refused trace under the
+        # function it came from), and the record says what runs
+        (row,) = device_telemetry.first_calls("train_step")
+        assert row["remat_fallback"] is True and row["remat_kept"] == []
+        assert _decide(config).kept == ()
+    else:
+        with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+            step(params, opt_state, *_batch(config))
+        assert _decide(config).kept == BOTH
+    assert remat.REMAT_FALLBACKS.get() == before + by_compiler
+
+
+# ------------------------------------------------------ create_sharded_state
+def test_moments_are_born_under_their_parameters_shardings():
+    """ROADMAP A1 (1): under ``MeshSpec(fsdp=4)`` every leaf of the Adam
+    moments lies as its parameter does, the counts replicated; optax makes
+    them from zeros, which carry nothing for the compiler to propagate."""
+    config = llama.LlamaConfig.tiny()
+    mesh = make_mesh(MeshSpec(fsdp=4), jax.devices()[:4])
+    params, opt_state = create_sharded_state(
+        lambda key: llama.init_params(config, key),
+        llama.logical_axes(config), mesh, jax.random.key(0),
+        llama.make_optimizer())
+    like = jax.tree.structure(params)
+    mirrors = [node for node in jax.tree.leaves(
+        opt_state, is_leaf=lambda n: jax.tree.structure(n) == like)
+        if jax.tree.structure(node) == like]
+    assert len(mirrors) == 2  # mu and nu
+    for mirror in mirrors:
+        for moment, param in zip(jax.tree.leaves(mirror),
+                                 jax.tree.leaves(params)):
+            assert moment.sharding.is_equivalent_to(param.sharding,
+                                                    param.ndim)
+    assert any(not p.sharding.is_fully_replicated
+               for p in jax.tree.leaves(params))
+    counts = [leaf for leaf in jax.tree.leaves(opt_state) if leaf.ndim == 0]
+    assert counts and all(c.sharding.is_fully_replicated for c in counts)
+
+
+def test_on_one_device_the_state_init_is_todays(monkeypatch):
+    """On one device ``optimizer.init`` is jitted without ``out_shardings``,
+    as it always was: the executable and its cache entry stay."""
+    config = llama.LlamaConfig.tiny()
+    seen = []
+    real_jit = jax.jit
+
+    def spy(fn, **kwargs):
+        seen.append(kwargs)
+        return real_jit(fn, **kwargs)
+
+    monkeypatch.setattr(jax, "jit", spy)
+    mesh = make_mesh(MeshSpec(), jax.devices()[:1])
+    create_sharded_state(lambda key: llama.init_params(config, key),
+                         llama.logical_axes(config), mesh, jax.random.key(0),
+                         llama.make_optimizer())
+    assert seen[-1] == {}
