@@ -36,6 +36,12 @@ hand; no field of the configuration steers it, and a backend that reports no
 memory (the CPU) gets the plain policy.  What the backward still re-does from
 the kept arrays is elementwise: the two RMSNorms, the casts, ``silu(gate) *
 up``, and with QK-norm the q and k products the norm's backward reads.
+
+Under an `fsdp` mesh axis the seven dense projections' weight gradients are
+not the partitioner's product-then-reduce-scatter: ``ops/grad_ring.py`` sums
+each over the chips as a ring of chunk products whose partial sums travel by
+``ppermute`` behind the next product (PR 32).  Which axis of a weight is
+`embed` comes from :func:`logical_axes`; on one device the call is ``x @ w``.
 """
 
 from __future__ import annotations
@@ -51,7 +57,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models import moe as _moe
-from ray_tpu.ops import remat
+from ray_tpu.ops import grad_ring, remat
 from ray_tpu.ops.attention import causal_attention
 from ray_tpu.ops.lm_head import lm_head_cross_entropy
 from ray_tpu.parallel.mesh import DEFAULT_RULES
@@ -274,15 +280,24 @@ def _block(x, blk, config: LlamaConfig):
     dt = config.dtype
     B, S, D = x.shape
     H, KV, hd = config.n_head, config.n_kv_head, config.head_dim
+    axes = logical_axes(config)["blocks"]
+
+    def dense(a, name):
+        """``a @ blk[name]`` in the compute dtype; under `fsdp` the weight's
+        gradient is summed while it multiplies (``ops/grad_ring.py``), along
+        the axis ``logical_axes`` calls `embed` (the layer axis is scanned
+        away here)."""
+        return grad_ring.dense(a, blk[name].astype(dt),
+                               axes[name][1:].index("embed"))
 
     with jax.named_scope("attn"):
         h = _rmsnorm(x, blk["attn_norm"], config.rms_eps).astype(dt)
-        q = h @ blk["wq"].astype(dt)
-        k = h @ blk["wk"].astype(dt)
+        q = dense(h, "wq")
+        k = dense(h, "wk")
         if config.qk_norm:
             q = _rmsnorm(q, blk["q_norm"], config.rms_eps).astype(dt)
             k = _rmsnorm(k, blk["k_norm"], config.rms_eps).astype(dt)
-        v = (h @ blk["wv"].astype(dt)).reshape(B, S, KV, hd)
+        v = dense(h, "wv").reshape(B, S, KV, hd)
         q = _rope(q.reshape(B, S, H, hd), config.rope_theta)
         k = _rope(k.reshape(B, S, KV, hd), config.rope_theta)
         q, k, v = (checkpoint_name(a, remat.QKV) for a in (q, k, v))
@@ -290,7 +305,7 @@ def _block(x, blk, config: LlamaConfig):
         # and the dispatcher repeats them for the paths that cannot.
         attn = causal_attention(q, k, v, config.attn_impl).astype(dt) \
             .reshape(B, S, H * hd)
-        x = x + attn @ blk["wo"].astype(dt)
+        x = x + dense(attn, "wo")
 
     with jax.named_scope("mlp"):
         h = _rmsnorm(x, blk["mlp_norm"], config.rms_eps)
@@ -302,10 +317,10 @@ def _block(x, blk, config: LlamaConfig):
                 norm_topk_prob=config.norm_topk_prob, dtype=dt)
             return x + y, router_losses
         h = h.astype(dt)
-        gate = checkpoint_name(h @ blk["w_gate"].astype(dt), remat.GATE_UP)
-        up = checkpoint_name(h @ blk["w_up"].astype(dt), remat.GATE_UP)
+        gate = checkpoint_name(dense(h, "w_gate"), remat.GATE_UP)
+        up = checkpoint_name(dense(h, "w_up"), remat.GATE_UP)
         act = jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
-        x = x + (act.astype(dt) @ blk["w_down"].astype(dt))
+        x = x + dense(act.astype(dt), "w_down")
     return x, None
 
 

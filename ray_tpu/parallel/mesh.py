@@ -5,7 +5,10 @@ over Ray-provided process groups).  Here parallelism is first-class: a
 ``MeshSpec`` names the six standard axes and maps them onto the physical
 device grid; shardings are expressed as PartitionSpecs over these names and
 XLA inserts the collectives (psum for dp/fsdp grad sync, all-gather for fsdp
-params, all-to-all/ppermute for sp) — the scaling-book recipe.
+params, all-to-all/ppermute for sp) — the scaling-book recipe.  The one
+collective written by hand is the reduction of the Llama layers' weight
+gradients over `fsdp` (``ops/grad_ring.py``: a ring of ``ppermute``s between
+chunk products, where XLA's reduce-scatter would run alone).
 
 Axes (outermost → innermost = slowest → fastest links):
   pipe   — pipeline parallel (GPipe microbatch schedule, parallel/pipeline.py;
@@ -13,7 +16,8 @@ Axes (outermost → innermost = slowest → fastest links):
            the slowest links — put it across DCN on multi-slice)
   data   — pure data parallel (gradient psum)
   fsdp   — data parallel with parameter/optimizer sharding (ZeRO-3 equiv:
-           XLA all-gathers params per layer, reduce-scatters grads)
+           XLA all-gathers params per layer and reduce-scatters grads, but
+           the Llama layers' weight gradients, which ops/grad_ring.py sums)
   expert — where MoE expert weights are stored (sharded on their expert
            axis; models/moe.py gathers them to compute, tokens stay put:
            no all_to_all dispatch is built)

@@ -5,8 +5,12 @@ train_loop_utils.py prepare_model DDP/FSDP wrap) with no wrapper at all:
 parameters are placed with their logical shardings, optimizer state is
 *computed from them under jit* so XLA propagates the same shardings onto the
 Adam moments (optimizer sharding = ZeRO), and the train step is jitted with
-donated state — gradient synchronization is derived by the partitioner, not
-written by hand.
+donated state.  Gradient synchronization is derived by the partitioner, with
+one exception since PR 32: under an `fsdp` axis the weight gradients of the
+Llama layer's seven projections are reduced by a hand-written ring of chunk
+products and ``ppermute``s (``ops/grad_ring.py``), because the partitioner's
+reduce-scatter of them runs alone on the TPU and the ring's sends run behind
+matmuls.  Every other collective of the step is the partitioner's.
 
 The jitted step is a :class:`TrainStep`: the same ``jax.jit`` dispatch, which
 also names its host side (``train.dispatch`` on the profiler's clock, the
@@ -31,7 +35,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops import remat
+from ray_tpu.ops import grad_ring, remat
 from ray_tpu.parallel.mesh import pytree_sharding
 from ray_tpu.util import device_telemetry, tracing
 
@@ -109,9 +113,11 @@ def make_train_step(loss_fn, optimizer):
     for a decoder's ``loss_fn(params, tokens, targets)``.
 
     Under jit with sharded inputs this is the whole distributed step: XLA
-    derives the gradient psum/reduce-scatter from the shardings — there is no
-    hand-written gradient sync (the DDP allreduce of the reference's
-    _TorchBackend lives inside the compiled program here).  The update runs
+    derives the gradient psum/reduce-scatter from the shardings (the DDP
+    allreduce of the reference's _TorchBackend lives inside the compiled
+    program here).  The one gradient sync written by hand sits below this
+    function, in the model: the Llama layer's weight gradients under `fsdp`
+    (``ops/grad_ring.py``).  The update runs
     under the ``optimizer`` scope, which :func:`classify_op_name` reads as
     the step's ``update`` phase.
     """
@@ -157,7 +163,9 @@ class TrainStep:
     A call that compiled is a ``train.first_call`` span and a first-call
     record of the registry; both say what the model's layers keep for the
     backward there (``remat_kept``, ``remat_kept_bytes``, ``remat_room_bytes``:
-    ``ops/remat.py`` decides it while the step is traced).
+    ``ops/remat.py`` decides it while the step is traced) and how many weight
+    gradients were traced as rings over `fsdp` (``grad_ring_products``,
+    ``grad_ring_axis``: ``ops/grad_ring.py``; 0 and 0 on one chip).
 
     A step that was traced in this call, keeps more than the plain policy
     would and is refused for memory (``RESOURCE_EXHAUSTED``, at compile or
@@ -191,7 +199,7 @@ class TrainStep:
             self.label, lambda: self._sign(args, kwargs))
         fell_back = False
         with tracing.annotate("train.dispatch"), label, self._in_mesh(), \
-                remat.recording() as decided:
+                remat.recording() as decided, grad_ring.recording() as rings:
             try:
                 out = self._jitted(*args, **kwargs)
             except RuntimeError as e:  # XLA's; re-raised unless remat's
@@ -211,7 +219,7 @@ class TrainStep:
         if label.compiles:
             end = time.time()
             attributes = dict(decided[-1].attributes() if decided else {},
-                              remat_fallback=fell_back)
+                              remat_fallback=fell_back, **rings.attributes())
             device_telemetry.record_first_call(self.label, seconds, ts=end,
                                                **attributes)
             tracing.record_span(

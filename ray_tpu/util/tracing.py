@@ -102,7 +102,8 @@ SPAN_REGISTRY: Dict[str, str] = {
                         "executable (trace, lower, compile or cache load, "
                         "dispatch), recorded after the fact (attrs: label, "
                         "compile_s, remat_kept, remat_kept_bytes, "
-                        "remat_room_bytes, remat_fallback)",
+                        "remat_room_bytes, remat_fallback, "
+                        "grad_ring_products, grad_ring_axis)",
     "train.report": "session: one train.report() call, step boundary "
                     "included",
     "train.init_params": "create_sharded_state: parameters initialised "
