@@ -17,6 +17,18 @@ place of the SwiGLU MLP, the layer scan carries each layer's two router
 losses out, and ``loss_fn`` adds them to the cross-entropy.  Without experts
 none of this is traced: Mistral's program is what it was.
 
+Further published facts, each defaulting to that program (SDAR-30B-A3B):
+``head_dim`` apart from ``d_model / n_head``; ``qk_norm="head"``, an RMSNorm
+over each head's ``head_dim`` with one vector shared by the heads;
+``experts_held``, the run of expert ids this chip holds of every layer
+(``models/moe.py``: the router stays ``n_experts`` wide, parameters exist for
+the held experts only); and ``block_length`` / ``mask_token_id`` /
+``noise_seed``, block-diffusion training (``models/block_diffusion.py``): the
+layers see the noised and the clean copy of a row, 2S positions under
+``ops.attention.block_diffusion_attention`` with RoPE positions that restart
+at the clean copy, and :func:`loss_fn` is the weighted cross-entropy of the
+noised copy's masked positions, the head run on those S positions only.
+
 What the layer's ``jax.checkpoint`` keeps: the layer's input ``x`` (whole-block
 remat) and, where the splash kernel runs, the kernel's attention output and
 log-sum-exp (``ops.attention.save_splash_residuals``), one more (B, S, D)
@@ -49,20 +61,22 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass
 from functools import partial
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
+from ray_tpu.models import block_diffusion
 from ray_tpu.models import moe as _moe
 from ray_tpu.ops import grad_ring, remat
-from ray_tpu.ops.attention import causal_attention
+from ray_tpu.ops.attention import block_diffusion_attention, causal_attention
 from ray_tpu.ops.lm_head import lm_head_cross_entropy
 from ray_tpu.parallel.mesh import DEFAULT_RULES
 from ray_tpu.parallel.train_state import make_optimizer  # noqa: F401
 from ray_tpu.parallel.train_state import make_train_step as _make_train_step
+from ray_tpu.parallel.train_state import note_first_call
 
 
 @dataclass(frozen=True)
@@ -73,6 +87,8 @@ class LlamaConfig:
     #: grouped-query attention: kv heads < query heads share k/v
     n_kv_head: int = 4
     d_model: int = 512
+    #: width of one head; 0 means ``d_model // n_head``
+    head_dim: int = 0
     #: SwiGLU hidden dim (Llama uses ~8/3 * d_model rounded to 256)
     d_ff: int = 1408
     seq_len: int = 1024
@@ -90,17 +106,30 @@ class LlamaConfig:
     experts_per_token: int = 0
     #: renormalise the chosen experts' probabilities to sum to one
     norm_topk_prob: bool = False
-    #: RMSNorm over the whole q and the whole k projection, before the heads
-    #: are split and RoPE is applied
-    qk_norm: bool = False
+    #: True: RMSNorm over the whole q and the whole k projection, before
+    #: the heads are split (OLMoE).  "head": over each head's head_dim, one
+    #: vector shared by the heads (SDAR).  Either way before RoPE.
+    qk_norm: Union[bool, str] = False
     #: loss = CE + router_aux_loss_coef x load-balance + router_z_loss_coef x
     #: z, each summed over the layers (moe.router_losses)
     router_aux_loss_coef: float = 0.0
     router_z_loss_coef: float = 0.0
+    #: the run of expert ids this chip holds of every layer, a ``range``
+    #: (models/moe.py); None means all ``n_experts``: read :attr:`held`
+    experts_held: Optional[range] = None
+    # Block-diffusion training (models/block_diffusion.py); 0 is causal
+    # next-token training.
+    block_length: int = 0
+    #: the id a noised position shows; inside the vocabulary, outside the data
+    mask_token_id: int = 0
+    #: the noise is a function of a row's ids and this
+    noise_seed: int = 0
 
     @property
-    def head_dim(self) -> int:
-        return self.d_model // self.n_head
+    def held(self) -> range:
+        """The expert ids whose matrices exist here."""
+        return range(self.n_experts) if self.experts_held is None \
+            else self.experts_held
 
     @property
     def q_per_kv(self) -> int:
@@ -120,11 +149,31 @@ class LlamaConfig:
                            router_aux_loss_coef=0.01,
                            router_z_loss_coef=0.001)
 
+    @staticmethod
+    def tiny_sdar() -> "LlamaConfig":
+        """SDAR's shape in small: heads of 64 at d_model / n_head = 32, GQA,
+        per-head QK-norm, experts 2 and 3 of 8 held, blocks of 4."""
+        return LlamaConfig(vocab_size=1024, n_layer=2, n_head=4, n_kv_head=2,
+                           d_model=128, head_dim=64, d_ff=64, seq_len=128,
+                           n_experts=8, experts_per_token=2,
+                           norm_topk_prob=True, qk_norm="head",
+                           experts_held=range(2, 4),
+                           router_aux_loss_coef=0.001, block_length=4,
+                           mask_token_id=1023)
+
     def __post_init__(self):
-        assert self.d_model % self.n_head == 0
+        if not self.head_dim:
+            assert self.d_model % self.n_head == 0
+            object.__setattr__(self, "head_dim", self.d_model // self.n_head)
+        held = self.held
+        assert held.step == 1 and 0 <= held.start <= held.stop \
+            <= self.n_experts and bool(held) == bool(self.n_experts), held
+        assert self.qk_norm in (False, True, "head"), self.qk_norm
         assert self.n_head % self.n_kv_head == 0
         assert 0 <= self.experts_per_token <= self.n_experts
         assert (self.n_experts > 0) == (self.experts_per_token > 0)
+        assert not self.block_length \
+            or 0 <= self.mask_token_id < self.vocab_size
 
 
 def init_params(config: LlamaConfig, key) -> Dict[str, Any]:
@@ -139,8 +188,9 @@ def init_params(config: LlamaConfig, key) -> Dict[str, Any]:
         return jax.random.normal(key, shape, jnp.float32) * s
 
     ks = jax.random.split(k_blocks, 8)
-    # With experts the three MLP matrices gain a leading expert axis.
-    E = (config.n_experts,) if config.n_experts else ()
+    # With experts the three MLP matrices gain a leading axis over the
+    # experts held here.
+    E = (len(config.held),) if config.n_experts else ()
     blocks = {
         "attn_norm": jnp.ones((L, D)),
         "wq": norm(ks[0], (L, D, H * hd), std),
@@ -154,7 +204,10 @@ def init_params(config: LlamaConfig, key) -> Dict[str, Any]:
     }
     if config.n_experts:
         blocks["router"] = norm(ks[7], (L, D, config.n_experts), std)
-    if config.qk_norm:
+    if config.qk_norm == "head":
+        blocks["q_norm"] = jnp.ones((L, hd))
+        blocks["k_norm"] = jnp.ones((L, hd))
+    elif config.qk_norm:
         blocks["q_norm"] = jnp.ones((L, H * hd))
         blocks["k_norm"] = jnp.ones((L, KV * hd))
     return {
@@ -193,28 +246,44 @@ def logical_axes(config: LlamaConfig) -> Dict[str, Any]:
     }
 
 
+def _attn_params(config: LlamaConfig) -> int:
+    hd = config.head_dim
+    return config.d_model * hd * 2 * (config.n_head + config.n_kv_head)
+
+
 def num_params(config: LlamaConfig) -> int:
+    """Parameters that exist here: of the experts, the held ones."""
     D, L, V, F = (config.d_model, config.n_layer, config.vocab_size,
                   config.d_ff)
-    hd = config.head_dim
-    attn = D * config.n_head * hd + 2 * D * config.n_kv_head * hd \
-        + config.n_head * hd * D
+    attn = _attn_params(config)
     mlp = 3 * D * F
     if config.n_experts:
-        mlp = config.n_experts * mlp + D * config.n_experts
-    if config.qk_norm:
-        attn += (config.n_head + config.n_kv_head) * hd
+        mlp = len(config.held) * mlp + D * config.n_experts
+    if config.qk_norm == "head":
+        attn += 2 * config.head_dim
+    elif config.qk_norm:
+        attn += (config.n_head + config.n_kv_head) * config.head_dim
     per_block = 2 * D + attn + mlp
     return 2 * V * D + L * per_block + D
 
 
 def flops_per_token(config: LlamaConfig) -> float:
-    """6 x the parameters a token meets (of the experts, its own) plus
-    causal attention."""
-    idle = (config.n_experts - config.experts_per_token) * 3 \
-        * config.d_model * config.d_ff * config.n_layer
-    return 6.0 * (num_params(config) - idle) \
-        + 12.0 * config.n_layer * config.d_model * config.seq_len
+    """Per trained token: 6 x the parameters a position meets (of the held
+    experts its own, in expectation under an even router) plus attention,
+    12 x width x S a layer (the whole square, as ever).  A block-diffusion
+    row sends two positions a trained token through the layers and one
+    through the head, and its attention is counted over the mask's own area,
+    S^2 + S x block_length a head."""
+    L, S = config.n_layer, config.seq_len
+    held = len(config.held)
+    idle = held * (1 - config.experts_per_token / max(config.n_experts, 1)) \
+        * 3 * config.d_model * config.d_ff * L
+    outside = (2 * config.vocab_size + 1) * config.d_model
+    layers = num_params(config) - outside - idle
+    copies, area = (2, S + config.block_length) if config.block_length \
+        else (1, S)
+    return 6.0 * (copies * layers + outside) \
+        + 12.0 * L * config.n_head * config.head_dim * area
 
 
 def _rmsnorm(x, scale, eps):
@@ -294,17 +363,28 @@ def _block(x, blk, config: LlamaConfig):
         h = _rmsnorm(x, blk["attn_norm"], config.rms_eps).astype(dt)
         q = dense(h, "wq")
         k = dense(h, "wk")
+        if config.qk_norm == "head":
+            q, k = q.reshape(B, S, H, hd), k.reshape(B, S, KV, hd)
         if config.qk_norm:
             q = _rmsnorm(q, blk["q_norm"], config.rms_eps).astype(dt)
             k = _rmsnorm(k, blk["k_norm"], config.rms_eps).astype(dt)
         v = dense(h, "wv").reshape(B, S, KV, hd)
-        q = _rope(q.reshape(B, S, H, hd), config.rope_theta)
-        k = _rope(k.reshape(B, S, KV, hd), config.rope_theta)
+        # A block-diffusion row is two copies that share positions: each
+        # rotates as a row of its own, so a position is its axis index.
+        copies = 2 if config.block_length else 1
+        q = _rope(q.reshape(B * copies, S // copies, H, hd),
+                  config.rope_theta).reshape(B, S, H, hd)
+        k = _rope(k.reshape(B * copies, S // copies, KV, hd),
+                  config.rope_theta).reshape(B, S, KV, hd)
         q, k, v = (checkpoint_name(a, remat.QKV) for a in (q, k, v))
         # GQA: k and v go in at KV heads; the splash kernel takes them so,
         # and the dispatcher repeats them for the paths that cannot.
-        attn = causal_attention(q, k, v, config.attn_impl).astype(dt) \
-            .reshape(B, S, H * hd)
+        if config.block_length:
+            attn = block_diffusion_attention(q, k, v, config.block_length,
+                                             config.attn_impl)
+        else:
+            attn = causal_attention(q, k, v, config.attn_impl)
+        attn = attn.astype(dt).reshape(B, S, H * hd)
         x = x + dense(attn, "wo")
 
     with jax.named_scope("mlp"):
@@ -314,7 +394,8 @@ def _block(x, blk, config: LlamaConfig):
             # cast to the compute dtype
             y, router_losses = _moe.moe_mlp(
                 h, blk, experts_per_token=config.experts_per_token,
-                norm_topk_prob=config.norm_topk_prob, dtype=dt)
+                norm_topk_prob=config.norm_topk_prob, dtype=dt,
+                first_held=config.held.start)
             return x + y, router_losses
         h = h.astype(dt)
         gate = checkpoint_name(dense(h, "w_gate"), remat.GATE_UP)
@@ -363,10 +444,18 @@ def _layer_sizes(params, x_shape, config: LlamaConfig):
         layer_bytes=sum(jax.tree.leaves(whole["blocks"])) // config.n_layer,
         sharded=total < sum(jax.tree.leaves(whole)), tokens=tokens,
         d_model=config.d_model, n_layer=config.n_layer,
-        attn_width=config.d_model // tensor, n_head=config.n_head // tensor,
+        attn_width=config.n_head * config.head_dim // tensor,
+        n_head=config.n_head // tensor,
         mlp_width=mlp_width, vocab=config.vocab_size // tensor,
         itemsize=item,
         logits_itemsize=jnp.dtype(config.logits_dtype).itemsize)
+    if config.n_experts:
+        # what the bound does not know of: the expert layer moves every
+        # (position, expert) pair as a row of d_model (models/moe.py): the
+        # rows in expert order, the down-projection's output, and the
+        # cotangent of each
+        temporaries += 4 * tokens * config.experts_per_token \
+            * config.d_model * item
     per_layer = {remat.QKV: tokens * qkv_width * item,
                  remat.GATE_UP: 2 * tokens * mlp_width * item}
     return ([(name, config.n_layer * per_layer[name])
@@ -413,10 +502,27 @@ def forward(params: Dict[str, Any], tokens, config: LlamaConfig):
 
 
 def loss_fn(params, tokens, targets, config: LlamaConfig):
+    """Mean next-token cross-entropy of ``tokens`` against ``targets``, plus
+    the router losses.  With a ``block_length``: the block-diffusion loss of
+    the rows ``tokens`` (``models/block_diffusion.py``), a masked position
+    predicting the id it covers; ``targets`` is then not read."""
+    weights = None
+    if config.block_length:
+        targets = tokens
+        tokens, weights = block_diffusion.noise(
+            tokens, config.noise_seed, config.block_length,
+            config.mask_token_id)
+    note_first_call(experts_held=len(config.held),
+                    experts_total=config.n_experts,
+                    block_length=config.block_length,
+                    attn_positions=tokens.shape[1],
+                    loss_positions=targets.shape[1])
     x, router_loss = forward_hidden(params, tokens, config)
     with jax.named_scope("lm_head"):
+        if config.block_length:  # the head reads the noised copy, the first
+            x = x[:, :targets.shape[1]]
         ce = lm_head_cross_entropy(x, params["lm_head"].astype(config.dtype),
-                                   targets, config.logits_dtype)
+                                   targets, config.logits_dtype, weights)
     return ce if router_loss is None else ce + router_loss
 
 

@@ -41,9 +41,19 @@ Mosaic call cannot be partitioned.  The only numbers that cross chips are the
 router losses' 2E + 1 means (one ``pmean``).  The router's and the experts'
 weights enter that ``shard_map`` replicated: however they are stored (``embed`` over `fsdp`, the expert axis
 over `expert`), XLA gathers them on the way in and reduces their gradients on
-the way out, which is FSDP's meaning.  True expert parallelism (experts
-resident on their own chips, tokens exchanged by a ragged all-to-all) is not
-built.
+the way out, which is FSDP's meaning.
+
+**A chip's share of the experts** (``experts_held``, a run of expert ids): what
+one chip of an expert-parallel job holds of a layer.  The router keeps its
+published width and its experts a token, all N x k pairs are sorted as above,
+and the grouped products run over the held groups only (``rhs`` is the held
+experts' matrices, the kernels' ``group_offset``): rows of an absent expert
+come out zero, cost no product, and add nothing in the combine.  Parameters,
+gradients and optimizer state exist for the held experts only.  What is not
+built is the exchange: the ragged all-to-all that would bring this chip the
+other chips' rows for its experts and send its own rows to theirs.  On one
+chip the layer computes its own experts' part for its own tokens, and what
+the absent experts would add is left out.
 """
 
 from __future__ import annotations
@@ -160,32 +170,43 @@ _weights_to_expert_order.defvjp(_weights_to_expert_order_fwd,
                                 _weights_to_expert_order_bwd)
 
 
-def expert_mlp(x, weights, experts, w_gate, w_up, w_down):
+def expert_mlp(x, weights, experts, w_gate, w_up, w_down, n_experts=None,
+               first_held: int = 0):
     """One chip's tokens through their experts.  x: (N, D); weights,
-    experts: (N, k); w_gate, w_up: (E, D, F); w_down: (E, F, D), all in the
-    compute dtype.  -> (N, D)."""
+    experts: (N, k); w_gate, w_up: (H, D, F); w_down: (H, F, D), all in the
+    compute dtype: the matrices of experts ``first_held .. first_held + H``
+    of ``n_experts`` (default H: every expert is held).  -> (N, D)."""
+    held = w_gate.shape[0]
+    n_experts = n_experts or held
     with jax.named_scope("moe_dispatch"):
-        order, inverse, group_sizes = sort_pairs(experts, w_gate.shape[0])
+        order, inverse, group_sizes = sort_pairs(experts, n_experts)
         rows = _to_expert_order(x, order, inverse)
         w_rows = _weights_to_expert_order(weights, order, inverse)
-    with jax.named_scope("experts"):
-        gate = checkpoint_name(grouped_matmul(rows, w_gate, group_sizes),
-                               remat.GATE_UP)
-        up = checkpoint_name(grouped_matmul(rows, w_up, group_sizes),
-                             remat.GATE_UP)
+    # the products' scope says whether the layer holds a share
+    with jax.named_scope("experts") if held == n_experts \
+            else jax.named_scope("moe_held"):
+        gate = checkpoint_name(
+            grouped_matmul(rows, w_gate, group_sizes, first_held),
+            remat.GATE_UP)
+        up = checkpoint_name(
+            grouped_matmul(rows, w_up, group_sizes, first_held),
+            remat.GATE_UP)
         act = (jax.nn.silu(gate.astype(jnp.float32))
                * up.astype(jnp.float32)
                * w_rows[:, None].astype(jnp.float32)).astype(x.dtype)
-        out = grouped_matmul(act, w_down, group_sizes)
+        out = grouped_matmul(act, w_down, group_sizes, first_held)
     with jax.named_scope("moe_dispatch"):
         return _combine(out, order, inverse)
 
 
-def moe_mlp(h32, blk, *, experts_per_token: int, norm_topk_prob: bool, dtype):
+def moe_mlp(h32, blk, *, experts_per_token: int, norm_topk_prob: bool, dtype,
+            first_held: int = 0):
     """The layer.  h32: (B, S, D) float32, the block's normed input (the
     router reads it as it is, the experts its cast to ``dtype``); ``blk``
-    holds ``router`` (D, E), ``w_gate``, ``w_up`` (E, D, F), ``w_down``
-    (E, F, D).  -> (y (B, S, D) in ``dtype``, (load-balance, z))."""
+    holds ``router`` (D, E) and the matrices of the H experts from
+    ``first_held`` on, ``w_gate``, ``w_up`` (H, D, F), ``w_down`` (H, F, D);
+    H = E unless the chip holds a share.  -> (y (B, S, D) in ``dtype``,
+    (load-balance, z)), the losses over all E experts."""
     mesh = jax.sharding.get_abstract_mesh()
     sharded = not (mesh.empty or mesh.size == 1)
     batch_axes = tuple(a for a in ("data", "fsdp")
@@ -197,7 +218,7 @@ def moe_mlp(h32, blk, *, experts_per_token: int, norm_topk_prob: bool, dtype):
             weights, experts, losses = route(
                 tokens, router, experts_per_token, norm_topk_prob, batch_axes)
         y = expert_mlp(tokens.astype(dtype), weights, experts, w_gate, w_up,
-                       w_down)
+                       w_down, router.shape[-1], first_held)
         return y.reshape(h32.shape), losses
 
     args = (h32, blk["router"], blk["w_gate"].astype(dtype),

@@ -1,8 +1,12 @@
-"""Causal attention for the decoders: one dispatcher, and the splash kernel.
+"""Attention for the decoders: one dispatcher with two entry points, and the
+splash kernel.
 
 :func:`causal_attention` is what a model's block calls, with its
-``attn_impl`` string.  It decides the implementation, and everything that
-follows from the choice lives here with it:
+``attn_impl`` string; :func:`block_diffusion_attention` is the same for a
+block-diffusion row (``models/block_diffusion.py``: 2S positions, the noised
+and the clean copy, under the three-part block mask).  The dispatcher decides
+the implementation, and everything that follows from the choice lives here
+with it:
 
 - ``"splash"`` is the fused flash kernel below; ``"auto"`` is splash on the
   TPU and the einsum elsewhere (the CPU tests' reference).  A kernel the
@@ -12,7 +16,8 @@ follows from the choice lives here with it:
   is what the kernel exists to avoid.
 - ``"ring"`` / ``"ulysses"`` are the context-parallel paths
   (``ops/ring_attention.py``): attention runs seq-sharded over the ambient
-  mesh's `seq` axis (``jit_train_step(mesh=)`` installs the mesh).
+  mesh's `seq` axis (``jit_train_step(mesh=)`` installs the mesh).  Causal
+  only: the block mask has no context-parallel path.
 
 q is (B, S, H, head_dim) and k, v are (B, S, KV, head_dim) with H a multiple
 of KV: grouped-query attention arrives at its own head count (KV == H is plain
@@ -24,7 +29,11 @@ want equal head counts, and the dispatcher repeats K and V for them, deciding
 from the implementation it is about to call and the shapes it holds.
 
 Splash keeps scores in VMEM tiles, never materializes them, and skips the
-blocks the causal mask empties.  head_dim=64 compiles unpadded under the
+blocks the mask empties (the causal half; of a block-diffusion row's 2S x 2S
+the noised copy's off-diagonal blocks, the clean copy's upper half and all a
+clean query would read of the noised copy).  A block the mask cuts through
+computes its part of the mask from the positions, in the kernel: no S x S
+array exists.  head_dim=64 compiles unpadded under the
 512x512 blocks on the v5e and agrees with the einsum (chip_smoke.py, kernel
 phase).
 """
@@ -36,6 +45,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 ATTN_IMPLS = ("auto", "xla", "splash", "ring", "ulysses")
 
@@ -65,13 +75,36 @@ def causal_attention(q, k, v, impl: str):
     """Causal attention by the implementation ``impl`` names (a model's
     ``attn_impl``; see the module docstring).  q: (B, S, H, head_dim); k, v:
     (B, S, KV, head_dim), H a multiple of KV.  -> (B, S, H, head_dim)."""
+    return _attention(q, k, v, impl, 0)
+
+
+def block_diffusion_attention(q, k, v, block_length: int, impl: str):
+    """Attention over the noised and the clean copy of a row under
+    :func:`block_diffusion_allowed`.  q: (B, 2S, H, head_dim); k, v:
+    (B, 2S, KV, head_dim), the noised copy's S positions first.
+    -> (B, 2S, H, head_dim)."""
+    if block_length < 1 or (q.shape[1] // 2) % block_length:
+        raise ValueError(
+            f"block_diffusion_attention: block_length {block_length} must "
+            f"divide the row's {q.shape[1] // 2} positions")
+    return _attention(q, k, v, impl, block_length)
+
+
+def _attention(q, k, v, impl: str, block_length: int):
+    """The dispatcher.  ``block_length`` 0: causal over S positions; else the
+    block-diffusion mask over 2S."""
     if impl not in ATTN_IMPLS:
         raise ValueError(
             f"Unknown attn_impl: {impl!r} (use {'|'.join(ATTN_IMPLS)})")
+    if block_length and impl in ("ring", "ulysses"):
+        raise ValueError(
+            f"attn_impl {impl!r} is causal only: a block-diffusion row runs "
+            "under auto|splash|xla")
     with jax.named_scope("attn_kernel"):
         if impl == "splash" or (impl == "auto"
                                 and jax.default_backend() == "tpu"):
-            return splash_attention(q, k, v, causal=True)
+            return splash_attention(q, k, v, causal=True,
+                                    block_length=block_length)
         if k.shape[2] != q.shape[2]:
             # Each K/V head serves a group of consecutive query heads.
             group = q.shape[2] // k.shape[2]
@@ -89,14 +122,19 @@ def causal_attention(q, k, v, impl: str):
         scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) \
             * scale
         S = q.shape[1]
-        mask = jnp.tril(jnp.ones((S, S), jnp.bool_))
+        if block_length:
+            at = np.arange(S)
+            mask = block_diffusion_allowed(at[:, None], at[None, :], S // 2,
+                                           block_length)
+        else:
+            mask = jnp.tril(jnp.ones((S, S), jnp.bool_))
         scores = jnp.where(mask, scores, -1e30)
         probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
         return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
 def _splash_kernel(seq_len: int, n_heads: int, block_q: int, block_kv: int,
-                   causal: bool):
+                   causal: bool, block_length: int = 0):
     # NOT cached: the kernel object built during one jit trace captures that
     # trace's context — reusing it from a later trace raises
     # UnexpectedTracerError.  Construction is cheap (lazy mask, no arrays).
@@ -105,9 +143,11 @@ def _splash_kernel(seq_len: int, n_heads: int, block_q: int, block_kv: int,
         splash_attention_mask as sm,
     )
 
-    mask_cls = sm.CausalMask if causal else sm.FullMask
-    mask = sm.MultiHeadMask(
-        [mask_cls((seq_len, seq_len)) for _ in range(n_heads)])
+    if block_length:
+        mask = _block_diffusion_mask(sm, seq_len, block_length)
+    else:
+        mask = (sm.CausalMask if causal else sm.FullMask)((seq_len, seq_len))
+    mask = sm.MultiHeadMask([mask] * n_heads)
     interpret = jax.default_backend() != "tpu"
     bq = min(block_q, seq_len)
     bkv = min(block_kv, seq_len)
@@ -121,12 +161,60 @@ def _splash_kernel(seq_len: int, n_heads: int, block_q: int, block_kv: int,
                               residual_checkpoint_name=SPLASH_RESIDUALS)
 
 
+def block_diffusion_allowed(i, j, seq_len: int, block_length: int):
+    """May query position ``i`` read key position ``j``?  Both index the 2S
+    positions ``[noised ; clean]`` of a row of ``seq_len`` ids in blocks of
+    ``block_length`` (``models/block_diffusion.py`` has the why, and hands
+    this on as ``allowed``): a noised query reads the noised keys of its own
+    block and the clean keys of earlier blocks; a clean query reads the clean
+    keys of its own and earlier blocks; nothing else.  numpy or jax integer
+    arrays that broadcast: the splash kernel calls it on both."""
+    def block_of(at):
+        at = at - seq_len * (at >= seq_len)
+        # The kernel computes this for every pair of a tile the mask cuts
+        # through, and the chip's vector unit has no integer divide: ``//``
+        # cost 18 of 890 ms a step in ``sdar-ep8-s8192`` (PERF.md, PR 34).
+        # A power of two is a shift.
+        if block_length & (block_length - 1) == 0:
+            return at >> (block_length.bit_length() - 1)
+        return at // block_length
+
+    q_noised, k_noised = i < seq_len, j < seq_len
+    bi, bj = block_of(i), block_of(j)
+    return (q_noised & k_noised & (bi == bj)) \
+        | (q_noised & ~k_noised & (bj < bi)) \
+        | (~q_noised & ~k_noised & (bj <= bi))
+
+
+def _block_diffusion_mask(sm, positions: int, block_length: int):
+    """:func:`block_diffusion_allowed` over a row's 2S ``positions`` as a
+    mask the splash kernel computes from the positions (its lazy masks'
+    base class: the library has no public one; ``CausalMask`` is built the
+    same way)."""
+    class BlockDiffusionMask(sm._ComputableMask):
+        # one instance serves every head; the library keeps distinct masks
+        # apart by these
+        def __eq__(self, other):
+            return self is other
+
+        def __hash__(self):
+            return hash((type(self).__name__, positions, block_length))
+
+    return BlockDiffusionMask(
+        shape=(positions, positions),
+        mask_function=lambda q_ids, kv_ids: block_diffusion_allowed(
+            q_ids, kv_ids, positions // 2, block_length))
+
+
 def splash_attention(q, k, v, causal: bool = True,
                      sm_scale: Optional[float] = None,
-                     block_q: int = 512, block_kv: int = 512):
+                     block_q: int = 512, block_kv: int = 512,
+                     block_length: int = 0):
     """Production TPU attention (splash kernel): sparse over the causal
     mask when causal (no wasted upper-triangle work), full-mask
-    bidirectional (ViT-style) otherwise, with a fused dq/dkv backward.
+    bidirectional (ViT-style) otherwise, with a fused dq/dkv backward.  With
+    a ``block_length`` the sequence is a block-diffusion row's 2S positions
+    and the mask :func:`block_diffusion_allowed` (``causal`` is not read).
 
     q: (B, S, H, head_dim), k and v: (B, S, KV, head_dim), the model's native
     layout; H is a multiple of KV, and query head ``h`` attends to K/V head
@@ -146,7 +234,8 @@ def splash_attention(q, k, v, causal: bool = True,
         sm_scale = 1.0 / math.sqrt(hd)
 
     def local(q, k, v):
-        kernel = _splash_kernel(S, q.shape[2], block_q, block_kv, causal)
+        kernel = _splash_kernel(S, q.shape[2], block_q, block_kv, causal,
+                                block_length)
         # Splash takes (H, S, hd) per example; scale q up front (no scale arg).
         qt = (q * sm_scale).transpose(0, 2, 1, 3)
         kt = k.transpose(0, 2, 1, 3)

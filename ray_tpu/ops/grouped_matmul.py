@@ -6,7 +6,9 @@ contiguous groups, group g multiplied by its own ``rhs[g]``.
 What a dropless mixture-of-experts layer needs (``models/moe.py``): every
 (token, expert) pair is a row, the groups are the experts, their sizes are
 data.  Shapes are static; only ``group_sizes`` varies, and an empty group
-costs nothing.
+costs nothing.  ``rhs`` may hold a run of the groups only (a chip's share of
+the experts, ``first_group`` onwards): the other groups' rows come out zero
+and their tiles are not visited.
 
 One implementation: the Pallas ``megablox`` kernels that ship with jax
 (``gmm`` for the forward and for dx, ``tgmm`` for the per-group dW), float32
@@ -20,9 +22,11 @@ cannot be partitioned).
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
+import numpy as np
 
 #: (rows, contraction, columns) tile the kernels walk, cut to the operands
 #: where they are smaller.  Timed on the v5e at 65536 x 2048 x 1024 in 64
@@ -45,33 +49,47 @@ def _tiling(m: int, k: int, n: int):
     return math.gcd(m, TILING[0]), min(k, TILING[1]), min(n, TILING[2])
 
 
-@jax.custom_vjp
-def grouped_matmul(lhs, rhs, group_sizes):
-    """lhs: (M, K); rhs: (G, K, N), same dtype; group_sizes: (G,) int32
-    summing to M.  -> (M, N) in that dtype, accumulated in float32."""
+def _held(rhs, group_sizes, first_group: int):
+    """The kernels' ``group_offset``: None where ``rhs`` holds every group,
+    which is the call they always got."""
+    if rhs.shape[0] == group_sizes.shape[0]:
+        return None
+    return np.int32(first_group)  # numpy: a trace-time constant
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def grouped_matmul(lhs, rhs, group_sizes, first_group: int = 0):
+    """lhs: (M, K); rhs: (H, K, N), same dtype, the matrices of groups
+    ``first_group .. first_group + H``; group_sizes: (G,) int32 summing to
+    M, H <= G.  -> (M, N) in that dtype, accumulated in float32; rows of a
+    group ``rhs`` does not hold are zero."""
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
     (m, k), n = lhs.shape, rhs.shape[2]
     return gmm(lhs, rhs, group_sizes, lhs.dtype, _tiling(m, k, n),
+               group_offset=_held(rhs, group_sizes, first_group),
                interpret=_interpret())
 
 
-def _fwd(lhs, rhs, group_sizes):
-    return grouped_matmul(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
+def _fwd(lhs, rhs, group_sizes, first_group):
+    return (grouped_matmul(lhs, rhs, group_sizes, first_group),
+            (lhs, rhs, group_sizes))
 
 
-def _bwd(res, g):
+def _bwd(first_group, res, g):
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
 
     lhs, rhs, group_sizes = res
     (m, k), n = lhs.shape, rhs.shape[2]
     interpret = _interpret()
+    offset = _held(rhs, group_sizes, first_group)
     # dx: the same grouped product against each group's transposed matrix
     dlhs = gmm(g, rhs, group_sizes, lhs.dtype, _tiling(m, n, k),
-               transpose_rhs=True, interpret=interpret)
-    # dW: per group, its rows of lhs transposed times its rows of g
+               group_offset=offset, transpose_rhs=True, interpret=interpret)
+    # dW: per group held, its rows of lhs transposed times its rows of g
     drhs = tgmm(lhs.swapaxes(0, 1), g, group_sizes, rhs.dtype,
-                _tiling(m, k, n), interpret=interpret)
+                _tiling(m, k, n), group_offset=offset,
+                num_actual_groups=rhs.shape[0], interpret=interpret)
     return dlhs, drhs, None
 
 

@@ -6,8 +6,10 @@ import jax
 import jax.numpy as jnp
 
 
-def lm_head_cross_entropy(x, head, targets, logits_dtype):
-    """Mean token cross-entropy of the logits ``x @ head.T``.
+def lm_head_cross_entropy(x, head, targets, logits_dtype, weights=None):
+    """Mean token cross-entropy of the logits ``x @ head.T``; with
+    ``weights`` (B, S) the sum of each position's cross-entropy times its
+    weight (the caller's weights say what mean that is).
 
     x: (B, S, D) final hidden states; head: (V, D) in the compute dtype (a
     tied head passes the embedding); targets: (B, S) int.  The (B, S, V)
@@ -21,4 +23,6 @@ def lm_head_cross_entropy(x, head, targets, logits_dtype):
     lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
     tgt = jnp.take_along_axis(
         logits, targets[..., None], axis=-1)[..., 0].astype(jnp.float32)
-    return jnp.mean(lse - tgt)
+    if weights is None:
+        return jnp.mean(lse - tgt)
+    return jnp.sum((lse - tgt) * weights)

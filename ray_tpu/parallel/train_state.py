@@ -28,6 +28,7 @@ import contextlib
 import functools
 import re
 import sys
+import threading
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -133,6 +134,30 @@ def make_train_step(loss_fn, optimizer):
     return step
 
 
+_thread = threading.local()  # .notes: the dicts of the calls traced in here
+
+
+def note_first_call(**facts) -> None:
+    """From inside a traced step: ``facts`` about the model being traced,
+    for the ``train.first_call`` span and first-call record of whichever
+    :class:`TrainStep` call is tracing on this thread (``models/llama.py``:
+    the experts held, the block length, how many positions the layers and
+    the loss see).  Outside such a call, nothing."""
+    for notes in getattr(_thread, "notes", ()):
+        notes.update(facts)
+
+
+@contextlib.contextmanager
+def _noting():
+    notes: Dict[str, Any] = {}
+    stack = _thread.__dict__.setdefault("notes", [])
+    stack.append(notes)
+    try:
+        yield notes
+    finally:
+        stack.pop()
+
+
 def jit_train_step(step_fn, donate_state: bool = True, mesh=None):
     """jit with donated (params, opt_state) so updates reuse their buffers —
     the HBM discipline that makes big models fit.  Returns a
@@ -165,7 +190,10 @@ class TrainStep:
     backward there (``remat_kept``, ``remat_kept_bytes``, ``remat_room_bytes``:
     ``ops/remat.py`` decides it while the step is traced) and how many weight
     gradients were traced as rings over `fsdp` (``grad_ring_products``,
-    ``grad_ring_axis``: ``ops/grad_ring.py``; 0 and 0 on one chip).
+    ``grad_ring_axis``: ``ops/grad_ring.py``; 0 and 0 on one chip), and what
+    the model said of itself through :func:`note_first_call`
+    (``models/llama.py``: ``experts_held``, ``experts_total``,
+    ``block_length``, ``attn_positions``, ``loss_positions``).
 
     A step that was traced in this call, keeps more than the plain policy
     would and is refused for memory (``RESOURCE_EXHAUSTED``, at compile or
@@ -199,7 +227,8 @@ class TrainStep:
             self.label, lambda: self._sign(args, kwargs))
         fell_back = False
         with tracing.annotate("train.dispatch"), label, self._in_mesh(), \
-                remat.recording() as decided, grad_ring.recording() as rings:
+                remat.recording() as decided, \
+                grad_ring.recording() as rings, _noting() as notes:
             try:
                 out = self._jitted(*args, **kwargs)
             except RuntimeError as e:  # XLA's; re-raised unless remat's
@@ -219,7 +248,8 @@ class TrainStep:
         if label.compiles:
             end = time.time()
             attributes = dict(decided[-1].attributes() if decided else {},
-                              remat_fallback=fell_back, **rings.attributes())
+                              remat_fallback=fell_back, **rings.attributes(),
+                              **notes)
             device_telemetry.record_first_call(self.label, seconds, ts=end,
                                                **attributes)
             tracing.record_span(

@@ -103,7 +103,9 @@ SPAN_REGISTRY: Dict[str, str] = {
                         "dispatch), recorded after the fact (attrs: label, "
                         "compile_s, remat_kept, remat_kept_bytes, "
                         "remat_room_bytes, remat_fallback, "
-                        "grad_ring_products, grad_ring_axis)",
+                        "grad_ring_products, grad_ring_axis; from "
+                        "models/llama.py experts_held, experts_total, "
+                        "block_length, attn_positions, loss_positions)",
     "train.report": "session: one train.report() call, step boundary "
                     "included",
     "train.init_params": "create_sharded_state: parameters initialised "
@@ -158,6 +160,12 @@ SCOPE_REGISTRY: Dict[str, str] = {
                     "order and back, the weighted sum",
     "experts": "expert layer, nested inside mlp: the grouped matmuls and "
                "the activation between them",
+    "moe_held": "expert layer that holds a share of the experts, nested "
+                "inside mlp in place of experts: the grouped matmuls over "
+                "the held groups and the activation between them",
+    "noise": "block-diffusion training (models/block_diffusion.py): the "
+             "draw of the masked positions, the noised copy, the "
+             "concatenation with the clean one, the loss weights",
     "lm_head": "final norm, logits, loss",
     "optimizer": "optimizer.update + apply_updates (gradient clipping is "
                  "inside the optax chain, so inside the scope)",

@@ -1,0 +1,421 @@
+"""SDAR-30B-A3B through ``models/llama.py``: block-diffusion training
+(``models/block_diffusion.py``, ``ops.attention.block_diffusion_attention``),
+``head_dim`` apart from ``d_model / n_head``, per-head QK-norm, and
+``models/moe.py``'s expert layer holding a share of the experts.
+
+The plain reference is ``benchmarks/reference/sdar.py``, the one copy (float32,
+dense mask, every held expert applied to every position).  Everything runs on
+the CPU with seeded random weights at tiny sizes, attention on the einsum path
+but for the one case that runs the splash kernel in interpret mode; the
+grouped matmul has no other path than its kernel in interpret mode.
+"""
+
+import dataclasses
+import functools
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.lib import cost_sdar, spec, traffic as traffic_lib
+from benchmarks.reference import sdar as reference
+from ray_tpu.models import block_diffusion, llama, moe
+from ray_tpu.ops import attention
+from ray_tpu.ops.grouped_matmul import grouped_matmul
+
+#: benchmarks/lib/correct.py's, which the bf16 program is held to on the chip
+LOSS_TOL, GRAD_TOL = 1e-3, 0.75
+
+
+def _rel_err(a, b):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
+
+
+def _tiny_family(dtype="bfloat16", **options):
+    config = spec.load_json(spec.BENCH_DIR, "configs", "tiny-sdar.json")
+    config["options"] = {"attn_impl": "xla", "dtype": jnp.dtype(dtype),
+                         "logits_dtype": jnp.dtype(dtype), **options}
+    return config, spec.load_module("models", "sdar").build(config, 128)
+
+
+# ------------------------------------------------ (a) against the reference
+@pytest.mark.parametrize("dtype,loss_tol,grad_tol", [
+    # the same mathematics in another order: float32 summation order only
+    ("float32", 1e-5, 1e-4),
+    # bf16 operands, residual stream and logits under the chip run's limits
+    ("bfloat16", LOSS_TOL, GRAD_TOL),
+], ids=["float32", "bfloat16"])
+def test_loss_and_gradients_match_the_plain_reference(dtype, loss_tol,
+                                                      grad_tol):
+    config, family = _tiny_family(dtype)
+    params = jax.jit(family.init_fn)(jax.random.key(0))
+    # a router that prefers some experts, so the held share is uneven
+    params["blocks"]["router"] = params["blocks"]["router"] * 20.0
+    rows = np.random.default_rng(0).integers(
+        0, family.vocab_size, (2, 128)).astype(np.int32)
+    loss, grads = jax.jit(jax.value_and_grad(
+        lambda p: family.loss_fn(p, rows, None)))(params)
+    ref_loss, ref_grads = jax.jit(jax.value_and_grad(
+        lambda p: family.reference_loss(p, rows, None, 128)))(params)
+    assert _rel_err(loss, ref_loss) <= loss_tol
+    errs = jax.tree.map(_rel_err, grads, ref_grads)
+    assert set(errs["blocks"]) == {"attn_norm", "wq", "wk", "wv", "wo",
+                                   "q_norm", "k_norm", "mlp_norm", "router",
+                                   "w_gate", "w_up", "w_down"}
+    assert max(jax.tree.leaves(errs)) <= grad_tol, errs
+    # an untrained model reads ln V, whatever the weights of the loss
+    assert abs(float(ref_loss) - np.log(config["vocab_size"])) < 0.1
+
+
+def test_query_blocks_do_not_change_the_reference():
+    config, family = _tiny_family("float32")
+    params = jax.jit(family.init_fn)(jax.random.key(1))
+    rows = np.random.default_rng(1).integers(0, 511, (1, 128)).astype(np.int32)
+    whole = family.reference_loss(params, rows, None, 256)
+    blocks = family.reference_loss(params, rows, None, 64)
+    assert float(whole) == pytest.approx(float(blocks), rel=1e-6)
+
+
+# ------------------------------------------------------------ (b) the mask
+def _brute_force(S, Bk):
+    """The rule, one pair at a time."""
+    want = np.zeros((2 * S, 2 * S), bool)
+    for i in range(2 * S):
+        for j in range(2 * S):
+            bi, bj = (i % S) // Bk, (j % S) // Bk
+            if i < S and j < S:
+                want[i, j] = bi == bj
+            elif i < S <= j:
+                want[i, j] = bj < bi
+            elif i >= S and j >= S:
+                want[i, j] = bj <= bi
+    return want
+
+
+@pytest.mark.parametrize("S,Bk", [(8, 2), (12, 4), (16, 1), (16, 16),
+                                  (24, 3)])
+def test_allowed_is_the_rule(S, Bk):
+    at = np.arange(2 * S)
+    got = block_diffusion.allowed(at[:, None], at[None, :], S, Bk)
+    want = _brute_force(S, Bk)
+    assert np.array_equal(got, want)
+    # the same on traced integers, as the kernel calls it
+    traced = jax.jit(lambda i, j: block_diffusion.allowed(i, j, S, Bk))(
+        jnp.asarray(at)[:, None], jnp.asarray(at)[None, :])
+    assert np.array_equal(np.asarray(traced), want)
+    # the reference's own spelling, and the area the cost functions count
+    assert np.array_equal(np.asarray(reference.may_read(
+        jnp.asarray(at), jnp.asarray(at), S, Bk)), want)
+    assert want.sum() == cost_sdar.mask_area(S, Bk) == S * S + S * Bk
+    assert want.any(axis=1).all()  # no query without a key
+
+
+def test_splash_kernel_agrees_with_the_einsum(monkeypatch):
+    """The one interpret-mode run of the splash kernel under the block mask:
+    forward and the fused backward, GQA, two blocks a side so that empty,
+    full and cut-through blocks all occur."""
+    B, S, H, KV, hd, Bk = 1, 128, 4, 2, 32, 4
+    ks = jax.random.split(jax.random.key(0), 4)
+    q = jax.random.normal(ks[0], (B, 2 * S, H, hd))
+    k, v = (jax.random.normal(key, (B, 2 * S, KV, hd)) for key in ks[1:3])
+    do = jax.random.normal(ks[3], q.shape)
+    monkeypatch.setattr(attention, "splash_attention", functools.partial(
+        attention.splash_attention, block_q=128, block_kv=128))
+
+    def run(impl):
+        return jax.jit(jax.value_and_grad(
+            lambda q, k, v: jnp.sum(attention.block_diffusion_attention(
+                q, k, v, Bk, impl) * do), argnums=(0, 1, 2)))(q, k, v)
+
+    (a, ga), (b, gb) = run("xla"), run("splash")
+    assert float(a) == pytest.approx(float(b), rel=1e-5)
+    for x, y in zip(ga, gb):
+        assert _rel_err(y, x) < 1e-5
+
+
+@pytest.mark.parametrize("impl", ["ring", "ulysses", "flash"])
+def test_block_mask_has_no_other_path(impl):
+    q = jnp.zeros((1, 16, 2, 8))
+    with pytest.raises(ValueError):
+        attention.block_diffusion_attention(q, q, q, 4, impl)
+    with pytest.raises(ValueError, match="must divide"):
+        attention.block_diffusion_attention(q, q, q, 3, "xla")
+
+
+# ----------------------------------------------------------- (c) the share
+def _layer(n_experts=8, k=2, tokens=48, d=32, f=16, seed=0):
+    ks = jax.random.split(jax.random.key(seed), 5)
+    h = jax.random.normal(ks[0], (1, tokens, d))
+    blk = {"router": jax.random.normal(ks[1], (d, n_experts)) * 2.0,
+           "w_gate": jax.random.normal(ks[2], (n_experts, d, f)) * 0.2,
+           "w_up": jax.random.normal(ks[3], (n_experts, d, f)) * 0.2,
+           "w_down": jax.random.normal(ks[4], (n_experts, f, d)) * 0.2}
+    return h, blk
+
+
+def _share(blk, first, stop):
+    return {"router": blk["router"],
+            **{n: blk[n][first:stop] for n in ("w_gate", "w_up", "w_down")}}
+
+
+@pytest.mark.parametrize("per_chip", [1, 4, 8], ids=lambda n: f"{8 // n}x{n}")
+def test_the_shares_add_up_to_the_whole_layer(per_chip):
+    """What every chip of an expert-parallel job computes for the same
+    tokens adds up to the uncut reference's whole layer; the router's losses
+    are the same on every chip."""
+    h, blk = _layer()
+    cfg = {"num_experts_published": 8, "num_experts_per_tok": 2,
+           "norm_topk_prob": True, "experts_held": [0, 8]}
+    with jax.default_matmul_precision("highest"):
+        whole, balance = reference.moe(h[0], blk, cfg)
+    total, losses = 0.0, []
+    for first in range(0, 8, per_chip):
+        y, (lb, _) = jax.jit(functools.partial(
+            moe.moe_mlp, experts_per_token=2, norm_topk_prob=True,
+            dtype=jnp.float32, first_held=first))(
+            h, _share(blk, first, first + per_chip))
+        total = total + y[0]
+        losses.append(float(lb))
+        # the reference's share is the same part
+        with jax.default_matmul_precision("highest"):
+            part, _ = reference.moe(h[0], _share(blk, first, first + per_chip),
+                                    dict(cfg, experts_held=[first,
+                                                            first + per_chip]))
+        assert _rel_err(y[0], part) < 1e-5
+    assert _rel_err(total, whole) < 1e-5
+    assert losses == pytest.approx([float(balance)] * len(losses), rel=1e-5)
+
+
+def test_an_absent_experts_rows_cost_no_product():
+    """Rows of a group ``rhs`` does not hold come out zero and carry no
+    gradient; the held groups' rows are the plain products."""
+    lhs = jax.random.normal(jax.random.key(0), (32, 16))
+    rhs = jax.random.normal(jax.random.key(1), (4, 16, 8))
+    sizes = jnp.asarray([5, 11, 0, 16], jnp.int32)
+
+    def held(lhs, rhs):
+        return grouped_matmul(lhs, rhs[1:3], sizes, 1)
+
+    out, vjp = jax.vjp(held, lhs, rhs)
+    assert np.array_equal(np.asarray(out[:5]), np.zeros((5, 8)))
+    assert np.array_equal(np.asarray(out[16:]), np.zeros((16, 8)))
+    assert _rel_err(out[5:16], lhs[5:16] @ rhs[1]) < 1e-5
+    dlhs, drhs = vjp(jnp.ones_like(out))
+    assert not np.asarray(dlhs[:5]).any() and not np.asarray(dlhs[16:]).any()
+    assert not np.asarray(drhs[0]).any() and not np.asarray(drhs[3]).any()
+    assert not np.asarray(drhs[2]).any()  # held, and empty
+    assert _rel_err(drhs[1], lhs[5:16].T @ jnp.ones((11, 8))) < 1e-5
+
+
+# ------------------------------------- (d) every expert held is today's layer
+def test_holding_every_expert_is_the_layer_as_it_was():
+    """With every expert held the layer traces to what it traced to before
+    a share existed (the kernels' calls without a ``group_offset``), and its
+    value and gradients are bit for bit those of that layer written out."""
+    h, blk = _layer()
+    k = 2
+
+    def as_it_was(h, blk):
+        tokens = h.reshape(-1, h.shape[-1])
+        weights, experts, losses = moe.route(tokens, blk["router"], k, True)
+        order, inverse, sizes = moe.sort_pairs(experts, 8)
+        rows = moe._to_expert_order(tokens, order, inverse)
+        w_rows = moe._weights_to_expert_order(weights, order, inverse)
+        gate = grouped_matmul(rows, blk["w_gate"], sizes)
+        up = grouped_matmul(rows, blk["w_up"], sizes)
+        act = jax.nn.silu(gate) * up * w_rows[:, None]
+        out = grouped_matmul(act, blk["w_down"], sizes)
+        return moe._combine(out, order, inverse).reshape(h.shape), losses
+
+    def now(h, blk):
+        return moe.moe_mlp(h, blk, experts_per_token=k, norm_topk_prob=True,
+                           dtype=jnp.float32)
+
+    def value_and_grads(layer):
+        return jax.jit(jax.value_and_grad(
+            lambda blk: jnp.sum(layer(h, blk)[0] ** 2)))(blk)
+
+    for a, b in zip(jax.tree.leaves(value_and_grads(now)),
+                    jax.tree.leaves(value_and_grads(as_it_was))):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    # the configuration's default is every expert, and says so
+    config = llama.LlamaConfig.tiny_moe()
+    assert config.experts_held is None and config.held == range(8)
+    tokens = jnp.zeros((1, 16), jnp.int32)
+    params = jax.eval_shape(functools.partial(llama.init_params, config),
+                            jax.random.key(0))
+    short = dataclasses.replace(config, seq_len=16, attn_impl="xla")
+
+    def traced(config):
+        text = str(jax.make_jaxpr(functools.partial(
+            llama.loss_fn, config=config))(params, tokens, tokens))
+        return re.sub(r"0x[0-9a-f]+", "", text)  # objects' addresses
+
+    assert traced(short) == traced(
+        dataclasses.replace(short, experts_held=range(8)))
+
+
+# ------------------------------------------------------------ (e) the noise
+def test_noise_is_a_function_of_the_row_and_the_seed():
+    rows = np.random.default_rng(0).integers(0, 500, (3, 64)).astype(np.int32)
+    rows[2] = rows[0]
+    draw = jax.jit(block_diffusion.masked_positions, static_argnums=(1, 2))
+    masked, m = draw(rows, 7, 4)
+    again, _ = draw(rows[::-1].copy(), 7, 4)
+    assert np.array_equal(masked[0], masked[2])          # same row, same mask
+    assert np.array_equal(masked, np.asarray(again)[::-1])  # whatever the batch
+    assert not np.array_equal(masked[0], masked[1])
+    assert not np.array_equal(masked, draw(rows, 8, 4)[0])  # another seed
+    changed = rows.copy()
+    changed[0, 5] += 1
+    assert not np.array_equal(masked[0], draw(changed, 7, 4)[0][0])
+    # each block lost exactly m, and the reference draws the same positions
+    assert np.array_equal(np.asarray(masked).reshape(3, 16, 4).sum(-1), m)
+    ref_masked, ref_m = reference.masked_positions(
+        jnp.asarray(rows), {"block_length": 4, "noise_seed": 7})
+    assert np.array_equal(masked, ref_masked) and np.array_equal(m, ref_m)
+
+
+@pytest.mark.parametrize("Bk", [1, 4, 8])
+def test_m_is_uniform_and_the_subset_too(Bk):
+    rows = np.random.default_rng(Bk).integers(
+        0, 500, (16, 512 * Bk)).astype(np.int32)
+    masked, m = jax.jit(block_diffusion.masked_positions,
+                        static_argnums=(1, 2))(rows, 34, Bk)
+    m = np.asarray(m).reshape(-1)                        # 8192 blocks
+    counts = np.bincount(m, minlength=Bk + 1)
+    expect = m.size / (Bk + 1)
+    assert len(counts) == Bk + 1
+    assert np.all(np.abs(counts - expect) < 5 * np.sqrt(expect)), counts
+    # every position of a block is masked equally often: m / Bk on average
+    by_place = np.asarray(masked).reshape(-1, Bk).mean(axis=0)
+    assert np.all(np.abs(by_place - 0.5) < 5 * 0.5 / np.sqrt(m.size))
+
+
+def test_the_input_the_weights_and_the_mask_id():
+    config, family = _tiny_family()
+    gen = traffic_lib.make(
+        spec.load_json(spec.BENCH_DIR, "traffic", "packed-s8192-b1.json"),
+        vocab_size=family.vocab_size, eod_id=family.eod_id, global_batch=2,
+        seq_len=128, seed=3000000019)
+    mask_id = config["mask_token_id"]
+    for rows in (gen.batch(0)["tokens"], gen.check_rows(2)[:, :-1]):
+        assert rows.max() < mask_id  # [MASK] never appears in clean data
+        both, weights = jax.jit(
+            block_diffusion.noise, static_argnums=(1, 2, 3))(
+            rows, config["noise_seed"], 4, mask_id)
+        noised, clean = np.asarray(both[:, :128]), np.asarray(both[:, 128:])
+        assert np.array_equal(clean, rows)
+        assert np.array_equal(noised == mask_id, np.asarray(weights) > 0)
+        assert np.array_equal(noised[noised != mask_id],
+                              rows[noised != mask_id])
+        # weight 1/m in a block that lost m, over the blocks that lost any
+        w = np.asarray(weights).reshape(2, 32, 4)
+        lost = (w > 0).sum(-1)
+        assert float(w.sum()) == pytest.approx(1.0, rel=1e-6)
+        n_blocks = (lost > 0).sum()
+        assert np.allclose(w[w > 0] * n_blocks,
+                           (1.0 / np.maximum(lost, 1)[..., None] * (w > 0))[w > 0])
+        assert w.max() * n_blocks <= 1.0 + 1e-6
+        assert w[w > 0].min() * n_blocks >= 0.25 - 1e-6
+
+
+# --------------------------------- (f) head_dim, per-head QK-norm, positions
+def _by_hand(x, blk, config, S, Bk):
+    """One layer's attention half in float64, a head and a query at a time:
+    per-head RMSNorm over head_dim with one shared vector, rotate-half RoPE
+    at the position within the copy, the mask by the rule."""
+    x = np.asarray(x, np.float64)
+    w = {k: np.asarray(v, np.float64) for k, v in blk.items()}
+    P, D = x.shape
+    H, KV, hd = config.n_head, config.n_kv_head, config.head_dim
+    eps = config.rms_eps
+
+    def rms(a, g):
+        return a / np.sqrt(np.mean(a * a, axis=-1, keepdims=True) + eps) * g
+
+    def rope(a, pos):
+        half = hd // 2
+        freq = config.rope_theta ** (-np.arange(half) / half)
+        cos, sin = np.cos(pos * freq), np.sin(pos * freq)
+        a1, a2 = a[:half], a[half:]
+        return np.concatenate([a1 * cos - a2 * sin, a2 * cos + a1 * sin])
+
+    h = rms(x, w["attn_norm"])
+    q = (h @ w["wq"]).reshape(P, H, hd)
+    k = (h @ w["wk"]).reshape(P, KV, hd)
+    v = (h @ w["wv"]).reshape(P, KV, hd)
+    out = np.zeros((P, H, hd))
+    for head in range(H):
+        kv = head // (H // KV)
+        qs = np.stack([rope(rms(q[i, head], w["q_norm"]), i % S)
+                       for i in range(P)])
+        ks = np.stack([rope(rms(k[j, kv], w["k_norm"]), j % S)
+                       for j in range(P)])
+        for i in range(P):
+            scores = qs[i] @ ks.T / np.sqrt(hd)
+            scores = np.where(_MASKS[(S, Bk)][i], scores, -np.inf)
+            p = np.exp(scores - scores.max())
+            out[i, head] = (p / p.sum()) @ v[:, kv]
+    return x + out.reshape(P, H * hd) @ w["wo"]
+
+
+_MASKS = {(16, 0): np.tril(np.ones((16, 16), bool)),
+          (8, 2): _brute_force(8, 2)}
+
+
+@pytest.mark.parametrize("S,Bk", [(16, 0), (8, 2)],
+                         ids=["causal", "block-diffusion"])
+def test_head_dim_and_per_head_qk_norm_by_hand(S, Bk):
+    """head_dim 128 where d_model / n_head is 64; QK-norm over each head."""
+    config = llama.LlamaConfig(
+        vocab_size=64, n_layer=1, n_head=4, n_kv_head=2, d_model=256,
+        head_dim=128, d_ff=32, seq_len=S, rope_theta=1e6, rms_eps=1e-6,
+        qk_norm="head", block_length=Bk, mask_token_id=63, attn_impl="xla",
+        dtype=jnp.float32, logits_dtype=jnp.float32)
+    assert config.head_dim == 128 != config.d_model // config.n_head
+    params = llama.init_params(config, jax.random.key(0))
+    shapes = jax.tree.map(lambda a: a.shape, params["blocks"])
+    assert shapes["wq"] == (1, 256, 512) and shapes["wo"] == (1, 512, 256)
+    assert shapes["wk"] == shapes["wv"] == (1, 256, 256)
+    assert shapes["q_norm"] == shapes["k_norm"] == (1, 128)
+    assert llama.num_params(config) == sum(
+        a.size for a in jax.tree.leaves(params))
+    ks = jax.random.split(jax.random.key(1), 3)
+    blk = jax.tree.map(lambda a: a[0], params["blocks"])
+    blk["q_norm"] = 1.0 + 0.3 * jax.random.normal(ks[0], (128,))
+    blk["k_norm"] = 1.0 + 0.3 * jax.random.normal(ks[1], (128,))
+    blk["wq"], blk["wk"] = blk["wq"] * 20, blk["wk"] * 20  # sharp scores
+    blk["w_down"] = jnp.zeros_like(blk["w_down"])  # the MLP adds nothing
+    P = 2 * S if Bk else S
+    x = jax.random.normal(ks[2], (1, P, 256))
+    with jax.default_matmul_precision("highest"):
+        got, _ = llama._block(x, blk, config)
+    assert _rel_err(got[0], _by_hand(x[0], blk, config, S, Bk)) < 1e-4
+
+
+def test_first_call_says_what_the_model_is():
+    """The ``train.first_call`` record carries the share and the block
+    length; a causal dense model says so with zeros."""
+    from ray_tpu.parallel.train_state import jit_train_step
+    from ray_tpu.util import device_telemetry as dt
+
+    dt.reset()
+    config, family = _tiny_family()
+    optimizer = family.make_optimizer()
+    params = jax.jit(family.init_fn)(jax.random.key(0))
+    opt_state = jax.jit(optimizer.init)(params)
+    rows = jnp.zeros((1, 128), jnp.int32)
+    step = jit_train_step(family.make_train_step(optimizer))
+    _, _, loss = step(params, opt_state, rows, rows)
+    assert np.isfinite(float(loss))
+    first = dt.first_calls("train_step")[-1]
+    assert {k: first[k] for k in ("experts_held", "experts_total",
+                                  "block_length", "attn_positions",
+                                  "loss_positions")} == {
+        "experts_held": 2, "experts_total": 8, "block_length": 4,
+        "attn_positions": 256, "loss_positions": 128}
+    dt.reset()

@@ -47,10 +47,15 @@ def clean_telemetry():
 
 #: scopes only a step with models/moe.py's expert layer opens
 MOE_SCOPES = {"router", "moe_dispatch", "experts"}
+#: scopes only a block-diffusion step whose expert layer holds a share opens;
+#: its products are ``moe_held`` where the whole layer's are ``experts``
+SDAR_SCOPES = {"moe_held", "noise"}
 
 
 def _scopes_of(family):
-    return set(tracing.SCOPE_REGISTRY) - (
+    if family == "llama-sdar":
+        return set(tracing.SCOPE_REGISTRY) - {"experts"}
+    return set(tracing.SCOPE_REGISTRY) - SDAR_SCOPES - (
         set() if family == "llama-moe" else MOE_SCOPES)
 
 
@@ -61,6 +66,8 @@ def _family(name):
         return llama, llama.LlamaConfig.tiny()
     if name == "llama-moe":  # what olmoe-s4096 runs
         return llama, llama.LlamaConfig.tiny_moe()
+    if name == "llama-sdar":  # what sdar-ep8-s8192 runs
+        return llama, llama.LlamaConfig.tiny_sdar()
     config = gpt2.GPTConfig.tiny()
     if name == "gpt2-attn-outside-unrolled":  # what gpt2xl-s1024 runs
         import dataclasses
@@ -93,8 +100,8 @@ def _tiny_step(name="llama"):
 
 
 # ------------------------------------------------------- names in the step
-@pytest.mark.parametrize("family", ["llama", "llama-moe", "gpt2",
-                                    "gpt2-attn-outside-unrolled"])
+@pytest.mark.parametrize("family", ["llama", "llama-moe", "llama-sdar",
+                                    "gpt2", "gpt2-attn-outside-unrolled"])
 def test_lowered_step_holds_every_registered_scope(family):
     import jax
     import jax.numpy as jnp
@@ -173,7 +180,7 @@ def test_parse_anatomy_on_v5e_module_excerpt():
     assert anatomy["tuple.9"] == (None, None)
 
 
-@pytest.mark.parametrize("family", ["llama", "llama-moe",
+@pytest.mark.parametrize("family", ["llama", "llama-moe", "llama-sdar",
                                     "gpt2-attn-outside-unrolled"])
 def test_anatomy_of_a_tiny_cpu_step_end_to_end(family):
     from ray_tpu.parallel.train_state import PHASES
@@ -352,7 +359,9 @@ def test_fit_puts_program_spans_on_the_profilers_clock(tmp_path):
         [{"tokens": r[:-1], "targets": r[1:]} for r in rows])
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
-    ray_tpu.init(num_cpus=2)
+    # another test on this worker may have left a runtime up (xdist hands
+    # tests out by load, so the neighbours change with the suite)
+    ray_tpu.init(num_cpus=2, ignore_reinit_error=True)
     try:
         jax.profiler.start_trace(str(tmp_path), profiler_options=options)
         try:
