@@ -31,17 +31,25 @@ from the implementation it is about to call and the shapes it holds.
 Splash keeps scores in VMEM tiles, never materializes them, and skips the
 blocks the mask empties (the causal half; of a block-diffusion row's 2S x 2S
 the noised copy's off-diagonal blocks, the clean copy's upper half and all a
-clean query would read of the noised copy).  A block the mask cuts through
-computes its part of the mask from the positions, in the kernel: no S x S
-array exists.  head_dim=64 compiles unpadded under the
-512x512 blocks on the v5e and agrees with the einsum (chip_smoke.py, kernel
-phase).
+clean query would read of the noised copy).  A causal block computes its part
+of the mask from the positions, in the kernel (one compare).  The
+block-diffusion rule is too dear for that (some thirty integer operations a
+pair, on a kernel its vector unit already bounds), so its mask goes to the
+library as a lazy object that is read a block at a time in numpy while the
+step is traced: whole blocks are told apart there and pay one ``or`` in the
+kernel, and a block the mask cuts through reads one of three stored tiles.
+No 2S x 2S array exists.  What the call costs a head (``attn_calls``,
+``attn_blocks``, ``attn_blocks_cut``, ``attn_grid_steps_fwd``,
+``attn_grid_steps_bwd``) is on the ``train.first_call`` span and record.
+head_dim=64 compiles unpadded under the 512x512 blocks on the v5e and agrees
+with the einsum (chip_smoke.py, kernel phase).
 """
 
 from __future__ import annotations
 
+import functools
 import math
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -135,16 +143,28 @@ def _attention(q, k, v, impl: str, block_length: int):
 
 def _splash_kernel(seq_len: int, n_heads: int, block_q: int, block_kv: int,
                    causal: bool, block_length: int = 0):
-    # NOT cached: the kernel object built during one jit trace captures that
-    # trace's context — reusing it from a later trace raises
-    # UnexpectedTracerError.  Construction is cheap (lazy mask, no arrays).
+    """The splash kernel over ``seq_len`` positions, and what the call costs
+    a head, as the first-call record carries it: ``attn_calls`` (1),
+    ``attn_blocks`` (those with work), ``attn_blocks_cut`` (those of them
+    that apply a mask: a stored tile read, or the mask computed from the
+    positions), ``attn_grid_steps_fwd`` and ``attn_grid_steps_bwd`` (of the
+    forward and of the fused backward, skipped steps included).
+
+    NOT cached: the kernel object built during one jit trace captures that
+    trace's context (its mask arrays are constants of that trace) and reusing
+    it from a later one raises UnexpectedTracerError.  What costs is the
+    library's pass over the mask, a block at a time in numpy, and that it
+    caches by the mask's value (``_process_mask``, twelve entries): the
+    second trace of a mask pays nothing, a first one about a second for a
+    block-diffusion row of 16384 (sandbox CPU)."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk,
         splash_attention_mask as sm,
+        splash_attention_mask_info as mi,
     )
 
     if block_length:
-        mask = _block_diffusion_mask(sm, seq_len, block_length)
+        mask = _block_diffusion_mask()(seq_len // 2, block_length)
     else:
         mask = (sm.CausalMask if causal else sm.FullMask)((seq_len, seq_len))
     mask = sm.MultiHeadMask([mask] * n_heads)
@@ -156,9 +176,28 @@ def _splash_kernel(seq_len: int, n_heads: int, block_q: int, block_kv: int,
         block_q_dkv=bq, block_kv_dkv=bkv, block_kv_dkv_compute=bkv,
         use_fused_bwd_kernel=True,
     )
-    return sk.make_splash_mha(mask, head_shards=1, q_seq_shards=1,
-                              block_sizes=bs, interpret=interpret,
-                              residual_checkpoint_name=SPLASH_RESIDUALS)
+    kernel = sk.make_splash_mha(mask, head_shards=1, q_seq_shards=1,
+                                block_sizes=bs, interpret=interpret,
+                                residual_checkpoint_name=SPLASH_RESIDUALS)
+    # The kernel's own mask arrays are tracers here.  These are the library's
+    # cached numpy originals, asked for as ``make_splash_mha`` asks (a cache
+    # hit; were its call to change, a second pass over the mask).
+    fwd, computed = mi.process_mask(
+        mask, (bq, bkv), downcast_smem_data=True, head_shards=1,
+        q_seq_shards=1)
+    dkv, _ = mi.process_mask_dkv(
+        mask, (bq, bkv), downcast_smem_data=True, head_shards=1,
+        q_seq_shards=1, shrink_grid=False)
+    work = fwd.block_mask[0] > 0
+    # With a mask function the kernel computes the mask on every block it
+    # runs; with stored tiles a whole block is told apart (``block_mask``
+    # 2) and pays one ``or``.
+    cut = work if computed is not None else fwd.block_mask[0] == 1
+    return kernel, {
+        "attn_calls": 1, "attn_blocks": int(work.sum()),
+        "attn_blocks_cut": int(cut.sum()),
+        "attn_grid_steps_fwd": int(np.prod(fwd.block_mask.shape[1:])),
+        "attn_grid_steps_bwd": int(np.prod(dkv.block_mask.shape[1:]))}
 
 
 def block_diffusion_allowed(i, j, seq_len: int, block_length: int):
@@ -168,16 +207,12 @@ def block_diffusion_allowed(i, j, seq_len: int, block_length: int):
     this on as ``allowed``): a noised query reads the noised keys of its own
     block and the clean keys of earlier blocks; a clean query reads the clean
     keys of its own and earlier blocks; nothing else.  numpy or jax integer
-    arrays that broadcast: the splash kernel calls it on both."""
+    arrays that broadcast."""
     def block_of(at):
-        at = at - seq_len * (at >= seq_len)
-        # The kernel computes this for every pair of a tile the mask cuts
-        # through, and the chip's vector unit has no integer divide: ``//``
-        # cost 18 of 890 ms a step in ``sdar-ep8-s8192`` (PERF.md, PR 34).
-        # A power of two is a shift.
-        if block_length & (block_length - 1) == 0:
-            return at >> (block_length.bit_length() - 1)
-        return at // block_length
+        # Evaluated in numpy while a step is traced (the einsum path's dense
+        # mask, the splash path's stored tiles), never on the chip, whose
+        # vector unit has no integer divide.
+        return (at - seq_len * (at >= seq_len)) // block_length
 
     q_noised, k_noised = i < seq_len, j < seq_len
     bi, bj = block_of(i), block_of(j)
@@ -186,24 +221,60 @@ def block_diffusion_allowed(i, j, seq_len: int, block_length: int):
         | (~q_noised & ~k_noised & (bj <= bi))
 
 
-def _block_diffusion_mask(sm, positions: int, block_length: int):
-    """:func:`block_diffusion_allowed` over a row's 2S ``positions`` as a
-    mask the splash kernel computes from the positions (its lazy masks'
-    base class: the library has no public one; ``CausalMask`` is built the
-    same way)."""
-    class BlockDiffusionMask(sm._ComputableMask):
-        # one instance serves every head; the library keeps distinct masks
-        # apart by these
-        def __eq__(self, other):
-            return self is other
+@functools.cache
+def _block_diffusion_mask():
+    """The class of :func:`block_diffusion_allowed` as a lazy mask of the
+    splash library (made on first use: the library is imported only where
+    the kernel runs)."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_mask as sm,
+    )
+
+    class BlockDiffusionMask(sm.Mask):
+        """The rule over the 2S positions of a row of ``seq_len`` ids.  Not a
+        ``_ComputableMask``: the library then finds the whole blocks, which
+        pay nothing for the mask in the kernel, and stores each distinct cut
+        tile once (three where the kernel's block is a multiple of the block
+        length), which the kernel reads.  No 2S x 2S array exists: the
+        library asks for one block at a time."""
+
+        def __init__(self, seq_len: int, block_length: int):
+            self._key = (seq_len, block_length)
+
+        @property
+        def shape(self) -> Tuple[int, ...]:
+            return (2 * self._key[0],) * 2
+
+        def __getitem__(self, idx) -> np.ndarray:
+            seq_len, block_length = self._key
+            if len(idx) != 2 or not all(isinstance(s, slice) for s in idx):
+                raise NotImplementedError(f"Unsupported slice: {idx}")
+            # The rule reads only a position's copy and block, which one
+            # number says (S is a multiple of the block length): decide each
+            # pair of those once and spread the answer over the pairs of
+            # positions.  A chunk is most often one answer: of the 4096 the
+            # library asks for in a trace of ``sdar-ep8-s8192`` 192 are cut.
+            def blocks_of(s: slice):
+                at = np.arange(*s.indices(2 * seq_len)) // block_length
+                return np.unique(at, return_inverse=True)
+
+            (q_blocks, q_spread), (k_blocks, k_spread) = map(blocks_of, idx)
+            small = block_diffusion_allowed(
+                q_blocks[:, None] * block_length,
+                k_blocks[None, :] * block_length, seq_len, block_length)
+            if small.all() or not small.any():
+                return np.full((q_spread.size, k_spread.size), small.all())
+            return small[q_spread[:, None], k_spread[None, :]]
+
+        # by value: the library's cache of processed masks hits on the next
+        # trace, and the heads' masks count as one
+        def __eq__(self, other: object):
+            return isinstance(other, type(self)) and self._key == other._key
 
         def __hash__(self):
-            return hash((type(self).__name__, positions, block_length))
+            return hash((type(self), self._key))
 
-    return BlockDiffusionMask(
-        shape=(positions, positions),
-        mask_function=lambda q_ids, kv_ids: block_diffusion_allowed(
-            q_ids, kv_ids, positions // 2, block_length))
+    return BlockDiffusionMask
 
 
 def splash_attention(q, k, v, causal: bool = True,
@@ -234,8 +305,12 @@ def splash_attention(q, k, v, causal: bool = True,
         sm_scale = 1.0 / math.sqrt(hd)
 
     def local(q, k, v):
-        kernel = _splash_kernel(S, q.shape[2], block_q, block_kv, causal,
-                                block_length)
+        kernel, counts = _splash_kernel(S, q.shape[2], block_q, block_kv,
+                                        causal, block_length)
+        # here, not at the top: ``parallel/train_state.py`` imports ``ops``
+        from ray_tpu.parallel.train_state import note_first_call
+
+        note_first_call(**counts)
         # Splash takes (H, S, hd) per example; scale q up front (no scale arg).
         qt = (q * sm_scale).transpose(0, 2, 1, 3)
         kt = k.transpose(0, 2, 1, 3)

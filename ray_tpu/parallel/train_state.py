@@ -142,7 +142,8 @@ def note_first_call(**facts) -> None:
     for the ``train.first_call`` span and first-call record of whichever
     :class:`TrainStep` call is tracing on this thread (``models/llama.py``:
     the experts held, the block length, how many positions the layers and
-    the loss see).  Outside such a call, nothing."""
+    the loss see; ``ops/attention.py``: the splash calls that cover a layer's
+    mask, their blocks and grid steps).  Outside such a call, nothing."""
     for notes in getattr(_thread, "notes", ()):
         notes.update(facts)
 
@@ -191,9 +192,12 @@ class TrainStep:
     ``ops/remat.py`` decides it while the step is traced) and how many weight
     gradients were traced as rings over `fsdp` (``grad_ring_products``,
     ``grad_ring_axis``: ``ops/grad_ring.py``; 0 and 0 on one chip), and what
-    the model said of itself through :func:`note_first_call`
+    the traced code said of itself through :func:`note_first_call`
     (``models/llama.py``: ``experts_held``, ``experts_total``,
-    ``block_length``, ``attn_positions``, ``loss_positions``).
+    ``block_length``, ``attn_positions``, ``loss_positions``;
+    ``ops/attention.py``, where the splash kernel runs, how its calls cover a
+    layer's mask, a head: ``attn_calls``, ``attn_blocks``,
+    ``attn_blocks_cut``, ``attn_grid_steps_fwd``, ``attn_grid_steps_bwd``).
 
     A step that was traced in this call, keeps more than the plain policy
     would and is refused for memory (``RESOURCE_EXHAUSTED``, at compile or

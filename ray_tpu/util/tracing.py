@@ -105,7 +105,10 @@ SPAN_REGISTRY: Dict[str, str] = {
                         "remat_room_bytes, remat_fallback, "
                         "grad_ring_products, grad_ring_axis; from "
                         "models/llama.py experts_held, experts_total, "
-                        "block_length, attn_positions, loss_positions)",
+                        "block_length, attn_positions, loss_positions; "
+                        "from ops/attention.py's splash path attn_calls, "
+                        "attn_blocks, attn_blocks_cut, attn_grid_steps_fwd, "
+                        "attn_grid_steps_bwd)",
     "train.report": "session: one train.report() call, step boundary "
                     "included",
     "train.init_params": "create_sharded_state: parameters initialised "

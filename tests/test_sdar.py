@@ -6,7 +6,7 @@
 The plain reference is ``benchmarks/reference/sdar.py``, the one copy (float32,
 dense mask, every held expert applied to every position).  Everything runs on
 the CPU with seeded random weights at tiny sizes, attention on the einsum path
-but for the one case that runs the splash kernel in interpret mode; the
+but for the cases that run the splash kernel in interpret mode; the
 grouped matmul has no other path than its kernel in interpret mode.
 """
 
@@ -113,11 +113,96 @@ def test_allowed_is_the_rule(S, Bk):
     assert want.any(axis=1).all()  # no query without a key
 
 
-def test_splash_kernel_agrees_with_the_einsum(monkeypatch):
-    """The one interpret-mode run of the splash kernel under the block mask:
-    forward and the fused backward, GQA, two blocks a side so that empty,
-    full and cut-through blocks all occur."""
-    B, S, H, KV, hd, Bk = 1, 128, 4, 2, 32, 4
+@pytest.mark.parametrize("S,Bk", [(8, 2), (48, 12), (16, 1), (128, 4),
+                                  (64, 64)])
+@pytest.mark.parametrize("block", [8, 16, 512])
+def test_the_stored_mask_is_the_rule(S, Bk, block):
+    """What the splash library reads of the mask, a kernel block at a time
+    (shorter where the row is), is the rule on every pair of the 2S
+    positions.  A block length that is not a power of two among them."""
+    at = np.arange(2 * S)
+    want = block_diffusion.allowed(at[:, None], at[None, :], S, Bk)
+    block = min(block, 2 * S)
+    mask = attention._block_diffusion_mask()(S, Bk)
+    assert mask.shape == want.shape
+    assert np.array_equal(mask[:, :], want)
+    for i in range(0, 2 * S, block):
+        for j in range(0, 2 * S, block):
+            chunk = mask[slice(i, i + block), slice(j, j + block)]
+            assert chunk.dtype == np.bool_
+            assert np.array_equal(chunk, want[i:i + block, j:j + block])
+    # by value: the library's cache of processed masks finds it again
+    again = attention._block_diffusion_mask()(S, Bk)
+    assert mask == again and hash(mask) == hash(again)
+    assert mask != attention._block_diffusion_mask()(S, 2 * Bk)
+
+
+def test_whole_tiles_pay_nothing_for_the_mask():
+    """The cell's row (2S = 16384, block length 4, 512-blocks): of the 288
+    blocks with work a head 48 are cut and read one of 3 stored tiles, the
+    other 240 are told apart as whole; the forward walks 544 grid steps and
+    the fused backward, whose grid the library does not shrink, 1024."""
+    S, Bk, H = 8192, 4, 2
+    kernel, counts = attention._splash_kernel(2 * S, H, 512, 512, True, Bk)
+    assert counts == {"attn_calls": 1, "attn_blocks": 288,
+                      "attn_blocks_cut": 48, "attn_grid_steps_fwd": 544,
+                      "attn_grid_steps_bwd": 1024}
+    assert kernel.kwargs["mask_function"] is None
+    for info in (kernel.fwd_mask_info, kernel.dkv_mask_info):
+        assert info.q_sequence is None  # no mask computed in the kernel
+        block_mask = np.asarray(info.block_mask)
+        assert (block_mask == 1).sum() == 48 and (block_mask == 2).sum() == 240
+        # every cut block points at a tile the kernel holds
+        assert info.partial_mask_blocks.shape == (3, 512, 512)
+        assert np.asarray(info.mask_next)[block_mask == 1].max() < 3
+    tiles = np.asarray(kernel.fwd_mask_info.partial_mask_blocks)
+    # the backward's tiles are the forward's, kv-major
+    assert np.array_equal(
+        np.asarray(kernel.dkv_mask_info.partial_mask_blocks),
+        tiles.swapaxes(-1, -2))
+    # the three kinds of diagonal tile: the noised copy's own blocks, the
+    # clean copy's earlier blocks, the clean copy's own and earlier blocks
+    at = np.arange(512)
+    mine, theirs = at[:, None] // Bk, at[None, :] // Bk
+    assert {t.tobytes() for t in tiles} == {
+        (mine == theirs).tobytes(), (theirs < mine).tobytes(),
+        (theirs <= mine).tobytes()}
+
+
+def test_a_causal_row_is_the_call_it_was():
+    """``block_length`` 0: the library's ``CausalMask``, the mask computed
+    in the kernel on every block with work."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk,
+        splash_attention_mask as sm,
+    )
+
+    S, H = 2048, 2
+    kernel, counts = attention._splash_kernel(S, H, 512, 512, True)
+    assert counts == {"attn_calls": 1, "attn_blocks": 10,
+                      "attn_blocks_cut": 10, "attn_grid_steps_fwd": 16,
+                      "attn_grid_steps_bwd": 16}
+    assert kernel.kwargs["mask_function"] is not None
+    want = sk.make_splash_mha(
+        sm.MultiHeadMask([sm.CausalMask((S, S))] * H), head_shards=1,
+        q_seq_shards=1, block_sizes=kernel.kwargs["block_sizes"],
+        interpret=True, residual_checkpoint_name=attention.SPLASH_RESIDUALS)
+    for got, ref in ((kernel.fwd_mask_info, want.fwd_mask_info),
+                     (kernel.dkv_mask_info, want.dkv_mask_info)):
+        assert got.partial_mask_blocks is None and got.mask_next is None
+        for a, b in zip(got, ref):
+            assert (a is None) == (b is None)
+            assert a is None or np.array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("S,Bk", [(128, 4), (384, 12)],
+                         ids=["two-blocks-a-side", "block-length-12"])
+def test_splash_kernel_agrees_with_the_einsum(monkeypatch, S, Bk):
+    """The interpret-mode runs of the splash kernel under the block mask:
+    forward and the fused backward, GQA, 128-blocks so that empty, whole and
+    cut-through blocks all occur (the cut ones read stored tiles), and a
+    block length that is not a power of two."""
+    B, H, KV, hd = 1, 4, 2, 32
     ks = jax.random.split(jax.random.key(0), 4)
     q = jax.random.normal(ks[0], (B, 2 * S, H, hd))
     k, v = (jax.random.normal(key, (B, 2 * S, KV, hd)) for key in ks[1:3])
@@ -397,14 +482,21 @@ def test_head_dim_and_per_head_qk_norm_by_hand(S, Bk):
     assert _rel_err(got[0], _by_hand(x[0], blk, config, S, Bk)) < 1e-4
 
 
-def test_first_call_says_what_the_model_is():
+@pytest.mark.parametrize("impl,covering", [
+    ("xla", {}),
+    # a row shorter than the kernel's 512-blocks: one block of 256 x 256, cut
+    ("splash", {"attn_calls": 1, "attn_blocks": 1, "attn_blocks_cut": 1,
+                "attn_grid_steps_fwd": 1, "attn_grid_steps_bwd": 1}),
+])
+def test_first_call_says_what_the_model_is(impl, covering):
     """The ``train.first_call`` record carries the share and the block
-    length; a causal dense model says so with zeros."""
+    length, and where the splash kernel runs how its calls cover the mask;
+    a causal dense model says so with zeros."""
     from ray_tpu.parallel.train_state import jit_train_step
     from ray_tpu.util import device_telemetry as dt
 
     dt.reset()
-    config, family = _tiny_family()
+    config, family = _tiny_family(attn_impl=impl)
     optimizer = family.make_optimizer()
     params = jax.jit(family.init_fn)(jax.random.key(0))
     opt_state = jax.jit(optimizer.init)(params)
@@ -413,9 +505,8 @@ def test_first_call_says_what_the_model_is():
     _, _, loss = step(params, opt_state, rows, rows)
     assert np.isfinite(float(loss))
     first = dt.first_calls("train_step")[-1]
-    assert {k: first[k] for k in ("experts_held", "experts_total",
-                                  "block_length", "attn_positions",
-                                  "loss_positions")} == {
+    assert {k: v for k, v in first.items()
+            if k.startswith(("experts_", "block_", "attn_", "loss_"))} == {
         "experts_held": 2, "experts_total": 8, "block_length": 4,
-        "attn_positions": 256, "loss_positions": 128}
+        "attn_positions": 256, "loss_positions": 128, **covering}
     dt.reset()
