@@ -77,7 +77,6 @@ from ray_tpu.parallel.mesh import DEFAULT_RULES
 from ray_tpu.parallel.train_state import make_optimizer  # noqa: F401
 from ray_tpu.parallel.train_state import make_train_step as _make_train_step
 from ray_tpu.parallel.train_state import note_first_call
-from ray_tpu.util.tracing import step_counter
 
 
 @dataclass(frozen=True)
@@ -347,7 +346,7 @@ _rope.defvjp(_rope_fwd, _rope_bwd)
 def _block(x, blk, config: LlamaConfig):
     """One layer.  -> (x, what the expert layer says of itself): the second
     is ``None`` for a dense MLP; with experts it is (moe.router_losses' pair,
-    the rows that reached each held expert: ``moe.moe_mlp``)."""
+    the layer's counts: ``moe.moe_mlp``)."""
     dt = config.dtype
     B, S, D = x.shape
     H, KV, hd = config.n_head, config.n_kv_head, config.head_dim
@@ -394,11 +393,11 @@ def _block(x, blk, config: LlamaConfig):
         if config.n_experts:
             # the router reads the norm's float32 output, the experts its
             # cast to the compute dtype
-            y, router_losses, rows = _moe.moe_mlp(
+            y, router_losses, counts = _moe.moe_mlp(
                 h, blk, experts_per_token=config.experts_per_token,
                 norm_topk_prob=config.norm_topk_prob, dtype=dt,
                 first_held=config.held.start)
-            return x + y, (router_losses, rows)
+            return x + y, (router_losses, counts)
         h = h.astype(dt)
         gate = checkpoint_name(dense(h, "w_gate"), remat.GATE_UP)
         up = checkpoint_name(dense(h, "w_up"), remat.GATE_UP)
@@ -471,10 +470,11 @@ def _mesh_axes(logical) -> Tuple[str, ...]:
 
 
 def forward_hidden(params: Dict[str, Any], tokens, config: LlamaConfig):
-    """-> (final hidden states (B, S, D), router loss, expert rows): the
+    """-> (final hidden states (B, S, D), router loss, expert counts): the
     second is the coefficient-weighted sum of the layers' router losses, the
-    third the rows that reached each held expert, (L, shards, H) int32
-    (``moe.moe_mlp``); both ``None`` (no scan output behind them) for a model
+    third ``moe.moe_mlp``'s counts with the layers in front: ``moe_rows``
+    (L, shards, H) int32 and, where the layers hold a share, ``moe_moved``
+    (L, shards); both ``None`` (no scan output behind them) for a model
     without experts."""
     dt = config.dtype
     with jax.named_scope("embed"):
@@ -487,14 +487,14 @@ def forward_hidden(params: Dict[str, Any], tokens, config: LlamaConfig):
         layer = jax.checkpoint(
             layer, policy=_layer_policy(params, x.shape, config))
     x, expert_layers = lax.scan(layer, x, params["blocks"])
-    router_loss = rows = None
+    router_loss = counts = None
     if expert_layers is not None:
-        (balance, z), rows = expert_layers  # (L,) each; (L, shards, H)
+        (balance, z), counts = expert_layers  # (L,) each; moe_mlp's
         router_loss = config.router_aux_loss_coef * jnp.sum(balance) \
             + config.router_z_loss_coef * jnp.sum(z)
     with jax.named_scope("lm_head"):
         x = _rmsnorm(x, params["final_norm"], config.rms_eps).astype(dt)
-    return x, router_loss, rows
+    return x, router_loss, counts
 
 
 def forward(params: Dict[str, Any], tokens, config: LlamaConfig):
@@ -516,7 +516,8 @@ def loss_fn(params, tokens, targets, config: LlamaConfig):
 def loss_and_counters(params, tokens, targets, config: LlamaConfig):
     """-> (:func:`loss_fn`'s scalar, the step counters of
     ``tracing.STEP_COUNTER_REGISTRY`` this model has: with experts
-    ``moe_rows``, else none)."""
+    ``moe_rows``, with a share of them held ``moe_moved`` too, else
+    none)."""
     weights = None
     if config.block_length:
         targets = tokens
@@ -528,7 +529,7 @@ def loss_and_counters(params, tokens, targets, config: LlamaConfig):
                     block_length=config.block_length,
                     attn_positions=tokens.shape[1],
                     loss_positions=targets.shape[1])
-    x, router_loss, rows = forward_hidden(params, tokens, config)
+    x, router_loss, counts = forward_hidden(params, tokens, config)
     with jax.named_scope("lm_head"):
         if config.block_length:  # the head reads the noised copy, the first
             x = x[:, :targets.shape[1]]
@@ -536,14 +537,15 @@ def loss_and_counters(params, tokens, targets, config: LlamaConfig):
                                    targets, config.logits_dtype, weights)
     if router_loss is None:
         return ce, {}
-    return ce + router_loss, {step_counter("moe_rows"): rows}
+    return ce + router_loss, counts
 
 
 def make_train_step(config: LlamaConfig, optimizer):
     """Pure (params, opt_state, tokens, targets) -> (params, opt_state, loss):
     parallel.train_state.make_train_step over this model's loss.  With
-    experts the step also leaves ``moe_rows`` in ``step.counters``; a dense
-    model's step is the plain one."""
+    experts the step also leaves ``moe_rows`` (and, a share of them held,
+    ``moe_moved``) in ``step.counters``; a dense model's step is the plain
+    one."""
     if config.n_experts:
         return _make_train_step(partial(loss_and_counters, config=config),
                                 optimizer, has_counters=True)

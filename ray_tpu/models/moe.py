@@ -34,9 +34,10 @@ and the combine are not run a second time.
 Two router losses come back with the output, for ``llama.loss_fn`` to weigh
 (:func:`router_losses`), and a count: the rows that reached each expert held
 here, (shards, H) int32, the held slice of the sort's ``group_sizes``.  It is
-what the grouped kernels' time follows, and the one fact of the layer that the
-data decide; ``llama.make_train_step`` carries it out of the compiled step as
-the step counter ``moe_rows`` (``util/tracing.py``).
+what the grouped kernels' time follows; ``llama.make_train_step`` carries it
+out of the compiled step as the step counter ``moe_rows``
+(``util/tracing.py``), and beside it ``moe_moved`` where the layer holds a
+share (below): the two facts of the layer that the data decide.
 
 Under a mesh tokens never leave their chip: the whole layer runs inside a
 ``shard_map`` over the mesh's batch axes, as ``ops.attention.splash_attention``
@@ -49,10 +50,20 @@ the way out, which is FSDP's meaning.
 
 **A chip's share of the experts** (``experts_held``, a run of expert ids): what
 one chip of an expert-parallel job holds of a layer.  The router keeps its
-published width and its experts a token, all N x k pairs are sorted as above,
-and the grouped products run over the held groups only (``rhs`` is the held
-experts' matrices, the kernels' ``group_offset``): rows of an absent expert
-come out zero, cost no product, and add nothing in the combine.  Parameters,
+published width and its experts a token, and all N x k pairs are sorted as
+above; in that order the pairs of the held experts are one run, and only the
+run is moved.  It is walked in windows of a static bound
+(:data:`WINDOW_SHARE` of the pairs): a window's rows are gathered,
+multiplied, activated and summed back into token order, and how many windows
+a step walks is decided on the device by the step's own count of held rows (a
+loop of that length; eight eighths are the move of every pair, so no pair is
+dropped: the rows never walked were zeros in the combine's float32 sum).  The
+kernels see the held experts' groups, cut to the window, between two groups
+that ``rhs`` does not hold (their ``group_offset``), the window's rows before
+and after its part of the run: those rows come out zero and cost no product.
+One set of kernels at the window's size serves every step; the rows a layer
+moved leave the step as ``moe_moved``.  Where every expert is held the run
+is every pair and the layer moves them at once, with no loop.  Parameters,
 gradients and optimizer state exist for the held experts only.  What is not
 built is the exchange: the ragged all-to-all that would bring this chip the
 other chips' rows for its experts and send its own rows to theirs.  On one
@@ -62,6 +73,7 @@ the absent experts would add is left out.
 
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax
@@ -71,6 +83,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops import remat
 from ray_tpu.ops.grouped_matmul import grouped_matmul
+from ray_tpu.util.tracing import step_counter
 
 
 def router_losses(logits, probs, experts, batch_axes=()
@@ -174,35 +187,173 @@ _weights_to_expert_order.defvjp(_weights_to_expert_order_fwd,
                                 _weights_to_expert_order_bwd)
 
 
+#: The rows an expert layer that holds a share moves at a time, as a share of
+#: the N x k pairs it sorts: the run of the sorted pairs that meets its
+#: experts is walked in windows of this many rows, as many as the step's own
+#: count needs.  An eighth: three (layer, step)s in five of sixteen held
+#: experts of 128 need one window and nine in ten at most two (``PERF.md``, PR
+#: 36), and at the cell's sizes a window of an eighth is the 64 MiB from which
+#: the combine's gather runs three times as fast as from 128 (``PERF.md``, PR
+#: 38).
+WINDOW_SHARE = 1 / 8
+
+
+def window_rows(pairs: int) -> int:
+    """The rows of one window of a layer that sorts ``pairs`` rows."""
+    return max(1, int(pairs * WINDOW_SHARE))
+
+
+@jax.custom_vjp
+def _to_window(x, pairs, inverse, run):
+    """x: (N, D) -> (R, D), row j the token of pair ``pairs[j]``; ``pairs``
+    is a window of ``order``, R entries that begin at place ``first - lead``
+    of it, and ``run`` = (first, stop, lead) says which places of the order
+    are the window's own and meet a held expert."""
+    return x[pairs // inverse.shape[1]]
+
+
+@jax.custom_vjp
+def _from_window(rows, pairs, inverse, run):
+    """rows: (R, D) on that window, already weighted -> (N, D), each token
+    the float32 sum of those of its k rows that lie in the run; a row of the
+    window outside the run met no expert and is left out, whatever it
+    holds."""
+    first, stop, lead = run
+    inside = (inverse >= first) & (inverse < stop)
+    place = jnp.clip(inverse - (first - lead), 0, rows.shape[0] - 1)
+    return jnp.sum(jnp.where(inside[..., None], rows[place], 0)
+                   .astype(jnp.float32), axis=1).astype(rows.dtype)
+
+
+# Each other's transposes on the rows of the run, as the full move's pair is
+# on every row; what the window's other rows get, no product reads.
+_to_window.defvjp(
+    lambda x, *window: (_to_window(x, *window), window),
+    lambda window, g: (_from_window(g, *window), None, None, None))
+_from_window.defvjp(
+    lambda rows, *window: (_from_window(rows, *window), window),
+    lambda window, g: (_to_window(g, *window), None, None, None))
+
+
+def _through_experts(rows, w_rows, w_gate, w_up, w_down, sizes, first):
+    """rows: (M, D) in expert order, w_rows: (M,) their combine weights ->
+    (M, D): gate and up, the weighted activation, down, over the groups
+    ``sizes``, whose matrices are those from group ``first`` on."""
+    gate = checkpoint_name(grouped_matmul(rows, w_gate, sizes, first),
+                           remat.GATE_UP)
+    up = checkpoint_name(grouped_matmul(rows, w_up, sizes, first),
+                         remat.GATE_UP)
+    act = (jax.nn.silu(gate.astype(jnp.float32))
+           * up.astype(jnp.float32)
+           * w_rows[:, None].astype(jnp.float32)).astype(rows.dtype)
+    return grouped_matmul(act, w_down, sizes, first)
+
+
+def _move_window(first_held, c, x, w_sorted, w_gate, w_up, w_down, order,
+                 inverse, group_sizes):
+    """Window ``c`` of the held experts' run through the layer: the
+    :func:`window_rows` places of the sorted order from ``c`` windows behind
+    the run's first (from earlier where the order ends sooner; those rows
+    are an earlier window's) -> (N, D), what its pairs add to each token.
+    The kernels see the held experts' groups, cut to the window, between two
+    groups that ``rhs`` does not hold, the window's rows before and after
+    its part of the run: those rows come out zero and no tile of theirs is
+    visited."""
+    bound = window_rows(order.shape[0])
+    with jax.named_scope("moe_dispatch"):
+        held_rows = group_sizes[first_held:first_held + w_gate.shape[0]]
+        start = jnp.sum(group_sizes[:first_held])  # of the run
+        stops = start + jnp.cumsum(held_rows)      # of each held group
+        first = start + c * bound
+        stop = jnp.minimum(first + bound, stops[-1])
+        lead = jnp.maximum(first + bound - order.shape[0], 0)
+        cut = jnp.maximum(jnp.minimum(stops, stop)
+                          - jnp.maximum(stops - held_rows, first), 0)
+        sizes = jnp.concatenate([lead[None], cut,
+                                 (bound - lead - jnp.sum(cut))[None]])
+        window = (lax.dynamic_slice(order, (first - lead,), (bound,)),
+                  inverse, (first, stop, lead))
+        rows = _to_window(x, *window)
+        w_rows = lax.dynamic_slice(w_sorted, (first - lead,), (bound,))
+    with jax.named_scope("moe_held"):
+        out = _through_experts(rows, w_rows, w_gate, w_up, w_down, sizes, 1)
+    with jax.named_scope("moe_dispatch"):
+        return _from_window(out, *window)
+
+
+def _sum(a, b):
+    """a + b made in float32, in a's dtype."""
+    return (a.astype(jnp.float32) + b.astype(jnp.float32)).astype(a.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _held_move(first_held, windows, x, w_sorted, w_gate, w_up, w_down, order,
+               inverse, group_sizes):
+    """A share's layer behind the sort: the first ``windows`` windows of the
+    held run, one after the other: a loop whose length the step's own count
+    sets.  The backward is a second such loop whose pass runs its window
+    again as far as the backward reads it and then backwards: what a window
+    keeps lives inside its pass, and the layer saves its arguments and
+    nothing else.  What the windows add up to, each token's rows and every
+    gradient, is carried in the dtype the layer hands it on in, each
+    addition made in float32 (two windows: the float32 sum, rounded once)."""
+    def add(c, y):
+        return _sum(y, _move_window(first_held, c, x, w_sorted, w_gate, w_up,
+                                    w_down, order, inverse, group_sizes))
+
+    return lax.fori_loop(0, windows, add, jnp.zeros_like(x))
+
+
+def _held_move_fwd(first_held, windows, *args):
+    return _held_move(first_held, windows, *args), (windows, args)
+
+
+def _held_move_bwd(first_held, saved, g):
+    windows, (*floats, order, inverse, group_sizes) = saved
+
+    def add(c, grads):
+        return jax.tree.map(_sum, grads, jax.vjp(
+            lambda *floats: _move_window(first_held, c, *floats, order,
+                                         inverse, group_sizes),
+            *floats)[1](g))
+
+    return (None, *lax.fori_loop(0, windows, add, tuple(
+        jnp.zeros_like(f) for f in floats)), None, None, None)
+
+
+_held_move.defvjp(_held_move_fwd, _held_move_bwd)
+
+
 def expert_mlp(x, weights, experts, w_gate, w_up, w_down, n_experts=None,
                first_held: int = 0):
     """One chip's tokens through their experts.  x: (N, D); weights,
     experts: (N, k); w_gate, w_up: (H, D, F); w_down: (H, F, D), all in the
     compute dtype: the matrices of experts ``first_held .. first_held + H``
-    of ``n_experts`` (default H: every expert is held).  -> ((N, D), the
-    pairs that reached each held expert, (H,) int32)."""
+    of ``n_experts`` (default H: every expert is held).  -> ((N, D); the
+    pairs that reached each held expert, (H,) int32; the rows the layer
+    moved, int32, :func:`window_rows` for each window the step's count
+    needed: ``None`` where every expert is held and the layer moves every
+    pair at once)."""
     held = w_gate.shape[0]
     n_experts = n_experts or held
     with jax.named_scope("moe_dispatch"):
         order, inverse, group_sizes = sort_pairs(experts, n_experts)
         held_rows = group_sizes[first_held:first_held + held]
+        if held < n_experts:
+            bound = window_rows(order.shape[0])
+            windows = (jnp.sum(held_rows) + (bound - 1)) // bound
+            return _held_move(
+                first_held, windows, x,
+                _weights_to_expert_order(weights, order, inverse), w_gate,
+                w_up, w_down, order, inverse, group_sizes), \
+                held_rows, windows * bound
         rows = _to_expert_order(x, order, inverse)
         w_rows = _weights_to_expert_order(weights, order, inverse)
-    # the products' scope says whether the layer holds a share
-    with jax.named_scope("experts") if held == n_experts \
-            else jax.named_scope("moe_held"):
-        gate = checkpoint_name(
-            grouped_matmul(rows, w_gate, group_sizes, first_held),
-            remat.GATE_UP)
-        up = checkpoint_name(
-            grouped_matmul(rows, w_up, group_sizes, first_held),
-            remat.GATE_UP)
-        act = (jax.nn.silu(gate.astype(jnp.float32))
-               * up.astype(jnp.float32)
-               * w_rows[:, None].astype(jnp.float32)).astype(x.dtype)
-        out = grouped_matmul(act, w_down, group_sizes, first_held)
+    with jax.named_scope("experts"):
+        out = _through_experts(rows, w_rows, w_gate, w_up, w_down,
+                               group_sizes, 0)
     with jax.named_scope("moe_dispatch"):
-        return _combine(out, order, inverse), held_rows
+        return _combine(out, order, inverse), held_rows, None
 
 
 def moe_mlp(h32, blk, *, experts_per_token: int, norm_topk_prob: bool, dtype,
@@ -212,10 +363,13 @@ def moe_mlp(h32, blk, *, experts_per_token: int, norm_topk_prob: bool, dtype,
     holds ``router`` (D, E) and the matrices of the H experts from
     ``first_held`` on, ``w_gate``, ``w_up`` (H, D, F), ``w_down`` (H, F, D);
     H = E unless the chip holds a share.  -> (y (B, S, D) in ``dtype``,
-    (load-balance, z), rows): the losses over all E experts; the pairs that
-    reached each held expert, (shards, H) int32, one row for each shard of
-    the batch (a chip's kernel time follows that chip's rows), one in all
-    without a mesh."""
+    (load-balance, z), counts): the losses over all E experts; ``counts``
+    what the data decided, by its name as a step counter
+    (``tracing.STEP_COUNTER_REGISTRY``), each with one row for each shard of
+    the batch (a chip's time follows that chip's rows), one in all without
+    a mesh: ``moe_rows``, the pairs that reached each held expert, (shards,
+    H) int32, and, where the layer holds a share, ``moe_moved``, the rows
+    it moved, (shards,) int32."""
     mesh = jax.sharding.get_abstract_mesh()
     sharded = not (mesh.empty or mesh.size == 1)
     batch_axes = tuple(a for a in ("data", "fsdp")
@@ -226,10 +380,13 @@ def moe_mlp(h32, blk, *, experts_per_token: int, norm_topk_prob: bool, dtype,
         with jax.named_scope("router"):
             weights, experts, losses = route(
                 tokens, router, experts_per_token, norm_topk_prob, batch_axes)
-        y, held_rows = expert_mlp(tokens.astype(dtype), weights, experts,
-                                  w_gate, w_up, w_down, router.shape[-1],
-                                  first_held)
-        return y.reshape(h32.shape), losses, held_rows[None]
+        y, held_rows, moved = expert_mlp(
+            tokens.astype(dtype), weights, experts, w_gate, w_up, w_down,
+            router.shape[-1], first_held)
+        counts = {step_counter("moe_rows"): held_rows[None]}
+        if moved is not None:
+            counts[step_counter("moe_moved")] = moved[None]
+        return y.reshape(h32.shape), losses, counts
 
     args = (h32, blk["router"], blk["w_gate"].astype(dtype),
             blk["w_up"].astype(dtype), blk["w_down"].astype(dtype))
@@ -237,9 +394,12 @@ def moe_mlp(h32, blk, *, experts_per_token: int, norm_topk_prob: bool, dtype,
         return local(*args)
     P = jax.sharding.PartitionSpec
     rows = P(batch_axes or None, None, None)
+    share = blk["w_gate"].shape[0] < blk["router"].shape[-1]
+    counts = ("moe_rows", "moe_moved") if share else ("moe_rows",)
     # check_vma off as for splash: a pallas_call declares no vma on its
     # outputs.  Axes a spec does not name (the weights' every axis) see
     # whole arrays.
     return jax.shard_map(local, in_specs=(rows, P(), P(), P(), P()),
-                         out_specs=(rows, (P(), P()), P(batch_axes or None)),
+                         out_specs=(rows, (P(), P()), dict.fromkeys(
+                             counts, P(batch_axes or None))),
                          check_vma=False)(*args)

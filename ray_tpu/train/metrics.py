@@ -142,6 +142,21 @@ def moe_load_max(moe_rows) -> Optional[float]:
     return float(np.mean(layers)) if layers else None
 
 
+MOE_MOVED_ROWS = Gauge(
+    "ray_tpu_train_moe_moved_rows",
+    "Expert layers that hold a share of the experts, last resolved step: "
+    "the rows a layer moved into expert order and back, mean over layers "
+    "and batch shards (a whole number of models/moe.py's windows: as many "
+    "as the step's held rows needed)",
+)
+
+
+def moe_moved_rows(moe_moved) -> float:
+    """From a row's ``moe_moved`` (layers x batch shards): the mean."""
+    return float(np.mean(moe_moved))
+
+
 #: What a gauge shows of a step counter (``tracing.STEP_COUNTER_REGISTRY``)
 #: once a row has it: counter -> (gauge, what it derives from the value).
-COUNTER_GAUGES = {"moe_rows": (MOE_LOAD_MAX, moe_load_max)}
+COUNTER_GAUGES = {"moe_rows": (MOE_LOAD_MAX, moe_load_max),
+                  "moe_moved": (MOE_MOVED_ROWS, moe_moved_rows)}

@@ -193,6 +193,11 @@ STEP_COUNTER_REGISTRY: Dict[str, str] = {
                 "pairs that reached each expert held here, int32 (layers, "
                 "batch shards, held experts); the grouped kernels' time "
                 "follows them",
+    "moe_moved": "expert layers that hold a share of the experts: the rows "
+                 "each layer moved into expert order and back, int32 "
+                 "(layers, batch shards): moe.window_rows for each window "
+                 "of the held run that the step's own count made it walk "
+                 "(every pair a layer sorts when the run is that long)",
 }
 
 

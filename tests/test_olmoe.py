@@ -193,6 +193,12 @@ def _equations(jaxpr, path=()):
             yield from _equations(sub, path + (eqn.primitive.name,))
 
 
+def _loops(jaxpr):
+    """The ``while`` equations that are not a kernel's own."""
+    return [e for path, e in _equations(jaxpr)
+            if e.primitive.name == "while" and "pallas_call" not in path]
+
+
 def test_remat_recomputes_neither_the_down_projection_nor_the_combine():
     """The guard against the combine weights drifting back into token order:
     then their gradient would read the down-projection's output, and remat
@@ -409,7 +415,7 @@ def test_without_experts_the_program_has_no_trace_of_them():
         return [e.primitive.name for _, e in _equations(jaxpr.jaxpr)]
 
     dense = names(jaxpr)
-    for primitive in ("sort", "top_k", "pallas_call", "shard_map"):
+    for primitive in ("sort", "top_k", "pallas_call", "shard_map", "while"):
         assert primitive not in dense
     # the embedding lookup and the target pick, nothing else
     assert dense.count("gather") == 2
@@ -425,8 +431,41 @@ def test_without_experts_the_program_has_no_trace_of_them():
     moe_jaxpr = jax.make_jaxpr(
         lambda p, t: llama.loss_fn(p, t, t, moe_config))(moe_params, tokens)
     assert {"sort", "top_k", "pallas_call"} <= set(names(moe_jaxpr))
-    # carry, load-balance (L,), z (L,), the held experts' rows (L, 1, E)
+    # carry, load-balance (L,), z (L,), the held experts' rows (L, 1, E):
+    # every expert is held, so no windows (no loop but the kernels' own)
+    # and no count of rows moved
     assert [len(e.outvars) for e in layer_scans(moe_jaxpr)] == [4]
+    assert not _loops(moe_jaxpr.jaxpr)
+    assert set(jax.eval_shape(
+        lambda p, t: llama.loss_and_counters(p, t, t, moe_config)[1],
+        moe_params, tokens)) == {"moe_rows"}
+
+
+def test_importing_the_model_imports_what_it_did():
+    """What a process pays before its first step: ``import
+    ray_tpu.models.llama`` brings in the modules it brought in at PR 38's
+    parent (``tests/data/llama_import_modules.json``) and no other of the
+    package, and no package it did not."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "tests", "data",
+                           "llama_import_modules.json")) as f:
+        before = json.load(f)
+    done = subprocess.run(
+        [sys.executable, "-c", "import json, sys\n"
+         "import ray_tpu.models.llama\n"
+         "print(json.dumps(sorted(sys.modules)))"],
+        cwd=root, env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=root),
+        capture_output=True, text=True, timeout=300)
+    assert done.returncode == 0, done.stderr[-2000:]
+    now = json.loads(done.stdout.strip().splitlines()[-1])
+    assert sorted(m for m in now if m.split(".")[0] == "ray_tpu") \
+        == before["ray_tpu"]
+    assert {m.split(".")[0] for m in now} <= set(before["top_level"])
 
 
 def test_sizes_follow_the_published_model():

@@ -24,6 +24,7 @@ from benchmarks.reference import sdar as reference
 from ray_tpu.models import block_diffusion, llama, moe
 from ray_tpu.ops import attention
 from ray_tpu.ops.grouped_matmul import grouped_matmul
+from tests.test_olmoe import _equations, _loops
 
 #: benchmarks/lib/correct.py's, which the bf16 program is held to on the chip
 LOSS_TOL, GRAD_TOL = 1e-3, 0.75
@@ -295,6 +296,164 @@ def test_an_absent_experts_rows_cost_no_product():
     assert _rel_err(drhs[1], lhs[5:16].T @ jnp.ones((11, 8))) < 1e-5
 
 
+# ------------------------------------------- (c2) the windows on the held run
+#: 32 tokens x 2 experts a token: 64 pairs, a window of 8 rows
+_N, _K, _E, _H = 32, 2, 8, 2
+
+
+def _pairs_with_a_run(run, first_held, absent):
+    """(N, k) expert ids of which exactly ``run`` pairs name one of the two
+    held experts (slot 0 the first, slot 1 the second, tokens in turn); the
+    other pairs go to the experts in ``absent``."""
+    experts = np.empty((_N, _K), np.int32)
+    for slot in range(_K):
+        experts[:, slot] = [absent[(t + slot * 3) % len(absent)]
+                            for t in range(_N)]
+    for j in range(run):
+        experts[j % _N, j // _N] = first_held + j // _N
+    assert np.isin(experts, [first_held, first_held + 1]).sum() == run
+    return jnp.asarray(experts)
+
+
+def _window_layer(seed=0):
+    ks = jax.random.split(jax.random.key(seed), 5)
+    d, f = 32, 16
+    return (jax.random.normal(ks[0], (_N, d)),
+            jax.nn.softmax(jax.random.normal(ks[1], (_N, _K))),
+            jax.random.normal(ks[2], (_H, d, f)) * 0.2,
+            jax.random.normal(ks[3], (_H, d, f)) * 0.2,
+            jax.random.normal(ks[4], (_H, f, d)) * 0.2)
+
+
+def _move_every_pair(first_held, experts, x, weights, w_gate, w_up, w_down):
+    """The share's layer as it was before it had windows: every pair a row,
+    the kernels told which groups ``rhs`` holds."""
+    order, inverse, sizes = moe.sort_pairs(experts, _E)
+    rows = moe._to_expert_order(x, order, inverse)
+    w_rows = moe._weights_to_expert_order(weights, order, inverse)
+    gate = grouped_matmul(rows, w_gate, sizes, first_held)
+    up = grouped_matmul(rows, w_up, sizes, first_held)
+    act = jax.nn.silu(gate) * up * w_rows[:, None]
+    return moe._combine(grouped_matmul(act, w_down, sizes, first_held),
+                        order, inverse)
+
+
+@functools.lru_cache(maxsize=None)
+def _window_programs(first_held):
+    """(the windows, the move of every pair, the gradients of each), jitted
+    over (expert ids, the five floats): how many windows are walked is the
+    data's, so one compiled program serves every length of run."""
+    def windowed(experts, *floats):
+        return moe.expert_mlp(floats[0], floats[1], experts, *floats[2:],
+                              _E, first_held)
+
+    full = functools.partial(_move_every_pair, first_held)
+
+    def gradients(layer):
+        return jax.jit(jax.grad(
+            lambda experts, dy, *floats: jnp.sum(layer(experts, *floats) * dy),
+            argnums=range(2, 7)))
+
+    return (jax.jit(windowed), jax.jit(full),
+            gradients(lambda *a: windowed(*a)[0]), gradients(full))
+
+
+@pytest.mark.parametrize("first_held,absent", [
+    (0, (2, 5, 7)),          # the run begins the sorted order
+    (3, (5, 6, 7)),          # ... and here too, behind no absent expert's pair
+    (3, (0, 2, 6)),          # absent pairs on both sides of it
+    (6, (0, 1, 2, 3)),       # the run ends the order: a window begins early
+], ids=["first0", "first3-at-start", "first3-inside", "first6-at-end"])
+@pytest.mark.parametrize("run,moved", [
+    (0, 0), (5, 8), (8, 8), (9, 16), (16, 16), (17, 24), (33, 40), (63, 64),
+    (64, 64),
+], ids=["empty", "inside-a-window", "exactly-a-window", "one-row-over",
+        "two-windows", "a-group-cut-by-a-window", "past-half", "all-but-one",
+        "every-pair-held"])
+def test_the_windows_are_the_move_of_every_pair(first_held, absent, run,
+                                                moved):
+    """The layer that holds a share walks as many windows as its own count
+    needs and gives what the move of every pair gives, to float32 rounding
+    (the kernels walk a window in tiles of its size, so a product sums in
+    another order; a token whose rows lie in two windows is summed in
+    float32 across them): the output and the gradient of x, of the combine
+    weights and of the three held matrices."""
+    x, weights, w_gate, w_up, w_down = floats = _window_layer()
+    experts = _pairs_with_a_run(run, first_held, absent)
+    assert moe.window_rows(_N * _K) == 8
+    windowed, full, windowed_grads, full_grads = _window_programs(first_held)
+
+    def same(a, b):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=1e-6)
+
+    y, held_rows, took = windowed(experts, *floats)
+    assert int(held_rows.sum()) == run and int(took) == moved
+    same(y, full(experts, *floats))
+    # and against the layer written out densely, absent experts left out
+    dense = jnp.zeros_like(x)
+    for e in range(_H):
+        w = jnp.sum(jnp.where(experts == first_held + e, weights, 0.0), 1)
+        dense += w[:, None] * ((jax.nn.silu(x @ w_gate[e]) * (x @ w_up[e]))
+                               @ w_down[e])
+    same(y, dense)
+    dy = jax.random.normal(jax.random.key(9), y.shape)
+    for a, b in zip(windowed_grads(experts, dy, *floats),
+                    full_grads(experts, dy, *floats)):
+        same(a, b)
+
+
+def test_the_windows_under_remat_run_no_product_twice_but_gate_and_up():
+    """``test_remat_recomputes_neither_the_down_projection_nor_the_combine``
+    (tests/test_olmoe.py) for the windows.  The layer saves its arguments
+    and nothing else: the forward is one loop of 3 kernels a window and
+    keeps no window's rows; the backward is one loop whose pass runs gate
+    and up again, then 3 dx and 3 dW.  Its ``jax.vjp`` also traces the
+    forward's down-projection and combine, whose results nothing reads:
+    what is counted is what dead-code elimination (the compiler's; jax's
+    own, applied to the loop's body here) leaves."""
+    from jax._src.interpreters import partial_eval as pe
+
+    x, weights, w_gate, w_up, w_down = floats = _window_layer()
+    experts = _pairs_with_a_run(13, 0, (2, 5, 7))
+    layer = jax.checkpoint(lambda *a: moe.expert_mlp(
+        a[0], a[1], experts, *a[2:], _E, 0)[0])
+    traced = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(layer(*a) ** 2), argnums=range(5)))(*floats).jaxpr
+    live, _ = pe.dce_jaxpr(traced, [True] * len(traced.outvars))
+    # the rematted forward's loop feeds nothing and is gone
+    forward, backward = _loops(live)
+    # carries: the window's number and how many there are, then each
+    # token's sum; the five gradients
+    assert [len(e.outvars) for e in (forward, backward)] == [3, 7]
+    d, rows = x.shape[1], moe.window_rows(_N * _K)
+
+    def body(loop):
+        jaxpr = loop.params["body_jaxpr"].jaxpr
+        return pe.dce_jaxpr(jaxpr, [True] * len(jaxpr.outvars))[0]
+
+    def kernels(loop):
+        return sum(e.primitive.name == "pallas_call"
+                   for _, e in _equations(body(loop)))
+
+    def row_gathers(loop):
+        return sorted(e.outvars[0].aval.shape
+                      for _, e in _equations(body(loop))
+                      if e.primitive.name == "gather"
+                      and e.outvars[0].aval.shape[-1] == d)
+
+    assert kernels(forward) == 3 and kernels(backward) == 2 + 3 + 3
+    # a window: the dispatch (R, D) and the combine (N, k, D); backwards:
+    # the dispatch again, the combine's transpose (R, D), the dispatch's
+    # transpose (N, k, D), and no combine a second time
+    assert row_gathers(forward) == sorted([(_N, _K, d), (rows, d)])
+    assert row_gathers(backward) == sorted([(_N, _K, d), (rows, d),
+                                            (rows, d)])
+    assert not any(e.primitive.name.startswith("scatter")
+                   and e.outvars[0].aval.shape[0] in (_N, _N * _K)
+                   for _, e in _equations(live))
+
+
 # ------------------------------------- (d) every expert held is today's layer
 def test_holding_every_expert_is_the_layer_as_it_was():
     """With every expert held the layer traces to what it traced to before
@@ -341,6 +500,25 @@ def test_holding_every_expert_is_the_layer_as_it_was():
 
     assert traced(short) == traced(
         dataclasses.replace(short, experts_held=range(8)))
+    # no windows where the run is every pair: no loop but the layers' scan
+    # and the kernels' own, and one counter; a share has both
+    def loops_and_counters(config):
+        jaxpr = jax.make_jaxpr(functools.partial(
+            llama.loss_and_counters, config=config))(params, tokens, tokens)
+        counters = jax.eval_shape(functools.partial(
+            llama.loss_and_counters, config=config), params, tokens,
+            tokens)[1]
+        return len(_loops(jaxpr.jaxpr)), \
+            {name: c.shape for name, c in counters.items()}
+
+    assert loops_and_counters(short) == (
+        0, {"moe_rows": (short.n_layer, 1, 8)})
+    share = dataclasses.replace(short, experts_held=range(2, 4))
+    params = jax.eval_shape(functools.partial(llama.init_params, share),
+                            jax.random.key(0))
+    assert loops_and_counters(share) == (
+        1, {"moe_rows": (share.n_layer, 1, 2),
+            "moe_moved": (share.n_layer, 1)})
 
 
 # ------------------------------------------------------------ (e) the noise

@@ -69,9 +69,10 @@ def test_the_layer_counts_the_rows_of_the_experts_it_holds(first, held):
            "w_gate": jax.random.normal(keys[2], (held, d, width)) * 0.1,
            "w_up": jax.random.normal(keys[3], (held, d, width)) * 0.1,
            "w_down": jax.random.normal(keys[4], (held, width, d)) * 0.1}
-    _, _, rows = jax.jit(functools.partial(
+    _, _, counts = jax.jit(functools.partial(
         moe.moe_mlp, experts_per_token=k, norm_topk_prob=True,
         dtype=jnp.float32, first_held=first))(h, blk)
+    rows = counts["moe_rows"]
     _, experts, _ = moe.route(h.reshape(-1, d), blk["router"], k, True)
     recount = np.bincount(np.asarray(experts).ravel(), minlength=n_experts)
     assert rows.dtype == jnp.int32 and rows.shape == (1, held)
@@ -105,19 +106,29 @@ def test_three_outputs_and_the_rows_carry_moe_rows(preset, profiler):
     per_layer = got.sum(axis=(1, 2))
     if held == config.n_experts:
         assert per_layer.tolist() == [pairs] * config.n_layer
+        assert "moe_moved" not in row  # every pair, on every step: no count
     else:
         assert (per_layer < pairs).all()
+        # a share: the windows each layer's own count needed, no more
+        moved = np.asarray(row["moe_moved"])
+        assert moved.shape == (config.n_layer, 1)
+        window = moe.window_rows(pairs)
+        assert (moved[:, 0] == -(-per_layer // window) * window).all()
+        assert train_metrics.MOE_MOVED_ROWS.get() == pytest.approx(
+            moved.mean())
     assert row["in_flight"] == 0 and row["done"] > 0
 
 
-def test_under_a_mesh_the_ref_holds_a_row_for_each_shard(profiler):
+@pytest.mark.parametrize("preset", ["tiny_moe", "tiny_sdar"])
+def test_under_a_mesh_the_ref_holds_a_row_for_each_shard(preset, profiler):
     """2 x 2 devices, the batch split four ways: the ref is replicated over
     the mesh, the second call is the first one's program, and each shard's
-    rows stay apart."""
+    rows stay apart; where the layers hold a share each shard walks the
+    windows its own count needs."""
     from ray_tpu.parallel.mesh import MeshSpec, batch_sharding, make_mesh
     from ray_tpu.parallel.train_state import create_sharded_state
 
-    config = llama.LlamaConfig.tiny_moe()
+    config = getattr(llama.LlamaConfig, preset)()
     mesh = make_mesh(MeshSpec(data=2, fsdp=2), jax.devices()[:4])
     optimizer = llama.make_optimizer()
     params, opt_state = create_sharded_state(
@@ -133,10 +144,18 @@ def test_under_a_mesh_the_ref_holds_a_row_for_each_shard(profiler):
     first, second = list(profiler.history)
     assert first["compiles"] >= 1 and second["compiles"] == 0
     got = np.asarray(second["moe_rows"])
-    assert got.shape == (config.n_layer, 4, config.n_experts)
-    # a shard is one sequence: its pairs, in every layer
-    assert (got.sum(axis=2)
-            == config.seq_len * config.experts_per_token).all()
+    assert got.shape == (config.n_layer, 4, len(config.held))
+    # a shard is one sequence (with its clean copy under block diffusion):
+    # its pairs, in every layer
+    pairs = config.seq_len * config.experts_per_token \
+        * (2 if config.block_length else 1)
+    if len(config.held) == config.n_experts:
+        assert (got.sum(axis=2) == pairs).all() and "moe_moved" not in second
+        return
+    moved = np.asarray(second["moe_moved"])
+    window = moe.window_rows(pairs)
+    assert moved.shape == (config.n_layer, 4)
+    assert (moved == -(-got.sum(axis=2) // window) * window).all()
 
 
 def _entry_parameters(text: str) -> int:
@@ -214,7 +233,7 @@ def test_without_a_profiler_nothing_is_read_and_no_thread_starts():
 
     assert RunConfig(profile=False).profile is False
     train_profiler.activate(None)
-    before = len(_resolver_threads())
+    before = set(_resolver_threads())  # an earlier test's may still end
     config = llama.LlamaConfig.tiny_moe()
     optimizer, params, opt_state, tokens = _state(config)
     step_fn = llama.make_train_step(config, optimizer)
@@ -223,7 +242,7 @@ def test_without_a_profiler_nothing_is_read_and_no_thread_starts():
     step._hand_over = lambda *a: reads.append(a)
     params, opt_state, loss = step(params, opt_state, tokens, tokens)
     assert np.isfinite(float(loss)) and reads == []
-    assert len(_resolver_threads()) == before
+    assert set(_resolver_threads()) <= before
     # the counter was written all the same: it is part of the program
     assert int(step_fn.counters["moe_rows"][...].sum()) > 0
 
@@ -397,6 +416,7 @@ def test_every_counter_gauge_reads_a_registered_counter():
     assert train_metrics.moe_load_max(
         [[[3, 1]], [[0, 0]]]) == pytest.approx(1.5)
     assert train_metrics.moe_load_max([[[0, 0]]]) is None
+    assert train_metrics.moe_moved_rows([[8, 16], [64, 8]]) == 24.0
 
 
 def test_the_resolver_thread_ends_with_the_session():
@@ -428,6 +448,7 @@ def test_counter_names_are_under_the_registry_check():
     source = ("from ray_tpu.util.tracing import step_counter\n"
               "def loss(rows):\n"
               "    return 0.0, {step_counter('moe_rows'): rows,\n"
+              "                 step_counter('moe_moved'): rows,\n"
               "                 step_counter('moe_rowz'): rows}\n")
     module = core.SourceModule("fixture.py", "ray_tpu/models/fixture.py",
                                source)
