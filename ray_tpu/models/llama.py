@@ -61,19 +61,18 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass
 from functools import partial
-from typing import Any, Dict, Optional, Tuple, Union
+from typing import Any, Dict, Optional, Union
 
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models import block_diffusion
-from ray_tpu.models import moe as _moe
-from ray_tpu.ops import grad_ring, remat
-from ray_tpu.ops.attention import block_diffusion_attention, causal_attention
+from ray_tpu.models.layers import attention, feed_forward, mesh_axes
+from ray_tpu.models.layers import rmsnorm as _rmsnorm
+from ray_tpu.models.layers import rope as _rope  # noqa: F401
+from ray_tpu.ops import remat
 from ray_tpu.ops.lm_head import lm_head_cross_entropy
-from ray_tpu.parallel.mesh import DEFAULT_RULES
 from ray_tpu.parallel.train_state import make_optimizer  # noqa: F401
 from ray_tpu.parallel.train_state import make_train_step as _make_train_step
 from ray_tpu.parallel.train_state import note_first_call
@@ -286,124 +285,13 @@ def flops_per_token(config: LlamaConfig) -> float:
         + 12.0 * L * config.n_head * config.head_dim * area
 
 
-def _rmsnorm(x, scale, eps):
-    x32 = x.astype(jnp.float32)
-    ms = jnp.mean(x32 * x32, axis=-1, keepdims=True)
-    return x32 * lax.rsqrt(ms + eps) * scale
-
-
-def _rope_pass(x, theta: float, direction: float):
-    """x * cos + swap_halves(x) * (-sin | +sin) over (B, S, H, hd): the
-    rotate-half pairing (i, i + hd/2) as published, cos and sin from float32
-    angles, products and sum in float32, one rounding to x's dtype.
-    ``direction`` 1.0 rotates each pair by its position's angle, -1.0 back.
-
-    Everything stays hd wide so that the compiler makes it one pass, x read
-    once and the result written once in x's dtype.  Slicing the two halves
-    (or ``jnp.roll``, which is two slices) gave arrays of 64 lanes padded to
-    128 and a float32 copy of x in HBM: three passes forward and three
-    backward, 0.6 and 0.95 GB a layer for Mistral's q at 8192 tokens where
-    this needs 0.2 and 0.13 (PERF.md, PR 27).  The halves are swapped by a
-    product with a 0/1 permutation instead: each output is one input times
-    one, so it is exact, and cos, sin and the cast fuse into its output.
-    """
-    hd = x.shape[-1]
-    half = hd // 2
-    lane = jnp.arange(hd)
-    # (S, hd): lanes i and i + hd/2 share a frequency, so an angle
-    freqs = 1.0 / (theta ** ((lane % half).astype(jnp.float32) / half))
-    angles = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * freqs[None, :]
-    sign = jnp.where(lane < half, -direction, direction)
-    cos = jnp.cos(angles)[None, :, None, :]
-    sin = (jnp.sin(angles) * sign)[None, :, None, :]
-    swap = (lane[:, None] == (lane[None, :] + half) % hd).astype(x.dtype)
-    swapped = jnp.einsum("bshd,de->bshe", x, swap,
-                         preferred_element_type=jnp.float32,
-                         precision=lax.Precision.HIGHEST)
-    return (x.astype(jnp.float32) * cos + swapped * sin).astype(x.dtype)
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(1,))
-def _rope(x, theta: float):
-    """Rotary position embedding over (B, S, H, hd), rotate-half form, in
-    and out in x's dtype.  The backward is the same pass with the sine
-    negated (a rotation's transpose is the rotation back), not autodiff's
-    sum over sliced halves."""
-    return _rope_pass(x, theta, 1.0)
-
-
-def _rope_fwd(x, theta):
-    return _rope_pass(x, theta, 1.0), None
-
-
-def _rope_bwd(theta, _, g):
-    return (_rope_pass(g, theta, -1.0),)
-
-
-_rope.defvjp(_rope_fwd, _rope_bwd)
-
-
 def _block(x, blk, config: LlamaConfig):
-    """One layer.  -> (x, what the expert layer says of itself): the second
-    is ``None`` for a dense MLP; with experts it is (moe.router_losses' pair,
-    the layer's counts: ``moe.moe_mlp``)."""
-    dt = config.dtype
-    B, S, D = x.shape
-    H, KV, hd = config.n_head, config.n_kv_head, config.head_dim
+    """One layer: ``models/layers.py``'s two halves.  -> (x, what the expert
+    layer says of itself): the second is ``None`` for a dense MLP; with
+    experts it is (moe.router_losses' pair, the layer's counts:
+    ``moe.moe_mlp``)."""
     axes = logical_axes(config)["blocks"]
-
-    def dense(a, name):
-        """``a @ blk[name]`` in the compute dtype; under `fsdp` the weight's
-        gradient is summed while it multiplies (``ops/grad_ring.py``), along
-        the axis ``logical_axes`` calls `embed` (the layer axis is scanned
-        away here)."""
-        return grad_ring.dense(a, blk[name].astype(dt),
-                               axes[name][1:].index("embed"))
-
-    with jax.named_scope("attn"):
-        h = _rmsnorm(x, blk["attn_norm"], config.rms_eps).astype(dt)
-        q = dense(h, "wq")
-        k = dense(h, "wk")
-        if config.qk_norm == "head":
-            q, k = q.reshape(B, S, H, hd), k.reshape(B, S, KV, hd)
-        if config.qk_norm:
-            q = _rmsnorm(q, blk["q_norm"], config.rms_eps).astype(dt)
-            k = _rmsnorm(k, blk["k_norm"], config.rms_eps).astype(dt)
-        v = dense(h, "wv").reshape(B, S, KV, hd)
-        # A block-diffusion row is two copies that share positions: each
-        # rotates as a row of its own, so a position is its axis index.
-        copies = 2 if config.block_length else 1
-        q = _rope(q.reshape(B * copies, S // copies, H, hd),
-                  config.rope_theta).reshape(B, S, H, hd)
-        k = _rope(k.reshape(B * copies, S // copies, KV, hd),
-                  config.rope_theta).reshape(B, S, KV, hd)
-        q, k, v = (checkpoint_name(a, remat.QKV) for a in (q, k, v))
-        # GQA: k and v go in at KV heads; the splash kernel takes them so,
-        # and the dispatcher repeats them for the paths that cannot.
-        if config.block_length:
-            attn = block_diffusion_attention(q, k, v, config.block_length,
-                                             config.attn_impl)
-        else:
-            attn = causal_attention(q, k, v, config.attn_impl)
-        attn = attn.astype(dt).reshape(B, S, H * hd)
-        x = x + dense(attn, "wo")
-
-    with jax.named_scope("mlp"):
-        h = _rmsnorm(x, blk["mlp_norm"], config.rms_eps)
-        if config.n_experts:
-            # the router reads the norm's float32 output, the experts its
-            # cast to the compute dtype
-            y, router_losses, counts = _moe.moe_mlp(
-                h, blk, experts_per_token=config.experts_per_token,
-                norm_topk_prob=config.norm_topk_prob, dtype=dt,
-                first_held=config.held.start)
-            return x + y, (router_losses, counts)
-        h = h.astype(dt)
-        gate = checkpoint_name(dense(h, "w_gate"), remat.GATE_UP)
-        up = checkpoint_name(dense(h, "w_up"), remat.GATE_UP)
-        act = jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
-        x = x + dense(act.astype(dt), "w_down")
-    return x, None
+    return feed_forward(attention(x, blk, config, axes), blk, config, axes)
 
 
 def _layer_policy(params, x_shape, config: LlamaConfig):
@@ -436,7 +324,7 @@ def _layer_sizes(params, x_shape, config: LlamaConfig):
     whole = jax.tree.map(lambda a: 4 * a.size, params)
     chips = jax.tree.map(
         lambda nbytes, axes: nbytes // remat.axis_shards(
-            mesh, *_mesh_axes(axes)),
+            mesh, *mesh_axes(axes)),
         whole, logical_axes(config))
     total, block_bytes = (sum(jax.tree.leaves(t)) for t in
                           (chips, chips["blocks"]))
@@ -461,12 +349,6 @@ def _layer_sizes(params, x_shape, config: LlamaConfig):
                  remat.GATE_UP: 2 * tokens * mlp_width * item}
     return ([(name, config.n_layer * per_layer[name])
              for name in remat.LADDER], temporaries)
-
-
-def _mesh_axes(logical) -> Tuple[str, ...]:
-    """The mesh axes a parameter with these logical axes is cut over."""
-    return tuple(a for name in logical if name is not None
-                 for a in DEFAULT_RULES.get(name) or ())
 
 
 def forward_hidden(params: Dict[str, Any], tokens, config: LlamaConfig):

@@ -108,18 +108,42 @@ def router_losses(logits, probs, experts, batch_axes=()
     return E * jnp.sum(f * p), z
 
 
-def route(h32, router_w, k: int, norm_topk_prob: bool, batch_axes=()):
+def route(h32, router_w, k: int, norm_topk_prob: bool, batch_axes=(),
+          scoring: str = "softmax", bias=None, scale: float = 1.0):
     """h32: (..., D) float32.  -> combine weights (..., k) float32, expert ids
     (..., k) int32, (load-balance, z).  The matmul runs at full float32
     precision (the TPU's default would round its operands to bfloat16, and a
-    token's k-th expert is decided by the last bits)."""
+    token's k-th expert is decided by the last bits).
+
+    ``scoring`` is how a logit becomes a weight.  ``"softmax"``: the k
+    largest probabilities, the two router losses over them.  ``"sigmoid"``
+    (Nemotron-3, DeepSeek-V3): every expert scored by itself; the k experts
+    are the largest of ``score + bias``, with ``bias`` (E,) a selection bias
+    that balances the load and is no parameter (it picks the experts, does
+    not weigh them, and gets no gradient), the weights the scores at those
+    experts **without** it; such a router names no auxiliary loss, and the
+    two losses come back zero.  Either way ``norm_topk_prob`` divides the k
+    weights by their sum and ``scale`` multiplies them
+    (``routed_scaling_factor``)."""
     logits = jnp.einsum("...d,de->...e", h32, router_w.astype(jnp.float32),
                         precision=lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    weights, experts = lax.top_k(probs, k)
-    losses = router_losses(logits, probs, experts, batch_axes)
+    if scoring == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, experts = lax.top_k(probs, k)
+        losses = router_losses(logits, probs, experts, batch_axes)
+    elif scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        chosen_by = scores if bias is None else \
+            scores + lax.stop_gradient(bias.astype(jnp.float32))
+        experts = lax.top_k(lax.stop_gradient(chosen_by), k)[1]
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
+        losses = (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32))
+    else:
+        raise ValueError(f"router scoring {scoring!r} (softmax|sigmoid)")
     if norm_topk_prob:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    if scale != 1.0:
+        weights = weights * scale
     return weights, experts, losses
 
 
@@ -235,22 +259,30 @@ _from_window.defvjp(
     lambda window, g: (_to_window(g, *window), None, None, None))
 
 
-def _through_experts(rows, w_rows, w_gate, w_up, w_down, sizes, first):
+def relu2(x):
+    """relu(x)^2, the activation of Nemotron's two-matrix experts."""
+    return jnp.square(jax.nn.relu(x))
+
+
+def _through_experts(rows, w_rows, matrices, sizes, first, activation):
     """rows: (M, D) in expert order, w_rows: (M,) their combine weights ->
-    (M, D): gate and up, the weighted activation, down, over the groups
-    ``sizes``, whose matrices are those from group ``first`` on."""
-    gate = checkpoint_name(grouped_matmul(rows, w_gate, sizes, first),
-                           remat.GATE_UP)
-    up = checkpoint_name(grouped_matmul(rows, w_up, sizes, first),
-                         remat.GATE_UP)
-    act = (jax.nn.silu(gate.astype(jnp.float32))
-           * up.astype(jnp.float32)
-           * w_rows[:, None].astype(jnp.float32)).astype(rows.dtype)
+    (M, D), over the groups ``sizes``, whose matrices are those from group
+    ``first`` on.  ``matrices`` = (w_gate, w_up, w_down): gate and up, the
+    weighted ``activation(gate) * up``, down (SwiGLU with ``silu``); or
+    (w_up, w_down), an expert without a gate: up, the weighted
+    ``activation(up)``, down."""
+    *w_in, w_down = matrices
+    hidden = [checkpoint_name(grouped_matmul(rows, w, sizes, first),
+                              remat.GATE_UP) for w in w_in]
+    act = activation(hidden[0].astype(jnp.float32))
+    if len(hidden) == 2:
+        act = act * hidden[1].astype(jnp.float32)
+    act = (act * w_rows[:, None].astype(jnp.float32)).astype(rows.dtype)
     return grouped_matmul(act, w_down, sizes, first)
 
 
-def _move_window(first_held, c, x, w_sorted, w_gate, w_up, w_down, order,
-                 inverse, group_sizes):
+def _move_window(static, c, x, w_sorted, matrices, order, inverse,
+                 group_sizes):
     """Window ``c`` of the held experts' run through the layer: the
     :func:`window_rows` places of the sorted order from ``c`` windows behind
     the run's first (from earlier where the order ends sooner; those rows
@@ -259,9 +291,10 @@ def _move_window(first_held, c, x, w_sorted, w_gate, w_up, w_down, order,
     groups that ``rhs`` does not hold, the window's rows before and after
     its part of the run: those rows come out zero and no tile of theirs is
     visited."""
+    first_held, activation = static
     bound = window_rows(order.shape[0])
     with jax.named_scope("moe_dispatch"):
-        held_rows = group_sizes[first_held:first_held + w_gate.shape[0]]
+        held_rows = group_sizes[first_held:first_held + matrices[-1].shape[0]]
         start = jnp.sum(group_sizes[:first_held])  # of the run
         stops = start + jnp.cumsum(held_rows)      # of each held group
         first = start + c * bound
@@ -276,7 +309,7 @@ def _move_window(first_held, c, x, w_sorted, w_gate, w_up, w_down, order,
         rows = _to_window(x, *window)
         w_rows = lax.dynamic_slice(w_sorted, (first - lead,), (bound,))
     with jax.named_scope("moe_held"):
-        out = _through_experts(rows, w_rows, w_gate, w_up, w_down, sizes, 1)
+        out = _through_experts(rows, w_rows, matrices, sizes, 1, activation)
     with jax.named_scope("moe_dispatch"):
         return _from_window(out, *window)
 
@@ -287,54 +320,58 @@ def _sum(a, b):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _held_move(first_held, windows, x, w_sorted, w_gate, w_up, w_down, order,
-               inverse, group_sizes):
+def _held_move(static, windows, x, w_sorted, matrices, order, inverse,
+               group_sizes):
     """A share's layer behind the sort: the first ``windows`` windows of the
     held run, one after the other: a loop whose length the step's own count
-    sets.  The backward is a second such loop whose pass runs its window
+    sets.  ``static`` = (the first held expert's id, the activation);
+    ``matrices`` as :func:`_through_experts` takes them.  The backward is a
+    second such loop whose pass runs its window
     again as far as the backward reads it and then backwards: what a window
     keeps lives inside its pass, and the layer saves its arguments and
     nothing else.  What the windows add up to, each token's rows and every
     gradient, is carried in the dtype the layer hands it on in, each
     addition made in float32 (two windows: the float32 sum, rounded once)."""
     def add(c, y):
-        return _sum(y, _move_window(first_held, c, x, w_sorted, w_gate, w_up,
-                                    w_down, order, inverse, group_sizes))
+        return _sum(y, _move_window(static, c, x, w_sorted, matrices, order,
+                                    inverse, group_sizes))
 
     return lax.fori_loop(0, windows, add, jnp.zeros_like(x))
 
 
-def _held_move_fwd(first_held, windows, *args):
-    return _held_move(first_held, windows, *args), (windows, args)
+def _held_move_fwd(static, windows, *args):
+    return _held_move(static, windows, *args), (windows, args)
 
 
-def _held_move_bwd(first_held, saved, g):
+def _held_move_bwd(static, saved, g):
     windows, (*floats, order, inverse, group_sizes) = saved
 
     def add(c, grads):
         return jax.tree.map(_sum, grads, jax.vjp(
-            lambda *floats: _move_window(first_held, c, *floats, order,
-                                         inverse, group_sizes),
+            lambda *floats: _move_window(static, c, *floats, order, inverse,
+                                         group_sizes),
             *floats)[1](g))
 
-    return (None, *lax.fori_loop(0, windows, add, tuple(
-        jnp.zeros_like(f) for f in floats)), None, None, None)
+    return (None, *lax.fori_loop(0, windows, add, jax.tree.map(
+        jnp.zeros_like, tuple(floats))), None, None, None)
 
 
 _held_move.defvjp(_held_move_fwd, _held_move_bwd)
 
 
 def expert_mlp(x, weights, experts, w_gate, w_up, w_down, n_experts=None,
-               first_held: int = 0):
+               first_held: int = 0, activation=jax.nn.silu):
     """One chip's tokens through their experts.  x: (N, D); weights,
     experts: (N, k); w_gate, w_up: (H, D, F); w_down: (H, F, D), all in the
     compute dtype: the matrices of experts ``first_held .. first_held + H``
-    of ``n_experts`` (default H: every expert is held).  -> ((N, D); the
+    of ``n_experts`` (default H: every expert is held); ``w_gate`` None for
+    experts of two matrices, ``down(activation(up(x)))``.  -> ((N, D); the
     pairs that reached each held expert, (H,) int32; the rows the layer
     moved, int32, :func:`window_rows` for each window the step's count
     needed: ``None`` where every expert is held and the layer moves every
     pair at once)."""
-    held = w_gate.shape[0]
+    matrices = (w_up, w_down) if w_gate is None else (w_gate, w_up, w_down)
+    held = w_up.shape[0]
     n_experts = n_experts or held
     with jax.named_scope("moe_dispatch"):
         order, inverse, group_sizes = sort_pairs(experts, n_experts)
@@ -343,26 +380,34 @@ def expert_mlp(x, weights, experts, w_gate, w_up, w_down, n_experts=None,
             bound = window_rows(order.shape[0])
             windows = (jnp.sum(held_rows) + (bound - 1)) // bound
             return _held_move(
-                first_held, windows, x,
-                _weights_to_expert_order(weights, order, inverse), w_gate,
-                w_up, w_down, order, inverse, group_sizes), \
+                (first_held, activation), windows, x,
+                _weights_to_expert_order(weights, order, inverse), matrices,
+                order, inverse, group_sizes), \
                 held_rows, windows * bound
         rows = _to_expert_order(x, order, inverse)
         w_rows = _weights_to_expert_order(weights, order, inverse)
     with jax.named_scope("experts"):
-        out = _through_experts(rows, w_rows, w_gate, w_up, w_down,
-                               group_sizes, 0)
+        out = _through_experts(rows, w_rows, matrices, group_sizes, 0,
+                               activation)
     with jax.named_scope("moe_dispatch"):
         return _combine(out, order, inverse), held_rows, None
 
 
 def moe_mlp(h32, blk, *, experts_per_token: int, norm_topk_prob: bool, dtype,
-            first_held: int = 0):
+            first_held: int = 0, scoring: str = "softmax", bias=None,
+            scale: float = 1.0, activation=jax.nn.silu):
     """The layer.  h32: (B, S, D) float32, the block's normed input (the
     router reads it as it is, the experts its cast to ``dtype``); ``blk``
     holds ``router`` (D, E) and the matrices of the H experts from
-    ``first_held`` on, ``w_gate``, ``w_up`` (H, D, F), ``w_down`` (H, F, D);
-    H = E unless the chip holds a share.  -> (y (B, S, D) in ``dtype``,
+    ``first_held`` on: ``w_gate``, ``w_up`` (H, D, F), ``w_down`` (H, F, D),
+    or, experts without a gate, ``w_up`` and ``w_down`` alone
+    (``down(activation(up(x)))``); H = E unless the chip holds a share,
+    which ``w_up``'s leading size against the router's outputs says.
+    ``scoring``, ``bias``, ``scale``: :func:`route`'s.  Where ``blk`` holds
+    ``shared_up`` (D, F_s) and ``shared_down`` (F_s, D), a shared expert,
+    ``shared_down(activation(shared_up(x)))`` of every token, is added to
+    the routed sum after the combine; every chip of an expert-parallel job
+    computes it alike.  -> (y (B, S, D) in ``dtype``,
     (load-balance, z), counts): the losses over all E experts; ``counts``
     what the data decided, by its name as a step counter
     (``tracing.STEP_COUNTER_REGISTRY``), each with one row for each shard of
@@ -374,32 +419,49 @@ def moe_mlp(h32, blk, *, experts_per_token: int, norm_topk_prob: bool, dtype,
     sharded = not (mesh.empty or mesh.size == 1)
     batch_axes = tuple(a for a in ("data", "fsdp")
                        if sharded and a in mesh.axis_names)
+    names = ("w_gate", "w_up", "w_down") if "w_gate" in blk \
+        else ("w_up", "w_down")
 
-    def local(h32, router, w_gate, w_up, w_down):
+    def local(h32, router, *matrices):
         tokens = h32.reshape(-1, h32.shape[-1])
         with jax.named_scope("router"):
             weights, experts, losses = route(
-                tokens, router, experts_per_token, norm_topk_prob, batch_axes)
+                tokens, router, experts_per_token, norm_topk_prob, batch_axes,
+                scoring, bias, scale)
+        w_gate = matrices[0] if len(matrices) == 3 else None
         y, held_rows, moved = expert_mlp(
-            tokens.astype(dtype), weights, experts, w_gate, w_up, w_down,
-            router.shape[-1], first_held)
+            tokens.astype(dtype), weights, experts, w_gate, *matrices[-2:],
+            router.shape[-1], first_held, activation)
         counts = {step_counter("moe_rows"): held_rows[None]}
         if moved is not None:
             counts[step_counter("moe_moved")] = moved[None]
         return y.reshape(h32.shape), losses, counts
 
-    args = (h32, blk["router"], blk["w_gate"].astype(dtype),
-            blk["w_up"].astype(dtype), blk["w_down"].astype(dtype))
+    args = (h32, blk["router"], *(blk[name].astype(dtype) for name in names))
     if not sharded:
-        return local(*args)
-    P = jax.sharding.PartitionSpec
-    rows = P(batch_axes or None, None, None)
-    share = blk["w_gate"].shape[0] < blk["router"].shape[-1]
-    counts = ("moe_rows", "moe_moved") if share else ("moe_rows",)
-    # check_vma off as for splash: a pallas_call declares no vma on its
-    # outputs.  Axes a spec does not name (the weights' every axis) see
-    # whole arrays.
-    return jax.shard_map(local, in_specs=(rows, P(), P(), P(), P()),
-                         out_specs=(rows, (P(), P()), dict.fromkeys(
-                             counts, P(batch_axes or None))),
-                         check_vma=False)(*args)
+        out = local(*args)
+    else:
+        P = jax.sharding.PartitionSpec
+        rows = P(batch_axes or None, None, None)
+        share = blk["w_up"].shape[0] < blk["router"].shape[-1]
+        counts = ("moe_rows", "moe_moved") if share else ("moe_rows",)
+        # check_vma off as for splash: a pallas_call declares no vma on its
+        # outputs.  Axes a spec does not name (the weights' every axis) see
+        # whole arrays.
+        out = jax.shard_map(
+            local, in_specs=(rows, P(), *(P() for _ in names)),
+            out_specs=(rows, (P(), P()), dict.fromkeys(
+                counts, P(batch_axes or None))),
+            check_vma=False)(*args)
+    if "shared_up" not in blk:
+        return out
+    y, losses, counts = out
+    with jax.named_scope("shared_expert"):
+        h = h32.astype(dtype)
+        up = checkpoint_name(
+            jnp.einsum("bsd,df->bsf", h, blk["shared_up"].astype(dtype)),
+            remat.GATE_UP)
+        act = activation(up.astype(jnp.float32)).astype(dtype)
+        shared = jnp.einsum("bsf,fd->bsd", act,
+                            blk["shared_down"].astype(dtype))
+        return _sum(y, shared), losses, counts

@@ -170,6 +170,16 @@ SCOPE_REGISTRY: Dict[str, str] = {
     "moe_held": "expert layer that holds a share of the experts, nested "
                 "inside mlp in place of experts: the grouped matmuls over "
                 "the held groups and the activation between them",
+    "shared_expert": "expert layer with a shared expert (models/moe.py), "
+                     "nested inside mlp: the dense two-matrix MLP every "
+                     "token passes, and its sum with the routed part",
+    "ssm": "a Mamba-2 layer (models/mamba2.py): norm, in-projection, the "
+           "gate, the grouped norm, out-projection (around the two scopes "
+           "below)",
+    "ssm_conv": "Mamba-2 layer, nested inside ssm: the causal depthwise "
+                "convolution over positions, its bias and the silu",
+    "ssm_scan": "Mamba-2 layer, nested inside ssm: the chunked state-space "
+                "scan (ops/ssd.py), whatever implements it",
     "noise": "block-diffusion training (models/block_diffusion.py): the "
              "draw of the masked positions, the noised copy, the "
              "concatenation with the clean one, the loss weights",

@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 from jax._src.ad_checkpoint import saved_residuals  # not re-exported in 0.9
 
-from ray_tpu.models import llama, moe
+from ray_tpu.models import layers, llama, moe
 from ray_tpu.ops import remat
 from ray_tpu.ops.attention import save_splash_residuals
 from ray_tpu.parallel import MeshSpec, make_mesh
@@ -103,6 +103,44 @@ def test_the_rule_decides_each_cell(cell, nudge_mb, monkeypatch):
         decision = _decide(config, B)
     assert decision.kept == want
     assert decision.room_bytes is not None and decision.processes == 1
+
+
+@pytest.mark.parametrize("nudge_mb", [0, -64, 64])
+def test_the_rule_is_given_each_layer_kinds_sizes(nudge_mb, monkeypatch):
+    """``models/hybrid.py`` at the sizes of ``nemotron-ep16-s8192`` (4 Mamba-2,
+    4 expert and 1 attention layer, 2 x 8192 tokens, 6.21 GiB of state
+    resident; compile-only for the v5e, PERF.md PR 40): the candidates are the
+    attention layer's q, k and v and the four shared experts' up products,
+    the bound lies over the compiler's 6.37 GiB of temporaries, and the chip
+    has room for no rung, with enough to spare that a second trace of the
+    step, 0.3 GiB fuller (the benchmark's lowering for the anatomy), says
+    the same; a chip with room keeps both."""
+    from ray_tpu.models import hybrid
+
+    config = hybrid.HybridConfig(
+        vocab_size=16384, d_model=2688, seq_len=8192, n_head=32, n_kv_head=2,
+        head_dim=128, ssm_heads=64, ssm_head_dim=64, ssm_groups=8,
+        ssm_state=128, ssm_chunk=128, n_experts=128, experts_per_token=6,
+        d_ff=1856, shared_width=3712, experts_held=range(8))
+    shapes = jax.eval_shape(lambda: hybrid.init_params(config,
+                                                       jax.random.key(0)))
+    candidates, temporaries = hybrid._layer_sizes(shapes, (2, 8192, 2688),
+                                                  config)
+    tokens = 2 * 8192
+    assert candidates == [(remat.QKV, tokens * (32 + 4) * 128 * 2),
+                          (remat.GATE_UP, 4 * tokens * 3712 * 2)]
+    assert 6.37 * GiB < temporaries < 9.0 * GiB
+    for fuller in (0.0, 0.3):
+        on_device(monkeypatch, (V5E, int((6.21 + fuller) * GiB)
+                                + nudge_mb * 2 ** 20))
+        with remat.recording() as decided:
+            remat.layer_policy(candidates, temporaries)
+        assert decided[0].kept == ()
+        assert decided[0].room_bytes < -0.5 * GiB
+    on_device(monkeypatch, ROOMY)
+    with remat.recording() as decided:
+        remat.layer_policy(candidates, temporaries)
+    assert decided[0].kept == BOTH
 
 
 @pytest.mark.parametrize("limit, in_use, candidates, temporaries, want", [
@@ -334,7 +372,7 @@ def test_without_device_memory_the_step_is_the_parents(preset, monkeypatch):
     ours = _tiny_step_text(config)
     monkeypatch.setattr(llama, "_layer_policy",
                         lambda *a: save_splash_residuals)
-    for module in (llama, moe):
+    for module in (layers, moe):  # where the layer's arrays are named
         monkeypatch.setattr(module, "checkpoint_name", lambda x, name: x)
     assert _tiny_step_text(config) == ours
 

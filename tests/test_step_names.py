@@ -4,7 +4,8 @@ step): ``jax.named_scope`` names in the compiled train step, ``TrainStep``
 clock, and the step profiler's counters.
 
 * each model family's lowered step holds every registered scope that is its
-  own (the three expert-layer scopes are the llama step's with experts);
+  own (the three expert-layer scopes are the llama step's with experts; the
+  Mamba-2 scopes and the shared expert's are the hybrid step's);
 * ``classify_op_name`` on real ``op_name`` strings of the compiled v5e steps
   (``tests/data/v5e_step_op_names.json``) and ``parse_anatomy`` on an
   excerpt of that module's text (``tests/data/v5e_step_excerpt.hlo.txt``);
@@ -52,10 +53,17 @@ MOE_SCOPES = {"router", "moe_dispatch", "experts"}
 SDAR_SCOPES = {"moe_held", "noise"}
 
 
+#: scopes only a step of models/hybrid.py opens: the Mamba-2 layers and the
+#: shared expert beside the routed ones
+HYBRID_SCOPES = {"ssm", "ssm_conv", "ssm_scan", "shared_expert"}
+
+
 def _scopes_of(family):
+    if family == "hybrid":  # holds a share, trains next tokens
+        return set(tracing.SCOPE_REGISTRY) - {"experts", "noise"}
     if family == "llama-sdar":
-        return set(tracing.SCOPE_REGISTRY) - {"experts"}
-    return set(tracing.SCOPE_REGISTRY) - SDAR_SCOPES - (
+        return set(tracing.SCOPE_REGISTRY) - {"experts"} - HYBRID_SCOPES
+    return set(tracing.SCOPE_REGISTRY) - SDAR_SCOPES - HYBRID_SCOPES - (
         set() if family == "llama-moe" else MOE_SCOPES)
 
 
@@ -68,6 +76,10 @@ def _family(name):
         return llama, llama.LlamaConfig.tiny_moe()
     if name == "llama-sdar":  # what sdar-ep8-s8192 runs
         return llama, llama.LlamaConfig.tiny_sdar()
+    if name == "hybrid":  # what nemotron-ep16-s8192 runs
+        from ray_tpu.models import hybrid
+
+        return hybrid, hybrid.HybridConfig.tiny()
     config = gpt2.GPTConfig.tiny()
     if name == "gpt2-attn-outside-unrolled":  # what gpt2xl-s1024 runs
         import dataclasses
@@ -101,7 +113,8 @@ def _tiny_step(name="llama"):
 
 # ------------------------------------------------------- names in the step
 @pytest.mark.parametrize("family", ["llama", "llama-moe", "llama-sdar",
-                                    "gpt2", "gpt2-attn-outside-unrolled"])
+                                    "hybrid", "gpt2",
+                                    "gpt2-attn-outside-unrolled"])
 def test_lowered_step_holds_every_registered_scope(family):
     import jax
     import jax.numpy as jnp
@@ -181,7 +194,7 @@ def test_parse_anatomy_on_v5e_module_excerpt():
 
 
 @pytest.mark.parametrize("family", ["llama", "llama-moe", "llama-sdar",
-                                    "gpt2-attn-outside-unrolled"])
+                                    "hybrid", "gpt2-attn-outside-unrolled"])
 def test_anatomy_of_a_tiny_cpu_step_end_to_end(family):
     from ray_tpu.parallel.train_state import PHASES
 
