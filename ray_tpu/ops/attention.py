@@ -38,18 +38,26 @@ pair, on a kernel its vector unit already bounds), so its mask goes to the
 library as a lazy object that is read a block at a time in numpy while the
 step is traced: whole blocks are told apart there and pay one ``or`` in the
 kernel, and a block the mask cuts through reads one of three stored tiles.
-No 2S x 2S array exists.  What the call costs a head (``attn_calls``,
-``attn_blocks``, ``attn_blocks_cut``, ``attn_grid_steps_fwd``,
-``attn_grid_steps_bwd``) is on the ``train.first_call`` span and record.
-head_dim=64 compiles unpadded under the 512x512 blocks on the v5e and agrees
-with the einsum (chip_smoke.py, kernel phase).
+No 2S x 2S array exists.
+
+The blocks a call runs in follow the row it runs on: :func:`splash_blocks`
+picks them where the kernel is built, from the row's kv length and the head
+dimension (1024 x 1024 at head 128 on a row of 2048 or more, 512 x 512
+otherwise, from a sweep on the v5e), the forward's and the fused backward's
+apart.  What the call costs a
+head (``attn_calls``, ``attn_blocks``, ``attn_blocks_cut``,
+``attn_grid_steps_fwd``, ``attn_grid_steps_bwd``) and in what blocks
+(``attn_block_q``, ``attn_block_kv``, ``attn_block_q_bwd``,
+``attn_block_kv_bwd``, ``attn_dq_partials``) is on the ``train.first_call``
+span and record.  head_dim=64 compiles unpadded under the 512x512 blocks on
+the v5e and agrees with the einsum (chip_smoke.py, kernel phase).
 """
 
 from __future__ import annotations
 
 import functools
 import math
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -141,14 +149,75 @@ def _attention(q, k, v, impl: str, block_length: int):
         return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def _splash_kernel(seq_len: int, n_heads: int, block_q: int, block_kv: int,
-                   causal: bool, block_length: int = 0):
-    """The splash kernel over ``seq_len`` positions, and what the call costs
-    a head, as the first-call record carries it: ``attn_calls`` (1),
-    ``attn_blocks`` (those with work), ``attn_blocks_cut`` (those of them
-    that apply a mask: a stored tile read, or the mask computed from the
-    positions), ``attn_grid_steps_fwd`` and ``attn_grid_steps_bwd`` (of the
-    forward and of the fused backward, skipped steps included).
+class SplashBlocks(NamedTuple):
+    """The block sizes of one splash call.  The library takes the forward's
+    and the fused backward's apart, and their costs differ: only the
+    backward writes a dq partial for every kv block of the row, and only its
+    grid is walked whole, steps without work included."""
+
+    q: int
+    kv: int
+    kv_compute: int
+    q_bwd: int
+    kv_bwd: int
+    kv_bwd_compute: int
+
+    @classmethod
+    def square(cls, block: int) -> "SplashBlocks":
+        return cls(*(block,) * 6)
+
+    def capped(self, seq_len: int) -> "SplashBlocks":
+        """No block longer than the row, no compute sub-block longer than
+        its block."""
+        q, kv, kvc, qb, kvb, kvbc = (min(b, seq_len) for b in self)
+        return SplashBlocks(q, kv, min(kvc, kv), qb, kvb, min(kvbc, kvb))
+
+
+def splash_blocks(kv_len: int, head_dim: int) -> SplashBlocks:
+    """The blocks a splash call runs in, from what the call site holds: the
+    row's kv length and the head dimension.  Swept on the v5e at the seven
+    shapes the benchmark's cells run, forward and forward + fused backward +
+    the sum of its dq partials, then held against the cells' traces (PERF.md,
+    PR 42; ``scripts/splash_block_sweep.py``):
+
+    - head 128 on a row of 2048 or more: q and kv blocks of 1024, the compute
+      sub-block 512, forward and backward alike, whatever the mask (causal or
+      block diffusion) and the row (4096 to 16384 measured).  Against blocks
+      of 512 the forward is 6-27 % faster and forward + backward 8-15 %: a
+      quarter of the grid steps, K and V read half as often, half the dq
+      partials written and summed, and on a block-diffusion row a quarter of
+      the backward's steps without work.  Longer blocks gain nothing more (a
+      kv block of 2048 halves the partials again and computes as much more
+      under the mask) or exceed the scoped VMEM (a q block of 2048 with most
+      kv blocks; kv blocks of 2048 with a compute sub-block that long).
+    - a row of 1024 keeps blocks of 512: one block as long as the row
+      computes the whole square under the mask where four blocks skip a
+      quarter of it, and in the cell's trace the kernels were no faster
+      (+0.9 %), though alone they had measured 4-8 %.
+    - another head dimension keeps blocks of 512: at head 64 the larger
+      blocks measured 3 % of the forward and nothing of the backward, and
+      over 128 nothing is measured and the tiles' VMEM grows with it.
+
+    A row that 1024 does not cut evenly keeps 512 too; no block is longer
+    than the row."""
+    wide = head_dim == 128 and kv_len >= 2048 and kv_len % 1024 == 0
+    block = 1024 if wide else 512
+    return SplashBlocks(block, block, 512, block, block, 512).capped(kv_len)
+
+
+def _splash_kernel(seq_len: int, n_heads: int, head_dim: int, causal: bool,
+                   block_length: int = 0,
+                   blocks: Optional[SplashBlocks] = None):
+    """The splash kernel over ``seq_len`` positions, in ``blocks`` (capped at
+    the row) or, by default, in what :func:`splash_blocks` picks for the row;
+    and what the call costs a head, as the first-call record carries it:
+    ``attn_calls`` (1), ``attn_blocks`` (the forward's with work),
+    ``attn_blocks_cut`` (those of them that apply a mask: a stored tile
+    read, or the mask computed from the positions), ``attn_grid_steps_fwd``
+    and ``attn_grid_steps_bwd`` (of the forward and of the fused backward,
+    skipped steps included), the blocks themselves (``attn_block_q``,
+    ``attn_block_kv``, ``attn_block_q_bwd``, ``attn_block_kv_bwd``) and
+    ``attn_dq_partials`` (the row over the backward's kv block).
 
     NOT cached: the kernel object built during one jit trace captures that
     trace's context (its mask arrays are constants of that trace) and reusing
@@ -169,11 +238,11 @@ def _splash_kernel(seq_len: int, n_heads: int, block_q: int, block_kv: int,
         mask = (sm.CausalMask if causal else sm.FullMask)((seq_len, seq_len))
     mask = sm.MultiHeadMask([mask] * n_heads)
     interpret = jax.default_backend() != "tpu"
-    bq = min(block_q, seq_len)
-    bkv = min(block_kv, seq_len)
+    b = blocks.capped(seq_len) if blocks else splash_blocks(seq_len, head_dim)
     bs = sk.BlockSizes(
-        block_q=bq, block_kv=bkv, block_kv_compute=bkv,
-        block_q_dkv=bq, block_kv_dkv=bkv, block_kv_dkv_compute=bkv,
+        block_q=b.q, block_kv=b.kv, block_kv_compute=b.kv_compute,
+        block_q_dkv=b.q_bwd, block_kv_dkv=b.kv_bwd,
+        block_kv_dkv_compute=b.kv_bwd_compute,
         use_fused_bwd_kernel=True,
     )
     kernel = sk.make_splash_mha(mask, head_shards=1, q_seq_shards=1,
@@ -183,10 +252,10 @@ def _splash_kernel(seq_len: int, n_heads: int, block_q: int, block_kv: int,
     # cached numpy originals, asked for as ``make_splash_mha`` asks (a cache
     # hit; were its call to change, a second pass over the mask).
     fwd, computed = mi.process_mask(
-        mask, (bq, bkv), downcast_smem_data=True, head_shards=1,
+        mask, (b.q, b.kv), downcast_smem_data=True, head_shards=1,
         q_seq_shards=1)
     dkv, _ = mi.process_mask_dkv(
-        mask, (bq, bkv), downcast_smem_data=True, head_shards=1,
+        mask, (b.q_bwd, b.kv_bwd), downcast_smem_data=True, head_shards=1,
         q_seq_shards=1, shrink_grid=False)
     work = fwd.block_mask[0] > 0
     # With a mask function the kernel computes the mask on every block it
@@ -197,7 +266,10 @@ def _splash_kernel(seq_len: int, n_heads: int, block_q: int, block_kv: int,
         "attn_calls": 1, "attn_blocks": int(work.sum()),
         "attn_blocks_cut": int(cut.sum()),
         "attn_grid_steps_fwd": int(np.prod(fwd.block_mask.shape[1:])),
-        "attn_grid_steps_bwd": int(np.prod(dkv.block_mask.shape[1:]))}
+        "attn_grid_steps_bwd": int(np.prod(dkv.block_mask.shape[1:])),
+        "attn_block_q": b.q, "attn_block_kv": b.kv,
+        "attn_block_q_bwd": b.q_bwd, "attn_block_kv_bwd": b.kv_bwd,
+        "attn_dq_partials": seq_len // b.kv_bwd}
 
 
 def block_diffusion_allowed(i, j, seq_len: int, block_length: int):
@@ -279,13 +351,13 @@ def _block_diffusion_mask():
 
 def splash_attention(q, k, v, causal: bool = True,
                      sm_scale: Optional[float] = None,
-                     block_q: int = 512, block_kv: int = 512,
                      block_length: int = 0):
     """Production TPU attention (splash kernel): sparse over the causal
     mask when causal (no wasted upper-triangle work), full-mask
     bidirectional (ViT-style) otherwise, with a fused dq/dkv backward.  With
     a ``block_length`` the sequence is a block-diffusion row's 2S positions
     and the mask :func:`block_diffusion_allowed` (``causal`` is not read).
+    The kernel's blocks are :func:`splash_blocks`' for the row: no argument.
 
     q: (B, S, H, head_dim), k and v: (B, S, KV, head_dim), the model's native
     layout; H is a multiple of KV, and query head ``h`` attends to K/V head
@@ -305,8 +377,8 @@ def splash_attention(q, k, v, causal: bool = True,
         sm_scale = 1.0 / math.sqrt(hd)
 
     def local(q, k, v):
-        kernel, counts = _splash_kernel(S, q.shape[2], block_q, block_kv,
-                                        causal, block_length)
+        kernel, counts = _splash_kernel(S, q.shape[2], hd, causal,
+                                        block_length)
         # here, not at the top: ``parallel/train_state.py`` imports ``ops``
         from ray_tpu.parallel.train_state import note_first_call
 

@@ -215,7 +215,8 @@ def note_first_call(**facts) -> None:
     :class:`TrainStep` call is tracing on this thread (``models/llama.py``:
     the experts held, the block length, how many positions the layers and
     the loss see; ``ops/attention.py``: the splash calls that cover a layer's
-    mask, their blocks and grid steps).  Outside such a call, nothing."""
+    mask, their blocks, block sizes and grid steps).  Outside such a call,
+    nothing."""
     for notes in getattr(_thread, "notes", ()):
         notes.update(facts)
 
@@ -272,7 +273,9 @@ class TrainStep:
     ``block_length``, ``attn_positions``, ``loss_positions``;
     ``ops/attention.py``, where the splash kernel runs, how its calls cover a
     layer's mask, a head: ``attn_calls``, ``attn_blocks``,
-    ``attn_blocks_cut``, ``attn_grid_steps_fwd``, ``attn_grid_steps_bwd``).
+    ``attn_blocks_cut``, ``attn_grid_steps_fwd``, ``attn_grid_steps_bwd``,
+    and in what blocks: ``attn_block_q``, ``attn_block_kv``,
+    ``attn_block_q_bwd``, ``attn_block_kv_bwd``, ``attn_dq_partials``).
 
     A step that was traced in this call, keeps more than the plain policy
     would and is refused for memory (``RESOURCE_EXHAUSTED``, at compile or

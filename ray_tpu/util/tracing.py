@@ -108,7 +108,9 @@ SPAN_REGISTRY: Dict[str, str] = {
                         "block_length, attn_positions, loss_positions; "
                         "from ops/attention.py's splash path attn_calls, "
                         "attn_blocks, attn_blocks_cut, attn_grid_steps_fwd, "
-                        "attn_grid_steps_bwd)",
+                        "attn_grid_steps_bwd, attn_block_q, attn_block_kv, "
+                        "attn_block_q_bwd, attn_block_kv_bwd, "
+                        "attn_dq_partials)",
     "train.report": "session: one train.report() call, step boundary "
                     "included",
     "train.step_done": "profiler: its resolver thread's wait for one "

@@ -1,0 +1,202 @@
+"""The splash calls' block geometry follows the row (``ops/attention.py``:
+``splash_blocks``): what the rule picks at the shapes the benchmark's cells
+run, what that does to the grid and the stored tiles of the block-diffusion
+row, and that a call whose forward and backward blocks differ still agrees
+with the einsum.  CPU only; the times behind the rule are PERF.md's (PR 42).
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.ops import attention
+from ray_tpu.ops.attention import SplashBlocks
+
+#: cell -> the kernel's shape there (positions, query heads, kv heads,
+#: head_dim, rows, block length of a block-diffusion row) and the blocks the
+#: sweep on the chip chose (q, kv, kv_compute forward, then the same of the
+#: fused backward)
+WIDE, NARROW = (1024, 1024, 512, 1024, 1024, 512), (512,) * 6
+CELLS = {
+    "sdar-ep8-s8192": ((16384, 32, 4, 128, 1, 4), WIDE),
+    "mistral7b-s8192": ((8192, 32, 8, 128, 1, 0), WIDE),
+    "nemotron-ep16-s8192": ((8192, 32, 2, 128, 2, 0), WIDE),
+    "mistral7b-fsdp4-s4096": ((4096, 32, 8, 128, 1, 0), WIDE),
+    "olmoe-s4096": ((4096, 16, 16, 128, 2, 0), WIDE),
+    "mistral7b-s1024": ((1024, 32, 8, 128, 8, 0), NARROW),
+    "gpt2xl-s1024": ((1024, 25, 25, 64, 16, 0), NARROW),
+}
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_the_rule_picks_what_the_sweep_chose(cell):
+    (kv_len, _, _, head_dim, _, block_length), want = CELLS[cell]
+    blocks = attention.splash_blocks(kv_len, head_dim)
+    assert tuple(blocks) == want
+    # what the first-call record will say of the call (no kernel runs)
+    _, counts = attention._splash_kernel(kv_len, 1, head_dim, True,
+                                         block_length)
+    assert {k: v for k, v in counts.items() if "block_" in k} == {
+        "attn_block_q": blocks.q, "attn_block_kv": blocks.kv,
+        "attn_block_q_bwd": blocks.q_bwd, "attn_block_kv_bwd": blocks.kv_bwd}
+    assert counts["attn_dq_partials"] == kv_len // blocks.kv_bwd
+    grid = (kv_len // blocks.q_bwd) * (kv_len // blocks.kv_bwd)
+    assert counts["attn_grid_steps_bwd"] == grid  # walked whole
+
+
+@pytest.mark.parametrize("kv_len,head_dim,block", [
+    (256, 128, 256), (512, 128, 512), (1536, 128, 512), (2048, 128, 1024),
+    (2560, 128, 512), (3072, 128, 1024), (32768, 128, 1024),
+    (2048, 256, 512), (2048, 96, 512)])
+def test_the_rule_keeps_to_rows_it_can_cut(kv_len, head_dim, block):
+    """Rows and heads no cell runs: a block is never longer than the row and
+    always cuts it evenly; a row of under two blocks of 1024, and a head the
+    sweep did not measure, keep 512."""
+    b = attention.splash_blocks(kv_len, head_dim)
+    assert b == SplashBlocks(block, block, min(block, 512),
+                             block, block, min(block, 512))
+    assert all(kv_len % size == 0 for size in b)
+
+
+def test_the_cells_row_under_the_rules_blocks():
+    """The cell's row (2S = 16384, block length 4) in the blocks the rule
+    picks, 1024 x 1024: 80 blocks with work a head (320 in blocks of 512,
+    where 512-blocks had 288), 24 of them cut; the forward walks 144 grid
+    steps for 544 and the fused backward 256 for 1024, 176 of them without
+    work for 736, and writes 16 dq partials for 32.  Cut blocks read one of
+    three stored tiles, the three kinds of diagonal at the new shape; no
+    mask is computed in the kernel."""
+    S, Bk, H = 8192, 4, 2
+    kernel, counts = attention._splash_kernel(2 * S, H, 128, True, Bk)
+    assert counts == {"attn_calls": 1, "attn_blocks": 80,
+                      "attn_blocks_cut": 24, "attn_grid_steps_fwd": 144,
+                      "attn_grid_steps_bwd": 256, "attn_block_q": 1024,
+                      "attn_block_kv": 1024, "attn_block_q_bwd": 1024,
+                      "attn_block_kv_bwd": 1024, "attn_dq_partials": 16}
+    assert kernel.kwargs["mask_function"] is None
+    for info in (kernel.fwd_mask_info, kernel.dkv_mask_info):
+        assert info.q_sequence is None  # no mask computed in the kernel
+        block_mask = np.asarray(info.block_mask)
+        assert (block_mask == 1).sum() == 24 and (block_mask == 2).sum() == 56
+        # every cut block points at a tile the kernel holds
+        assert info.partial_mask_blocks.shape == (3, 1024, 1024)
+        assert np.asarray(info.mask_next)[block_mask == 1].max() < 3
+    tiles = np.asarray(kernel.fwd_mask_info.partial_mask_blocks)
+    # the backward's tiles are the forward's, kv-major
+    assert np.array_equal(
+        np.asarray(kernel.dkv_mask_info.partial_mask_blocks),
+        tiles.swapaxes(-1, -2))
+    at = np.arange(1024)
+    mine, theirs = at[:, None] // Bk, at[None, :] // Bk
+    assert {t.tobytes() for t in tiles} == {
+        (mine == theirs).tobytes(), (theirs < mine).tobytes(),
+        (theirs <= mine).tobytes()}
+
+
+def test_a_longer_kv_block_in_the_backward_alone():
+    """The geometry ISSUE 42 expected and the sweep did not choose, through
+    explicit blocks: only the backward's kv block at 1024.  512 grid steps
+    and 16 partials; its 48 cut blocks read one of 6 tiles, the three kinds
+    of diagonal through either half of the kv block."""
+    Bk = 4
+    kernel, counts = attention._splash_kernel(
+        16384, 2, 128, True, Bk, SplashBlocks(512, 512, 512, 512, 1024, 512))
+    assert (counts["attn_grid_steps_fwd"], counts["attn_grid_steps_bwd"],
+            counts["attn_dq_partials"]) == (544, 512, 16)
+    dkv = kernel.dkv_mask_info
+    block_mask = np.asarray(dkv.block_mask)
+    assert (block_mask == 1).sum() == 48 and (block_mask == 2).sum() == 112
+    # kv-major: 1024 keys by 512 queries
+    q_at = np.arange(512)[None, :] // Bk
+    want = set()
+    for half in (0, 512):
+        k_at = (np.arange(1024)[:, None] - half) // Bk
+        want |= {(k_at == q_at).tobytes(), (k_at < q_at).tobytes(),
+                 (k_at <= q_at).tobytes()}
+    assert {t.tobytes() for t in
+            np.asarray(dkv.partial_mask_blocks).astype(bool)} == want
+
+
+@pytest.mark.parametrize("kind,blocks", [
+    ("causal", SplashBlocks(128, 128, 128, 128, 256, 128)),
+    ("block_diffusion", SplashBlocks(128, 128, 128, 128, 256, 128)),
+    ("block_diffusion", SplashBlocks(128, 256, 128, 256, 512, 256)),
+], ids=["causal", "block-diffusion", "block-diffusion-wider"])
+def test_unequal_blocks_agree_with_the_einsum(monkeypatch, kind, blocks):
+    """Interpret mode, a row of 512 positions: the fused backward in other
+    blocks than the forward and compute sub-blocks shorter than their blocks
+    (as the rule has them), GQA; output and gradients against the einsum
+    path."""
+    B, P, H, KV, hd, Bk = 1, 512, 4, 2, 32, 4
+    ks = jax.random.split(jax.random.key(42), 4)
+    q = jax.random.normal(ks[0], (B, P, H, hd))
+    k, v = (jax.random.normal(key, (B, P, KV, hd)) for key in ks[1:3])
+    do = jax.random.normal(ks[3], q.shape)
+    seen, real = [], attention._splash_kernel
+
+    def forced(*args):
+        kernel, counts = real(*args, blocks=blocks)
+        seen.append(counts)
+        return kernel, counts
+
+    monkeypatch.setattr(attention, "_splash_kernel", forced)
+
+    def run(impl):
+        def out(q, k, v):
+            if kind == "causal":
+                return attention.causal_attention(q, k, v, impl)
+            return attention.block_diffusion_attention(q, k, v, Bk, impl)
+
+        return jax.jit(jax.value_and_grad(
+            lambda q, k, v: jnp.sum(out(q, k, v) * do),
+            argnums=(0, 1, 2)))(q, k, v)
+
+    (a, ga), (b, gb) = run("xla"), run("splash")
+    assert seen and seen[0]["attn_block_kv_bwd"] == blocks.kv_bwd \
+        and seen[0]["attn_dq_partials"] == P // blocks.kv_bwd
+    assert float(a) == pytest.approx(float(b), rel=1e-5)
+    for x, y in zip(ga, gb):
+        err = float(jnp.linalg.norm(y - x) / jnp.linalg.norm(x))
+        assert err < 1e-5
+
+
+# ------------------------------------- the rule's blocks fit the chip's VMEM
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_the_rules_blocks_compile_for_the_v5e(monkeypatch, one_chip):
+    """Forward and fused backward at every cell's real shape, compiled for a
+    described v5e (nothing runs): the compiler refuses a kernel whose blocks
+    need more than the scoped VMEM, which interpret mode never sees (in the
+    sweep a q block of 2048 was refused with most kv blocks).  One test, so
+    that one worker of a parallel run loads the TPU's compiler."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        for cell, ((S, H, KV, hd, B, Bk), _) in CELLS.items():
+            def call(q, k, v, do):
+                kernel, _ = attention._splash_kernel(S, H, hd, True, Bk)
+                _, pull = jax.vjp(jax.vmap(kernel), q, k, v)
+                return pull(do)
+
+            q = jax.ShapeDtypeStruct((B, H, S, hd), jnp.bfloat16,
+                                     sharding=one_chip)
+            kv = jax.ShapeDtypeStruct((B, KV, S, hd), jnp.bfloat16,
+                                      sharding=one_chip)
+            text = jax.jit(call).lower(q, kv, kv, q).compile().as_text()
+            # one forward, one backward
+            assert text.count("tpu_custom_call") == 2, cell
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)

@@ -5,6 +5,8 @@ native sequence parallelism.  Correctness target is the dense attention math
 itself, forward AND backward, on the virtual 8-device mesh.)
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -196,14 +198,18 @@ def test_auto_downgrades_loudly_when_splash_surface_breaks(
 
 
 @pytest.mark.parametrize("causal", [True, False])
-def test_splash_attention_matches_dense(causal):
-    """Single-device splash kernel (interpret on CPU): causal AND the
-    bidirectional FullMask path (previously NotImplementedError)."""
-    from ray_tpu.ops.attention import splash_attention
+def test_splash_attention_matches_dense(causal, monkeypatch):
+    """Single-device splash kernel (interpret on CPU), two blocks a side:
+    causal AND the bidirectional FullMask path (previously
+    NotImplementedError)."""
+    from ray_tpu.ops import attention
+    monkeypatch.setattr(attention, "_splash_kernel", functools.partial(
+        attention._splash_kernel,
+        blocks=attention.SplashBlocks.square(128)))
     q, k, v = _qkv(jax.random.key(9), B=1, S=256, H=2, D=64)
     expected = _xla_attention(q, k, v, causal=causal)
-    out = jax.jit(lambda q, k, v: splash_attention(
-        q, k, v, causal=causal, block_q=128, block_kv=128))(q, k, v)
+    out = jax.jit(lambda q, k, v: attention.splash_attention(
+        q, k, v, causal=causal))(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
                                rtol=2e-5, atol=2e-5)
 

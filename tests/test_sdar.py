@@ -144,10 +144,13 @@ def test_whole_tiles_pay_nothing_for_the_mask():
     other 240 are told apart as whole; the forward walks 544 grid steps and
     the fused backward, whose grid the library does not shrink, 1024."""
     S, Bk, H = 8192, 4, 2
-    kernel, counts = attention._splash_kernel(2 * S, H, 512, 512, True, Bk)
+    kernel, counts = attention._splash_kernel(
+        2 * S, H, 128, True, Bk, attention.SplashBlocks.square(512))
     assert counts == {"attn_calls": 1, "attn_blocks": 288,
                       "attn_blocks_cut": 48, "attn_grid_steps_fwd": 544,
-                      "attn_grid_steps_bwd": 1024}
+                      "attn_grid_steps_bwd": 1024, "attn_block_q": 512,
+                      "attn_block_kv": 512, "attn_block_q_bwd": 512,
+                      "attn_block_kv_bwd": 512, "attn_dq_partials": 32}
     assert kernel.kwargs["mask_function"] is None
     for info in (kernel.fwd_mask_info, kernel.dkv_mask_info):
         assert info.q_sequence is None  # no mask computed in the kernel
@@ -179,10 +182,13 @@ def test_a_causal_row_is_the_call_it_was():
     )
 
     S, H = 2048, 2
-    kernel, counts = attention._splash_kernel(S, H, 512, 512, True)
+    kernel, counts = attention._splash_kernel(
+        S, H, 128, True, blocks=attention.SplashBlocks.square(512))
     assert counts == {"attn_calls": 1, "attn_blocks": 10,
                       "attn_blocks_cut": 10, "attn_grid_steps_fwd": 16,
-                      "attn_grid_steps_bwd": 16}
+                      "attn_grid_steps_bwd": 16, "attn_block_q": 512,
+                      "attn_block_kv": 512, "attn_block_q_bwd": 512,
+                      "attn_block_kv_bwd": 512, "attn_dq_partials": 4}
     assert kernel.kwargs["mask_function"] is not None
     want = sk.make_splash_mha(
         sm.MultiHeadMask([sm.CausalMask((S, S))] * H), head_shards=1,
@@ -208,8 +214,9 @@ def test_splash_kernel_agrees_with_the_einsum(monkeypatch, S, Bk):
     q = jax.random.normal(ks[0], (B, 2 * S, H, hd))
     k, v = (jax.random.normal(key, (B, 2 * S, KV, hd)) for key in ks[1:3])
     do = jax.random.normal(ks[3], q.shape)
-    monkeypatch.setattr(attention, "splash_attention", functools.partial(
-        attention.splash_attention, block_q=128, block_kv=128))
+    monkeypatch.setattr(attention, "_splash_kernel", functools.partial(
+        attention._splash_kernel,
+        blocks=attention.SplashBlocks.square(128)))
 
     def run(impl):
         return jax.jit(jax.value_and_grad(
@@ -664,7 +671,10 @@ def test_head_dim_and_per_head_qk_norm_by_hand(S, Bk):
     ("xla", {}),
     # a row shorter than the kernel's 512-blocks: one block of 256 x 256, cut
     ("splash", {"attn_calls": 1, "attn_blocks": 1, "attn_blocks_cut": 1,
-                "attn_grid_steps_fwd": 1, "attn_grid_steps_bwd": 1}),
+                "attn_grid_steps_fwd": 1, "attn_grid_steps_bwd": 1,
+                "attn_block_q": 256, "attn_block_kv": 256,
+                "attn_block_q_bwd": 256, "attn_block_kv_bwd": 256,
+                "attn_dq_partials": 1}),
 ])
 def test_first_call_says_what_the_model_is(impl, covering):
     """The ``train.first_call`` record carries the share and the block
