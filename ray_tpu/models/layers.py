@@ -104,7 +104,9 @@ def attention(x, blk, config, axes):
     ``head_dim``, ``qk_norm``, ``rms_eps``, ``rope_theta`` (``None``: no
     rotary embedding, the positions reach the model some other way),
     ``block_length`` and ``attn_impl``; of ``blk`` ``attn_norm``, ``wq``,
-    ``wk``, ``wv``, ``wo`` and with QK-norm ``q_norm``, ``k_norm``."""
+    ``wk``, ``wv``, ``wo`` and with QK-norm ``q_norm``, ``k_norm``.  Where
+    ``blk`` holds ``wg`` (D, H x hd), an output gate: ``wo`` reads ``attn *
+    sigmoid(norm(x) wg)``, a channel each, made in float32."""
     dt = config.dtype
     B, S, D = x.shape
     H, KV, hd = config.n_head, config.n_kv_head, config.head_dim
@@ -137,6 +139,10 @@ def attention(x, blk, config, axes):
         else:
             attn = causal_attention(q, k, v, config.attn_impl)
         attn = attn.astype(dt).reshape(B, S, H * hd)
+        if "wg" in blk:
+            gate = jax.nn.sigmoid(
+                dense(h, blk, "wg", axes, dt).astype(jnp.float32))
+            attn = (attn.astype(jnp.float32) * gate).astype(dt)
         return x + dense(attn, blk, "wo", axes, dt)
 
 

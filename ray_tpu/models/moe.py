@@ -405,10 +405,11 @@ def moe_mlp(h32, blk, *, experts_per_token: int, norm_topk_prob: bool, dtype,
     which ``w_up``'s leading size against the router's outputs says.
     ``scoring``, ``bias``, ``scale``: :func:`route`'s.  Where ``blk`` holds
     ``shared_up`` (D, F_s) and ``shared_down`` (F_s, D), a shared expert,
-    ``shared_down(activation(shared_up(x)))`` of every token, is added to
-    the routed sum after the combine; every chip of an expert-parallel job
-    computes it alike.  -> (y (B, S, D) in ``dtype``,
-    (load-balance, z), counts): the losses over all E experts; ``counts``
+    ``shared_down(activation(shared_up(x)))`` of every token (with
+    ``shared_gate`` (D, F_s) beside them, ``shared_down(activation(
+    shared_gate(x)) * shared_up(x))``), is added to the routed sum after the
+    combine; every chip of an expert-parallel job computes it alike.  -> (y
+    (B, S, D) in ``dtype``, (load-balance, z), counts): the losses over all E experts; ``counts``
     what the data decided, by its name as a step counter
     (``tracing.STEP_COUNTER_REGISTRY``), each with one row for each shard of
     the batch (a chip's time follows that chip's rows), one in all without
@@ -461,7 +462,14 @@ def moe_mlp(h32, blk, *, experts_per_token: int, norm_topk_prob: bool, dtype,
         up = checkpoint_name(
             jnp.einsum("bsd,df->bsf", h, blk["shared_up"].astype(dtype)),
             remat.GATE_UP)
-        act = activation(up.astype(jnp.float32)).astype(dtype)
+        if "shared_gate" in blk:
+            gate = checkpoint_name(
+                jnp.einsum("bsd,df->bsf", h,
+                           blk["shared_gate"].astype(dtype)), remat.GATE_UP)
+            act = (activation(gate.astype(jnp.float32))
+                   * up.astype(jnp.float32)).astype(dtype)
+        else:
+            act = activation(up.astype(jnp.float32)).astype(dtype)
         shared = jnp.einsum("bsf,fd->bsd", act,
                             blk["shared_down"].astype(dtype))
         return _sum(y, shared), losses, counts

@@ -155,8 +155,8 @@ SPAN_REGISTRY: Dict[str, str] = {
 #: dead.
 SCOPE_REGISTRY: Dict[str, str] = {
     "embed": "token (and position) embedding lookup",
-    "attn": "attention part of a block: norm, qkv, RoPE, kernel, "
-            "out-projection",
+    "attn": "attention part of a block: norm, qkv, RoPE, kernel, the "
+            "output gate where the layer has one, out-projection",
     "attn_kernel": "the attention kernel call itself, nested inside attn "
                    "(splash/ring/ulysses/XLA, with its layout changes)",
     "mlp": "MLP part of a block: norm to down-projection (with experts: "
@@ -173,8 +173,9 @@ SCOPE_REGISTRY: Dict[str, str] = {
                 "inside mlp in place of experts: the grouped matmuls over "
                 "the held groups and the activation between them",
     "shared_expert": "expert layer with a shared expert (models/moe.py), "
-                     "nested inside mlp: the dense two-matrix MLP every "
-                     "token passes, and its sum with the routed part",
+                     "nested inside mlp: the dense MLP every token passes "
+                     "(two matrices, three with a gate), and its sum with "
+                     "the routed part",
     "ssm": "a Mamba-2 layer (models/mamba2.py): norm, in-projection, the "
            "gate, the grouped norm, out-projection (around the two scopes "
            "below)",
@@ -182,6 +183,14 @@ SCOPE_REGISTRY: Dict[str, str] = {
                 "convolution over positions, its bias and the silu",
     "ssm_scan": "Mamba-2 layer, nested inside ssm: the chunked state-space "
                 "scan (ops/ssd.py), whatever implements it",
+    "kda": "a KDA layer (models/kda.py), the gated delta rule with a "
+           "per-channel decay: norm, projections, L2 norms, the decay's and "
+           "the output's low-rank gates, beta, the head norm, "
+           "out-projection (around the two scopes below)",
+    "kda_conv": "KDA layer, nested inside kda: the three causal depthwise "
+                "convolutions over positions and the silu",
+    "kda_scan": "KDA layer, nested inside kda: the chunked delta-rule scan "
+                "(ops/kda.py), whatever implements it",
     "noise": "block-diffusion training (models/block_diffusion.py): the "
              "draw of the masked positions, the noised copy, the "
              "concatenation with the clean one, the loss weights",

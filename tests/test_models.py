@@ -175,7 +175,8 @@ def test_remat_policy_is_one_of_two(tiny):
 def test_imports_point_down(package, forbidden):
     """No model file imports another decoder (the arrows inside the package
     point at what is no decoder: llama and hybrid -> layers -> moe, hybrid ->
-    mamba2 -> layers), and nothing under ops/ reaches up into models/."""
+    mamba2 -> layers, hybrid -> kda -> mamba2 and layers), and nothing under
+    ops/ reaches up into models/."""
     import ast
     import os
 

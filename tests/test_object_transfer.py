@@ -84,7 +84,7 @@ def test_remote_ref_as_task_dependency(owner_node, borrower):
     assert ray_tpu.get(total.remote(ref), timeout=30) == 7000.0
 
 
-def test_concurrent_pulls_are_deduplicated(owner_node):
+def test_concurrent_pulls_are_deduplicated(owner_node, borrower):
     from ray_tpu._private.runtime import get_runtime
 
     rt = get_runtime()

@@ -3,6 +3,7 @@ trace time from the device's free bytes.  The CPU reports no memory, so every
 test that wants a richer program hands the rule a device of its own."""
 
 import contextlib
+import dataclasses
 import os
 import re
 import socket
@@ -137,6 +138,49 @@ def test_the_rule_is_given_each_layer_kinds_sizes(nudge_mb, monkeypatch):
             remat.layer_policy(candidates, temporaries)
         assert decided[0].kept == ()
         assert decided[0].room_bytes < -0.5 * GiB
+    on_device(monkeypatch, ROOMY)
+    with remat.recording() as decided:
+        remat.layer_policy(candidates, temporaries)
+    assert decided[0].kept == BOTH
+
+
+def test_the_rule_is_given_the_kda_kinds_sizes(monkeypatch):
+    """``models/hybrid.py`` at the sizes of ``solar-open2-ep40-tp8`` (a gated
+    attention layer and three KDA layers, each followed by SwiGLU experts
+    beside a SwiGLU shared expert, 1 x 8192 tokens, 7.83 GiB of state
+    resident; compile-only for the v5e, PERF.md PR 43): the candidates are
+    the attention layer's q, k and v (8 query heads and 1 KV head held) and
+    the four shared experts' gate and up products, the KDA layers name
+    nothing, the bound lies over the compiler's 3.62 GiB of temporaries, and
+    beside that state the chip has room for no rung; a chip with room keeps
+    both."""
+    from ray_tpu.models import hybrid
+
+    config = hybrid.HybridConfig(
+        vocab_size=24576, d_model=4096, seq_len=8192, pattern="*EKEKEKE",
+        n_head=8, n_kv_head=1, head_dim=128, attn_gate=True, n_head_total=64,
+        kda_heads=8, kda_head_dim=128, kda_chunk=64, n_experts=320,
+        experts_per_token=8, d_ff=1280, shared_width=1280,
+        expert_activation="silu", gated_experts=True, routed_scaling=1.0,
+        experts_held=range(8))
+    shapes = jax.eval_shape(lambda: hybrid.init_params(config,
+                                                       jax.random.key(0)))
+    candidates, temporaries = hybrid._layer_sizes(shapes, (1, 8192, 4096),
+                                                  config)
+    assert candidates == [(remat.QKV, 8192 * (8 + 2) * 128 * 2),
+                          (remat.GATE_UP, 4 * 8192 * 2 * 1280 * 2)]
+    assert 3.62 * GiB < temporaries < 7.5 * GiB
+    # the widest layer's working set is the KDA layer's: without it the
+    # bound is lower
+    plain = dataclasses.replace(config, pattern="*E*E*E*E")
+    assert hybrid._layer_sizes(
+        jax.eval_shape(lambda: hybrid.init_params(plain, jax.random.key(0))),
+        (1, 8192, 4096), plain)[1] < temporaries
+    for fuller in (0.0, 0.3):
+        on_device(monkeypatch, (V5E, int((7.83 + fuller) * GiB)))
+        with remat.recording() as decided:
+            remat.layer_policy(candidates, temporaries)
+        assert decided[0].kept == ()
     on_device(monkeypatch, ROOMY)
     with remat.recording() as decided:
         remat.layer_policy(candidates, temporaries)

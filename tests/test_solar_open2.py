@@ -1,0 +1,411 @@
+"""Upstage Solar-Open2 through ``models/hybrid.py``: KDA layers
+(``models/kda.py`` over ``ops/kda.py``, the gated delta rule with a
+per-channel decay), a gated attention layer without rotary embedding
+(``models/layers.py``), and on every layer SwiGLU experts beside a SwiGLU
+shared expert (``models/moe.py``), for one chip's share of the heads and of
+the experts.
+
+The plain reference is ``benchmarks/reference/solar_open2.py``, the one copy
+(float32, the recurrence position by position, every held expert applied to
+every position).  Everything runs on the CPU with seeded random weights at
+tiny sizes, attention on the einsum path.
+"""
+
+import dataclasses
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.lib import correct, spec
+from benchmarks.reference import solar_open2 as reference
+from ray_tpu.models import hybrid, kda as kda_model, moe
+from ray_tpu.models.layers import attention
+from ray_tpu.ops.kda import kda
+from ray_tpu.parallel import MeshSpec, make_mesh
+
+#: benchmarks/lib/correct.py's, which the bf16 program is held to on the chip
+LOSS_TOL, GRAD_TOL = 1e-3, 0.75
+
+
+def _rel_err(a, b):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
+
+
+def _tiny_family(dtype="bfloat16", **changes):
+    config = dict(spec.load_json(spec.BENCH_DIR, "configs",
+                                 "tiny-solar-open2.json"), **changes)
+    config["options"] = {"attn_impl": "xla", "dtype": jnp.dtype(dtype),
+                         "logits_dtype": jnp.dtype(dtype)}
+    return config, spec.load_module("models", "solar_open2").build(config,
+                                                                   128)
+
+
+# ------------------------------------------------------- (1) the chunked scan
+def _scan_inputs(chunks, chunk, decay, b=2, H=3, d=16):
+    """Keys that resemble each other (a common part), ``beta`` above 1 on
+    most positions, ``g`` summing to ``decay`` times a few a chunk."""
+    S = chunks * chunk
+    k = jax.random.split(jax.random.key(chunks * chunk), 5)
+    return (reference.l2norm(jax.random.normal(k[0], (b, S, H, d))) * d ** -.5,
+            reference.l2norm(jax.random.normal(k[1], (b, S, H, d)) + 0.5),
+            jax.random.normal(k[2], (b, S, H, d)),
+            -jax.nn.softplus(jax.random.normal(k[3], (b, S, H, d))) * decay,
+            2.0 * jax.nn.sigmoid(jax.random.normal(k[4], (b, S, H)) + 1.0))
+
+
+@pytest.mark.parametrize("chunks,chunk,decay", [
+    (2, 64, 0.3), (3, 32, 0.3), (2, 64, 8.0), (3, 32, 8.0), (1, 16, 8.0),
+    (2, 8, 8.0)])
+def test_chunked_scan_is_the_recurrence(chunks, chunk, decay):
+    """Forward and every gradient (q, k, v, g, beta) against a
+    position-by-position ``lax.scan`` in float32, two rows a batch, with
+    ``beta`` above 1 and, at ``decay`` 8, a ``g`` that sums below -88 inside
+    a chunk, where a product of ratios would overflow: no inf, no nan."""
+    args = _scan_inputs(chunks, chunk, decay)
+    assert float(jnp.mean(args[4] > 1.0)) > 0.5
+    if decay > 1 and chunk >= 32:
+        sums = jnp.sum(args[3].reshape(2, chunks, chunk, 3, 16), axis=2)
+        assert float(jnp.max(sums)) < -88  # exp(88) is no float32
+    dy = jax.random.normal(jax.random.key(9), args[0].shape)
+
+    def out_and_grads(fn):
+        def run(*args):
+            out, vjp = jax.vjp(fn, *args)
+            return out, vjp(dy)
+        return jax.jit(run)(*args)
+
+    with jax.default_matmul_precision("highest"):
+        got, grads = out_and_grads(lambda *a: kda(*a, chunk))
+        want, grads_ref = out_and_grads(reference.recurrence)
+    assert np.all(np.isfinite(got))
+    assert _rel_err(got, want) < 1e-5
+    for name, g, g_ref in zip("qkvgb", grads, grads_ref):
+        assert np.all(np.isfinite(g)), name
+        assert _rel_err(g, g_ref) < 1e-4, name
+
+
+def test_scan_products_are_in_the_inputs_dtype():
+    """bf16 in: bf16 products with float32 accumulation, within bf16's
+    rounding of the float32 recurrence."""
+    q, k, v, g, beta = _scan_inputs(3, 32, 0.3)
+    low = tuple(a.astype(jnp.bfloat16) for a in (q, k, v))
+    got = jax.jit(lambda *a: kda(*a, 32))(*low, g, beta)
+    assert got.dtype == jnp.bfloat16
+    assert _rel_err(got, reference.recurrence(q, k, v, g, beta)) < 0.05
+
+
+def test_the_state_is_zero_before_a_rows_first_position():
+    """Row 1's output does not depend on row 0, and position 0's output is
+    ``beta (k . q) v`` of that position alone."""
+    q, k, v, g, beta = _scan_inputs(2, 16, 0.3)
+    scan = jax.jit(lambda *a: kda(*a, 16))
+    with jax.default_matmul_precision("highest"):
+        both = scan(q, k, v, g, beta)
+        alone = scan(*(a[1:] for a in (q, k, v, g, beta)))
+    assert _rel_err(both[1:], alone) < 1e-6
+    first = beta[:, 0, :, None] * jnp.sum(k[:, 0] * q[:, 0], -1,
+                                          keepdims=True) * v[:, 0]
+    assert _rel_err(both[:, 0], first) < 1e-5
+
+
+# ------------------------------------------------------------- (2) the mixer
+def _kda_parts(heads, dtype=jnp.float32, d_model=64):
+    config = dataclasses.replace(hybrid.HybridConfig.tiny_solar(),
+                                 kda_heads=heads, d_model=d_model,
+                                 dtype=dtype)
+    blk = jax.tree.map(lambda a: a[0], kda_model.init_params(
+        config, jax.random.key(0), 1, 0.05))
+    # every vector away from its start and the projections large enough
+    # that the decay and beta differ by position
+    noise = iter(jax.random.split(jax.random.key(1), len(blk)))
+    blk = {name: a + 0.1 * jax.random.normal(next(noise), a.shape)
+           if a.ndim == 1 else a * 5.0 for name, a in blk.items()}
+    x = jax.random.normal(jax.random.key(2), (2, 64, d_model), dtype)
+    cfg = {"linear_attn_config": {"num_heads": heads,
+                                  "head_dim": config.kda_head_dim},
+           "rms_norm_eps": config.rms_eps}
+    return config, blk, x, cfg
+
+
+def _normed(x, weight, eps):
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) \
+        * weight
+
+
+def test_mixer_matches_the_reference():
+    config, blk, x, cfg = _kda_parts(4)
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(lambda x, blk: kda_model.mixer(
+            x, blk, config, kda_model.logical_axes()))(x, blk) - x
+        want = jax.jit(lambda x, blk: reference.kda(
+            _normed(x, blk["kda_norm"], config.rms_eps), blk, cfg))(x, blk)
+    assert _rel_err(got, want) < 1e-5
+
+
+def test_beta_may_pass_one_and_the_decay_is_a_channels_own():
+    """``beta`` = 2 sigmoid: above 1 on some positions (an eigenvalue of
+    ``I - beta k k^T`` is then negative); and a channel's decay reaches that
+    channel of the state alone: changing ``dt_bias`` at channel c of head 0
+    changes nothing that head 1 puts out."""
+    config, blk, x, cfg = _kda_parts(2)
+    u = _normed(x, blk["kda_norm"], config.rms_eps)
+    beta = 2.0 * jax.nn.sigmoid(u @ blk["w_beta"])
+    assert 0.1 < float(jnp.mean(beta > 1.0)) < 0.9
+    d = config.kda_head_dim
+    moved = dict(blk, dt_bias=blk["dt_bias"].at[3].add(2.0))
+    only_head_1 = dict(wo=blk["wo"].at[:d].set(0.0))
+    layer = jax.jit(lambda w: reference.kda(u, w, cfg))
+    with jax.default_matmul_precision("highest"):
+        a, b = (layer(dict(w, **only_head_1)) for w in (blk, moved))
+        c, e = (layer(w) for w in (blk, moved))
+    assert np.array_equal(np.asarray(a), np.asarray(b))
+    assert not np.allclose(np.asarray(c), np.asarray(e))
+
+
+def test_the_convolutions_are_causal():
+    config, blk, x, _ = _kda_parts(2)
+    axes = kda_model.logical_axes()
+    t = 37
+    moved = x.at[:, t].add(1.0)
+    a, b = map(jax.jit(lambda v: kda_model.mixer(v, blk, config, axes)),
+               (x, moved))
+    assert np.array_equal(np.asarray(a[:, :t]), np.asarray(b[:, :t]))
+    assert not np.allclose(np.asarray(a[:, t]), np.asarray(b[:, t]))
+
+
+# ----------------------------------------------------- (3) the shares add up
+def _head_columns(name, a, first, stop, d):
+    """The part of one KDA or attention leaf that heads [first, stop) own:
+    columns of the head-wide projections, rows of ``wo``, all of what every
+    head reads."""
+    if name in ("wq", "wk", "wv", "w_fb", "w_gb", "b_g", "dt_bias", "wg",
+                "conv_q", "conv_k", "conv_v"):
+        return a[..., first * d:stop * d]
+    if name == "wo":
+        return a[first * d:stop * d]
+    if name in ("w_beta", "A_log"):
+        return a[..., first:stop]
+    return a  # the pre-norm, the low-rank halves w_fa and w_ga, head_norm
+
+
+@pytest.mark.parametrize("shares", [2, 8])
+def test_the_kda_head_shares_add_up_to_the_uncut_mixer(shares):
+    """8 heads cut in ``shares``: the mixers' outputs over the shares, each
+    on its own heads' columns and ``wo``'s rows for them, sum to the uncut
+    reference's mixer."""
+    config, blk, x, cfg = _kda_parts(8)
+    d, held = config.kda_head_dim, 8 // shares
+    part = dataclasses.replace(config, kda_heads=held)
+    axes = kda_model.logical_axes()
+    mixer = jax.jit(lambda mine: kda_model.mixer(x, mine, part, axes) - x)
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(lambda blk: reference.kda(
+            _normed(x, blk["kda_norm"], config.rms_eps), blk, cfg))(blk)
+        total = jnp.zeros_like(x)
+        for share in range(shares):
+            total = total + mixer({
+                name: _head_columns(name, a, share * held,
+                                    (share + 1) * held, d)
+                for name, a in blk.items()})
+    assert _rel_err(total, want) < 1e-5
+
+
+@pytest.mark.parametrize("shares", [2, 4])
+def test_the_attention_head_shares_add_up_to_the_uncut_layer(shares):
+    """8 query heads over 4 KV heads, gated, no rotary, cut in ``shares``
+    (a share: whole KV heads with the query heads that read them)."""
+    H, KV, hd, D = 8, 4, 16, 64
+    config = dataclasses.replace(
+        hybrid.HybridConfig.tiny_solar(), n_head=H, n_kv_head=KV,
+        head_dim=hd, d_model=D, dtype=jnp.float32, attn_impl="xla")
+    blk = jax.tree.map(lambda a: a[0] * 5.0, hybrid.init_params(
+        config, jax.random.key(0))["attn"])
+    assert set(blk) == {"attn_norm", "wq", "wk", "wv", "wo", "wg"}
+    axes = hybrid.logical_axes(config)["attn"]
+    x = jax.random.normal(jax.random.key(1), (2, 64, D))
+    cfg = {"num_attention_heads": H, "num_key_value_heads": KV,
+           "head_dim": hd, "use_gqa_gate": True}
+    with jax.default_matmul_precision("highest"):
+        want = reference.attention(
+            _normed(x, blk["attn_norm"], config.rms_eps), blk, cfg, 64)
+        total = jnp.zeros_like(x)
+        for share in range(shares):
+            q0, q1 = share * H // shares, (share + 1) * H // shares
+            k0, k1 = share * KV // shares, (share + 1) * KV // shares
+            mine = {"attn_norm": blk["attn_norm"],
+                    "wq": blk["wq"][:, q0 * hd:q1 * hd],
+                    "wg": blk["wg"][:, q0 * hd:q1 * hd],
+                    "wk": blk["wk"][:, k0 * hd:k1 * hd],
+                    "wv": blk["wv"][:, k0 * hd:k1 * hd],
+                    "wo": blk["wo"][q0 * hd:q1 * hd]}
+            part = dataclasses.replace(config, n_head=q1 - q0,
+                                       n_kv_head=k1 - k0)
+            total = total + jax.jit(lambda mine: attention(
+                x, mine, part, axes))(mine) - x
+    assert _rel_err(total, want) < 1e-5
+    # the gate is no no-op: without it the layer is another
+    plain = dict(cfg, use_gqa_gate=False)
+    assert _rel_err(reference.attention(
+        _normed(x, blk["attn_norm"], config.rms_eps), blk, plain, 64),
+        want) > 0.1
+
+
+@pytest.mark.parametrize("shares", [2, 4, 16])
+def test_the_expert_shares_add_up_to_the_uncut_layer(shares):
+    """16 SwiGLU experts cut in ``shares``: the routed parts that all the
+    shares give, plus the SwiGLU shared expert counted once, are the uncut
+    reference's layer."""
+    D, E, F, k = 32, 16, 24, 3
+    ks = jax.random.split(jax.random.key(shares), 8)
+    whole = {"router": jax.random.normal(ks[0], (D, E)) * 0.5,
+             "w_gate": jax.random.normal(ks[1], (E, D, F)) * 0.2,
+             "w_up": jax.random.normal(ks[2], (E, D, F)) * 0.2,
+             "w_down": jax.random.normal(ks[3], (E, F, D)) * 0.2,
+             "shared_gate": jax.random.normal(ks[4], (D, F)) * 0.2,
+             "shared_up": jax.random.normal(ks[5], (D, F)) * 0.2,
+             "shared_down": jax.random.normal(ks[6], (F, D)) * 0.2}
+    h = jax.random.normal(ks[7], (2, 64, D))
+    cfg = {"experts_held": [0, E], "num_experts_per_tok": k,
+           "norm_topk_prob": True, "routed_scaling_factor": 1,
+           "n_routed_experts_published": E}
+    layer = jax.jit(lambda blk, first: moe.moe_mlp(
+        h, blk, experts_per_token=k, norm_topk_prob=True, dtype=jnp.float32,
+        first_held=first, scoring="sigmoid")[0], static_argnums=1)
+    with jax.default_matmul_precision("highest"):
+        want = reference.experts(h.reshape(-1, D), whole, cfg)
+        held = E // shares
+        total = jnp.zeros_like(h)
+        for share in range(shares):
+            first = share * held
+            blk = {"router": whole["router"]}
+            blk.update({name: whole[name][first:first + held]
+                        for name in ("w_gate", "w_up", "w_down")})
+            if share == 0:  # what every chip computes alike, counted once
+                blk.update({name: whole[name] for name in (
+                    "shared_gate", "shared_up", "shared_down")})
+            total = total + layer(blk, first)
+    assert _rel_err(total.reshape(-1, D), want) < 1e-5
+
+
+# ------------------------------------------------------ (4) the whole model
+@pytest.mark.parametrize("dtype,loss_tol,grad_tol", [
+    # the same mathematics in another order: float32 summation order only
+    ("float32", 1e-5, 2e-4),
+    # bf16 operands, residual stream and logits under the chip run's limits
+    ("bfloat16", LOSS_TOL, GRAD_TOL),
+], ids=["float32", "bfloat16"])
+def test_loss_and_gradients_match_the_plain_reference(dtype, loss_tol,
+                                                      grad_tol):
+    config, family = _tiny_family(dtype)
+    assert spec.load_module("models", "solar_open2").pattern(config) \
+        == "*EKEKEKE"
+    params = jax.jit(family.init_fn)(jax.random.key(0))
+    # a router that prefers some experts, decays and betas that matter
+    params["experts"]["router"] = params["experts"]["router"] * 20.0
+    for name in ("wq", "wk", "wv", "w_fb", "w_beta"):
+        params["kda"][name] = params["kda"][name] * 5.0
+    rows = np.random.default_rng(0).integers(
+        0, family.vocab_size, (2, 129)).astype(np.int32)
+    tokens, targets = rows[:, :-1], rows[:, 1:]
+    loss, grads = jax.jit(jax.value_and_grad(family.loss_fn))(
+        params, tokens, targets)
+    ref_loss, ref_grads = jax.jit(jax.value_and_grad(
+        lambda p, t, y: family.reference_loss(p, t, y, 64)))(
+        params, tokens, targets)
+    assert _rel_err(loss, ref_loss) < loss_tol
+    errors = jax.tree.map(_rel_err, grads, ref_grads)
+    assert set(errors) == {"wte", "kda", "attn", "experts", "final_norm",
+                           "lm_head"}
+    assert {"wg"} <= set(errors["attn"])
+    assert {"w_gate", "shared_gate"} <= set(errors["experts"])
+    for path, err in jax.tree_util.tree_flatten_with_path(errors)[0]:
+        assert err < grad_tol, (jax.tree_util.keystr(path), err)
+
+
+def test_counters_leave_the_step_stacked_by_expert_layer():
+    config = dataclasses.replace(hybrid.HybridConfig.tiny_solar(),
+                                 attn_impl="xla")
+    params = hybrid.init_params(config, jax.random.key(0))
+    ids = np.random.default_rng(1).integers(0, 1024, (2, 128)).astype(
+        np.int32)
+    _, counts = jax.jit(lambda p: hybrid.loss_and_counters(
+        p, ids, ids, config))(params)
+    assert counts["moe_rows"].shape == (4, 1, 4)   # E layers, shards, held
+    assert counts["moe_moved"].shape == (4, 1)
+
+
+def test_num_params_flops_and_the_first_call_record():
+    from ray_tpu.parallel.train_state import _noting
+
+    config = dataclasses.replace(hybrid.HybridConfig.tiny_solar(),
+                                 attn_impl="xla")
+    shapes = jax.eval_shape(lambda: hybrid.init_params(config,
+                                                       jax.random.key(0)))
+    assert hybrid.num_params(config) == sum(
+        a.size for a in jax.tree.leaves(shapes))
+    ids = jax.ShapeDtypeStruct((2, 128), jnp.int32)
+    with _noting() as notes:
+        jax.eval_shape(lambda p, t: hybrid.loss_and_counters(
+            p, t, t, config), shapes, ids)
+    assert notes == {
+        "layer_kinds": "*EKEKEKE", "kda_heads": 2, "kda_head_dim": 16,
+        "kda_chunk": 32, "kda_chunks": 8, "heads_held": 2, "heads_total": 8,
+        "attn_gate": True, "experts_held": 4, "experts_total": 16,
+        "router_scoring": "sigmoid", "attn_positions": 128,
+        "loss_positions": 128}
+
+
+# -------------------------------------------------- (5) the 8-bit control
+def test_the_control_is_refused():
+    """The reference on weights rounded to 8 bits (``tools/control.py``), in
+    the program's place, comes out as not correct at the seed's parameters
+    where the program itself passes, on the same rows, with room on both
+    sides of the tiny preset's limit."""
+    control = spec.load_module("tools", "control").control
+    config, family = _tiny_family()
+    # On the CPU over three seeds of uniform rows, S=128: the leaves' median
+    # error read 0.027-0.031 in the program (largest leaf 0.12-0.20) and
+    # 0.25-0.28 in the control (largest leaf 0.41-0.56, over the 0.24 that
+    # three times the limit allows).  The chip's readings at the cell's own
+    # size set the configuration's own limit (its ``check_why``).
+    limit = 0.08
+    mesh = make_mesh(MeshSpec(), jax.local_devices()[:1])
+    rows = np.random.default_rng(0).integers(
+        0, family.vocab_size, (1, 129)).astype(np.int32)
+    program = correct.at_the_seed(family, mesh, 0, rows, limit)
+    refused = correct.at_the_seed(control(family), mesh, 0, rows, limit)
+    assert program["ok"], program
+    assert not refused["ok"], refused
+    assert 2 * program["grad_norm_err_median"] < limit \
+        < refused["grad_norm_err_median"] / 2
+
+
+# --------------------------------- (6) nothing new on an older model's path
+def test_the_kda_modules_load_with_the_first_model_that_holds_k():
+    """``ops/kda.py`` and ``models/kda.py`` load when a pattern with ``K``
+    is built: not with ``ray_tpu``, ``ray_tpu.models.llama`` or
+    ``ray_tpu.models.hybrid``, and not when the other hybrid model is
+    initialised and traced."""
+    script = (
+        "import sys, jax, ray_tpu, ray_tpu.models.llama\n"
+        "from ray_tpu.models import hybrid\n"
+        "late = {'ray_tpu.ops.kda', 'ray_tpu.models.kda'}\n"
+        "c = hybrid.HybridConfig.tiny()\n"
+        "p = jax.eval_shape(lambda: hybrid.init_params(c, jax.random.key(0)))\n"
+        "t = jax.ShapeDtypeStruct((2, 128), 'int32')\n"
+        "hybrid.num_params(c); hybrid.flops_per_token(c)\n"
+        "jax.eval_shape(lambda p, t: hybrid.loss_fn(p, t, t, c), p, t)\n"
+        "assert not late & set(sys.modules), late & set(sys.modules)\n"
+        "hybrid.init_params(hybrid.HybridConfig.tiny_solar(), "
+        "jax.random.key(0))\n"
+        "assert late <= set(sys.modules)\n")
+    done = subprocess.run([sys.executable, "-c", script], capture_output=True,
+                          text=True, env={"JAX_PLATFORMS": "cpu",
+                                          "PATH": "/usr/bin:/bin"},
+                          cwd=spec.ROOT)
+    assert done.returncode == 0, done.stderr[-2000:]

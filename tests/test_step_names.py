@@ -5,7 +5,8 @@ clock, and the step profiler's counters.
 
 * each model family's lowered step holds every registered scope that is its
   own (the three expert-layer scopes are the llama step's with experts; the
-  Mamba-2 scopes and the shared expert's are the hybrid step's);
+  Mamba-2 scopes and the shared expert's are the hybrid step's, the KDA
+  scopes those of a hybrid step whose pattern holds ``K``);
 * ``classify_op_name`` on real ``op_name`` strings of the compiled v5e steps
   (``tests/data/v5e_step_op_names.json``) and ``parse_anatomy`` on an
   excerpt of that module's text (``tests/data/v5e_step_excerpt.hlo.txt``);
@@ -55,12 +56,16 @@ SDAR_SCOPES = {"moe_held", "noise"}
 
 #: scopes only a step of models/hybrid.py opens: the Mamba-2 layers and the
 #: shared expert beside the routed ones
-HYBRID_SCOPES = {"ssm", "ssm_conv", "ssm_scan", "shared_expert"}
+SSM_SCOPES = {"ssm", "ssm_conv", "ssm_scan"}
+KDA_SCOPES = {"kda", "kda_conv", "kda_scan"}
+HYBRID_SCOPES = SSM_SCOPES | KDA_SCOPES | {"shared_expert"}
 
 
 def _scopes_of(family):
     if family == "hybrid":  # holds a share, trains next tokens
-        return set(tracing.SCOPE_REGISTRY) - {"experts", "noise"}
+        return set(tracing.SCOPE_REGISTRY) - {"experts", "noise"} - KDA_SCOPES
+    if family == "hybrid-kda":
+        return set(tracing.SCOPE_REGISTRY) - {"experts", "noise"} - SSM_SCOPES
     if family == "llama-sdar":
         return set(tracing.SCOPE_REGISTRY) - {"experts"} - HYBRID_SCOPES
     return set(tracing.SCOPE_REGISTRY) - SDAR_SCOPES - HYBRID_SCOPES - (
@@ -80,6 +85,10 @@ def _family(name):
         from ray_tpu.models import hybrid
 
         return hybrid, hybrid.HybridConfig.tiny()
+    if name == "hybrid-kda":  # what solar-open2-ep40-tp8 runs
+        from ray_tpu.models import hybrid
+
+        return hybrid, hybrid.HybridConfig.tiny_solar()
     config = gpt2.GPTConfig.tiny()
     if name == "gpt2-attn-outside-unrolled":  # what gpt2xl-s1024 runs
         import dataclasses
@@ -113,7 +122,7 @@ def _tiny_step(name="llama"):
 
 # ------------------------------------------------------- names in the step
 @pytest.mark.parametrize("family", ["llama", "llama-moe", "llama-sdar",
-                                    "hybrid", "gpt2",
+                                    "hybrid", "hybrid-kda", "gpt2",
                                     "gpt2-attn-outside-unrolled"])
 def test_lowered_step_holds_every_registered_scope(family):
     import jax
@@ -194,7 +203,8 @@ def test_parse_anatomy_on_v5e_module_excerpt():
 
 
 @pytest.mark.parametrize("family", ["llama", "llama-moe", "llama-sdar",
-                                    "hybrid", "gpt2-attn-outside-unrolled"])
+                                    "hybrid", "hybrid-kda",
+                                    "gpt2-attn-outside-unrolled"])
 def test_anatomy_of_a_tiny_cpu_step_end_to_end(family):
     from ray_tpu.parallel.train_state import PHASES
 
