@@ -32,6 +32,7 @@ goes to ``chiprun_out/chip_smoke.json``.
 from __future__ import annotations
 
 import json
+import math
 import os
 import re
 import sys
@@ -438,7 +439,7 @@ def run_kernel(name: str, kernel_fn, reference_fn, args, tols,
     return row
 
 
-def kernels_phase(attn_shape, ce_shape, ring_shape) -> list:
+def kernels_phase(attn_shape, ce_shape, ring_shape, ssd_shape) -> list:
     import jax
     import jax.numpy as jnp
 
@@ -446,6 +447,7 @@ def kernels_phase(attn_shape, ce_shape, ring_shape) -> list:
     from ray_tpu.ops.fused_ce import fused_lm_head_ce
     from ray_tpu.ops.lm_head import lm_head_cross_entropy
     from ray_tpu.ops.ring_attention import ring_attention
+    from ray_tpu.ops.ssd import ssd, ssd_xla
     from ray_tpu.parallel import MeshSpec, make_mesh
 
     def with_grads(fn):
@@ -512,6 +514,24 @@ def kernels_phase(attn_shape, ce_shape, ring_shape) -> list:
             f"{ring_shape}",
             ring_with("fused", causal), ring_with("einsum", causal), qkv,
             (FWD_TOL, GRAD_TOL, GRAD_TOL, GRAD_TOL), want_mosaic=2))
+
+    # the Mamba-2 scan's two kernels (``ssd`` takes them at this shape),
+    # output and every gradient (x, delta, A, B, C, D), against the XLA
+    # form; delta and A as a layer has them when training starts
+    b, S, H, P, G, N, chunk = ssd_shape
+    keys = jax.random.split(jax.random.key(9), 3)
+    scan_args = (
+        normal(10, (b, S, H, P)),
+        jnp.exp(jax.random.uniform(keys[0], (b, S, H), minval=math.log(1e-3),
+                                   maxval=math.log(0.1))),
+        -jax.random.uniform(keys[1], (H,), minval=1.0, maxval=16.0),
+        normal(11, (b, S, G, N)) * 0.5, normal(12, (b, S, G, N)) * 0.5,
+        jax.random.normal(keys[2], (H,)))
+    rows.append(run_kernel(
+        f"ssd scan fwd+grad {ssd_shape}",
+        with_grads(lambda *a: ssd(*a, chunk)),
+        with_grads(lambda *a: ssd_xla(*a, chunk)), scan_args,
+        (FWD_TOL,) + (GRAD_TOL,) * 6, want_mosaic=2))
     return rows
 
 
@@ -557,7 +577,10 @@ def main() -> int:
             attn_shape=(2, config.seq_len, config.n_head, config.head_dim),
             ce_shape=(SEQS_PER_CHIP, config.seq_len, config.d_model,
                       config.vocab_size),
-            ring_shape=(1, 8192, 8, 128))  # the BENCH_RING.json shape
+            ring_shape=(1, 8192, 8, 128),  # the BENCH_RING.json shape
+            # a Mamba-2 layer's scan in ``nemotron-ep16-s8192``: rows,
+            # positions, heads, head_dim, groups, state, chunk
+            ssd_shape=(2, 8192, 64, 64, 8, 128, 128))
         check_kernels_on_chip(kernels)
     finally:
         ray_tpu.shutdown()

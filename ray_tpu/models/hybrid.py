@@ -47,8 +47,11 @@ runs under ``jax.checkpoint`` with the one policy the rule gives this step.
 The attention layers name q, k and v and the shared experts their up (and
 gate) product, as ``llama.py`` names its own; the KDA layer names nothing
 (what its scan keeps is bounded by the layer's own pass); the Mamba layer
-names nothing (its scan's intermediates are the (chunk x chunk) decays, 32 KiB a token a
-float32 copy at 64 heads and chunks of 128: nothing worth a rung), and the
+names nothing (where ``ops/ssd.py`` takes its kernels the scan keeps its
+inputs and each chunk's incoming state, 16 KiB a token at 64 heads of 64 over
+a state of 128 in chunks of 128, from the layer's second forward to its
+backward; the XLA form's intermediates are the (chunk x chunk) decays, 32 KiB
+a token a float32 copy: neither is worth a rung), and the
 held experts' products are recomputed inside their own backward
 (``moe._held_move``).  :func:`_layer_sizes` hands the rule each kind's sizes.
 """
@@ -383,7 +386,12 @@ def _layer_sizes(params, x_shape, config: HybridConfig):
     log-sum-exp), and the widest layer's working set: for a Mamba layer six
     arrays as wide as ``in_proj``'s output and, a head and a chunk position,
     the scan's (chunk x chunk) decays, two float32 and a compute-dtype copy
-    each way (the compiler fuses the rest of them away); for a KDA layer
+    each way (the compiler fuses the rest of them away; **where the scan
+    runs as ``ops/ssd_kernel.py``'s kernels they never reach HBM and the
+    term overstates the layer by what it counts, 2.7 GB in the benchmark's
+    cell, against 0.27 GB of boundary states: left as it is by PR 44, since
+    the bound it feeds decides what ``ops/remat.py`` keeps and a change
+    there is ROADMAP C15's**); for a KDA layer
     what ``kda.working_bytes`` counts a position.  Around the head:
     the logits and their cotangent beside the same casts and inputs.  Held
     against the v5e compiler for the benchmark's cell (9 layers, 2 x 8192

@@ -22,7 +22,11 @@ norm are float32 passes over (tokens, d_inner)-sized arrays that round once;
 ``delta`` and ``A`` stay float32 into the scan.
 
 Scopes: the whole mixer is ``ssm``, inside it ``ssm_conv`` (the four shifted
-multiply-adds, the bias and the silu) and ``ssm_scan`` (``ops/ssd.py``).
+multiply-adds, the bias and the silu) and ``ssm_scan`` (``ops/ssd.py``: two
+Pallas kernels, ``ops/ssd_kernel.py``, where the sizes lie on the chip's
+tiles and the placement lets a Mosaic call sit, as at the published sizes on
+one chip or under a mesh over rows and groups; XLA einsums elsewhere.  The
+call passes no choice: ``ssd`` reads the shapes and the mesh).
 """
 
 from __future__ import annotations

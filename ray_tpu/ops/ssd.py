@@ -30,18 +30,44 @@ adds up to -88, which a head with ``A = -16`` reaches in six positions of
 states are float32; the four products multiply in ``x``'s dtype (bfloat16 in
 a training step) and accumulate in float32.
 
-**The backward** is autodiff through this chunked form: each product's
-transpose is a product of the same shape, and the scan over the chunk states
-transposes into the reverse scan over their cotangents, which is what a
-hand-written backward would be.  Under the layer's ``jax.checkpoint`` nothing
-of a chunk outlives the layer's pass.
+**Two implementations of the one algorithm**, chosen by what the call can
+observe (:func:`path`), with no argument, configuration field or environment
+variable to pick one:
+
+- ``kernel``: ``ops/ssd_kernel.py``, a forward and a backward Pallas kernel
+  over a grid of (row, group of heads, chunk) that keep a chunk's decays,
+  scores and the carried state in VMEM.  Taken where the sizes lie on the
+  chip's tiles (chunk and state multiples of 128, heads of 128 / 2^n
+  channels that fill lane tiles side by side within their group: the
+  published Mamba-2 sizes do, ``HybridConfig.tiny()``'s do not) and the call
+  sits where a Mosaic call may sit: no mesh, a mesh of one device, or a mesh
+  whose `data` / `fsdp` axes divide the rows and whose `tensor` axis divides
+  the groups, under which the kernels run inside a ``shard_map`` over those
+  axes (the partitioner cannot cut a Mosaic call), every chip scanning its
+  own rows and groups.  Its backward is written out (a ``custom_vjp``); the
+  residuals are the inputs and each chunk's incoming state.
+- ``xla``: :func:`ssd_xla`, the einsum form below, for every other shape and
+  placement (a `seq` axis over a row's positions among them), and the
+  kernels' oracle in the tests.  Its backward is autodiff through the
+  chunked form: each product's transpose is a product of the same shape, and
+  the scan over the chunk states transposes into the reverse scan over their
+  cotangents.  XLA writes its (chunk x chunk) decays of every head to HBM,
+  which is what the kernels are for (PERF.md, PRs 40 and 44).
+
+Under the layer's ``jax.checkpoint`` nothing of a chunk outlives the layer's
+pass on either path.  The first-call record says which ran
+(``ssm_scan_kernel``) and over what grid (``ssm_scan_grid``).
 """
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.sharding import PartitionSpec
+
+from ray_tpu.ops import ssd_kernel
 
 
 def ssd(x, delta, A, B, C, D, chunk: int):
@@ -50,11 +76,59 @@ def ssd(x, delta, A, B, C, D, chunk: int):
     ``h // (H / G)``); D: (H,).  S a multiple of ``chunk``.  -> y (b, S, H,
     P) in x's dtype; the state before a row's first position is zero."""
     b, S, H, P = x.shape
+    G = B.shape[2]
+    if S % chunk or H % G:
+        raise ValueError(f"ssd: {S} positions in chunks of {chunk}, {H} "
+                         f"heads in {G} groups")
+    # here, not at the top: ``parallel/train_state.py`` imports ``ops``
+    from ray_tpu.parallel.train_state import note_first_call
+
+    mesh = jax.sharding.get_abstract_mesh()
+    taken, specs = path(x.shape, B.shape, chunk, mesh)
+    if taken == "xla":
+        note_first_call(ssm_scan_kernel=False, ssm_scan_grid=None)
+        return ssd_xla(x, delta, A, B, C, D, chunk)
+
+    def local(x, delta, A, B, C, D):
+        note_first_call(ssm_scan_kernel=True,
+                        ssm_scan_grid=list(ssd_kernel.grid(x, B, chunk)))
+        return ssd_kernel.scan(x, delta, A, B, C, D, chunk, True)
+
+    if specs is None:
+        return local(x, delta, A, B, C, D)
+    # check_vma off as around the splash call: a pallas_call declares no
+    # vma on its outputs
+    return jax.shard_map(local, in_specs=specs, out_specs=specs[0],
+                         check_vma=False)(x, delta, A, B, C, D)
+
+
+def path(x_shape, B_shape, chunk: int, mesh):
+    """-> (``"kernel"`` or ``"xla"``, the ``shard_map`` specs of the six
+    arguments or None where the kernels run unwrapped): which implementation
+    a call of these shapes takes under ``mesh`` (the module's docstring has
+    the rule)."""
+    b, _, H, P = x_shape
+    G, N = B_shape[2:]
+    if not ssd_kernel.tiles(chunk, H // G, P, N):
+        return "xla", None
+    if mesh.empty or mesh.size == 1:
+        return "kernel", None
+    rows = tuple(a for a in ("data", "fsdp") if a in mesh.axis_names)
+    tensor = mesh.shape.get("tensor", 1)
+    cut = int(np.prod([mesh.shape[a] for a in rows])) * tensor
+    if cut != mesh.size or b % (cut // tensor) or G % tensor:
+        return "xla", None  # positions or nothing it knows is cut
+    heads = "tensor" if "tensor" in mesh.axis_names else None
+    wide = PartitionSpec(rows or None, None, heads, None)
+    return "kernel", (wide, PartitionSpec(rows or None, None, heads),
+                      PartitionSpec(heads), wide, wide, PartitionSpec(heads))
+
+
+def ssd_xla(x, delta, A, B, C, D, chunk: int):
+    """:func:`ssd` as einsums and one ``lax.scan`` over the chunk states."""
+    b, S, H, P = x.shape
     G, N = B.shape[2:]
     Q, J = chunk, H // G
-    if S % Q or H % G:
-        raise ValueError(f"ssd: {S} positions in chunks of {Q}, {H} heads "
-                         f"in {G} groups")
     c, dt, f32 = S // Q, x.dtype, jnp.float32
     xc = x.reshape(b, c, Q, G, J, P)
     Bc, Cc = B.reshape(b, c, Q, G, N), C.reshape(b, c, Q, G, N)

@@ -200,3 +200,63 @@ def test_the_rules_blocks_compile_for_the_v5e(monkeypatch, one_chip):
             assert text.count("tpu_custom_call") == 2, cell
     finally:
         jax.config.update("jax_enable_compilation_cache", cache)
+
+
+def test_the_scan_kernels_compile_for_the_v5e(monkeypatch, one_chip):
+    """The Mamba-2 scan's forward and backward kernels (``ops/ssd_kernel.py``)
+    at the shape ``nemotron-ep16-s8192`` runs them (2 rows of 8192, 64 heads
+    of 64 in 8 groups, a state of 128, chunks of 128, bf16), compiled for a
+    described v5e: the compiler refuses a kernel whose blocks, scratch and
+    temporaries need more than the 16 MB of scoped VMEM (PR 42 met that
+    limit with splash blocks of 2048), or that slices off the tiles, and
+    interpret mode sees neither.  What the kernels ask for themselves, the
+    blocks in two buffers and the scratch, is counted here too.  In this
+    file, beside the other compile: one worker loads the TPU's compiler."""
+    import re
+
+    from ray_tpu.ops import ssd
+    from ray_tpu.parallel.train_state import classify_op_name
+
+    b, S, H, P, G, N, Q = 2, 8192, 64, 64, 8, 128, 128
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    args = (shape((b, S, H, P), jnp.bfloat16), shape((b, S, H), jnp.float32),
+            shape((H,), jnp.float32), shape((b, S, G, N), jnp.bfloat16),
+            shape((b, S, G, N), jnp.bfloat16), shape((H,), jnp.float32))
+    assert ssd.path(args[0].shape, args[3].shape, Q,
+                    jax.sharding.get_abstract_mesh()) == ("kernel", None)
+
+    def scan(*inputs):
+        with jax.named_scope("ssm_scan"):  # as models/mamba2.py:mixer
+            return ssd.ssd(*inputs, Q)
+
+    def both(*inputs):
+        y, pull = jax.vjp(jax.checkpoint(scan), *inputs)
+        return pull(y)
+
+    try:
+        compiled = jax.jit(both).lower(*args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    # the forward, the checkpoint's second run of it that keeps the boundary
+    # states, the backward: each under the caller's scope, in its own phase
+    calls = [line for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line]
+    filed = sorted(classify_op_name(
+        re.search(r'op_name="([^"]*)"', line).group(1)) for line in calls)
+    assert filed == [("backward", "ssm_scan"), ("forward", "ssm_scan"),
+                     ("recompute", "ssm_scan")]
+    # a (Q, J) or (J, Q) float32 block in VMEM: J = 8 pads to a whole tile
+    J = H // G
+    wide, group, small = Q * J * P * 2, Q * N * 2, Q * 128 * 4
+    states = J * P // 128 * N * 128 * 4
+    forward = 2 * (2 * wide + 2 * group + 4 * small + states) + states
+    backward = 2 * (3 * wide + 4 * group + 7 * small + states
+                    + 8 * J * P * 4) + states + 3 * small
+    assert forward < backward < 4 * 2 ** 20  # of 16 MiB
+

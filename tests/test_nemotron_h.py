@@ -339,7 +339,8 @@ def test_num_params_and_the_first_call_record():
             p, t, t, config), shapes, ids)
     assert notes == {
         "layer_kinds": "MEMEM*EME", "ssm_heads": 8, "ssm_state": 16,
-        "ssm_chunk": 32, "ssm_chunks": 8, "experts_held": 4,
+        "ssm_chunk": 32, "ssm_chunks": 8, "ssm_scan_kernel": False,
+        "ssm_scan_grid": None, "experts_held": 4,
         "experts_total": 16, "router_scoring": "sigmoid",
         "attn_positions": 128, "loss_positions": 128}
 
@@ -387,6 +388,13 @@ LOWERED_STEPS = {
         "71443c1fd2cd39de68e41005488544c5a2b3c35b144a9b9f7961918484e15f16",
     "tiny-gpt2":
         "86135e6f43a576200ef38b5a76d717607699853ed5341b6350fd1c81db4dbe04",
+    # the two hybrid presets, as PR 43 left them (added at PR 44: the tiny
+    # Mamba-2 sizes lie off the chip's tiles and take ``ops.ssd.ssd_xla``,
+    # whose lowered text is the parent's ``ssd``)
+    "tiny-nemotron-h":
+        "51f2becdf53dcafbee0623c9aafa4cf4b8e38fd401b4baea5130cd23797af14a",
+    "tiny-solar-open2":
+        "5db3e1bc235c28d1dcc4e850b73b655552f3f0efe19609e2eb37a5a78bc33c2f",
 }
 
 
