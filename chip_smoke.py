@@ -439,12 +439,14 @@ def run_kernel(name: str, kernel_fn, reference_fn, args, tols,
     return row
 
 
-def kernels_phase(attn_shape, ce_shape, ring_shape, ssd_shape) -> list:
+def kernels_phase(attn_shape, ce_shape, ring_shape, ssd_shape,
+                  kda_shape) -> list:
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.ops.attention import causal_attention, splash_attention
     from ray_tpu.ops.fused_ce import fused_lm_head_ce
+    from ray_tpu.ops.kda import kda, kda_xla
     from ray_tpu.ops.lm_head import lm_head_cross_entropy
     from ray_tpu.ops.ring_attention import ring_attention
     from ray_tpu.ops.ssd import ssd, ssd_xla
@@ -532,6 +534,31 @@ def kernels_phase(attn_shape, ce_shape, ring_shape, ssd_shape) -> list:
         with_grads(lambda *a: ssd(*a, chunk)),
         with_grads(lambda *a: ssd_xla(*a, chunk)), scan_args,
         (FWD_TOL,) + (GRAD_TOL,) * 6, want_mosaic=2))
+
+    # the delta-rule scan's kernels (``kda`` takes them at this shape),
+    # output and every gradient (q, k, v, g, beta), against the XLA form; q
+    # and k of unit length a head, keys that share a part, g a head's own
+    # rate in [1, 16] times a step in [0.001, 0.1]
+    b, S, H, d, chunk = kda_shape
+    keys = jax.random.split(jax.random.key(13), 5)
+
+    def unit(key, shift):
+        x = jax.random.normal(key, (b, S, H, d)) + shift
+        return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True))
+
+    delta_args = (
+        (unit(keys[0], 0.0) * d ** -0.5).astype(jnp.bfloat16),
+        unit(keys[1], 0.5).astype(jnp.bfloat16), normal(14, (b, S, H, d)),
+        -jax.random.uniform(keys[2], (1, 1, H, 1), minval=1.0, maxval=16.0)
+        * jnp.exp(jax.random.uniform(keys[3], (b, S, H, d),
+                                     minval=math.log(1e-3),
+                                     maxval=math.log(0.1))),
+        2.0 * jax.nn.sigmoid(jax.random.normal(keys[4], (b, S, H))))
+    rows.append(run_kernel(
+        f"kda scan fwd+grad {kda_shape}",
+        with_grads(lambda *a: kda(*a, chunk)),
+        with_grads(lambda *a: kda_xla(*a, chunk)), delta_args,
+        (FWD_TOL,) + (GRAD_TOL,) * 5, want_mosaic=4))
     return rows
 
 
@@ -580,7 +607,10 @@ def main() -> int:
             ring_shape=(1, 8192, 8, 128),  # the BENCH_RING.json shape
             # a Mamba-2 layer's scan in ``nemotron-ep16-s8192``: rows,
             # positions, heads, head_dim, groups, state, chunk
-            ssd_shape=(2, 8192, 64, 64, 8, 128, 128))
+            ssd_shape=(2, 8192, 64, 64, 8, 128, 128),
+            # a KDA layer's scan in ``solar-open2-ep40-tp8``: rows,
+            # positions, heads, head_dim, chunk
+            kda_shape=(1, 8192, 8, 128, 64))
         check_kernels_on_chip(kernels)
     finally:
         ray_tpu.shutdown()

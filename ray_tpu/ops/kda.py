@@ -52,17 +52,49 @@ not; the same joins from single positions up (``D - D M D`` over the whole
 chunk, six times) are as exact and moved 7 GB more a step through HBM (the
 step 279 ms against 266: PERF.md, PR 43).
 
-**The backward** is autodiff through this form, under the layer's
-``jax.checkpoint``: each product's transpose is a product of the same shape
-and the scan over the chunk states transposes into the reverse scan over
-their cotangents.
+**Two implementations of the one algorithm**, chosen by what the call can
+observe (:func:`path`), with no argument, configuration field or environment
+variable to pick one:
+
+- ``kernel``: ``ops/kda_kernel.py``, Pallas kernels over a grid of (row,
+  heads, chunk) that keep every decay, every scaled copy of q and k, the
+  products behind ``A`` and ``B``, ``U`` and the carried state in VMEM (``A``
+  and ``B`` there by halving the chunk log2(C) times instead of writing a
+  sub-chunk's decays out; the triangular system stays XLA's, 16 KB a chunk a
+  head).  Taken where the sizes lie on the chip's tiles (heads of whole lane
+  tiles, 128 channels or a multiple; chunks of whole sublane tiles of q's
+  dtype, 16 to 128 positions, compiled for the v5e at each: at 256 the
+  backward's blocks outgrow its 16 MB of scoped VMEM; the published KDA sizes
+  lie inside, ``tiny-solar-open2``'s heads of 16 do not) and the call sits where a
+  Mosaic call may sit: no mesh, a mesh of one device, or a mesh whose `data`
+  / `fsdp` axes divide the rows and whose `tensor` axis divides the heads,
+  under which the kernels run inside a ``shard_map`` over those axes, every
+  chip scanning its own rows and heads.  Its backward is written out
+  (``custom_vjp``s); the residuals are the inputs, ``A``, ``B``, the inverse
+  and each chunk's incoming state.
+- ``xla``: :func:`kda_xla`, the einsum form above, for every other shape and
+  placement and as the kernels' oracle in the tests.  Its backward is
+  autodiff through this form: each product's transpose is a product of the
+  same shape and the scan over the chunk states transposes into the reverse
+  scan over their cotangents.  XLA writes the explicit decays, the scaled
+  copies and the chunk matrices to HBM, 51 GB a step in
+  ``solar-open2-ep40-tp8``, which is what the kernels are for (PERF.md, PRs
+  43 and 45).
+
+Under the layer's ``jax.checkpoint`` nothing of a chunk outlives the layer's
+pass on either path.  The first-call record says which ran
+(``kda_scan_kernel``) and over what grid (``kda_scan_grid``).
 """
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.sharding import PartitionSpec
+
+from ray_tpu.ops.ssd import rows_and_heads
 
 #: positions of a sub-chunk: the explicit sum over channels is SUB x SUB x d
 #: a sub-chunk, so it grows with SUB, the forward substitution has SUB - 1
@@ -113,12 +145,57 @@ def kda(q, k, v, g, beta, chunk: int):
     of the decay a channel; beta: (b, S, H) float32.  S a multiple of
     ``chunk``, ``chunk`` a power of two.  -> o (b, S, H, d) in q's dtype; the
     state before a row's first position is zero."""
+    S = q.shape[1]
+    if S % chunk or chunk & (chunk - 1):
+        raise ValueError(f"kda: {S} positions in chunks of {chunk} (a power "
+                         "of two that divides them)")
+    # here, not at the top: ``parallel/train_state.py`` imports ``ops``, and
+    # the kernels' module imports this one
+    from ray_tpu.parallel.train_state import note_first_call
+
+    taken, specs = path(q.shape, chunk, jax.sharding.get_abstract_mesh())
+    if taken == "xla":
+        note_first_call(kda_scan_kernel=False, kda_scan_grid=None)
+        return kda_xla(q, k, v, g, beta, chunk)
+    from ray_tpu.ops import kda_kernel
+
+    def local(q, k, v, g, beta):
+        note_first_call(kda_scan_kernel=True,
+                        kda_scan_grid=list(kda_kernel.grid(q, chunk)))
+        return kda_kernel.scan(q, k, v, g, beta, chunk)
+
+    if specs is None:
+        return local(q, k, v, g, beta)
+    # check_vma off as around the splash call: a pallas_call declares no
+    # vma on its outputs
+    return jax.shard_map(local, in_specs=specs, out_specs=specs[0],
+                         check_vma=False)(q, k, v, g, beta)
+
+
+def path(q_shape, chunk: int, mesh):
+    """-> (``"kernel"`` or ``"xla"``, the ``shard_map`` specs of the five
+    arguments or None where the kernels run unwrapped): which implementation
+    a call of these shapes takes under ``mesh`` (the module's docstring has
+    the rule)."""
+    b, _, H, d = q_shape
+    if d % 128 or chunk % 16 or chunk > 128:
+        return "xla", None
+    if mesh.empty or mesh.size == 1:
+        return "kernel", None
+    cut = rows_and_heads(mesh, b, H)
+    if cut is None:
+        return "xla", None  # positions or nothing it knows is cut
+    rows, heads = cut
+    wide = PartitionSpec(rows, None, heads, None)
+    return "kernel", (wide, wide, wide, wide,
+                      PartitionSpec(rows, None, heads))
+
+
+def kda_xla(q, k, v, g, beta, chunk: int):
+    """:func:`kda` as einsums and one ``lax.scan`` over the chunk states."""
     b, S, H, d = q.shape
     C = chunk
     sub = min(SUB, C)
-    if S % C or C & (C - 1):
-        raise ValueError(f"kda: {S} positions in chunks of {C} (a power of "
-                         "two that divides them)")
     n, m, dt, f32 = S // C, C // sub, q.dtype, jnp.float32
     hi = lax.Precision.HIGHEST
     # heads in front of the positions: every product is over (b, n, H)

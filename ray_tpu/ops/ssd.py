@@ -113,15 +113,27 @@ def path(x_shape, B_shape, chunk: int, mesh):
         return "xla", None
     if mesh.empty or mesh.size == 1:
         return "kernel", None
-    rows = tuple(a for a in ("data", "fsdp") if a in mesh.axis_names)
-    tensor = mesh.shape.get("tensor", 1)
-    cut = int(np.prod([mesh.shape[a] for a in rows])) * tensor
-    if cut != mesh.size or b % (cut // tensor) or G % tensor:
+    cut = rows_and_heads(mesh, b, G)
+    if cut is None:
         return "xla", None  # positions or nothing it knows is cut
-    heads = "tensor" if "tensor" in mesh.axis_names else None
-    wide = PartitionSpec(rows or None, None, heads, None)
-    return "kernel", (wide, PartitionSpec(rows or None, None, heads),
+    rows, heads = cut
+    wide = PartitionSpec(rows, None, heads, None)
+    return "kernel", (wide, PartitionSpec(rows, None, heads),
                       PartitionSpec(heads), wide, wide, PartitionSpec(heads))
+
+
+def rows_and_heads(mesh, rows: int, heads: int):
+    """-> (the axes of ``mesh`` that cut a call's rows, `data` and `fsdp`;
+    the one that cuts its heads or groups, `tensor`; None for none) where
+    those are all its axes and divide ``rows`` and ``heads``, so that every
+    device has rows and heads of its own to scan; else None.  ``ops/kda.py``
+    places its kernels by the same rule."""
+    over = tuple(a for a in ("data", "fsdp") if a in mesh.axis_names)
+    tensor = mesh.shape.get("tensor", 1)
+    cut = int(np.prod([mesh.shape[a] for a in over])) * tensor
+    if cut != mesh.size or rows % (cut // tensor) or heads % tensor:
+        return None
+    return over or None, "tensor" if "tensor" in mesh.axis_names else None
 
 
 def ssd_xla(x, delta, A, B, C, D, chunk: int):

@@ -260,3 +260,59 @@ def test_the_scan_kernels_compile_for_the_v5e(monkeypatch, one_chip):
                     + 8 * J * P * 4) + states + 3 * small
     assert forward < backward < 4 * 2 ** 20  # of 16 MiB
 
+
+
+def test_the_kda_kernels_compile_for_the_v5e(monkeypatch, one_chip):
+    """The delta-rule scan's kernels (``ops/kda_kernel.py``) at the shape
+    ``solar-open2-ep40-tp8`` runs them (one row of 8192, 8 heads of 128,
+    chunks of 64, bf16), compiled for a described v5e under the layer's
+    checkpoint: ``A`` and ``B`` and the scan with the outputs, each forward,
+    forward again and backward, every call under the caller's ``kda_scan``
+    scope in its own phase (``step.kda_scan_ms`` reads the scope), and what
+    the kernels ask of VMEM themselves far inside the 16 MiB."""
+    import re
+
+    from ray_tpu.ops import kda, kda_kernel
+    from ray_tpu.parallel.train_state import classify_op_name
+
+    b, S, H, d, C = 1, 8192, 8, 128, 64
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    wide = shape((b, S, H, d), jnp.bfloat16)
+    args = (wide, wide, wide, shape((b, S, H, d), jnp.float32),
+            shape((b, S, H), jnp.float32))
+    assert kda.path(wide.shape, C, jax.sharding.get_abstract_mesh()) == (
+        "kernel", None)
+    assert kda_kernel.grid(wide, C) == (1, 1, 128)
+
+    def scan(*inputs):
+        with jax.named_scope("kda_scan"):  # as models/kda.py:mixer
+            return kda.kda(*inputs, C)
+
+    def both(*inputs):
+        o, pull = jax.vjp(jax.checkpoint(scan), *inputs)
+        return pull(o)
+
+    try:
+        compiled = jax.jit(both).lower(*args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    calls = [line for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line]
+    filed = sorted(classify_op_name(
+        re.search(r'op_name="([^"]*)"', line).group(1)) for line in calls)
+    assert filed == [(phase, "kda_scan") for phase in
+                     ("backward",) * 2 + ("forward",) * 2 + ("recompute",) * 2]
+    # the backward scan's blocks in two buffers, and its scratch
+    heads = kda_kernel.heads_a_step(H)
+    # (q, k, v, dO, dq, dk, dv; G, dG; T, B, dB; dT; a (C, C) block pads its
+    # 64 lanes to a tile)
+    wide, square, states = C * heads * d, heads * C * 128, heads * d * d * 4
+    backward = 2 * (7 * wide * 2 + 2 * wide * 4 + 3 * square * 2
+                    + square * 4 + states) + states
+    assert backward < 8 * 2 ** 20  # of 16 MiB

@@ -24,7 +24,7 @@ from benchmarks.lib import correct, spec
 from benchmarks.reference import solar_open2 as reference
 from ray_tpu.models import hybrid, kda as kda_model, moe
 from ray_tpu.models.layers import attention
-from ray_tpu.ops.kda import kda
+from ray_tpu.ops.kda import kda, kda_xla
 from ray_tpu.parallel import MeshSpec, make_mesh
 
 #: benchmarks/lib/correct.py's, which the bf16 program is held to on the chip
@@ -58,11 +58,15 @@ def _scan_inputs(chunks, chunk, decay, b=2, H=3, d=16):
             2.0 * jax.nn.sigmoid(jax.random.normal(k[4], (b, S, H)) + 1.0))
 
 
+@pytest.mark.parametrize("scan", [kda_xla, kda],
+                         ids=["the-xla-form", "as-dispatched"])
 @pytest.mark.parametrize("chunks,chunk,decay", [
     (2, 64, 0.3), (3, 32, 0.3), (2, 64, 8.0), (3, 32, 8.0), (1, 16, 8.0),
     (2, 8, 8.0)])
-def test_chunked_scan_is_the_recurrence(chunks, chunk, decay):
-    """Forward and every gradient (q, k, v, g, beta) against a
+def test_chunked_scan_is_the_recurrence(chunks, chunk, decay, scan):
+    """Forward and every gradient (q, k, v, g, beta) of the XLA form, and of
+    ``kda`` as it dispatches at these sizes (heads of 16: the XLA form; the
+    kernels' sizes are ``tests/test_kda_kernel.py``'s), against a
     position-by-position ``lax.scan`` in float32, two rows a batch, with
     ``beta`` above 1 and, at ``decay`` 8, a ``g`` that sums below -88 inside
     a chunk, where a product of ratios would overflow: no inf, no nan."""
@@ -80,7 +84,7 @@ def test_chunked_scan_is_the_recurrence(chunks, chunk, decay):
         return jax.jit(run)(*args)
 
     with jax.default_matmul_precision("highest"):
-        got, grads = out_and_grads(lambda *a: kda(*a, chunk))
+        got, grads = out_and_grads(lambda *a: scan(*a, chunk))
         want, grads_ref = out_and_grads(reference.recurrence)
     assert np.all(np.isfinite(got))
     assert _rel_err(got, want) < 1e-5
@@ -354,7 +358,8 @@ def test_num_params_flops_and_the_first_call_record():
             p, t, t, config), shapes, ids)
     assert notes == {
         "layer_kinds": "*EKEKEKE", "kda_heads": 2, "kda_head_dim": 16,
-        "kda_chunk": 32, "kda_chunks": 8, "heads_held": 2, "heads_total": 8,
+        "kda_chunk": 32, "kda_chunks": 8, "kda_scan_kernel": False,
+        "kda_scan_grid": None, "heads_held": 2, "heads_total": 8,
         "attn_gate": True, "experts_held": 4, "experts_total": 16,
         "router_scoring": "sigmoid", "attn_positions": 128,
         "loss_positions": 128}
