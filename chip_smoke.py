@@ -439,15 +439,12 @@ def run_kernel(name: str, kernel_fn, reference_fn, args, tols,
     return row
 
 
-def kernels_phase(attn_shape, ce_shape, ring_shape, ssd_shape,
-                  kda_shape) -> list:
+def kernels_phase(attn_shape, ring_shape, ssd_shape, kda_shape) -> list:
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.ops.attention import causal_attention, splash_attention
-    from ray_tpu.ops.fused_ce import fused_lm_head_ce
     from ray_tpu.ops.kda import kda, kda_xla
-    from ray_tpu.ops.lm_head import lm_head_cross_entropy
     from ray_tpu.ops.ring_attention import ring_attention
     from ray_tpu.ops.ssd import ssd, ssd_xla
     from ray_tpu.parallel import MeshSpec, make_mesh
@@ -472,32 +469,6 @@ def kernels_phase(attn_shape, ce_shape, ring_shape, ssd_shape,
         with_grads(splash_attention),
         with_grads(lambda q, k, v: causal_attention(q, k, v, "xla")),
         qkv, (FWD_TOL, GRAD_TOL, GRAD_TOL, GRAD_TOL), want_mosaic=2))
-
-    # fused LM-head cross-entropy, forward and both backwards, against the
-    # dense loss with fp32 logits
-    B, S, D, V = ce_shape
-    x = normal(3, (B, S, D))
-    wte = (jax.random.normal(jax.random.key(4), (V, D)) * 0.02
-           ).astype(jnp.bfloat16)
-    targets = jax.random.randint(jax.random.key(5), (B, S), 0, V)
-
-    def dense_ce(x, wte, targets):
-        return lm_head_cross_entropy(x, wte, targets, jnp.float32)
-
-    def ce_with(fn):
-        def run(x, wte, targets):
-            loss, (dx, dw) = jax.value_and_grad(fn, argnums=(0, 1))(
-                x, wte, targets)
-            return loss, dx, dw
-        return run
-
-    for bwd, want in (("pallas", 3), ("xla", 1)):
-        rows.append(run_kernel(
-            f"fused_lm_head_ce fwd + bwd_impl={bwd} {ce_shape}",
-            ce_with(lambda x, w, t, bwd=bwd: fused_lm_head_ce(
-                x, w, t, bwd_impl=bwd)),
-            ce_with(dense_ce), (x, wte, targets),
-            (1e-4, GRAD_TOL, GRAD_TOL), want_mosaic=want))
 
     # fused ring block at world=1 against the einsum body.  The ring traces a
     # masked diagonal block and an unmasked full block; at world=1 the causal
@@ -602,8 +573,6 @@ def main() -> int:
         check_train_on_chip(train, config, SEQS_PER_CHIP)
         kernels = kernels_phase(
             attn_shape=(2, config.seq_len, config.n_head, config.head_dim),
-            ce_shape=(SEQS_PER_CHIP, config.seq_len, config.d_model,
-                      config.vocab_size),
             ring_shape=(1, 8192, 8, 128),  # the BENCH_RING.json shape
             # a Mamba-2 layer's scan in ``nemotron-ep16-s8192``: rows,
             # positions, heads, head_dim, groups, state, chunk

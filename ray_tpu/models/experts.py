@@ -1,0 +1,145 @@
+"""The expert layer of a hybrid decoder (``models/hybrid.py``, kind ``E``):
+``models/layers.py:feed_forward`` over ``models/moe.py``, with its parameters
+and its sizes.  Not a model.
+
+Sigmoid (or softmax) scores, a selection bias that picks the experts and
+does not weigh them, renormalised weights times ``routed_scaling``, experts
+of two matrices with relu^2 (or, with ``gated_experts``, of three: SwiGLU), a
+shared expert of the same make beside them; ``experts_held`` says which
+experts this chip holds.  The held experts' products are recomputed inside
+their own backward (``moe._held_move``); the shared expert names its up (and
+gate) product for ``ops/remat.py``.
+
+**The selection bias** is no parameter: the published recipe moves it by the
+load, outside the gradient.  Here it is a constant of the configuration,
+drawn a layer from ``router_bias_seed`` (numpy, when the step is traced),
+so that the mechanism is not a no-op at zero; no leaf holds it, so the
+optimizer cannot touch it.  The module has the interface ``hybrid.KINDS``
+asks of a kind.
+"""
+
+from __future__ import annotations
+
+from functools import partial
+from typing import Any, Dict, Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ray_tpu.models import moe
+from ray_tpu.models.layers import feed_forward, stacked_normal
+from ray_tpu.ops import remat
+
+#: what ``expert_activation`` may name
+ACTIVATIONS = {"relu2": moe.relu2, "silu": jax.nn.silu}
+
+
+def router_bias(config, layer: int) -> Optional[np.ndarray]:
+    """The selection bias of the ``layer``-th expert layer, (E,) float32: a
+    function of the configuration, the same on every trace."""
+    if not config.router_bias_std:
+        return None
+    rng = np.random.default_rng([config.router_bias_seed, layer])
+    return (rng.standard_normal(config.n_experts)
+            * config.router_bias_std).astype(np.float32)
+
+
+def init_params(config, key, n: int, out_std: float) -> Dict[str, Any]:
+    """``n`` layers stacked on a leading axis.  Matrices normal(0.02), the
+    experts' and the shared expert's down normal(``out_std``), the norm
+    ones; of the routed experts the held ones."""
+    D, held, F, Fs = (config.d_model, len(config.held), config.d_ff,
+                      config.shared_width)
+    ks = jax.random.split(key, 5)
+    norm = partial(stacked_normal, n)
+
+    params = {
+        "mlp_norm": jnp.ones((n, D)),
+        "router": norm(ks[0], (D, config.n_experts)),
+        "w_up": norm(ks[1], (held, D, F)),
+        "w_down": norm(ks[2], (held, F, D), out_std),
+    }
+    if Fs:
+        params["shared_up"] = norm(ks[3], (D, Fs))
+        params["shared_down"] = norm(ks[4], (Fs, D), out_std)
+    if config.gated_experts:
+        gates = jax.random.split(jax.random.fold_in(key, 5))
+        params["w_gate"] = norm(gates[0], (held, D, F))
+        if Fs:
+            params["shared_gate"] = norm(gates[1], (D, Fs))
+    return params
+
+
+def logical_axes(config) -> Dict[str, Any]:
+    L = "layers"
+    axes = {"mlp_norm": (L, "norm"), "router": (L, "embed", None),
+            "w_up": (L, "expert", "embed", "mlp"),
+            "w_down": (L, "expert", "mlp", "embed")}
+    if config.shared_width:
+        axes["shared_up"] = (L, "embed", "mlp")
+        axes["shared_down"] = (L, "mlp", "embed")
+    if config.gated_experts:
+        axes["w_gate"] = (L, "expert", "embed", "mlp")
+        if config.shared_width:
+            axes["shared_gate"] = (L, "embed", "mlp")
+    return axes
+
+
+def _matrices(config) -> int:
+    """Of one expert, and of the shared one."""
+    return 3 if config.gated_experts else 2
+
+
+def matmul_params(config, routed: float) -> float:
+    """The matrix entries of one layer that a position meets, with ``routed``
+    of its routed experts counted: the router, the shared expert and those."""
+    return config.d_model * config.n_experts + _matrices(config) \
+        * config.d_model * (config.shared_width + routed * config.d_ff)
+
+
+def num_params(config) -> int:
+    """Of one layer that exist here, its pre-norm included: of the routed
+    experts the held ones."""
+    return matmul_params(config, len(config.held)) + config.d_model
+
+
+def mixer_flops(config, seq_len: int) -> float:
+    """Nothing beside the matrices."""
+    return 0.0
+
+
+def layer_bytes(config, tokens: int, seq_len: int, tensor: int,
+                itemsize: int):
+    """For ``hybrid._layer_sizes``, a chip's bytes of one layer over
+    ``tokens`` positions with the shared expert's width cut ``tensor`` ways:
+    (its working set: the shared expert's products, their activation and the
+    cotangents, and four copies of the (position, expert) rows; nothing kept
+    for the backward beside its input; the ladder's candidates it names: the
+    shared expert's up, and gate, product)."""
+    shared = config.shared_width
+    return (tokens * (3 * _matrices(config) * shared // tensor * itemsize
+                      + 4 * config.experts_per_token * config.d_model
+                      * itemsize),
+            0,
+            {remat.GATE_UP: tokens * (_matrices(config) - 1) * shared
+             // tensor * itemsize})
+
+
+def first_call_facts(config, rows: int, seq_len: int) -> Dict[str, Any]:
+    return {"experts_held": len(config.held),
+            "experts_total": config.n_experts,
+            "router_scoring": config.router_scoring}
+
+
+def layer(config, axes, index: int):
+    """Layer ``index`` of the kind as (x, its row of the stack) -> (x, the
+    layer's counts: ``moe.moe_mlp``'s)."""
+    def experts(x, blk):
+        x, (_, counts) = feed_forward(
+            x, blk, config, axes, scoring=config.router_scoring,
+            bias=router_bias(config, index), scale=config.routed_scaling,
+            activation=ACTIVATIONS[config.expert_activation])
+        return x, counts
+
+    return experts

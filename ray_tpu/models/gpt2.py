@@ -51,11 +51,6 @@ class GPTConfig:
     pp_stages: int = 1
     #: GPipe microbatches; 0 = pp_stages (minimum). Must divide batch.
     pp_microbatches: int = 0
-    #: LM-head loss implementation: "auto" flips to the fused pallas CE
-    #: kernel (ops/fused_ce.py — logits never in HBM) when its roofline
-    #: cost model predicts a win (D < 120 with bf16 logits, < 240 with
-    #: float32: no published width), "fused"/"dense" force it.
-    loss_impl: str = "auto"
     #: Dtype the (B, S, V) logits MATERIALIZE in.  bf16 halves the step's
     #: single biggest HBM tensor (fwd logits + bwd dlogits, ~1.6 GB each at
     #: B=16 fp32) for ~+1 MFU point on v5e; the loss reductions (logsumexp /
@@ -290,30 +285,8 @@ def loss_fn(params, tokens, targets, config: GPTConfig):
     x = forward_hidden(params, tokens, config)
     with jax.named_scope("lm_head"):
         # Tied LM head.
-        wte = params["wte"].astype(config.dtype)
-        if _fused_loss(config):
-            from ray_tpu.ops.fused_ce import fused_lm_head_ce
-
-            return fused_lm_head_ce(x, wte, targets)
-        return lm_head_cross_entropy(x, wte, targets, config.logits_dtype)
-
-
-def _fused_loss(config: GPTConfig) -> bool:
-    impl = config.loss_impl
-    if impl not in ("auto", "fused", "dense"):
-        raise ValueError(f"loss_impl must be auto|fused|dense, got {impl!r}")
-    if impl != "auto":
-        return impl == "fused"
-    # TPU-only flip (same gating as attn_impl): interpret-mode pallas
-    # off-TPU would be a silent orders-of-magnitude slowdown.
-    if jax.default_backend() != "tpu":
-        return False
-    from ray_tpu._private.accelerators import device_peaks
-    from ray_tpu.ops.fused_ce import fused_ce_wins
-
-    return fused_ce_wins(config.d_model,
-                         jnp.dtype(config.logits_dtype).itemsize,
-                         device_peaks(jax.devices()[0].device_kind))
+        return lm_head_cross_entropy(x, params["wte"].astype(config.dtype),
+                                     targets, config.logits_dtype)
 
 
 def make_train_step(config: GPTConfig, optimizer):

@@ -1,7 +1,8 @@
 """The KDA mixer: the layer of a hybrid decoder (``models/hybrid.py``) whose
 token mixing is the gated delta rule with a per-channel decay
-(``ops/kda.py``), as Solar-Open2 and Kimi-Linear have it.  Not a model; the
-file is the mixer, its parameters and its sizes.
+(``ops/kda.py``), as Solar-Open2 and Kimi-Linear have it (kind ``K``).  Not a
+model; the file is the mixer, its parameters and its sizes, with the
+interface ``hybrid.KINDS`` asks of a kind.
 
 Per layer, on ``u = norm(x)`` (H heads of d channels, ``inner = H x d``; the
 two low-rank gates pass through d channels, ``kda_use_full_proj`` false):
@@ -35,13 +36,14 @@ convolutions' shifted multiply-adds and the silu) and ``kda_scan``
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models.layers import dense, rmsnorm
+from ray_tpu.models.layers import dense, rmsnorm, stacked_normal
 from ray_tpu.models.mamba2 import causal_conv
 from ray_tpu.ops.kda import SUB, kda
 
@@ -61,9 +63,7 @@ def init_params(config, key, n: int, out_std: float) -> Dict[str, Any]:
                   config.kda_conv)
     inner = H * d
     ks = jax.random.split(key, 15)
-
-    def norm(key, shape, s=0.02):
-        return jax.random.normal(key, (n, *shape)) * s
+    norm = partial(stacked_normal, n)
 
     dt = jnp.exp(jax.random.uniform(ks[0], (n, inner)) * (
         math.log(config.time_step_max) - math.log(config.time_step_min))
@@ -91,7 +91,7 @@ def init_params(config, key, n: int, out_std: float) -> Dict[str, Any]:
     return params
 
 
-def logical_axes() -> Dict[str, Any]:
+def logical_axes(config) -> Dict[str, Any]:
     """Of the stacked leaves: the head-wide projections cut as attention's
     are (`embed` over `fsdp`, the heads over `tensor`), the low-rank halves
     that every head reads and the vectors whole."""
@@ -109,7 +109,7 @@ def logical_axes() -> Dict[str, Any]:
     return axes
 
 
-def matmul_params(config) -> int:
+def matmul_params(config, routed: float) -> int:
     """The matrix entries of one mixer that a position meets."""
     D, H, d = config.d_model, config.kda_heads, config.kda_head_dim
     return 4 * D * H * d + 2 * (D * d + d * H * d) + D * H
@@ -118,11 +118,11 @@ def matmul_params(config) -> int:
 def num_params(config) -> int:
     """Of one mixer, its pre-norm included."""
     H, d = config.kda_heads, config.kda_head_dim
-    return (matmul_params(config) + 3 * config.kda_conv * H * d
+    return (matmul_params(config, 0) + 3 * config.kda_conv * H * d
             + 2 * H * d + H + d + config.d_model)
 
 
-def scan_flops(config, seq_len: int) -> float:
+def mixer_flops(config, seq_len: int) -> float:
     """Forward FLOPs a position of ``ops/kda.py``'s products: ``A`` and ``B``
     at the causal half, ``T [V | Kbar]`` at the triangular half, ``B U``, and
     the three d x d products with the state."""
@@ -131,19 +131,30 @@ def scan_flops(config, seq_len: int) -> float:
     return 2.0 * H * (2.5 * C * d + 3 * d * d)
 
 
-def working_bytes(config, seq_len: int, itemsize: int) -> int:
-    """What one position of a running layer holds, for ``hybrid._layer_sizes``'
-    bound on a step's temporaries: eight arrays as wide as the heads (q, k,
-    v, o, the two gates, the decay's log and its cumulative sum in float32
-    counted twice) and, a head, what ``ops/kda.py`` builds: the explicit
-    decays inside a sub-chunk (SUB x d float32, two copies live), the keys
-    scaled for each later sub-chunk, five more scaled copies of q and k, and
-    the chunk's three (chunk x chunk) matrices."""
+def layer_bytes(config, tokens: int, seq_len: int, tensor: int,
+                itemsize: int):
+    """For ``hybrid._layer_sizes``, a chip's bytes of one layer over
+    ``tokens`` positions with the heads cut ``tensor`` ways: (its working
+    set; nothing kept for the backward beside its input; no candidate of the
+    ladder: what the scan keeps is bounded by the layer's own pass).  The
+    working set a position: eight arrays as wide as the heads (q, k, v, o,
+    the two gates, the decay's log and its cumulative sum in float32 counted
+    twice) and, a head, what ``ops/kda.py`` builds: the explicit decays
+    inside a sub-chunk (SUB x d float32, two copies live), the keys scaled
+    for each later sub-chunk, five more scaled copies of q and k, and the
+    chunk's three (chunk x chunk) matrices."""
     d, chunk = config.kda_head_dim, min(config.kda_chunk, seq_len)
     sub = min(SUB, chunk)
-    return config.kda_heads * (
+    return (tokens * (config.kda_heads * (
         d * (8 * itemsize + 2 * 4) + 2 * sub * d * 4
-        + (chunk // sub + 5) * d * itemsize + 3 * chunk * (4 + itemsize))
+        + (chunk // sub + 5) * d * itemsize + 3 * chunk * (4 + itemsize)))
+        // tensor, 0, {})
+
+
+def first_call_facts(config, rows: int, seq_len: int) -> Dict[str, Any]:
+    chunk = min(config.kda_chunk, seq_len)
+    return {"kda_heads": config.kda_heads, "kda_head_dim": config.kda_head_dim,
+            "kda_chunk": chunk, "kda_chunks": rows * seq_len // chunk}
 
 
 def l2norm(x):
@@ -183,3 +194,9 @@ def mixer(x, blk, config, axes):
         y = rmsnorm(o, blk["head_norm"], config.rms_eps).reshape(B, S, H * d)
         y = (y * jax.nn.sigmoid(gate)).astype(dt)
         return x + dense(y, blk, "wo", axes, dt)
+
+
+def layer(config, axes, index: int):
+    """Layer ``index`` of the kind as (x, its row of the stack) -> (x,
+    None)."""
+    return lambda x, blk: (mixer(x, blk, config, axes), None)

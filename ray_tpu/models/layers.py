@@ -32,6 +32,11 @@ def mesh_axes(logical):
                  for a in DEFAULT_RULES.get(name) or ())
 
 
+def stacked_normal(n: int, key, shape, std: float = 0.02):
+    """``n`` matrices of ``shape`` on a leading axis, normal(``std``)."""
+    return jax.random.normal(key, (n, *shape), jnp.float32) * std
+
+
 def rmsnorm(x, scale, eps):
     x32 = x.astype(jnp.float32)
     ms = jnp.mean(x32 * x32, axis=-1, keepdims=True)

@@ -75,7 +75,7 @@ from ray_tpu.ops import remat
 from ray_tpu.ops.lm_head import lm_head_cross_entropy
 from ray_tpu.parallel.train_state import make_optimizer  # noqa: F401
 from ray_tpu.parallel.train_state import make_train_step as _make_train_step
-from ray_tpu.parallel.train_state import note_first_call
+from ray_tpu.util import first_call
 
 
 @dataclass(frozen=True)
@@ -406,7 +406,7 @@ def loss_and_counters(params, tokens, targets, config: LlamaConfig):
         tokens, weights = block_diffusion.noise(
             tokens, config.noise_seed, config.block_length,
             config.mask_token_id)
-    note_first_call(experts_held=len(config.held),
+    first_call.note(experts_held=len(config.held),
                     experts_total=config.n_experts,
                     block_length=config.block_length,
                     attn_positions=tokens.shape[1],

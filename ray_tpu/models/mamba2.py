@@ -1,6 +1,7 @@
 """The Mamba-2 mixer: the layer of a hybrid decoder (``models/hybrid.py``)
 whose token mixing is a state-space recurrence (``ops/ssd.py``) and not
-attention.  Not a model; the file is the mixer, its parameters and its sizes.
+attention (kind ``M``).  Not a model; the file is the mixer, its parameters
+and its sizes, with the interface ``hybrid.KINDS`` asks of a kind.
 
 Per layer, on ``u = norm(x)`` (H heads of P channels, G groups that share B
 and C, a state of N; ``d_inner = H x P``):
@@ -81,7 +82,7 @@ def init_params(config, key, n: int, out_std: float) -> Dict[str, Any]:
     }
 
 
-def logical_axes() -> Dict[str, Any]:
+def logical_axes(config) -> Dict[str, Any]:
     """Of the stacked leaves: the projections cut as an MLP's are (`embed`
     over `fsdp`, the inner width over `tensor`), the vectors whole."""
     L = "layers"
@@ -98,12 +99,60 @@ def logical_axes() -> Dict[str, Any]:
     }
 
 
+def matmul_params(config, routed: float) -> int:
+    """The matrix entries of one mixer that a position meets."""
+    w = widths(config)
+    return config.d_model * w["in_proj"] + w["inner"] * config.d_model
+
+
 def num_params(config) -> int:
     """Of one mixer, its pre-norm included."""
     w = widths(config)
     return (config.d_model * w["in_proj"] + (config.ssm_conv + 1) * w["conv"]
             + 3 * config.ssm_heads + w["inner"] + w["inner"] * config.d_model
             + config.d_model)
+
+
+def mixer_flops(config, seq_len: int) -> float:
+    """Forward FLOPs a position of the scan's four products a chunk
+    (``ops/ssd.py``): a position's share of C B^T a group (Q x Q x N) and
+    (L o C B^T)(delta x) a head (Q x Q x P), both at the causal half; the
+    chunk's state and C times the incoming state a head (Q x P x N each)."""
+    Q = min(config.ssm_chunk, seq_len)
+    H, P, G, N = (config.ssm_heads, config.ssm_head_dim, config.ssm_groups,
+                  config.ssm_state)
+    return 2.0 * (G * Q * N / 2 + H * Q * P / 2 + 2 * H * P * N)
+
+
+def layer_bytes(config, tokens: int, seq_len: int, tensor: int,
+                itemsize: int):
+    """For ``hybrid._layer_sizes``, a chip's bytes of one layer over
+    ``tokens`` positions with the inner width cut ``tensor`` ways: (its
+    working set; nothing kept for the backward beside its input; no
+    candidate of the ladder: where ``ops/ssd.py`` takes its kernels the scan
+    keeps its inputs and each chunk's incoming state, 16 KiB a token at 64
+    heads of 64 over a state of 128 in chunks of 128, from the layer's
+    second forward to its backward; the XLA form's intermediates are the
+    (chunk x chunk) decays, 32 KiB a token a float32 copy: neither is worth
+    a rung).  The working set: six arrays as wide as ``in_proj``'s output
+    and, a head and a chunk position, the scan's (chunk x chunk) decays, two
+    float32 and a compute-dtype copy each way (the compiler fuses the rest
+    of them away; **where the scan runs as ``ops/ssd_kernel.py``'s kernels
+    they never reach HBM and the term overstates the layer by what it
+    counts, 2.7 GB in the benchmark's cell, against 0.27 GB of boundary
+    states: left as it is by PR 44, since the bound it feeds decides what
+    ``ops/remat.py`` keeps and a change there is ROADMAP C15's**)."""
+    return (tokens * (6 * widths(config)["in_proj"] // tensor * itemsize
+                      + config.ssm_heads // tensor
+                      * min(config.ssm_chunk, seq_len)
+                      * 2 * (2 * 4 + itemsize)),
+            0, {})
+
+
+def first_call_facts(config, rows: int, seq_len: int) -> Dict[str, Any]:
+    chunk = min(config.ssm_chunk, seq_len)
+    return {"ssm_heads": config.ssm_heads, "ssm_state": config.ssm_state,
+            "ssm_chunk": chunk, "ssm_chunks": rows * seq_len // chunk}
 
 
 def _taps(x, w, back: bool):
@@ -184,3 +233,9 @@ def mixer(x, blk, config, axes):
         y = gated_norm(y.reshape(B, S, w["inner"]), z, blk["gate_norm"], G,
                        config.gate_norm_eps).astype(dt)
         return x + dense(y, blk, "out_proj", axes, dt)
+
+
+def layer(config, axes, index: int):
+    """Layer ``index`` of the kind as (x, its row of the stack) -> (x,
+    None)."""
+    return lambda x, blk: (mixer(x, blk, config, axes), None)

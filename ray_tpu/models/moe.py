@@ -81,7 +81,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
-from ray_tpu.ops import remat
+from ray_tpu.ops import placement, remat
 from ray_tpu.ops.grouped_matmul import grouped_matmul
 from ray_tpu.util.tracing import step_counter
 
@@ -416,12 +416,10 @@ def moe_mlp(h32, blk, *, experts_per_token: int, norm_topk_prob: bool, dtype,
     a mesh: ``moe_rows``, the pairs that reached each held expert, (shards,
     H) int32, and, where the layer holds a share, ``moe_moved``, the rows
     it moved, (shards,) int32."""
-    mesh = jax.sharding.get_abstract_mesh()
-    sharded = not (mesh.empty or mesh.size == 1)
-    batch_axes = tuple(a for a in ("data", "fsdp")
-                       if sharded and a in mesh.axis_names)
+    batch_axes, _ = placement.axes(jax.sharding.get_abstract_mesh())
     names = ("w_gate", "w_up", "w_down") if "w_gate" in blk \
         else ("w_up", "w_down")
+    share = blk["w_up"].shape[0] < blk["router"].shape[-1]
 
     def local(h32, router, *matrices):
         tokens = h32.reshape(-1, h32.shape[-1])
@@ -438,22 +436,13 @@ def moe_mlp(h32, blk, *, experts_per_token: int, norm_topk_prob: bool, dtype,
             counts[step_counter("moe_moved")] = moved[None]
         return y.reshape(h32.shape), losses, counts
 
-    args = (h32, blk["router"], *(blk[name].astype(dtype) for name in names))
-    if not sharded:
-        out = local(*args)
-    else:
-        P = jax.sharding.PartitionSpec
-        rows = P(batch_axes or None, None, None)
-        share = blk["w_up"].shape[0] < blk["router"].shape[-1]
-        counts = ("moe_rows", "moe_moved") if share else ("moe_rows",)
-        # check_vma off as for splash: a pallas_call declares no vma on its
-        # outputs.  Axes a spec does not name (the weights' every axis) see
-        # whole arrays.
-        out = jax.shard_map(
-            local, in_specs=(rows, P(), *(P() for _ in names)),
-            out_specs=(rows, (P(), P()), dict.fromkeys(
-                counts, P(batch_axes or None))),
-            check_vma=False)(*args)
+    # the rows cut, the router and the weights whole on every chip
+    out = placement.place(
+        local,
+        (h32, blk["router"], *(blk[name].astype(dtype) for name in names)),
+        ("r", "") + ("",) * len(names),
+        ("r", ("", ""), dict.fromkeys(
+            ("moe_rows", "moe_moved") if share else ("moe_rows",), "r")))
     if "shared_up" not in blk:
         return out
     y, losses, counts = out

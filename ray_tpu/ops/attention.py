@@ -63,6 +63,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu.ops.placement import place
+from ray_tpu.util import first_call
+
 ATTN_IMPLS = ("auto", "xla", "splash", "ring", "ulysses")
 
 #: The name the kernel's forward gives (``jax.ad_checkpoint.checkpoint_name``)
@@ -379,10 +382,7 @@ def splash_attention(q, k, v, causal: bool = True,
     def local(q, k, v):
         kernel, counts = _splash_kernel(S, q.shape[2], hd, causal,
                                         block_length)
-        # here, not at the top: ``parallel/train_state.py`` imports ``ops``
-        from ray_tpu.parallel.train_state import note_first_call
-
-        note_first_call(**counts)
+        first_call.note(**counts)
         # Splash takes (H, S, hd) per example; scale q up front (no scale arg).
         qt = (q * sm_scale).transpose(0, 2, 1, 3)
         kt = k.transpose(0, 2, 1, 3)
@@ -390,20 +390,11 @@ def splash_attention(q, k, v, causal: bool = True,
         return jax.vmap(kernel)(qt, kt, vt).transpose(0, 2, 1, 3)
 
     mesh = jax.sharding.get_abstract_mesh()
-    if mesh.empty or mesh.size == 1:
-        return local(q, k, v)
-    KV, tensor = k.shape[2], mesh.shape.get("tensor", 1)
+    KV = k.shape[2]
+    tensor = 1 if mesh.empty else mesh.shape.get("tensor", 1)
     if H % tensor or KV % tensor:
         raise ValueError(
             f"splash_attention: the mesh's tensor axis ({tensor}) must divide "
             f"the {KV} K/V heads as it must divide the {H} query heads: a "
             "chip's query heads read only the K/V heads it holds")
-    batch_axes = tuple(a for a in ("data", "fsdp") if a in mesh.axis_names)
-    spec = jax.sharding.PartitionSpec(
-        batch_axes or None, None,
-        "tensor" if "tensor" in mesh.axis_names else None, None)
-    # check_vma off: the splash pallas_call does not declare vma on its
-    # output avals, which the vma checker rejects.
-    return jax.shard_map(local, in_specs=(spec, spec, spec), out_specs=spec,
-                         check_vma=False)(q, k, v)
-
+    return place(local, (q, k, v), ("rh", "rh", "rh"), "rh")

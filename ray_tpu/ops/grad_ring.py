@@ -50,24 +50,23 @@ default rules put them (``embed`` over ``fsdp``, the batch over ``data`` then
 ``fsdp``), as ``ops/remat.py`` assumes.  Under other rules the ``shard_map``
 reshards its operands and its result: the answer is the same, only slower.
 
-**What it records.**  Every ``dW`` traced through the ring is counted for
-whoever is :func:`recording` on the thread (``TrainStep`` puts
-``grad_ring_products`` and ``grad_ring_axis`` on the ``train.first_call``
-span and first-call record; both 0 where the ring is not engaged).
+**What it notes.**  Every ``dW`` traced through the ring is counted for the
+first-call record (``util/first_call.py``: ``grad_ring_products`` and
+``grad_ring_axis``; ``TrainStep`` starts both at 0, :data:`NO_RINGS`, which
+is what they stay where the ring is not engaged).
 """
 
 from __future__ import annotations
 
-import contextlib
-import dataclasses
-import threading
 from functools import partial
-from typing import Dict, Iterator, List, Optional, Sequence, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+
+from ray_tpu.util import first_call
 
 AXIS = "fsdp"
 #: the mesh axes the batch is cut over, outermost first
@@ -116,33 +115,9 @@ def snake(coords: Sequence[Tuple[int, ...]]) -> List[int]:
     return sorted(range(len(coords)), key=lambda i: key(coords[i]))
 
 
-@dataclasses.dataclass
-class Traced:
-    """What :func:`recording` saw: how many weight gradients were traced as
-    rings (a scanned layer's are traced once, whatever the depth) and the
-    size of the axis they run over; 0 and 0 where none was."""
-    products: int = 0
-    axis: int = 0
-
-    def attributes(self) -> Dict[str, int]:
-        """As the ``train.first_call`` span and record carry it."""
-        return {"grad_ring_products": self.products,
-                "grad_ring_axis": self.axis}
-
-
-_thread = threading.local()  # .recordings: the calling thread's open records
-
-
-@contextlib.contextmanager
-def recording() -> Iterator[Traced]:
-    """Counts the rings traced on this thread while the block runs."""
-    seen = Traced()
-    stack: List[Traced] = _thread.__dict__.setdefault("recordings", [])
-    stack.append(seen)
-    try:
-        yield seen
-    finally:
-        stack.pop()  # blocks nest: this one's record is the last
+#: the first-call record of a step in which no weight gradient was traced as
+#: a ring (a scanned layer's are traced once, whatever the depth)
+NO_RINGS = {"grad_ring_products": 0, "grad_ring_axis": 0}
 
 
 def _shards(batch: int) -> Optional[Tuple[int, int]]:
@@ -193,9 +168,8 @@ def _ring_dw(x, g, embed: int):
     axis ``embed``: (K, N_out) for ``x`` (batch, ..., K), ``g`` (batch, ...,
     N_out)."""
     data, ring = _shards(x.shape[0])
-    for seen in getattr(_thread, "recordings", ()):
-        seen.products += 1
-        seen.axis = ring
+    first_call.count("grad_ring_products")
+    first_call.note(grad_ring_axis=ring)
     # The batch lies data-major over (data, fsdp): give each its own
     # dimension, so that cutting the second over the ring moves nothing.
     x, g = (a.reshape(data, ring, -1, *a.shape[1:]) for a in (x, g))

@@ -92,9 +92,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.sharding import PartitionSpec
 
-from ray_tpu.ops.ssd import rows_and_heads
+from ray_tpu.ops.placement import place, rows_and_heads
+from ray_tpu.util import first_call
 
 #: positions of a sub-chunk: the explicit sum over channels is SUB x SUB x d
 #: a sub-chunk, so it grows with SUB, the forward substitution has SUB - 1
@@ -149,46 +149,28 @@ def kda(q, k, v, g, beta, chunk: int):
     if S % chunk or chunk & (chunk - 1):
         raise ValueError(f"kda: {S} positions in chunks of {chunk} (a power "
                          "of two that divides them)")
-    # here, not at the top: ``parallel/train_state.py`` imports ``ops``, and
-    # the kernels' module imports this one
-    from ray_tpu.parallel.train_state import note_first_call
-
-    taken, specs = path(q.shape, chunk, jax.sharding.get_abstract_mesh())
-    if taken == "xla":
-        note_first_call(kda_scan_kernel=False, kda_scan_grid=None)
+    if path(q.shape, chunk, jax.sharding.get_abstract_mesh()) == "xla":
+        first_call.note(kda_scan_kernel=False, kda_scan_grid=None)
         return kda_xla(q, k, v, g, beta, chunk)
-    from ray_tpu.ops import kda_kernel
+    from ray_tpu.ops import kda_kernel  # it imports this module
 
     def local(q, k, v, g, beta):
-        note_first_call(kda_scan_kernel=True,
+        first_call.note(kda_scan_kernel=True,
                         kda_scan_grid=list(kda_kernel.grid(q, chunk)))
         return kda_kernel.scan(q, k, v, g, beta, chunk)
 
-    if specs is None:
-        return local(q, k, v, g, beta)
-    # check_vma off as around the splash call: a pallas_call declares no
-    # vma on its outputs
-    return jax.shard_map(local, in_specs=specs, out_specs=specs[0],
-                         check_vma=False)(q, k, v, g, beta)
+    return place(local, (q, k, v, g, beta), ("rh",) * 5, "rh")
 
 
-def path(q_shape, chunk: int, mesh):
-    """-> (``"kernel"`` or ``"xla"``, the ``shard_map`` specs of the five
-    arguments or None where the kernels run unwrapped): which implementation
-    a call of these shapes takes under ``mesh`` (the module's docstring has
-    the rule)."""
+def path(q_shape, chunk: int, mesh) -> str:
+    """-> ``"kernel"`` or ``"xla"``: which implementation a call of these
+    shapes takes under ``mesh`` (the module's docstring has the rule; the
+    mesh's half of it is ``ops.placement.rows_and_heads``)."""
     b, _, H, d = q_shape
-    if d % 128 or chunk % 16 or chunk > 128:
-        return "xla", None
-    if mesh.empty or mesh.size == 1:
-        return "kernel", None
-    cut = rows_and_heads(mesh, b, H)
-    if cut is None:
-        return "xla", None  # positions or nothing it knows is cut
-    rows, heads = cut
-    wide = PartitionSpec(rows, None, heads, None)
-    return "kernel", (wide, wide, wide, wide,
-                      PartitionSpec(rows, None, heads))
+    if d % 128 == 0 and chunk % 16 == 0 and chunk <= 128 \
+            and rows_and_heads(mesh, b, H) is not None:
+        return "kernel"
+    return "xla"  # the sizes; or positions, or nothing it knows, are cut
 
 
 def kda_xla(q, k, v, g, beta, chunk: int):

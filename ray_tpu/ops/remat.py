@@ -55,20 +55,19 @@ compiler, which every process gets alike.
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import hashlib
 import logging
 import math
 import threading
-from typing import Dict, Iterator, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 
 from ray_tpu._private.config import GLOBAL_CONFIG
 from ray_tpu.collective.dcn_group import kv_client, multiprocess_world
 from ray_tpu.ops.attention import SPLASH_RESIDUALS
-from ray_tpu.util import metrics
+from ray_tpu.util import first_call, metrics
 
 logger = logging.getLogger(__name__)
 
@@ -113,7 +112,7 @@ class Decision:
     processes: int = 1
 
     def attributes(self) -> Dict[str, object]:
-        """As the ``train.first_call`` span and record carry it."""
+        """What :func:`decide` notes of it (``util/first_call.py``)."""
         return {"remat_kept": list(self.kept),
                 "remat_kept_bytes": self.kept_bytes,
                 "remat_room_bytes": self.room_bytes}
@@ -209,7 +208,6 @@ _lock = threading.Lock()
 _plain_only = False  # guarded_by: _lock
 #: how often each question was put to the peers (:func:`every_process`)
 _asked: Dict[str, int] = {}  # guarded_by: _lock
-_thread = threading.local()  # .recordings: the calling thread's open lists
 
 
 def every_process(report: Optional[Tuple[int, int]], question: str
@@ -242,26 +240,36 @@ def every_process(report: Optional[Tuple[int, int]], question: str
     return reports
 
 
+def tracing_processes(mesh) -> int:
+    """How many processes trace a step under ``mesh`` as one program: this
+    one alone unless the job has several and the mesh is larger than the
+    process's own devices (or there is none to say whose program it is)."""
+    world = multiprocess_world()
+    if world <= 1 or (not mesh.empty
+                      and mesh.size <= jax.local_device_count()):
+        return 1
+    return world
+
+
 def _job_memory(mesh, question_parts) -> Tuple[Optional[Tuple[int, int]], int]:
     """(the fullest chip of every process that will run the step being
     traced under ``mesh``, how many processes that is)."""
-    memory, world = device_memory(), multiprocess_world()
-    if world <= 1 or (not mesh.empty
-                      and mesh.size <= jax.local_device_count()):
+    memory, processes = device_memory(), tracing_processes(mesh)
+    if processes == 1:
         return memory, 1
     if mesh.empty:  # whose program this is, nothing here can tell
-        return None, world
+        return None, processes
     question = hashlib.sha1(repr(question_parts).encode()).hexdigest()[:16]
-    return fullest(every_process(memory, question)), world
+    return fullest(every_process(memory, question)), processes
 
 
-def layer_policy(candidates: Sequence[Tuple[str, int]], temporaries: int):
-    """The checkpoint policy of a layer whose named intermediates would take
+def decide(candidates: Sequence[Tuple[str, int]],
+           temporaries: int) -> Decision:
+    """:func:`choose` for a layer whose named intermediates would take
     ``candidates`` (name, bytes a chip over all layers, ladder order), in a
-    step that needs ``temporaries`` without them.  Reads the devices (of
-    every process under a mesh that spans several), hands the decision to
-    whoever is :func:`recording` on this thread and returns
-    ``save_only_these_names(splash residuals, *kept)``."""
+    step that needs ``temporaries`` without them, from what the devices
+    report (those of every process under a mesh that spans several).  Notes
+    the decision for the first-call record."""
     mesh = jax.sharding.get_abstract_mesh()
     with _lock:
         plain_only = _plain_only
@@ -270,31 +278,23 @@ def layer_policy(candidates: Sequence[Tuple[str, int]], temporaries: int):
     decision = Decision(processes=processes) if memory is None else \
         dataclasses.replace(choose(*memory, candidates, temporaries),
                             processes=processes)
-    for seen in getattr(_thread, "recordings", ()):
-        seen.append(decision)
+    first_call.note(**decision.attributes())
     logger.info("remat: keeping %s for the backward (%d bytes a chip; room "
                 "%s; device memory %s; %d process(es))",
                 decision.kept or "nothing more", decision.kept_bytes,
                 decision.room_bytes, memory, processes)
+    return decision
+
+
+def layer_policy(candidates: Sequence[Tuple[str, int]], temporaries: int):
+    """The checkpoint policy of such a layer:
+    ``save_only_these_names(splash residuals, *what decide keeps)``."""
     return jax.checkpoint_policies.save_only_these_names(
-        SPLASH_RESIDUALS, *decision.kept)
+        SPLASH_RESIDUALS, *decide(candidates, temporaries).kept)
 
 
-@contextlib.contextmanager
-def recording() -> Iterator[List[Decision]]:
-    """The decisions the rule takes on this thread while the block runs, in
-    order: empty unless something in it traced a model."""
-    seen: List[Decision] = []
-    stack = _thread.__dict__.setdefault("recordings", [])
-    stack.append(seen)
-    try:
-        yield seen
-    finally:
-        stack.pop()  # blocks nest: this one's list is the last
-
-
-def fall_back(refused: Decision, reason: str) -> None:
-    """A program that kept ``refused.kept`` was refused for memory: from here
+def fall_back(kept: Sequence[str], reason: str) -> None:
+    """A program that kept ``kept`` was refused for memory: from here
     on this process gets the plain policy, so a second trace of the same
     step builds what the first ended up running."""
     global _plain_only
@@ -304,4 +304,4 @@ def fall_back(refused: Decision, reason: str) -> None:
     logger.warning(
         "remat: the step that kept %s for the backward was refused for "
         "memory (%s); rebuilding it under the plain policy, which this "
-        "process keeps from here on", ", ".join(refused.kept), reason)
+        "process keeps from here on", ", ".join(kept), reason)

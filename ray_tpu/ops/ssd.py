@@ -65,9 +65,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.sharding import PartitionSpec
 
 from ray_tpu.ops import ssd_kernel
+from ray_tpu.ops.placement import place, rows_and_heads
+from ray_tpu.util import first_call
 
 
 def ssd(x, delta, A, B, C, D, chunk: int):
@@ -80,60 +81,30 @@ def ssd(x, delta, A, B, C, D, chunk: int):
     if S % chunk or H % G:
         raise ValueError(f"ssd: {S} positions in chunks of {chunk}, {H} "
                          f"heads in {G} groups")
-    # here, not at the top: ``parallel/train_state.py`` imports ``ops``
-    from ray_tpu.parallel.train_state import note_first_call
-
-    mesh = jax.sharding.get_abstract_mesh()
-    taken, specs = path(x.shape, B.shape, chunk, mesh)
-    if taken == "xla":
-        note_first_call(ssm_scan_kernel=False, ssm_scan_grid=None)
+    if path(x.shape, B.shape, chunk,
+            jax.sharding.get_abstract_mesh()) == "xla":
+        first_call.note(ssm_scan_kernel=False, ssm_scan_grid=None)
         return ssd_xla(x, delta, A, B, C, D, chunk)
 
     def local(x, delta, A, B, C, D):
-        note_first_call(ssm_scan_kernel=True,
+        first_call.note(ssm_scan_kernel=True,
                         ssm_scan_grid=list(ssd_kernel.grid(x, B, chunk)))
         return ssd_kernel.scan(x, delta, A, B, C, D, chunk, True)
 
-    if specs is None:
-        return local(x, delta, A, B, C, D)
-    # check_vma off as around the splash call: a pallas_call declares no
-    # vma on its outputs
-    return jax.shard_map(local, in_specs=specs, out_specs=specs[0],
-                         check_vma=False)(x, delta, A, B, C, D)
+    return place(local, (x, delta, A, B, C, D),
+                 ("rh", "rh", "h", "rh", "rh", "h"), "rh")
 
 
-def path(x_shape, B_shape, chunk: int, mesh):
-    """-> (``"kernel"`` or ``"xla"``, the ``shard_map`` specs of the six
-    arguments or None where the kernels run unwrapped): which implementation
-    a call of these shapes takes under ``mesh`` (the module's docstring has
-    the rule)."""
+def path(x_shape, B_shape, chunk: int, mesh) -> str:
+    """-> ``"kernel"`` or ``"xla"``: which implementation a call of these
+    shapes takes under ``mesh`` (the module's docstring has the rule; the
+    mesh's half of it is ``ops.placement.rows_and_heads``)."""
     b, _, H, P = x_shape
     G, N = B_shape[2:]
-    if not ssd_kernel.tiles(chunk, H // G, P, N):
-        return "xla", None
-    if mesh.empty or mesh.size == 1:
-        return "kernel", None
-    cut = rows_and_heads(mesh, b, G)
-    if cut is None:
-        return "xla", None  # positions or nothing it knows is cut
-    rows, heads = cut
-    wide = PartitionSpec(rows, None, heads, None)
-    return "kernel", (wide, PartitionSpec(rows, None, heads),
-                      PartitionSpec(heads), wide, wide, PartitionSpec(heads))
-
-
-def rows_and_heads(mesh, rows: int, heads: int):
-    """-> (the axes of ``mesh`` that cut a call's rows, `data` and `fsdp`;
-    the one that cuts its heads or groups, `tensor`; None for none) where
-    those are all its axes and divide ``rows`` and ``heads``, so that every
-    device has rows and heads of its own to scan; else None.  ``ops/kda.py``
-    places its kernels by the same rule."""
-    over = tuple(a for a in ("data", "fsdp") if a in mesh.axis_names)
-    tensor = mesh.shape.get("tensor", 1)
-    cut = int(np.prod([mesh.shape[a] for a in over])) * tensor
-    if cut != mesh.size or rows % (cut // tensor) or heads % tensor:
-        return None
-    return over or None, "tensor" if "tensor" in mesh.axis_names else None
+    if ssd_kernel.tiles(chunk, H // G, P, N) \
+            and rows_and_heads(mesh, b, G) is not None:
+        return "kernel"
+    return "xla"  # the sizes; or positions, or nothing it knows, are cut
 
 
 def ssd_xla(x, delta, A, B, C, D, chunk: int):

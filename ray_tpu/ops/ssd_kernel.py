@@ -50,7 +50,7 @@ and (8, J x P) partial sums of ``dy x`` a (row, group) for dD; a few small
 XLA operations sum the sum's cotangent back over each chunk and finish
 d delta, dA and dD.
 
-Not a TPU: Pallas' interpret mode (``ops/fused_ce.py`` does the same).
+Not a TPU: Pallas' interpret mode.
 """
 
 from __future__ import annotations

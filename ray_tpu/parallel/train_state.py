@@ -28,7 +28,6 @@ import contextlib
 import functools
 import re
 import sys
-import threading
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -39,7 +38,7 @@ import numpy as np
 
 from ray_tpu.ops import grad_ring, remat
 from ray_tpu.parallel.mesh import pytree_sharding
-from ray_tpu.util import device_telemetry, tracing
+from ray_tpu.util import device_telemetry, first_call, tracing
 
 
 def create_sharded_state(
@@ -206,30 +205,10 @@ def _addressable(mesh) -> bool:
     return bool(set(mesh.local_devices) & set(jax.local_devices()))
 
 
-_thread = threading.local()  # .notes: the dicts of the calls traced in here
-
-
-def note_first_call(**facts) -> None:
-    """From inside a traced step: ``facts`` about the model being traced,
-    for the ``train.first_call`` span and first-call record of whichever
-    :class:`TrainStep` call is tracing on this thread (``models/llama.py``:
-    the experts held, the block length, how many positions the layers and
-    the loss see; ``ops/attention.py``: the splash calls that cover a layer's
-    mask, their blocks, block sizes and grid steps).  Outside such a call,
-    nothing."""
-    for notes in getattr(_thread, "notes", ()):
-        notes.update(facts)
-
-
-@contextlib.contextmanager
-def _noting():
-    notes: Dict[str, Any] = {}
-    stack = _thread.__dict__.setdefault("notes", [])
-    stack.append(notes)
-    try:
-        yield notes
-    finally:
-        stack.pop()
+def _first_call_notes():
+    """The block around a :class:`TrainStep`'s call: what the traced code
+    notes (``util/first_call.py``), beginning at no fallback and no ring."""
+    return first_call.noting(remat_fallback=False, **grad_ring.NO_RINGS)
 
 
 def jit_train_step(step_fn, donate_state: bool = True, mesh=None):
@@ -263,19 +242,9 @@ class TrainStep:
     The signature is taken only when a compile event fired, before the
     arguments are donated; the first one is kept for :meth:`anatomy`.
     A call that compiled is a ``train.first_call`` span and a first-call
-    record of the registry; both say what the model's layers keep for the
-    backward there (``remat_kept``, ``remat_kept_bytes``, ``remat_room_bytes``:
-    ``ops/remat.py`` decides it while the step is traced) and how many weight
-    gradients were traced as rings over `fsdp` (``grad_ring_products``,
-    ``grad_ring_axis``: ``ops/grad_ring.py``; 0 and 0 on one chip), and what
-    the traced code said of itself through :func:`note_first_call`
-    (``models/llama.py``: ``experts_held``, ``experts_total``,
-    ``block_length``, ``attn_positions``, ``loss_positions``;
-    ``ops/attention.py``, where the splash kernel runs, how its calls cover a
-    layer's mask, a head: ``attn_calls``, ``attn_blocks``,
-    ``attn_blocks_cut``, ``attn_grid_steps_fwd``, ``attn_grid_steps_bwd``,
-    and in what blocks: ``attn_block_q``, ``attn_block_kv``,
-    ``attn_block_q_bwd``, ``attn_block_kv_bwd``, ``attn_dq_partials``).
+    record of the registry; both carry what the traced code said of itself
+    while this call traced it (``util/first_call.py`` lists the keys and who
+    notes each) and ``remat_fallback``.
 
     A step that was traced in this call, keeps more than the plain policy
     would and is refused for memory (``RESOURCE_EXHAUSTED``, at compile or
@@ -310,17 +279,16 @@ class TrainStep:
         t0 = time.perf_counter()
         label = device_telemetry.compile_label(
             self.label, lambda: self._sign(args, kwargs))
-        fell_back = False
         with tracing.annotate("train.dispatch"), label, self._in_mesh(), \
-                remat.recording() as decided, \
-                grad_ring.recording() as rings, _noting() as notes:
+                _first_call_notes() as notes:
             try:
                 out = self._jitted(*args, **kwargs)
             except RuntimeError as e:  # XLA's; re-raised unless remat's
-                if not self._refused_for_remat(e, decided, args, kwargs):
+                kept = notes.get("remat_kept")
+                if not self._refused_for_remat(e, kept, args, kwargs):
                     raise
-                remat.fall_back(decided[-1], str(e).splitlines()[0][:200])
-                fell_back = True
+                remat.fall_back(kept, str(e).splitlines()[0][:200])
+                notes["remat_fallback"] = True
                 # a new function object: jax keeps the refused program's
                 # trace under the old one and would hand it back
                 self._jitted = jax.jit(functools.partial(self._step_fn),
@@ -335,15 +303,12 @@ class TrainStep:
             profiler.count("hand_over", time.perf_counter() - t0 - seconds)
         if label.compiles:
             end = time.time()
-            attributes = dict(decided[-1].attributes() if decided else {},
-                              remat_fallback=fell_back, **rings.attributes(),
-                              **notes)
             device_telemetry.record_first_call(self.label, seconds, ts=end,
-                                               **attributes)
+                                               **notes)
             tracing.record_span(
                 "train.first_call", end - seconds, end,
                 attributes={"label": self.label,
-                            "compile_s": label.compile_s, **attributes})
+                            "compile_s": label.compile_s, **notes})
         return out
 
     def _hand_over(self, profiler, out) -> None:
@@ -360,22 +325,21 @@ class TrainStep:
         if fresh:
             profiler.dispatched(fresh[-1], counters)
 
-    def _refused_for_remat(self, error, decided, args, kwargs) -> bool:
+    def _refused_for_remat(self, error, kept, args, kwargs) -> bool:
         """Whether ``error`` is the memory's refusal of a program that this
-        call traced with more kept than the plain policy keeps (``decided``:
-        what the rule said while it traced), and the (donated) arguments are
-        still whole for a second try.  A step of several processes is rebuilt
+        call traced with more kept than the plain policy keeps (``kept``:
+        the rungs the rule noted while it traced), and the (donated)
+        arguments are still whole for a second try.  A step of several processes is rebuilt
         only if the compiler refused it, which every process sees alike (the
         compile is asked once more, alone, to tell): a process that ran out
         of memory on its own while its peers launched the program cannot
         leave them, and the error stands."""
-        if not ("RESOURCE_EXHAUSTED" in str(error)
-                and decided and decided[-1].kept
+        if not ("RESOURCE_EXHAUSTED" in str(error) and kept
                 and not any(leaf.is_deleted()
                             for leaf in jax.tree.leaves((args, kwargs))
                             if isinstance(leaf, jax.Array))):
             return False
-        if decided[-1].processes == 1:
+        if remat.tracing_processes(jax.sharding.get_abstract_mesh()) == 1:
             return True
         try:
             self._jitted.lower(*args, **kwargs).compile()

@@ -29,19 +29,12 @@ one (no times); ``--tiny`` is the rehearsal on the CPU in interpret mode."""
 
 from __future__ import annotations
 
-import argparse
 import functools
-import json
-import os
-import sys
-import time
-
-os.environ.setdefault("TPU_LOG_DIR", "disabled")
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import sweep_common as sweep
 from jax import lax
 from jax.experimental import pallas as pl
 
@@ -125,17 +118,10 @@ def variants(H: int):
     return out
 
 
-def pulled(run):
-    def both(inputs, dy):
-        y, pull = jax.vjp(run, *inputs)
-        return y, pull(dy)
-    return both
-
-
-def passes(fn, shape):
-    """name -> jitted f(inputs, dy).  q, k, v, g and dy come and o goes as
-    the mixer holds them, (rows, positions, width): the split into heads is
-    a reshape inside the program and costs no copy there."""
+def flat(fn, shape):
+    """``fn`` over q, k, v, g and o as the mixer holds them, (rows,
+    positions, width): the split into heads is a reshape inside the program
+    and costs no copy there."""
     b, S, H, d, chunk = shape
 
     def run(q, k, v, g, beta):
@@ -143,11 +129,7 @@ def passes(fn, shape):
         return fn(q.reshape(heads), k.reshape(heads), v.reshape(heads),
                   g.reshape(heads), beta, chunk).reshape(b, S, H * d)
 
-    return {
-        "fwd": jax.jit(lambda inputs, dy: run(*inputs)),
-        "fwd+bwd": jax.jit(pulled(run)),
-        "checkpoint fwd+bwd": jax.jit(pulled(jax.checkpoint(run))),
-    }
+    return run
 
 
 def stages(shape):
@@ -201,8 +183,7 @@ def inputs(shape, sharding=None):
     shapes = [wide, wide, wide, ((b, S, H * d), jnp.float32),
               ((b, S, H), jnp.float32), wide]
     if sharding is not None:
-        abstract = [jax.ShapeDtypeStruct(s, t, sharding=sharding)
-                    for s, t in shapes]
+        abstract = sweep.abstract(shapes, sharding)
         return tuple(abstract[:5]), abstract[5]
     k = jax.random.split(jax.random.key(45), 6)
     heads = (b, S, H, d)
@@ -226,45 +207,14 @@ def inputs(shape, sharding=None):
             flat(v, jnp.bfloat16), flat(g, jnp.float32), beta), dy
 
 
-def close(got, want):
-    """max |a - b| / max |b| over a pair of pytrees' leaves."""
-    return [float(jnp.max(jnp.abs(a.astype(jnp.float32)
-                                  - b.astype(jnp.float32)))
-                  / jnp.max(jnp.abs(b.astype(jnp.float32))))
-            for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want))]
-
-
 def main():
-    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--out", default="chiprun_out/kda_kernel_sweep.jsonl")
-    ap.add_argument("--calls", type=int, default=20)
-    ap.add_argument("--rounds", type=int, default=3)
-    ap.add_argument("--compile-only", action="store_true")
-    ap.add_argument("--tiny", action="store_true")
-    args = ap.parse_args()
-
-    shape, sharding = CELL, None
-    if args.tiny:
-        shape, args.calls, args.rounds = TINY, 1, 1
-    if args.compile_only:
-        from jax.experimental import topologies
-        from jax.sharding import SingleDeviceSharding
-
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-        sharding = SingleDeviceSharding(topo.devices[0])
-        jax.default_backend = lambda: "tpu"  # the kernels' interpret switch
-    elif not args.tiny and jax.default_backend() != "tpu":
-        sys.exit("kda_kernel_sweep: no TPU here (use --tiny or "
-                 "--compile-only): a CPU run gives no time")
-    device = jax.devices()[0]
-    print(f"[sweep] device {device.platform} {device.device_kind}, shape "
-          f"{shape}, {args.calls} calls x {args.rounds} rounds", flush=True)
+    args = sweep.arguments(__doc__, "kda_kernel_sweep")
+    shape = TINY if args.tiny else CELL
+    sharding = sweep.device(args, f"shape {shape}, ")
     xs, dy = inputs(shape, sharding)
-    os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
     runs = [(name, which, run, xs, dy)
             for name, fn in variants(shape[2]).items()
-            for which, run in passes(fn, shape).items()]
+            for which, run in sweep.passes(flat(fn, shape)).items()]
     if sharding is None:
         runs += [("stage", name, *rest) for name, rest in
                  stages(shape).items()]
@@ -275,33 +225,17 @@ def main():
     with open(args.out, "a") as out:
         for name, which, run, xs, dy in runs:
             row = {"variant": name, "pass": which, "shape": list(shape)}
-            t0 = time.perf_counter()
             try:
-                if sharding is not None:
-                    run.lower(xs, dy).compile()
-                    row["compile_s"] = round(time.perf_counter() - t0, 2)
-                else:
-                    got = jax.block_until_ready(run(xs, dy))
-                    row["first_call_s"] = round(time.perf_counter() - t0, 2)
-                    if name != "stage" and which == "fwd+bwd":
-                        if want is None:
-                            want = got
-                        row["against_xla"] = [round(e, 5)
-                                              for e in close(got, want)]
-                    rounds = []
-                    for _ in range(args.rounds):
-                        t0 = time.perf_counter()
-                        for _ in range(args.calls):
-                            got = run(xs, dy)
-                        jax.block_until_ready(got)
-                        rounds.append((time.perf_counter() - t0)
-                                      / args.calls * 1e3)
-                    row["ms"] = round(min(rounds), 4)
-                    row["ms_mean"] = round(sum(rounds) / len(rounds), 4)
+                got = sweep.timed(row, run, (xs, dy), args, sharding)
+                if got is not None and name != "stage" \
+                        and which == "fwd+bwd":
+                    if want is None:
+                        want = got
+                    row["against_xla"] = [round(e, 5)
+                                          for e in sweep.close(got, want)]
             except Exception as e:  # a variant the compiler refuses
                 row["refused"] = str(e)[-600:]
-            out.write(json.dumps(row) + "\n")
-            out.flush()
+            sweep.write(out, row)
             print(f"{name:72s} {which:20s} "
                   f"{row.get('ms', row.get('compile_s', -1)):9.3f} "
                   f"{row.get('ms_mean', 0):9.3f}  "

@@ -24,18 +24,11 @@ mode."""
 
 from __future__ import annotations
 
-import argparse
 import itertools
-import json
-import os
-import sys
-import time
-
-os.environ.setdefault("TPU_LOG_DIR", "disabled")
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import jax
 import jax.numpy as jnp
+import sweep_common as sweep
 
 from ray_tpu.ops import attention
 from ray_tpu.ops.attention import SplashBlocks
@@ -91,34 +84,15 @@ def measure(shape, blocks, backward: bool, args, topo_sharding=None):
         _, pull = jax.vjp(run, q, k, v)
         return pull(do)
 
-    q = jax.ShapeDtypeStruct((B, H, S, hd), jnp.bfloat16,
-                             sharding=topo_sharding)
-    kv = jax.ShapeDtypeStruct((B, KV, S, hd), jnp.bfloat16,
-                              sharding=topo_sharding)
-    abstract = (q, kv, kv, q)
-    fn = jax.jit(call)
-    try:
-        t0 = time.perf_counter()
-        if topo_sharding is not None:
-            fn.lower(*abstract).compile()
-            row["compile_s"] = round(time.perf_counter() - t0, 2)
-            return row
+    shapes = [((B, H, S, hd), jnp.bfloat16), ((B, KV, S, hd), jnp.bfloat16)]
+    q, kv = sweep.abstract(shapes, topo_sharding)
+    operands = (q, kv, kv, q)
+    if topo_sharding is None:
         keys = jax.random.split(jax.random.key(0), 4)
-        arrays = [jax.random.normal(k, a.shape, a.dtype)
-                  for k, a in zip(keys, abstract)]
-        jax.block_until_ready(fn(*arrays))
-        row["first_call_s"] = round(time.perf_counter() - t0, 2)
-        jax.block_until_ready(fn(*arrays))
-        rounds = []
-        for _ in range(args.rounds):
-            t0 = time.perf_counter()
-            out = None
-            for _ in range(args.calls):
-                out = fn(*arrays)
-            jax.block_until_ready(out)
-            rounds.append((time.perf_counter() - t0) / args.calls * 1e3)
-        row["ms"] = round(min(rounds), 4)
-        row["ms_mean"] = round(sum(rounds) / len(rounds), 4)
+        operands = [jax.random.normal(k, a.shape, a.dtype)
+                    for k, a in zip(keys, operands)]
+    try:
+        sweep.timed(row, jax.jit(call), operands, args, topo_sharding)
     except Exception as e:  # the compiler's refusal: recorded, sweep goes on
         text = str(e)
         at = text.find("vmem")
@@ -128,38 +102,16 @@ def measure(shape, blocks, backward: bool, args, topo_sharding=None):
 
 
 def main():
-    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--out", default="chiprun_out/splash_block_sweep.jsonl")
-    ap.add_argument("--calls", type=int, default=20)
-    ap.add_argument("--rounds", type=int, default=3)
-    ap.add_argument("--shapes", nargs="*", help="names; default all")
-    ap.add_argument("--compile-only", action="store_true")
-    ap.add_argument("--tiny", action="store_true")
-    args = ap.parse_args()
-
+    args = sweep.arguments(
+        __doc__, "splash_block_sweep", tiny_calls=2,
+        **{"--shapes": dict(nargs="*", help="names; default all")})
     shapes, sizes, base = SHAPES, [512, 1024, 2048], 512
     if args.tiny:
         shapes, sizes, base = TINY, [128, 256], 128
-        args.calls, args.rounds = 2, 1
     if args.shapes:
         shapes = [s for s in shapes if s[0] in args.shapes]
-    sharding = None
-    if args.compile_only:
-        from jax.experimental import topologies
-        from jax.sharding import SingleDeviceSharding
+    sharding = sweep.device(args)
 
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-        sharding = SingleDeviceSharding(topo.devices[0])
-        jax.default_backend = lambda: "tpu"  # the kernels' interpret switch
-    elif not args.tiny and jax.default_backend() != "tpu":
-        sys.exit("splash_block_sweep: no TPU here (use --tiny or "
-                 "--compile-only): a CPU run gives no time")
-    device = jax.devices()[0]
-    print(f"[sweep] device {device.platform} {device.device_kind}, "
-          f"{args.calls} calls x {args.rounds} rounds", flush=True)
-
-    os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
     with open(args.out, "a") as out:
         for shape in shapes:
             S, hd = shape[3], shape[6]
@@ -180,8 +132,7 @@ def main():
                 row = measure(shape, blocks.capped(S), backward, args,
                               sharding)
                 row["swept"] = swept
-                out.write(json.dumps(row) + "\n")
-                out.flush()
+                sweep.write(out, row)
                 b = row["blocks"]
                 what = (f"{row['ms']:9.3f}" if "ms" in row else
                         "  refused" if "refused" in row else

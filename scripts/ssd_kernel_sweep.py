@@ -24,18 +24,10 @@ CPU in interpret mode."""
 
 from __future__ import annotations
 
-import argparse
-import json
-import os
-import sys
-import time
-
-os.environ.setdefault("TPU_LOG_DIR", "disabled")
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-
 import jax
 import jax.numpy as jnp
 import numpy as np
+import sweep_common as sweep
 
 from ray_tpu.ops import ssd, ssd_kernel
 
@@ -58,29 +50,19 @@ def variants(chunk: int):
     }
 
 
-def passes(fn, shape):
-    """name -> jitted f(inputs, dy).  x, B, C and dy come and y goes as the
-    layer holds them, (rows, positions, width): the split into heads and
-    groups is a reshape inside the program, as in ``models/mamba2.py``, and
-    costs no copy there (handed over as (b, S, H, P) the arrays would be
-    laid out anew around every variant's call)."""
+def flat(fn, shape):
+    """``fn`` over x, B, C and y as the layer holds them, (rows, positions,
+    width): the split into heads and groups is a reshape inside the program,
+    as in ``models/mamba2.py``, and costs no copy there (handed over as (b,
+    S, H, P) the arrays would be laid out anew around every variant's
+    call)."""
     b, S, H, P, G, N, _ = shape
 
     def run(x, delta, A, B, C, D):
         return fn(x.reshape(b, S, H, P), delta, A, B.reshape(b, S, G, N),
                   C.reshape(b, S, G, N), D).reshape(b, S, H * P)
 
-    def pulled(run):
-        def both(inputs, dy):
-            y, pull = jax.vjp(run, *inputs)
-            return y, pull(dy)
-        return both
-
-    return {
-        "fwd": jax.jit(lambda inputs, dy: run(*inputs)),
-        "fwd+bwd": jax.jit(pulled(run)),
-        "checkpoint fwd+bwd": jax.jit(pulled(jax.checkpoint(run))),
-    }
+    return run
 
 
 def inputs(shape, sharding=None):
@@ -90,8 +72,7 @@ def inputs(shape, sharding=None):
               ((b, S, G * N), jnp.bfloat16), ((H,), jnp.float32),
               ((b, S, H * P), jnp.bfloat16)]
     if sharding is not None:
-        abstract = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
-                    for s, d in shapes]
+        abstract = sweep.abstract(shapes, sharding)
         return tuple(abstract[:6]), abstract[6]
     k = jax.random.split(jax.random.key(44), 7)
     x = jax.random.normal(k[0], shapes[0][0], jnp.bfloat16)
@@ -107,76 +88,28 @@ def inputs(shape, sharding=None):
     return (x, delta, A, B, C, D), dy
 
 
-def close(got, want):
-    """max |a - b| / max |b| over a pair of pytrees' leaves."""
-    return [float(jnp.max(jnp.abs(a.astype(jnp.float32)
-                                  - b.astype(jnp.float32)))
-                  / jnp.max(jnp.abs(b.astype(jnp.float32))))
-            for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want))]
-
-
 def main():
-    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--out", default="chiprun_out/ssd_kernel_sweep.jsonl")
-    ap.add_argument("--calls", type=int, default=20)
-    ap.add_argument("--rounds", type=int, default=3)
-    ap.add_argument("--compile-only", action="store_true")
-    ap.add_argument("--tiny", action="store_true")
-    args = ap.parse_args()
-
-    shape, sharding = CELL, None
-    if args.tiny:
-        shape, args.calls, args.rounds = TINY, 1, 1
-    if args.compile_only:
-        from jax.experimental import topologies
-        from jax.sharding import SingleDeviceSharding
-
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-        sharding = SingleDeviceSharding(topo.devices[0])
-        jax.default_backend = lambda: "tpu"  # the kernels' interpret switch
-    elif not args.tiny and jax.default_backend() != "tpu":
-        sys.exit("ssd_kernel_sweep: no TPU here (use --tiny or "
-                 "--compile-only): a CPU run gives no time")
-    device = jax.devices()[0]
-    print(f"[sweep] device {device.platform} {device.device_kind}, shape "
-          f"{shape}, {args.calls} calls x {args.rounds} rounds", flush=True)
+    args = sweep.arguments(__doc__, "ssd_kernel_sweep")
+    shape = TINY if args.tiny else CELL
+    sharding = sweep.device(args, f"shape {shape}, ")
     xs, dy = inputs(shape, sharding)
-    os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
     want = None
     print(f"{'variant':34s} {'pass':20s} {'ms':>9s} {'ms mean':>9s}  "
           "worst leaf against the XLA form (y, dx, ddelta, dA, dB, dC, dD)",
           flush=True)
     with open(args.out, "a") as out:
         for name, (fn, chunk) in variants(shape[6]).items():
-            for which, run in passes(fn, shape).items():
+            for which, run in sweep.passes(flat(fn, shape)).items():
                 row = {"variant": name, "pass": which, "shape": list(shape),
                        "chunk": chunk,
                        "grid": [shape[0], shape[4], shape[1] // chunk]}
-                t0 = time.perf_counter()
-                if sharding is not None:
-                    run.lower(xs, dy).compile()
-                    row["compile_s"] = round(time.perf_counter() - t0, 2)
-                else:
-                    got = jax.block_until_ready(run(xs, dy))
-                    row["first_call_s"] = round(time.perf_counter() - t0, 2)
-                    if which == "fwd+bwd":
-                        if want is None:
-                            want = got
-                        row["against_xla"] = [round(e, 5)
-                                              for e in close(got, want)]
-                    rounds = []
-                    for _ in range(args.rounds):
-                        t0 = time.perf_counter()
-                        for _ in range(args.calls):
-                            got = run(xs, dy)
-                        jax.block_until_ready(got)
-                        rounds.append((time.perf_counter() - t0)
-                                      / args.calls * 1e3)
-                    row["ms"] = round(min(rounds), 4)
-                    row["ms_mean"] = round(sum(rounds) / len(rounds), 4)
-                out.write(json.dumps(row) + "\n")
-                out.flush()
+                got = sweep.timed(row, run, (xs, dy), args, sharding)
+                if got is not None and which == "fwd+bwd":
+                    if want is None:
+                        want = got
+                    row["against_xla"] = [round(e, 5)
+                                          for e in sweep.close(got, want)]
+                sweep.write(out, row)
                 print(f"{name:34s} {which:20s} "
                       f"{row.get('ms', row.get('compile_s')):9.3f} "
                       f"{row.get('ms_mean', 0):9.3f}  "

@@ -35,6 +35,7 @@ def main() -> None:
     from ray_tpu.models import llama
     from ray_tpu.ops import remat
     from ray_tpu.parallel import MeshSpec, make_mesh
+    from ray_tpu.util import device_telemetry
     from ray_tpu.parallel.train_state import (create_sharded_state,
                                               jit_train_step)
 
@@ -67,12 +68,11 @@ def main() -> None:
         0, config.vocab_size, (n_local, config.seq_len + 1)).astype(np.int32)
     tokens = dist.local_batch_to_global(mesh, local[:, :-1], axis="fsdp")
     targets = dist.local_batch_to_global(mesh, local[:, 1:], axis="fsdp")
-    with remat.recording() as decided:
-        params, opt_state, loss = step(params, opt_state, tokens, targets)
-    (decision,) = decided
-    print(f"RESULT {rank} {','.join(decision.kept) or '-'} "
-          f"{decision.processes} {','.join(alone.kept) or '-'} "
-          f"{float(loss):.6f}", flush=True)
+    params, opt_state, loss = step(params, opt_state, tokens, targets)
+    (row,) = device_telemetry.first_calls("train_step")
+    print(f"RESULT {rank} {','.join(row['remat_kept']) or '-'} "
+          f"{remat.tracing_processes(mesh.abstract_mesh)} "
+          f"{','.join(alone.kept) or '-'} {float(loss):.6f}", flush=True)
     dist.shutdown()
 
 

@@ -229,7 +229,7 @@ def test_the_scan_kernels_compile_for_the_v5e(monkeypatch, one_chip):
             shape((H,), jnp.float32), shape((b, S, G, N), jnp.bfloat16),
             shape((b, S, G, N), jnp.bfloat16), shape((H,), jnp.float32))
     assert ssd.path(args[0].shape, args[3].shape, Q,
-                    jax.sharding.get_abstract_mesh()) == ("kernel", None)
+                    jax.sharding.get_abstract_mesh()) == "kernel"
 
     def scan(*inputs):
         with jax.named_scope("ssm_scan"):  # as models/mamba2.py:mixer
@@ -286,8 +286,8 @@ def test_the_kda_kernels_compile_for_the_v5e(monkeypatch, one_chip):
     wide = shape((b, S, H, d), jnp.bfloat16)
     args = (wide, wide, wide, shape((b, S, H, d), jnp.float32),
             shape((b, S, H), jnp.float32))
-    assert kda.path(wide.shape, C, jax.sharding.get_abstract_mesh()) == (
-        "kernel", None)
+    assert kda.path(wide.shape, C,
+                    jax.sharding.get_abstract_mesh()) == "kernel"
     assert kda_kernel.grid(wide, C) == (1, 1, 128)
 
     def scan(*inputs):

@@ -17,13 +17,18 @@ from ray_tpu.ops import grad_ring
 from ray_tpu.parallel import MeshSpec, batch_sharding, make_mesh
 from ray_tpu.parallel.mesh import pytree_sharding
 from ray_tpu.parallel.train_state import jit_train_step
-from ray_tpu.util import device_telemetry
+from ray_tpu.util import device_telemetry, first_call
 
 LAYOUTS = {"fsdp4": MeshSpec(fsdp=4), "data2.fsdp2": MeshSpec(data=2, fsdp=2),
            "fsdp2.tensor2": MeshSpec(fsdp=2, tensor=2),
            "data2.fsdp4": MeshSpec(data=2, fsdp=4)}
 #: every dense projection of ``llama._block``
 RING_WEIGHTS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+
+
+def _rings(seen):
+    """The ring's two facts among whatever else a trace noted."""
+    return {name: seen[name] for name in grad_ring.NO_RINGS}
 
 
 def _float32_tiny():
@@ -58,11 +63,11 @@ def test_tiny_llama_gradients_equal_the_partitioners(partitioner, layout):
     params = jax.device_put(
         params, pytree_sharding(llama.logical_axes(config), mesh))
     batch = [jax.device_put(a, batch_sharding(mesh)) for a in batch]
-    with jax.set_mesh(mesh), grad_ring.recording() as seen:
+    with jax.set_mesh(mesh), first_call.noting(**grad_ring.NO_RINGS) as seen:
         got = jax.jit(jax.grad(partial(llama.loss_fn, config=config)))(
             params, *batch)
-    assert (seen.products, seen.axis) == (len(RING_WEIGHTS),
-                                          mesh.shape["fsdp"])
+    assert _rings(seen) == {"grad_ring_products": len(RING_WEIGHTS),
+                            "grad_ring_axis": mesh.shape["fsdp"]}
     for name in RING_WEIGHTS:
         g, w = got["blocks"][name], want["blocks"][name]
         assert float(jnp.max(jnp.abs(g - w))) < 1e-5 * float(
@@ -120,10 +125,10 @@ def test_a_batch_the_mesh_does_not_cut_is_left_to_the_partitioner():
     the product is the plain one."""
     mesh = make_mesh(MeshSpec(fsdp=4))
     x, w = jnp.ones((3, 16, 64)), jnp.ones((64, 32))
-    with jax.set_mesh(mesh), grad_ring.recording() as seen:
+    with jax.set_mesh(mesh), first_call.noting(**grad_ring.NO_RINGS) as seen:
         text = jax.jit(jax.grad(lambda x, w: jnp.sum(
             grad_ring.dense(x, w, 0)), argnums=1)).lower(x, w).as_text()
-    assert "sdy.manual_computation" not in text and seen.products == 0
+    assert "sdy.manual_computation" not in text and seen == grad_ring.NO_RINGS
 
 
 def _hops(coords, order):
@@ -204,13 +209,12 @@ def test_on_one_device_the_step_holds_no_shard_map(mesh_spec):
     mesh = mesh_spec and make_mesh(mesh_spec, jax.devices()[:1])
     context = jax.set_mesh(mesh) if mesh is not None else \
         contextlib.nullcontext()
-    with context, grad_ring.recording() as seen:
+    with context, first_call.noting(**grad_ring.NO_RINGS) as seen:
         jaxpr = jax.make_jaxpr(step_fn)(params, opt_state, *_batch(config))
     text = str(jaxpr)
     assert "shard_map" not in text and "ppermute" not in text
     assert "custom_vjp_call" not in text.replace("_rope", "")
-    assert seen.attributes() == {"grad_ring_products": 0,
-                                 "grad_ring_axis": 0}
+    assert _rings(seen) == grad_ring.NO_RINGS
 
 
 def test_the_step_lowers_for_the_tpu_under_fsdp4(monkeypatch):

@@ -19,6 +19,7 @@ from ray_tpu.ops import kda as kda_module
 from ray_tpu.ops import kda_kernel
 from ray_tpu.ops.kda import kda, kda_xla
 from ray_tpu.parallel import MeshSpec, make_mesh
+from ray_tpu.util import first_call
 
 C, D = 64, 128
 
@@ -62,7 +63,7 @@ def test_the_kernels_are_the_recurrence(chunks, heads, decay):
     at -88): no inf, no nan."""
     args = _inputs(chunks, decay, H=heads)
     assert kda_module.path(args[0].shape, C,
-                           jax.sharding.get_abstract_mesh())[0] == "kernel"
+                           jax.sharding.get_abstract_mesh()) == "kernel"
     assert float(jnp.mean(args[4] > 1.0)) > 0.5
     if decay > 1:
         sums = jnp.sum(args[3].reshape(2, chunks, C, heads, D), axis=2)
@@ -215,17 +216,13 @@ def test_which_path_a_call_takes(name):
     (b, S, H, d, chunk), axes, want = PLACEMENTS[name]
     mesh = _mesh(**axes).abstract_mesh if axes \
         else jax.sharding.get_abstract_mesh()
-    taken, specs = kda_module.path((b, S, H, d), min(chunk, S), mesh)
-    assert taken == want
-    assert (specs is not None) == (want == "kernel" and mesh.size > 1)
+    assert kda_module.path((b, S, H, d), min(chunk, S), mesh) == want
 
 
 def test_on_a_mesh_every_device_scans_its_own_rows_and_heads():
     """Four CPU devices, rows over `data` and heads over `tensor`: the
     kernels run inside a ``shard_map`` (a Mosaic call cannot be partitioned)
     and output and gradients are the XLA form's."""
-    from ray_tpu.parallel.train_state import _noting
-
     args = _inputs(2, 1.0, seed=7)
     dy = jax.random.normal(jax.random.key(3), args[0].shape)
 
@@ -235,7 +232,7 @@ def test_on_a_mesh_every_device_scans_its_own_rows_and_heads():
 
     with jax.default_matmul_precision("highest"):
         want, grads_xla = loss(kda_xla)(args)
-        with jax.set_mesh(_mesh(data=2, tensor=2)), _noting() as notes:
+        with jax.set_mesh(_mesh(data=2, tensor=2)), first_call.noting() as notes:
             got, grads = loss(kda)(args)
     assert notes == {"kda_scan_kernel": True, "kda_scan_grid": [1, 1, 2]}
     assert float(got) == pytest.approx(float(want), rel=1e-5)
@@ -244,13 +241,11 @@ def test_on_a_mesh_every_device_scans_its_own_rows_and_heads():
 
 
 def test_the_first_call_record_says_which_ran():
-    from ray_tpu.parallel.train_state import _noting
-
     args = _inputs(2, 1.0, H=3)
-    with _noting() as notes:
+    with first_call.noting() as notes:
         jax.eval_shape(lambda *a: kda(*a, C), *args)
     assert notes == {"kda_scan_kernel": True, "kda_scan_grid": [2, 1, 2]}
     small = tuple(a[:, :8] for a in args)
-    with _noting() as notes:
+    with first_call.noting() as notes:
         jax.eval_shape(lambda *a: kda(*a, 8), *small)
     assert notes == {"kda_scan_kernel": False, "kda_scan_grid": None}

@@ -23,9 +23,12 @@ import pytest
 
 from benchmarks.lib import correct, spec
 from benchmarks.reference import nemotron_h as reference
-from ray_tpu.models import hybrid, mamba2, moe
+from ray_tpu.models import experts, hybrid, mamba2, moe
 from ray_tpu.ops.ssd import ssd
 from ray_tpu.parallel import MeshSpec, make_mesh
+from ray_tpu.parallel.train_state import (create_sharded_state,
+                                          jit_train_step)
+from ray_tpu.util import device_telemetry, first_call
 
 #: benchmarks/lib/correct.py's, which the bf16 program is held to on the chip
 LOSS_TOL, GRAD_TOL = 1e-3, 0.75
@@ -121,7 +124,7 @@ def _mixer_parts(dtype=jnp.float32):
 
 def test_mixer_matches_the_reference():
     config, blk, x, cfg = _mixer_parts()
-    axes = mamba2.logical_axes()
+    axes = mamba2.logical_axes(config)
     with jax.default_matmul_precision("highest"):
         got = mamba2.mixer(x, blk, config, axes) - x
         u = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True)
@@ -134,7 +137,7 @@ def test_the_convolution_is_causal():
     """Changing position t changes no output before t, and the row's first
     outputs see zeros before it."""
     config, blk, x, _ = _mixer_parts()
-    axes = mamba2.logical_axes()
+    axes = mamba2.logical_axes(config)
     t = 37
     moved = x.at[:, t].add(1.0)
     a, b = (mamba2.mixer(v, blk, config, axes) for v in (x, moved))
@@ -205,7 +208,7 @@ def test_the_bias_is_no_leaf_and_survives_an_optimizer_step():
     config = dataclasses.replace(hybrid.HybridConfig.tiny(), attn_impl="xla",
                                  dtype=jnp.float32,
                                  logits_dtype=jnp.float32)
-    before = [hybrid.router_bias(config, i) for i in range(4)]
+    before = [experts.router_bias(config, i) for i in range(4)]
     assert all(b.shape == (config.n_experts,) and np.any(b) for b in before)
     assert not np.allclose(before[0], before[1])  # a draw a layer
     params = hybrid.init_params(config, jax.random.key(0))
@@ -220,7 +223,7 @@ def test_the_bias_is_no_leaf_and_survives_an_optimizer_step():
     assert np.isfinite(float(loss))
     assert all(not np.array_equal(a, b) for a, b in zip(
         jax.tree.leaves(params), jax.tree.leaves(moved)))
-    after = [hybrid.router_bias(config, i) for i in range(4)]
+    after = [experts.router_bias(config, i) for i in range(4)]
     assert all(np.array_equal(a, b) for a, b in zip(before, after))
 
 
@@ -326,15 +329,13 @@ def test_counters_leave_the_step_stacked_by_expert_layer():
 
 
 def test_num_params_and_the_first_call_record():
-    from ray_tpu.parallel.train_state import _noting
-
     config = dataclasses.replace(hybrid.HybridConfig.tiny(), attn_impl="xla")
     shapes = jax.eval_shape(lambda: hybrid.init_params(config,
                                                        jax.random.key(0)))
     assert hybrid.num_params(config) == sum(
         a.size for a in jax.tree.leaves(shapes))
     ids = jax.ShapeDtypeStruct((2, 128), jnp.int32)
-    with _noting() as notes:
+    with first_call.noting() as notes:
         jax.eval_shape(lambda p, t: hybrid.loss_and_counters(
             p, t, t, config), shapes, ids)
     assert notes == {
@@ -342,7 +343,10 @@ def test_num_params_and_the_first_call_record():
         "ssm_chunk": 32, "ssm_chunks": 8, "ssm_scan_kernel": False,
         "ssm_scan_grid": None, "experts_held": 4,
         "experts_total": 16, "router_scoring": "sigmoid",
-        "attn_positions": 128, "loss_positions": 128}
+        "attn_positions": 128, "loss_positions": 128,
+        # the attention kind's own since PR 46, whatever else the pattern holds
+        "heads_held": 4, "heads_total": 4, "attn_gate": False,
+        "remat_kept": [], "remat_kept_bytes": 0, "remat_room_bytes": None}
 
 
 # -------------------------------------------------- (6) the 8-bit control
@@ -411,14 +415,158 @@ def test_the_older_models_lower_to_the_parents_text(name):
     assert hashlib.sha256(text.encode()).hexdigest() == LOWERED_STEPS[name]
 
 
+# ------------------------------- (8) a kind is one entry of ``hybrid.KINDS``
+#: sha256 over the leaves of each hybrid preset's parameters at key 0 (path,
+#: dtype, shape, bytes; leaves in jax's order), recorded on the parent of
+#: PR 46, where ``hybrid.init_params`` drew every kind's stack itself: the
+#: kinds' modules draw from the same keys, so a cell's parameters stay the
+#: function of ``--seed`` and ``init_seed`` its warm-up was fitted to
+INIT_PARAMS = {
+    "tiny-nemotron-h":
+        "ebfc85ea46efc41f6519187746add9f4b03743061be81a10de24e0c863c87223",
+    "tiny-solar-open2":
+        "32dcf88a013bce5ad0b19cef861903d502cd126a2f676b45b698bcfd2b54a705",
+}
+
+
+@pytest.mark.parametrize("name", sorted(INIT_PARAMS))
+def test_the_parameters_are_the_parents_bit_for_bit(name):
+    config = spec.load_json(spec.BENCH_DIR, "configs", name + ".json")
+    family = spec.load_module("models", config["family"]).build(config, 128)
+    h = hashlib.sha256()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(
+            jax.jit(family.init_fn)(jax.random.key(0)))[0]:
+        a = np.asarray(leaf)
+        for part in (jax.tree_util.keystr(path), str(a.dtype), str(a.shape)):
+            h.update(part.encode())
+        h.update(a.tobytes())
+    assert h.hexdigest() == INIT_PARAMS[name]
+
+
+def _cell_config(name):
+    """(the configuration a cell of the benchmark trains, its batch)."""
+    cell = spec.load_cell(spec.load_benchmark(), name)
+    config, traffic = cell["config_file"], cell["traffic_file"]
+    model = spec.load_module("models", config["family"]).model_config(
+        config, traffic["seq_len"])[1]
+    return model, (traffic["seqs_per_chip"] * cell["chips"],
+                   traffic["seq_len"])
+
+
+#: ``hybrid._layer_sizes`` on the parent of PR 46, (q/k/v bytes, gate/up
+#: bytes, the bound on the step's temporaries): what ``ops/remat.py`` decides
+#: from.  The kinds' ``layer_bytes`` carry the parent's terms over as they
+#: were, the two overstated ones with them (ROADMAP C15).
+LAYER_SIZES = {
+    "tiny": (lambda: (hybrid.HybridConfig.tiny(), (2, 128)),
+             (131072, 262144, 7640128)),
+    "tiny_solar": (lambda: (hybrid.HybridConfig.tiny_solar(), (2, 128)),
+                   (65536, 196608, 5602244)),
+    "nemotron-ep16-s8192": (lambda: _cell_config("nemotron-ep16-s8192"),
+                            (150994944, 486539264, 9288687104)),
+    "solar-open2-ep40-tp8": (lambda: _cell_config("solar-open2-ep40-tp8"),
+                             (20971520, 167772160, 7130061200)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(LAYER_SIZES))
+def test_the_remat_rule_is_given_the_parents_sizes(name):
+    build, (qkv, gate_up, temporaries) = LAYER_SIZES[name]
+    config, (rows, seq_len) = build()
+    shapes = jax.eval_shape(lambda: hybrid.init_params(config,
+                                                       jax.random.key(0)))
+    assert hybrid._layer_sizes(
+        shapes, (rows, seq_len, config.d_model), config) == (
+        [("attn_qkv", qkv), ("mlp_gate_up", gate_up)], temporaries)
+
+
+def _identity_kind():
+    """A kind made here alone, as a module object with the interface of
+    ``hybrid.KINDS``: ``x + scale * norm(x)``, one norm vector and one
+    scale a layer, nothing for the ladder, no counter."""
+    import types
+
+    from ray_tpu.models.layers import rmsnorm
+
+    def layer(config, axes, index):
+        def mix(x, blk):
+            with jax.named_scope("mlp"):
+                return x + (rmsnorm(x, blk["id_norm"], config.rms_eps)
+                            * blk["id_scale"]).astype(x.dtype), None
+        return mix
+
+    return types.SimpleNamespace(
+        init_params=lambda config, key, n, out_std: {
+            "id_norm": jnp.ones((n, config.d_model)),
+            "id_scale": jax.random.normal(key, (n, 1)) * out_std},
+        logical_axes=lambda config: {"id_norm": ("layers", "norm"),
+                                     "id_scale": ("layers", None)},
+        matmul_params=lambda config, routed: 0,
+        num_params=lambda config: config.d_model + 1,
+        mixer_flops=lambda config, seq_len: 0.0,
+        layer_bytes=lambda config, tokens, seq_len, tensor, itemsize: (
+            2 * tokens * config.d_model * itemsize, 0, {}),
+        first_call_facts=lambda config, rows, seq_len: {"id_layers":
+                                                        config.count("I")},
+        layer=layer)
+
+
+def test_a_kind_is_a_module_and_one_line_of_kinds(monkeypatch):
+    """The seam's own test: a kind registered from outside initialises,
+    shards, counts its parameters and FLOPs, is sized for the remat rule,
+    notes its facts and trains a step beside the others, with no edit to
+    ``models/hybrid.py``."""
+    monkeypatch.setitem(hybrid.KINDS, "I",
+                        hybrid.Kind("identity", _identity_kind(), 6))
+    plain = dataclasses.replace(hybrid.HybridConfig.tiny(), attn_impl="xla",
+                                pattern="ME*E")
+    config = dataclasses.replace(plain, pattern="MIE*IE")
+    params = hybrid.init_params(config, jax.random.key(0))
+    assert params["identity"]["id_norm"].shape == (2, config.d_model)
+    # the others draw from the keys they drew from without it: the same
+    # leaves, each layer's last matrix rescaled for six layers and not four
+    rest = {k: v for k, v in params.items() if k != "identity"}
+    assert jax.tree.all(jax.tree.map(
+        lambda a, b: np.array_equal(a, b) or np.allclose(
+            a * np.sqrt(6 / 4), b, rtol=1e-6, atol=0),
+        rest, hybrid.init_params(plain, jax.random.key(0))))
+    axes = hybrid.logical_axes(config)
+    assert jax.tree.structure(axes, is_leaf=lambda a: isinstance(a, tuple)) \
+        == jax.tree.structure(params)
+    assert hybrid.num_params(config) == sum(
+        a.size for a in jax.tree.leaves(params)) \
+        == hybrid.num_params(plain) + 2 * (config.d_model + 1)
+    assert hybrid.flops_per_token(config) == hybrid.flops_per_token(plain)
+    mesh = make_mesh(MeshSpec(data=2), jax.devices()[:2])
+    optimizer = hybrid.make_optimizer()
+    state, opt_state = create_sharded_state(
+        lambda key: hybrid.init_params(config, key), axes, mesh,
+        jax.random.key(0), optimizer)
+    step_fn = hybrid.make_train_step(config, optimizer)
+    step = jit_train_step(step_fn, mesh=mesh)
+    ids = np.random.default_rng(0).integers(0, 1024, (2, 129)).astype(
+        np.int32)
+    device_telemetry.reset()
+    moved, _, loss = step(state, opt_state, ids[:, :-1], ids[:, 1:])
+    assert np.isfinite(float(loss))
+    assert not np.array_equal(moved["identity"]["id_scale"],
+                              params["identity"]["id_scale"])
+    (row,) = device_telemetry.first_calls("train_step")
+    assert row["layer_kinds"] == "MIE*IE" and row["id_layers"] == 2
+    # the expert layers' counters: layers, batch shards, held experts
+    assert step_fn.counters["moe_rows"].shape == (2, 2, 4)
+
+
 def test_importing_llama_loads_no_state_space_module():
-    """``ops/ssd.py`` and ``models/mamba2.py`` load when the hybrid decoder
-    is built, not with ``ray_tpu`` or ``ray_tpu.models.llama``."""
+    """``ops/ssd.py`` and ``models/mamba2.py`` load when a hybrid decoder
+    with ``M`` in its pattern is built, not with ``ray_tpu`` or
+    ``ray_tpu.models.llama``."""
     script = ("import sys, ray_tpu, ray_tpu.models.llama\n"
               "late = {'ray_tpu.ops.ssd', 'ray_tpu.models.mamba2', "
               "'ray_tpu.models.hybrid'}\n"
               "assert not late & set(sys.modules), late & set(sys.modules)\n"
-              "import ray_tpu.models.hybrid\n"
+              "from ray_tpu.models import hybrid\n"
+              "hybrid.num_params(hybrid.HybridConfig.tiny())\n"
               "assert late <= set(sys.modules)\n")
     done = subprocess.run([sys.executable, "-c", script], capture_output=True,
                           text=True, env={"JAX_PLATFORMS": "cpu",

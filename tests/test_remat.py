@@ -22,7 +22,7 @@ from ray_tpu.ops.attention import save_splash_residuals
 from ray_tpu.parallel import MeshSpec, make_mesh
 from ray_tpu.parallel.train_state import (create_sharded_state,
                                           jit_train_step)
-from ray_tpu.util import device_telemetry
+from ray_tpu.util import device_telemetry, first_call
 
 GiB = 2 ** 30
 #: a v5e chip's ``bytes_limit``
@@ -50,10 +50,10 @@ def _policy(config, B=2):
 
 def _decide(config, B=2):
     """The rule's decision for that batch."""
-    with remat.recording() as decided:
-        _policy(config, B)
-    (decision,) = decided
-    return decision
+    shapes = jax.eval_shape(lambda: llama.init_params(config,
+                                                      jax.random.key(0)))
+    return remat.decide(*llama._layer_sizes(
+        shapes, (B, config.seq_len, config.d_model), config))
 
 
 MISTRAL = dict(vocab_size=32768, n_head=32, n_kv_head=8, d_model=4096,
@@ -134,14 +134,11 @@ def test_the_rule_is_given_each_layer_kinds_sizes(nudge_mb, monkeypatch):
     for fuller in (0.0, 0.3):
         on_device(monkeypatch, (V5E, int((6.21 + fuller) * GiB)
                                 + nudge_mb * 2 ** 20))
-        with remat.recording() as decided:
-            remat.layer_policy(candidates, temporaries)
-        assert decided[0].kept == ()
-        assert decided[0].room_bytes < -0.5 * GiB
+        decided = remat.decide(candidates, temporaries)
+        assert decided.kept == ()
+        assert decided.room_bytes < -0.5 * GiB
     on_device(monkeypatch, ROOMY)
-    with remat.recording() as decided:
-        remat.layer_policy(candidates, temporaries)
-    assert decided[0].kept == BOTH
+    assert remat.decide(candidates, temporaries).kept == BOTH
 
 
 def test_the_rule_is_given_the_kda_kinds_sizes(monkeypatch):
@@ -178,13 +175,9 @@ def test_the_rule_is_given_the_kda_kinds_sizes(monkeypatch):
         (1, 8192, 4096), plain)[1] < temporaries
     for fuller in (0.0, 0.3):
         on_device(monkeypatch, (V5E, int((7.83 + fuller) * GiB)))
-        with remat.recording() as decided:
-            remat.layer_policy(candidates, temporaries)
-        assert decided[0].kept == ()
+        assert remat.decide(candidates, temporaries).kept == ()
     on_device(monkeypatch, ROOMY)
-    with remat.recording() as decided:
-        remat.layer_policy(candidates, temporaries)
-    assert decided[0].kept == BOTH
+    assert remat.decide(candidates, temporaries).kept == BOTH
 
 
 @pytest.mark.parametrize("limit, in_use, candidates, temporaries, want", [
@@ -333,10 +326,10 @@ def test_keeping_more_changes_no_number(preset, monkeypatch):
     batch = _batch(config)
 
     def run(kept):
-        with remat.recording() as decided:
+        with first_call.noting() as notes:
             out = jax.jit(jax.value_and_grad(llama.loss_fn),
                           static_argnums=3)(params, *batch, config)
-        assert [d.kept for d in decided] == [kept]
+        assert notes["remat_kept"] == list(kept)
         return out
 
     plain_loss, plain = run(())
@@ -455,10 +448,10 @@ def test_a_refused_richer_step_falls_back_once(monkeypatch, caplog):
     refusals = []
 
     def step_fn(*args):
-        with remat.recording() as decided:
+        with first_call.noting() as notes:
             out = inner(*args)
-        if decided[-1].kept or refusals == ["always"]:
-            refusals.append(decided[-1].kept)
+        if notes["remat_kept"] or refusals == ["always"]:
+            refusals.append(tuple(notes["remat_kept"]))
             raise RuntimeError("RESOURCE_EXHAUSTED: XLA:TPU compile "
                                "permanent error. Ran out of memory in hbm.")
         return out
@@ -511,6 +504,7 @@ def test_across_processes_only_the_compilers_refusal_falls_back(
     it, which every process sees alike; a process that ran out of memory on
     its own raises, since its peers have launched the program."""
     monkeypatch.setattr(remat, "_job_memory", lambda *a: (ROOMY, 2))
+    monkeypatch.setattr(remat, "tracing_processes", lambda mesh: 2)
     config = llama.LlamaConfig.tiny()
     step_fn, params, opt_state = _step_and_state(config)
     step = jit_train_step(step_fn)

@@ -26,6 +26,7 @@ from ray_tpu.models import hybrid, kda as kda_model, moe
 from ray_tpu.models.layers import attention
 from ray_tpu.ops.kda import kda, kda_xla
 from ray_tpu.parallel import MeshSpec, make_mesh
+from ray_tpu.util import first_call
 
 #: benchmarks/lib/correct.py's, which the bf16 program is held to on the chip
 LOSS_TOL, GRAD_TOL = 1e-3, 0.75
@@ -145,7 +146,7 @@ def test_mixer_matches_the_reference():
     config, blk, x, cfg = _kda_parts(4)
     with jax.default_matmul_precision("highest"):
         got = jax.jit(lambda x, blk: kda_model.mixer(
-            x, blk, config, kda_model.logical_axes()))(x, blk) - x
+            x, blk, config, kda_model.logical_axes(config)))(x, blk) - x
         want = jax.jit(lambda x, blk: reference.kda(
             _normed(x, blk["kda_norm"], config.rms_eps), blk, cfg))(x, blk)
     assert _rel_err(got, want) < 1e-5
@@ -173,7 +174,7 @@ def test_beta_may_pass_one_and_the_decay_is_a_channels_own():
 
 def test_the_convolutions_are_causal():
     config, blk, x, _ = _kda_parts(2)
-    axes = kda_model.logical_axes()
+    axes = kda_model.logical_axes(config)
     t = 37
     moved = x.at[:, t].add(1.0)
     a, b = map(jax.jit(lambda v: kda_model.mixer(v, blk, config, axes)),
@@ -205,7 +206,7 @@ def test_the_kda_head_shares_add_up_to_the_uncut_mixer(shares):
     config, blk, x, cfg = _kda_parts(8)
     d, held = config.kda_head_dim, 8 // shares
     part = dataclasses.replace(config, kda_heads=held)
-    axes = kda_model.logical_axes()
+    axes = kda_model.logical_axes(config)
     mixer = jax.jit(lambda mine: kda_model.mixer(x, mine, part, axes) - x)
     with jax.default_matmul_precision("highest"):
         want = jax.jit(lambda blk: reference.kda(
@@ -344,8 +345,6 @@ def test_counters_leave_the_step_stacked_by_expert_layer():
 
 
 def test_num_params_flops_and_the_first_call_record():
-    from ray_tpu.parallel.train_state import _noting
-
     config = dataclasses.replace(hybrid.HybridConfig.tiny_solar(),
                                  attn_impl="xla")
     shapes = jax.eval_shape(lambda: hybrid.init_params(config,
@@ -353,7 +352,7 @@ def test_num_params_flops_and_the_first_call_record():
     assert hybrid.num_params(config) == sum(
         a.size for a in jax.tree.leaves(shapes))
     ids = jax.ShapeDtypeStruct((2, 128), jnp.int32)
-    with _noting() as notes:
+    with first_call.noting() as notes:
         jax.eval_shape(lambda p, t: hybrid.loss_and_counters(
             p, t, t, config), shapes, ids)
     assert notes == {
@@ -362,7 +361,8 @@ def test_num_params_flops_and_the_first_call_record():
         "kda_scan_grid": None, "heads_held": 2, "heads_total": 8,
         "attn_gate": True, "experts_held": 4, "experts_total": 16,
         "router_scoring": "sigmoid", "attn_positions": 128,
-        "loss_positions": 128}
+        "loss_positions": 128,
+        "remat_kept": [], "remat_kept_bytes": 0, "remat_room_bytes": None}
 
 
 # -------------------------------------------------- (5) the 8-bit control

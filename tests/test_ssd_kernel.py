@@ -19,6 +19,7 @@ from ray_tpu.ops import ssd as ssd_module
 from ray_tpu.ops import ssd_kernel
 from ray_tpu.ops.ssd import ssd, ssd_xla
 from ray_tpu.parallel import MeshSpec, make_mesh
+from ray_tpu.util import first_call
 
 Q = 128
 
@@ -65,7 +66,7 @@ def test_the_kernels_are_the_recurrence(chunks):
     ``lax.scan`` in float32."""
     a = _inputs(chunks)
     assert ssd_module.path(a["x"].shape, a["B"].shape, Q,
-                           jax.sharding.get_abstract_mesh())[0] == "kernel"
+                           jax.sharding.get_abstract_mesh()) == "kernel"
     decay = jax.nn.softplus(a["dt"]) * -jnp.exp(a["A_log"])
     assert float(jnp.min(jnp.sum(decay.reshape(2, chunks, Q, -1),
                                  axis=2))) < -200  # exp(200) is no float32
@@ -190,10 +191,8 @@ def test_which_path_a_call_takes(name):
     (b, S, H, P, G, N, chunk), axes, want = PLACEMENTS[name]
     mesh = _mesh(**axes).abstract_mesh if axes \
         else jax.sharding.get_abstract_mesh()
-    taken, specs = ssd_module.path((b, S, H, P), (b, S, G, N),
-                                   min(chunk, S), mesh)
-    assert taken == want
-    assert (specs is not None) == (want == "kernel" and mesh.size > 1)
+    assert ssd_module.path((b, S, H, P), (b, S, G, N), min(chunk, S),
+                           mesh) == want
 
 
 def test_on_a_mesh_every_device_scans_its_own_rows_and_groups():
@@ -201,8 +200,6 @@ def test_on_a_mesh_every_device_scans_its_own_rows_and_groups():
     kernels run inside a ``shard_map`` (a Mosaic call cannot be partitioned)
     and output and gradients are the XLA form's, A's and D's gradients
     summed over the rows' shards."""
-    from ray_tpu.parallel.train_state import _noting
-
     a = _inputs(2, b=2, seed=7)
     dy = jax.random.normal(jax.random.key(3), a["x"].shape)
 
@@ -212,7 +209,7 @@ def test_on_a_mesh_every_device_scans_its_own_rows_and_groups():
 
     with jax.default_matmul_precision("highest"):
         want, grads_xla = loss(ssd_xla)(a)
-        with jax.set_mesh(_mesh(data=2, tensor=2)), _noting() as notes:
+        with jax.set_mesh(_mesh(data=2, tensor=2)), first_call.noting() as notes:
             got, grads = loss(ssd)(a)
     assert notes == {"ssm_scan_kernel": True, "ssm_scan_grid": [1, 1, 2]}
     assert float(got) == pytest.approx(float(want), rel=1e-5)
@@ -221,14 +218,12 @@ def test_on_a_mesh_every_device_scans_its_own_rows_and_groups():
 
 
 def test_the_first_call_record_says_which_ran():
-    from ray_tpu.parallel.train_state import _noting
-
     a = _inputs(2)
-    with _noting() as notes:
+    with first_call.noting() as notes:
         jax.eval_shape(lambda a: _run(ssd, a), a)
     assert notes == {"ssm_scan_kernel": True, "ssm_scan_grid": [2, 2, 2]}
     small = {k: (v[:, :64] if v.ndim > 1 else v) for k, v in a.items()}
-    with _noting() as notes:
+    with first_call.noting() as notes:
         jax.eval_shape(lambda a: ssd(
             a["x"], a["dt"], a["A_log"], a["B"], a["C"], a["D"], 64), small)
     assert notes == {"ssm_scan_kernel": False, "ssm_scan_grid": None}

@@ -467,3 +467,81 @@ def test_scope_names_are_under_the_registry_check():
               if f.detail.startswith("scope-unused:")}
     assert unused == {f"scope-unused:{s}" for s in tracing.SCOPE_REGISTRY
                       if s != "mlp"}
+
+
+def test_ops_import_nothing_above_them():
+    """``parallel/`` and ``train/`` import ``ops/``; no file under ``ops/``
+    imports them back, at the top or inside a function (what a traced
+    kernel says of itself goes through ``util/first_call.py``)."""
+    import ast
+
+    above = ("ray_tpu.parallel", "ray_tpu.train")
+    found = []
+    for path in sorted(glob.glob(os.path.join(REPO, "ray_tpu", "ops",
+                                              "*.py"))):
+        with open(path) as f:
+            tree = ast.parse(f.read())
+        for node in ast.walk(tree):
+            names = [a.name for a in node.names] \
+                if isinstance(node, ast.Import) else \
+                [node.module or ""] + [f"{node.module}.{a.name}"
+                                       for a in node.names] \
+                if isinstance(node, ast.ImportFrom) else []
+            found += [(os.path.basename(path), node.lineno, name)
+                      for name in names if name.startswith(above)]
+    assert found == []
+
+
+# ------------------------------------------------ the first-call record
+_REMAT = {"remat_kept", "remat_kept_bytes", "remat_room_bytes"}
+_STEP = {"remat_fallback", "grad_ring_products", "grad_ring_axis"}
+_LLAMA = _STEP | _REMAT | {"experts_held", "experts_total", "block_length",
+                           "attn_positions", "loss_positions"}
+_HYBRID = _STEP | _REMAT | {
+    "layer_kinds", "loss_positions", "attn_positions", "heads_held",
+    "heads_total", "attn_gate", "experts_held", "experts_total",
+    "router_scoring"}
+#: the keys of each tiny family's first-call record (no splash kernel on the
+#: CPU, so no ``attn_*`` geometry), recorded on PR 46's parent; since PR 46
+#: ``tiny-nemotron-h`` also carries the attention kind's ``heads_held``,
+#: ``heads_total`` and ``attn_gate``, which the parent noted only beside ``K``
+FIRST_CALL_KEYS = {
+    "tiny-gpt2": _STEP,
+    "tiny-llama": _LLAMA, "tiny-olmoe": _LLAMA, "tiny-sdar": _LLAMA,
+    "tiny-nemotron-h": _HYBRID | {
+        "ssm_heads", "ssm_state", "ssm_chunk", "ssm_chunks",
+        "ssm_scan_kernel", "ssm_scan_grid"},
+    "tiny-solar-open2": _HYBRID | {
+        "kda_heads", "kda_head_dim", "kda_chunk", "kda_chunks",
+        "kda_scan_kernel", "kda_scan_grid"},
+}
+
+
+@pytest.mark.parametrize("name", sorted(FIRST_CALL_KEYS))
+def test_the_first_call_record_carries_the_parents_keys(name):
+    """What a ``TrainStep`` would record of each family's tiny step, from a
+    trace of it under the block its call opens; every key is listed in
+    ``util/first_call.py``'s docstring."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.lib import spec
+    from ray_tpu.parallel.train_state import _first_call_notes
+    from ray_tpu.util import first_call
+
+    config = spec.load_json(spec.BENCH_DIR, "configs", name + ".json")
+    family = spec.load_module("models", config["family"]).build(config, 128)
+    optimizer = family.make_optimizer()
+    params = jax.eval_shape(family.init_fn, jax.random.key(0))
+    ids = jax.ShapeDtypeStruct((2, 128), jnp.int32)
+    with _first_call_notes() as notes:
+        jax.eval_shape(family.make_train_step(optimizer), params,
+                       jax.eval_shape(optimizer.init, params), ids, ids)
+    assert set(notes) == FIRST_CALL_KEYS[name]
+    assert all(f"``{key}``" in first_call.__doc__ for key in notes)
+    assert notes["remat_fallback"] is False \
+        and notes["grad_ring_products"] == 0
+    if name == "tiny-nemotron-h":
+        assert (notes["heads_held"], notes["heads_total"],
+                notes["attn_gate"]) == (4, 4, False)
+        assert notes["layer_kinds"] == "MEMEM*EME"
