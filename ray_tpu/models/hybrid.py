@@ -9,7 +9,12 @@ mixer and then experts, is two of these):
   (``models/kda.py`` over ``ops/kda.py``);
 - ``*``: attention (``models/attn.py``), here without rotary embedding and
   without QK-norm: the recurrent layers carry the positions;
-- ``E``: a mixture of experts (``models/experts.py`` over ``models/moe.py``).
+- ``E``: a mixture of experts (``models/experts.py`` over ``models/moe.py``);
+- ``L``: latent attention (``models/mla.py``): queries and keys from two
+  normed low-rank latents, one rotary key for all heads, a value head
+  narrower than the q.k head;
+- ``D``: a dense SwiGLU MLP (``models/dense.py``), the leading layers of a
+  model whose later layers hold experts.
 
 **A kind is a module** and one line of :data:`KINDS`; this file knows no
 kind by name.  The module (loaded with the first pattern that holds its
@@ -42,8 +47,21 @@ After the last layer a final norm and an untied head; the loss is next-token
 cross-entropy.  The functional contract is the other decoders':
 init_params / logical_axes / loss_fn / make_train_step.
 
+**A multi-token-prediction module** (``mtp_depth`` 1; the ``deepseek_v3``
+family's) is a second pass after the last layer: each position's hidden
+state, before the final norm, is joined with the embedding of the token
+after it (two norms, then ``w_eh`` over the two side by side, the embedding
+first), runs one more of the model's last layer (the pattern's last two
+kinds: a mixer and what follows it), causal over the row, and reads the same
+head, after a final norm of its own, for the token two ahead.  The step's
+loss is ``loss_main + mtp_weight x loss_mtp``, and the two leave as step
+counters.  Its parameters: ``mtp`` (the three norms and ``w_eh``) and one
+more row of its kinds' stacks.  A configuration without one (``mtp_depth``
+0) traces none of it.
+
 **Parameters.**  Layers of one kind share one stacked tree (``ssm``,
-``kda``, ``attn``, ``experts``, each leaf with its kind's layers in front), so the
+``kda``, ``attn``, ``experts``, ``mla``, ``dense``, each leaf with its kind's
+layers in front, the prediction module's after the pattern's), so the
 optimizer, the sharding rules and a checkpoint see a stack a kind and not
 ``len(pattern)`` trees.  The stack runs unrolled: layer i takes row
 ``pattern[:i].count(kind)`` of its kind's stack.  (A pattern that repeats
@@ -62,7 +80,7 @@ import importlib
 import math
 from dataclasses import dataclass
 from functools import partial
-from typing import Any, Dict, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -73,6 +91,7 @@ from ray_tpu.ops.lm_head import lm_head_cross_entropy
 from ray_tpu.parallel.train_state import make_optimizer  # noqa: F401
 from ray_tpu.parallel.train_state import make_train_step as _make_train_step
 from ray_tpu.util import first_call
+from ray_tpu.util.tracing import step_counter
 
 
 @dataclass(frozen=True)
@@ -97,7 +116,12 @@ class Kind:
 KINDS = {"M": Kind("ssm", "ray_tpu.models.mamba2", 2),
          "*": Kind("attn", "ray_tpu.models.attn", 3),
          "E": Kind("experts", "ray_tpu.models.experts", 4),
-         "K": Kind("kda", "ray_tpu.models.kda", 5)}
+         "K": Kind("kda", "ray_tpu.models.kda", 5),
+         "L": Kind("mla", "ray_tpu.models.mla", 6),
+         "D": Kind("dense", "ray_tpu.models.dense", 7)}
+
+#: folded into ``init_params``' key for the prediction module's ``w_eh``
+MTP_DRAW = 47
 
 
 @dataclass(frozen=True)
@@ -160,6 +184,24 @@ class HybridConfig:
     router_bias_seed: int = 0
     #: spread of the selection bias's draw; 0: no bias
     router_bias_std: float = 0.0
+    # ``L``: models/mla.py
+    mla_heads: int = 4
+    #: the width of the query's and of the keys' and values' latent
+    mla_q_latent: int = 48
+    mla_kv_latent: int = 32
+    #: a q.k head: its lanes without position, then its rotary lanes
+    mla_nope_dim: int = 16
+    mla_rope_dim: int = 8
+    mla_v_dim: int = 16
+    mla_rope_theta: float = 10000.0
+    #: the rotary pairs are lanes (2i, 2i + 1), not (i, i + half)
+    mla_rope_interleave: bool = True
+    # ``D``: models/dense.py
+    dense_width: int = 256
+    #: multi-token-prediction modules after the last layer (0 or 1) and the
+    #: weight of a module's loss in the step's
+    mtp_depth: int = 0
+    mtp_weight: float = 0.3
 
     @property
     def held(self) -> range:
@@ -172,6 +214,17 @@ class HybridConfig:
 
     def count(self, kind: str) -> int:
         return self.pattern.count(kind)
+
+    @property
+    def mtp_kinds(self) -> str:
+        """The kinds of the prediction module's layers, one more of the
+        model's last layer (a mixer and what follows it: the pattern's last
+        two kinds); empty without one."""
+        return self.pattern[-2:] * self.mtp_depth
+
+    def rows(self, kind: str) -> int:
+        """Of the kind's stack: the pattern's layers, then the module's."""
+        return self.count(kind) + self.mtp_kinds.count(kind)
 
     @staticmethod
     def tiny() -> "HybridConfig":
@@ -192,8 +245,21 @@ class HybridConfig:
             experts_held=range(4, 8), d_ff=48, shared_width=48,
             expert_activation="silu", gated_experts=True, routed_scaling=1.0)
 
+    @staticmethod
+    def tiny_joyai() -> "HybridConfig":
+        """JoyAI-LLM-Flash's shape in small: a dense layer and two expert
+        layers, each after latent attention (4 heads of 16 + 8 for q.k and
+        16 for v over latents of 48 and 32), experts 4-7 of 16 held, 2 a
+        token, SwiGLU experts and shared expert, a selection bias, and one
+        prediction module."""
+        return HybridConfig(
+            pattern="LDLELE", mtp_depth=1, experts_held=range(4, 8), d_ff=48,
+            shared_width=48, expert_activation="silu", gated_experts=True,
+            router_bias_std=0.05)
+
     def __post_init__(self):
         assert self.pattern and set(self.pattern) <= set(KINDS), self.pattern
+        assert self.mtp_depth in (0, 1), self.mtp_depth
         held = self.held
         assert held.step == 1 and 0 <= held.start < held.stop \
             <= self.n_experts, held
@@ -226,7 +292,12 @@ def init_params(config: HybridConfig, key) -> Dict[str, Any]:
         params[entry.stack] = entry.module.init_params(
             config, keys[entry.draw] if entry.draw < len(keys)
             else jax.random.fold_in(key, entry.draw),
-            config.count(kind), out_std)
+            config.rows(kind), out_std)
+    if config.mtp_depth:
+        params["mtp"] = {
+            "embed_norm": jnp.ones((D,)), "hidden_norm": jnp.ones((D,)),
+            "w_eh": norm(jax.random.fold_in(key, MTP_DRAW), (2 * D, D), std),
+            "final_norm": jnp.ones((D,))}
     return params
 
 
@@ -235,24 +306,33 @@ def logical_axes(config: HybridConfig) -> Dict[str, Any]:
             "lm_head": ("vocab", "embed")}
     for entry in _kinds(config).values():
         axes[entry.stack] = entry.module.logical_axes(config)
+    if config.mtp_depth:
+        axes["mtp"] = {"embed_norm": ("norm",), "hidden_norm": ("norm",),
+                       "w_eh": ("embed", None), "final_norm": ("norm",)}
     return axes
 
 
 def num_params(config: HybridConfig) -> int:
-    return 2 * config.vocab_size * config.d_model + config.d_model \
+    D = config.d_model
+    return 2 * config.vocab_size * D + D \
         + sum(KINDS[kind].module.num_params(config)
-              for kind in config.pattern)
+              for kind in config.pattern + config.mtp_kinds) \
+        + config.mtp_depth * (2 * D * D + 3 * D)
 
 
 def flops_per_token(config: HybridConfig) -> float:
     """Per trained token: 6 x the matrix parameters a position meets (of the
     held experts its own, in expectation under an even router) plus 3 x what
     each layer's mixer adds beside them (causal attention, the state-space
-    scan's products, the delta rule's)."""
+    scan's products, the delta rule's).  A prediction module adds its
+    block's layers, ``w_eh`` and a second pass through the head."""
     routed = config.experts_per_token * len(config.held) / config.n_experts
-    layers = [KINDS[kind].module for kind in config.pattern]
+    layers = [KINDS[kind].module
+              for kind in config.pattern + config.mtp_kinds]
+    D = config.d_model
     return 6.0 * (sum(m.matmul_params(config, routed) for m in layers)
-                  + config.vocab_size * config.d_model) \
+                  + (1 + config.mtp_depth) * config.vocab_size * D
+                  + config.mtp_depth * 2 * D * D) \
         + 3.0 * sum(m.mixer_flops(config, config.seq_len) for m in layers)
 
 
@@ -291,34 +371,37 @@ def _layer_sizes(params, x_shape, config: HybridConfig):
                                             tensor, item)
              for kind, entry in kinds.items()}
     candidates = dict.fromkeys(remat.LADDER, 0)
-    kept_inputs = config.n_layer * tokens * config.d_model * item
-    for kind in config.pattern:
+    layers = config.pattern + config.mtp_kinds
+    kept_inputs = len(layers) * tokens * config.d_model * item
+    for kind in layers:
         _, kept, named = sizes[kind]
         kept_inputs += kept
         for name, nbytes in named.items():
             candidates[name] += nbytes
     in_the_layers = stacks + casts + kept_inputs \
         + max(working for working, _, _ in sizes.values())
-    at_the_head = (total - stacks) + casts + kept_inputs + 2 * tokens \
+    # with a prediction module the first pass's logits wait for the second's
+    at_the_head = (total - stacks) + casts + kept_inputs \
+        + (2 + config.mtp_depth) * tokens \
         * config.vocab_size // tensor * jnp.dtype(config.logits_dtype).itemsize
     return list(candidates.items()), max(in_the_layers, at_the_head)
 
 
-def forward_hidden(params: Dict[str, Any], tokens, config: HybridConfig):
-    """-> (final hidden states (B, S, D), the layers' step counters, each
-    stacked over the layers that leave it: the expert layers' ``moe_rows``
-    (layers, shards, held) and ``moe_moved`` (layers, shards); an empty dict
-    for a pattern whose kinds leave none)."""
-    dt = config.dtype
-    with jax.named_scope("embed"):
-        x = params["wte"][tokens].astype(dt)
-    axes = logical_axes(config)
-    policy = remat.layer_policy(*_layer_sizes(params, x.shape, config)) \
-        if config.remat else None
-    seen = dict.fromkeys(KINDS, 0)
+def _placed(kinds: str) -> List[Tuple[str, int]]:
+    """``kinds`` as (kind, its row of the kind's stack)."""
+    seen: Dict[str, int] = {}
+    placed = []
+    for kind in kinds:
+        placed.append((kind, seen.get(kind, 0)))
+        seen[kind] = placed[-1][1] + 1
+    return placed
+
+
+def _run(params, x, layers, config: HybridConfig, axes, policy):
+    """``x`` through ``layers`` (:func:`_placed`'s pairs) -> (x, the step
+    counters of the layers that leave some, in order)."""
     counts = []
-    for kind in config.pattern:
-        index, seen[kind] = seen[kind], seen[kind] + 1
+    for kind, index in layers:
         stack = KINDS[kind].stack
         layer = KINDS[kind].module.layer(config, axes[stack], index)
         if config.remat:
@@ -326,30 +409,91 @@ def forward_hidden(params: Dict[str, Any], tokens, config: HybridConfig):
         x, counted = layer(x, jax.tree.map(lambda a: a[index], params[stack]))
         if counted is not None:
             counts.append(counted)
-    with jax.named_scope("lm_head"):
-        x = rmsnorm(x, params["final_norm"], config.rms_eps).astype(dt)
-    return x, {name: jnp.stack([c[name] for c in counts])
-               for name in (counts[0] if counts else ())}
+    return x, counts
+
+
+def _stacked(counts) -> Dict[str, Any]:
+    """The layers' counters, each stacked over the layers that leave it; an
+    empty dict where none does."""
+    return {name: jnp.stack([c[name] for c in counts])
+            for name in (counts[0] if counts else ())}
+
+
+def _predict_ahead(params, x, targets, run, config: HybridConfig):
+    """The prediction module over the last layer's output ``x`` (before the
+    final norm) and the row's ``targets`` (position i's is the token after
+    it) -> (the mean over i < S - 1 of the cross-entropy of the token two
+    ahead, the module's layers' counters in order).  ``run``: a row's
+    activations through the module's layers (:func:`_run` with the step's
+    axes and policy).  The last position, which has no token two ahead,
+    goes through the block and weighs nothing."""
+    dt = config.dtype
+    mtp = params["mtp"]
+    B, S = targets.shape
+    with jax.named_scope("mtp"):
+        ahead = rmsnorm(params["wte"][targets].astype(dt), mtp["embed_norm"],
+                        config.rms_eps).astype(dt)
+        here = rmsnorm(x, mtp["hidden_norm"], config.rms_eps).astype(dt)
+        z = jnp.concatenate([ahead, here], axis=-1) @ mtp["w_eh"].astype(dt)
+    z, counts = run(z)
+    with jax.named_scope("mtp_head"):
+        z = rmsnorm(z, mtp["final_norm"], config.rms_eps).astype(dt)
+        weights = jnp.broadcast_to(
+            (jnp.arange(S) < S - 1) / (B * (S - 1)), (B, S))
+        ce = lm_head_cross_entropy(
+            z, params["lm_head"].astype(dt), jnp.roll(targets, -1, axis=1),
+            config.logits_dtype, weights)
+    return ce, counts
 
 
 def loss_fn(params, tokens, targets, config: HybridConfig):
-    """Mean next-token cross-entropy of ``tokens`` against ``targets``; the
+    """Mean next-token cross-entropy of ``tokens`` against ``targets`` (with
+    a prediction module, plus ``mtp_weight`` times its own); the
     configuration names no auxiliary loss and none is added."""
     return loss_and_counters(params, tokens, targets, config)[0]
 
 
 def loss_and_counters(params, tokens, targets, config: HybridConfig):
     """-> (:func:`loss_fn`'s scalar, the step counters of
-    ``tracing.STEP_COUNTER_REGISTRY`` the layers leave)."""
+    ``tracing.STEP_COUNTER_REGISTRY`` the step leaves): the layers', each
+    stacked over the layers that leave it (the expert layers' ``moe_rows``
+    (layers, shards, held) and ``moe_moved`` (layers, shards), the
+    prediction module's layer last; none for a pattern whose kinds leave
+    none), and with a prediction module the two losses the scalar sums,
+    ``loss_main`` and ``loss_mtp``."""
     rows, S = tokens.shape
+    dt = config.dtype
     first_call.note(layer_kinds=config.pattern, loss_positions=S)
     for entry in _kinds(config).values():
         first_call.note(**entry.module.first_call_facts(config, rows, S))
-    x, counts = forward_hidden(params, tokens, config)
+    with jax.named_scope("embed"):
+        x = params["wte"][tokens].astype(dt)
+    policy = remat.layer_policy(*_layer_sizes(params, x.shape, config)) \
+        if config.remat else None
+    run = partial(_run, params, config=config, axes=logical_axes(config),
+                  policy=policy)
+    placed = _placed(config.pattern + config.mtp_kinds)
+    x, counts = run(x, placed[:config.n_layer])
     with jax.named_scope("lm_head"):
-        ce = lm_head_cross_entropy(x, params["lm_head"].astype(config.dtype),
-                                   targets, config.logits_dtype, None)
-    return ce, counts
+        hidden = rmsnorm(x, params["final_norm"], config.rms_eps).astype(dt)
+    # between the norm and the head, where the older steps' text has it
+    counts = _stacked(counts)
+    with jax.named_scope("lm_head"):
+        loss = lm_head_cross_entropy(
+            hidden, params["lm_head"].astype(dt), targets,
+            config.logits_dtype, None)
+    if config.mtp_depth:
+        first_call.note(mtp_depth=config.mtp_depth,
+                        mtp_weight=config.mtp_weight)
+        ahead, more = _predict_ahead(
+            params, x, targets, partial(run, layers=placed[config.n_layer:]),
+            config)
+        for name, rows in _stacked(more).items():
+            counts[name] = jnp.concatenate([counts[name], rows])
+        counts.update({step_counter("loss_main"): loss,
+                       step_counter("loss_mtp"): ahead})
+        loss = loss + config.mtp_weight * ahead
+    return loss, counts
 
 
 def make_train_step(config: HybridConfig, optimizer):

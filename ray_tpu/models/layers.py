@@ -43,52 +43,77 @@ def rmsnorm(x, scale, eps):
     return x32 * lax.rsqrt(ms + eps) * scale
 
 
-def _rope_pass(x, theta: float, direction: float):
-    """x * cos + swap_halves(x) * (-sin | +sin) over (B, S, H, hd): the
-    rotate-half pairing (i, i + hd/2) as published, cos and sin from float32
-    angles, products and sum in float32, one rounding to x's dtype.
-    ``direction`` 1.0 rotates each pair by its position's angle, -1.0 back.
+def _rope_pass(x, theta: float, direction: float, rotary=None,
+               interleave: bool = False):
+    """x * cos + partner(x) * (-sin | +sin) over (B, S, H, hd), cos and sin
+    from float32 angles, products and sum in float32, one rounding to x's
+    dtype.  ``direction`` 1.0 rotates each pair by its position's angle,
+    -1.0 back.  The pairing is rotate-half, lanes (i, i + hd/2), as the
+    Llama family publishes it, or with ``interleave`` lanes (2i, 2i + 1), as
+    the ``deepseek_v3`` family does (``rope_interleave``).  With ``rotary``
+    only the head's last ``rotary`` lanes rotate, over frequencies of their
+    own (a latent-attention head: 128 lanes without position, then 64 with);
+    the lanes before them pass at an angle of zero.
 
     Everything stays hd wide so that the compiler makes it one pass, x read
     once and the result written once in x's dtype.  Slicing the two halves
     (or ``jnp.roll``, which is two slices) gave arrays of 64 lanes padded to
     128 and a float32 copy of x in HBM: three passes forward and three
     backward, 0.6 and 0.95 GB a layer for Mistral's q at 8192 tokens where
-    this needs 0.2 and 0.13 (PERF.md, PR 27).  The halves are swapped by a
+    this needs 0.2 and 0.13 (PERF.md, PR 27).  The partners are swapped by a
     product with a 0/1 permutation instead: each output is one input times
     one, so it is exact, and cos, sin and the cast fuse into its output.
     """
     hd = x.shape[-1]
-    half = hd // 2
+    rot = hd if rotary is None else rotary
+    half = rot // 2
     lane = jnp.arange(hd)
-    # (S, hd): lanes i and i + hd/2 share a frequency, so an angle
-    freqs = 1.0 / (theta ** ((lane % half).astype(jnp.float32) / half))
+    # a lane's place in the rotary part; negative before it.  (Where the
+    # whole head rotates nothing is traced for the part: the older models'
+    # lowered steps are pinned by their text, tests/test_nemotron_h.py.)
+    at = lane if rot == hd else lane - (hd - rot)
+    # (S, hd): the two lanes of a pair share a frequency, so an angle
+    pair = at // 2 if interleave else at % half
+    freqs = 1.0 / (theta ** (pair.astype(jnp.float32) / half))
+    if rot != hd:
+        freqs = jnp.where(at >= 0, freqs, 0.0)
     angles = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * freqs[None, :]
-    sign = jnp.where(lane < half, -direction, direction)
+    first = at % 2 == 0 if interleave else at < half
+    sign = jnp.where(first, -direction, direction)
+
+    def partner(of):
+        if interleave:
+            return of ^ 1  # rot is even, so a pair lies inside the part
+        if rot == hd:
+            return (of + half) % hd
+        return (of - (hd - rot) + half) % rot + (hd - rot)
+
     cos = jnp.cos(angles)[None, :, None, :]
     sin = (jnp.sin(angles) * sign)[None, :, None, :]
-    swap = (lane[:, None] == (lane[None, :] + half) % hd).astype(x.dtype)
+    swap = (lane[:, None] == partner(lane[None, :])).astype(x.dtype)
     swapped = jnp.einsum("bshd,de->bshe", x, swap,
                          preferred_element_type=jnp.float32,
                          precision=lax.Precision.HIGHEST)
     return (x.astype(jnp.float32) * cos + swapped * sin).astype(x.dtype)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(1,))
-def rope(x, theta: float):
-    """Rotary position embedding over (B, S, H, hd), rotate-half form, in
-    and out in x's dtype.  The backward is the same pass with the sine
+@partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
+def rope(x, theta: float, rotary=None, interleave: bool = False):
+    """Rotary position embedding over (B, S, H, hd), in and out in x's
+    dtype: rotate-half form over the whole head by default, over the head's
+    last ``rotary`` lanes and in the ``interleave``d pairing where asked
+    (:func:`_rope_pass`).  The backward is the same pass with the sine
     negated (a rotation's transpose is the rotation back), not autodiff's
     sum over sliced halves."""
-    return _rope_pass(x, theta, 1.0)
+    return _rope_pass(x, theta, 1.0, rotary, interleave)
 
 
-def _rope_fwd(x, theta):
-    return _rope_pass(x, theta, 1.0), None
+def _rope_fwd(x, theta, rotary, interleave):
+    return _rope_pass(x, theta, 1.0, rotary, interleave), None
 
 
-def _rope_bwd(theta, _, g):
-    return (_rope_pass(g, theta, -1.0),)
+def _rope_bwd(theta, rotary, interleave, _, g):
+    return (_rope_pass(g, theta, -1.0, rotary, interleave),)
 
 
 rope.defvjp(_rope_fwd, _rope_bwd)
@@ -153,16 +178,16 @@ def attention(x, blk, config, axes):
 
 def feed_forward(x, blk, config, axes, **expert_layer):
     """The feed-forward half.  -> (x + its output, what the expert layer
-    says of itself): the second is ``None`` for a dense MLP; with experts it
-    is (moe.router_losses' pair, the layer's counts: ``moe.moe_mlp``).
-    Reads ``dtype``, ``rms_eps``, ``n_experts`` and, with experts,
+    says of itself): the second is ``None`` for a dense MLP; with experts
+    (``blk`` holds a ``router``) it is (moe.router_losses' pair, the layer's
+    counts: ``moe.moe_mlp``).  Reads ``dtype``, ``rms_eps`` and, with experts,
     ``experts_per_token``, ``norm_topk_prob`` and ``held``; of ``blk``
     ``mlp_norm`` and the SwiGLU's ``w_gate``, ``w_up``, ``w_down``, or what
     ``moe.moe_mlp`` reads, which is also handed ``expert_layer``."""
     dt = config.dtype
     with jax.named_scope("mlp"):
         h = rmsnorm(x, blk["mlp_norm"], config.rms_eps)
-        if config.n_experts:
+        if "router" in blk:
             # the router reads the norm's float32 output, the experts its
             # cast to the compute dtype
             y, router_losses, counts = _moe.moe_mlp(

@@ -21,7 +21,11 @@ with it:
 
 q is (B, S, H, head_dim) and k, v are (B, S, KV, head_dim) with H a multiple
 of KV: grouped-query attention arrives at its own head count (KV == H is plain
-MHA).  The splash kernel takes K and V so: it reads the K/V head ``h // (H //
+MHA).  v may come at a head dimension of its own (a latent-attention layer's
+q.k head is 192 wide and its v head 128, ``models/mla.py``): the output is
+then as wide as v, and the scale and the block rule read q's.  The splash
+kernel and the einsum take it so; the context-parallel paths do not.  The
+splash kernel takes K and V so: it reads the K/V head ``h // (H //
 KV)`` for query head ``h`` and sums dk and dv over the group in VMEM, so
 nothing copies K and V out to H heads in HBM, forward or backward (0.55 GB a
 layer for Mistral-7B at 8192 tokens, PERF.md PR 27).  The other three paths
@@ -41,10 +45,10 @@ kernel, and a block the mask cuts through reads one of three stored tiles.
 No 2S x 2S array exists.
 
 The blocks a call runs in follow the row it runs on: :func:`splash_blocks`
-picks them where the kernel is built, from the row's kv length and the head
-dimension (1024 x 1024 at head 128 on a row of 2048 or more, 512 x 512
-otherwise, from a sweep on the v5e), the forward's and the fused backward's
-apart.  What the call costs a
+picks them where the kernel is built, from the row's kv length and the q.k
+head dimension (1024 x 1024 at head 128 or 192 on a row of 2048 or more, 512
+x 512 otherwise, from sweeps on the v5e), the forward's and the fused
+backward's apart.  What the call costs a
 head (``attn_calls``, ``attn_blocks``, ``attn_blocks_cut``,
 ``attn_grid_steps_fwd``, ``attn_grid_steps_bwd``) and in what blocks
 (``attn_block_q``, ``attn_block_kv``, ``attn_block_q_bwd``,
@@ -93,7 +97,8 @@ save_splash_residuals = jax.checkpoint_policies.save_only_these_names(
 def causal_attention(q, k, v, impl: str):
     """Causal attention by the implementation ``impl`` names (a model's
     ``attn_impl``; see the module docstring).  q: (B, S, H, head_dim); k, v:
-    (B, S, KV, head_dim), H a multiple of KV.  -> (B, S, H, head_dim)."""
+    (B, S, KV, head_dim), H a multiple of KV, v's head dimension its own
+    where it differs.  -> (B, S, H, v's head_dim)."""
     return _attention(q, k, v, impl, 0)
 
 
@@ -178,7 +183,7 @@ class SplashBlocks(NamedTuple):
 
 def splash_blocks(kv_len: int, head_dim: int) -> SplashBlocks:
     """The blocks a splash call runs in, from what the call site holds: the
-    row's kv length and the head dimension.  Swept on the v5e at the seven
+    row's kv length and the q.k head dimension.  Swept on the v5e at the seven
     shapes the benchmark's cells run, forward and forward + fused backward +
     the sum of its dq partials, then held against the cells' traces (PERF.md,
     PR 42; ``scripts/splash_block_sweep.py``):
@@ -193,17 +198,25 @@ def splash_blocks(kv_len: int, head_dim: int) -> SplashBlocks:
       kv block of 2048 halves the partials again and computes as much more
       under the mask) or exceed the scoped VMEM (a q block of 2048 with most
       kv blocks; kv blocks of 2048 with a compute sub-block that long).
+    - a q.k head of 192 over a v head of 128 (latent attention; a row of
+      8192, 32 heads, swept at PR 47): the same blocks.  Against 512 the
+      forward is 12 % faster (7.08 against 8.02 ms) and forward + backward
+      9 % (25.3 against 27.9): a quarter of the grid steps and half the dq
+      partials, as at 128.  A compute sub-block of 1024 reads the same
+      within 1 %; q or kv blocks of 2048 are refused for scoped VMEM with
+      most partners, sooner than at 128 (the tiles are half again as wide).
     - a row of 1024 keeps blocks of 512: one block as long as the row
       computes the whole square under the mask where four blocks skip a
       quarter of it, and in the cell's trace the kernels were no faster
       (+0.9 %), though alone they had measured 4-8 %.
     - another head dimension keeps blocks of 512: at head 64 the larger
       blocks measured 3 % of the forward and nothing of the backward, and
-      over 128 nothing is measured and the tiles' VMEM grows with it.
+      over 192 nothing is measured and the tiles' VMEM grows with it.
 
     A row that 1024 does not cut evenly keeps 512 too; no block is longer
     than the row."""
-    wide = head_dim == 128 and kv_len >= 2048 and kv_len % 1024 == 0
+    wide = head_dim in (128, 192) and kv_len >= 2048 \
+        and kv_len % 1024 == 0
     block = 1024 if wide else 512
     return SplashBlocks(block, block, 512, block, block, 512).capped(kv_len)
 
@@ -364,7 +377,9 @@ def splash_attention(q, k, v, causal: bool = True,
 
     q: (B, S, H, head_dim), k and v: (B, S, KV, head_dim), the model's native
     layout; H is a multiple of KV, and query head ``h`` attends to K/V head
-    ``h // (H // KV)`` (``jnp.repeat``'s order).  KV == H is plain MHA.
+    ``h // (H // KV)`` (``jnp.repeat``'s order).  KV == H is plain MHA.  v's
+    head dimension may be its own (the library's ``head_dim_v``): the output
+    has it, and the default scale and the blocks follow q's.
 
     On more than one device the kernel must run inside a ``shard_map`` that
     makes every mesh axis manual: the SPMD partitioner cannot split a Mosaic

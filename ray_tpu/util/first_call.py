@@ -40,8 +40,9 @@ and ``seconds``, the span ``compile_s``):
                               ``block_length``, ``attn_positions``,
                               ``loss_positions``
 ``models/hybrid.py``          ``layer_kinds`` (the pattern run),
-                              ``loss_positions``; then each kind of the
-                              pattern its own:
+                              ``loss_positions``; with a prediction module
+                              ``mtp_depth``, ``mtp_weight``; then each kind
+                              of the pattern its own:
 ``models/attn.py`` (``*``)    ``attn_positions``, ``heads_held``,
                               ``heads_total``, ``attn_gate``
 ``models/experts.py`` (``E``) ``experts_held``, ``experts_total``,
@@ -50,6 +51,11 @@ and ``seconds``, the span ``compile_s``):
                               ``ssm_chunks`` (S / chunk x rows)
 ``models/kda.py`` (``K``)     ``kda_heads``, ``kda_head_dim``,
                               ``kda_chunk``, ``kda_chunks``
+``models/mla.py`` (``L``)     ``attn_positions``, ``mla_heads``,
+                              ``mla_qk_head_dim``, ``mla_v_head_dim``,
+                              ``mla_latents`` (the query's and the
+                              key-value latent's width)
+``models/dense.py`` (``D``)   ``dense_width``
 ============================  ==============================================
 """
 

@@ -159,6 +159,10 @@ SCOPE_REGISTRY: Dict[str, str] = {
             "output gate where the layer has one, out-projection",
     "attn_kernel": "the attention kernel call itself, nested inside attn "
                    "(splash/ring/ulysses/XLA, with its layout changes)",
+    "latent": "latent-attention layer (models/mla.py), nested inside attn: "
+              "the two down-projections, the latent norms, the two "
+              "up-projections, the rotary passes, assembling k (all of the "
+              "mixer but the pre-norm, the kernel and the out-projection)",
     "mlp": "MLP part of a block: norm to down-projection (with experts: "
            "the norm, the weights' casts and the residual add around the "
            "three scopes below)",
@@ -195,6 +199,12 @@ SCOPE_REGISTRY: Dict[str, str] = {
              "draw of the masked positions, the noised copy, the "
              "concatenation with the clean one, the loss weights",
     "lm_head": "final norm, logits, loss",
+    "mtp": "multi-token-prediction module (models/hybrid.py): the norms of "
+           "the next token's embedding and of the last layer's output, "
+           "their join and its projection; the module's block opens its "
+           "kinds' own scopes",
+    "mtp_head": "multi-token-prediction module: its final norm and its pass "
+                "through the shared head, logits and loss",
     "optimizer": "optimizer.update + apply_updates (gradient clipping is "
                  "inside the optax chain, so inside the scope)",
 }
@@ -219,6 +229,12 @@ STEP_COUNTER_REGISTRY: Dict[str, str] = {
                  "(layers, batch shards): moe.window_rows for each window "
                  "of the held run that the step's own count made it walk "
                  "(every pair a layer sorts when the run is that long)",
+    "loss_main": "a decoder with a multi-token-prediction module "
+                 "(models/hybrid.py): the next-token cross-entropy, float32 "
+                 "scalar, one of the two terms of the step's loss",
+    "loss_mtp": "the same decoder: the module's cross-entropy of the token "
+                "two ahead, mean over the positions that have one, float32 "
+                "scalar; the step's loss adds mtp_weight times it",
 }
 
 

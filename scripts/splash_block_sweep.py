@@ -33,7 +33,8 @@ import sweep_common as sweep
 from ray_tpu.ops import attention
 from ray_tpu.ops.attention import SplashBlocks
 
-#: name, cell(s), mask kind, positions, query heads, kv heads, head_dim, batch
+#: name, cell(s), mask kind, positions, query heads, kv heads, head_dim (one
+#: number, or the q.k head's and the v head's), batch
 SHAPES = [
     ("blockdiff-16384-32x4-128-b1", "sdar-ep8-s8192", "block_diffusion",
      16384, 32, 4, 128, 1),
@@ -49,9 +50,12 @@ SHAPES = [
      1024, 32, 8, 128, 8),
     ("causal-1024-25x25-64-b16", "gpt2xl-s1024", "causal",
      1024, 25, 25, 64, 16),
+    ("causal-8192-32x32-192v128-b1", "joyai-ep16-s8192", "causal",
+     8192, 32, 32, (192, 128), 1),
 ]
 TINY = [("blockdiff-512-4x2-32-b1", "-", "block_diffusion", 512, 4, 2, 32, 1),
-        ("causal-256-4x2-32-b2", "-", "causal", 256, 4, 2, 32, 2)]
+        ("causal-256-4x2-32-b2", "-", "causal", 256, 4, 2, 32, 2),
+        ("causal-256-2x2-48v32-b1", "-", "causal", 256, 2, 2, (48, 32), 1)]
 BLOCK_LENGTH = 4
 
 
@@ -70,6 +74,7 @@ def measure(shape, blocks, backward: bool, args, topo_sharding=None):
     """One candidate at one shape: a row of the output.  With a sharding of a
     described device the call is compiled and not run."""
     name, cell, kind, S, H, KV, hd, B = shape
+    hd, hd_v = hd if isinstance(hd, tuple) else (hd, hd)
     row = {"shape": name, "cell": cell, "pass": "fwd+bwd" if backward
            else "fwd", "blocks": list(blocks)}
 
@@ -84,9 +89,9 @@ def measure(shape, blocks, backward: bool, args, topo_sharding=None):
         _, pull = jax.vjp(run, q, k, v)
         return pull(do)
 
-    shapes = [((B, H, S, hd), jnp.bfloat16), ((B, KV, S, hd), jnp.bfloat16)]
-    q, kv = sweep.abstract(shapes, topo_sharding)
-    operands = (q, kv, kv, q)
+    shapes = [((B, heads, S, width), jnp.bfloat16) for heads, width in (
+        (H, hd), (KV, hd), (KV, hd_v), (H, hd_v))]  # q, k, v, do
+    operands = tuple(sweep.abstract(shapes, topo_sharding))
     if topo_sharding is None:
         keys = jax.random.split(jax.random.key(0), 4)
         operands = [jax.random.normal(k, a.shape, a.dtype)
@@ -115,6 +120,7 @@ def main():
     with open(args.out, "a") as out:
         for shape in shapes:
             S, hd = shape[3], shape[6]
+            hd = hd[0] if isinstance(hd, tuple) else hd  # the rule reads q.k's
             square = SplashBlocks.square(base)
             rule = attention.splash_blocks(S, hd)
             plan = [("fwd", square._replace(q=q, kv=kv, kv_compute=kvc), False)

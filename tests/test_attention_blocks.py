@@ -14,7 +14,8 @@ from ray_tpu.ops import attention
 from ray_tpu.ops.attention import SplashBlocks
 
 #: cell -> the kernel's shape there (positions, query heads, kv heads,
-#: head_dim, rows, block length of a block-diffusion row) and the blocks the
+#: head_dim (the q.k head's and the v head's where they differ), rows, block
+#: length of a block-diffusion row) and the blocks the
 #: sweep on the chip chose (q, kv, kv_compute forward, then the same of the
 #: fused backward)
 WIDE, NARROW = (1024, 1024, 512, 1024, 1024, 512), (512,) * 6
@@ -26,12 +27,19 @@ CELLS = {
     "olmoe-s4096": ((4096, 16, 16, 128, 2, 0), WIDE),
     "mistral7b-s1024": ((1024, 32, 8, 128, 8, 0), NARROW),
     "gpt2xl-s1024": ((1024, 25, 25, 64, 16, 0), NARROW),
+    "joyai-ep16-s8192": ((8192, 32, 32, (192, 128), 1, 0), WIDE),
 }
+
+
+def _head_dims(head_dim):
+    """(the q.k head's, the v head's)."""
+    return head_dim if isinstance(head_dim, tuple) else (head_dim, head_dim)
 
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_the_rule_picks_what_the_sweep_chose(cell):
     (kv_len, _, _, head_dim, _, block_length), want = CELLS[cell]
+    head_dim = _head_dims(head_dim)[0]  # the rule reads the q.k head's
     blocks = attention.splash_blocks(kv_len, head_dim)
     assert tuple(blocks) == want
     # what the first-call record will say of the call (no kernel runs)
@@ -186,6 +194,8 @@ def test_the_rules_blocks_compile_for_the_v5e(monkeypatch, one_chip):
     jax.config.update("jax_enable_compilation_cache", False)
     try:
         for cell, ((S, H, KV, hd, B, Bk), _) in CELLS.items():
+            hd, hd_v = _head_dims(hd)
+
             def call(q, k, v, do):
                 kernel, _ = attention._splash_kernel(S, H, hd, True, Bk)
                 _, pull = jax.vjp(jax.vmap(kernel), q, k, v)
@@ -193,9 +203,10 @@ def test_the_rules_blocks_compile_for_the_v5e(monkeypatch, one_chip):
 
             q = jax.ShapeDtypeStruct((B, H, S, hd), jnp.bfloat16,
                                      sharding=one_chip)
-            kv = jax.ShapeDtypeStruct((B, KV, S, hd), jnp.bfloat16,
-                                      sharding=one_chip)
-            text = jax.jit(call).lower(q, kv, kv, q).compile().as_text()
+            k, v, do = (jax.ShapeDtypeStruct((B, heads, S, width),
+                                             jnp.bfloat16, sharding=one_chip)
+                        for heads, width in ((KV, hd), (KV, hd_v), (H, hd_v)))
+            text = jax.jit(call).lower(q, k, v, do).compile().as_text()
             # one forward, one backward
             assert text.count("tpu_custom_call") == 2, cell
     finally:

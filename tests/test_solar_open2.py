@@ -12,6 +12,7 @@ tiny sizes, attention on the einsum path.
 """
 
 import dataclasses
+import functools
 import subprocess
 import sys
 
@@ -260,12 +261,18 @@ def test_the_attention_head_shares_add_up_to_the_uncut_layer(shares):
         want) > 0.1
 
 
-@pytest.mark.parametrize("shares", [2, 4, 16])
-def test_the_expert_shares_add_up_to_the_uncut_layer(shares):
-    """16 SwiGLU experts cut in ``shares``: the routed parts that all the
+@pytest.mark.parametrize("family,E,k,shares", [
+    ("solar_open2", 16, 3, 2), ("solar_open2", 16, 3, 4),
+    ("solar_open2", 16, 3, 16),
+    # JoyAI-LLM-Flash's layer: 256 outputs, 8 a token, sixteen shares of 16,
+    # a selection bias that picks and does not weigh, weights times 2.5
+    ("joyai_llm_flash", 256, 8, 16)],
+    ids=["2", "4", "16", "joyai-16-of-256"])
+def test_the_expert_shares_add_up_to_the_uncut_layer(family, E, k, shares):
+    """``E`` SwiGLU experts cut in ``shares``: the routed parts that all the
     shares give, plus the SwiGLU shared expert counted once, are the uncut
     reference's layer."""
-    D, E, F, k = 32, 16, 24, 3
+    D, F = 32, 24
     ks = jax.random.split(jax.random.key(shares), 8)
     whole = {"router": jax.random.normal(ks[0], (D, E)) * 0.5,
              "w_gate": jax.random.normal(ks[1], (E, D, F)) * 0.2,
@@ -278,11 +285,19 @@ def test_the_expert_shares_add_up_to_the_uncut_layer(shares):
     cfg = {"experts_held": [0, E], "num_experts_per_tok": k,
            "norm_topk_prob": True, "routed_scaling_factor": 1,
            "n_routed_experts_published": E}
+    plain, more = spec.load_module("reference", family), {}
+    if family == "joyai_llm_flash":
+        cfg.update(routed_scaling_factor=2.5, router_bias_seed=5,
+                   router_bias_std=0.1)
+        more = {"bias": plain.selection_bias(cfg, 2), "scale": 2.5}
+        uncut = functools.partial(plain.experts, layer=2)
+    else:
+        uncut = plain.experts
     layer = jax.jit(lambda blk, first: moe.moe_mlp(
         h, blk, experts_per_token=k, norm_topk_prob=True, dtype=jnp.float32,
-        first_held=first, scoring="sigmoid")[0], static_argnums=1)
+        first_held=first, scoring="sigmoid", **more)[0], static_argnums=1)
     with jax.default_matmul_precision("highest"):
-        want = reference.experts(h.reshape(-1, D), whole, cfg)
+        want = uncut(h.reshape(-1, D), whole, cfg)
         held = E // shares
         total = jnp.zeros_like(h)
         for share in range(shares):

@@ -449,6 +449,8 @@ def test_counter_names_are_under_the_registry_check():
               "def loss(rows):\n"
               "    return 0.0, {step_counter('moe_rows'): rows,\n"
               "                 step_counter('moe_moved'): rows,\n"
+              "                 step_counter('loss_main'): 0.0,\n"
+              "                 step_counter('loss_mtp'): 0.0,\n"
               "                 step_counter('moe_rowz'): rows}\n")
     module = core.SourceModule("fixture.py", "ray_tpu/models/fixture.py",
                                source)

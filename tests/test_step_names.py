@@ -58,14 +58,21 @@ SDAR_SCOPES = {"moe_held", "noise"}
 #: shared expert beside the routed ones
 SSM_SCOPES = {"ssm", "ssm_conv", "ssm_scan"}
 KDA_SCOPES = {"kda", "kda_conv", "kda_scan"}
-HYBRID_SCOPES = SSM_SCOPES | KDA_SCOPES | {"shared_expert"}
+#: scopes only a step with latent attention and a prediction module opens
+MLA_SCOPES = {"latent", "mtp", "mtp_head"}
+HYBRID_SCOPES = SSM_SCOPES | KDA_SCOPES | MLA_SCOPES | {"shared_expert"}
 
 
 def _scopes_of(family):
     if family == "hybrid":  # holds a share, trains next tokens
-        return set(tracing.SCOPE_REGISTRY) - {"experts", "noise"} - KDA_SCOPES
+        return set(tracing.SCOPE_REGISTRY) - {"experts", "noise"} \
+            - KDA_SCOPES - MLA_SCOPES
     if family == "hybrid-kda":
-        return set(tracing.SCOPE_REGISTRY) - {"experts", "noise"} - SSM_SCOPES
+        return set(tracing.SCOPE_REGISTRY) - {"experts", "noise"} \
+            - SSM_SCOPES - MLA_SCOPES
+    if family == "hybrid-mla":
+        return set(tracing.SCOPE_REGISTRY) - {"experts", "noise"} \
+            - SSM_SCOPES - KDA_SCOPES
     if family == "llama-sdar":
         return set(tracing.SCOPE_REGISTRY) - {"experts"} - HYBRID_SCOPES
     return set(tracing.SCOPE_REGISTRY) - SDAR_SCOPES - HYBRID_SCOPES - (
@@ -89,6 +96,10 @@ def _family(name):
         from ray_tpu.models import hybrid
 
         return hybrid, hybrid.HybridConfig.tiny_solar()
+    if name == "hybrid-mla":  # what joyai-ep16-s8192 runs
+        from ray_tpu.models import hybrid
+
+        return hybrid, hybrid.HybridConfig.tiny_joyai()
     config = gpt2.GPTConfig.tiny()
     if name == "gpt2-attn-outside-unrolled":  # what gpt2xl-s1024 runs
         import dataclasses
@@ -122,8 +133,8 @@ def _tiny_step(name="llama"):
 
 # ------------------------------------------------------- names in the step
 @pytest.mark.parametrize("family", ["llama", "llama-moe", "llama-sdar",
-                                    "hybrid", "hybrid-kda", "gpt2",
-                                    "gpt2-attn-outside-unrolled"])
+                                    "hybrid", "hybrid-kda", "hybrid-mla",
+                                    "gpt2", "gpt2-attn-outside-unrolled"])
 def test_lowered_step_holds_every_registered_scope(family):
     import jax
     import jax.numpy as jnp
@@ -203,7 +214,7 @@ def test_parse_anatomy_on_v5e_module_excerpt():
 
 
 @pytest.mark.parametrize("family", ["llama", "llama-moe", "llama-sdar",
-                                    "hybrid", "hybrid-kda",
+                                    "hybrid", "hybrid-kda", "hybrid-mla",
                                     "gpt2-attn-outside-unrolled"])
 def test_anatomy_of_a_tiny_cpu_step_end_to_end(family):
     from ray_tpu.parallel.train_state import PHASES
