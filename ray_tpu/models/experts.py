@@ -8,7 +8,8 @@ of two matrices with relu^2 (or, with ``gated_experts``, of three: SwiGLU), a
 shared expert of the same make beside them; ``experts_held`` says which
 experts this chip holds.  The held experts' products are recomputed inside
 their own backward (``moe._held_move``); the shared expert names its up (and
-gate) product for ``ops/remat.py``.
+gate) product for ``ops/remat.py``, and what the router decided is named and
+always kept (``remat.ROUTING``).
 
 **The selection bias** is no parameter: the published recipe moves it by the
 load, outside the gradient.  Here it is a constant of the configuration,
@@ -115,15 +116,18 @@ def layer_bytes(config, tokens: int, seq_len: int, tensor: int,
     ``tokens`` positions with the shared expert's width cut ``tensor`` ways:
     (its working set: the shared expert's products, their activation and the
     cotangents, and four copies of the (position, expert) rows; nothing kept
-    for the backward beside its input; the ladder's candidates it names: the
-    shared expert's up, and gate, product)."""
+    for the backward beside its input; what it names: of the ladder the
+    shared expert's up, and gate, product, and the routing, which no rule
+    decides on)."""
     shared = config.shared_width
     return (tokens * (3 * _matrices(config) * shared // tensor * itemsize
                       + 4 * config.experts_per_token * config.d_model
                       * itemsize),
             0,
             {remat.GATE_UP: tokens * (_matrices(config) - 1) * shared
-             // tensor * itemsize})
+             // tensor * itemsize,
+             remat.ROUTING: moe.routing_bytes(tokens, config.n_experts,
+                                              config.experts_per_token)})
 
 
 def first_call_facts(config, rows: int, seq_len: int) -> Dict[str, Any]:

@@ -30,8 +30,8 @@ letter) answers, for a configuration with the fields it reads:
   position that are no matrix it meets (causal attention, a scan);
 - ``layer_bytes(config, tokens, seq_len, tensor, itemsize)``: a chip's bytes
   of one layer for :func:`_layer_sizes`, (its working set, what it keeps for
-  the backward beside its input, {the name of a rung of ``ops.remat``'s
-  ladder it names: the bytes});
+  the backward beside its input, {a name of ``ops.remat``'s it bears, a rung
+  of the ladder or ``ROUTING``: the bytes});
 - ``first_call_facts(config, rows, seq_len)``: what it notes for the
   first-call record (``util/first_call.py``);
 - ``layer(config, axes, index)``: layer ``index`` of the kind as (x, its row
@@ -71,7 +71,9 @@ the cut a chip trains is one stretch of it.)
 **What each layer keeps for the backward** (``ops/remat.py``): every layer
 runs under ``jax.checkpoint`` with the one policy the rule gives this step;
 the kinds name the arrays worth keeping, as ``llama.py`` names its own, and
-:func:`_layer_sizes` hands the rule each kind's sizes.
+:func:`_layer_sizes` hands the rule each kind's sizes.  Beside its input and
+the splash kernel's residuals a layer always keeps what its router decided
+(``remat.ROUTING``, ``models/moe.py``): a step routes once.
 """
 
 from __future__ import annotations
@@ -341,7 +343,8 @@ def _layer_sizes(params, x_shape, config: HybridConfig):
     activations of ``x_shape`` (B, S, D), every size a chip's, as
     ``llama._layer_sizes`` gives them: (the ladder's candidates as (name,
     bytes), a bound on the step's own temporaries).  The candidates: what
-    the pattern's layers name of each rung (``layer_bytes`` of their kinds).
+    the pattern's layers name of each rung (``layer_bytes`` of their kinds),
+    and behind them, where a layer routes, what the layers name ``ROUTING``.
     The bound is the larger of two moments.  Inside the layers: the stacks'
     gradients in float32, every weight's cast to the compute dtype, each
     layer's kept input and what its kind keeps beside it, and the widest
@@ -377,7 +380,7 @@ def _layer_sizes(params, x_shape, config: HybridConfig):
         _, kept, named = sizes[kind]
         kept_inputs += kept
         for name, nbytes in named.items():
-            candidates[name] += nbytes
+            candidates[name] = candidates.get(name, 0) + nbytes
     in_the_layers = stacks + casts + kept_inputs \
         + max(working for working, _, _ in sizes.values())
     # with a prediction module the first pass's logits wait for the second's

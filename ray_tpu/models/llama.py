@@ -67,7 +67,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models import block_diffusion
+from ray_tpu.models import block_diffusion, moe
 from ray_tpu.models.layers import attention, feed_forward, mesh_axes
 from ray_tpu.models.layers import rmsnorm as _rmsnorm
 from ray_tpu.models.layers import rope as _rope  # noqa: F401
@@ -303,15 +303,16 @@ def _layer_policy(params, x_shape, config: LlamaConfig):
 def _layer_sizes(params, x_shape, config: LlamaConfig):
     """What ``ops.remat`` needs to know of ``params`` (arrays or their
     shapes) and activations of ``x_shape`` (B, S, D), every size a chip's:
-    (the ladder's candidates as (name, bytes), the bound on the step's own
-    temporaries).  The ambient mesh cuts the tokens over its batch and `seq`
-    axes and the heads and the MLP's width over `tensor`.  The parameters
-    are taken to lie as ``logical_axes`` and the default rules put them: a
-    trace sees no shardings, and a caller who hands ``create_sharded_state``
-    rules of their own gets the chip's share mis-sized, which the compiler's
-    refusal and ``TrainStep``'s fallback then have to catch.  The scan stacks
-    whatever is kept, so a candidate's bytes are a layer's times
-    ``n_layer``."""
+    (the ladder's candidates as (name, bytes), with experts the routing's
+    behind them, which is kept whatever the rule answers; the bound on the
+    step's own temporaries).  The ambient mesh cuts the tokens over its
+    batch and `seq` axes and the heads and the MLP's width over `tensor`.
+    The parameters are taken to lie as ``logical_axes`` and the default
+    rules put them: a trace sees no shardings, and a caller who hands
+    ``create_sharded_state`` rules of their own gets the chip's share
+    mis-sized, which the compiler's refusal and ``TrainStep``'s fallback
+    then have to catch.  The scan stacks whatever is kept, so a candidate's
+    bytes are a layer's times ``n_layer``."""
     mesh = jax.sharding.get_abstract_mesh()
     tensor = remat.axis_shards(mesh, "tensor")
     tokens = math.prod(x_shape[:2]) // remat.axis_shards(
@@ -347,8 +348,13 @@ def _layer_sizes(params, x_shape, config: LlamaConfig):
             * config.d_model * item
     per_layer = {remat.QKV: tokens * qkv_width * item,
                  remat.GATE_UP: 2 * tokens * mlp_width * item}
-    return ([(name, config.n_layer * per_layer[name])
-             for name in remat.LADDER], temporaries)
+    names = remat.LADDER
+    if config.n_experts:  # kept whatever the rule answers
+        per_layer[remat.ROUTING] = moe.routing_bytes(
+            tokens, config.n_experts, config.experts_per_token)
+        names += (remat.ROUTING,)
+    return ([(name, config.n_layer * per_layer[name]) for name in names],
+            temporaries)
 
 
 def forward_hidden(params: Dict[str, Any], tokens, config: LlamaConfig):

@@ -31,6 +31,16 @@ down-projection's output: the weights' gradient is a row sum inside the
 activation's backward pass, and under ``jax.checkpoint`` the down-projection
 and the combine are not run a second time.
 
+**A layer decides its routing once a step.**  What the router decided bears
+one name, ``remat.ROUTING``, which the layer's ``jax.checkpoint`` always
+keeps (``ops/remat.py``; :func:`routing_bytes`, a few MB a layer): the
+logits, the chosen ids and their scores (:func:`route`), the sorted order,
+its inverse and the group sizes (:func:`sort_pairs`), the weights in expert
+order.  The backward reads the forward's own and makes no full-precision
+product, ``top_k`` or sort a second time, so it cannot pick another k-th
+expert than the forward did; what it makes again of these is elementwise on
+(N, E) or (N, k).
+
 Two router losses come back with the output, for ``llama.loss_fn`` to weigh
 (:func:`router_losses`), and a count: the rows that reached each expert held
 here, (shards, H) int32, the held slice of the sort's ``group_sizes``.  It is
@@ -108,12 +118,33 @@ def router_losses(logits, probs, experts, batch_axes=()
     return E * jnp.sum(f * p), z
 
 
+def _scores_at(scores, experts):
+    """scores: (..., E), experts: (..., k) ids -> (..., k), the scores at
+    those ids: ``take_along_axis`` as a comparison, a select and a sum over
+    E of one score and zeros, so exact, and its transpose the same over k.
+    On the v5e both fuse into their neighbours' pass, where the gather of
+    16,384 x 8 scalars out of 128 a row took 1.0 ms and its transpose, a
+    scatter-add, 1.1 (``PERF.md``, PR 48)."""
+    hot = experts[..., None] == jnp.arange(scores.shape[-1],
+                                           dtype=experts.dtype)
+    return jnp.sum(jnp.where(hot, scores[..., None, :], 0), axis=-1)
+
+
 def route(h32, router_w, k: int, norm_topk_prob: bool, batch_axes=(),
           scoring: str = "softmax", bias=None, scale: float = 1.0):
     """h32: (..., D) float32.  -> combine weights (..., k) float32, expert ids
     (..., k) int32, (load-balance, z).  The matmul runs at full float32
     precision (the TPU's default would round its operands to bfloat16, and a
     token's k-th expert is decided by the last bits).
+
+    **Decided once a step.**  The logits, the chosen ids and their scores
+    bear ``remat.ROUTING``, which a layer's ``jax.checkpoint`` always keeps
+    (``ops/remat.py``): the backward reads the forward's own routing and
+    makes neither the product nor the ``top_k`` a second time; what it makes
+    again of these is elementwise.  The ids are ``top_k``'s of a value that
+    carries no gradient and the scores are read at them (:func:`_scores_at`:
+    ``top_k``'s values to the bit), because ``top_k``'s own derivative reads
+    the ids it made itself, before any name.
 
     ``scoring`` is how a logit becomes a weight.  ``"softmax"``: the k
     largest probabilities, the two router losses over them.  ``"sigmoid"``
@@ -125,21 +156,22 @@ def route(h32, router_w, k: int, norm_topk_prob: bool, batch_axes=(),
     two losses come back zero.  Either way ``norm_topk_prob`` divides the k
     weights by their sum and ``scale`` multiplies them
     (``routed_scaling_factor``)."""
-    logits = jnp.einsum("...d,de->...e", h32, router_w.astype(jnp.float32),
-                        precision=lax.Precision.HIGHEST)
+    logits = checkpoint_name(
+        jnp.einsum("...d,de->...e", h32, router_w.astype(jnp.float32),
+                   precision=lax.Precision.HIGHEST), remat.ROUTING)
     if scoring == "softmax":
-        probs = jax.nn.softmax(logits, axis=-1)
-        weights, experts = lax.top_k(probs, k)
-        losses = router_losses(logits, probs, experts, batch_axes)
+        scores = chosen_by = jax.nn.softmax(logits, axis=-1)
     elif scoring == "sigmoid":
         scores = jax.nn.sigmoid(logits)
         chosen_by = scores if bias is None else \
-            scores + lax.stop_gradient(bias.astype(jnp.float32))
-        experts = lax.top_k(lax.stop_gradient(chosen_by), k)[1]
-        weights = jnp.take_along_axis(scores, experts, axis=-1)
-        losses = (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32))
+            scores + bias.astype(jnp.float32)
     else:
         raise ValueError(f"router scoring {scoring!r} (softmax|sigmoid)")
+    experts = checkpoint_name(
+        lax.top_k(lax.stop_gradient(chosen_by), k)[1], remat.ROUTING)
+    weights = checkpoint_name(_scores_at(scores, experts), remat.ROUTING)
+    losses = router_losses(logits, scores, experts, batch_axes) \
+        if scoring == "softmax" else (jnp.zeros((), jnp.float32),) * 2
     if norm_topk_prob:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
     if scale != 1.0:
@@ -150,14 +182,26 @@ def route(h32, router_w, k: int, norm_topk_prob: bool, batch_axes=(),
 def sort_pairs(experts, n_experts: int):
     """experts: (N, k) ids.  -> ``order`` (N x k,): the flat (token, slot)
     pairs in expert order, ties in token order; ``inverse`` (N, k): where
-    each pair went; ``group_sizes`` (E,) int32, summing to N x k."""
+    each pair went; ``group_sizes`` (E,) int32, summing to N x k.  All three
+    bear ``remat.ROUTING``: the backward reads these and sorts nothing."""
     flat = experts.reshape(-1)
     order = jnp.argsort(flat, stable=True).astype(jnp.int32)
     inverse = jnp.argsort(order).astype(jnp.int32).reshape(experts.shape)
     group_sizes = jnp.sum(
         flat[:, None] == jnp.arange(n_experts, dtype=flat.dtype), axis=0,
         dtype=jnp.int32)
-    return order, inverse, group_sizes
+    return tuple(checkpoint_name(a, remat.ROUTING)
+                 for a in (order, inverse, group_sizes))
+
+
+def routing_bytes(tokens: int, n_experts: int, k: int) -> int:
+    """The bytes of what one layer over ``tokens`` positions names
+    ``remat.ROUTING``: the logits, (N, E); the ids, their scores and
+    ``inverse``, (N, k); ``order`` and the weights in it, (N x k,);
+    ``group_sizes``, (E,); four bytes each.  (Of a name the checkpoint
+    keeps what the backward reads: not the scores where nothing divides
+    them by their sum.)"""
+    return 4 * (tokens * (n_experts + 5 * k) + n_experts)
 
 
 @jax.custom_vjp
@@ -375,17 +419,17 @@ def expert_mlp(x, weights, experts, w_gate, w_up, w_down, n_experts=None,
     n_experts = n_experts or held
     with jax.named_scope("moe_dispatch"):
         order, inverse, group_sizes = sort_pairs(experts, n_experts)
+        w_rows = checkpoint_name(
+            _weights_to_expert_order(weights, order, inverse), remat.ROUTING)
         held_rows = group_sizes[first_held:first_held + held]
         if held < n_experts:
             bound = window_rows(order.shape[0])
             windows = (jnp.sum(held_rows) + (bound - 1)) // bound
             return _held_move(
-                (first_held, activation), windows, x,
-                _weights_to_expert_order(weights, order, inverse), matrices,
+                (first_held, activation), windows, x, w_rows, matrices,
                 order, inverse, group_sizes), \
                 held_rows, windows * bound
         rows = _to_expert_order(x, order, inverse)
-        w_rows = _weights_to_expert_order(weights, order, inverse)
     with jax.named_scope("experts"):
         out = _through_experts(rows, w_rows, matrices, group_sizes, 0,
                                activation)

@@ -3,10 +3,12 @@ when the step is traced from what the chip has room for.
 
 A decoder's layer scan (``models/llama.py``) runs under ``jax.checkpoint``:
 the layer's input is kept, the splash kernel's output and log-sum-exp are kept
-(``ops.attention.SPLASH_RESIDUALS``), and the rest of the layer is run a second
-time in the backward.  Where the chip has memory to spare that second run is
-10-14 % of the step paid for bytes nobody uses.  The models name the arrays
-worth keeping (``jax.ad_checkpoint.checkpoint_name``), in the order of
+(``ops.attention.SPLASH_RESIDUALS``), what an expert layer's router decided is
+kept (:data:`ROUTING`: a few MB a layer, which a full-precision product, a
+``top_k`` and three sorts would make again), and the rest of the layer is run
+a second time in the backward.  Where the chip has memory to spare that second
+run is 10-14 % of the step paid for bytes nobody uses.  The models name the
+arrays worth keeping (``jax.ad_checkpoint.checkpoint_name``), in the order of
 :data:`LADDER`; :func:`layer_policy` keeps as many rungs as fit and hands back
 the checkpoint policy.  There is no option: the answer follows from sizes the
 code can observe.
@@ -81,6 +83,12 @@ GATE_UP = "mlp_gate_up"
 #: byte kept (``d_model`` a byte); q, k and v go first because they spare
 #: RoPE's passes besides and are a fifth of the bytes.
 LADDER = (QKV, GATE_UP)
+#: What an expert layer's router decided (``models/moe.py``): its logits, the
+#: chosen experts and their weights, the pairs' sorted order with its inverse
+#: and group sizes, the weights in that order: (N, E), (N, k) and (N x k,)
+#: arrays of four bytes.  No rung of the ladder: always kept, as the splash
+#: residuals are, so a step routes once and its backward reads that routing.
+ROUTING = "moe_routing"
 
 #: Share of the chip's ``bytes_limit`` the rule leaves alone: a tenth, as a
 #: policy.  It is not there for the estimate's error: :func:`own_temporaries`
@@ -101,21 +109,25 @@ REMAT_FALLBACKS = metrics.Counter(
 @dataclasses.dataclass(frozen=True)
 class Decision:
     """One answer of the rule.  ``kept``: the rungs of :data:`LADDER` kept
-    (the splash residuals are always kept and not listed); ``kept_bytes``:
-    their bytes a chip, all layers; ``room_bytes``: what the rule saw free
-    for them, after the program's own temporaries and the reserve (``None``:
-    the device reports no memory); ``processes``: how many processes took
-    it together (1: this process's own program)."""
+    (the splash residuals and :data:`ROUTING` are always kept and not
+    listed); ``kept_bytes``: their bytes a chip, all layers; ``room_bytes``:
+    what the rule saw free for them, after the program's own temporaries,
+    the routing and the reserve (``None``: the device reports no memory);
+    ``processes``: how many processes took it together (1: this process's
+    own program); ``routing_bytes``: what the layers' routing takes, a chip,
+    all layers (0: no layer routes)."""
     kept: Tuple[str, ...] = ()
     kept_bytes: int = 0
     room_bytes: Optional[int] = None
     processes: int = 1
+    routing_bytes: int = 0
 
     def attributes(self) -> Dict[str, object]:
         """What :func:`decide` notes of it (``util/first_call.py``)."""
         return {"remat_kept": list(self.kept),
                 "remat_kept_bytes": self.kept_bytes,
-                "remat_room_bytes": self.room_bytes}
+                "remat_room_bytes": self.room_bytes,
+                "remat_routing_bytes": self.routing_bytes}
 
 
 def choose(limit: int, in_use: int, candidates: Sequence[Tuple[str, int]],
@@ -266,31 +278,38 @@ def _job_memory(mesh, question_parts) -> Tuple[Optional[Tuple[int, int]], int]:
 def decide(candidates: Sequence[Tuple[str, int]],
            temporaries: int) -> Decision:
     """:func:`choose` for a layer whose named intermediates would take
-    ``candidates`` (name, bytes a chip over all layers, ladder order), in a
-    step that needs ``temporaries`` without them, from what the devices
-    report (those of every process under a mesh that spans several).  Notes
-    the decision for the first-call record."""
+    ``candidates`` (name, bytes a chip over all layers: the ladder's rungs
+    in its order and, where a layer routes, :data:`ROUTING`, which is kept
+    whatever the answer and counts among what the step needs), in a step
+    that needs ``temporaries`` without them, from what the devices report
+    (those of every process under a mesh that spans several).  Notes the
+    decision for the first-call record."""
+    routing = dict(candidates).get(ROUTING, 0)
+    candidates = [c for c in candidates if c[0] != ROUTING]
+    temporaries += routing
     mesh = jax.sharding.get_abstract_mesh()
     with _lock:
         plain_only = _plain_only
     memory, processes = (None, 1) if plain_only else _job_memory(
         mesh, (tuple(candidates), temporaries, tuple(mesh.shape.items())))
-    decision = Decision(processes=processes) if memory is None else \
-        dataclasses.replace(choose(*memory, candidates, temporaries),
-                            processes=processes)
+    decision = dataclasses.replace(
+        Decision() if memory is None
+        else choose(*memory, candidates, temporaries),
+        processes=processes, routing_bytes=routing)
     first_call.note(**decision.attributes())
     logger.info("remat: keeping %s for the backward (%d bytes a chip; room "
-                "%s; device memory %s; %d process(es))",
-                decision.kept or "nothing more", decision.kept_bytes,
-                decision.room_bytes, memory, processes)
+                "%s; device memory %s; %d process(es)) beside %d bytes of "
+                "routing", decision.kept or "nothing more",
+                decision.kept_bytes, decision.room_bytes, memory, processes,
+                routing)
     return decision
 
 
 def layer_policy(candidates: Sequence[Tuple[str, int]], temporaries: int):
-    """The checkpoint policy of such a layer:
-    ``save_only_these_names(splash residuals, *what decide keeps)``."""
+    """The checkpoint policy of such a layer: ``save_only_these_names(splash
+    residuals, the routing, *what decide keeps)``."""
     return jax.checkpoint_policies.save_only_these_names(
-        SPLASH_RESIDUALS, *decide(candidates, temporaries).kept)
+        SPLASH_RESIDUALS, ROUTING, *decide(candidates, temporaries).kept)
 
 
 def fall_back(kept: Sequence[str], reason: str) -> None:

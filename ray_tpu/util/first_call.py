@@ -18,8 +18,10 @@ and ``seconds``, the span ``compile_s``):
                               memory and rebuilt under the plain policy
 ``ops/remat.py``              ``remat_kept`` (the ladder's rungs kept),
                               ``remat_kept_bytes``, ``remat_room_bytes``
-                              (None: the device reports no memory); absent
-                              where no layer asked the rule (GPT-2)
+                              (None: the device reports no memory),
+                              ``remat_routing_bytes`` (the expert layers'
+                              routing, always kept; 0: no layer routes);
+                              absent where no layer asked the rule (GPT-2)
 ``ops/grad_ring.py``          ``grad_ring_products`` (weight gradients
                               traced as rings; a scanned layer's once),
                               ``grad_ring_axis`` (`fsdp`'s size); 0 and 0

@@ -102,7 +102,8 @@ SPAN_REGISTRY: Dict[str, str] = {
                         "executable (trace, lower, compile or cache load, "
                         "dispatch), recorded after the fact (attrs: label, "
                         "compile_s, remat_kept, remat_kept_bytes, "
-                        "remat_room_bytes, remat_fallback, "
+                        "remat_room_bytes, remat_routing_bytes, "
+                        "remat_fallback, "
                         "grad_ring_products, grad_ring_axis; from "
                         "models/llama.py experts_held, experts_total, "
                         "block_length, attn_positions, loss_positions; "

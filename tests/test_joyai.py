@@ -24,7 +24,7 @@ import pytest
 from benchmarks.lib import correct, spec
 from benchmarks.reference import joyai_llm_flash as reference
 from benchmarks.reference.llama import _rmsnorm, _rope
-from ray_tpu.models import hybrid, mla
+from ray_tpu.models import hybrid, mla, moe
 from ray_tpu.models.layers import rope
 from ray_tpu.ops import attention, remat
 from ray_tpu.parallel import MeshSpec, make_mesh
@@ -397,7 +397,9 @@ def test_num_params_flops_and_the_first_call_record():
         "mtp_depth": 1, "mtp_weight": 0.3, "experts_held": 4,
         "experts_total": 16, "router_scoring": "sigmoid",
         "attn_positions": 128, "loss_positions": 128,
-        "remat_kept": [], "remat_kept_bytes": 0, "remat_room_bytes": None}
+        "remat_kept": [], "remat_kept_bytes": 0, "remat_room_bytes": None,
+        # the pattern's two expert layers' routing and the module's one's
+        "remat_routing_bytes": 3 * moe.routing_bytes(256, 16, 2)}
 
 
 def test_the_remat_rule_is_given_the_modules_sizes():
@@ -417,10 +419,13 @@ def test_the_remat_rule_is_given_the_modules_sizes():
     qkv = tokens * 4 * (2 * 24 + 16) * 2
     shared = tokens * 2 * 48 * 2
     dense = tokens * 2 * 256 * 2
+    routing = moe.routing_bytes(tokens, 16, 2)
     assert dict(without) == {remat.QKV: 3 * qkv,
-                             remat.GATE_UP: 2 * shared + dense}
+                             remat.GATE_UP: 2 * shared + dense,
+                             remat.ROUTING: 2 * routing}
     assert dict(with_module) == {remat.QKV: 4 * qkv,
-                                 remat.GATE_UP: 3 * shared + dense}
+                                 remat.GATE_UP: 3 * shared + dense,
+                                 remat.ROUTING: 3 * routing}
 
 
 # -------------------------------------------------- (5) the 8-bit control

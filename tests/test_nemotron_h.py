@@ -346,7 +346,9 @@ def test_num_params_and_the_first_call_record():
         "attn_positions": 128, "loss_positions": 128,
         # the attention kind's own since PR 46, whatever else the pattern holds
         "heads_held": 4, "heads_total": 4, "attn_gate": False,
-        "remat_kept": [], "remat_kept_bytes": 0, "remat_room_bytes": None}
+        "remat_kept": [], "remat_kept_bytes": 0, "remat_room_bytes": None,
+        # four expert layers' routing, kept under every policy (PR 48)
+        "remat_routing_bytes": 4 * moe.routing_bytes(256, 16, 2)}
 
 
 # -------------------------------------------------- (6) the 8-bit control
@@ -382,23 +384,29 @@ def test_the_control_is_refused():
 #: ``llama._block`` moved into ``models/layers.py`` and ``models/moe.py``
 #: learned a second scoring, a second activation and a shared expert without
 #: one operation of these steps changing.  A change that means to alter one
-#: of these programs records the new hash here and says so.
+#: of these programs records the new hash here and says so.  **PR 48 meant
+#: to, in the four with experts**: what the router decided bears a name the
+#: checkpoint keeps, the ids are ``top_k``'s of a value without a gradient
+#: and the scores are read at them by a select and a sum (the forward's
+#: numbers and, in float32, every gradient are the parent's to the bit:
+#: ``tests/test_olmoe.py``, ``PERF.md`` PR 48); the two dense ones are the
+#: parent's.
 LOWERED_STEPS = {
     "tiny-llama":
         "f6d4a6b1cbf541233f13675bccbe7766fcb6a630709a7aa7b7f50a0428b1fe2c",
     "tiny-olmoe":
-        "75440a3668e37a39df59ccae7d07ca3656d6695e2fcd69b4ec267c55818036c7",
+        "115daacfda98cd00748642ce33854899b338aef11544f9ea9a8dcadda6abf25f",
     "tiny-sdar":
-        "71443c1fd2cd39de68e41005488544c5a2b3c35b144a9b9f7961918484e15f16",
+        "ef767ddf254bcd4a9f989bc5b774a16a7764a3fdadebd3a2f46dc4c1f2a10436",
     "tiny-gpt2":
         "86135e6f43a576200ef38b5a76d717607699853ed5341b6350fd1c81db4dbe04",
     # the two hybrid presets, as PR 43 left them (added at PR 44: the tiny
     # Mamba-2 sizes lie off the chip's tiles and take ``ops.ssd.ssd_xla``,
     # whose lowered text is the parent's ``ssd``)
     "tiny-nemotron-h":
-        "51f2becdf53dcafbee0623c9aafa4cf4b8e38fd401b4baea5130cd23797af14a",
+        "3280108af5618f25c63009a49ad08bcf89d6a390ab0ed6f661dd3c3d4be1bc19",
     "tiny-solar-open2":
-        "5db3e1bc235c28d1dcc4e850b73b655552f3f0efe19609e2eb37a5a78bc33c2f",
+        "4297b2f0c8cca36c5ec2d261de100efcfcab727819b4629e5344018e64085590",
 }
 
 
@@ -456,28 +464,35 @@ def _cell_config(name):
 #: ``hybrid._layer_sizes`` on the parent of PR 46, (q/k/v bytes, gate/up
 #: bytes, the bound on the step's temporaries): what ``ops/remat.py`` decides
 #: from.  The kinds' ``layer_bytes`` carry the parent's terms over as they
-#: were, the two overstated ones with them (ROADMAP C15).
+#: were, the two overstated ones with them (ROADMAP C15).  Behind them since
+#: PR 48 what the expert layers' routing takes (``moe.routing_bytes`` a
+#: layer), which the rule keeps whatever it decides: four layers each, of
+#: 256 tokens with 16 experts and 2 a token, of 16,384 with 128 and 6, of
+#: 8,192 with 320 and 8.
 LAYER_SIZES = {
     "tiny": (lambda: (hybrid.HybridConfig.tiny(), (2, 128)),
-             (131072, 262144, 7640128)),
+             (131072, 262144, 7640128), 16 * (256 * (16 + 10) + 16)),
     "tiny_solar": (lambda: (hybrid.HybridConfig.tiny_solar(), (2, 128)),
-                   (65536, 196608, 5602244)),
+                   (65536, 196608, 5602244), 16 * (256 * (16 + 10) + 16)),
     "nemotron-ep16-s8192": (lambda: _cell_config("nemotron-ep16-s8192"),
-                            (150994944, 486539264, 9288687104)),
+                            (150994944, 486539264, 9288687104),
+                            16 * (16384 * (128 + 30) + 128)),
     "solar-open2-ep40-tp8": (lambda: _cell_config("solar-open2-ep40-tp8"),
-                             (20971520, 167772160, 7130061200)),
+                             (20971520, 167772160, 7130061200),
+                             16 * (8192 * (320 + 40) + 320)),
 }
 
 
 @pytest.mark.parametrize("name", sorted(LAYER_SIZES))
 def test_the_remat_rule_is_given_the_parents_sizes(name):
-    build, (qkv, gate_up, temporaries) = LAYER_SIZES[name]
+    build, (qkv, gate_up, temporaries), routing = LAYER_SIZES[name]
     config, (rows, seq_len) = build()
     shapes = jax.eval_shape(lambda: hybrid.init_params(config,
                                                        jax.random.key(0)))
     assert hybrid._layer_sizes(
         shapes, (rows, seq_len, config.d_model), config) == (
-        [("attn_qkv", qkv), ("mlp_gate_up", gate_up)], temporaries)
+        [("attn_qkv", qkv), ("mlp_gate_up", gate_up),
+         ("moe_routing", routing)], temporaries)
 
 
 def _identity_kind():

@@ -14,9 +14,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax._src.ad_checkpoint import saved_residuals  # not re-exported in 0.9
 
 from benchmarks.reference import olmoe as reference
 from ray_tpu.models import llama, moe
+from ray_tpu.ops import remat
+from ray_tpu.ops.attention import save_splash_residuals
 from ray_tpu.ops.grouped_matmul import grouped_matmul
 from ray_tpu.parallel import MeshSpec, batch_sharding, make_mesh
 from ray_tpu.parallel.mesh import pytree_sharding
@@ -231,6 +234,113 @@ def test_remat_recomputes_neither_the_down_projection_nor_the_combine():
     assert not any(e.primitive.name.startswith("scatter")
                    and e.outvars[0].aval.shape[0] in (n, n * k)
                    for _, e in found)
+
+
+# ------------------------------- the routing is decided once a step (PR 48)
+#: the expert layers the benchmark's cells run: (scoring, experts, a token's,
+#: held, matrices an expert, selection bias, ``norm_topk_prob``)
+ROUTED = {
+    "softmax-every-expert": ("softmax", 8, 2, 8, 3, False, False),  # OLMoE
+    "softmax-a-share": ("softmax", 8, 2, 2, 3, False, True),        # SDAR
+    "sigmoid-bias-a-share-two-matrices":                  # Nemotron-3-Nano
+        ("sigmoid", 8, 2, 2, 2, True, True),
+}
+N_TOKENS, D_MODEL = 64, 32
+
+
+def _routed_layer(case, policy):
+    """-> (``loss(h, blk)`` of one such layer, its router losses among it,
+    under ``jax.checkpoint`` with ``policy``; the checkpointed layer; h;
+    blk), float32."""
+    scoring, n_experts, k, held, matrices, biased, norm = ROUTED[case]
+    ks = jax.random.split(jax.random.key(0), 6)
+    blk = {"router": jax.random.normal(ks[0], (D_MODEL, n_experts)) * 0.5,
+           "w_up": jax.random.normal(ks[1], (held, D_MODEL, 16)) * 0.2,
+           "w_down": jax.random.normal(ks[2], (held, 16, D_MODEL)) * 0.2}
+    if matrices == 3:
+        blk["w_gate"] = jax.random.normal(ks[3], (held, D_MODEL, 16)) * 0.2
+    h = jax.random.normal(ks[4], (2, N_TOKENS // 2, D_MODEL))
+    bias = jax.random.normal(ks[5], (n_experts,)) * 0.1 if biased else None
+
+    def layer(h, blk):
+        y, losses, _ = moe.moe_mlp(
+            h, blk, experts_per_token=k, norm_topk_prob=norm,
+            dtype=jnp.float32, first_held=2 if held < n_experts else 0,
+            scoring=scoring, bias=bias,
+            activation=jax.nn.silu if matrices == 3 else moe.relu2)
+        return y, losses
+
+    layer = jax.checkpoint(layer, policy=policy)
+
+    def loss(h, blk):
+        y, (balance, z) = layer(h, blk)
+        return jnp.sum(y ** 2) + balance + z
+
+    return loss, layer, h, blk
+
+
+@pytest.mark.parametrize("case", sorted(ROUTED))
+def test_the_layer_keeps_what_its_router_decided(case):
+    """Under the plain policy (no device memory, no rung of the ladder) the
+    checkpointed layer's residuals are its arguments and the routing's
+    arrays, ``moe.routing_bytes`` in all; the scores only where the
+    backward reads them (through ``norm_topk_prob``'s division)."""
+    _, n_experts, k, *_, norm = ROUTED[case]
+    _, layer, h, blk = _routed_layer(case, remat.layer_policy([], 0))
+    N = N_TOKENS
+    routing = [("float32", (N, n_experts)),         # the logits
+               ("int32", (N, k)), ("float32", (N, k)),  # the ids, the scores
+               ("int32", (N * k,)), ("int32", (N, k)),  # order, inverse
+               ("int32", (n_experts,)),             # group sizes
+               ("float32", (N * k,))]               # the weights, sorted
+    assert 4 * sum(np.prod(shape) for _, shape in routing) \
+        == moe.routing_bytes(N, n_experts, k)
+    if not norm:
+        routing.remove(("float32", (N, k)))
+    arguments = [(str(a.dtype), a.shape) for a in jax.tree.leaves((h, blk))]
+    assert sorted((str(aval.dtype), tuple(aval.shape))
+                  for aval, _ in saved_residuals(layer, h, blk)) \
+        == sorted(arguments + routing)
+
+
+@pytest.mark.parametrize("case", sorted(ROUTED))
+def test_the_backward_routes_nothing_again(case):
+    """One ``top_k`` and one full-precision product in the forward, none in
+    the checkpoint's second run: the backward holds the product's two
+    transposes and, of the sorts, the one that brings the weights' gradient
+    back into token order."""
+    loss, _, h, blk = _routed_layer(case, remat.layer_policy([], 0))
+    found = list(_equations(jax.make_jaxpr(
+        jax.grad(loss, argnums=(0, 1)))(h, blk).jaxpr))
+
+    def count(name, backward, **params):
+        return sum(e.primitive.name == name
+                   and ("remat2" in path or "checkpoint" in path) == backward
+                   and all(str(e.params[key]) == str(value)
+                           for key, value in params.items())
+                   for path, e in found)
+
+    highest = dict(precision=(jax.lax.Precision.HIGHEST,) * 2)
+    assert (count("top_k", False), count("top_k", True)) == (1, 0)
+    assert (count("dot_general", False, **highest),
+            count("dot_general", True, **highest)) == (1, 2)
+    # forward: two argsorts and the weights into expert order
+    assert (count("sort", False), count("sort", True)) == (3, 1)
+
+
+@pytest.mark.parametrize("case", sorted(ROUTED))
+def test_keeping_the_routing_changes_no_number(case):
+    """Loss equal and every gradient within 1e-6 of the same layer under
+    ``save_splash_residuals`` alone, which routes a second time."""
+    def run(policy):
+        loss, _, h, blk = _routed_layer(case, policy)
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(h, blk)
+
+    (want_loss, want), (loss, grads) = (
+        run(save_splash_residuals), run(remat.layer_policy([], 0)))
+    assert float(loss) == float(want_loss)
+    for got, ref in zip(jax.tree.leaves(grads), jax.tree.leaves(want)):
+        assert _rel_err(got, ref) <= 1e-6
 
 
 # ----------------------------------------------------------- (c) dropless

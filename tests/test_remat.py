@@ -15,10 +15,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax._src.ad_checkpoint import saved_residuals  # not re-exported in 0.9
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models import layers, llama, moe
 from ray_tpu.ops import remat
-from ray_tpu.ops.attention import save_splash_residuals
+from ray_tpu.ops.attention import SPLASH_RESIDUALS, save_splash_residuals
 from ray_tpu.parallel import MeshSpec, make_mesh
 from ray_tpu.parallel.train_state import (create_sharded_state,
                                           jit_train_step)
@@ -128,14 +129,18 @@ def test_the_rule_is_given_each_layer_kinds_sizes(nudge_mb, monkeypatch):
     candidates, temporaries = hybrid._layer_sizes(shapes, (2, 8192, 2688),
                                                   config)
     tokens = 2 * 8192
+    # the four routers' decisions, kept whatever the rule says: (N, 128)
+    # logits, five (N, 6) arrays and the group sizes, four bytes each
+    routing = 4 * 4 * (tokens * (128 + 5 * 6) + 128)
     assert candidates == [(remat.QKV, tokens * (32 + 4) * 128 * 2),
-                          (remat.GATE_UP, 4 * tokens * 3712 * 2)]
+                          (remat.GATE_UP, 4 * tokens * 3712 * 2),
+                          (remat.ROUTING, routing)]
     assert 6.37 * GiB < temporaries < 9.0 * GiB
     for fuller in (0.0, 0.3):
         on_device(monkeypatch, (V5E, int((6.21 + fuller) * GiB)
                                 + nudge_mb * 2 ** 20))
         decided = remat.decide(candidates, temporaries)
-        assert decided.kept == ()
+        assert decided.kept == () and decided.routing_bytes == routing
         assert decided.room_bytes < -0.5 * GiB
     on_device(monkeypatch, ROOMY)
     assert remat.decide(candidates, temporaries).kept == BOTH
@@ -165,7 +170,9 @@ def test_the_rule_is_given_the_kda_kinds_sizes(monkeypatch):
     candidates, temporaries = hybrid._layer_sizes(shapes, (1, 8192, 4096),
                                                   config)
     assert candidates == [(remat.QKV, 8192 * (8 + 2) * 128 * 2),
-                          (remat.GATE_UP, 4 * 8192 * 2 * 1280 * 2)]
+                          (remat.GATE_UP, 4 * 8192 * 2 * 1280 * 2),
+                          (remat.ROUTING, 4 * 4 * (8192 * (320 + 5 * 8)
+                                                   + 320))]
     assert 3.62 * GiB < temporaries < 7.5 * GiB
     # the widest layer's working set is the KDA layer's: without it the
     # bound is lower
@@ -352,13 +359,28 @@ def _layer_residuals(config, policy):
                   for aval, _ in saved_residuals(layer, x, blk))
 
 
+def _routing_residuals(config, B=2):
+    """(dtype, shape) of what one layer's router decided (``moe.route``,
+    ``moe.sort_pairs``, the weights in expert order), which a layer keeps
+    under every policy: nothing for a model without experts."""
+    if not config.n_experts:
+        return []
+    N, E, k = B * config.seq_len, config.n_experts, config.experts_per_token
+    scores = [("float32", (N, k))] if config.norm_topk_prob else []
+    return [("float32", (N, E)), ("int32", (N, k)), *scores,
+            ("int32", (N * k,)), ("int32", (N, k)), ("int32", (E,)),
+            ("float32", (N * k,))]
+
+
 @pytest.mark.parametrize("preset", ["tiny", "tiny_moe"])
 def test_the_layer_saves_the_named_arrays(preset, monkeypatch):
     """Under the richest policy the layer's residuals are today's plus q, k,
-    v and the two MLP products; with no device memory they are exactly what
-    ``save_splash_residuals`` gives."""
+    v and the two MLP products; with no device memory they are what
+    ``save_splash_residuals`` gives and, with experts, the routing (since
+    PR 48; the dense layer's stay as they were)."""
     config = getattr(llama.LlamaConfig, preset)()
-    today = _layer_residuals(config, save_splash_residuals)
+    today = sorted(_layer_residuals(config, save_splash_residuals)
+                   + _routing_residuals(config))
     assert _layer_residuals(config, _policy(config)) == today
     on_device(monkeypatch, ROOMY)
     rich = _layer_residuals(config, _policy(config))
@@ -403,14 +425,20 @@ def _tiny_step_text(config):
 @pytest.mark.parametrize("preset", ["tiny", "tiny_moe"])
 def test_without_device_memory_the_step_is_the_parents(preset, monkeypatch):
     """With no memory statistics the compiled tiny step is, instruction for
-    instruction, the one of a tree without the rule: a layer under
-    ``save_splash_residuals`` in which nothing is named."""
+    instruction, the one of a tree without the rule: a layer in which no
+    rung of the ladder is named, under ``save_splash_residuals`` and, since
+    PR 48, the routing's name beside it (which a dense layer does not
+    bear: its step is the one of the tree before the rule)."""
     config = getattr(llama.LlamaConfig, preset)()
     ours = _tiny_step_text(config)
-    monkeypatch.setattr(llama, "_layer_policy",
-                        lambda *a: save_splash_residuals)
+    monkeypatch.setattr(
+        llama, "_layer_policy",
+        lambda *a: jax.checkpoint_policies.save_only_these_names(
+            SPLASH_RESIDUALS, remat.ROUTING))
     for module in (layers, moe):  # where the layer's arrays are named
-        monkeypatch.setattr(module, "checkpoint_name", lambda x, name: x)
+        monkeypatch.setattr(
+            module, "checkpoint_name", lambda x, name: checkpoint_name(
+                x, name) if name == remat.ROUTING else x)
     assert _tiny_step_text(config) == ours
 
 
@@ -435,6 +463,25 @@ def test_first_call_says_what_was_kept(monkeypatch):
     assert row["remat_kept"] == list(BOTH)
     assert row["remat_kept_bytes"] > 0 and row["remat_room_bytes"] > 0
     assert row["remat_fallback"] is False
+
+
+@pytest.mark.parametrize("memory", [None, ROOMY],
+                         ids=["no-memory-statistics", "roomy"])
+@pytest.mark.parametrize("preset", ["tiny", "tiny_moe"])
+def test_first_call_says_what_the_routing_takes(preset, memory, monkeypatch):
+    """``remat_routing_bytes``: every layer's routing for a model with
+    experts, 0 for a dense one, whether or not the device reports memory
+    (the routing is kept either way)."""
+    device_telemetry.reset()
+    on_device(monkeypatch, memory)
+    config = getattr(llama.LlamaConfig, preset)()
+    step_fn, params, opt_state = _step_and_state(config)
+    jit_train_step(step_fn)(params, opt_state, *_batch(config))
+    (row,) = device_telemetry.first_calls("train_step")
+    N, E, k = 2 * config.seq_len, config.n_experts, config.experts_per_token
+    assert row["remat_routing_bytes"] == (
+        config.n_layer * 4 * (N * (E + 5 * k) + E) if E else 0)
+    assert row["remat_kept"] == (list(BOTH) if memory else [])
 
 
 def test_a_refused_richer_step_falls_back_once(monkeypatch, caplog):

@@ -377,7 +377,8 @@ def test_num_params_flops_and_the_first_call_record():
         "attn_gate": True, "experts_held": 4, "experts_total": 16,
         "router_scoring": "sigmoid", "attn_positions": 128,
         "loss_positions": 128,
-        "remat_kept": [], "remat_kept_bytes": 0, "remat_room_bytes": None}
+        "remat_kept": [], "remat_kept_bytes": 0, "remat_room_bytes": None,
+        "remat_routing_bytes": 4 * moe.routing_bytes(256, 16, 2)}
 
 
 # -------------------------------------------------- (5) the 8-bit control

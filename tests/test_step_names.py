@@ -504,7 +504,8 @@ def test_ops_import_nothing_above_them():
 
 
 # ------------------------------------------------ the first-call record
-_REMAT = {"remat_kept", "remat_kept_bytes", "remat_room_bytes"}
+_REMAT = {"remat_kept", "remat_kept_bytes", "remat_room_bytes",
+          "remat_routing_bytes"}  # the last since PR 48
 _STEP = {"remat_fallback", "grad_ring_products", "grad_ring_axis"}
 _LLAMA = _STEP | _REMAT | {"experts_held", "experts_total", "block_length",
                            "attn_positions", "loss_positions"}
