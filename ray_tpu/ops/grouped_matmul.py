@@ -14,6 +14,10 @@ One implementation: the Pallas ``megablox`` kernels that ship with jax
 (``gmm`` for the forward and for dx, ``tgmm`` for the per-group dW), float32
 accumulation, wrapped here in one ``custom_vjp``.  ``jax.lax.ragged_dot`` was
 timed against them on the v5e at OLMoE's shapes and lost (PERF.md, PR 26).
+The (rows, contraction, columns) tile each call walks is a function of that
+call's own shapes (:func:`tile_for`): the kernels mask what of a tile lies
+past a width, so a tile that does not fit the width multiplies zeros (1280
+walked in tiles of 1024 took 1.5 times what it takes in tiles of 640).
 On a TPU the kernels are Mosaic calls; on the CPU, for the tests, the same
 kernels run in Pallas interpret mode; any other backend is refused by name.
 Under a mesh the caller puts the call inside a ``shard_map`` (a Mosaic call
@@ -28,10 +32,19 @@ import math
 import jax
 import numpy as np
 
-#: (rows, contraction, columns) tile the kernels walk, cut to the operands
-#: where they are smaller.  Timed on the v5e at 65536 x 2048 x 1024 in 64
-#: groups (PERF.md, PR 26).
-TILING = (512, 1024, 1024)
+from ray_tpu.util import first_call
+
+#: What the chip sets, not a model: the row tile, the widest tile of a
+#: contraction or of the columns, and the lanes a tile's width is a multiple
+#: of.  At 512 rows a visit multiplies 512 times for each byte of the
+#: expert's matrix it reads, over the v5e's 240 a byte (256 rows sit on the
+#: ridge: faster where groups hold a few hundred rows, slower where they
+#: hold a thousand, and no shape says which); a (512, 1024, 1024) visit's
+#: buffers take 10-12 of the 16 MiB of VMEM a kernel may use, and a width
+#: tile of 2048 or a row tile of 1024 beside 1024 x 1024 is refused.  Swept
+#: on the v5e at the five expert cells' shapes with
+#: ``scripts/gmm_tile_sweep.py`` (PERF.md, PR 50; OLMoE's first in PR 26).
+ROW_TILE, WIDEST_TILE, LANES = 512, 1024, 128
 
 
 def _interpret() -> bool:
@@ -44,9 +57,26 @@ def _interpret() -> bool:
     return backend == "cpu"
 
 
-def _tiling(m: int, k: int, n: int):
-    # the kernels want the row tile to divide the rows
-    return math.gcd(m, TILING[0]), min(k, TILING[1]), min(n, TILING[2])
+def _width_tile(width: int) -> int:
+    """The tile a width (a contraction or the columns) is walked in: the
+    width itself where one tile takes it, else the multiple of 128 whose
+    tiles overhang it least, the widest of those (1280 -> 640, 1856 -> 640,
+    2688 -> 896; 2048 and 4096 -> 1024)."""
+    if width <= WIDEST_TILE:
+        return width
+    return min(range(LANES, WIDEST_TILE + 1, LANES),
+               key=lambda t: (-(-width // t) * t, -t))
+
+
+def tile_for(m: int, k: int, n: int):
+    """The (rows, contraction, columns) tile of a product of (m, k) rows
+    with (k, n) matrices, from its shapes alone; the forward, dx (which
+    contracts over the forward's columns) and dW all ask here, each with
+    its own roles.  The kernels want the row tile to divide the rows.
+    Noted in the first-call record as ``gmm_tiles``."""
+    chosen = math.gcd(m, ROW_TILE), _width_tile(k), _width_tile(n)
+    first_call.entry("gmm_tiles", f"{m}x{k}x{n}", chosen)
+    return chosen
 
 
 def _held(rhs, group_sizes, first_group: int):
@@ -66,7 +96,7 @@ def grouped_matmul(lhs, rhs, group_sizes, first_group: int = 0):
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
     (m, k), n = lhs.shape, rhs.shape[2]
-    return gmm(lhs, rhs, group_sizes, lhs.dtype, _tiling(m, k, n),
+    return gmm(lhs, rhs, group_sizes, lhs.dtype, tile_for(m, k, n),
                group_offset=_held(rhs, group_sizes, first_group),
                interpret=_interpret())
 
@@ -84,11 +114,11 @@ def _bwd(first_group, res, g):
     interpret = _interpret()
     offset = _held(rhs, group_sizes, first_group)
     # dx: the same grouped product against each group's transposed matrix
-    dlhs = gmm(g, rhs, group_sizes, lhs.dtype, _tiling(m, n, k),
+    dlhs = gmm(g, rhs, group_sizes, lhs.dtype, tile_for(m, n, k),
                group_offset=offset, transpose_rhs=True, interpret=interpret)
     # dW: per group held, its rows of lhs transposed times its rows of g
     drhs = tgmm(lhs.swapaxes(0, 1), g, group_sizes, rhs.dtype,
-                _tiling(m, k, n), group_offset=offset,
+                tile_for(m, k, n), group_offset=offset,
                 num_actual_groups=rhs.shape[0], interpret=interpret)
     return dlhs, drhs, None
 

@@ -5,10 +5,11 @@ loaded its executable is a ``train.first_call`` span and a row of
 ``device_telemetry.first_calls()``.  The code that is traced in that call
 knows things no reader of the compiled program can ask it for: what the
 layers keep for the backward, which implementation a scan took, how a mask
-was covered.  It says them with :func:`note` (and :func:`count`, for what
-adds up over a trace); whoever wraps the trace in :func:`noting` reads them
-as one dict.  Nothing here imports the package's layers, so ``ops/`` and
-``models/`` import it at the top of the file.  No metric reads the record.
+was covered.  It says them with :func:`note` (:func:`count` for what adds
+up over a trace, :func:`entry` for what it says a case at a time); whoever
+wraps the trace in :func:`noting` reads them as one dict.  Nothing here
+imports the package's layers, so ``ops/`` and ``models/`` import it at the
+top of the file.  No metric reads the record.
 
 **The keys**, by who notes them (the record also carries ``label``, ``ts``
 and ``seconds``, the span ``compile_s``):
@@ -34,6 +35,9 @@ and ``seconds``, the span ``compile_s``):
                               ``attn_block_kv``, ``attn_block_q_bwd``,
                               ``attn_block_kv_bwd``, ``attn_dq_partials``
                               (the row over the backward's kv block)
+``ops/grouped_matmul.py``     ``gmm_tiles``: ``"m x k x n"`` of each distinct
+                              grouped product traced -> the (rows,
+                              contraction, columns) tile it walks
 ``ops/ssd.py``                ``ssm_scan_kernel`` (the Pallas kernels, or
                               the XLA form), ``ssm_scan_grid`` (the
                               kernels' grid a chip, or None)
@@ -80,6 +84,13 @@ def note(**facts: Any) -> None:
     such a block, nothing."""
     for notes in _open():
         notes.update(facts)
+
+
+def entry(name: str, key: str, value: Any) -> None:
+    """``key -> value`` in the dict ``name`` (from empty) of every block open
+    on this thread: what a trace says once for each distinct case of it."""
+    for notes in _open():
+        notes.setdefault(name, {})[key] = value
 
 
 def count(name: str) -> None:

@@ -111,7 +111,8 @@ SPAN_REGISTRY: Dict[str, str] = {
                         "attn_blocks, attn_blocks_cut, attn_grid_steps_fwd, "
                         "attn_grid_steps_bwd, attn_block_q, attn_block_kv, "
                         "attn_block_q_bwd, attn_block_kv_bwd, "
-                        "attn_dq_partials)",
+                        "attn_dq_partials; from ops/grouped_matmul.py "
+                        "gmm_tiles)",
     "train.report": "session: one train.report() call, step boundary "
                     "included",
     "train.step_done": "profiler: its resolver thread's wait for one "

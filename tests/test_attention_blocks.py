@@ -327,3 +327,40 @@ def test_the_kda_kernels_compile_for_the_v5e(monkeypatch, one_chip):
     backward = 2 * (7 * wide * 2 + 2 * wide * 4 + 3 * square * 2
                     + square * 4 + states) + states
     assert backward < 8 * 2 ** 20  # of 16 MiB
+
+
+def test_the_grouped_products_compile_for_the_v5e(monkeypatch, one_chip):
+    """The expert layers' grouped products (``ops/grouped_matmul.py``: the
+    forward, dx and dW of up and of down) at the four held-share cells' full
+    shapes (``tests/test_olmoe.py``'s ``CELL_PRODUCTS`` but OLMoE's, whose
+    tile is the (512, 1024, 1024) every cell ran until PR 50), in bf16 with the tiles ``tile_for`` gives them, compiled for a
+    described v5e: three Mosaic calls each, none refused for the 16 MiB of
+    scoped VMEM a tile's buffers must fit (a tile is a function of the
+    call's shapes since PR 50: 640 for Solar's 1280, 896 and 640 for
+    Nemotron's 2688 and 1856).  In this file: one worker loads the TPU's
+    compiler."""
+    from ray_tpu.ops.grouped_matmul import grouped_matmul
+    from tests.test_olmoe import CELL_PRODUCTS
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    try:
+        for cell, (M, D, F, G, H, first) in CELL_PRODUCTS.items():
+            for K, N in ((D, F), (F, D)) if H < G else ():
+                def three(lhs, rhs, dout, sizes):
+                    out, pull = jax.vjp(
+                        lambda a, b: grouped_matmul(a, b, sizes, first),
+                        lhs, rhs)
+                    return out, pull(dout)
+
+                text = jax.jit(three).lower(
+                    shape((M, K)), shape((H, K, N)), shape((M, N)),
+                    shape((G,), jnp.int32)).compile().as_text()
+                assert text.count("tpu_custom_call") == 3, (cell, K, N)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)

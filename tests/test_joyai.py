@@ -399,7 +399,9 @@ def test_num_params_flops_and_the_first_call_record():
         "attn_positions": 128, "loss_positions": 128,
         "remat_kept": [], "remat_kept_bytes": 0, "remat_room_bytes": None,
         # the pattern's two expert layers' routing and the module's one's
-        "remat_routing_bytes": 3 * moe.routing_bytes(256, 16, 2)}
+        "remat_routing_bytes": 3 * moe.routing_bytes(256, 16, 2),
+        # a window's products, gate / up and down, and their tiles (PR 50)
+        "gmm_tiles": {"64x128x48": (64, 128, 48), "64x48x128": (64, 48, 128)}}
 
 
 def test_the_remat_rule_is_given_the_modules_sizes():

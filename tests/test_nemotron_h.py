@@ -348,7 +348,9 @@ def test_num_params_and_the_first_call_record():
         "heads_held": 4, "heads_total": 4, "attn_gate": False,
         "remat_kept": [], "remat_kept_bytes": 0, "remat_room_bytes": None,
         # four expert layers' routing, kept under every policy (PR 48)
-        "remat_routing_bytes": 4 * moe.routing_bytes(256, 16, 2)}
+        "remat_routing_bytes": 4 * moe.routing_bytes(256, 16, 2),
+        # a window's products, up and down, and the tiles they walk (PR 50)
+        "gmm_tiles": {"64x128x64": (64, 128, 64), "64x64x128": (64, 64, 128)}}
 
 
 # -------------------------------------------------- (6) the 8-bit control

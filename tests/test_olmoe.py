@@ -20,9 +20,10 @@ from benchmarks.reference import olmoe as reference
 from ray_tpu.models import llama, moe
 from ray_tpu.ops import remat
 from ray_tpu.ops.attention import save_splash_residuals
-from ray_tpu.ops.grouped_matmul import grouped_matmul
+from ray_tpu.ops.grouped_matmul import grouped_matmul, tile_for
 from ray_tpu.parallel import MeshSpec, batch_sharding, make_mesh
 from ray_tpu.parallel.mesh import pytree_sharding
+from ray_tpu.util import first_call
 
 #: benchmarks/lib/correct.py's, which the bf16 program is held to on the chip
 LOSS_TOL, GRAD_TOL = 1e-3, 0.75
@@ -408,19 +409,124 @@ def test_grouped_matmul_refuses_a_backend_without_the_kernel(monkeypatch):
                        jnp.asarray([8], jnp.int32))
 
 
-def test_grouped_matmul_lowers_for_the_tpu_at_olmoe_shapes(monkeypatch):
-    """Lowering for the TPU needs no TPU: at the cell's shapes (8192 tokens x
-    8 experts a token, 64 groups, 2048 -> 1024 and back) the three products
-    become three Mosaic calls, in bf16 with the tiles ops/grouped_matmul.py
-    was timed with."""
+#: The grouped products of the five cells with experts, as a window (or
+#: OLMoE's whole layer) hands them over: cell -> (rows, hidden width, expert
+#: width, groups, held groups, the first held): a share's window lies between
+#: two groups ``rhs`` does not hold (``models/moe.py:_move_window``).
+CELL_PRODUCTS = {
+    "olmoe-s4096": (65536, 2048, 1024, 64, 64, 0),
+    "sdar-ep8-s8192": (16384, 2048, 768, 18, 16, 1),
+    "nemotron-ep16-s8192": (12288, 2688, 1856, 10, 8, 1),
+    "solar-open2-ep40-tp8": (8192, 4096, 1280, 10, 8, 1),
+    "joyai-ep16-s8192": (8192, 2048, 768, 18, 16, 1),
+}
+#: cell -> the tiles of gate / up (rows x hidden -> expert width) and of down
+#: (back): dx of each asks with the other's roles, dW with its own
+CELL_TILES = {
+    "olmoe-s4096": ((512, 1024, 1024), (512, 1024, 1024)),
+    "sdar-ep8-s8192": ((512, 1024, 768), (512, 768, 1024)),
+    "nemotron-ep16-s8192": ((512, 896, 640), (512, 640, 896)),
+    "solar-open2-ep40-tp8": ((512, 1024, 640), (512, 640, 1024)),
+    "joyai-ep16-s8192": ((512, 1024, 768), (512, 768, 1024)),
+}
+
+
+@pytest.mark.parametrize("product", ["gate_up", "down"])
+@pytest.mark.parametrize("cell", sorted(CELL_PRODUCTS))
+def test_the_tile_is_a_function_of_the_products_shapes(cell, product):
+    """What ``grouped_matmul.tile_for`` returns at every distinct call of the
+    five cells: OLMoE's exactly the tile it was timed with, a width over one
+    tile walked in the multiple of 128 that overhangs it least (1280 as 2 x
+    640, 2688 as 3 x 896, 1856 as 3 x 640), the row tile dividing the rows;
+    and the first-call record says so."""
+    m, hidden, width, *_ = CELL_PRODUCTS[cell]
+    k, n = (hidden, width) if product == "gate_up" else (width, hidden)
+    with first_call.noting() as notes:
+        tile = tile_for(m, k, n)
+    assert tile == CELL_TILES[cell][product == "down"]
+    assert notes == {"gmm_tiles": {f"{m}x{k}x{n}": tile}}
+    tm, tk, tn = tile
+    assert m % tm == 0 and tk <= 1024 and tn <= 1024
+    for w, t in ((k, tk), (n, tn)):  # no multiple of 128 overhangs less
+        assert t == w or t % 128 == 0
+        assert all(-(-w // t) * t <= -(-w // c) * c
+                   for c in range(128, 1025, 128))
+
+
+def test_a_width_under_one_tile_is_its_own_tile():
+    assert tile_for(24, 16, 24) == (8, 16, 24)
+    assert tile_for(1024, 1000, 1024) == (512, 1000, 1024)
+    assert tile_for(512, 1152, 1100) == (512, 384, 384)
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_PRODUCTS))
+def test_grouped_matmul_at_the_cells_widths_and_tiles(cell):
+    """The three products (the forward, dx, dW) of gate / up and of down in
+    interpret mode against the per-group einsum in float32, at the cell's
+    own widths, groups, held run and tiles, the rows cut to three row tiles:
+    uneven groups that straddle the row tiles, empty held groups, and,
+    where the cell holds a share, rows before and after the held run, which
+    come out zero and whose matrices' gradients are not asked for.  (OLMoE's
+    64 groups are cut to 8: the interpreter copies all 64 gradients at every
+    visit, and the tile, which is the parent's there, does not ask for the
+    groups.)"""
+    _, hidden, width, G, H, first = CELL_PRODUCTS[cell]
+    G, H = (8, 8) if G == H else (G, H)
+    rng = np.random.default_rng(G * width)
+    M = 3 * 512
+    # the held rows over six of the held groups, the others empty
+    sizes = np.zeros(H, np.int64)
+    sizes[rng.choice(np.arange(H), 6, replace=False)] = rng.multinomial(
+        M - 100 * (G - H), np.ones(6) / 6)
+    if G > H:  # the window: rows before and after the held run
+        sizes = np.concatenate([[37], sizes, [100 * (G - H) - 37]])
+    assert sizes.sum() == M and len(sizes) == G
+    starts = np.concatenate([[0], np.cumsum(sizes)])
+    group_sizes = jnp.asarray(sizes, jnp.int32)
+    for K, N in ((hidden, width), (width, hidden)):
+        lhs = rng.standard_normal((M, K), np.float32)
+        rhs = rng.standard_normal((H, K, N), np.float32) / np.sqrt(K)
+        dout = rng.standard_normal((M, N), np.float32)
+        with first_call.noting() as notes:
+            out, vjp = jax.vjp(
+                lambda a, b: grouped_matmul(a, b, group_sizes, first),
+                jnp.asarray(lhs), jnp.asarray(rhs))
+            dlhs, drhs = vjp(jnp.asarray(dout))
+        # the tiles of the cell's own calls, rows apart
+        assert set(notes["gmm_tiles"].values()) == {
+            tile_for(M, K, N), tile_for(M, N, K)} == {
+            (512, *t[1:]) for t in CELL_TILES[cell]}
+        want_out, want_dlhs = np.zeros((M, N), np.float32), np.zeros_like(lhs)
+        for h in range(H):
+            lo, hi = starts[first + h], starts[first + h + 1]
+            want_out[lo:hi] = lhs[lo:hi] @ rhs[h]
+            want_dlhs[lo:hi] = dout[lo:hi] @ rhs[h].T
+            np.testing.assert_allclose(drhs[h], lhs[lo:hi].T @ dout[lo:hi],
+                                       rtol=2e-4, atol=2e-4)
+        np.testing.assert_allclose(out, want_out, rtol=2e-4, atol=2e-4)
+        np.testing.assert_allclose(dlhs, want_dlhs, rtol=2e-4, atol=2e-4)
+        for got in (np.asarray(out), np.asarray(dlhs)):  # exactly zero
+            assert not got[:starts[first]].any()
+            assert not got[starts[first + H]:].any()
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_PRODUCTS))
+def test_grouped_matmul_lowers_for_the_tpu_at_the_cells_shapes(monkeypatch,
+                                                               cell):
+    """Lowering for the TPU needs no TPU: at each cell's full shapes (OLMoE:
+    8192 tokens x 8 experts a token, 64 groups, 2048 -> 1024 and back; a
+    share's window: ``rhs`` a run of the groups from the second on) the three
+    products become three Mosaic calls, in bf16 with the tiles
+    ``grouped_matmul.tile_for`` gives them: a tile the lowering refuses is
+    found without a chip."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    M, G, D, F = 65536, 64, 2048, 1024
+    M, D, F, G, H, first = CELL_PRODUCTS[cell]
     group_sizes = jax.ShapeDtypeStruct((G,), jnp.int32)
     for K, N in ((D, F), (F, D)):
         lhs = jax.ShapeDtypeStruct((M, K), jnp.bfloat16)
-        rhs = jax.ShapeDtypeStruct((G, K, N), jnp.bfloat16)
+        rhs = jax.ShapeDtypeStruct((H, K, N), jnp.bfloat16)
         text = jax.jit(jax.value_and_grad(
-            lambda a, b, s: jnp.sum(grouped_matmul(a, b, s).astype(
+            lambda a, b, s: jnp.sum(grouped_matmul(a, b, s, first).astype(
                 jnp.float32)), (0, 1))).trace(lhs, rhs, group_sizes).lower(
                     lowering_platforms=("tpu",)).as_text()
         assert text.count("tpu_custom_call") == 3

@@ -378,7 +378,9 @@ def test_num_params_flops_and_the_first_call_record():
         "router_scoring": "sigmoid", "attn_positions": 128,
         "loss_positions": 128,
         "remat_kept": [], "remat_kept_bytes": 0, "remat_room_bytes": None,
-        "remat_routing_bytes": 4 * moe.routing_bytes(256, 16, 2)}
+        "remat_routing_bytes": 4 * moe.routing_bytes(256, 16, 2),
+        # a window's products, gate / up and down, and their tiles (PR 50)
+        "gmm_tiles": {"64x128x48": (64, 128, 48), "64x48x128": (64, 48, 128)}}
 
 
 # -------------------------------------------------- (5) the 8-bit control

@@ -512,14 +512,15 @@ _LLAMA = _STEP | _REMAT | {"experts_held", "experts_total", "block_length",
 _HYBRID = _STEP | _REMAT | {
     "layer_kinds", "loss_positions", "attn_positions", "heads_held",
     "heads_total", "attn_gate", "experts_held", "experts_total",
-    "router_scoring"}
+    "router_scoring", "gmm_tiles"}  # the last since PR 50
 #: the keys of each tiny family's first-call record (no splash kernel on the
 #: CPU, so no ``attn_*`` geometry), recorded on PR 46's parent; since PR 46
 #: ``tiny-nemotron-h`` also carries the attention kind's ``heads_held``,
 #: ``heads_total`` and ``attn_gate``, which the parent noted only beside ``K``
 FIRST_CALL_KEYS = {
     "tiny-gpt2": _STEP,
-    "tiny-llama": _LLAMA, "tiny-olmoe": _LLAMA, "tiny-sdar": _LLAMA,
+    "tiny-llama": _LLAMA, "tiny-olmoe": _LLAMA | {"gmm_tiles"},
+    "tiny-sdar": _LLAMA | {"gmm_tiles"},
     "tiny-nemotron-h": _HYBRID | {
         "ssm_heads", "ssm_state", "ssm_chunk", "ssm_chunks",
         "ssm_scan_kernel", "ssm_scan_grid"},
@@ -538,6 +539,7 @@ def test_the_first_call_record_carries_the_parents_keys(name):
     import jax.numpy as jnp
 
     from benchmarks.lib import spec
+    from ray_tpu.ops import grouped_matmul
     from ray_tpu.parallel.train_state import _first_call_notes
     from ray_tpu.util import first_call
 
@@ -553,6 +555,10 @@ def test_the_first_call_record_carries_the_parents_keys(name):
     assert all(f"``{key}``" in first_call.__doc__ for key in notes)
     assert notes["remat_fallback"] is False \
         and notes["grad_ring_products"] == 0
+    if "gmm_tiles" in notes:  # a product's shapes -> the tile it walks
+        assert all(grouped_matmul.tile_for(*map(int, shape.split("x"))) == tile
+                   for shape, tile in notes["gmm_tiles"].items())
+        assert len(notes["gmm_tiles"]) >= 2  # gate / up, and down
     if name == "tiny-nemotron-h":
         assert (notes["heads_held"], notes["heads_total"],
                 notes["attn_gate"]) == (4, 4, False)
