@@ -9,6 +9,10 @@ at a ``block_until_ready``; inside it the loop hands the loss to
 and never idles the device while its own work per step is shorter than a
 step.  It stops dispatching when ``--seconds`` have passed; the steps then in
 flight are part of the window, which ends when the last of them has.
+
+The batches are a stream one epoch long, or, where the traffic file states a
+corpus (``dataset_batches``), the program's ingest epoch after epoch: the same
+rows on every run, each epoch in the order ``--seed`` gives it.
 """
 
 from __future__ import annotations
@@ -115,8 +119,7 @@ def run(cell: Dict, args, t_process: float) -> Dict:
         cell=cell, chips=chips, peaks=peaks,
         tokens_per_step=global_batch * seq_len,
         flops_per_step=family.flops_per_token * global_batch * seq_len,
-        attention_batch_per_chip=seqs_per_chip, seq_len=seq_len,
-        attention_heads=family.attention_heads)
+        seq_len=seq_len, attention_calls=family.attention_calls)
     out: Dict = {"device_count": len(local), "marks": marks}
     trace_dir = os.path.join(args.out_dir, "trace",
                              f"{cell['name']}-seed{args.seed}")
@@ -141,8 +144,20 @@ def run(cell: Dict, args, t_process: float) -> Dict:
         record.init_state_s = time.perf_counter() - t0
         mark("state_ready")
         step = jit_train_step(family.make_train_step(optimizer), mesh=mesh)
-        batches = iter(train.get_dataset_shard("train").iter_batches(
-            batch_size=global_batch, device_sharding=batch_sharding(mesh)))
+        shard = train.get_dataset_shard("train")
+
+        def epochs():
+            # A stream is one epoch long; a corpus (``dataset_batches`` in
+            # the traffic file) is trained on epoch after epoch, each call
+            # of ``iter_batches`` the ingest's next epoch in its own order.
+            while True:
+                yield from shard.iter_batches(
+                    batch_size=global_batch,
+                    device_sharding=batch_sharding(mesh))
+                if getattr(gen, "dataset_batches", None) is None:
+                    return
+
+        batches = epochs()
         losses = []
 
         def one_step(i, spans=None):

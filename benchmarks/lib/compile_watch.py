@@ -52,22 +52,38 @@ _COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                 "collective-permute")
 
 
+#: where an instruction's text starts: ``  %name = `` or ``  ROOT %name = ``
+_INSTRUCTION = re.compile(r"^[ \t]*(?:ROOT )?%?([\w.\-]+) = ", re.M)
+_OPERANDS = re.compile(
+    r"operand_layout_constraints=\{((?:[^{}]|\{[^{}]*\})*)\}")
+
+
 def hlo_report(hlo: str) -> Dict:
     """From the compiled (per-device) module's text: the Mosaic custom calls
-    by instruction name with the jax op that made each, and the collectives
-    by kind (an async pair counts once, at its ``-start``; the TPU compiler
-    emits a reduce-scatter as a fusion that ``calls=%all-reduce-scatter``,
-    counted as one)."""
-    mosaic = {}
-    for line in hlo.splitlines():
-        if "tpu_custom_call" not in line or " custom-call(" not in line:
+    by instruction name with the jax op that made each (``mosaic``) and the
+    shapes the kernel is handed (``operands``: ``[dtype, dims]`` of each
+    operand, from the call's ``operand_layout_constraints``), and the
+    collectives by kind (an async pair counts once, at its ``-start``; the TPU
+    compiler emits a reduce-scatter as a fusion that
+    ``calls=%all-reduce-scatter``, counted as one).  An instruction's text
+    runs to the next instruction's: a kernel's ``kernel_metadata`` holds
+    newlines, and the call's ``op_name`` stands after them."""
+    mosaic, operands = {}, {}
+    starts = list(_INSTRUCTION.finditer(hlo))
+    for at, following in zip(starts, starts[1:] + [None]):
+        text = hlo[at.start():following.start() if following else len(hlo)]
+        head = text.split("\n", 1)[0]
+        if "tpu_custom_call" not in head or " custom-call(" not in head:
             continue
-        name = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = ", line)
-        op_name = re.search(r'op_name="([^"]*)"', line)
-        if name:
-            mosaic[name.group(1)] = op_name.group(1) if op_name else ""
+        op_name = re.search(r'op_name="([^"]*)"', text)
+        mosaic[at.group(1)] = op_name.group(1) if op_name else ""
+        handed = _OPERANDS.search(head)
+        operands[at.group(1)] = [
+            [dtype, [int(n) for n in dims.split(",") if n]]
+            for dtype, dims in re.findall(r"([a-z]\w*)\[([\d,]*)\]",
+                                          handed.group(1))] if handed else []
     counts = {kind: len(re.findall(rf" {kind}(?:-start)?\(", hlo))
               for kind in _COLLECTIVES}
     counts["reduce-scatter"] += len(re.findall(
         r" fusion\([^\n]*calls=%?all-reduce-scatter", hlo))
-    return {"mosaic": mosaic, "collectives": counts}
+    return {"mosaic": mosaic, "operands": operands, "collectives": counts}

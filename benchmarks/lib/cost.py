@@ -15,10 +15,19 @@ rows the program pads.  Forward is 2 FLOPs per parameter per token, backward
 backward, 6 x S x D causal.
 
 Attention kernel cost (what ``kernels.splash_roofline`` divides kernel time
-into) is that of the flash algorithm the kernel implements: the forward call
-does the two matmuls, the backward call five (it recomputes QK^T, then dV, dP,
-dQ, dK), both over the causal half.  Bytes are one read of each input and one
-write of each output in the kernel's dtype.
+into) is that of the flash algorithm the kernel implements, over the pairs the
+call's own mask allows (``lib/family.py:AttentionCall``: the family states
+each kind of call its step makes; a causal call's pairs are half the square,
+``causal_pairs``: the diagonal's half, S / 2 pairs, is left out).  The
+forward call is QK^T over the q.k head and PV over the v head, ``2 x (qk +
+v)`` FLOPs a pair; the fused backward recomputes QK^T and makes dV, dP, dQ
+and dK, three products over the q.k head and two over the v head, ``2 x (3 qk
++ 2 v)`` a pair (4 and 10 times the head dimension where the two are one).
+Bytes are one read of each input and one write of each output in the kernel's
+dtype: q and o (do, dq) at the query heads and length, k and v (dk, dv) at
+the key/value heads and length the kernel is handed, q and k at the q.k head
+dimension and v and o at v's: the model's own, whatever an implementation
+pads.
 """
 
 from __future__ import annotations
@@ -50,17 +59,26 @@ def model_flops_per_token(matmul_params: int, n_layer: int, attn_width: int,
 
 
 # ----------------------------------------------------------------- kernels
-def attention_call_cost(kind: str, batch: int, heads: int, seq: int,
-                        head_dim: int, itemsize: int = 2,
-                        causal: bool = True) -> Tuple[float, float]:
-    """(FLOPs, bytes) of one flash-attention call over (batch, heads, seq,
-    head_dim); ``kind`` is ``fwd`` or ``bwd``."""
-    square = batch * heads * seq * seq * head_dim * (0.5 if causal else 1.0)
-    tensor = batch * heads * seq * head_dim * itemsize
-    if kind == "fwd":
-        return 2 * 2.0 * square, 4.0 * tensor       # q k v -> o
-    if kind == "bwd":
-        return 5 * 2.0 * square, 8.0 * tensor       # q k v o do -> dq dk dv
+def causal_pairs(seq_len: int) -> float:
+    """Pairs a head of a causal call over ``seq_len`` positions is charged:
+    half the square."""
+    return seq_len * seq_len / 2
+
+
+def attention_call_cost(kind: str, batch: int, call, seq_len: int,
+                        itemsize: int = 2) -> Tuple[float, float]:
+    """(FLOPs, bytes) of one flash-attention call of the kind ``call``
+    (``lib/family.py:AttentionCall``) over ``batch`` rows of a cell whose
+    sequence length is ``seq_len``; ``kind`` is ``fwd`` or ``bwd``."""
+    qk, v = call.qk_dim, call.v_dim
+    pairs = batch * call.q_heads * call.pairs(seq_len)
+    q_like = batch * call.q_heads * call.q_len * seq_len * itemsize
+    kv_like = batch * call.kv_heads * call.kv_len * seq_len * itemsize
+    once = (qk + v) * (q_like + kv_like)            # q o; k v
+    if kind == "fwd":    # q k v -> o
+        return 2.0 * (qk + v) * pairs, float(once)
+    if kind == "bwd":    # q k v o do -> dq dk dv
+        return 2.0 * (3 * qk + 2 * v) * pairs, 2.0 * once
     raise ValueError(f"attention call kind {kind!r} (use fwd|bwd)")
 
 

@@ -16,21 +16,13 @@ module** adds, for each of its ``num_nextn_predict_layers``: ``w_eh`` (2 x
 hidden x hidden), one more layer of latent attention and experts, and one
 more pass through the head.
 
-**A splash call at two head dimensions**: the forward is QK^T over the q.k
-head and PV over the v head, ``2 x (qk + v)`` FLOPs a pair; the fused
-backward recomputes QK^T and makes dV, dP, dQ and dK: three products over
-the q.k head and two over the v head, ``2 x (3 qk + 2 v)`` a pair; both over
-the causal half.  Bytes: one read of each input and one write of each output
-in the kernel's dtype, q and k (and dq, dk) at the q.k head, v and o (and do,
-dv) at the v head: the model's own 192 and 128, whatever an implementation
-pads.
+A splash call's cost at the two head dimensions is ``lib/cost.py``'s
+(``attention_call_cost`` over the kind the adapter states).
 """
 
 from __future__ import annotations
 
 from typing import Dict, Tuple
-
-from benchmarks.lib import cost
 
 
 def layers(cfg: Dict) -> Tuple[int, int, int]:
@@ -86,24 +78,3 @@ def model_flops_per_token(cfg: Dict, seq_len: int) -> float:
     attention = 3.0 * mla * seq_len * H * (
         cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"] + cfg["v_head_dim"])
     return 6.0 * matmuls + attention
-
-
-def attention_call_cost(kind: str, cfg: Dict, batch: int, seq: int,
-                        itemsize: int = 2) -> Tuple[float, float]:
-    """(FLOPs, bytes) of one causal splash call over ``batch`` rows of
-    ``seq`` positions; ``kind`` is ``fwd`` or ``bwd``."""
-    qk, dv = cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"], \
-        cfg["v_head_dim"]
-    pairs = batch * cfg["num_attention_heads"] * seq * seq * 0.5
-    tensor = batch * cfg["num_attention_heads"] * seq * itemsize
-    if kind == "fwd":    # q k v -> o
-        return 2.0 * (qk + dv) * pairs, (2 * qk + 2 * dv) * tensor
-    if kind == "bwd":    # q k v o do -> dq dk dv
-        return 2.0 * (3 * qk + 2 * dv) * pairs, (4 * qk + 4 * dv) * tensor
-    raise ValueError(f"attention call kind {kind!r} (use fwd|bwd)")
-
-
-def attention_least_time(kind: str, cfg: Dict, batch: int, seq: int,
-                         peak_flops: float, peak_bw: float):
-    return cost.least_time(*attention_call_cost(kind, cfg, batch, seq),
-                           peak_flops, peak_bw)

@@ -19,20 +19,34 @@ the step).  Attention is counted over the mask's own area, which per head is
 
 so 4 x head_dim FLOPs a pair forward (QK^T and PV), 12 with the backward.
 
-The splash calls: the forward does the two matmuls over the area, the fused
-backward five (it recomputes QK^T, then dV, dP, dQ, dK).  Bytes are one read
-of each input and one write of each output in the kernel's dtype, K and V at
-their own head count (the kernel reads them so).
+A splash call's cost over such an area is ``lib/cost.py``'s
+(``attention_call_cost`` over the kinds the adapter states: the one call over
+2S x 2S, and the two calls that cover the same mask, the noised rows S x 2S
+and the clean rows S x S, whose areas are the table's first two lines and its
+third).
 """
 
 from __future__ import annotations
 
-from typing import Dict, Tuple
+from typing import Dict
 
 
 def mask_area(seq_len: int, block_length: int) -> int:
     """Pairs (query, key) one head's mask allows over a row's 2S positions."""
     return seq_len * seq_len + seq_len * block_length
+
+
+def noised_area(seq_len: int, block_length: int) -> float:
+    """Of ``mask_area``, the pairs of the noised copy's S queries (over the
+    2S keys: their own block of the noised copy, earlier blocks of the
+    clean)."""
+    return seq_len * block_length + seq_len * (seq_len - block_length) / 2
+
+
+def clean_area(seq_len: int, block_length: int) -> float:
+    """Of ``mask_area``, the pairs of the clean copy's S queries (over the
+    clean copy's S keys)."""
+    return seq_len * (seq_len + block_length) / 2
 
 
 def position_matmul_params(cfg: Dict) -> float:
@@ -55,19 +69,3 @@ def model_flops_per_token(cfg: Dict, seq_len: int) -> float:
     attention = 12.0 * L * width * mask_area(seq_len, cfg["block_length"]) \
         / seq_len
     return 6.0 * matmuls + attention
-
-
-def attention_call_cost(kind: str, cfg: Dict, batch: int, seq_len: int,
-                        itemsize: int = 2) -> Tuple[float, float]:
-    """(FLOPs, bytes) of one splash call over a batch of block-diffusion
-    rows of ``seq_len``; ``kind`` is ``fwd`` or ``bwd``."""
-    H, KV, hd = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                 cfg["head_dim"])
-    pairs = batch * H * mask_area(seq_len, cfg["block_length"]) * hd
-    q_like = batch * H * 2 * seq_len * hd * itemsize
-    kv_like = batch * KV * 2 * seq_len * hd * itemsize
-    if kind == "fwd":
-        return 2 * 2.0 * pairs, 2.0 * q_like + 2.0 * kv_like  # q k v -> o
-    if kind == "bwd":  # q k v o do -> dq dk dv
-        return 5 * 2.0 * pairs, 4.0 * q_like + 4.0 * kv_like
-    raise ValueError(f"attention call kind {kind!r} (use fwd|bwd)")

@@ -17,9 +17,8 @@ class RunRecord:
     peaks: Any                      # lib/peaks.py Peaks of one chip
     tokens_per_step: int
     flops_per_step: float           # lib/cost.py, the whole mesh
-    attention_batch_per_chip: int   # sequences one chip's kernel call sees
     seq_len: int
-    attention_heads: Any            # (heads, head_dim)
+    attention_calls: Any            # lib/family.py AttentionCall kinds
     steps: int = 0                  # dispatched in the window
     window_s: float = 0.0           # fenced
     init_state_s: float = 0.0

@@ -10,7 +10,17 @@ document boundaries and none over padding: the program has neither (PERF.md,
 Open questions), so every position is trained on.
 
 Batch ``i`` is a pure function of ``(seed, i)``, so the ingest may build
-batches in any order and on any thread.  A traffic file names its generator
+batches in any order and on any thread.
+
+A traffic file that states ``dataset_batches`` (with ``data_seed``) describes
+a job that trains for epochs over a small corpus: the dataset is that many
+batches, batch ``j`` a pure function of ``(data_seed, j)``, the same rows
+whatever ``--seed`` is, and the run iterates the program's ingest epoch after
+epoch; ``--seed`` then draws only the order of each epoch (the ingest's
+seeded shuffle), the check rows and whatever else the cell draws from it.
+That is for models whose step length is data (expert layers that walk as many
+windows as their router sent rows): every seed does the same work, in another
+order.  A traffic file names its generator
 under ``"generator"``: a key of ``GENERATORS`` here, or a module
 ``benchmarks/traffic_gen/<name>.py`` with the same ``make`` signature.
 """
@@ -32,7 +42,8 @@ def _zipf_cdf(vocab_size: int, exponent: float) -> np.ndarray:
 
 class PackedDocuments:
     """``params``: seq_len, seqs_per_chip, doc_len_median, doc_len_sigma,
-    zipf_exponent.  Token id = Zipf rank - 1 over ``vocab_size`` ids."""
+    zipf_exponent and, for a corpus trained on for epochs, dataset_batches
+    and data_seed.  Token id = Zipf rank - 1 over ``vocab_size`` ids."""
 
     def __init__(self, params: Dict, *, vocab_size: int, eod_id: int,
                  global_batch: int, seq_len: int, seed: int):
@@ -44,11 +55,20 @@ class PackedDocuments:
         self.median = float(params["doc_len_median"])
         self.sigma = float(params["doc_len_sigma"])
         self.exponent = float(params["zipf_exponent"])
+        #: batches in the corpus, or None: a stream, one epoch long
+        self.dataset_batches = params.get("dataset_batches")
+        if self.dataset_batches is not None:
+            self.dataset_batches = int(self.dataset_batches)
+            self.data_seed = int(params["data_seed"])
 
     def rows(self, index: int) -> Tuple[np.ndarray, Dict]:
         """Batch ``index`` as (global_batch, S+1) int32 rows, and what was
         drawn: document lengths as packed, padding and separator counts."""
-        rng = np.random.default_rng([self.seed, index])
+        if self.dataset_batches is None:
+            rng = np.random.default_rng([self.seed, index])
+        else:
+            rng = np.random.default_rng(
+                [self.data_seed, index % self.dataset_batches])
         B, width = self.global_batch, self.seq_len + 1
         cdf = _zipf_cdf(self.vocab_size, self.exponent)
         rows = np.searchsorted(cdf, rng.random((B, width))).astype(np.int32)
@@ -103,13 +123,16 @@ class PackedDocuments:
                 "padding_share": padding / max(positions, 1)}
 
     def dataset(self, n_batches: int):
-        """``n_batches`` lazy blocks, one global batch each, through the
-        program's own dataset API so that the streaming ingest, its shuffle
-        window, its prefetcher and ``device_put_batch`` do the work they do
-        in a job."""
+        """``n_batches`` lazy blocks (the corpus's ``dataset_batches`` where
+        the traffic file states them: one epoch), one global batch each,
+        through the program's own dataset API so that the streaming ingest,
+        its shuffle window, its prefetcher and ``device_put_batch`` do the
+        work they do in a job."""
         from ray_tpu import data
 
         B = self.global_batch
+        if self.dataset_batches is not None:
+            n_batches = self.dataset_batches
 
         def to_batch(block):
             return self.batch(int(block["id"][0]) // B)

@@ -7,7 +7,7 @@ import functools
 from typing import Dict
 
 from benchmarks.lib import cost
-from benchmarks.lib.family import Family
+from benchmarks.lib.family import Family, causal
 from benchmarks.reference import gpt2 as reference
 
 
@@ -36,5 +36,6 @@ def build(config_file: Dict, seq_len: int) -> Family:
             p, t, y, c, q_block=q_block),
         flops_per_token=cost.model_flops_per_token(
             cost.gpt2_matmul_params(c), c["n_layer"], c["n_embd"], seq_len),
-        attention_heads=(c["n_head"], c["n_embd"] // c["n_head"]),
+        attention_calls=(causal(c["n_head"], c["n_head"],
+                                c["n_embd"] // c["n_head"]),),
         vocab_size=c["vocab_size"], eod_id=c["eos_token_id"])

@@ -13,13 +13,9 @@ the documents and their order; the learning rate is ``sdar.py``'s, the other
 families' 3e-4 reached linearly from zero over the configuration's
 ``lr_warmup_steps``.
 
-``Family.attention_heads`` is (heads, 160): ``lib/cost.py`` has one head
-dimension a call, and at the mean of the q.k head's 192 and the v head's 128
-its forward FLOPs and every byte of a 192 / 128 call come out exactly and
-its backward FLOPs 4 % low (5 products of 160 where the call has 3 of 192
-and 2 of 128), so the all-cell ``kernels.splash_roofline`` reads a little
-low here; ``kernels.mla_attn_roofline`` counts the two dimensions
-(``lib/cost_joyai.py``).
+``Family.attention_calls`` states one kind: causal, every head a key head,
+the q.k head ``qk_head_dim`` wide and the v head ``v_head_dim`` (192 and 128:
+``lib/cost.py`` counts the two dimensions apart).
 """
 
 from __future__ import annotations
@@ -27,8 +23,8 @@ from __future__ import annotations
 import functools
 from typing import Dict
 
-from benchmarks.lib import cost_joyai
-from benchmarks.lib.family import Family
+from benchmarks.lib import cost, cost_joyai
+from benchmarks.lib.family import AttentionCall, Family
 from benchmarks.models.sdar import _learning_rate
 from benchmarks.reference import joyai_llm_flash as reference
 
@@ -115,8 +111,7 @@ def build(config_file: Dict, seq_len: int) -> Family:
         reference_loss=lambda p, t, y, q_block: reference.loss(
             p, t, y, c, q_block=q_block),
         flops_per_token=cost_joyai.model_flops_per_token(c, seq_len),
-        attention_heads=(
-            c["num_attention_heads"],
-            (c["qk_nope_head_dim"] + c["qk_rope_head_dim"]
-             + c["v_head_dim"]) // 2),
+        attention_calls=(AttentionCall(
+            "causal", c["num_attention_heads"], c["num_key_value_heads"],
+            c["qk_head_dim"], c["v_head_dim"], pairs=cost.causal_pairs),),
         vocab_size=c["vocab_size"], eod_id=c["eos_token_id"])

@@ -7,7 +7,7 @@ import functools
 from typing import Dict
 
 from benchmarks.lib import cost
-from benchmarks.lib.family import Family
+from benchmarks.lib.family import Family, causal
 from benchmarks.reference import llama as reference
 
 
@@ -37,6 +37,7 @@ def build(config_file: Dict, seq_len: int) -> Family:
         flops_per_token=cost.model_flops_per_token(
             cost.llama_matmul_params(c), c["num_hidden_layers"],
             c["num_attention_heads"] * hd, seq_len),
-        # the program repeats k and v to the query heads before the kernel
-        attention_heads=(c["num_attention_heads"], hd),
+        # the kernel is handed k and v at their own head count
+        attention_calls=(causal(c["num_attention_heads"],
+                                c["num_key_value_heads"], hd),),
         vocab_size=c["vocab_size"], eod_id=c["eos_token_id"])

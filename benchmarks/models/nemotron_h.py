@@ -18,7 +18,7 @@ import functools
 from typing import Dict
 
 from benchmarks.lib import cost_nemotron
-from benchmarks.lib.family import Family
+from benchmarks.lib.family import Family, causal
 from benchmarks.models.sdar import _learning_rate
 from benchmarks.reference import nemotron_h as reference
 
@@ -96,5 +96,6 @@ def build(config_file: Dict, seq_len: int) -> Family:
         reference_loss=lambda p, t, y, q_block: reference.loss(
             p, t, y, c, q_block=q_block),
         flops_per_token=cost_nemotron.model_flops_per_token(c, seq_len),
-        attention_heads=(c["num_attention_heads"], c["head_dim"]),
+        attention_calls=(causal(c["num_attention_heads"],
+                                c["num_key_value_heads"], c["head_dim"]),),
         vocab_size=c["vocab_size"], eod_id=c["eos_token_id"])

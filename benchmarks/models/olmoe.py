@@ -8,7 +8,7 @@ import functools
 from typing import Dict
 
 from benchmarks.lib import cost_moe
-from benchmarks.lib.family import Family
+from benchmarks.lib.family import Family, causal
 from benchmarks.reference import olmoe as reference
 
 
@@ -44,5 +44,6 @@ def build(config_file: Dict, seq_len: int) -> Family:
         reference_loss=lambda p, t, y, q_block: reference.loss(
             p, t, y, c, q_block=q_block),
         flops_per_token=cost_moe.model_flops_per_token(c, seq_len),
-        attention_heads=(c["num_attention_heads"], hd),
+        attention_calls=(causal(c["num_attention_heads"],
+                                c["num_key_value_heads"], hd),),
         vocab_size=c["vocab_size"], eod_id=c["eos_token_id"])

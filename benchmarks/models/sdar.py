@@ -30,7 +30,7 @@ import functools
 from typing import Dict
 
 from benchmarks.lib import cost_sdar
-from benchmarks.lib.family import Family
+from benchmarks.lib.family import AttentionCall, Family
 from benchmarks.reference import sdar as reference
 
 
@@ -42,6 +42,26 @@ def _learning_rate(warmup_steps: int):
     import optax
 
     return optax.linear_schedule(0.0, 3e-4, warmup_steps)
+
+
+def attention_calls(c: Dict):
+    """The kinds of both covers of the mask, told apart by their lengths:
+    ``whole``, the one call over a row's 2S x 2S positions that
+    ``ops/attention.py:block_diffusion_attention`` makes today, and the two
+    calls that would cover the same mask, ``noised`` (the noised copy's S
+    queries over the 2S keys) and ``clean`` (the clean copy's S queries over
+    its own S keys), whose areas sum to the whole mask's.  A step makes the
+    one or the two; a kind that claims no call costs nothing."""
+    heads = (c["num_attention_heads"], c["num_key_value_heads"],
+             c["head_dim"], c["head_dim"])
+    Bk = c["block_length"]
+    return (
+        AttentionCall("whole", *heads, q_len=2, kv_len=2,
+                      pairs=lambda S: cost_sdar.mask_area(S, Bk)),
+        AttentionCall("noised", *heads, q_len=1, kv_len=2,
+                      pairs=lambda S: cost_sdar.noised_area(S, Bk)),
+        AttentionCall("clean", *heads, q_len=1, kv_len=1,
+                      pairs=lambda S: cost_sdar.clean_area(S, Bk)))
 
 
 def build(config_file: Dict, seq_len: int) -> Family:
@@ -94,7 +114,5 @@ def build(config_file: Dict, seq_len: int) -> Family:
         reference_loss=lambda p, t, y, q_block: reference.loss(
             p, t, y, c, q_block=q_block),
         flops_per_token=cost_sdar.model_flops_per_token(c, seq_len),
-        # what one splash call sees: 2S positions; the metrics built on
-        # (heads, head_dim) and the cell's S count a causal S x S call
-        attention_heads=(c["num_attention_heads"], c["head_dim"]),
+        attention_calls=attention_calls(c),
         vocab_size=c["vocab_size"] - 1, eod_id=c["eos_token_id"])

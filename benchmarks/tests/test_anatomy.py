@@ -51,8 +51,7 @@ def recorded(name):
         held = json.load(f)
     run = RunRecord(
         cell={}, chips=1, peaks=None, tokens_per_step=2048,
-        flops_per_step=1.0, attention_batch_per_chip=8, seq_len=256,
-        attention_heads=(4, 32), trace=trace,
+        flops_per_step=1.0, seq_len=256, attention_calls=(), trace=trace,
         steady=tr.steady_window(trace.first.modules,
                                 tr.step_module(trace.first.modules)),
         hlo={"mosaic": held["mosaic"], "collectives": {}})

@@ -10,6 +10,11 @@ from benchmarks.lib import spec
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+#: ``reduced`` may never name a width: a hidden, intermediate, latent, state
+#: or projection size, a head size, an expansion factor, the experts a token
+#: (``vocab_size`` is a cut the ``model-configs`` guide allows)
+WIDTH = re.compile(r"(_dim|_rank|hidden_size|intermediate_size|state_size"
+                   r"|n_embd|n_inner|n_head|expand|num_experts_per_tok)$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 
 
@@ -45,7 +50,7 @@ def test_configs(bench):
         assert set(c["reduced"]) == set(held["reduced"])
         for key in c["reduced"]:
             assert NAME.match(key)
-            assert not re.search(r"(_dim|_rank|_size|n_embd|n_head)$", key)
+            assert not WIDTH.search(key)
         assert held["layout"]["chips"] in (1, 4)
         # limits of its own for lib/correct.py come with their readings
         if "check" in held:

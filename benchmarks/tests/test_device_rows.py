@@ -27,8 +27,7 @@ def _record(cell_name, rows, **more):
     cell = spec.load_cell(spec.load_benchmark(), cell_name)
     return RunRecord(cell=cell, chips=1, peaks=peaks_for("TPU v5 lite"),
                      tokens_per_step=8192, flops_per_step=1.0,
-                     attention_batch_per_chip=1, seq_len=8192,
-                     attention_heads=(32, 128), steps=len(rows),
+                     seq_len=8192, attention_calls=(), steps=len(rows),
                      profiler_rows=rows, **more)
 
 

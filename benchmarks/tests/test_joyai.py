@@ -13,14 +13,14 @@ import numpy as np
 import pytest
 
 from benchmarks.lib import correct, cost, cost_joyai, spec
+from benchmarks.lib.family import causal
 from benchmarks.tests.test_run import result_line, run
 from ray_tpu.parallel import MeshSpec, make_mesh
 
 CELL = "joyai-ep16-s8192"
 CONFIG = "joyai-llm-flash-l6-ep16"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-NEW_METRICS = {"step.latent_ms", "step.mtp_ms", "kernels.mla_attn_ms",
-               "kernels.mla_attn_roofline"}
+NEW_METRICS = {"step.latent_ms", "step.mtp_ms"}
 SHARED_METRICS = {"step.moe_held_rows", "step.moe_load_max",
                   "step.moe_moved_rows", "step.moe_shared_ms",
                   "step.moe_routed_ms", "kernels.gmm_held_ms",
@@ -104,7 +104,10 @@ def test_parameters_are_the_issues_arithmetic(config):
     assert shapes["experts"]["router"].shape == (6, 2048, 256)
     assert shapes["experts"]["w_gate"].shape == (6, 16, 2048, 768)
     assert shapes["mtp"]["w_eh"].shape == (4096, 2048)
-    assert family.vocab_size == 16160 and family.attention_heads == (32, 160)
+    assert family.vocab_size == 16160
+    (call,) = family.attention_calls
+    assert call.shapes(8192) == ((32, 8192, 192), (32, 8192, 192),
+                                 (32, 8192, 128))
 
 
 def test_model_flops_by_hand(config):
@@ -130,23 +133,25 @@ def test_model_flops_by_hand(config):
 
 def test_a_calls_flops_and_bytes(config):
     """Forward 2 x (192 + 128) a pair, backward 2 x (3 x 192 + 2 x 128),
-    over the causal half; q and k (dq, dk) at 192, v and o (do, dv) at 128.
-    ``lib/cost.py`` at the one head dimension 160 the adapter hands it gives
-    the forward and the bytes exactly and the backward 4 % low."""
+    over the causal half; q and k (dq, dk) at 192, v and o (do, dv) at 128:
+    ``lib/cost.py`` over the kind the adapter states.  At one head dimension
+    of 160, which the adapter stated until PR 51, the forward and the bytes
+    come out the same and the backward 4 % low."""
     B, H, S = 1, 32, 8192
     pairs = B * H * S * S / 2
-    fwd = cost_joyai.attention_call_cost("fwd", config, B, S)
-    bwd = cost_joyai.attention_call_cost("bwd", config, B, S)
+    (call,) = spec.load_module("models", "joyai_llm_flash").build(
+        config, S).attention_calls
+    fwd = cost.attention_call_cost("fwd", B, call, S)
+    bwd = cost.attention_call_cost("bwd", B, call, S)
     assert fwd == (640 * pairs, B * H * S * 2 * (2 * 192 + 2 * 128))
     assert bwd == (1664 * pairs, B * H * S * 2 * (4 * 192 + 4 * 128))
-    assert cost.attention_call_cost("fwd", B, H, S, 160) == fwd
-    low = cost.attention_call_cost("bwd", B, H, S, 160)
+    assert cost.attention_call_cost("fwd", B, causal(H, H, 160), S) == fwd
+    low = cost.attention_call_cost("bwd", B, causal(H, H, 160), S)
     assert low[1] == bwd[1] and low[0] / bwd[0] == pytest.approx(1600 / 1664)
-    seconds, bound = cost_joyai.attention_least_time("bwd", config, B, S,
-                                                     197e12, 819e9)
+    seconds, bound = cost.least_time(*bwd, 197e12, 819e9)
     assert bound == "compute" and seconds == bwd[0] / 197e12
     with pytest.raises(ValueError):
-        cost_joyai.attention_call_cost("both", config, B, S)
+        cost.attention_call_cost("both", B, call, S)
 
 
 @pytest.mark.parametrize("dtype,loss_tol,grad_tol", [

@@ -111,8 +111,9 @@ def test_the_cell_rehearses_with_every_new_metric(tmp_path):
     device plane and come back None without raising."""
     bench = spec.load_benchmark()
     cell = spec.load_cell(bench, CELL)
+    # (later cells appended themselves to two of the lists, PR 43)
     assert {m["name"] for m in cell["metrics"]["per_layer"]
-            if m.get("workloads") == [CELL]} == NEW_METRICS
+            if m.get("workloads", [None])[0] == CELL} == NEW_METRICS
     line = result_line(run(spec.ROOT, "--workload", CELL, "--seed",
                            "3987654321", "--seconds", "1", "--trace", "1",
                            "--rehearse"))

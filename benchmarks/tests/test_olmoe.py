@@ -90,8 +90,8 @@ def chip_run():
         held = json.load(f)
     run = RunRecord(
         cell={"config_file": TINY}, chips=1, peaks=peaks_for("TPU v5 lite"),
-        tokens_per_step=512, flops_per_step=1.0, attention_batch_per_chip=2,
-        seq_len=256, attention_heads=(4, 32), trace=trace,
+        tokens_per_step=512, flops_per_step=1.0, seq_len=256,
+        attention_calls=(), trace=trace,
         steady=tr.steady_window(trace.first.modules,
                                 tr.step_module(trace.first.modules)),
         hlo={"mosaic": held["mosaic"], "collectives": {}})
@@ -134,9 +134,8 @@ def test_readers_return_nothing_without_their_sources():
     """A program without the expert layer (or an untraced run) has nothing
     for these readers: they return None and raise nothing."""
     run = RunRecord(cell={"config_file": {}}, chips=1, peaks=None,
-                    tokens_per_step=1, flops_per_step=1.0,
-                    attention_batch_per_chip=1, seq_len=1,
-                    attention_heads=(1, 1))
+                    tokens_per_step=1, flops_per_step=1.0, seq_len=1,
+                    attention_calls=())
     run.anatomy = None
     for metric in ("step.moe_ms", "step.moe_dispatch_ms",
                    "kernels.experts_ms", "kernels.experts_roofline"):

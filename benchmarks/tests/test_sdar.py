@@ -6,7 +6,7 @@ import jax
 import numpy as np
 import pytest
 
-from benchmarks.lib import cost, cost_sdar, spec
+from benchmarks.lib import cost, cost_sdar, family, spec
 
 #: the model-configs catalog's row SDAR-30B-A3B-Chat, its ``config``
 PUBLISHED = {
@@ -82,13 +82,22 @@ def test_model_flops_per_token(config):
 @pytest.mark.parametrize("kind,matmuls,q_like,kv_like", [("fwd", 2, 2, 2),
                                                          ("bwd", 5, 4, 4)])
 def test_attention_call_cost(config, kind, matmuls, q_like, kv_like):
+    """The one call over a row's 2S x 2S positions (the kind ``whole``)."""
     S = 8192
-    flops, nbytes = cost_sdar.attention_call_cost(kind, config, 1, S)
+    whole, noised, clean = spec.load_module("models", "sdar").build(
+        config, S).attention_calls
+    flops, nbytes = cost.attention_call_cost(kind, 1, whole, S)
     area = S * S + S * 4
     assert flops == matmuls * 2.0 * 32 * area * 128
     assert nbytes == (q_like * 32 + kv_like * 4) * 2 * S * 128 * 2
     # twice a causal call's pairs and a block's width more
-    causal, _ = cost.attention_call_cost(kind, 1, 32, S, 128)
+    causal, _ = cost.attention_call_cost(kind, 1, family.causal(32, 4, 128),
+                                         S)
     assert flops / causal == pytest.approx(2.0, rel=1e-3)
+    # the two-call cover does the same work and reads k and v once more
+    two = [cost.attention_call_cost(kind, 1, call, S)
+           for call in (noised, clean)]
+    assert two[0][0] + two[1][0] == flops
+    assert two[0][1] + two[1][1] == nbytes + kv_like * 4 * S * 128 * 2
     # compute-bound on the v5e, so the roofline share is FLOPs over time
     assert cost.least_time(flops, nbytes, 197e12, 819e9)[1] == "compute"

@@ -129,7 +129,7 @@ def test_the_cell_rehearses_with_every_new_metric(tmp_path):
     back None without raising."""
     bench = spec.load_benchmark()
     cell = spec.load_cell(bench, CELL)
-    assert (cell["chips"], cell["traffic"]) == (1, "packed-s8192-b1")
+    assert (cell["chips"], cell["traffic"]) == (1, "epochs8-s8192-b1")
     assert {m["name"] for m in cell["metrics"]["per_layer"]
             if m.get("workloads") == [CELL]} == NEW_METRICS
     line = result_line(run(spec.ROOT, "--workload", CELL, "--seed",

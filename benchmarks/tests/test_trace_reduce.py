@@ -81,8 +81,7 @@ def record(trace, chips, mosaic=None, collectives=None):
 
     return RunRecord(
         cell={}, chips=chips, peaks=None, tokens_per_step=1024,
-        flops_per_step=1.0, attention_batch_per_chip=1, seq_len=256,
-        attention_heads=(2, 128), trace=trace,
+        flops_per_step=1.0, seq_len=256, attention_calls=(), trace=trace,
         steady=tr.steady_window(trace.first.modules,
                                 tr.step_module(trace.first.modules)),
         hlo={"mosaic": mosaic or {}, "collectives": collectives or {}})

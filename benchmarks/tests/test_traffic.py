@@ -56,3 +56,46 @@ def test_ids_follow_zipf():
     counts = np.bincount(tokens.ravel(), minlength=50257)
     assert counts[0] > counts[1] > counts[3] > counts[20]
     assert tokens.max() < 50257
+
+
+# ------------------------------------------------ a corpus, trained on for epochs
+def test_a_corpus_is_the_same_rows_whatever_the_seed():
+    a, b = make(7, "epochs8-s8192-b1"), make(2251000011, "epochs8-s8192-b1")
+    assert a.dataset_batches == 8
+    for j in range(8):
+        assert np.array_equal(a.batch(j)["tokens"], b.batch(j)["tokens"])
+        assert np.array_equal(a.batch(j)["tokens"],
+                              a.batch(j + 8)["tokens"])  # it repeats
+    distinct = {a.batch(j)["tokens"].tobytes() for j in range(8)}
+    assert len(distinct) == 8
+    # the check rows stay the seed's: no step trains on them
+    assert not np.array_equal(a.check_rows(1), b.check_rows(1))
+    # a stream (no dataset_batches) is the seed's from its first row
+    assert make(7, "packed-s8192-b1").dataset_batches is None
+
+
+def _epoch_orders(seed, epochs):
+    """The order in which the program's ingest hands out the corpus's rows,
+    epoch by epoch, as kinds/train.py's loop asks for them."""
+    from ray_tpu.data.ingest import StreamingIngest
+
+    gen = make(seed, "epochs8-s8192-b1")
+    rows = {gen.batch(j)["tokens"].tobytes(): j for j in range(8)}
+    shard = StreamingIngest(gen.dataset(171), seed=seed).make_shard()
+    return [[rows[np.asarray(b["tokens"]).tobytes()]
+             for b in shard.iter_batches(batch_size=1)]
+            for _ in range(epochs)]
+
+
+def test_every_epoch_is_every_row_once_in_the_seeds_order():
+    import ray_tpu
+
+    ray_tpu.init(num_cpus=2)
+    try:
+        first, again, other = (_epoch_orders(s, 3) for s in (7, 7, 8))
+    finally:
+        ray_tpu.shutdown()
+    assert all(sorted(epoch) == list(range(8)) for epoch in first + other)
+    assert first == again                   # the same seed, the same order
+    assert first != other                   # another seed, another order
+    assert first[0] != first[1]             # and another in every epoch
