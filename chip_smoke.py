@@ -439,10 +439,12 @@ def run_kernel(name: str, kernel_fn, reference_fn, args, tols,
     return row
 
 
-def kernels_phase(attn_shape, ring_shape, ssd_shape, kda_shape) -> list:
+def kernels_phase(attn_shape, ring_shape, ssd_shape, kda_shape,
+                  rope_shape) -> list:
     import jax
     import jax.numpy as jnp
 
+    from ray_tpu.models import layers
     from ray_tpu.ops.attention import causal_attention, splash_attention
     from ray_tpu.ops.kda import kda, kda_xla
     from ray_tpu.ops.ring_attention import ring_attention
@@ -530,6 +532,24 @@ def kernels_phase(attn_shape, ring_shape, ssd_shape, kda_shape) -> list:
         with_grads(lambda *a: kda(*a, chunk)),
         with_grads(lambda *a: kda_xla(*a, chunk)), delta_args,
         (FWD_TOL,) + (GRAD_TOL,) * 5, want_mosaic=4))
+
+    # the rotary pass's kernel over q and k in one call (``rope`` takes it
+    # at this shape), values and gradients, against the product an array
+    b, S, heads, hd, rotary = rope_shape
+
+    def turned(turn):
+        def run(*xs):
+            out, vjp = jax.vjp(lambda *xs: turn(xs), *xs)
+            return (*out, *vjp(out))
+        return run
+
+    rows.append(run_kernel(
+        f"rope fwd+grad {rope_shape}",
+        turned(lambda xs: layers.rope(xs, 5e5, rotary, first=True)),
+        turned(lambda xs: tuple(
+            layers._rope_product(x, 5e5, rotary, False, True) for x in xs)),
+        [normal(20 + i, (b, S, H, hd)) for i, H in enumerate(heads)],
+        (FWD_TOL,) * 2 + (GRAD_TOL,) * 2, want_mosaic=2))
     return rows
 
 
@@ -579,7 +599,10 @@ def main() -> int:
             ssd_shape=(2, 8192, 64, 64, 8, 128, 128),
             # a KDA layer's scan in ``solar-open2-ep40-tp8``: rows,
             # positions, heads, head_dim, chunk
-            kda_shape=(1, 8192, 8, 128, 64))
+            kda_shape=(1, 8192, 8, 128, 64),
+            # a full layer's q and k in ``laguna-ep32-s8192``: rows,
+            # positions, the two arrays' heads, head_dim, the lanes that turn
+            rope_shape=(1, 8192, (48, 8), 128, 64))
         check_kernels_on_chip(kernels)
     finally:
         ray_tpu.shutdown()

@@ -27,10 +27,12 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models import moe as _moe
-from ray_tpu.ops import grad_ring, remat
+from ray_tpu.ops import grad_ring, remat, rope_kernel
 from ray_tpu.ops.attention import (block_diffusion_attention,
-                                   causal_attention, window_attention)
+                                   causal_attention, scales_q,
+                                   window_attention)
 from ray_tpu.parallel.mesh import DEFAULT_RULES
+from ray_tpu.util import first_call
 
 
 def mesh_axes(logical):
@@ -92,33 +94,24 @@ class Yarn:
         return tuple(float(f) for f in inter * ramp + extra * (1 - ramp))
 
 
-def _rope_pass(x, theta: float, direction: float, rotary=None,
-               interleave: bool = False, first: bool = False,
-               inv_freq=None, scale: float = 1.0):
-    """x * cos + partner(x) * (-sin | +sin) over (B, S, H, hd), cos and sin
-    from float32 angles, products and sum in float32, one rounding to x's
-    dtype.  ``direction`` 1.0 rotates each pair by its position's angle,
-    -1.0 back.  The pairing is rotate-half, lanes (i, i + hd/2), as the
-    Llama family publishes it, or with ``interleave`` lanes (2i, 2i + 1), as
-    the ``deepseek_v3`` family does (``rope_interleave``).  With ``rotary``
-    only the head's last ``rotary`` lanes rotate, over frequencies of their
-    own (a latent-attention head: 128 lanes without position, then 64 with),
-    or with ``first`` its first (``partial_rotary_factor``, as
-    ``transformers`` cuts a head); the other lanes pass at an angle of zero.
-    ``inv_freq``: the rotary part's inverse frequencies a pair, as a tuple,
-    in the place of ``theta ** (-2i / rotary)`` (:class:`Yarn`'s); ``scale``
-    multiplies cos and sin on the lanes that rotate and no other.
-
-    Everything stays hd wide so that the compiler makes it one pass, x read
-    once and the result written once in x's dtype.  Slicing the two halves
-    (or ``jnp.roll``, which is two slices) gave arrays of 64 lanes padded to
-    128 and a float32 copy of x in HBM: three passes forward and three
-    backward, 0.6 and 0.95 GB a layer for Mistral's q at 8192 tokens where
-    this needs 0.2 and 0.13 (PERF.md, PR 27).  The partners are swapped by a
-    product with a 0/1 permutation instead: each output is one input times
-    one, so it is exact, and cos, sin and the cast fuse into its output.
-    """
-    hd = x.shape[-1]
+def _rope_tables(positions: int, hd: int, theta: float, direction: float,
+                 rotary=None, interleave: bool = False, first: bool = False,
+                 inv_freq=None, scale: float = 1.0):
+    """-> (cos, sin, lane, partner): the float32 tables of a rotary pass over
+    ``positions`` positions of ``hd`` lanes, (1, positions, 1, hd) each, the
+    sine signed for the pair's two lanes and by ``direction`` (1.0 rotates
+    each pair by its position's angle, -1.0 back); the lanes' indices and the
+    function from a lane to its partner's.  The pairing is rotate-half, lanes
+    (i, i + hd/2), as the Llama family publishes it, or with ``interleave``
+    lanes (2i, 2i + 1), as the ``deepseek_v3`` family does
+    (``rope_interleave``).  With ``rotary`` only the head's last ``rotary``
+    lanes rotate, over frequencies of their own (a latent-attention head: 128
+    lanes without position, then 64 with), or with ``first`` its first
+    (``partial_rotary_factor``, as ``transformers`` cuts a head); the other
+    lanes pass at an angle of zero (cos 1, sin 0).  ``inv_freq``: the rotary
+    part's inverse frequencies a pair, as a tuple, in the place of ``theta **
+    (-2i / rotary)`` (:class:`Yarn`'s); ``scale`` multiplies cos and sin on
+    the lanes that rotate and no other."""
     rot = hd if rotary is None else rotary
     half = rot // 2
     before = 0 if first else hd - rot  # the lanes ahead of the rotary part
@@ -137,7 +130,7 @@ def _rope_pass(x, theta: float, direction: float, rotary=None,
     if rot != hd:
         inside = at < rot if first else at >= 0
         freqs = jnp.where(inside, freqs, 0.0)
-    angles = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * freqs[None, :]
+    angles = jnp.arange(positions, dtype=jnp.float32)[:, None] * freqs[None, :]
     first_of_pair = at % 2 == 0 if interleave else at < half
     sign = jnp.where(first_of_pair, -direction, direction)
 
@@ -156,6 +149,31 @@ def _rope_pass(x, theta: float, direction: float, rotary=None,
         # an angle of zero is cos 1, sin 0: only cos needs telling apart
         cos = cos * scale if rot == hd else jnp.where(inside, cos * scale, cos)
         sin = sin * scale
+    return cos, sin, lane, partner
+
+
+def _rope_pass(x, theta: float, direction: float, *how):
+    """x * cos + partner(x) * sin over (B, S, H, hd) by :func:`_rope_tables`'
+    tables (``how``: its arguments from ``rotary`` on), products and sum in
+    float32, one rounding to x's dtype: the form for the shapes and places
+    ``ops/rope_kernel.py`` does not take.
+
+    Everything stays hd wide so that the compiler makes it one pass, x read
+    once and the result written once in x's dtype.  Slicing the two halves
+    (or ``jnp.roll``, which is two slices) gave arrays of 64 lanes padded to
+    128 and a float32 copy of x in HBM: three passes forward and three
+    backward, 0.6 and 0.95 GB a layer for Mistral's q at 8192 tokens where
+    this needs 0.2 and 0.13 (PERF.md, PR 27).  The partners are swapped by a
+    product with a 0/1 permutation instead: each output is one input times
+    one, so it is exact, and cos, sin and the cast fuse into its output.
+    What that costs (PERF.md, PR 53: the pass alone at 72 + 8 heads of 8192
+    positions): 3.56 ms forward, 8.7 times its bytes' time, and 1.69
+    backward; HIGHEST costs nothing where x is bf16 (3.563 without: one pass
+    of the matrix unit either way); the forward's two layout copies of x
+    inside the fusion about half, the K = N = 128 product the rest.
+    """
+    cos, sin, lane, partner = _rope_tables(x.shape[1], x.shape[-1], theta,
+                                           direction, *how)
     swap = (lane[:, None] == partner(lane[None, :])).astype(x.dtype)
     swapped = jnp.einsum("bshd,de->bshe", x, swap,
                          preferred_element_type=jnp.float32,
@@ -164,16 +182,12 @@ def _rope_pass(x, theta: float, direction: float, rotary=None,
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6))
-def rope(x, theta: float, rotary=None, interleave: bool = False,
-         first: bool = False, inv_freq=None, scale: float = 1.0):
-    """Rotary position embedding over (B, S, H, hd), in and out in x's
-    dtype: rotate-half form over the whole head by default, over the head's
-    last (or, with ``first``, first) ``rotary`` lanes, in the
-    ``interleave``d pairing, over a table of inverse frequencies and with
-    cos and sin scaled where asked (:func:`_rope_pass`).  The backward is
-    the same pass with the sine negated (a rotation's transpose is the
-    rotation back, and a scale stays a scale), not autodiff's sum over
-    sliced halves."""
+def _rope_product(x, theta: float, rotary=None, interleave: bool = False,
+                  first: bool = False, inv_freq=None, scale: float = 1.0):
+    """:func:`rope` of one array by :func:`_rope_pass`.  The backward is the
+    same pass with the sine negated (a rotation's transpose is the rotation
+    back, and a scale stays a scale), not autodiff's sum over sliced
+    halves."""
     return _rope_pass(x, theta, 1.0, rotary, interleave, first, inv_freq,
                       scale)
 
@@ -187,7 +201,98 @@ def _rope_bwd(*how_and_g):
     return (_rope_pass(g, how[0], -1.0, *how[1:]),)
 
 
-rope.defvjp(_rope_fwd, _rope_bwd)
+_rope_product.defvjp(_rope_fwd, _rope_bwd)
+
+
+def _rope_rolled(xs, direction: float, theta: float, rotary, first: bool,
+                 inv_freq, scale: float, copies: int, scales):
+    """``xs`` rotated, and scaled where ``scales`` has a factor, by one call
+    of ``ops/rope_kernel.py`` over :func:`_rope_tables`' tables of a copy's
+    positions."""
+    S, hd = xs[0].shape[1], xs[0].shape[-1]
+    cos, sin, _, _ = _rope_tables(S // copies, hd, theta, direction, rotary,
+                                  False, first, inv_freq, scale)
+    rot = hd if rotary is None else rotary
+    return rope_kernel.rotate(
+        cos.reshape(-1, hd), sin.reshape(-1, hd), xs, half=rot // 2,
+        before=0 if first else hd - rot, backward=direction < 0,
+        scales=scales)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6, 7))
+def _rope_by_kernel(xs, theta: float, rotary, first: bool, inv_freq,
+                    scale: float, copies: int, scales):
+    """:func:`rope` of a tuple of arrays by the kernel; the backward is the
+    kernel with the sine negated, and nothing is saved."""
+    return _rope_rolled(xs, 1.0, theta, rotary, first, inv_freq, scale,
+                        copies, scales)
+
+
+def _rope_by_kernel_fwd(xs, *how):
+    return _rope_rolled(xs, 1.0, *how), None
+
+
+def _rope_by_kernel_bwd(*how_and_gs):
+    *how, _, gs = how_and_gs
+    return (_rope_rolled(tuple(gs), -1.0, *how),)
+
+
+_rope_by_kernel.defvjp(_rope_by_kernel_fwd, _rope_by_kernel_bwd)
+
+
+def _rope_takes_kernel(shapes, interleave: bool, copies: int) -> bool:
+    """Whether a rotary call over arrays of these (B, S, H, hd) shapes is
+    the kernel's, here and now (``ops/rope_kernel.py:path``)."""
+    return rope_kernel.path(shapes, interleave, copies,
+                            jax.sharding.get_abstract_mesh()) == "kernel"
+
+
+def rope(x, theta: float, rotary=None, interleave: bool = False,
+         first: bool = False, inv_freq=None, scale: float = 1.0,
+         copies: int = 1, hd: Optional[int] = None, scales=None):
+    """Rotary position embedding over (B, S, H, hd), in and out in x's
+    dtype: rotate-half form over the whole head by default, over the head's
+    last (or, with ``first``, first) ``rotary`` lanes, in the
+    ``interleave``d pairing, over a table of inverse frequencies and with
+    cos and sin scaled where asked (:func:`_rope_tables`).  ``x`` may be a
+    tuple of arrays that share B, S, hd and a dtype (a layer's q and k) and
+    comes back as one; with ``hd`` an array may come as a projection writes
+    it, (B, S, H x hd), and goes back (B, S, H, hd).  A row of ``copies``
+    copies back to back restarts its positions at each.  ``scales``: a
+    factor an array of ``x`` (None: none) that multiplies the rotated array
+    in its dtype, ``rope(a) * factor``: the scale an attention kernel wants
+    on q (``ops.attention.scales_q``), which the kernel form applies on its
+    way out and XLA fuses into the product form.
+
+    One algorithm in two forms, chosen by what the call can observe
+    (``ops/rope_kernel.py:path``: the lane width, the pairing, the backend
+    and the mesh), with no argument, configuration field or environment
+    variable to pick one: a Mosaic kernel that makes the partner lanes by a
+    lane roll, one call for all of ``x``, or :func:`_rope_pass`'s product an
+    array.  The first-call record says which ran (``rope_kernel``) and how
+    many calls a step traces (``rope_calls``)."""
+    xs = x if isinstance(x, tuple) else (x,)
+    B, S = xs[0].shape[:2]
+    hd = hd or xs[0].shape[-1]
+    shapes = [(B, S, a.size // (B * S * hd), hd) for a in xs]
+    kernel = _rope_takes_kernel(shapes, interleave, copies)
+    first_call.note(rope_kernel=kernel)
+    first_call.count("rope_calls")
+    if kernel:
+        out = _rope_by_kernel(
+            tuple(a.reshape(s) for a, s in zip(xs, shapes)), theta, rotary,
+            first, inv_freq, scale, copies, scales and tuple(scales))
+    else:
+        # an array at a time, a copy a row of its own (in this order: the
+        # older models' lowered steps are pinned by their text)
+        out = tuple(_rope_product(
+            a.reshape(B * copies, S // copies, *s[2:]), theta, rotary,
+            interleave, first, inv_freq, scale).reshape(s)
+            for a, s in zip(xs, shapes))
+        if scales is not None:
+            out = tuple(a if factor is None else a * factor
+                        for a, factor in zip(out, scales))
+    return out if isinstance(x, tuple) else out[0]
 
 
 def dense(a, blk, name: str, axes, dtype):
@@ -223,6 +328,15 @@ def attention(x, blk, config, axes):
     rotary, yarn = (getattr(config, name, None)
                     for name in ("rope_rotary", "rope_yarn"))
     turn = partial(rope, theta=config.rope_theta)
+    copies = 2 if config.block_length else 1
+    # Where the attention would scale q itself before its kernel and the
+    # rotary pass is a kernel too, that one scales q on its way out: between
+    # two Mosaic calls the scale is a pass of its own over q.  (Elsewhere
+    # the trace stays as it was: XLA fuses the scale into the product.)
+    scaled = (config.rope_theta is not None and scales_q(config.attn_impl)
+              and _rope_takes_kernel([(B, S, H, hd), (B, S, KV, hd)], False,
+                                     copies))
+    sm_scale = 1.0 if scaled else None
     if rotary is not None or yarn is not None:
         turn = partial(
             turn, rotary=rotary, first=True, scale=yarn.scale if yarn else 1.0,
@@ -242,22 +356,20 @@ def attention(x, blk, config, axes):
             q, k = q.reshape(B, S, H, hd), k.reshape(B, S, KV, hd)
         else:
             # A block-diffusion row is two copies that share positions: each
-            # rotates as a row of its own, so a position is its axis index.
-            copies = 2 if config.block_length else 1
-            q = turn(q.reshape(B * copies, S // copies, H, hd)
-                     ).reshape(B, S, H, hd)
-            k = turn(k.reshape(B * copies, S // copies, KV, hd)
-                     ).reshape(B, S, KV, hd)
+            # rotates as a row of its own.
+            q, k = turn((q, k), hd=hd, copies=copies,
+                        scales=(hd ** -0.5, None) if scaled else None)
         q, k, v = (checkpoint_name(a, remat.QKV) for a in (q, k, v))
         # GQA: k and v go in at KV heads; the splash kernel takes them so,
         # and the dispatcher repeats them for the paths that cannot.
         if config.block_length:
             attn = block_diffusion_attention(q, k, v, config.block_length,
-                                             config.attn_impl)
+                                             config.attn_impl, sm_scale)
         elif window:
-            attn = window_attention(q, k, v, window, config.attn_impl)
+            attn = window_attention(q, k, v, window, config.attn_impl,
+                                    sm_scale)
         else:
-            attn = causal_attention(q, k, v, config.attn_impl)
+            attn = causal_attention(q, k, v, config.attn_impl, sm_scale)
         attn = attn.astype(dt).reshape(B, S, H * hd)
         if "wg" in blk:
             gate = jax.nn.sigmoid(

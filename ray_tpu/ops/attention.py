@@ -98,15 +98,28 @@ save_splash_residuals = jax.checkpoint_policies.save_only_these_names(
     SPLASH_RESIDUALS)
 
 
-def causal_attention(q, k, v, impl: str):
+def causal_attention(q, k, v, impl: str, sm_scale: Optional[float] = None):
     """Causal attention by the implementation ``impl`` names (a model's
     ``attn_impl``; see the module docstring).  q: (B, S, H, head_dim); k, v:
     (B, S, KV, head_dim), H a multiple of KV, v's head dimension its own
-    where it differs.  -> (B, S, H, v's head_dim)."""
-    return _attention(q, k, v, impl, 0)
+    where it differs.  -> (B, S, H, v's head_dim).  ``sm_scale``: what the
+    scores are multiplied by (None: ``head_dim ** -0.5``; 1.0 where q comes
+    scaled, :func:`scales_q`)."""
+    return _attention(q, k, v, impl, 0, sm_scale=sm_scale)
 
 
-def block_diffusion_attention(q, k, v, block_length: int, impl: str):
+def scales_q(impl: str) -> bool:
+    """Whether ``impl`` multiplies q itself by the scale before its kernel,
+    in q's dtype (the splash call: the library takes no scale).  After a
+    Mosaic call that product is a pass of its own over q, so a caller whose
+    q comes out of one (``models/layers.py``: the rotary kernel) has that
+    kernel scale it and passes ``sm_scale=1.0`` here."""
+    return impl == "splash" or (impl == "auto"
+                                and jax.default_backend() == "tpu")
+
+
+def block_diffusion_attention(q, k, v, block_length: int, impl: str,
+                              sm_scale: Optional[float] = None):
     """Attention over the noised and the clean copy of a row under
     :func:`block_diffusion_allowed`.  q: (B, 2S, H, head_dim); k, v:
     (B, 2S, KV, head_dim), the noised copy's S positions first.
@@ -115,16 +128,17 @@ def block_diffusion_attention(q, k, v, block_length: int, impl: str):
         raise ValueError(
             f"block_diffusion_attention: block_length {block_length} must "
             f"divide the row's {q.shape[1] // 2} positions")
-    return _attention(q, k, v, impl, block_length)
+    return _attention(q, k, v, impl, block_length, sm_scale=sm_scale)
 
 
-def window_attention(q, k, v, window: int, impl: str):
+def window_attention(q, k, v, window: int, impl: str,
+                     sm_scale: Optional[float] = None):
     """Causal attention over a band: query i reads the ``window`` keys up to
     and including its own (:func:`window_allowed`; the ``transformers``
     sliding-window convention).  Shapes as :func:`causal_attention`."""
     if window < 1:
         raise ValueError(f"window_attention: a window of {window} keys")
-    return _attention(q, k, v, impl, 0, window)
+    return _attention(q, k, v, impl, 0, window, sm_scale)
 
 
 def window_allowed(i, j, window: int):
@@ -133,7 +147,8 @@ def window_allowed(i, j, window: int):
     return (j <= i) & (j > i - window)
 
 
-def _attention(q, k, v, impl: str, block_length: int, window: int = 0):
+def _attention(q, k, v, impl: str, block_length: int, window: int = 0,
+               sm_scale: Optional[float] = None):
     """The dispatcher.  ``block_length`` 0: causal over S positions, with a
     ``window`` the causal band of that many keys; else the block-diffusion
     mask over 2S."""
@@ -145,9 +160,8 @@ def _attention(q, k, v, impl: str, block_length: int, window: int = 0):
             f"attn_impl {impl!r} is causal only: a block-diffusion row or a "
             "window runs under auto|splash|xla")
     with jax.named_scope("attn_kernel"):
-        if impl == "splash" or (impl == "auto"
-                                and jax.default_backend() == "tpu"):
-            return splash_attention(q, k, v, causal=True,
+        if scales_q(impl):
+            return splash_attention(q, k, v, causal=True, sm_scale=sm_scale,
                                     block_length=block_length, window=window)
         if k.shape[2] != q.shape[2]:
             # Each K/V head serves a group of consecutive query heads.
@@ -157,12 +171,14 @@ def _attention(q, k, v, impl: str, block_length: int, window: int = 0):
         if impl == "ring":
             from ray_tpu.ops.ring_attention import ring_attention
 
-            return ring_attention(q, k, v, causal=True)
+            return ring_attention(q, k, v, causal=True, sm_scale=sm_scale)
         if impl == "ulysses":
             from ray_tpu.ops.ring_attention import ulysses_attention
 
-            return ulysses_attention(q, k, v, causal=True)
-        scale = 1.0 / math.sqrt(q.shape[-1])
+            return ulysses_attention(q, k, v, causal=True, sm_scale=sm_scale)
+        scale = sm_scale
+        if scale is None:
+            scale = 1.0 / math.sqrt(q.shape[-1])
         scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) \
             * scale
         S = q.shape[1]
@@ -464,8 +480,9 @@ def splash_attention(q, k, v, causal: bool = True,
         kernel, counts = _splash_kernel(S, q.shape[2], hd, causal,
                                         block_length, window=window)
         first_call.note(**counts)
-        # Splash takes (H, S, hd) per example; scale q up front (no scale arg).
-        qt = (q * sm_scale).transpose(0, 2, 1, 3)
+        # Splash takes (H, S, hd) per example; scale q up front (no scale
+        # arg), where the caller has not.
+        qt = (q if sm_scale == 1.0 else q * sm_scale).transpose(0, 2, 1, 3)
         kt = k.transpose(0, 2, 1, 3)
         vt = v.transpose(0, 2, 1, 3)
         return jax.vmap(kernel)(qt, kt, vt).transpose(0, 2, 1, 3)

@@ -49,6 +49,12 @@ and ``seconds``, the span ``compile_s``):
                               the XLA form), ``ssm_scan_grid`` (the
                               kernels' grid a chip, or None)
 ``ops/kda.py``                ``kda_scan_kernel``, ``kda_scan_grid``
+``models/layers.py``          where a layer rotates (``rope``):
+                              ``rope_kernel`` (the lane roll of
+                              ``ops/rope_kernel.py``, or the product with a
+                              permutation), ``rope_calls`` (the calls
+                              traced: a layer's q and k are one, a scanned
+                              layer's once)
 ``models/llama.py``           ``experts_held``, ``experts_total``,
                               ``block_length``, ``attn_positions``,
                               ``loss_positions``

@@ -364,3 +364,56 @@ def test_the_grouped_products_compile_for_the_v5e(monkeypatch, one_chip):
                 assert text.count("tpu_custom_call") == 3, (cell, K, N)
     finally:
         jax.config.update("jax_enable_compilation_cache", cache)
+
+
+def test_the_rotary_kernel_compiles_for_the_v5e(monkeypatch, one_chip):
+    """The rotary pass's kernel (``ops/rope_kernel.py``) over q and k at the
+    shapes the cells hand it (Laguna's window layers at 72 + 8 heads and its
+    full layers' 64 of 128 lanes under a YaRN table, Mistral's rows of 8192
+    and of 1024, the block-diffusion row's two copies; heads of 256 lanes,
+    which no cell has), forward and backward under a ``jax.checkpoint``,
+    compiled for a described v5e: two Mosaic calls each, q and k in one (the
+    forward and the backward: the pass saves nothing, so alone it is not run
+    a second time), none refused for the scoped VMEM the rule's blocks must
+    fit, every one under the caller's scope, and no
+    product with a permutation beside them.  In this file: one worker loads
+    the TPU's compiler."""
+    from ray_tpu.models import layers
+    from ray_tpu.parallel.train_state import classify_op_name
+
+    yarn = layers.Yarn(factor=32.0, original=4096)
+    shapes = {
+        "laguna-window": (1, 8192, (72, 8), 128, {}),
+        "laguna-full": (1, 8192, (48, 8), 128, dict(
+            rotary=64, first=True, scale=yarn.scale,
+            inv_freq=yarn.inv_freq(64, 5e5))),
+        "mistral7b-s8192": (1, 8192, (32, 8), 128, {}),
+        "mistral7b-s1024": (8, 1024, (32, 8), 128, {}),
+        "sdar-ep8-s8192": (1, 16384, (32, 4), 128, dict(copies=2)),
+        "heads-of-256": (1, 4096, (16, 4), 256, dict(rotary=128)),
+    }
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        for name, (B, S, heads, hd, how) in shapes.items():
+            def turn(*xs):
+                with jax.named_scope("attn"):  # as layers.attention
+                    return layers.rope(xs, 5e5, hd=hd, **how)
+
+            def both(*xs):
+                out, pull = jax.vjp(jax.checkpoint(turn), *xs)
+                return pull(out)
+
+            xs = [jax.ShapeDtypeStruct((B, S, H * hd), jnp.bfloat16,
+                                       sharding=one_chip) for H in heads]
+            text = jax.jit(both).lower(*xs).compile().as_text()
+            calls = [line for line in text.splitlines()
+                     if "tpu_custom_call" in line]
+            assert len(calls) == 2, name
+            assert all(classify_op_name(
+                line.split('op_name="')[1].split('"')[0])[1] == "attn"
+                for line in calls), name
+            assert "convolution" not in text, name
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)

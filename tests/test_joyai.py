@@ -397,6 +397,9 @@ def test_num_params_flops_and_the_first_call_record():
         "mtp_depth": 1, "mtp_weight": 0.3, "experts_held": 4,
         "experts_total": 16, "router_scoring": "sigmoid",
         "attn_positions": 128, "loss_positions": 128,
+        # q's and the rotary key's pass of each of the four latent layers
+        # (the module's among them), by the product: 24 and 8 lanes (PR 53)
+        "rope_kernel": False, "rope_calls": 8,
         "remat_kept": [], "remat_kept_bytes": 0, "remat_room_bytes": None,
         # the pattern's two expert layers' routing and the module's one's
         "remat_routing_bytes": 3 * moe.routing_bytes(256, 16, 2),

@@ -430,6 +430,9 @@ def test_num_params_flops_and_the_first_call_record():
         "rope_yarn_factor": 4.0, "attn_window": 16, "window_heads": 6,
         "dense_width": 256, "experts_held": 4, "experts_total": 16,
         "router_scoring": "sigmoid", "loss_positions": 128,
+        # q and k of each of the five attention layers in one call, by the
+        # product: heads of 32 lanes (PR 53)
+        "rope_kernel": False, "rope_calls": 5,
         "remat_kept": [], "remat_kept_bytes": 0, "remat_room_bytes": None,
         "remat_routing_bytes": 4 * moe.routing_bytes(256, 16, 2),
         "gmm_tiles": {"64x128x48": (64, 128, 48), "64x48x128": (64, 48, 128)}}

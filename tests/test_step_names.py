@@ -519,8 +519,10 @@ def test_ops_import_nothing_above_them():
 _REMAT = {"remat_kept", "remat_kept_bytes", "remat_room_bytes",
           "remat_routing_bytes"}  # the last since PR 48
 _STEP = {"remat_fallback", "grad_ring_products", "grad_ring_axis"}
-_LLAMA = _STEP | _REMAT | {"experts_held", "experts_total", "block_length",
-                           "attn_positions", "loss_positions"}
+_ROPE = {"rope_kernel", "rope_calls"}  # where a preset rotates, since PR 53
+_LLAMA = _STEP | _REMAT | _ROPE | {
+    "experts_held", "experts_total", "block_length", "attn_positions",
+    "loss_positions"}
 _HYBRID = _STEP | _REMAT | {
     "layer_kinds", "loss_positions", "attn_positions", "heads_held",
     "heads_total", "attn_gate", "experts_held", "experts_total",
@@ -539,7 +541,7 @@ FIRST_CALL_KEYS = {
     "tiny-solar-open2": _HYBRID | {
         "kda_heads", "kda_head_dim", "kda_chunk", "kda_chunks",
         "kda_scan_kernel", "kda_scan_grid"},
-    "tiny-laguna": _HYBRID | {
+    "tiny-laguna": _HYBRID | _ROPE | {
         "dense_width", "rope_rotary_lanes", "rope_yarn_factor",
         "attn_window", "window_heads"},
 }
