@@ -12,6 +12,7 @@ so a Ray user can switch with minimal edits.
 from __future__ import annotations
 
 import inspect
+import time
 from typing import Any, Dict, Optional, Sequence, Union
 
 from ray_tpu import exceptions
@@ -23,6 +24,11 @@ from ray_tpu.actor import ActorClass, ActorHandle, exit_actor
 from ray_tpu.remote_function import RemoteFunction
 
 __version__ = "0.1.0"
+
+#: ``time.time()`` when the package had been imported: where the set-up's
+#: account (``util.device_telemetry.setup_account``) starts if the kernel
+#: does not say when the process did.
+IMPORTED_AT = time.time()
 
 __all__ = [
     "init", "shutdown", "is_initialized", "remote", "get", "put", "wait",
@@ -62,18 +68,21 @@ def init(
         if ignore_reinit_error:
             return _rt.get_runtime()
         raise RuntimeError("ray_tpu.init() called twice; pass ignore_reinit_error=True")
-    if address and address.startswith("ray://"):
-        from ray_tpu.util.client import connect
+    from ray_tpu.util import device_telemetry
 
-        return connect(address)
-    return _rt.init_runtime(
-        num_cpus=num_cpus,
-        num_tpus=num_tpus,
-        resources=resources,
-        labels=labels,
-        namespace=namespace,
-        _system_config=_system_config,
-    )
+    with device_telemetry.setup_span("runtime.init"):
+        if address and address.startswith("ray://"):
+            from ray_tpu.util.client import connect
+
+            return connect(address)
+        return _rt.init_runtime(
+            num_cpus=num_cpus,
+            num_tpus=num_tpus,
+            resources=resources,
+            labels=labels,
+            namespace=namespace,
+            _system_config=_system_config,
+        )
 
 
 def shutdown() -> None:

@@ -37,7 +37,7 @@ from ray_tpu.data.ingest import metrics as ingest_metrics
 from ray_tpu.data.ingest.prefetch import DeviceBatchIterator, HostPrefetcher
 from ray_tpu.data.ingest.shuffle import epoch_rng, window_shuffle
 from ray_tpu.train.elastic import PROVISIONAL_STEP, SampleLedger
-from ray_tpu.util import tracing
+from ray_tpu.util import device_telemetry, tracing
 
 #: Live StreamingIngest instances (weak — an abandoned ingest must not be
 #: kept alive by the registry).  The cluster autoscaler's signal collector
@@ -485,6 +485,24 @@ class StreamingIngest:
             resident.release()
 
 
+def _first_batch_timed(batches: Iterator[Dict[str, Any]]
+                       ) -> Iterator[Dict[str, Any]]:
+    """``batches``, the first pull of it under the span
+    ``train.first_batch`` (attribute ``bytes``, the batch's): the ingest's
+    start-up, from the consumer's first ``next()`` to the first batch in
+    its hands, on the device where a ``device_sharding`` was given.  A row
+    of the set-up's account (``device_telemetry.setup_account``)."""
+    with device_telemetry.setup_span("train.first_batch") as attributes:
+        first = next(batches, None)
+        attributes["bytes"] = device_telemetry.tree_nbytes(first)
+    try:
+        if first is not None:
+            yield first
+            yield from batches
+    finally:
+        batches.close()  # the epoch's own teardown, wherever this one ends
+
+
 class IngestShard:
     """A worker's view of a shared :class:`StreamingIngest` — what
     ``train.get_dataset_shard()`` returns on the streaming path.  Like
@@ -503,9 +521,10 @@ class IngestShard:
                      device_sharding=None) -> Iterator[Dict[str, Any]]:
         epoch = self._epoch
         self._epoch += 1
-        return self._ingest._iter_epoch(
+        batches = self._ingest._iter_epoch(
             epoch, self._session, batch_size, batch_format,
             prefetch_batches, device_sharding)
+        return _first_batch_timed(batches) if epoch == 0 else batches
 
     def iter_rows(self) -> Iterator[Dict[str, Any]]:
         for batch in self.iter_batches(batch_size=None):

@@ -10,7 +10,8 @@ an operator's dashboard:
   scanning the whole package, every registered point must be consulted
   somewhere: a dead registry row is a lie about coverage);
 * span names — ``tracing.span("x")`` / ``annotate("x")`` /
-  ``record_span[_batch]("x")`` must name a key of
+  ``record_span[_batch]("x")`` / ``device_telemetry.setup_span("x")`` /
+  ``record_setup_span("x")`` must name a key of
   ``tracing.SPAN_REGISTRY``; dynamic f-string names must start with a
   registered prefix entry (``...::`` or trailing-``_`` families like
   ``serve.ttft_``);
@@ -50,7 +51,10 @@ METRIC_CTORS = ("Counter", "Gauge", "Histogram")
 #: the metric library itself declares no metrics; skip it and the analyzer
 _METRIC_EXEMPT = ("ray_tpu/util/metrics.py", "ray_tpu/devtools/")
 _FAULT_RECEIVERS = ("fault_injection", "injector", "inj")
-_SPAN_FUNCS = ("span", "annotate", "record_span", "record_span_batch")
+_SPAN_FUNCS = ("span", "annotate", "record_span", "record_span_batch",
+               # device_telemetry's: the same spans, also rows of the
+               # set-up's account
+               "setup_span", "record_setup_span")
 
 
 def _first_arg_str(call: ast.Call) -> Optional[str]:

@@ -59,13 +59,13 @@ def create_sharded_state(
     shardings = pytree_sharding(logical, mesh, rules)
     device_telemetry.listen_for_compiles()
     with jax.set_mesh(mesh):
-        with tracing.span("train.init_params"), \
-                device_telemetry.compile_label("init_params"):
+        with device_telemetry.setup_span("train.init_params") as span, \
+                _first_call("init_params", span):
             params = jax.jit(init_fn, out_shardings=shardings)(key)
         opt_state = None
         if optimizer is not None:
-            with tracing.span("train.init_opt_state"), \
-                    device_telemetry.compile_label("init_opt_state"):
+            with device_telemetry.setup_span("train.init_opt_state") as span, \
+                    _first_call("init_opt_state", span):
                 # On one device there is nothing to say, and the executable
                 # stays the one it was.
                 init = jax.jit(optimizer.init) if mesh.size == 1 else \
@@ -73,6 +73,22 @@ def create_sharded_state(
                         optimizer, params, shardings, mesh))
                 opt_state = init(params)
     return params, opt_state
+
+
+@contextlib.contextmanager
+def _first_call(label: str, attributes: Dict[str, Any]):
+    """The one call of a program of the state's initialisation, under the
+    compile label ``label``: where its seconds went
+    (``compile_label.phases``) goes onto the span around it, through
+    ``attributes``, and where the call compiled, into a first-call record."""
+    t0 = time.perf_counter()
+    with device_telemetry.compile_label(label) as built:
+        yield
+    seconds = time.perf_counter() - t0
+    phases = built.phases(seconds)
+    attributes.update(label=label, **phases)
+    if built.compiles:
+        device_telemetry.record_first_call(label, seconds, **phases)
 
 
 def _state_shardings(optimizer, params, shardings, mesh):
@@ -242,9 +258,12 @@ class TrainStep:
     The signature is taken only when a compile event fired, before the
     arguments are donated; the first one is kept for :meth:`anatomy`.
     A call that compiled is a ``train.first_call`` span and a first-call
-    record of the registry; both carry what the traced code said of itself
+    record of the registry; both carry where its seconds went (``trace_s``,
+    ``lower_s``, ``compile_s``, ``other_s``, ``cache_load_s``, ``cache``:
+    ``compile_label.phases``), what the traced code said of itself
     while this call traced it (``util/first_call.py`` lists the keys and who
-    notes each) and ``remat_fallback``.
+    notes each) and ``remat_fallback``.  The first call that compiled
+    nothing closes the set-up's account (``device_telemetry.setup_account``).
 
     A step that was traced in this call, keeps more than the plain policy
     would and is refused for memory (``RESOURCE_EXHAUSTED``, at compile or
@@ -272,6 +291,8 @@ class TrainStep:
         self._abstract: Optional[Tuple[Any, Any]] = None
         self._anatomy: Optional[Dict[str, Tuple[Optional[str],
                                                 Optional[str]]]] = None
+        #: a call of this step has compiled nothing
+        self._steady = False
         device_telemetry.listen_for_compiles()
         device_telemetry.register_program(self.label, self)
 
@@ -303,13 +324,27 @@ class TrainStep:
             profiler.count("hand_over", time.perf_counter() - t0 - seconds)
         if label.compiles:
             end = time.time()
+            notes.update(label.phases(seconds))
             device_telemetry.record_first_call(self.label, seconds, ts=end,
                                                **notes)
-            tracing.record_span(
+            device_telemetry.record_setup_span(
                 "train.first_call", end - seconds, end,
-                attributes={"label": self.label,
-                            "compile_s": label.compile_s, **notes})
+                {"label": self.label, **notes})
+        elif not self._steady:
+            self._first_steady_call(profiler)
         return out
+
+    def _first_steady_call(self, profiler) -> None:
+        """The first call that compiled nothing closes the set-up's account
+        (``device_telemetry.setup_account``): at the step's end on the
+        device where the calling worker's step profiler waits for it, else
+        at this call's return."""
+        self._steady = True
+        if profiler is None:
+            device_telemetry.close_setup_account(by="host")
+        else:
+            profiler.when_resolved(functools.partial(
+                device_telemetry.close_setup_account, by="device"))
 
     def _hand_over(self, profiler, out) -> None:
         """Give the calling worker's step profiler what tells it when this

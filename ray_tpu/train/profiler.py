@@ -205,6 +205,15 @@ class StepProfiler:
                 name=f"train-step-done:{self.run_name}:{self.rank}")
             self._resolver.start()
 
+    def when_resolved(self, then) -> None:
+        """``then(ts)`` once every sentinel handed over so far has been
+        seen ready, on the resolver thread, ``ts`` being ``time.time()``
+        when the last of them was; at once where none is pending."""
+        if self._resolver is None:
+            then(time.time())
+        else:
+            self._pending.put(then)
+
     def _resolve_loop(self) -> None:
         """The resolver thread: sentinels in dispatch order, each waited
         for (the GIL is released meanwhile), stamped, its counters copied
@@ -213,16 +222,25 @@ class StepProfiler:
         loop's to raise.  Anything else is a fault of this code: logged,
         and the thread goes on, since ``history`` waits for its answers."""
         previous = None
+        done_at = time.time()  # the last sentinel's ``done``, on the wall
         while True:
             item = self._pending.get()
             if item is None:
                 self._pending.task_done()
                 return
+            if callable(item):  # when_resolved's
+                try:
+                    item(done_at)
+                except Exception:  # noqa: BLE001
+                    logging.getLogger(__name__).exception(
+                        "when_resolved: %r failed", item)
+                self._pending.task_done()
+                continue
             step, in_flight, sentinel, counters = item
             try:
                 with tracing.annotate("train.step_done"):
                     sentinel.block_until_ready()
-                done = time.perf_counter()
+                done, done_at = time.perf_counter(), time.time()
                 facts = {"done": done, "in_flight": in_flight,
                          "device_period": None if previous is None
                          else done - previous}
@@ -351,12 +369,6 @@ class StepProfiler:
         tracing.record_span_batch(
             "train.ckpt_block",
             [(s, e, parent) for s, e in iv["ckpt_block"]])
-        if compute > 0.0:
-            # The residual has no measured interval; render it anchored at
-            # the step start so the lane shows its share of the step.
-            tracing.record_span("train.compute", t0, t0 + compute,
-                                parent=parent,
-                                attributes={"residual": True})
 
     # ------------------------------------------------------------- gauges
     def _update_metrics(self, wall: float, totals: Dict[str, float],

@@ -68,6 +68,8 @@ class TrainSession:
         #: train.get_dataset_config() for prefetch/shuffle tuning knobs.
         self.dataset_config = dataset_config
         self.stop_requested = threading.Event()
+        #: set by the worker as it enters the loop function (or fails to)
+        self.loop_entered = threading.Event()
         #: ray_tpu.checkpoint.ShardWriter when async checkpointing is on
         #: (CheckpointConfig.async_save) — report(checkpoint=<pytree>) then
         #: goes through the coordinator's two-phase commit instead of the

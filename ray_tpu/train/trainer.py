@@ -47,7 +47,7 @@ from ray_tpu.train.config import DatasetConfig, RunConfig, ScalingConfig
 from ray_tpu.train.elastic import ElasticDatasetShard, SampleLedger
 from ray_tpu.train.profiler import StepProfiler
 from ray_tpu.train.session import TrainContext, TrainSession, clear_session, init_session
-from ray_tpu.util import flight_recorder, tracing
+from ray_tpu.util import device_telemetry, flight_recorder, tracing
 from ray_tpu.util.placement_group import (
     PlacementGroupSchedulingStrategy,
     placement_group,
@@ -109,8 +109,13 @@ class TrainWorker:
             session: TrainSession) -> str:
         # Chaos: a worker dying right at run entry (the other half of the
         # per-report() consultation in TrainSession.report).
-        fault_injection.check("train_worker_run")
-        init_session(session)
+        try:
+            fault_injection.check("train_worker_run")
+            init_session(session)
+        finally:
+            # The loop function is entered now or never: ``fit()``'s
+            # ``train.fit_setup`` span waits for this.
+            session.loop_entered.set()
         try:
             invoke_train_loop(train_loop, loop_config)
             return "done"
@@ -284,6 +289,18 @@ class DataParallelTrainer:
 
     # ------------------------------------------------------------------ fit
     def fit(self) -> Result:
+        """Run the training; returns a :class:`Result` (a failure is its
+        ``error``).  From here to the moment the first worker enters the
+        loop function (placement group, workers, dataset split, sessions)
+        is the span ``train.fit_setup``, a row of the set-up's account
+        (``device_telemetry.setup_account``); with process-tier workers,
+        whose loop this process cannot see, to their ``run`` calls."""
+        self._fit_setup = device_telemetry.setup_span(
+            "train.fit_setup", {"workers": self.scaling_config.num_workers})
+        with self._fit_setup:  # _run_with_pg ends it early
+            return self._fit()
+
+    def _fit(self) -> Result:
         if not ray_tpu.is_initialized():
             ray_tpu.init()
         run_name = self.run_config.name or f"train_{int(time.time())}"
@@ -534,8 +551,6 @@ class DataParallelTrainer:
             # pool high-water, transfer tail) — best-effort, the registry
             # write must never mask the real exit path.
             try:
-                from ray_tpu.util import device_telemetry
-
                 run_registry.update_run(
                     run_name,
                     device_telemetry=device_telemetry.snapshot())
@@ -823,6 +838,11 @@ class DataParallelTrainer:
             w.run.remote(self.train_loop, self.train_loop_config, s)
             for w, s in zip(workers, sessions)
         ]
+        # fit()'s set-up ends as the first worker enters the loop function
+        # (a worker that never starts must not hold the controller: 2 s)
+        sessions[0].loop_entered.wait(timeout=2.0)
+        self._fit_setup.attributes["worker_mode"] = "threads"
+        self._fit_setup.end()
 
         history: List[Dict[str, Any]] = []
         last_metrics: Optional[Dict[str, Any]] = None
@@ -1058,6 +1078,8 @@ class DataParallelTrainer:
             refs = [w.run.remote(self.train_loop, self.train_loop_config, ctx,
                                  report_queue, blob)
                     for w, ctx in zip(workers, contexts)]
+            self._fit_setup.attributes["worker_mode"] = "processes"
+            self._fit_setup.end()
             _drive_worker_refs(refs, drain)
             for w in workers:
                 try:

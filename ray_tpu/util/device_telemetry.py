@@ -5,12 +5,14 @@ attribution PR 12, flight recorder PR 15) measures host-side wall time;
 this module watches the XLA/device layer those planes cannot see:
 
 * **Compile tracking** — jax's own monitoring events
-  (:func:`listen_for_compiles`: ``backend_compile_duration``, persistent
-  cache hits and misses) feed :func:`record_compile`, a per-process
+  (:func:`listen_for_compiles`: the trace, the lowering and
+  ``backend_compile_duration`` as time spans, the persistent cache's hits,
+  misses and retrieval seconds) feed :func:`record_compile`, a per-process
   registry of every executable the process builds or loads, with the label
   the compiling thread set (:class:`compile_label`; ``TrainStep`` and
   ``create_sharded_state`` set one, everything else is ``unlabelled``),
-  the abstract shape+sharding signature, seconds, and a classified trigger
+  the abstract shape+sharding signature, the seconds of each phase (trace,
+  lower, compile, and of the compile the cache's load), and a classified trigger
   (first_compile / shape_change / sharding_change / donation_change /
   recompile; ``unclassified`` without a signature).  The signature is
   computed only when an event fired, never per step.  Programs that want
@@ -23,6 +25,10 @@ this module watches the XLA/device layer those planes cannot see:
   per window over threshold) emits an ``xla.compile_storm`` ERROR span and
   a flight-recorder dump, same seam pattern as the hang watchdog's stall
   report; :func:`storm_tick` is driven from ``HangWatchdog.tick``.
+* **The set-up's account** — :func:`setup_account`: from the process's
+  start to the end of the first steady step, the spans of the set-up
+  (:class:`setup_span`, :func:`record_setup_span`) in order, what they
+  cover and the gaps between them; always on, a handful of rows a process.
 * **HBM pool accounting** — named live-byte pools (``kv_blocks``,
   ``mux_weights``, ``ckpt_staging``, ``dag_channel``) tracked host-side
   via :func:`pool_add`/:func:`pool_sub` with high-water marks, plus real
@@ -44,12 +50,13 @@ profiler) so no data/serve/checkpoint layer gains an import dependency.
 
 from __future__ import annotations
 
+import logging
 import os
 import sys
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 from ray_tpu._private import fault_injection
 from ray_tpu.util import flight_recorder, metrics, tracing
@@ -60,6 +67,11 @@ from ray_tpu.util.metrics_agent import get_aggregator
 _COMPILE_TAIL = 512
 #: Transfer-record tail retained per process.
 _TRANSFER_TAIL = 256
+#: Rows the set-up's account takes before it closes (a run has about eight).
+_ACCOUNT_ROWS = 128
+#: What a row of the account keeps of its span's attributes.
+_ACCOUNT_KEYS = ("label", "trace_s", "lower_s", "compile_s", "cache_load_s",
+                 "cache", "other_s", "workers", "worker_mode", "bytes")
 
 #: Recompiles (non-first-compile) inside the window that trip the storm
 #: detector.  Env-overridable so chaos tests can trip it deterministically.
@@ -81,6 +93,18 @@ TRIGGER_UNCLASSIFIED = "unclassified"
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
                  "/jax/compilation_cache/cache_misses": "miss"}
+#: The time spans of a compile's phases -> the :class:`compile_label`
+#: attribute that sums the phase.  The compile's own span sums nowhere
+#: (``compile_s`` is its duration event, as ever); it is here so that a trace
+#: it ran inside does not count it again.
+_PHASE_EVENTS = {"/jax/core/compile/jaxpr_trace_duration": "trace_s",
+                 "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+                 _COMPILE_EVENT: None}
+#: Duration events jax fires inside a compile the persistent cache answered
+#: -> where the thread keeps them until the compile's own event.
+_CACHE_DURATIONS = {
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load_s",
+    "/jax/compilation_cache/compile_time_saved_sec": "saved_s"}
 UNLABELLED = "unlabelled"
 
 COMPILES_TOTAL = metrics.Counter(
@@ -122,6 +146,11 @@ TRANSFER_BYTES = metrics.Counter(
     "and source path (ingest_prefetch, ckpt_snapshot, kv_handoff, "
     "kv_tier, dag_channel, ...).",
     ("direction", "src"))
+TIME_TO_FIRST_STEP = metrics.Gauge(
+    "ray_tpu_train_time_to_first_step_seconds",
+    "Seconds from the process's start to the end, on the device, of the "
+    "first train step whose call compiled nothing (setup_account(); set "
+    "once, when the account closes).")
 TRANSFERS_TOTAL = metrics.Counter(
     "ray_tpu_device_transfers_total",
     "Host<->device transfer events, by direction and source path.",
@@ -140,6 +169,11 @@ _programs: Dict[str, Any] = {}  # guarded_by: _lock
 #: Bounded tail of first-call records ({"label", "ts", "seconds"}).
 _first_calls: "deque" = deque(maxlen=_COMPILE_TAIL)  # guarded_by: _lock
 _listening = False  # guarded_by: _lock
+#: The set-up's account: its rows while it is open, then how it closed.
+_account_rows: List[dict] = []  # guarded_by: _lock
+_account_closed: Optional[Dict[str, Any]] = None  # guarded_by: _lock
+#: (time.time() at the process's start, where that was read)
+_process_started: Optional[Tuple[float, str]] = None  # guarded_by: _lock
 #: The compiling thread's :class:`compile_label` and the cache event of the
 #: compile in flight on it.
 _thread = threading.local()
@@ -179,14 +213,20 @@ def _classify(prev: Optional[Dict[str, Any]], shapes: Any, shardings: Any,
 
 def record_compile(label: str, *, shapes: Any, shardings: Any = None,
                    donation: Any = (), trace_s: float = 0.0,
-                   compile_s: float = 0.0, cache: Optional[str] = None,
-                   ts: Optional[float] = None) -> str:
+                   lower_s: float = 0.0, compile_s: float = 0.0,
+                   cache: Optional[str] = None, cache_load_s: float = 0.0,
+                   saved_s: float = 0.0, ts: Optional[float] = None,
+                   start: Optional[float] = None) -> str:
     """Record one executable built or loaded; returns the classified
     trigger.  ``shapes``/``shardings``/``donation`` are opaque hashable
     signature components — classification only compares them against the
     label's previous compile; ``shapes=None`` is an event nobody could
-    sign (``unclassified``).  ``cache`` is the persistent cache's answer
-    for this compile ("hit" / "miss" / None when it was not asked)."""
+    sign (``unclassified``).  ``trace_s``, ``lower_s`` and ``compile_s``
+    are the seconds of its three phases; ``cache`` is the persistent cache's
+    answer for this compile ("hit" / "miss" / None when it was not asked),
+    ``cache_load_s`` the part of ``compile_s`` that read the entry and
+    ``saved_s`` what jax says the hit saved.  ``ts`` is the end and
+    ``start`` the start of the trace (else the end less the phases)."""
     t = time.time() if ts is None else ts
     with _lock:
         trigger = _classify(_last_sig.get(label), shapes, shardings,
@@ -197,20 +237,26 @@ def record_compile(label: str, *, shapes: Any, shardings: Any = None,
         _compile_tail.append({
             "label": label, "trigger": trigger, "ts": t,
             "trace_s": round(float(trace_s), 6),
+            "lower_s": round(float(lower_s), 6),
             "compile_s": round(float(compile_s), 6),
             "cache": cache,
+            "cache_load_s": round(float(cache_load_s), 6),
+            "saved_s": round(float(saved_s), 6),
             "signature": repr(shapes)[:200],
         })
         recompiled = trigger not in (TRIGGER_FIRST, TRIGGER_UNCLASSIFIED)
         if recompiled:
             _recompile_ts.append(t)
+    wall = trace_s + lower_s + compile_s
     COMPILES_TOTAL.inc(tags={"label": label, "trigger": trigger})
-    COMPILE_SECONDS.inc(trace_s + compile_s, tags={"label": label})
-    wall = trace_s + compile_s
-    tracing.record_span("xla.compile", t - wall, t,
-                        attributes={"label": label, "trigger": trigger,
-                                    "trace_s": trace_s,
-                                    "compile_s": compile_s})
+    COMPILE_SECONDS.inc(wall, tags={"label": label})
+    tracing.record_span("xla.compile", t - wall if start is None else start,
+                        t, attributes={"label": label, "trigger": trigger,
+                                       "trace_s": trace_s,
+                                       "lower_s": lower_s,
+                                       "compile_s": compile_s,
+                                       "cache": cache,
+                                       "cache_load_s": cache_load_s})
     if recompiled:
         storm_tick(now=t)
     return trigger
@@ -222,15 +268,33 @@ class compile_label:
     label.  ``signature`` is a zero-argument callable returning
     ``(shapes, shardings, donation)``; it runs only when an event fired,
     so a steady step pays two thread-local stores and nothing else.
-    ``compiles`` / ``compile_s`` say afterwards what the block built."""
 
-    __slots__ = ("label", "signature", "compiles", "compile_s", "_outer")
+    Afterwards the label says what the block built and where the seconds
+    went: ``compiles`` / ``compile_s`` (jax's ``backend_compile_duration``,
+    a cache load included), ``trace_s`` and ``lower_s`` (the time spans of
+    the trace and of the lowering to MLIR), ``cache_load_s`` and ``saved_s``
+    (of the compiles the persistent cache answered: the read, and what jax
+    says it saved), ``hits`` / ``misses``.  Nested jits fire a trace span
+    each, innermost first, each inside its caller's: a span counts for its
+    own phase less every span it holds, so the three phases sum to the
+    union of the intervals and nothing is counted twice.  A label is for
+    one block (``TrainStep`` makes one a call): the spans it still holds
+    are those no later one has enclosed."""
+
+    # What a block that compiles nothing never writes stays the class's: a
+    # steady step's label costs its two arguments and nothing else.
+    compiles = hits = misses = 0
+    compile_s = trace_s = lower_s = cache_load_s = saved_s = 0.0
+    #: (start, seconds) of the spans no later span has enclosed, in order
+    _spans: Optional[List[Tuple[float, float]]] = None
+    #: (trace_s, lower_s) already given to a compile record
+    _filed = (0.0, 0.0)
+    #: the earliest start among the spans since that record
+    _since: Optional[float] = None
 
     def __init__(self, label: str, signature=None):
         self.label = label
         self.signature = signature
-        self.compiles = 0
-        self.compile_s = 0.0
 
     def __enter__(self):
         self._outer = getattr(_thread, "label", None)
@@ -240,6 +304,49 @@ class compile_label:
     def __exit__(self, et, ev, tb):
         _thread.label = self._outer
         return False
+
+    def _span(self, phase: Optional[str], start: float, end: float) -> None:
+        """One time span of a phase.  Spans on one thread nest or lie apart
+        and arrive by their ends, so those this one holds are the last of
+        ``_spans``: constant work a span, however deep the jits nest."""
+        own = end - start
+        spans = self._spans
+        if spans is None:
+            spans = self._spans = []
+        while spans and spans[-1][0] >= start:
+            own -= spans.pop()[1]
+        spans.append((start, end - start))
+        if self._since is None or start < self._since:
+            self._since = start
+        if phase is not None:
+            setattr(self, phase, getattr(self, phase) + max(own, 0.0))
+
+    def _file(self) -> Tuple[float, float, Optional[float]]:
+        """(trace_s, lower_s, start) since the last compile record."""
+        trace_s, lower_s = self._filed
+        self._filed = (self.trace_s, self.lower_s)
+        since, self._since = self._since, None
+        return self.trace_s - trace_s, self.lower_s - lower_s, since
+
+    @property
+    def cache(self) -> Optional[str]:
+        """The persistent cache's answers to the block's compiles as one
+        word: ``hit``, ``miss``, ``mixed``, or None where it was not asked."""
+        if self.hits and self.misses:
+            return "mixed"
+        return "hit" if self.hits else "miss" if self.misses else None
+
+    def phases(self, seconds: float) -> Dict[str, Any]:
+        """Where ``seconds``, the wall time of the block's one call, went:
+        the three phases, ``other_s`` (the rest: argument handling, the
+        first enqueue), and of ``compile_s`` the cache's ``cache_load_s``
+        with its answer."""
+        phases = {"trace_s": self.trace_s, "lower_s": self.lower_s,
+                  "compile_s": self.compile_s}
+        phases["other_s"] = seconds - sum(phases.values())
+        phases["cache_load_s"] = self.cache_load_s
+        return {**{k: round(v, 6) for k, v in phases.items()},
+                "cache": self.cache}
 
 
 def listen_for_compiles() -> None:
@@ -255,6 +362,7 @@ def listen_for_compiles() -> None:
 
     monitoring.register_event_listener(_on_event)
     monitoring.register_event_duration_secs_listener(_on_duration)
+    monitoring.register_event_time_span_listener(_on_time_span)
 
 
 def _on_event(event: str, **_: Any) -> None:
@@ -264,20 +372,42 @@ def _on_event(event: str, **_: Any) -> None:
         _thread.cache = answer
 
 
+def _on_time_span(event: str, start: float, end: float, **_: Any) -> None:
+    # A hybrid step's trace fires thousands of these: a thread-local read,
+    # and on a thread with no label nothing more.
+    ctx = getattr(_thread, "label", None)
+    if ctx is not None and event in _PHASE_EVENTS:
+        ctx._span(_PHASE_EVENTS[event], start, end)
+
+
 def _on_duration(event: str, seconds: float, **_: Any) -> None:
     if event != _COMPILE_EVENT:
+        # Inside a compile the cache answered, before the compile's own.
+        kept = _CACHE_DURATIONS.get(event)
+        if kept is not None:
+            setattr(_thread, kept, seconds)
         return
     cache, _thread.cache = getattr(_thread, "cache", None), None
+    cache_load_s, _thread.cache_load_s = \
+        getattr(_thread, "cache_load_s", 0.0), 0.0
+    saved_s, _thread.saved_s = getattr(_thread, "saved_s", 0.0), 0.0
     ctx = getattr(_thread, "label", None)
     shapes, shardings, donation = None, None, ()
+    trace_s, lower_s, start = 0.0, 0.0, None
     if ctx is not None:
         ctx.compiles += 1
         ctx.compile_s += seconds
+        ctx.cache_load_s += cache_load_s
+        ctx.saved_s += saved_s
+        ctx.hits += cache == "hit"
+        ctx.misses += cache == "miss"
+        trace_s, lower_s, start = ctx._file()
         if ctx.signature is not None:
             shapes, shardings, donation = ctx.signature()
     record_compile(UNLABELLED if ctx is None else ctx.label, shapes=shapes,
-                   shardings=shardings, donation=donation,
-                   compile_s=seconds, cache=cache)
+                   shardings=shardings, donation=donation, trace_s=trace_s,
+                   lower_s=lower_s, compile_s=seconds, cache=cache,
+                   cache_load_s=cache_load_s, saved_s=saved_s, start=start)
     # The step profiler's row counts what its own thread compiled (probed:
     # no profiler module, no train worker in the process).
     profiler = sys.modules.get("ray_tpu.train.profiler")
@@ -303,8 +433,9 @@ def record_first_call(label: str, seconds: float,
                       ts: Optional[float] = None, **attributes: Any) -> None:
     """One call of a labelled program that built or loaded its executable
     (trace, lower, compile or cache load, dispatch), ``ts`` its end;
-    ``attributes`` are the caller's own (``TrainStep``: what the layers keep
-    for the backward, ``remat_*``)."""
+    ``attributes`` are the caller's own (where the seconds went, from the
+    call's label: :meth:`compile_label.phases`; ``TrainStep``: what the
+    traced code said of itself, ``util/first_call.py``)."""
     row = {"label": label, "ts": time.time() if ts is None else ts,
            "seconds": round(float(seconds), 6), **attributes}
     with _lock:
@@ -343,7 +474,8 @@ def compile_totals() -> Dict[str, Any]:
         by_trigger[r["trigger"]] = by_trigger.get(r["trigger"], 0) + 1
     return {"compiles": len(rows),
             "compile_seconds": round(
-                sum(r["trace_s"] + r["compile_s"] for r in rows), 6),
+                sum(r["trace_s"] + r["lower_s"] + r["compile_s"]
+                    for r in rows), 6),
             "by_trigger": by_trigger,
             "storms": storms}
 
@@ -391,6 +523,147 @@ def _report_storm(since: float, detected: float, count: int,
     tracing.record_span("xla.compile_storm", since, detected,
                         attributes=detail, status="ERROR: CompileStorm")
     flight_recorder.trigger_dump("compile_storm", detail)
+
+
+# ------------------------------------------------------------ set-up account
+
+class setup_span:
+    """``with setup_span("runtime.init", {...}) as attributes:`` — a
+    :func:`tracing.span` (so a ``TraceAnnotation`` on the device trace's
+    clock whenever a profile is open, and the exporter's span when tracing
+    is on) that is also a row of :func:`setup_account` while that is open.
+    The block may add to ``attributes`` before it ends.  :meth:`end` is the
+    block's exit for a span that one function opens and another closes, on
+    the same thread; a second call does nothing."""
+
+    __slots__ = ("name", "attributes", "_span", "_start")
+
+    def __init__(self, name: str,
+                 attributes: Optional[Dict[str, Any]] = None):
+        self.name = name
+        self.attributes = {} if attributes is None else attributes
+        self._span = tracing.span(name, attributes=self.attributes)
+        self._start: Optional[float] = None
+
+    def __enter__(self) -> Dict[str, Any]:
+        self._start = time.time()
+        self._span.__enter__()
+        return self.attributes
+
+    def __exit__(self, et, ev, tb):
+        start, self._start = self._start, None
+        if start is not None:
+            self._span.__exit__(et, ev, tb)
+            _account_row(self.name, start, time.time(), self.attributes)
+        return False
+
+    def end(self) -> None:
+        self.__exit__(None, None, None)
+
+
+def record_setup_span(name: str, start: float, end: float,
+                      attributes: Optional[Dict[str, Any]] = None) -> None:
+    """:func:`tracing.record_span` for a span of the set-up timed after the
+    fact (``train.first_call``), and its row of :func:`setup_account`."""
+    tracing.record_span(name, start, end, attributes=attributes)
+    _account_row(name, start, end, attributes or {})
+
+
+def _account_row(name: str, start: float, end: float,
+                 attributes: Dict[str, Any]) -> None:
+    row = {"name": name, "start": start, "end": end,
+           **{k: attributes[k] for k in _ACCOUNT_KEYS if k in attributes}}
+    with _lock:
+        if _account_closed is None and len(_account_rows) < _ACCOUNT_ROWS:
+            _account_rows.append(row)
+
+
+def _process_start() -> Tuple[float, str]:
+    """``time.time()`` at this process's start, from the kernel's account
+    of it (``proc``); where that cannot be read, the import of ``ray_tpu``
+    (``import``).  Read once."""
+    global _process_started
+    with _lock:
+        if _process_started is None:
+            imported = sys.modules["ray_tpu"].IMPORTED_AT
+            _process_started = (imported, "import")
+            try:
+                with open("/proc/self/stat") as f:  # after "(comm)": 3rd on
+                    ticks = float(f.read().rsplit(")", 1)[1].split()[19])
+                with open("/proc/uptime") as f:
+                    age = float(f.read().split()[0]) \
+                        - ticks / os.sysconf("SC_CLK_TCK")
+                if 0.0 <= age and time.time() - age <= imported:
+                    _process_started = (time.time() - age, "proc")
+            except (OSError, ValueError, IndexError):
+                pass
+        return _process_started
+
+
+def close_setup_account(ts: Optional[float] = None,
+                        by: str = "host") -> Optional[Dict[str, Any]]:
+    """The first train step whose call compiled nothing has ended at ``ts``
+    (``by``: ``device`` where the step profiler's resolver thread saw its
+    sentinel ready, ``host`` where the call's return is all there is): the
+    account takes no more rows, the gauge
+    ``ray_tpu_train_time_to_first_step_seconds`` is set and the
+    ``ray_tpu.train`` logger says at INFO where the seconds went.  Once a
+    process; a later call returns None."""
+    global _account_closed
+    with _lock:
+        if _account_closed is not None:
+            return None
+        _account_closed = {"ts": time.time() if ts is None else ts, "by": by}
+    account = setup_account()
+    TIME_TO_FIRST_STEP.set(account["to_first_step_s"])
+    logging.getLogger("ray_tpu.train").info(
+        "first steady step done %.2f s after the process started (%s): %s; "
+        "%.2f s outside every span, the longest gap %s",
+        account["to_first_step_s"], account["start_from"],
+        ", ".join(f"{r['name']} {r['end'] - r['start']:.2f}"
+                  for r in account["rows"]),
+        account["unspanned_s"],
+        max(account["gaps"], key=lambda g: g["seconds"], default=None))
+    return account
+
+
+def setup_account() -> Dict[str, Any]:
+    """The set-up from inside the program, on ``time.time()``: ``start``
+    (the process's, ``start_from`` says whence), the ``rows`` in order of
+    their starts (``runtime.init``, ``train.fit_setup``,
+    ``train.init_params``, ``train.init_opt_state``, ``train.first_batch``,
+    ``train.first_call`` and every later call of a labelled program that
+    compiled: name, start, end and what ``_ACCOUNT_KEYS`` lists of the
+    span's attributes), and ``closed``: the end, on the device, of the
+    first step whose call compiled nothing.  From them ``spanned_s`` (the
+    union of the rows), ``gaps`` (each stretch no row covers, by the rows
+    it lies between, the process's start and the first step's end
+    included) and, once closed, ``to_first_step_s`` and ``unspanned_s``
+    (the gaps' sum: the interpreter's start, the imports, the accelerator
+    runtime coming up, and what the caller ran between the rows).  Open,
+    the account ends at its last row and the two read None."""
+    start, start_from = _process_start()
+    with _lock:
+        rows = sorted((dict(r) for r in _account_rows),
+                      key=lambda r: r["start"])
+        closed = dict(_account_closed) if _account_closed else None
+    end = closed["ts"] if closed else max(
+        (r["end"] for r in rows), default=start)
+    gaps, spanned, at, last = [], 0.0, start, "process_start"
+    for row in rows + [{"name": "first_step" if closed else "open",
+                        "start": end, "end": end}]:
+        lo, hi = max(row["start"], start), min(row["end"], end)
+        if lo > at:
+            gaps.append({"after": last, "before": row["name"],
+                         "seconds": round(lo - at, 6)})
+        if hi > at:
+            spanned += hi - max(lo, at)
+            at, last = hi, row["name"]
+    total = end - start
+    return {"start": start, "start_from": start_from, "rows": rows,
+            "closed": closed, "gaps": gaps, "spanned_s": round(spanned, 6),
+            "to_first_step_s": round(total, 6) if closed else None,
+            "unspanned_s": round(total - spanned, 6) if closed else None}
 
 
 # --------------------------------------------------------------------- pools
@@ -590,7 +863,9 @@ def publish(collector: Any, source: str = "", *,
 def reset() -> None:
     """Drop all retained state (tests / bench arms): compile registry with
     its programs and first calls, storm window, pools (gauges cleared),
-    transfer tail.  The jax listeners stay registered."""
+    transfer tail, the set-up account's rows and its close (it is open
+    again, from the same process start).  The jax listeners stay
+    registered."""
     with _lock:
         _last_sig.clear()
         _compile_tail.clear()
@@ -599,8 +874,10 @@ def reset() -> None:
         _first_calls.clear()
         _transfer_tail.clear()
         _pools.clear()
-        global _storms
+        _account_rows.clear()
+        global _storms, _account_closed
         _storms = 0
+        _account_closed = None
     POOL_BYTES.clear()
     POOL_PEAK_BYTES.clear()
     HBM_BYTES.clear()

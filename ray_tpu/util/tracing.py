@@ -93,6 +93,16 @@ SPAN_REGISTRY: Dict[str, str] = {
     "data.prefetch": "ingest: host->device transfer dispatch, per batch",
     "data.pump": "ingest: one batch pulled out of the pipeline by the "
                  "ingest-prefetch pump thread",
+    "runtime.init": "ray_tpu.init(): the runtime's start, entry to return "
+                    "(a row of device_telemetry.setup_account())",
+    "train.fit_setup": "trainer: fit() entry to the moment the first "
+                       "worker enters the loop function (placement group, "
+                       "workers, dataset split, sessions; attrs: workers, "
+                       "worker_mode); a row of the set-up's account",
+    "train.first_batch": "ingest: a shard's first epoch, the consumer's "
+                         "first pull to the first batch in its hands (on "
+                         "the device under a device_sharding; attrs: "
+                         "bytes); a row of the set-up's account",
     "train.step": "profiler: one training step, report() to report()",
     "train.data_wait": "profiler: step blocked on the input pipeline (live "
                        "around the starved wait in HostPrefetcher)",
@@ -100,8 +110,10 @@ SPAN_REGISTRY: Dict[str, str] = {
                       "side (enqueue; trace+compile on a first call)",
     "train.first_call": "TrainStep: a call that built or loaded an "
                         "executable (trace, lower, compile or cache load, "
-                        "dispatch), recorded after the fact (attrs: label, "
-                        "compile_s, remat_kept, remat_kept_bytes, "
+                        "dispatch), recorded after the fact, a row of the "
+                        "set-up's account (attrs: label, trace_s, lower_s, "
+                        "compile_s, other_s, cache_load_s, cache, "
+                        "remat_kept, remat_kept_bytes, "
                         "remat_room_bytes, remat_routing_bytes, "
                         "remat_fallback, "
                         "grad_ring_products, grad_ring_axis; from "
@@ -120,14 +132,16 @@ SPAN_REGISTRY: Dict[str, str] = {
                        "over); the end is when the step finished on the "
                        "device, the row's done",
     "train.init_params": "create_sharded_state: parameters initialised "
-                         "into their sharded layout",
+                         "into their sharded layout (attrs: label and the "
+                         "phases, as train.first_call); a row of the "
+                         "set-up's account",
     "train.init_opt_state": "create_sharded_state: optimizer state derived "
-                            "from the parameters",
+                            "from the parameters (attrs and account as "
+                            "train.init_params)",
     "train.result_drain": "controller: one pass over the workers' report "
                           "queues (its thread shares the GIL with the step "
                           "loop)",
     "train.h2d": "profiler: host->device batch transfer within a step",
-    "train.compute": "profiler: step compute residual (wall - waits)",
     "train.collective": "profiler: gradient-sync rendezvous within a step",
     "train.ckpt_block": "profiler: device->host snapshot blocking a step",
     "train.elastic": "controller: elastic recovery, failure -> resumed",
@@ -137,7 +151,9 @@ SPAN_REGISTRY: Dict[str, str] = {
                       "file written",
     "watchdog.tick": "hang watchdog: one detection pass on its thread",
     "xla.compile": "device telemetry: one executable built or loaded, from "
-                   "jax's compile events (attrs: label, trigger)",
+                   "the start of its trace to the executable (attrs: "
+                   "label, trigger, trace_s, lower_s, compile_s, cache, "
+                   "cache_load_s)",
     "xla.compile_storm": "device telemetry: recompile storm episode, first "
                          "windowed recompile -> detection (status ERROR)",
     "device.transfer": "device telemetry: one timed host<->device "

@@ -102,6 +102,32 @@ class TestTriggerClassification:
                                         dt.TRIGGER_SHAPE: 1}
         assert totals["compile_seconds"] == pytest.approx(2.0)
 
+    def test_a_record_carries_its_phases_and_the_caches_answer(self):
+        tracing.clear_spans()
+        tracing.enable_tracing()
+        try:
+            dt.record_compile("f", shapes=("a",), trace_s=0.5, lower_s=0.25,
+                              compile_s=1.0, cache="hit", cache_load_s=0.75,
+                              saved_s=9.0, ts=100.0, start=98.0)
+            dt.record_compile("f", shapes=("a",), trace_s=0.5, lower_s=0.25,
+                              compile_s=1.0, ts=200.0)
+            first, second = [s for s in tracing.exported_spans()
+                             if s["name"] == "xla.compile"]
+        finally:
+            tracing.disable_tracing()
+            tracing.clear_spans()
+        row = dt.compile_records("f")[0]
+        assert (row["trace_s"], row["lower_s"], row["compile_s"]) \
+            == (0.5, 0.25, 1.0)
+        assert (row["cache"], row["cache_load_s"], row["saved_s"]) \
+            == ("hit", 0.75, 9.0)
+        # the lowering counts: in the totals and in the span's extent
+        assert dt.compile_totals()["compile_seconds"] == pytest.approx(3.5)
+        assert (first["start"], first["end"]) == (98.0, 100.0)
+        assert (second["start"], second["end"]) == (198.25, 200.0)
+        assert first["attributes"]["cache"] == "hit"
+        assert first["attributes"]["lower_s"] == 0.25
+
     def test_classify_trigger_is_read_only(self):
         dt.record_compile("f", shapes=("a",))
         # Peeking twice at the same changed signature must not update the

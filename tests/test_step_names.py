@@ -440,6 +440,9 @@ def test_fit_puts_program_spans_on_the_profilers_clock(tmp_path):
     assert worker["data.prefetch"] == 6
     assert worker["train.init_params"] == 1
     assert worker["train.init_opt_state"] == 1
+    assert worker["train.first_batch"] == 1
+    # fit()'s own start is the controller's, on the line of the test's thread
+    assert any("train.fit_setup" in c for c in lines if c is not worker)
     others = [c for c in lines if c is not worker]
     assert any("data.pump" in c for c in others), lines
     assert any("train.result_drain" in c for c in others), lines
@@ -472,8 +475,10 @@ def test_scope_names_are_under_the_registry_check():
     assert ctx.scope_names == set(tracing.SCOPE_REGISTRY)
     assert "device.burn" not in ctx.span_names
     for name in ("train.dispatch", "train.report", "train.first_call",
-                 "data.pump", "train.result_drain", "watchdog.tick"):
+                 "data.pump", "train.result_drain", "watchdog.tick",
+                 "runtime.init", "train.fit_setup", "train.first_batch"):
         assert name in ctx.span_names
+    assert "train.compute" not in ctx.span_names
 
     source = ("import jax\n"
               "def f(x):\n"
