@@ -285,12 +285,16 @@ def _from_window(rows, pairs, inverse, run):
     """rows: (R, D) on that window, already weighted -> (N, D), each token
     the float32 sum of those of its k rows that lie in the run; a row of the
     window outside the run met no expert and is left out, whatever it
-    holds."""
+    holds.  The gathered rows lie slot-major, (k, N, D): with the k slots
+    between N and D they sit on the tiled second-minor axis, and where k is
+    no multiple of the sublane tile the layout pads them through a copy of
+    the whole array (6 -> 8, 10 -> 16: ``PERF.md``, PR 55)."""
     first, stop, lead = run
-    inside = (inverse >= first) & (inverse < stop)
-    place = jnp.clip(inverse - (first - lead), 0, rows.shape[0] - 1)
+    slots = inverse.T
+    inside = (slots >= first) & (slots < stop)
+    place = jnp.clip(slots - (first - lead), 0, rows.shape[0] - 1)
     return jnp.sum(jnp.where(inside[..., None], rows[place], 0)
-                   .astype(jnp.float32), axis=1).astype(rows.dtype)
+                   .astype(jnp.float32), axis=0).astype(rows.dtype)
 
 
 # Each other's transposes on the rows of the run, as the full move's pair is

@@ -392,23 +392,28 @@ def test_the_control_is_refused():
 #: and the scores are read at them by a select and a sum (the forward's
 #: numbers and, in float32, every gradient are the parent's to the bit:
 #: ``tests/test_olmoe.py``, ``PERF.md`` PR 48); the two dense ones are the
-#: parent's.
+#: parent's.  **PR 55 meant to, in the three whose expert layers hold a
+#: share** (``tiny-sdar``, ``tiny-nemotron-h``, ``tiny-solar-open2``): the
+#: window's combine gathers slot-major, (k, N, D), and sums over axis 0
+#: (three steps of each on this CPU leave the parent's parameters and losses
+#: to the bit; ``tests/test_moe_combine_layout.py``); ``tiny-olmoe`` holds
+#: every expert and runs ``_combine``, untouched.
 LOWERED_STEPS = {
     "tiny-llama":
         "f6d4a6b1cbf541233f13675bccbe7766fcb6a630709a7aa7b7f50a0428b1fe2c",
     "tiny-olmoe":
         "115daacfda98cd00748642ce33854899b338aef11544f9ea9a8dcadda6abf25f",
     "tiny-sdar":
-        "ef767ddf254bcd4a9f989bc5b774a16a7764a3fdadebd3a2f46dc4c1f2a10436",
+        "a8918c30e97c45dcea988163b92a015cf348df7dec773fcc53019d75b7e138b6",
     "tiny-gpt2":
         "86135e6f43a576200ef38b5a76d717607699853ed5341b6350fd1c81db4dbe04",
     # the two hybrid presets, as PR 43 left them (added at PR 44: the tiny
     # Mamba-2 sizes lie off the chip's tiles and take ``ops.ssd.ssd_xla``,
     # whose lowered text is the parent's ``ssd``)
     "tiny-nemotron-h":
-        "3280108af5618f25c63009a49ad08bcf89d6a390ab0ed6f661dd3c3d4be1bc19",
+        "d911af43f10569b30e177e26e8d157abf9112a654b5409cbfe0bc5484e1f12c4",
     "tiny-solar-open2":
-        "4297b2f0c8cca36c5ec2d261de100efcfcab727819b4629e5344018e64085590",
+        "ba7f4d2464639435af96c62f55186c6f8fb59b856fd6788db50aa64331b4c1fa",
 }
 
 

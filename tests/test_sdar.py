@@ -450,11 +450,11 @@ def test_the_windows_under_remat_run_no_product_twice_but_gate_and_up():
                       and e.outvars[0].aval.shape[-1] == d)
 
     assert kernels(forward) == 3 and kernels(backward) == 2 + 3 + 3
-    # a window: the dispatch (R, D) and the combine (N, k, D); backwards:
-    # the dispatch again, the combine's transpose (R, D), the dispatch's
-    # transpose (N, k, D), and no combine a second time
-    assert row_gathers(forward) == sorted([(_N, _K, d), (rows, d)])
-    assert row_gathers(backward) == sorted([(_N, _K, d), (rows, d),
+    # a window: the dispatch (R, D) and the combine, slot-major (k, N, D);
+    # backwards: the dispatch again, the combine's transpose (R, D), the
+    # dispatch's transpose (k, N, D), and no combine a second time
+    assert row_gathers(forward) == sorted([(_K, _N, d), (rows, d)])
+    assert row_gathers(backward) == sorted([(_K, _N, d), (rows, d),
                                             (rows, d)])
     assert not any(e.primitive.name.startswith("scatter")
                    and e.outvars[0].aval.shape[0] in (_N, _N * _K)
