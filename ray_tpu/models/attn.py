@@ -2,8 +2,11 @@
 ``*``): ``models/layers.py:attention``, the half ``llama._block`` runs too,
 with its parameters and its sizes.  Not a model.
 
-Here without QK-norm and, where ``rope_theta`` is None, without rotary
-embedding (the recurrent layers carry the positions); with ``attn_gate`` an
+Where ``rope_theta`` is None, without rotary embedding (the recurrent layers
+carry the positions); with ``qk_norm`` an RMSNorm with a weight on q and on
+k before the rotary pass (``"head"``: over each head's lanes, one weight of
+``head_dim`` for all heads; True: over all of a layer's heads, as
+``models/llama.py`` has both); with ``attn_gate`` an
 output gate before ``wo``, a channel each or with ``"head"`` a scalar a
 head; with ``attn_window`` over a causal band of that many keys; with
 ``rope_rotary`` / ``rope_yarn`` the rotary pass over a head's first lanes
@@ -45,7 +48,19 @@ def init_params(config, key, n: int, out_std: float) -> Dict[str, Any]:
     if config.attn_gate:
         params["wg"] = norm(jax.random.fold_in(key, 4),
                             (D, _gate_width(config)))
+    if config.qk_norm:
+        q_width, k_width = _qk_norm_widths(config)
+        params["q_norm"] = jnp.ones((n, q_width))
+        params["k_norm"] = jnp.ones((n, k_width))
     return params
+
+
+def _qk_norm_widths(config):
+    """The lanes one weight of ``q_norm`` and of ``k_norm`` covers."""
+    if config.qk_norm == "head":
+        return config.head_dim, config.head_dim
+    return (config.n_head * config.head_dim,
+            config.n_kv_head * config.head_dim)
 
 
 def _gate_width(config) -> int:
@@ -61,6 +76,8 @@ def logical_axes(config) -> Dict[str, Any]:
             "wo": (L, "heads", "embed")}
     if config.attn_gate:
         axes["wg"] = (L, "embed", "heads")
+    if config.qk_norm:
+        axes["q_norm"] = axes["k_norm"] = (L, "norm")
     return axes
 
 
@@ -73,7 +90,8 @@ def matmul_params(config, routed: float) -> int:
 
 def num_params(config) -> int:
     """Of one layer, its pre-norm included."""
-    return matmul_params(config, 0) + config.d_model
+    return matmul_params(config, 0) + config.d_model \
+        + (sum(_qk_norm_widths(config)) if config.qk_norm else 0)
 
 
 def mixer_flops(config, seq_len: int) -> float:
@@ -106,6 +124,8 @@ def first_call_facts(config, rows: int, seq_len: int) -> Dict[str, Any]:
     facts = {"attn_positions": seq_len, "heads_held": config.n_head,
              "heads_total": config.n_head_total or config.n_head,
              "attn_gate": config.attn_gate}
+    if config.qk_norm:
+        facts["qk_norm"] = config.qk_norm
     if config.rope_rotary is not None:
         facts["rope_rotary_lanes"] = config.rope_rotary
     if config.rope_yarn is not None:

@@ -18,7 +18,11 @@ mixer and then experts, is two of these):
 - ``W``: attention over a causal band (``models/window.py`` over
   ``models/attn.py``), beside ``*`` in a stack whose full and window layers
   differ in head count and rotary settings (the ``laguna`` family's
-  ``layer_types``).
+  ``layer_types``);
+- ``C``: a gated short convolution (``models/shortconv.py``): a causal
+  depthwise convolution of ``conv_taps`` taps between two element-wise
+  gates, the token mixer of three layers in four of the ``lfm2_moe``
+  family, whose fourth is ``*`` with ``rope_theta`` and ``qk_norm``.
 
 **A kind is a module** and one line of :data:`KINDS`; this file knows no
 kind by name.  The module (loaded with the first pattern that holds its
@@ -47,9 +51,12 @@ A chip may also hold a share of the **heads**: that is a smaller ``n_head``
 the absent heads would add is left out, as with the experts, and no code
 here differs for it.
 
-After the last layer a final norm and an untied head; the loss is next-token
-cross-entropy.  The functional contract is the other decoders':
-init_params / logical_axes / loss_fn / make_train_step.
+After the last layer a final norm and an untied head, or with ``tie_head``
+the embedding as the head: no ``lm_head`` leaf, ``wte`` read by the gather
+and by the head's product (and by a prediction module's), its gradient the
+sum of the two.  The loss is next-token cross-entropy.  The functional
+contract is the other decoders': init_params / logical_axes / loss_fn /
+make_train_step.
 
 **A multi-token-prediction module** (``mtp_depth`` 1; the ``deepseek_v3``
 family's) is a second pass after the last layer: each position's hidden
@@ -64,8 +71,9 @@ more row of its kinds' stacks.  A configuration without one (``mtp_depth``
 0) traces none of it.
 
 **Parameters.**  Layers of one kind share one stacked tree (``ssm``,
-``kda``, ``attn``, ``experts``, ``mla``, ``dense``, ``window``, each leaf with its kind's
-layers in front, the prediction module's after the pattern's), so the
+``kda``, ``attn``, ``experts``, ``mla``, ``dense``, ``window``,
+``shortconv``, each leaf with its kind's layers in front, the prediction
+module's after the pattern's), so the
 optimizer, the sharding rules and a checkpoint see a stack a kind and not
 ``len(pattern)`` trees.  The stack runs unrolled: layer i takes row
 ``pattern[:i].count(kind)`` of its kind's stack.  (A pattern that repeats
@@ -125,7 +133,8 @@ KINDS = {"M": Kind("ssm", "ray_tpu.models.mamba2", 2),
          "K": Kind("kda", "ray_tpu.models.kda", 5),
          "L": Kind("mla", "ray_tpu.models.mla", 6),
          "D": Kind("dense", "ray_tpu.models.dense", 7),
-         "W": Kind("window", "ray_tpu.models.window", 8)}
+         "W": Kind("window", "ray_tpu.models.window", 8),
+         "C": Kind("shortconv", "ray_tpu.models.shortconv", 9)}
 
 #: folded into ``init_params``' key for the prediction module's ``w_eh``
 MTP_DRAW = 47
@@ -148,7 +157,9 @@ class HybridConfig:
     head_dim: int = 32
     #: None: no rotary embedding (nemotron_h builds none)
     rope_theta: Optional[float] = None
-    qk_norm: bool = False
+    #: ``"head"``: an RMSNorm with a weight over each head's lanes of q and
+    #: of k, before the rotary pass; True: over all of a layer's heads
+    qk_norm: Any = False
     block_length: int = 0
     attn_impl: str = "auto"
     #: an output gate, ``(attn * sigmoid(x wg)) wo``: True a channel each,
@@ -217,6 +228,10 @@ class HybridConfig:
     window_heads: int = 4
     window_keys: int = 16
     window_rope_theta: Optional[float] = 10000.0
+    # ``C``: models/shortconv.py, ``d_model`` wide
+    conv_taps: int = 3
+    #: the head is the embedding: no ``lm_head`` leaf
+    tie_head: bool = False
     #: multi-token-prediction modules after the last layer (0 or 1) and the
     #: weight of a module's loss in the step's
     mtp_depth: int = 0
@@ -292,6 +307,20 @@ class HybridConfig:
             window_heads=6, experts_held=range(4, 8), d_ff=48,
             shared_width=48, expert_activation="silu", gated_experts=True)
 
+    @staticmethod
+    def tiny_lfm2() -> "HybridConfig":
+        """LFM2-8B-A1B's shape in small: a gated short convolution of three
+        taps before a dense layer, then attention (4 heads of 32 over 2 key
+        heads, rotary over the whole head, an RMSNorm a head on q and k)
+        and convolutions in turn, each before experts; experts 4-7 of 16
+        held, 2 a token, SwiGLU, no shared expert, a selection bias, weights
+        that sum to one unscaled, a tied head."""
+        return HybridConfig(
+            pattern="CD*ECE*ECE", rope_theta=1000000.0, qk_norm="head",
+            experts_held=range(4, 8), d_ff=48, shared_width=0,
+            expert_activation="silu", gated_experts=True,
+            routed_scaling=1.0, router_bias_std=0.05, tie_head=True)
+
     def __post_init__(self):
         assert self.pattern and set(self.pattern) <= set(KINDS), self.pattern
         assert self.mtp_depth in (0, 1), self.mtp_depth
@@ -321,8 +350,9 @@ def init_params(config: HybridConfig, key) -> Dict[str, Any]:
     def norm(key, shape, s):
         return jax.random.normal(key, shape, jnp.float32) * s
 
-    params = {"wte": norm(keys[0], (V, D), std), "final_norm": jnp.ones((D,)),
-              "lm_head": norm(keys[1], (V, D), std)}
+    params = {"wte": norm(keys[0], (V, D), std), "final_norm": jnp.ones((D,))}
+    if not config.tie_head:
+        params["lm_head"] = norm(keys[1], (V, D), std)
     for kind, entry in _kinds(config).items():
         params[entry.stack] = entry.module.init_params(
             config, keys[entry.draw] if entry.draw < len(keys)
@@ -337,8 +367,9 @@ def init_params(config: HybridConfig, key) -> Dict[str, Any]:
 
 
 def logical_axes(config: HybridConfig) -> Dict[str, Any]:
-    axes = {"wte": ("vocab", "embed"), "final_norm": ("norm",),
-            "lm_head": ("vocab", "embed")}
+    axes = {"wte": ("vocab", "embed"), "final_norm": ("norm",)}
+    if not config.tie_head:
+        axes["lm_head"] = ("vocab", "embed")
     for entry in _kinds(config).values():
         axes[entry.stack] = entry.module.logical_axes(config)
     if config.mtp_depth:
@@ -349,7 +380,7 @@ def logical_axes(config: HybridConfig) -> Dict[str, Any]:
 
 def num_params(config: HybridConfig) -> int:
     D = config.d_model
-    return 2 * config.vocab_size * D + D \
+    return (1 if config.tie_head else 2) * config.vocab_size * D + D \
         + sum(KINDS[kind].module.num_params(config)
               for kind in config.pattern + config.mtp_kinds) \
         + config.mtp_depth * (2 * D * D + 3 * D)
@@ -359,8 +390,10 @@ def flops_per_token(config: HybridConfig) -> float:
     """Per trained token: 6 x the matrix parameters a position meets (of the
     held experts its own, in expectation under an even router) plus 3 x what
     each layer's mixer adds beside them (causal attention, the state-space
-    scan's products, the delta rule's).  A prediction module adds its
-    block's layers, ``w_eh`` and a second pass through the head."""
+    scan's products, the delta rule's, a short convolution's taps and
+    gates).  The head counts once, tied or not: the embedding is a gather.
+    A prediction module adds its block's layers, ``w_eh`` and a second pass
+    through the head."""
     routed = config.experts_per_token * len(config.held) / config.n_experts
     layers = [KINDS[kind].module
               for kind in config.pattern + config.mtp_kinds]
@@ -455,6 +488,11 @@ def _stacked(counts) -> Dict[str, Any]:
             for name in (counts[0] if counts else ())}
 
 
+def _head(params, config: HybridConfig):
+    """The (V, D) matrix the logits are made with."""
+    return params["wte" if config.tie_head else "lm_head"]
+
+
 def _predict_ahead(params, x, targets, run, config: HybridConfig):
     """The prediction module over the last layer's output ``x`` (before the
     final norm) and the row's ``targets`` (position i's is the token after
@@ -477,7 +515,8 @@ def _predict_ahead(params, x, targets, run, config: HybridConfig):
         weights = jnp.broadcast_to(
             (jnp.arange(S) < S - 1) / (B * (S - 1)), (B, S))
         ce = lm_head_cross_entropy(
-            z, params["lm_head"].astype(dt), jnp.roll(targets, -1, axis=1),
+            z, _head(params, config).astype(dt),
+            jnp.roll(targets, -1, axis=1),
             config.logits_dtype, weights)
     return ce, counts
 
@@ -516,7 +555,7 @@ def loss_and_counters(params, tokens, targets, config: HybridConfig):
     counts = _stacked(counts)
     with jax.named_scope("lm_head"):
         loss = lm_head_cross_entropy(
-            hidden, params["lm_head"].astype(dt), targets,
+            hidden, _head(params, config).astype(dt), targets,
             config.logits_dtype, None)
     if config.mtp_depth:
         first_call.note(mtp_depth=config.mtp_depth,

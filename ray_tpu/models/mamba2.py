@@ -22,6 +22,11 @@ dtype with float32 accumulation; the convolution, the gate and the grouped
 norm are float32 passes over (tokens, d_inner)-sized arrays that round once;
 ``delta`` and ``A`` stay float32 into the scan.
 
+``causal_conv(x, w, b)`` serves three kinds: this one and ``K``
+(``models/kda.py``) at four taps with a bias, and ``C``
+(``models/shortconv.py``) at three without one (``b=None``); the number of
+taps is ``w``'s leading size.
+
 Scopes: the whole mixer is ``ssm``, inside it ``ssm_conv`` (the four shifted
 multiply-adds, the bias and the silu) and ``ssm_scan`` (``ops/ssd.py``: two
 Pallas kernels, ``ops/ssd_kernel.py``, where the sizes lie on the chip's
@@ -171,13 +176,16 @@ def _taps(x, w, back: bool):
 
 @jax.custom_vjp
 def causal_conv(x, w, b):
-    """x: (B, S, C); w: (K, C), tap K-1 the position itself; b: (C,).
-    Depthwise: ``y_t = b + sum_k w_k x_{t-(K-1)+k}`` in float32, zeros
-    before the row's first position.  The backward is written out, because
-    it is the same pass run the other way (``dx_t = sum_k w_k
-    dy_{t+(K-1)-k}``) and K reductions for the taps; autodiff's transpose of
-    the pad and the K slices is K padded float32 copies of ``dy`` summed."""
-    return _taps(x, w, False) + b.astype(jnp.float32)
+    """x: (B, S, C); w: (K, C), any number of taps K, tap K-1 the position
+    itself; b: (C,), or None for a convolution without a bias
+    (``models/shortconv.py``: three taps, ``conv_bias`` false).  Depthwise:
+    ``y_t = b + sum_k w_k x_{t-(K-1)+k}`` in float32, zeros before the row's
+    first position.  The backward is written out, because it is the same
+    pass run the other way (``dx_t = sum_k w_k dy_{t+(K-1)-k}``) and K
+    reductions for the taps; autodiff's transpose of the pad and the K
+    slices is K padded float32 copies of ``dy`` summed."""
+    y = _taps(x, w, False)
+    return y if b is None else y + b.astype(jnp.float32)
 
 
 def _causal_conv_fwd(x, w, b):
@@ -192,7 +200,7 @@ def _causal_conv_bwd(saved, dy):
     dw = jnp.stack([jnp.sum(dy * padded[:, k:k + S].astype(jnp.float32),
                             axis=(0, 1)) for k in range(K)])
     return (_taps(dy, w, True).astype(x.dtype), dw.astype(w.dtype),
-            jnp.sum(dy, axis=(0, 1)).astype(b.dtype))
+            None if b is None else jnp.sum(dy, axis=(0, 1)).astype(b.dtype))
 
 
 causal_conv.defvjp(_causal_conv_fwd, _causal_conv_bwd)

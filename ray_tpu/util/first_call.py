@@ -66,7 +66,8 @@ and ``seconds``, the span ``compile_s``):
                               ``heads_total``, ``attn_gate``; where only a
                               head's first lanes rotate
                               ``rope_rotary_lanes``, under YaRN
-                              ``rope_yarn_factor``
+                              ``rope_yarn_factor``, with QK-norm
+                              ``qk_norm``
 ``models/window.py`` (``W``)  ``attn_window`` (the band's keys),
                               ``window_heads``
 ``models/experts.py`` (``E``) ``experts_held``, ``experts_total``,
@@ -80,6 +81,8 @@ and ``seconds``, the span ``compile_s``):
                               ``mla_latents`` (the query's and the
                               key-value latent's width)
 ``models/dense.py`` (``D``)   ``dense_width``
+``models/shortconv.py``       ``shortconv_taps``, ``shortconv_width``,
+(``C``)                       ``shortconv_layers``
 ============================  ==============================================
 """
 

@@ -218,6 +218,14 @@ SCOPE_REGISTRY: Dict[str, str] = {
                 "convolutions over positions and the silu",
     "kda_scan": "KDA layer, nested inside kda: the chunked delta-rule scan "
                 "(ops/kda.py), whatever implements it",
+    "shortconv": "a gated short-convolution layer (models/shortconv.py): "
+                 "norm, in-projection, out-projection (around the scope "
+                 "below)",
+    "shortconv_gate": "gated short-convolution layer, nested inside "
+                      "shortconv: the input gate, the causal depthwise taps "
+                      "over positions and the output gate, one pass forward "
+                      "and one backward, which makes the gate and the taps "
+                      "again",
     "noise": "block-diffusion training (models/block_diffusion.py): the "
              "draw of the masked positions, the noised copy, the "
              "concatenation with the clean one, the loss weights",

@@ -62,23 +62,29 @@ KDA_SCOPES = {"kda", "kda_conv", "kda_scan"}
 MLA_SCOPES = {"latent", "mtp", "mtp_head"}
 #: the scope only a stack with window-attention layers opens
 WINDOW_SCOPES = {"window"}
+#: scopes only a stack with gated short-convolution layers opens
+CONV_SCOPES = {"shortconv", "shortconv_gate"}
 HYBRID_SCOPES = SSM_SCOPES | KDA_SCOPES | MLA_SCOPES | WINDOW_SCOPES \
-    | {"shared_expert"}
+    | CONV_SCOPES | {"shared_expert"}
 
 
 def _scopes_of(family):
     if family == "hybrid":  # holds a share, trains next tokens
         return set(tracing.SCOPE_REGISTRY) - {"experts", "noise"} \
-            - KDA_SCOPES - MLA_SCOPES - WINDOW_SCOPES
+            - KDA_SCOPES - MLA_SCOPES - WINDOW_SCOPES - CONV_SCOPES
     if family == "hybrid-kda":
         return set(tracing.SCOPE_REGISTRY) - {"experts", "noise"} \
-            - SSM_SCOPES - MLA_SCOPES - WINDOW_SCOPES
+            - SSM_SCOPES - MLA_SCOPES - WINDOW_SCOPES - CONV_SCOPES
     if family == "hybrid-mla":
         return set(tracing.SCOPE_REGISTRY) - {"experts", "noise"} \
-            - SSM_SCOPES - KDA_SCOPES - WINDOW_SCOPES
+            - SSM_SCOPES - KDA_SCOPES - WINDOW_SCOPES - CONV_SCOPES
     if family == "hybrid-window":
         return set(tracing.SCOPE_REGISTRY) - {"experts", "noise"} \
-            - SSM_SCOPES - KDA_SCOPES - MLA_SCOPES
+            - SSM_SCOPES - KDA_SCOPES - MLA_SCOPES - CONV_SCOPES
+    if family == "hybrid-conv":  # no shared expert beside its routed ones
+        return set(tracing.SCOPE_REGISTRY) - {"experts", "noise"} \
+            - SSM_SCOPES - KDA_SCOPES - MLA_SCOPES - WINDOW_SCOPES \
+            - {"shared_expert"}
     if family == "llama-sdar":
         return set(tracing.SCOPE_REGISTRY) - {"experts"} - HYBRID_SCOPES
     return set(tracing.SCOPE_REGISTRY) - SDAR_SCOPES - HYBRID_SCOPES - (
@@ -110,6 +116,10 @@ def _family(name):
         from ray_tpu.models import hybrid
 
         return hybrid, hybrid.HybridConfig.tiny_laguna()
+    if name == "hybrid-conv":  # what lfm2-ep4-s8192 runs
+        from ray_tpu.models import hybrid
+
+        return hybrid, hybrid.HybridConfig.tiny_lfm2()
     config = gpt2.GPTConfig.tiny()
     if name == "gpt2-attn-outside-unrolled":  # what gpt2xl-s1024 runs
         import dataclasses
@@ -144,7 +154,7 @@ def _tiny_step(name="llama"):
 # ------------------------------------------------------- names in the step
 @pytest.mark.parametrize("family", ["llama", "llama-moe", "llama-sdar",
                                     "hybrid", "hybrid-kda", "hybrid-mla",
-                                    "hybrid-window",
+                                    "hybrid-window", "hybrid-conv",
                                     "gpt2", "gpt2-attn-outside-unrolled"])
 def test_lowered_step_holds_every_registered_scope(family):
     import jax
@@ -226,7 +236,7 @@ def test_parse_anatomy_on_v5e_module_excerpt():
 
 @pytest.mark.parametrize("family", ["llama", "llama-moe", "llama-sdar",
                                     "hybrid", "hybrid-kda", "hybrid-mla",
-                                    "hybrid-window",
+                                    "hybrid-window", "hybrid-conv",
                                     "gpt2-attn-outside-unrolled"])
 def test_anatomy_of_a_tiny_cpu_step_end_to_end(family):
     from ray_tpu.parallel.train_state import PHASES
@@ -549,6 +559,9 @@ FIRST_CALL_KEYS = {
     "tiny-laguna": _HYBRID | _ROPE | {
         "dense_width", "rope_rotary_lanes", "rope_yarn_factor",
         "attn_window", "window_heads"},
+    "tiny-lfm2": _HYBRID | _ROPE | {
+        "dense_width", "qk_norm", "shortconv_taps", "shortconv_width",
+        "shortconv_layers"},
 }
 
 
