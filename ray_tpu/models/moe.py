@@ -64,11 +64,13 @@ published width and its experts a token, and all N x k pairs are sorted as
 above; in that order the pairs of the held experts are one run, and only the
 run is moved.  It is walked in windows of a static bound
 (:data:`WINDOW_SHARE` of the pairs): a window's rows are gathered,
-multiplied, activated and summed back into token order, and how many windows
-a step walks is decided on the device by the step's own count of held rows (a
-loop of that length; eight eighths are the move of every pair, so no pair is
-dropped: the rows never walked were zeros in the combine's float32 sum).  The
-kernels see the held experts' groups, cut to the window, between two groups
+multiplied, activated and summed back into token order (on the chip by a
+kernel that reads the window's rows once, ``ops/window_return.py``), and how
+many windows a step walks is decided on the device by the step's own count
+of held rows (a loop of that length; eight eighths are the move of every
+pair, so no pair is dropped: the rows never walked were zeros in the
+combine's float32 sum).  The kernels see the held experts' groups, cut to
+the window, between two groups
 that ``rhs`` does not hold (their ``group_offset``), the window's rows before
 and after its part of the run: those rows come out zero and cost no product.
 One set of kernels at the window's size serves every step; the rows a layer
@@ -91,8 +93,9 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
-from ray_tpu.ops import placement, remat
+from ray_tpu.ops import placement, remat, window_return
 from ray_tpu.ops.grouped_matmul import grouped_matmul
+from ray_tpu.util import first_call
 from ray_tpu.util.tracing import step_counter
 
 
@@ -283,16 +286,29 @@ def _to_window(x, pairs, inverse, run):
 @jax.custom_vjp
 def _from_window(rows, pairs, inverse, run):
     """rows: (R, D) on that window, already weighted -> (N, D), each token
-    the float32 sum of those of its k rows that lie in the run; a row of the
-    window outside the run met no expert and is left out, whatever it
-    holds.  The gathered rows lie slot-major, (k, N, D): with the k slots
-    between N and D they sit on the tiled second-minor axis, and where k is
-    no multiple of the sublane tile the layout pads them through a copy of
-    the whole array (6 -> 8, 10 -> 16: ``PERF.md``, PR 55)."""
+    the float32 sum of those of its k rows that lie in the run, rounded
+    once; a row of the window outside the run met no expert and is left
+    out, whatever it holds.  Two forms, chosen while tracing from the
+    backend, the mesh and the shapes (``ops/window_return.py:path``; the
+    first-call record's ``moe_return`` says which).  On the chip a kernel
+    that reads the window's R rows once (:mod:`~ray_tpu.ops.window_return`).
+    Elsewhere a gather by ``inverse`` over all k x N slots and a masked sum
+    of them, k x N x D bytes written and read whatever R is: the gathered
+    rows lie slot-major, (k, N, D), because with the k slots between N and
+    D they sit on the tiled second-minor axis, and where k is no multiple
+    of the sublane tile the layout pads them through a copy of the whole
+    array (6 -> 8, 10 -> 16: ``PERF.md``, PR 55)."""
+    R, (N, k), D = rows.shape[0], inverse.shape, rows.shape[1]
+    how = window_return.path(R, N, D, jax.sharding.get_abstract_mesh())
+    first_call.entry(
+        "moe_return", f"{R}x{N}x{k}x{D}",
+        (how, window_return.tile(R, N, D)[0] if how == "kernel" else None))
+    if how == "kernel":
+        return window_return.from_window(rows, pairs, inverse, run)
     first, stop, lead = run
     slots = inverse.T
     inside = (slots >= first) & (slots < stop)
-    place = jnp.clip(slots - (first - lead), 0, rows.shape[0] - 1)
+    place = jnp.clip(slots - (first - lead), 0, R - 1)
     return jnp.sum(jnp.where(inside[..., None], rows[place], 0)
                    .astype(jnp.float32), axis=0).astype(rows.dtype)
 

@@ -45,6 +45,12 @@ and ``seconds``, the span ``compile_s``):
 ``ops/grouped_matmul.py``     ``gmm_tiles``: ``"m x k x n"`` of each distinct
                               grouped product traced -> the (rows,
                               contraction, columns) tile it walks
+``models/moe.py``             where a layer holds a share of its experts,
+                              ``moe_return``: ``"R x N x k x D"`` of each
+                              distinct window's return to its tokens ->
+                              (``"kernel"``, the token tile of
+                              ``ops/window_return.py``) or (``"gather"``,
+                              None)
 ``ops/ssd.py``                ``ssm_scan_kernel`` (the Pallas kernels, or
                               the XLA form), ``ssm_scan_grid`` (the
                               kernels' grid a chip, or None)

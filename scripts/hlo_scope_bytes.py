@@ -153,7 +153,8 @@ def main() -> int:
     for phase, kind, name, opcode, read, written, types in sorted(
             rows, key=lambda r: -(r[4] + r[5])):
         if kind == "movement" and read + written >= args.min_mb * 1e6:
-            print(f"  {phase:9s} {name:32s} {opcode:8s} in {read / 1e6:7.1f} "
+            print(f"  {phase or '-':9s} {name:32s} {opcode:8s} in "
+                  f"{read / 1e6:7.1f} "
                   f"out {written / 1e6:7.1f} MB  {types}")
     return 0
 

@@ -404,7 +404,9 @@ def test_num_params_flops_and_the_first_call_record():
         # the pattern's two expert layers' routing and the module's one's
         "remat_routing_bytes": 3 * moe.routing_bytes(256, 16, 2),
         # a window's products, gate / up and down, and their tiles (PR 50)
-        "gmm_tiles": {"64x128x48": (64, 128, 48), "64x48x128": (64, 48, 128)}}
+        "gmm_tiles": {"64x128x48": (64, 128, 48), "64x48x128": (64, 48, 128)},
+        # off the chip a window returns by the gather (PR 57)
+        "moe_return": {"64x256x2x128": ("gather", None)}}
 
 
 def test_the_remat_rule_is_given_the_modules_sizes():

@@ -334,7 +334,9 @@ def test_num_params_flops_and_the_first_call_record():
         "rope_kernel": False, "rope_calls": 2,
         "remat_kept": [], "remat_kept_bytes": 0, "remat_room_bytes": None,
         "remat_routing_bytes": 4 * moe.routing_bytes(256, 16, 2),
-        "gmm_tiles": {"64x128x48": (64, 128, 48), "64x48x128": (64, 48, 128)}}
+        "gmm_tiles": {"64x128x48": (64, 128, 48), "64x48x128": (64, 48, 128)},
+        # off the chip a window returns by the gather (PR 57)
+        "moe_return": {"64x256x2x128": ("gather", None)}}
     assert all(f"``{key}``" in first_call.__doc__ for key in notes)
 
 

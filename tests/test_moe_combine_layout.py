@@ -3,7 +3,10 @@ k slots over the leading axis of what it gathers: the numbers against a plain
 float32 scatter-add, the pairing with ``_to_window`` as each other's
 transposes, and, compiled for a described v5e, that no (N, k, D) array is
 left for the layout to pad where k is no multiple of the sublane tile (6 and
-10 of the benchmark's cells; ``PERF.md``, PR 55).  CPU only: counts, no times.
+10 of the benchmark's cells; ``PERF.md``, PR 55); and, where the described
+chip's backend answers, that the six held-share cells' shapes compile to the
+kernel of ``ops/window_return.py`` with no array of all k x N slots left
+(PR 57).  CPU only: counts, no times.
 """
 
 import re
@@ -152,3 +155,37 @@ def test_the_layout_pads_no_slot_axis_on_the_v5e(one_chip, shape):
     assert not [line for line in entry.splitlines() if padded.search(line)]
     assert compiled.memory_analysis().temp_size_in_bytes \
         < 1.1 * tokens * k * width * 2
+
+
+@pytest.mark.parametrize("shape", [
+    (16384, 4, 2048), (8192, 8, 4096), (16384, 8, 2048), (16384, 6, 2688),
+    (8192, 10, 3072), (8192, 8, 2048)],
+    ids=["lfm2-ep4-s8192", "solar-open2-ep40-tp8", "sdar-ep8-s8192",
+         "nemotron-ep16-s8192", "laguna-ep32-s8192", "joyai-ep16-s8192"])
+def test_on_the_v5e_the_return_is_the_kernel_and_gathers_no_slot(
+        monkeypatch, one_chip, shape):
+    """At the six cells' shapes, compiled for a described v5e whose backend
+    says so of itself (nothing runs): one Mosaic call, the window's rows in
+    token order the one large temporary, and no array of k x N rows."""
+    tokens, k, width = shape
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def like(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    R, at = moe.window_rows(tokens * k), like((), jnp.int32)
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = jax.jit(lambda *window: moe._from_window(*window)).lower(
+            like((R, width), jnp.bfloat16), like((R,), jnp.int32),
+            like((tokens, k), jnp.int32), (at, at, at)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "moe_window_return" in text
+    assert not re.search(
+        rf"bf16\[({tokens * k},{width}|{k},{tokens},{width})\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 1.5 * R * width * 2

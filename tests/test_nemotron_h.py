@@ -350,7 +350,9 @@ def test_num_params_and_the_first_call_record():
         # four expert layers' routing, kept under every policy (PR 48)
         "remat_routing_bytes": 4 * moe.routing_bytes(256, 16, 2),
         # a window's products, up and down, and the tiles they walk (PR 50)
-        "gmm_tiles": {"64x128x64": (64, 128, 64), "64x64x128": (64, 64, 128)}}
+        "gmm_tiles": {"64x128x64": (64, 128, 64), "64x64x128": (64, 64, 128)},
+        # off the chip a window returns by the gather (PR 57)
+        "moe_return": {"64x256x2x128": ("gather", None)}}
 
 
 # -------------------------------------------------- (6) the 8-bit control

@@ -541,7 +541,8 @@ _LLAMA = _STEP | _REMAT | _ROPE | {
 _HYBRID = _STEP | _REMAT | {
     "layer_kinds", "loss_positions", "attn_positions", "heads_held",
     "heads_total", "attn_gate", "experts_held", "experts_total",
-    "router_scoring", "gmm_tiles"}  # the last since PR 50
+    "router_scoring", "gmm_tiles",  # since PR 50
+    "moe_return"}  # since PR 57: every hybrid preset holds a share
 #: the keys of each tiny family's first-call record (no splash kernel on the
 #: CPU, so no ``attn_*`` geometry), recorded on PR 46's parent; since PR 46
 #: ``tiny-nemotron-h`` also carries the attention kind's ``heads_held``,
@@ -549,7 +550,7 @@ _HYBRID = _STEP | _REMAT | {
 FIRST_CALL_KEYS = {
     "tiny-gpt2": _STEP,
     "tiny-llama": _LLAMA, "tiny-olmoe": _LLAMA | {"gmm_tiles"},
-    "tiny-sdar": _LLAMA | {"gmm_tiles"},
+    "tiny-sdar": _LLAMA | {"gmm_tiles", "moe_return"},
     "tiny-nemotron-h": _HYBRID | {
         "ssm_heads", "ssm_state", "ssm_chunk", "ssm_chunks",
         "ssm_scan_kernel", "ssm_scan_grid"},
