@@ -14,8 +14,10 @@ and YaRN's frequencies.  ``models/window.py`` is a second kind over these
 functions, with fields of its own in the place of some of these.  A chip may
 hold a share of the heads: a smaller
 ``n_head`` / ``n_kv_head`` (the matrices' columns for the heads held,
-``wo``'s rows), with ``n_head_total`` stating how many the model has.  The
-module has the interface ``hybrid.KINDS`` asks of a kind.
+``wo``'s rows), with ``n_head_total`` stating how many the model has.  With
+``norm_after`` the projections read ``x`` itself and ``attn_norm`` norms
+``wo``'s output before the residual add.  The module has the interface
+``hybrid.KINDS`` asks of a kind.
 """
 
 from __future__ import annotations
@@ -28,6 +30,9 @@ import jax.numpy as jnp
 
 from ray_tpu.models.layers import attention, stacked_normal
 from ray_tpu.ops import remat
+
+#: the kind reads ``norm_after`` (``models/layers.py:attention``)
+NORM_AFTER = True
 
 
 def init_params(config, key, n: int, out_std: float) -> Dict[str, Any]:
@@ -89,7 +94,7 @@ def matmul_params(config, routed: float) -> int:
 
 
 def num_params(config) -> int:
-    """Of one layer, its pre-norm included."""
+    """Of one layer, its norm included."""
     return matmul_params(config, 0) + config.d_model \
         + (sum(_qk_norm_widths(config)) if config.qk_norm else 0)
 

@@ -1,9 +1,10 @@
 """The dense feed-forward layer of a hybrid decoder (``models/hybrid.py``,
 kind ``D``): ``models/layers.py:feed_forward`` without experts, the SwiGLU
 MLP ``x + down(silu(gate h) * up h)`` over ``h = norm(x)`` that
-``llama._block`` runs too, with its parameters and its sizes: the leading
-layers of a model whose later layers hold experts
-(``first_k_dense_replace``).  Not a model.  ``gate`` and ``up`` are named for
+``llama._block`` runs too (with ``norm_after`` ``x + norm(down(silu(gate
+x) * up x))``), with its parameters and its sizes: the leading layers of a
+model whose later layers hold experts (``first_k_dense_replace``), or every
+layer's feed-forward part in a model without experts.  Not a model.  ``gate`` and ``up`` are named for
 ``ops/remat.py``.  The module has the interface ``hybrid.KINDS`` asks of a
 kind.
 """
@@ -18,6 +19,9 @@ import jax.numpy as jnp
 
 from ray_tpu.models.layers import feed_forward, stacked_normal
 from ray_tpu.ops import remat
+
+#: the kind reads ``norm_after`` (``models/layers.py:feed_forward``)
+NORM_AFTER = True
 
 
 def init_params(config, key, n: int, out_std: float) -> Dict[str, Any]:
@@ -43,7 +47,7 @@ def matmul_params(config, routed: float) -> int:
 
 
 def num_params(config) -> int:
-    """Of one layer, its pre-norm included."""
+    """Of one layer, its norm included."""
     return matmul_params(config, 0) + config.d_model
 
 

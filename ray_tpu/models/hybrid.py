@@ -1,5 +1,8 @@
 """A decoder that is a list of layer kinds: every layer is
-``x + mixer(norm(x))`` with the mixer one of :data:`KINDS`, in an order the
+``x + mixer(norm(x))``, or with ``norm_after`` (the Olmo 2 / Olmo 3
+family's block) ``x + norm(mixer(x))``: the kind's one norm weight
+multiplies the sub-layer's output before the residual add and the sub-layer
+reads ``x`` un-normed.  The mixer is one of :data:`KINDS`, in an order the
 configuration spells out (NVIDIA Nemotron-3-Nano's
 ``hybrid_override_pattern``, ``nemotron_h``; a Solar-Open2 layer, a token
 mixer and then experts, is two of these):
@@ -13,8 +16,9 @@ mixer and then experts, is two of these):
 - ``L``: latent attention (``models/mla.py``): queries and keys from two
   normed low-rank latents, one rotary key for all heads, a value head
   narrower than the q.k head;
-- ``D``: a dense SwiGLU MLP (``models/dense.py``), the leading layers of a
-  model whose later layers hold experts;
+- ``D``: a dense SwiGLU MLP (``models/dense.py``): the leading layers of a
+  model whose later layers hold experts, or every layer's feed-forward part
+  in a model without experts;
 - ``W``: attention over a causal band (``models/window.py`` over
   ``models/attn.py``), beside ``*`` in a stack whose full and window layers
   differ in head count and rotary settings (the ``laguna`` family's
@@ -22,7 +26,12 @@ mixer and then experts, is two of these):
 - ``C``: a gated short convolution (``models/shortconv.py``): a causal
   depthwise convolution of ``conv_taps`` taps between two element-wise
   gates, the token mixer of three layers in four of the ``lfm2_moe``
-  family, whose fourth is ``*`` with ``rope_theta`` and ``qk_norm``.
+  family, whose fourth is ``*`` with ``rope_theta`` and ``qk_norm``;
+- ``G``: a gated-delta-net mixer, the gated delta rule with one decay a
+  head and keys narrower than values (``models/gdn.py`` over
+  ``ops/gdn.py``), the ``linear_attention`` layers of the ``olmo_hybrid``
+  family, whose fourth layer is ``*`` without rotary embedding and with a
+  QK-norm over all heads, every layer under ``norm_after``.
 
 **A kind is a module** and one line of :data:`KINDS`; this file knows no
 kind by name.  The module (loaded with the first pattern that holds its
@@ -43,7 +52,10 @@ letter) answers, for a configuration with the fields it reads:
 - ``first_call_facts(config, rows, seq_len)``: what it notes for the
   first-call record (``util/first_call.py``);
 - ``layer(config, axes, index)``: layer ``index`` of the kind as (x, its row
-  of the stack) -> (x, the step counters it leaves or None).
+  of the stack) -> (x, the step counters it leaves or None);
+- ``NORM_AFTER = True`` where the kind reads ``norm_after`` (``*``, ``D``,
+  ``G``); a configuration that sets it over any other kind is refused, so
+  no kind grows a path no model uses.
 
 A chip may also hold a share of the **heads**: that is a smaller ``n_head``
 / ``n_kv_head`` / ``kda_heads`` (the matrices' columns for the heads held,
@@ -72,7 +84,7 @@ more row of its kinds' stacks.  A configuration without one (``mtp_depth``
 
 **Parameters.**  Layers of one kind share one stacked tree (``ssm``,
 ``kda``, ``attn``, ``experts``, ``mla``, ``dense``, ``window``,
-``shortconv``, each leaf with its kind's layers in front, the prediction
+``shortconv``, ``gdn``, each leaf with its kind's layers in front, the prediction
 module's after the pattern's), so the
 optimizer, the sharding rules and a checkpoint see a stack a kind and not
 ``len(pattern)`` trees.  The stack runs unrolled: layer i takes row
@@ -134,7 +146,8 @@ KINDS = {"M": Kind("ssm", "ray_tpu.models.mamba2", 2),
          "L": Kind("mla", "ray_tpu.models.mla", 6),
          "D": Kind("dense", "ray_tpu.models.dense", 7),
          "W": Kind("window", "ray_tpu.models.window", 8),
-         "C": Kind("shortconv", "ray_tpu.models.shortconv", 9)}
+         "C": Kind("shortconv", "ray_tpu.models.shortconv", 9),
+         "G": Kind("gdn", "ray_tpu.models.gdn", 10)}
 
 #: folded into ``init_params``' key for the prediction module's ``w_eh``
 MTP_DRAW = 47
@@ -230,6 +243,15 @@ class HybridConfig:
     window_rope_theta: Optional[float] = 10000.0
     # ``C``: models/shortconv.py, ``d_model`` wide
     conv_taps: int = 3
+    # ``G``: models/gdn.py (its step sizes are ``time_step_*`` above)
+    gdn_heads: int = 4
+    gdn_key_dim: int = 12
+    gdn_value_dim: int = 24
+    gdn_chunk: int = 64
+    gdn_conv: int = 4
+    #: every layer is ``x + norm(f(x))`` and not ``x + f(norm(x))``: the
+    #: family's block, read by the kinds that state ``NORM_AFTER``
+    norm_after: bool = False
     #: the head is the embedding: no ``lm_head`` leaf
     tie_head: bool = False
     #: multi-token-prediction modules after the last layer (0 or 1) and the
@@ -321,8 +343,26 @@ class HybridConfig:
             expert_activation="silu", gated_experts=True,
             routed_scaling=1.0, router_bias_std=0.05, tie_head=True)
 
+    @staticmethod
+    def tiny_olmo_hybrid() -> "HybridConfig":
+        """Olmo-Hybrid-7B's shape in small: one period of four layers, each
+        a mixer and then a dense MLP (three gated-delta-net layers, 4 heads
+        with keys of 12 under values of 24, then attention without rotary
+        embedding, 4 heads of 32 with an RMSNorm over all of q and of k),
+        the norm after every sub-layer, no experts, an untied head."""
+        return HybridConfig(
+            pattern="GDGDGD*D", rms_eps=1e-6, norm_after=True, n_kv_head=4,
+            qk_norm=True, gdn_chunk=32)
+
     def __post_init__(self):
         assert self.pattern and set(self.pattern) <= set(KINDS), self.pattern
+        if self.norm_after:
+            deaf = [kind for kind, entry in _kinds(self).items()
+                    if not getattr(entry.module, "NORM_AFTER", False)]
+            if deaf:
+                raise ValueError(
+                    f"norm_after over the kinds {deaf} of pattern "
+                    f"{self.pattern!r}: their modules do not read it")
         assert self.mtp_depth in (0, 1), self.mtp_depth
         held = self.held
         assert held.step == 1 and 0 <= held.start < held.stop \
@@ -390,7 +430,7 @@ def flops_per_token(config: HybridConfig) -> float:
     """Per trained token: 6 x the matrix parameters a position meets (of the
     held experts its own, in expectation under an even router) plus 3 x what
     each layer's mixer adds beside them (causal attention, the state-space
-    scan's products, the delta rule's, a short convolution's taps and
+    scan's products, either delta rule's, a short convolution's taps and
     gates).  The head counts once, tied or not: the embedding is a gather.
     A prediction module adds its block's layers, ``w_eh`` and a second pass
     through the head."""

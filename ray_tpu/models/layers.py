@@ -320,8 +320,10 @@ def attention(x, blk, config, axes):
     band's layers run under the scope ``window`` inside ``attn``);
     ``rope_rotary``, how many of a head's first lanes rotate (None: all);
     ``rope_yarn``, a :class:`Yarn` whose table and scale the rotary pass
-    takes."""
+    takes; ``norm_after``: the projections read ``x`` itself and
+    ``attn_norm`` norms ``wo``'s output, ``x + norm(wo(...))``."""
     dt = config.dtype
+    after = getattr(config, "norm_after", False)
     B, S, D = x.shape
     H, KV, hd = config.n_head, config.n_kv_head, config.head_dim
     window = getattr(config, "attn_window", 0)
@@ -343,7 +345,8 @@ def attention(x, blk, config, axes):
             inv_freq=yarn and yarn.inv_freq(rotary or hd, config.rope_theta))
     with jax.named_scope("attn"), jax.named_scope("window") if window \
             else contextlib.nullcontext():
-        h = rmsnorm(x, blk["attn_norm"], config.rms_eps).astype(dt)
+        h = x if after else rmsnorm(x, blk["attn_norm"],
+                                    config.rms_eps).astype(dt)
         q = dense(h, blk, "wq", axes, dt)
         k = dense(h, blk, "wk", axes, dt)
         if config.qk_norm == "head":
@@ -377,7 +380,10 @@ def attention(x, blk, config, axes):
             if gate.shape[-1] == H:  # a scalar a head
                 gate = jnp.repeat(gate, hd, axis=-1)
             attn = (attn.astype(jnp.float32) * gate).astype(dt)
-        return x + dense(attn, blk, "wo", axes, dt)
+        out = dense(attn, blk, "wo", axes, dt)
+        if after:
+            out = rmsnorm(out, blk["attn_norm"], config.rms_eps).astype(dt)
+        return x + out
 
 
 def feed_forward(x, blk, config, axes, **expert_layer):
@@ -387,10 +393,14 @@ def feed_forward(x, blk, config, axes, **expert_layer):
     counts: ``moe.moe_mlp``).  Reads ``dtype``, ``rms_eps`` and, with experts,
     ``experts_per_token``, ``norm_topk_prob`` and ``held``; of ``blk``
     ``mlp_norm`` and the SwiGLU's ``w_gate``, ``w_up``, ``w_down``, or what
-    ``moe.moe_mlp`` reads, which is also handed ``expert_layer``."""
+    ``moe.moe_mlp`` reads, which is also handed ``expert_layer``.  Where the
+    configuration has ``norm_after`` the dense MLP reads ``x`` itself and
+    ``mlp_norm`` norms its output, ``x + norm(down(...))`` (no expert layer
+    has it)."""
     dt = config.dtype
+    after = getattr(config, "norm_after", False)
     with jax.named_scope("mlp"):
-        h = rmsnorm(x, blk["mlp_norm"], config.rms_eps)
+        h = x if after else rmsnorm(x, blk["mlp_norm"], config.rms_eps)
         if "router" in blk:
             # the router reads the norm's float32 output, the experts its
             # cast to the compute dtype
@@ -404,5 +414,8 @@ def feed_forward(x, blk, config, axes, **expert_layer):
                                remat.GATE_UP)
         up = checkpoint_name(dense(h, blk, "w_up", axes, dt), remat.GATE_UP)
         act = jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
-        x = x + dense(act.astype(dt), blk, "w_down", axes, dt)
+        out = dense(act.astype(dt), blk, "w_down", axes, dt)
+        if after:
+            out = rmsnorm(out, blk["mlp_norm"], config.rms_eps).astype(dt)
+        x = x + out
     return x, None

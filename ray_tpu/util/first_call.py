@@ -89,6 +89,9 @@ and ``seconds``, the span ``compile_s``):
 ``models/dense.py`` (``D``)   ``dense_width``
 ``models/shortconv.py``       ``shortconv_taps``, ``shortconv_width``,
 (``C``)                       ``shortconv_layers``
+``models/gdn.py`` (``G``)     ``gdn_heads``, ``gdn_key_dim``,
+                              ``gdn_value_dim``, ``gdn_chunk``,
+                              ``gdn_chunks``
 ============================  ==============================================
 """
 

@@ -226,6 +226,14 @@ SCOPE_REGISTRY: Dict[str, str] = {
                       "over positions and the output gate, one pass forward "
                       "and one backward, which makes the gate and the taps "
                       "again",
+    "gdn": "a gated-delta-net layer (models/gdn.py), the gated delta rule "
+           "with one decay a head: projections, L2 norms, the decay, beta, "
+           "the head norm, the output gate, out-projection and the norm "
+           "(around the two scopes below)",
+    "gdn_conv": "gated-delta-net layer, nested inside gdn: the three causal "
+                "depthwise convolutions over positions and the silu",
+    "gdn_scan": "gated-delta-net layer, nested inside gdn: the chunked "
+                "delta-rule scan (ops/gdn.py), whatever implements it",
     "noise": "block-diffusion training (models/block_diffusion.py): the "
              "draw of the masked positions, the noised copy, the "
              "concatenation with the clean one, the loss weights",

@@ -6,7 +6,8 @@ clock, and the step profiler's counters.
 * each model family's lowered step holds every registered scope that is its
   own (the three expert-layer scopes are the llama step's with experts; the
   Mamba-2 scopes and the shared expert's are the hybrid step's, the KDA
-  scopes those of a hybrid step whose pattern holds ``K``);
+  scopes those of a hybrid step whose pattern holds ``K``, the
+  gated-delta-net scopes those of one whose pattern holds ``G``);
 * ``classify_op_name`` on real ``op_name`` strings of the compiled v5e steps
   (``tests/data/v5e_step_op_names.json``) and ``parse_anatomy`` on an
   excerpt of that module's text (``tests/data/v5e_step_excerpt.hlo.txt``);
@@ -64,27 +65,36 @@ MLA_SCOPES = {"latent", "mtp", "mtp_head"}
 WINDOW_SCOPES = {"window"}
 #: scopes only a stack with gated short-convolution layers opens
 CONV_SCOPES = {"shortconv", "shortconv_gate"}
+#: scopes only a stack with gated-delta-net layers opens
+GDN_SCOPES = {"gdn", "gdn_conv", "gdn_scan"}
 HYBRID_SCOPES = SSM_SCOPES | KDA_SCOPES | MLA_SCOPES | WINDOW_SCOPES \
-    | CONV_SCOPES | {"shared_expert"}
+    | CONV_SCOPES | GDN_SCOPES | {"shared_expert"}
 
 
 def _scopes_of(family):
     if family == "hybrid":  # holds a share, trains next tokens
         return set(tracing.SCOPE_REGISTRY) - {"experts", "noise"} \
-            - KDA_SCOPES - MLA_SCOPES - WINDOW_SCOPES - CONV_SCOPES
+            - KDA_SCOPES - MLA_SCOPES - WINDOW_SCOPES - CONV_SCOPES \
+            - GDN_SCOPES
     if family == "hybrid-kda":
         return set(tracing.SCOPE_REGISTRY) - {"experts", "noise"} \
-            - SSM_SCOPES - MLA_SCOPES - WINDOW_SCOPES - CONV_SCOPES
+            - SSM_SCOPES - MLA_SCOPES - WINDOW_SCOPES - CONV_SCOPES \
+            - GDN_SCOPES
     if family == "hybrid-mla":
         return set(tracing.SCOPE_REGISTRY) - {"experts", "noise"} \
-            - SSM_SCOPES - KDA_SCOPES - WINDOW_SCOPES - CONV_SCOPES
+            - SSM_SCOPES - KDA_SCOPES - WINDOW_SCOPES - CONV_SCOPES \
+            - GDN_SCOPES
     if family == "hybrid-window":
         return set(tracing.SCOPE_REGISTRY) - {"experts", "noise"} \
-            - SSM_SCOPES - KDA_SCOPES - MLA_SCOPES - CONV_SCOPES
+            - SSM_SCOPES - KDA_SCOPES - MLA_SCOPES - CONV_SCOPES \
+            - GDN_SCOPES
     if family == "hybrid-conv":  # no shared expert beside its routed ones
         return set(tracing.SCOPE_REGISTRY) - {"experts", "noise"} \
             - SSM_SCOPES - KDA_SCOPES - MLA_SCOPES - WINDOW_SCOPES \
-            - {"shared_expert"}
+            - GDN_SCOPES - {"shared_expert"}
+    if family == "hybrid-gdn":  # dense: no expert layer at all
+        return set(tracing.SCOPE_REGISTRY) - SDAR_SCOPES - MOE_SCOPES \
+            - (HYBRID_SCOPES - GDN_SCOPES)
     if family == "llama-sdar":
         return set(tracing.SCOPE_REGISTRY) - {"experts"} - HYBRID_SCOPES
     return set(tracing.SCOPE_REGISTRY) - SDAR_SCOPES - HYBRID_SCOPES - (
@@ -120,6 +130,10 @@ def _family(name):
         from ray_tpu.models import hybrid
 
         return hybrid, hybrid.HybridConfig.tiny_lfm2()
+    if name == "hybrid-gdn":  # what olmo-hybrid-s8192 runs
+        from ray_tpu.models import hybrid
+
+        return hybrid, hybrid.HybridConfig.tiny_olmo_hybrid()
     config = gpt2.GPTConfig.tiny()
     if name == "gpt2-attn-outside-unrolled":  # what gpt2xl-s1024 runs
         import dataclasses
@@ -155,6 +169,7 @@ def _tiny_step(name="llama"):
 @pytest.mark.parametrize("family", ["llama", "llama-moe", "llama-sdar",
                                     "hybrid", "hybrid-kda", "hybrid-mla",
                                     "hybrid-window", "hybrid-conv",
+                                    "hybrid-gdn",
                                     "gpt2", "gpt2-attn-outside-unrolled"])
 def test_lowered_step_holds_every_registered_scope(family):
     import jax
@@ -237,6 +252,7 @@ def test_parse_anatomy_on_v5e_module_excerpt():
 @pytest.mark.parametrize("family", ["llama", "llama-moe", "llama-sdar",
                                     "hybrid", "hybrid-kda", "hybrid-mla",
                                     "hybrid-window", "hybrid-conv",
+                                    "hybrid-gdn",
                                     "gpt2-attn-outside-unrolled"])
 def test_anatomy_of_a_tiny_cpu_step_end_to_end(family):
     from ray_tpu.parallel.train_state import PHASES
@@ -563,6 +579,11 @@ FIRST_CALL_KEYS = {
     "tiny-lfm2": _HYBRID | _ROPE | {
         "dense_width", "qk_norm", "shortconv_taps", "shortconv_width",
         "shortconv_layers"},
+    # dense: no expert layer's keys, no rotary pass's
+    "tiny-olmo-hybrid": _STEP | _REMAT | {
+        "layer_kinds", "loss_positions", "attn_positions", "heads_held",
+        "heads_total", "attn_gate", "qk_norm", "dense_width", "gdn_heads",
+        "gdn_key_dim", "gdn_value_dim", "gdn_chunk", "gdn_chunks"},
 }
 
 
