@@ -440,12 +440,13 @@ def run_kernel(name: str, kernel_fn, reference_fn, args, tols,
 
 
 def kernels_phase(attn_shape, ring_shape, ssd_shape, kda_shape,
-                  rope_shape) -> list:
+                  rope_shape, gdn_shape) -> list:
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.models import layers
     from ray_tpu.ops.attention import causal_attention, splash_attention
+    from ray_tpu.ops.gdn import gdn, gdn_xla
     from ray_tpu.ops.kda import kda, kda_xla
     from ray_tpu.ops.ring_attention import ring_attention
     from ray_tpu.ops.ssd import ssd, ssd_xla
@@ -515,8 +516,8 @@ def kernels_phase(attn_shape, ring_shape, ssd_shape, kda_shape,
     b, S, H, d, chunk = kda_shape
     keys = jax.random.split(jax.random.key(13), 5)
 
-    def unit(key, shift):
-        x = jax.random.normal(key, (b, S, H, d)) + shift
+    def unit(key, shift, shape=None):
+        x = jax.random.normal(key, shape or (b, S, H, d)) + shift
         return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True))
 
     delta_args = (
@@ -531,6 +532,25 @@ def kernels_phase(attn_shape, ring_shape, ssd_shape, kda_shape,
         f"kda scan fwd+grad {kda_shape}",
         with_grads(lambda *a: kda(*a, chunk)),
         with_grads(lambda *a: kda_xla(*a, chunk)), delta_args,
+        (FWD_TOL,) + (GRAD_TOL,) * 5, want_mosaic=4))
+
+    # the same rule with one decay a head (``gdn`` takes its kernels at this
+    # shape: keys and values off the lane tiles), drawn the same way
+    b, S, H, dk, dv, chunk = gdn_shape
+    keys = jax.random.split(jax.random.key(15), 5)
+    delta_args = (
+        (unit(keys[0], 0.0, (b, S, H, dk)) * dk ** -0.5).astype(jnp.bfloat16),
+        unit(keys[1], 0.5, (b, S, H, dk)).astype(jnp.bfloat16),
+        normal(16, (b, S, H, dv)),
+        -jax.random.uniform(keys[2], (1, 1, H), minval=1.0, maxval=16.0)
+        * jnp.exp(jax.random.uniform(keys[3], (b, S, H),
+                                     minval=math.log(1e-3),
+                                     maxval=math.log(0.1))),
+        2.0 * jax.nn.sigmoid(jax.random.normal(keys[4], (b, S, H))))
+    rows.append(run_kernel(
+        f"gdn scan fwd+grad {gdn_shape}",
+        with_grads(lambda *a: gdn(*a, chunk)),
+        with_grads(lambda *a: gdn_xla(*a, chunk)), delta_args,
         (FWD_TOL,) + (GRAD_TOL,) * 5, want_mosaic=4))
 
     # the rotary pass's kernel over q and k in one call (``rope`` takes it
@@ -602,7 +622,10 @@ def main() -> int:
             kda_shape=(1, 8192, 8, 128, 64),
             # a full layer's q and k in ``laguna-ep32-s8192``: rows,
             # positions, the two arrays' heads, head_dim, the lanes that turn
-            rope_shape=(1, 8192, (48, 8), 128, 64))
+            rope_shape=(1, 8192, (48, 8), 128, 64),
+            # a gated-delta-net layer's scan in ``olmo-hybrid-s8192``: rows,
+            # positions, heads, a head's keys, its values, chunk
+            gdn_shape=(1, 8192, 30, 96, 192, 64))
         check_kernels_on_chip(kernels)
     finally:
         ray_tpu.shutdown()

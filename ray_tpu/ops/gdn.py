@@ -42,20 +42,58 @@ less than -88 underflows to zero where it should and nothing overflows.
 multiply in q's dtype (bfloat16 in a training step) and accumulate in
 float32.
 
-The backward is autodiff through this form: each product's transpose is a
-product of the same shape and the scan over the chunk states transposes into
-the reverse scan over their cotangents.  XLA writes ``Gamma``, ``A`` and
-``B`` to HBM, three (C x C) float32 arrays a head a chunk; a Mosaic kernel
-that keeps them in VMEM at keys of 96 lanes is ROADMAP B's.
+**Two implementations of the one algorithm**, chosen by what the call can
+observe (:func:`path`), with no argument, configuration field or environment
+variable to pick one:
+
+- ``kernel``: ``ops/gdn_kernel.py``, Pallas kernels over a grid of (row,
+  heads, chunk) that keep ``Gamma``, ``B``, every scaled copy of q and k,
+  ``U`` and the carried state in VMEM (``A`` and ``T`` cross HBM, 16 KB each
+  a head a chunk of 64: the triangular system stays XLA's) and read q, k and
+  v in the projections' own layout, a head's columns sliced at its lane
+  offset, so that no heads-major copy is made.  Taken where the kernels were
+  compiled for the v5e: keys and values of whole sublane tiles of 8 lanes
+  (the published 96 under 192; 128 under 128 and 128 under 256, the
+  Qwen3-Next family's; 64 under 128), at least :data:`NARROWEST` wide (under
+  that a head is a sliver of a lane tile and the XLA form's batched products
+  are no worse: ``tiny_olmo_hybrid``'s heads of 12 under 24), chunks of whole
+  sublane tiles of q's dtype, 16 to 128 positions, and a step's blocks
+  inside :data:`gdn_kernel.VMEM_MOST`; and where a Mosaic call may sit: no
+  mesh, a mesh of one device, or a mesh whose `data` / `fsdp` axes divide
+  the rows and whose `tensor` axis divides the heads, under which the
+  kernels run inside a ``shard_map`` over those axes
+  (``ops/placement.py``).  Its backward is written out (one
+  ``custom_vjp``); the residuals are the inputs, ``A``, the inverse, ``T``
+  and each chunk's incoming state.
+- ``xla``: :func:`gdn_xla`, the einsum form above, for every other shape and
+  placement and as the kernels' oracle in the tests.  Its backward is
+  autodiff through this form: each product's transpose is a product of the
+  same shape and the scan over the chunk states transposes into the reverse
+  scan over their cotangents.  XLA writes ``Gamma``, ``A`` and ``B`` (three
+  (C x C) float32 arrays a head a chunk), ``T`` and every scaled copy of q
+  and k to HBM and turns q, k, v heads-major around the products: 108 ms a
+  step in ``olmo-hybrid-s8192`` at 11.5 % of what the chip allows, which is
+  what the kernels are for (PERF.md, PRs 58 and 59).
+
+Under the layer's ``jax.checkpoint`` nothing of a chunk outlives the layer's
+pass on either path.  The first-call record says which ran
+(``gdn_scan_kernel``) and over what grid (``gdn_scan_grid``).
 """
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from ray_tpu.ops import gdn_kernel
 from ray_tpu.ops.kda import _unit_lower_inverse
+from ray_tpu.ops.placement import place, rows_and_heads
+from ray_tpu.util import first_call
+
+#: the narrowest keys and values the kernels take
+NARROWEST = 64
 
 
 def gdn(q, k, v, g, beta, chunk: int):
@@ -63,12 +101,42 @@ def gdn(q, k, v, g, beta, chunk: int):
     non-positive, the log of the decay a head; beta: (b, S, H) float32.  S a
     multiple of ``chunk``, ``chunk`` a power of two.  -> o (b, S, H, dv) in
     q's dtype; the state before a row's first position is zero."""
+    S = q.shape[1]
+    if S % chunk or chunk & (chunk - 1):
+        raise ValueError(f"gdn: {S} positions in chunks of {chunk} (a power "
+                         "of two that divides them)")
+    if path(q.shape, v.shape, chunk,
+            jax.sharding.get_abstract_mesh()) == "xla":
+        first_call.note(gdn_scan_kernel=False, gdn_scan_grid=None)
+        return gdn_xla(q, k, v, g, beta, chunk)
+
+    def local(q, k, v, g, beta):
+        first_call.note(gdn_scan_kernel=True,
+                        gdn_scan_grid=list(gdn_kernel.grid(q, v, chunk)))
+        return gdn_kernel.scan(q, k, v, g, beta, chunk)
+
+    return place(local, (q, k, v, g, beta), ("rh",) * 5, "rh")
+
+
+def path(q_shape, v_shape, chunk: int, mesh) -> str:
+    """-> ``"kernel"`` or ``"xla"``: which implementation a call of these
+    shapes takes under ``mesh`` (the module's docstring has the rule; the
+    mesh's half of it is ``ops.placement.rows_and_heads``)."""
+    b, _, H, dk = q_shape
+    dv = v_shape[-1]
+    cut = rows_and_heads(mesh, b, H)
+    if cut is None or dk % 8 or dv % 8 or min(dk, dv) < NARROWEST \
+            or chunk % 16 or chunk > 128:
+        return "xla"  # the sizes; or positions, or nothing it knows, are cut
+    heads = H // (mesh.shape[cut[1]] if cut[1] else 1)  # a chip's own
+    return "kernel" if gdn_kernel.fits(heads, dk, dv, chunk) else "xla"
+
+
+def gdn_xla(q, k, v, g, beta, chunk: int):
+    """:func:`gdn` as einsums and one ``lax.scan`` over the chunk states."""
     b, S, H, dk = q.shape
     dv = v.shape[-1]
     C = chunk
-    if S % C or C & (C - 1):
-        raise ValueError(f"gdn: {S} positions in chunks of {C} (a power of "
-                         "two that divides them)")
     n, dt, f32 = S // C, q.dtype, jnp.float32
     # heads in front of the positions: every product is over (b, n, H)
     qc, kc = (jnp.moveaxis(a.reshape(b, n, C, H, dk), 3, 2) for a in (q, k))

@@ -55,6 +55,7 @@ and ``seconds``, the span ``compile_s``):
                               the XLA form), ``ssm_scan_grid`` (the
                               kernels' grid a chip, or None)
 ``ops/kda.py``                ``kda_scan_kernel``, ``kda_scan_grid``
+``ops/gdn.py``                ``gdn_scan_kernel``, ``gdn_scan_grid``
 ``models/layers.py``          where a layer rotates (``rope``):
                               ``rope_kernel`` (the lane roll of
                               ``ops/rope_kernel.py``, or the product with a

@@ -329,6 +329,65 @@ def test_the_kda_kernels_compile_for_the_v5e(monkeypatch, one_chip):
     assert backward < 8 * 2 ** 20  # of 16 MiB
 
 
+@pytest.mark.parametrize("b,S,H,dk,dv,C", [
+    (1, 8192, 30, 96, 192, 64),     # olmo-hybrid-s8192
+    (1, 1024, 30, 96, 192, 128), (1, 512, 30, 96, 192, 16),
+    (1, 1024, 16, 128, 256, 64), (1, 1024, 32, 128, 128, 64),
+    (1, 1024, 6, 64, 128, 64)],
+    ids=["the-cell", "chunks-of-128", "chunks-of-16", "128-under-256",
+         "128s", "64-under-128"])
+def test_the_gdn_kernels_compile_for_the_v5e(monkeypatch, one_chip, b, S, H,
+                                             dk, dv, C):
+    """The gated-delta-net scan's kernels (``ops/gdn_kernel.py``) at the
+    shape ``olmo-hybrid-s8192`` runs them (one row of 8192, 30 heads with
+    keys of 96 under values of 192, sliced off the lane tiles, chunks of 64,
+    bf16) and at the other widths and chunks ``ops.gdn.path`` admits,
+    compiled for a described v5e under the layer's checkpoint: ``A`` and the
+    scan with the outputs, each forward, forward again and backward, every
+    call under the caller's ``gdn_scan`` scope in its own phase
+    (``step.gdn_scan_ms`` reads the scope), and what the kernels ask of VMEM
+    themselves inside what they tell the compiler."""
+    import re
+
+    from ray_tpu.ops import gdn, gdn_kernel
+    from ray_tpu.parallel.train_state import classify_op_name
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    keys, values = (shape((b, S, H, d), jnp.bfloat16) for d in (dk, dv))
+    args = (keys, keys, values, shape((b, S, H), jnp.float32),
+            shape((b, S, H), jnp.float32))
+    assert gdn.path(keys.shape, values.shape, C,
+                    jax.sharding.get_abstract_mesh()) == "kernel"
+    heads = gdn_kernel.heads_a_step(H, dk, dv)
+    assert gdn_kernel.grid(keys, values, C) == (b, H // heads, S // C)
+
+    def scan(*inputs):
+        with jax.named_scope("gdn_scan"):  # as models/gdn.py:mixer
+            return gdn.gdn(*inputs, C)
+
+    def both(*inputs):
+        o, pull = jax.vjp(jax.checkpoint(scan), *inputs)
+        return pull(o)
+
+    try:
+        compiled = jax.jit(both).lower(*args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    calls = [line for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line]
+    filed = sorted(classify_op_name(
+        re.search(r'op_name="([^"]*)"', line).group(1)) for line in calls)
+    assert filed == [(phase, "gdn_scan") for phase in
+                     ("backward",) * 2 + ("forward",) * 2 + ("recompute",) * 2]
+    assert gdn_kernel.fits(H, dk, dv, C)
+
+
 def test_the_grouped_products_compile_for_the_v5e(monkeypatch, one_chip):
     """The expert layers' grouped products (``ops/grouped_matmul.py``: the
     forward, dx and dW of up and of down) at the four held-share cells' full

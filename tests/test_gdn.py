@@ -4,7 +4,9 @@ against the recurrence one position after the other in float32
 sum, no triangular system): outputs and all five gradients, over chunks of
 16, 64 and the whole row, keys narrower and wider than values, ``beta`` near
 2, a decay that sums below -88 inside a chunk, float32 and bfloat16.
-Everything on the CPU at small sizes."""
+Everything on the CPU at small sizes, heads too narrow for the kernels of
+``ops/gdn_kernel.py`` (``tests/test_gdn_kernel.py`` has those): every call
+here takes the XLA form, through ``gdn`` as a layer calls it."""
 
 import jax
 import jax.numpy as jnp
@@ -46,6 +48,9 @@ def _both(chunk, dtype, args):
     """((o, the five gradients) by the chunked form on ``dtype`` operands,
     the same by the recurrence in float32), under one probe."""
     probe = jax.random.normal(jax.random.key(9), args[2].shape)
+
+    assert gdn_ops.path(args[0].shape, args[2].shape, chunk,
+                        jax.sharding.get_abstract_mesh()) == "xla"
 
     def ours(q, k, v, g, beta):
         o = gdn(q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta,
@@ -133,4 +138,6 @@ def test_the_inverse_is_kdas_and_not_a_copy():
     assert gdn_ops._unit_lower_inverse is kda_ops._unit_lower_inverse
     source = open(gdn_ops.__file__).read()
     assert "def _unit_lower_inverse" not in source
-    assert "pallas" not in source  # one form: XLA's
+    assert "pallas" not in source  # the kernels are ops/gdn_kernel.py's
+    from ray_tpu.ops import gdn_kernel
+    assert gdn_kernel._unit_lower_inverse is kda_ops._unit_lower_inverse

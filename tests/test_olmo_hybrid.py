@@ -318,6 +318,7 @@ def test_flops_by_hand_and_the_first_call_record():
         "attn_gate": False, "qk_norm": True, "dense_width": 256,
         "gdn_heads": 4, "gdn_key_dim": 12, "gdn_value_dim": 24,
         "gdn_chunk": 32, "gdn_chunks": 2 * 128 // 32,
+        "gdn_scan_kernel": False, "gdn_scan_grid": None,  # heads of 12
         "remat_kept": [], "remat_kept_bytes": 0, "remat_room_bytes": None,
         "remat_routing_bytes": 0}
     assert all(f"``{key}``" in first_call.__doc__ for key in notes)

@@ -583,7 +583,8 @@ FIRST_CALL_KEYS = {
     "tiny-olmo-hybrid": _STEP | _REMAT | {
         "layer_kinds", "loss_positions", "attn_positions", "heads_held",
         "heads_total", "attn_gate", "qk_norm", "dense_width", "gdn_heads",
-        "gdn_key_dim", "gdn_value_dim", "gdn_chunk", "gdn_chunks"},
+        "gdn_key_dim", "gdn_value_dim", "gdn_chunk", "gdn_chunks",
+        "gdn_scan_kernel", "gdn_scan_grid"},  # the last two since PR 59
 }
 
 
