@@ -282,78 +282,6 @@ class HybridConfig:
         """Of the kind's stack: the pattern's layers, then the module's."""
         return self.count(kind) + self.mtp_kinds.count(kind)
 
-    @staticmethod
-    def tiny() -> "HybridConfig":
-        """Nemotron-3-Nano's shape in small: the cut's own pattern, 8 heads
-        of 16 in 2 groups over a state of 16 in chunks of 32, GQA without
-        rotary, experts 4-7 of 16 held, 2 a token, a shared expert."""
-        return HybridConfig(experts_held=range(4, 8), router_bias_std=0.05)
-
-    @staticmethod
-    def tiny_solar() -> "HybridConfig":
-        """Solar-Open2's shape in small: one period of four layers, each a
-        mixer and then experts (a gated attention layer without rotary, then
-        three KDA layers), heads 2-3 of 8 and experts 4-7 of 16 held, 2 a
-        token, SwiGLU experts and shared expert, no selection bias."""
-        return HybridConfig(
-            pattern="*EKEKEKE", n_head=2, n_kv_head=1, n_head_total=8,
-            attn_gate=True, kda_heads=2, kda_chunk=32,
-            experts_held=range(4, 8), d_ff=48, shared_width=48,
-            expert_activation="silu", gated_experts=True, routed_scaling=1.0)
-
-    @staticmethod
-    def tiny_joyai() -> "HybridConfig":
-        """JoyAI-LLM-Flash's shape in small: a dense layer and two expert
-        layers, each after latent attention (4 heads of 16 + 8 for q.k and
-        16 for v over latents of 48 and 32), experts 4-7 of 16 held, 2 a
-        token, SwiGLU experts and shared expert, a selection bias, and one
-        prediction module."""
-        return HybridConfig(
-            pattern="LDLELE", mtp_depth=1, experts_held=range(4, 8), d_ff=48,
-            shared_width=48, expert_activation="silu", gated_experts=True,
-            router_bias_std=0.05)
-
-    @staticmethod
-    def tiny_laguna() -> "HybridConfig":
-        """Laguna-S's shape in small: a full attention layer and a dense
-        one, three window layers each before experts, a full layer before
-        experts; 4 heads (full) and 6 (window, 16 keys) of 32 over 2 key
-        heads, a gate a head, the full layers' first 16 lanes rotating under
-        YaRN and the window layers' whole head plainly, experts 4-7 of 16
-        held, 2 a token, SwiGLU experts and shared expert, no selection
-        bias."""
-        return HybridConfig(
-            pattern="*DWEWEWE*E", rms_eps=1e-6, attn_gate="head",
-            rope_theta=500000.0, rope_rotary=16,
-            rope_yarn=Yarn(factor=4.0, original=64, beta_fast=4.0),
-            window_heads=6, experts_held=range(4, 8), d_ff=48,
-            shared_width=48, expert_activation="silu", gated_experts=True)
-
-    @staticmethod
-    def tiny_lfm2() -> "HybridConfig":
-        """LFM2-8B-A1B's shape in small: a gated short convolution of three
-        taps before a dense layer, then attention (4 heads of 32 over 2 key
-        heads, rotary over the whole head, an RMSNorm a head on q and k)
-        and convolutions in turn, each before experts; experts 4-7 of 16
-        held, 2 a token, SwiGLU, no shared expert, a selection bias, weights
-        that sum to one unscaled, a tied head."""
-        return HybridConfig(
-            pattern="CD*ECE*ECE", rope_theta=1000000.0, qk_norm="head",
-            experts_held=range(4, 8), d_ff=48, shared_width=0,
-            expert_activation="silu", gated_experts=True,
-            routed_scaling=1.0, router_bias_std=0.05, tie_head=True)
-
-    @staticmethod
-    def tiny_olmo_hybrid() -> "HybridConfig":
-        """Olmo-Hybrid-7B's shape in small: one period of four layers, each
-        a mixer and then a dense MLP (three gated-delta-net layers, 4 heads
-        with keys of 12 under values of 24, then attention without rotary
-        embedding, 4 heads of 32 with an RMSNorm over all of q and of k),
-        the norm after every sub-layer, no experts, an untied head."""
-        return HybridConfig(
-            pattern="GDGDGD*D", rms_eps=1e-6, norm_after=True, n_kv_head=4,
-            qk_norm=True, gdn_chunk=32)
-
     def __post_init__(self):
         assert self.pattern and set(self.pattern) <= set(KINDS), self.pattern
         if self.norm_after:

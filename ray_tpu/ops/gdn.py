@@ -56,8 +56,8 @@ variable to pick one:
   (the published 96 under 192; 128 under 128 and 128 under 256, the
   Qwen3-Next family's; 64 under 128), at least :data:`NARROWEST` wide (under
   that a head is a sliver of a lane tile and the XLA form's batched products
-  are no worse: ``tiny_olmo_hybrid``'s heads of 12 under 24), chunks of whole
-  sublane tiles of q's dtype, 16 to 128 positions, and a step's blocks
+  are no worse: ``tiny-olmo-hybrid.json``'s heads of 12 under 24), chunks of
+  whole sublane tiles of q's dtype, 16 to 128 positions, and a step's blocks
   inside :data:`gdn_kernel.VMEM_MOST`; and where a Mosaic call may sit: no
   mesh, a mesh of one device, or a mesh whose `data` / `fsdp` axes divide
   the rows and whose `tensor` axis divides the heads, under which the

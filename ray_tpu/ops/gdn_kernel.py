@@ -35,9 +35,10 @@ a grid step all H heads' columns of a chunk, and slice a head out at the
 lane offset ``j d`` (Mosaic shifts the lanes of the three heads in four that
 start inside a tile): no heads-major copy is made on either side of a call
 and no padding reaches HBM.  ``scripts/gdn_scan_sweep.py`` times this
-against heads-major operands and against keys padded to 128 (PERF.md, PR
-59).  Where ``heads x dk`` and ``heads x dv`` are whole lane tiles for a
-divisor of H, a grid step may take that many heads (:func:`heads_a_step`).
+against keys padded to 128; heads-major operands, turned by XLA, lost that
+sweep and their arm left the kernels with PR 60 (PERF.md, PR 59).  Where
+``heads x dk`` and ``heads x dv`` are whole lane tiles for a divisor of H, a
+grid step may take that many heads (:func:`heads_a_step`).
 
 **``G`` in two layouts**, formed once by XLA (a product with a triangle of
 ones at full float32 precision): positions down the sublanes, (C, heads),
@@ -75,10 +76,7 @@ from ray_tpu.ops.ssd_kernel import LANES, _F32, _NT, _TN, _dot, _interpret
 
 def _head(ref, j: int, d: int):
     """Head j's (C, d) of a block: its columns of a (C, heads x d) block of
-    the projections' layout, or its slab of a heads-major (heads, C, d)
-    one."""
-    if len(ref.shape) == 3:
-        return ref.at[j]
+    the projections' layout."""
     return ref.at[:, j * d:(j + 1) * d]
 
 
@@ -314,19 +312,15 @@ def _outputs_backward_kernel(q_ref, k_ref, v_ref, g_col_ref, g_row_ref,
 # ---------------------------------------------------------------- the calls
 class _Sizes:
     """The extents of a call and the block specs of its arrays.  q, k, v, o
-    and their cotangents are (b, S, H x d) in the projections' layout, or
-    (b, H, S, d) heads-major (``major``); ``G`` and its cotangent come as
-    (b, H / heads, S, heads) and (b, n, H / heads, heads, C); a chunk's
-    matrices as (b, n, H, C, C) and its incoming states as (b, n, H, dk,
-    dv)."""
+    and their cotangents are (b, S, H x d), the projections' layout; ``G``
+    and its cotangent come as (b, H / heads, S, heads) and (b, n, H / heads,
+    heads, C); a chunk's matrices as (b, n, H, C, C) and its incoming states
+    as (b, n, H, dk, dv)."""
 
     def __init__(self, k, v, chunk: int, heads: int, H: int):
-        self.major = k.ndim == 4
-        self.b, self.S = (k.shape[0], k.shape[2]) if self.major \
-            else k.shape[:2]
+        self.b, self.S = k.shape[:2]
         self.H, self.chunk, self.heads = H, chunk, heads
-        self.dk = k.shape[-1] if self.major else k.shape[-1] // H
-        self.dv = v.shape[-1] if self.major else v.shape[-1] // H
+        self.dk, self.dv = k.shape[-1] // H, v.shape[-1] // H
         self.n = self.S // chunk
 
     @property
@@ -340,9 +334,6 @@ class _Sizes:
         C, heads = self.chunk, self.heads
 
         def wide(d):
-            if self.major:
-                return pl.BlockSpec((None, heads, C, d),
-                                    lambda i, h, c: (i, h, at(c), 0))
             return pl.BlockSpec((None, C, heads * d),
                                 lambda i, h, c: (i, at(c), h))
 
@@ -428,8 +419,8 @@ _SEQUENTIAL = ("parallel", "parallel", "arbitrary")  # the chunks in order
 
 @functools.partial(jax.jit, static_argnames=("chunk", "heads", "H"))
 def a_forward(k, g_col, g_row, chunk: int, heads: int, H: int):
-    """k in either layout; the two layouts of ``G``.  -> A (b, n, H, C, C)
-    float32, zero on and above the diagonal.  Jitted, as the other three
+    """k as :func:`_lay` gives it; the two layouts of ``G``.  -> A (b, n, H,
+    C, C) float32, zero on and above the diagonal.  Jitted, as the other three
     are: a model's layers then share one traced and lowered kernel a
     signature."""
     z = _Sizes(k, k, chunk, heads, H)
@@ -483,14 +474,13 @@ def outputs_backward(q, k, v, g_col, g_row, T, incoming, dO, chunk: int,
 
 
 # ------------------------------------------------------------ the operation
-def heads_a_step(H: int, dk: int, dv: int, major: bool = False) -> int:
-    """Heads a grid step takes.  In the projections' layout a step's columns
-    must be whole lane tiles or all of them: the largest divisor of H up to
-    eight whose keys and values are, else all H (30 heads of 96 under 192:
-    no divisor's keys fill tiles).  Heads-major: the largest divisor up to
-    eight (``ops/kda_kernel.py``'s rule)."""
-    fits = [j for j in range(1, min(H, 8) + 1) if H % j == 0 and (
-        major or (j * dk % LANES == 0 and j * dv % LANES == 0))]
+def heads_a_step(H: int, dk: int, dv: int) -> int:
+    """Heads a grid step takes.  A step's columns must be whole lane tiles
+    or all of them: the largest divisor of H up to eight whose keys and
+    values are, else all H (30 heads of 96 under 192: no divisor's keys
+    fill tiles)."""
+    fits = [j for j in range(1, min(H, 8) + 1) if H % j == 0
+            and j * dk % LANES == 0 and j * dv % LANES == 0]
     return max(fits, default=H)
 
 
@@ -527,23 +517,22 @@ def _one_layout(col, row):
             + jnp.moveaxis(row, 4, 2).reshape(b, n, C, groups * heads))
 
 
-def _lay(x, major: bool):
-    """(b, S, H, d) as the kernels take it."""
+def _lay(x):
+    """(b, S, H, d) as the kernels take it: the heads' columns side by
+    side, no copy."""
     b, S, H, d = x.shape
-    return jnp.moveaxis(x, 2, 1) if major else x.reshape(b, S, H * d)
+    return x.reshape(b, S, H * d)
 
 
-def _unlay(x, major: bool, H: int):
-    if major:
-        return jnp.moveaxis(x, 1, 2)
+def _unlay(x, H: int):
     b, S, width = x.shape
     return x.reshape(b, S, H, width // H)
 
 
-def _forward(q, k, v, g, beta, chunk, heads, major):
+def _forward(q, k, v, g, beta, chunk, heads):
     b, S, H, dk = q.shape
-    heads = heads or heads_a_step(H, dk, v.shape[-1], major)
-    q, k, v = (_lay(a, major) for a in (q, k, v))
+    heads = heads or heads_a_step(H, dk, v.shape[-1])
+    q, k, v = (_lay(a) for a in (q, k, v))
     # the cumulative sum over each chunk by itself, as ``gdn_xla`` forms it
     G = within_chunks(g.astype(_F32), chunk).reshape(b, S // chunk, chunk, H)
     g_col, g_row = _two_layouts(G, heads)
@@ -555,34 +544,31 @@ def _forward(q, k, v, g, beta, chunk, heads, major):
     X = _unit_lower_inverse(A * beta[..., None])
     T = (X * beta[..., None, :]).astype(q.dtype)
     o, incoming = outputs_forward(q, k, v, g_col, g_row, T, chunk, heads, H)
-    return _unlay(o, major, H), (q, k, v, g_col, g_row, beta, A, X, T,
-                                 incoming)
+    return _unlay(o, H), (q, k, v, g_col, g_row, beta, A, X, T, incoming)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def scan(q, k, v, g, beta, chunk: int, heads: int | None = None,
-         major: bool = False):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def scan(q, k, v, g, beta, chunk: int, heads: int | None = None):
     """``ops.gdn.gdn``'s arguments and result, by the kernels.  ``heads`` a
-    grid step (None: :func:`heads_a_step`) and ``major`` (q, k, v turned
-    heads-major by XLA in place of the projections' layout) are what
+    grid step (None: :func:`heads_a_step`) is what
     ``scripts/gdn_scan_sweep.py`` varies."""
-    return _forward(q, k, v, g, beta, chunk, heads, major)[0]
+    return _forward(q, k, v, g, beta, chunk, heads)[0]
 
 
-def _scan_fwd(q, k, v, g, beta, chunk, heads, major):
-    o, saved = _forward(q, k, v, g, beta, chunk, heads, major)
+def _scan_fwd(q, k, v, g, beta, chunk, heads):
+    o, saved = _forward(q, k, v, g, beta, chunk, heads)
     # of g and beta their cotangents' types alone
     return o, (saved, jnp.zeros((), g.dtype), jnp.zeros((), beta.dtype))
 
 
-def _scan_bwd(chunk, heads, major, saved, dO):
+def _scan_bwd(chunk, heads, saved, dO):
     # traced under the name stack of the call it is the backward of: the
     # caller's ``gdn_scan`` scope names these calls too
     (q, k, v, g_col, g_row, beta, A, X, T, incoming), like_g, like_beta = saved
     b, S, H, _ = dO.shape
     heads = g_col.shape[-1]
     dq, dk, dv, dg_col, dg_row, dT = outputs_backward(
-        q, k, v, g_col, g_row, T, incoming, _lay(dO, major), chunk, heads, H)
+        q, k, v, g_col, g_row, T, incoming, _lay(dO), chunk, heads, H)
     # T = X diag(beta), X = (I + diag(beta) A)^-1
     dN = inverse_backward(X, dT * beta[..., None, :])
     d_beta = jnp.sum(dT * X, axis=-2) + jnp.sum(dN * A, axis=-1)
@@ -594,7 +580,7 @@ def _scan_bwd(chunk, heads, major, saved, dO):
                     _one_layout(dg_col + dg_col_a, dg_row + dg_row_a),
                     precision=lax.Precision.HIGHEST)
     d_beta = jnp.moveaxis(d_beta, 2, 3).reshape(b, S, H)
-    return (_unlay(dq, major, H), _unlay(dk, major, H), _unlay(dv, major, H),
+    return (_unlay(dq, H), _unlay(dk, H), _unlay(dv, H),
             dg.reshape(b, S, H).astype(like_g.dtype),
             d_beta.astype(like_beta.dtype))
 

@@ -39,7 +39,7 @@ variable to pick one:
   scores and the carried state in VMEM.  Taken where the sizes lie on the
   chip's tiles (chunk and state multiples of 128, heads of 128 / 2^n
   channels that fill lane tiles side by side within their group: the
-  published Mamba-2 sizes do, ``HybridConfig.tiny()``'s do not) and the call
+  published Mamba-2 sizes do, ``tiny-nemotron-h.json``'s do not) and the call
   sits where a Mosaic call may sit: no mesh, a mesh of one device, or a mesh
   whose `data` / `fsdp` axes divide the rows and whose `tensor` axis divides
   the groups, under which the kernels run inside a ``shard_map`` over those

@@ -9,91 +9,17 @@ was covered.  It says them with :func:`note` (:func:`count` for what adds
 up over a trace, :func:`entry` for what it says a case at a time); whoever
 wraps the trace in :func:`noting` reads them as one dict.  Nothing here
 imports the package's layers, so ``ops/`` and ``models/`` import it at the
-top of the file.  No metric reads the record.
+top of the file.  One metric reads the record
+(``kernels.splash_window_visited``: the band's ``window_pairs_visited*``).
 
-**The keys**, by who notes them (the record also carries ``label``, ``ts``
-and ``seconds``, the span ``compile_s``):
-
-============================  ==============================================
-``parallel/train_state.py``   ``remat_fallback``: the step was refused for
-                              memory and rebuilt under the plain policy
-``ops/remat.py``              ``remat_kept`` (the ladder's rungs kept),
-                              ``remat_kept_bytes``, ``remat_room_bytes``
-                              (None: the device reports no memory),
-                              ``remat_routing_bytes`` (the expert layers'
-                              routing, always kept; 0: no layer routes);
-                              absent where no layer asked the rule (GPT-2)
-``ops/grad_ring.py``          ``grad_ring_products`` (weight gradients
-                              traced as rings; a scanned layer's once),
-                              ``grad_ring_axis`` (`fsdp`'s size); 0 and 0
-                              where none was
-``ops/attention.py``          where the splash kernel runs, a head:
-                              ``attn_calls``, ``attn_blocks`` (with work),
-                              ``attn_blocks_cut`` (that apply a mask),
-                              ``attn_grid_steps_fwd``,
-                              ``attn_grid_steps_bwd``, ``attn_block_q``,
-                              ``attn_block_kv``, ``attn_block_q_bwd``,
-                              ``attn_block_kv_bwd``, ``attn_dq_partials``
-                              (the row over the backward's kv block);
-                              a call over a causal band by names of its
-                              own: ``window_blocks`` (q, kv, the backward's
-                              q, kv), ``window_blocks_run``,
-                              ``window_blocks_cut``, ``window_grid_steps``
-                              (forward, backward), ``window_pairs_visited``
-                              and ``window_pairs_visited_bwd`` (the pairs
-                              inside the blocks run, a head)
-``ops/grouped_matmul.py``     ``gmm_tiles``: ``"m x k x n"`` of each distinct
-                              grouped product traced -> the (rows,
-                              contraction, columns) tile it walks
-``models/moe.py``             where a layer holds a share of its experts,
-                              ``moe_return``: ``"R x N x k x D"`` of each
-                              distinct window's return to its tokens ->
-                              (``"kernel"``, the token tile of
-                              ``ops/window_return.py``) or (``"gather"``,
-                              None)
-``ops/ssd.py``                ``ssm_scan_kernel`` (the Pallas kernels, or
-                              the XLA form), ``ssm_scan_grid`` (the
-                              kernels' grid a chip, or None)
-``ops/kda.py``                ``kda_scan_kernel``, ``kda_scan_grid``
-``ops/gdn.py``                ``gdn_scan_kernel``, ``gdn_scan_grid``
-``models/layers.py``          where a layer rotates (``rope``):
-                              ``rope_kernel`` (the lane roll of
-                              ``ops/rope_kernel.py``, or the product with a
-                              permutation), ``rope_calls`` (the calls
-                              traced: a layer's q and k are one, a scanned
-                              layer's once)
-``models/llama.py``           ``experts_held``, ``experts_total``,
-                              ``block_length``, ``attn_positions``,
-                              ``loss_positions``
-``models/hybrid.py``          ``layer_kinds`` (the pattern run),
-                              ``loss_positions``; with a prediction module
-                              ``mtp_depth``, ``mtp_weight``; then each kind
-                              of the pattern its own:
-``models/attn.py`` (``*``)    ``attn_positions``, ``heads_held``,
-                              ``heads_total``, ``attn_gate``; where only a
-                              head's first lanes rotate
-                              ``rope_rotary_lanes``, under YaRN
-                              ``rope_yarn_factor``, with QK-norm
-                              ``qk_norm``
-``models/window.py`` (``W``)  ``attn_window`` (the band's keys),
-                              ``window_heads``
-``models/experts.py`` (``E``) ``experts_held``, ``experts_total``,
-                              ``router_scoring``
-``models/mamba2.py`` (``M``)  ``ssm_heads``, ``ssm_state``, ``ssm_chunk``,
-                              ``ssm_chunks`` (S / chunk x rows)
-``models/kda.py`` (``K``)     ``kda_heads``, ``kda_head_dim``,
-                              ``kda_chunk``, ``kda_chunks``
-``models/mla.py`` (``L``)     ``attn_positions``, ``mla_heads``,
-                              ``mla_qk_head_dim``, ``mla_v_head_dim``,
-                              ``mla_latents`` (the query's and the
-                              key-value latent's width)
-``models/dense.py`` (``D``)   ``dense_width``
-``models/shortconv.py``       ``shortconv_taps``, ``shortconv_width``,
-(``C``)                       ``shortconv_layers``
-``models/gdn.py`` (``G``)     ``gdn_heads``, ``gdn_key_dim``,
-                              ``gdn_value_dim``, ``gdn_chunk``,
-                              ``gdn_chunks``
-============================  ==============================================
+**The keys** are :data:`KEYS`, each with the module that notes it and what
+it says; the record also carries ``label``, ``ts`` and ``seconds``, the span
+``compile_s``.  A new key is written where it is noted, in :data:`KEYS` and
+in the test that is about it: nothing is checked while a step is traced (a
+refusal there would be one more way for a step to stop), and
+``tests/test_step_names.py`` holds every key a call site under
+``ray_tpu/`` notes, and every key a kind's ``first_call_facts`` returns,
+inside the table.
 """
 
 from __future__ import annotations
@@ -101,6 +27,73 @@ from __future__ import annotations
 import contextlib
 import threading
 from typing import Any, Dict, Iterator, List
+
+#: (the module that notes them, what they say, the keys); a key that several
+#: modules note stands under the first
+_NOTED = (
+    ("parallel/train_state.py", "the step was refused for memory and rebuilt "
+     "under the plain policy", "remat_fallback"),
+    ("ops/remat.py", "the ladder's rungs kept, their bytes, the room the rule "
+     "saw (None: the device reports no memory), the expert layers' routing "
+     "(always kept; 0: no layer routes); absent where no layer asked the "
+     "rule (GPT-2)", "remat_kept remat_kept_bytes remat_room_bytes "
+     "remat_routing_bytes"),
+    ("ops/grad_ring.py", "weight gradients traced as rings (a scanned "
+     "layer's once), `fsdp`'s size; 0 and 0 where none was",
+     "grad_ring_products grad_ring_axis"),
+    ("ops/attention.py", "where the splash kernel runs, a head: its calls, "
+     "blocks with work, blocks that apply a mask, grid steps, the blocks "
+     "picked for the row, the row over the backward's kv block",
+     "attn_calls attn_blocks attn_blocks_cut attn_grid_steps_fwd "
+     "attn_grid_steps_bwd attn_block_q attn_block_kv attn_block_q_bwd "
+     "attn_block_kv_bwd attn_dq_partials"),
+    ("ops/attention.py", "a call over a causal band, by names of its own: "
+     "blocks (q, kv, the backward's q, kv), blocks run, blocks cut, grid "
+     "steps (forward, backward), the pairs inside the blocks run, a head",
+     "window_blocks window_blocks_run window_blocks_cut window_grid_steps "
+     "window_pairs_visited window_pairs_visited_bwd"),
+    ("ops/grouped_matmul.py", '"m x k x n" of each distinct grouped product '
+     "traced -> the (rows, contraction, columns) tile it walks", "gmm_tiles"),
+    ("models/moe.py", 'where a layer holds a share of its experts, "R x N x k '
+     'x D" of each distinct window\'s return to its tokens -> ("kernel", the '
+     'token tile of ops/window_return.py) or ("gather", None)', "moe_return"),
+    ("ops/ssd.py", "the Pallas kernels or the XLA form, the kernels' grid a "
+     "chip or None", "ssm_scan_kernel ssm_scan_grid"),
+    ("ops/kda.py", "as ops/ssd.py's", "kda_scan_kernel kda_scan_grid"),
+    ("ops/gdn.py", "as ops/ssd.py's", "gdn_scan_kernel gdn_scan_grid"),
+    ("models/layers.py", "where a layer rotates: the lane roll of "
+     "ops/rope_kernel.py or the product with a permutation, the calls traced "
+     "(a layer's q and k are one, a scanned layer's once)",
+     "rope_kernel rope_calls"),
+    ("models/llama.py", "the experts held of a layer's, a block-diffusion "
+     "row's block (0: next-token), the positions attention and the loss "
+     "run over", "experts_held experts_total block_length attn_positions "
+     "loss_positions"),
+    ("models/hybrid.py", "the pattern run, one letter a layer; with a "
+     "prediction module its depth and its loss's weight",
+     "layer_kinds mtp_depth mtp_weight"),
+    ("models/attn.py", "(*) the query heads held of the model's, the output "
+     "gate; where only a head's first lanes rotate, under YaRN, with QK-norm",
+     "heads_held heads_total attn_gate rope_rotary_lanes rope_yarn_factor "
+     "qk_norm"),
+    ("models/window.py", "(W) the band's keys, its layers' query heads",
+     "attn_window window_heads"),
+    ("models/experts.py", "(E) sigmoid or softmax", "router_scoring"),
+    ("models/mamba2.py", "(M) the last: S / chunk x rows",
+     "ssm_heads ssm_state ssm_chunk ssm_chunks"),
+    ("models/kda.py", "(K)", "kda_heads kda_head_dim kda_chunk kda_chunks"),
+    ("models/mla.py", "(L) the last: the query's and the key-value latent's "
+     "width", "mla_heads mla_qk_head_dim mla_v_head_dim mla_latents"),
+    ("models/dense.py", "(D)", "dense_width"),
+    ("models/shortconv.py", "(C)",
+     "shortconv_taps shortconv_width shortconv_layers"),
+    ("models/gdn.py", "(G)",
+     "gdn_heads gdn_key_dim gdn_value_dim gdn_chunk gdn_chunks"),
+)
+#: every key of the record -> the module that notes it and what its keys say
+KEYS: Dict[str, str] = {key: f"{module}: {says}"
+                        for module, says, keys in _NOTED
+                        for key in keys.split()}
 
 _thread = threading.local()  # .open: the dicts of the blocks open in here
 

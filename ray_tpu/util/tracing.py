@@ -111,20 +111,11 @@ SPAN_REGISTRY: Dict[str, str] = {
     "train.first_call": "TrainStep: a call that built or loaded an "
                         "executable (trace, lower, compile or cache load, "
                         "dispatch), recorded after the fact, a row of the "
-                        "set-up's account (attrs: label, trace_s, lower_s, "
-                        "compile_s, other_s, cache_load_s, cache, "
-                        "remat_kept, remat_kept_bytes, "
-                        "remat_room_bytes, remat_routing_bytes, "
-                        "remat_fallback, "
-                        "grad_ring_products, grad_ring_axis; from "
-                        "models/llama.py experts_held, experts_total, "
-                        "block_length, attn_positions, loss_positions; "
-                        "from ops/attention.py's splash path attn_calls, "
-                        "attn_blocks, attn_blocks_cut, attn_grid_steps_fwd, "
-                        "attn_grid_steps_bwd, attn_block_q, attn_block_kv, "
-                        "attn_block_q_bwd, attn_block_kv_bwd, "
-                        "attn_dq_partials; from ops/grouped_matmul.py "
-                        "gmm_tiles)",
+                        "set-up's account (attrs: label, the phases "
+                        "trace_s, lower_s, compile_s, other_s, "
+                        "cache_load_s, cache, and what the traced code "
+                        "noted of itself: the keys of "
+                        "util/first_call.py's first_call.KEYS)",
     "train.report": "session: one train.report() call, step boundary "
                     "included",
     "train.step_done": "profiler: its resolver thread's wait for one "

@@ -15,18 +15,19 @@ reported beside the mean of all),
 - forward + backward under ``jax.checkpoint``, as the layer runs it (the
   forward, the forward again with its residuals, the backward).
 
-The variants are the three ways to lay keys of 96 and values of 192 on
-lane tiles of 128, each at the heads a grid step its blocks allow:
+The variants are two ways to lay keys of 96 and values of 192 on lane
+tiles of 128, each at the heads a grid step its blocks allow:
 
 (i)   the projections' own layout, (rows, positions, H x d), all heads a
       grid step and a head's columns sliced at the lane offset ``96 j``
       (``192 j``): no copy on either side of a call;
-(ii)  heads-major operands, (rows, H, positions, d), turned by XLA as the
-      XLA form's ``moveaxis`` turns them, the last block dimension the whole
-      96 / 192 (VMEM and HBM pad it to 128 / 256 lanes);
 (iii) keys zero-padded to 128 in HBM (zeros add nothing to ``K K^T``, ``Q
       K^T`` or the state's products), values as they are or padded to 256,
       in the projections' layout: every slice starts on a tile;
+
+((ii), heads-major operands turned by XLA, read 5.15 / 10.88 / 15.18 ms at
+its best against (i)'s 4.11 / 10.31 / 14.24 on the chip at PR 59 and left
+``ops/gdn_kernel.py`` with PR 60: ``PERF.md``, PR 59, holds its readings.)
 
 then each stage of (i) alone: ``A``'s kernel and its backward, the scan's
 two, XLA's triangular inverse and its backward, the cumulative sum.  One
@@ -70,10 +71,6 @@ def variants(H: int, dk: int, dv: int):
     scan = gdn_kernel.scan
     out = {"xla": gdn.gdn_xla,
            f"(i) the projections' layout, all {H} heads a step (taken)": scan}
-    for heads in (1, 2, 5, 6, 10):
-        if H % heads == 0:
-            out[f"(ii) heads-major, {heads} head(s) a step"] = \
-                functools.partial(scan, heads=heads, major=True)
     for dv_to in (dv, 2 * gdn_kernel.LANES):
         for heads in (2, 6, H):
             if H % heads == 0:

@@ -23,10 +23,54 @@ assert jax.device_count() == 8, (
     f"tests need the virtual 8-device CPU mesh, got {jax.devices()}"
 )
 
+import faulthandler
+import sys
+
 import pytest
+
+#: seconds after which a test that is still running has every thread's stack
+#: written to stderr (and so to the run's log): a run the clock cuts (exit
+#: code 124) then names the test that was waiting and where.  The longest
+#: tier-1 test takes about 80 s.
+HANG_DUMP_S = 150
+#: the process's stderr as ``pytest_configure`` finds it (an xdist worker's is
+#: the run's own): while a test runs, pytest's capture holds descriptor 2
+_STDERR = pytest.StashKey[int]()
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_protocol(item):
+    faulthandler.dump_traceback_later(HANG_DUMP_S,
+                                      file=item.config.stash[_STDERR])
+    try:
+        return (yield)
+    finally:
+        faulthandler.cancel_dump_traceback_later()
+
+
+def pytest_collection_modifyitems(items):
+    """``tests/test_families.py`` family-major: a family's cases side by
+    side, in the order of their first appearance, so that the contiguous
+    chunks xdist hands a worker under ``--dist load`` hold most of one
+    family and what ``tests/families.py`` builds once a process is built
+    once."""
+    def family(item):
+        return getattr(item, "callspec", None) and item.callspec.params.get(
+            "family")
+
+    slots = [i for i, item in enumerate(items)
+             if item.path.name == "test_families.py" and family(item)]
+    first = {}
+    for i in slots:
+        first.setdefault(family(items[i]), len(first))
+    ordered = sorted((items[i] for i in slots),
+                     key=lambda item: first[family(item)])
+    for i, item in zip(slots, ordered):
+        items[i] = item
 
 
 def pytest_configure(config):
+    config.stash[_STDERR] = os.dup(sys.__stderr__.fileno())
     config.addinivalue_line(
         "markers",
         "slow: reference-scale envelope benchmarks (excluded from tier-1 "

@@ -115,11 +115,16 @@ class TestRing:
         metric_rows = [r for r in rec.snapshot() if r["kind"] == "metric"]
         assert any(r["name"] == "ray_tpu_forensics_ring_events_total"
                    and r["detail"] >= 1 for r in metric_rows)
-        # No movement since the last sample -> no new delta rows.
-        before = len([r for r in rec.snapshot() if r["kind"] == "metric"])
+        # No movement since the last sample -> no new delta row: of the
+        # counter this test moves.  (The registry is the process's: threads
+        # that earlier tests of this worker left running still move others.)
+        def own_rows():
+            return len([r for r in rec.snapshot() if r["kind"] == "metric"
+                        and r["name"] == "ray_tpu_forensics_ring_events_total"])
+
+        before = own_rows()
         rec.sample_metric_deltas(now=3.0)
-        after = len([r for r in rec.snapshot() if r["kind"] == "metric"])
-        assert after == before
+        assert own_rows() == before == 1
 
 
 # --------------------------------------------------------------------------

@@ -17,13 +17,9 @@ from benchmarks.reference.olmo_hybrid import l2norm, recurrence
 from ray_tpu.ops import gdn as gdn_ops
 from ray_tpu.ops import kda as kda_ops
 from ray_tpu.ops.gdn import gdn
+from tests.families import rel_err
 
 S = 128
-
-
-def _rel_err(a, b):
-    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
-    return float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
 
 
 def _inputs(case, dk=12, dv=24, b=2, H=3, seed=0):
@@ -83,11 +79,11 @@ def test_outputs_and_gradients_are_the_recurrences(chunk, dtype, tol, case):
     ((_, o), grads), ((_, want), want_grads) = _both(
         chunk, jnp.dtype(dtype), args)
     assert np.all(np.isfinite(np.asarray(o, np.float32)))
-    assert _rel_err(o, want) < tol
+    assert rel_err(o, want) < tol
     for name, got, ref in zip(("q", "k", "v", "g", "beta"), grads,
                               want_grads):
         assert np.all(np.isfinite(np.asarray(got))), name
-        assert _rel_err(got, ref) < tol, (name, _rel_err(got, ref))
+        assert rel_err(got, ref) < tol, (name, rel_err(got, ref))
 
 
 @pytest.mark.parametrize("dk,dv", [(12, 24), (24, 8), (16, 16)])
@@ -97,8 +93,8 @@ def test_keys_and_values_of_unlike_widths(dk, dv):
     args = _inputs("plain", dk=dk, dv=dv, seed=1)
     ((_, o), grads), ((_, want), want_grads) = _both(32, jnp.float32, args)
     assert o.shape == (2, S, 3, dv)
-    assert _rel_err(o, want) < 2e-4
-    assert all(_rel_err(a, b) < 2e-4 for a, b in zip(grads, want_grads))
+    assert rel_err(o, want) < 2e-4
+    assert all(rel_err(a, b) < 2e-4 for a, b in zip(grads, want_grads))
 
 
 def test_a_decay_that_underflows_neither_overflows_nor_leaks():
@@ -110,7 +106,7 @@ def test_a_decay_that_underflows_neither_overflows_nor_leaks():
     g = jnp.full(beta.shape, -40.0)
     o, pull = jax.vjp(lambda *a: gdn(*a, 64), q, k, v, g, beta)
     own = beta[..., None] * jnp.sum(q * k, axis=-1, keepdims=True) * v
-    assert _rel_err(o, own) < 1e-5
+    assert rel_err(o, own) < 1e-5
     assert all(np.all(np.isfinite(np.asarray(a)))
                for a in pull(jnp.ones_like(o)))
 
@@ -120,7 +116,7 @@ def test_a_row_starts_from_a_zero_state_and_reads_no_later_position():
     o = gdn(q, k, v, g, beta, 16)
     # rows are independent: the second row alone gives the second row
     alone = gdn(*(a[1:] for a in (q, k, v, g, beta)), 16)
-    assert _rel_err(o[1:], alone) < 1e-6
+    assert rel_err(o[1:], alone) < 1e-6
     # a change at position 40 reaches positions 40 and later of its row
     moved = gdn(q, k, v.at[0, 40].add(1.0), g, beta, 16)
     changed = np.any(np.asarray(moved != o), axis=(2, 3))

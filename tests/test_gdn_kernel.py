@@ -16,25 +16,15 @@ import numpy as np
 import pytest
 
 from benchmarks.reference.olmo_hybrid import l2norm, recurrence
-from ray_tpu.models import hybrid
 from ray_tpu.ops import gdn as gdn_module
 from ray_tpu.ops import gdn_kernel
 from ray_tpu.ops.gdn import gdn, gdn_xla
-from ray_tpu.parallel import MeshSpec, make_mesh
 from ray_tpu.util import first_call
+from tests import families
+from tests.families import l2_err, out_and_grads, rel_err
 
 S = 128
 WIDTHS = [(96, 192), (128, 128)]
-
-
-def _rel_err(a, b):
-    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
-    return float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
-
-
-def _l2_err(a, b):
-    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
-    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
 
 
 def _inputs(case, dk=96, dv=192, b=2, H=3, S=S, seed=0, chunk=64):
@@ -58,13 +48,6 @@ def _inputs(case, dk=96, dv=192, b=2, H=3, S=S, seed=0, chunk=64):
     return q, k, v, g, beta
 
 
-def _out_and_grads(fn, args, dy):
-    def run(*args):
-        out, vjp = jax.vjp(fn, *args)
-        return out, vjp(dy)
-    return jax.jit(run)(*args)
-
-
 def _low(args):
     return tuple(a.astype(jnp.bfloat16) for a in args[:3]) + tuple(args[3:])
 
@@ -86,13 +69,13 @@ def test_the_kernels_are_the_recurrence(dk, dv, chunk, case):
     assert _takes_the_kernels(args, chunk)
     dy = jax.random.normal(jax.random.key(9), args[2].shape)
     with jax.default_matmul_precision("highest"):
-        got, grads = _out_and_grads(lambda *a: gdn(*a, chunk), args, dy)
-        want, grads_ref = _out_and_grads(recurrence, args, dy)
+        got, grads = out_and_grads(lambda *a: gdn(*a, chunk), args, dy)
+        want, grads_ref = out_and_grads(recurrence, args, dy)
     assert got.shape == args[2].shape and np.all(np.isfinite(got))
-    assert _rel_err(got, want) < 2e-4
+    assert rel_err(got, want) < 2e-4
     for name, g, g_ref in zip("qkvgb", grads, grads_ref):
         assert np.all(np.isfinite(g)), name
-        assert _rel_err(g, g_ref) < 2e-4, name
+        assert rel_err(g, g_ref) < 2e-4, name
 
 
 @pytest.mark.parametrize("case", ["plain", "beta-near-2", "strong-decay"])
@@ -108,42 +91,50 @@ def test_bf16_in_is_within_bf16s_rounding_and_no_further_than_the_xla_form(
     low = _low(_inputs(case, dk, dv, chunk=chunk))
     exact = tuple(a.astype(jnp.float32) for a in low)
     dy = jax.random.normal(jax.random.key(9), low[2].shape, jnp.bfloat16)
-    want, grads_ref = _out_and_grads(recurrence, exact,
+    want, grads_ref = out_and_grads(recurrence, exact,
                                      dy.astype(jnp.float32))
-    got, grads = _out_and_grads(lambda *a: gdn(*a, chunk), low, dy)
-    xla, grads_xla = _out_and_grads(lambda *a: gdn_xla(*a, chunk), low, dy)
+    got, grads = out_and_grads(lambda *a: gdn(*a, chunk), low, dy)
+    xla, grads_xla = out_and_grads(lambda *a: gdn_xla(*a, chunk), low, dy)
     assert got.dtype == jnp.bfloat16
     for name, g, g_xla, g_ref, a in zip(
             "oqkvgb", (got,) + grads, (xla,) + grads_xla,
             (want,) + grads_ref, (low[2],) + low):
         assert g.dtype == a.dtype and g.shape == a.shape, name
         assert np.all(np.isfinite(np.asarray(g, np.float32))), name
-        assert _rel_err(g, g_ref) < 4e-2, name
-        assert _l2_err(g, g_ref) <= 1.02 * _l2_err(g_xla, g_ref), name
+        assert rel_err(g, g_ref) < 4e-2, name
+        assert l2_err(g, g_ref) <= 1.02 * l2_err(g_xla, g_ref), name
 
 
-@pytest.mark.parametrize("heads,major", [
-    (None, False), (None, True), (1, True), (3, True)],
-    ids=["taken", "heads-major", "heads-major-a-head-a-step",
-         "heads-major-all-heads"])
+#: (keys, values, heads, heads a grid step; None: the rule's).  The rule's
+#: own at every width (all three heads of 96 under 192 and of 64 under 128,
+#: one of 128s), then blocks of heads the rule would not pick, where a
+#: step's columns are whole lane tiles and the block index walks them: a
+#: head a step, and two of four
+LAYOUTS = {
+    "96-under-192": (96, 192, 3, None), "128s": (128, 128, 3, None),
+    "64-under-128": (64, 128, 3, None),
+    "128s-a-head-a-step": (128, 128, 3, 1),
+    "64-under-128-two-of-four-heads-a-step": (64, 128, 4, 2),
+}
+
+
 @pytest.mark.parametrize("chunk", [16, 64], ids=["c16", "c64"])
-@pytest.mark.parametrize("dk,dv", WIDTHS + [(64, 128)],
-                         ids=["96-under-192", "128s", "64-under-128"])
-def test_the_kernels_against_the_xla_form(dk, dv, chunk, heads, major):
+@pytest.mark.parametrize("name", LAYOUTS)
+def test_the_kernels_against_the_xla_form(name, chunk):
     """Float32, the same inputs, three chunks of 64 or twelve of 16: output
-    and gradients of ``gdn_kernel.scan`` against ``gdn_xla``, in the
-    projections' layout and heads-major, at the heads a grid step the rule
-    gives and at others."""
-    args = _inputs("plain", dk, dv, S=192, seed=11)
+    and gradients of ``gdn_kernel.scan`` against ``gdn_xla``, at the heads
+    a grid step the rule gives and at others."""
+    dk, dv, H, heads = LAYOUTS[name]
+    args = _inputs("plain", dk, dv, H=H, S=192, seed=11)
     dy = jax.random.normal(jax.random.key(3), args[2].shape)
     with jax.default_matmul_precision("highest"):
-        got, grads = _out_and_grads(lambda *a: gdn_kernel.scan(
-            *a, chunk, heads, major), args, dy)
-        want, grads_xla = _out_and_grads(lambda *a: gdn_xla(*a, chunk), args,
+        got, grads = out_and_grads(lambda *a: gdn_kernel.scan(
+            *a, chunk, heads), args, dy)
+        want, grads_xla = out_and_grads(lambda *a: gdn_xla(*a, chunk), args,
                                          dy)
-    assert _rel_err(got, want) < 1e-5
+    assert rel_err(got, want) < 1e-5
     for name, g, g_xla in zip("qkvgb", grads, grads_xla):
-        assert _rel_err(g, g_xla) < 1e-4, name
+        assert rel_err(g, g_xla) < 1e-4, name
 
 
 def test_the_state_crosses_chunks_and_starts_a_row_at_zero():
@@ -166,31 +157,30 @@ def test_the_state_crosses_chunks_and_starts_a_row_at_zero():
         first = tuple(x[:, :C] for x in other)
         np.testing.assert_allclose(o[1, :C], scan(*first)[0], rtol=1e-6,
                                    atol=1e-6)
-        assert _rel_err(o, recurrence(*both)) < 1e-5
+        assert rel_err(o, recurrence(*both)) < 1e-5
     # position 0's output is ``beta (k . q) v`` of that position alone
     q, k, v, _, beta = both
     start = beta[:, 0, :, None] * jnp.sum(k[:, 0] * q[:, 0], -1,
                                           keepdims=True) * v[:, 0]
-    assert _rel_err(o[:, 0], start) < 1e-5
+    assert rel_err(o[:, 0], start) < 1e-5
 
 
-@pytest.mark.parametrize("H,dk,dv,major,want", [
-    (30, 96, 192, False, 30),    # no divisor's keys fill lane tiles: all
-    (30, 96, 192, True, 6),
-    (32, 128, 128, False, 8), (16, 128, 256, False, 8),
-    (6, 64, 128, False, 6), (4, 96, 192, False, 4), (3, 96, 192, False, 3),
-    (7, 128, 128, False, 7), (7, 128, 128, True, 7), (9, 128, 128, True, 3)])
-def test_heads_a_grid_step(H, dk, dv, major, want):
-    assert gdn_kernel.heads_a_step(H, dk, dv, major) == want
-
-
-def _mesh(**axes):
-    return make_mesh(MeshSpec(**axes), jax.devices()[:MeshSpec(**axes).size])
+@pytest.mark.parametrize("H,dk,dv,want", [
+    (30, 96, 192, 30),    # no divisor's keys fill lane tiles: all
+    (32, 128, 128, 8), (16, 128, 256, 8),
+    (6, 64, 128, 6), (4, 96, 192, 4), (3, 96, 192, 3),
+    (7, 128, 128, 7),
+    (9, 128, 128, 3),     # the largest divisor up to eight, not eight
+    (12, 64, 128, 6),     # of 2, 4 and 6, whose keys fill tiles, the most
+    (8, 96, 192, 8),      # keys of 96 fill tiles four heads at a time
+    (30, 128, 128, 6)])   # the cell's heads at keys that fill a tile each
+def test_heads_a_grid_step(H, dk, dv, want):
+    assert gdn_kernel.heads_a_step(H, dk, dv) == want
 
 
 #: rows, positions, heads, a head's keys, its values, chunk; the mesh's axes
 CELL = (1, 8192, 30, 96, 192, 64)
-TINY = hybrid.HybridConfig.tiny_olmo_hybrid()
+TINY = families.preset("olmo_hybrid")
 PLACEMENTS = {
     "the-cell": (CELL, {}, "kernel"),
     "the-cell-on-one-device-of-a-mesh": (CELL, {"data": 1}, "kernel"),
@@ -231,7 +221,7 @@ def test_which_path_a_call_takes(name):
     its VMEM and every device of the mesh can scan rows and heads of its
     own; the XLA form everywhere else."""
     (b, S, H, dk, dv, chunk), axes, want = PLACEMENTS[name]
-    mesh = _mesh(**axes).abstract_mesh if axes \
+    mesh = families.mesh(**axes).abstract_mesh if axes \
         else jax.sharding.get_abstract_mesh()
     assert gdn_module.path((b, S, H, dk), (b, S, H, dv), min(chunk, S),
                            mesh) == want
@@ -250,13 +240,13 @@ def test_on_a_mesh_every_device_scans_its_own_rows_and_heads():
 
     with jax.default_matmul_precision("highest"):
         want, grads_xla = loss(gdn_xla)(args)
-        with jax.set_mesh(_mesh(data=2, tensor=2)), \
+        with jax.set_mesh(families.mesh(data=2, tensor=2)), \
                 first_call.noting() as notes:
             got, grads = loss(gdn)(args)
     assert notes == {"gdn_scan_kernel": True, "gdn_scan_grid": [1, 1, 2]}
     assert float(got) == pytest.approx(float(want), rel=1e-5)
     for name, g, g_xla in zip("qkvgb", grads, grads_xla):
-        assert _rel_err(g, g_xla) < 1e-4, name
+        assert rel_err(g, g_xla) < 1e-4, name
 
 
 def test_the_first_call_record_says_which_ran():

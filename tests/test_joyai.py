@@ -8,51 +8,29 @@ whose loss joins the step's, for one chip's share of the experts.
 The plain reference is ``benchmarks/reference/joyai_llm_flash.py``, the one
 copy (float32, the einsum attention, every held expert applied to every
 position).  Everything runs on the CPU with seeded random weights at tiny
-sizes, attention on the einsum path unless a test says otherwise.
+sizes, attention on the einsum path unless a test says otherwise.  What
+every family is held to is ``tests/test_families.py``'s, by the row
+``joyai_llm_flash``.
 """
 
 import dataclasses
-import re
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks.lib import correct, spec
 from benchmarks.reference import joyai_llm_flash as reference
 from benchmarks.reference.llama import _rmsnorm, _rope
 from ray_tpu.models import hybrid, mla, moe
 from ray_tpu.models.layers import rope
 from ray_tpu.ops import attention, remat
-from ray_tpu.parallel import MeshSpec, make_mesh
 from ray_tpu.parallel.train_state import jit_train_step
 from ray_tpu.util import first_call, tracing
+from tests import families
+from tests.families import rel_err
 
-#: benchmarks/lib/correct.py's, which the bf16 program is held to on the chip
-LOSS_TOL, GRAD_TOL = 1e-3, 0.75
-
-
-def _rel_err(a, b):
-    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
-    return float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
-
-
-def _tiny_family(dtype="bfloat16", **changes):
-    config = dict(spec.load_json(spec.BENCH_DIR, "configs",
-                                 "tiny-joyai.json"), **changes)
-    config["options"] = {"attn_impl": "xla", "dtype": jnp.dtype(dtype),
-                         "logits_dtype": jnp.dtype(dtype)}
-    return config, spec.load_module("models", "joyai_llm_flash").build(
-        config, 128)
-
-
-def _float32(**changes):
-    return dataclasses.replace(
-        hybrid.HybridConfig.tiny_joyai(), attn_impl="xla", dtype=jnp.float32,
-        logits_dtype=jnp.float32, **changes)
+FAMILY = "joyai_llm_flash"
 
 
 # ----------------------------------------------------- (1) the rotary pass
@@ -84,8 +62,8 @@ def test_rope_over_a_part_of_a_head_and_in_pairs(rotary, interleave):
         want, pull = jax.vjp(lambda x: _written_out(x, theta, rotary,
                                                     interleave), x)
         got, ours_pull = jax.vjp(ours, x)
-        assert _rel_err(got, want) < 1e-5
-        assert _rel_err(ours_pull(do)[0], pull(do)[0]) < 1e-5
+        assert rel_err(got, want) < 1e-5
+        assert rel_err(ours_pull(do)[0], pull(do)[0]) < 1e-5
     assert np.array_equal(got[..., :24 - rotary], x[..., :24 - rotary])
     # a rotation: norms of the pairs, so of the head, are kept
     assert np.allclose(jnp.linalg.norm(got, axis=-1),
@@ -103,7 +81,7 @@ def test_the_two_pairings_differ_by_one_permutation_of_the_lanes():
                        rope(k, 1e4, None, True))
     halves = jnp.einsum("bqhd,bkhd->bhqk", rope(q[..., order], 1e4),
                         rope(k[..., order], 1e4))
-    assert _rel_err(pairs, halves) < 1e-5
+    assert rel_err(pairs, halves) < 1e-5
 
 
 # ------------------------------------------------------- (2) the ``L`` mixer
@@ -132,7 +110,7 @@ def test_mixer_matches_the_written_out_formula():
     """The layer against the einsum formula in float32, its output and the
     gradient of every leaf and of the input: v (16) narrower than q.k (16 +
     8), the rotary pairs interleaved, one rotary key for all four heads."""
-    config = _float32()
+    config = families.float32(FAMILY)
     assert (config.mla_nope_dim + config.mla_rope_dim, config.mla_v_dim) \
         == (24, 16)
     blk, x = _mixer_parts(config)
@@ -151,8 +129,8 @@ def test_mixer_matches_the_written_out_formula():
         got, grads = jax.jit(jax.value_and_grad(ours, (0, 1)))(blk, x)
         want, ref_grads = jax.jit(jax.value_and_grad(written_out, (0, 1)))(
             blk, x)
-    assert _rel_err(got, want) < 1e-5
-    errors = jax.tree.map(_rel_err, grads, ref_grads)
+    assert rel_err(got, want) < 1e-5
+    errors = jax.tree.map(rel_err, grads, ref_grads)
     assert set(errors[0]) == {"attn_norm", "wq_a", "q_norm", "wq_b", "wkv_a",
                               "kv_norm", "wkv_b", "wo"}
     for path, err in jax.tree_util.tree_flatten_with_path(errors)[0]:
@@ -164,7 +142,7 @@ def test_every_head_reads_the_one_rotary_key():
     with q's lanes without position zeroed, every head's scores come from
     that key alone, and zeroing those columns leaves every head a uniform
     causal average of its values."""
-    config = _float32()
+    config = families.float32(FAMILY)
     blk, x = _mixer_parts(config)
     H, nope, rot = config.mla_heads, config.mla_nope_dim, config.mla_rope_dim
     wq_b = blk["wq_b"].reshape(-1, H, nope + rot).at[..., :nope].set(0.0)
@@ -178,8 +156,8 @@ def test_every_head_reads_the_one_rotary_key():
                     blk["kv_norm"], config.rms_eps)
     v = (c_kv @ blk["wkv_b"]).reshape(2, 64, H, -1)[..., nope:]
     mean = jnp.cumsum(v, axis=1) / jnp.arange(1, 65)[None, :, None, None]
-    assert _rel_err(flat, mean.reshape(2, 64, -1) @ blk["wo"]) < 1e-4
-    assert _rel_err(out, flat) > 0.01
+    assert rel_err(flat, mean.reshape(2, 64, -1) @ blk["wo"]) < 1e-4
+    assert rel_err(out, flat) > 0.01
 
 
 @pytest.mark.parametrize("rows", [1, 2])
@@ -208,30 +186,25 @@ def test_splash_takes_v_at_its_own_head_dimension(rows):
 
 
 # ------------------------------------------------ (3) the prediction module
-def _rows(config, seed=0, rows=2):
-    ids = np.random.default_rng(seed).integers(
-        0, config.vocab_size, (rows, 129)).astype(np.int32)
-    return ids[:, :-1], ids[:, 1:]
-
-
 def test_the_steps_loss_is_the_two_cross_entropies():
     """``loss = loss_main + 0.3 loss_mtp``, the two terms leave as step
     counters, and each is the written-out one: the module's over the last
     layer's output before the final norm joined with the next token's
     embedding (the embedding first), one more ``LE`` block, the shared head,
     the token two ahead, the mean over the S - 1 positions that have one."""
-    config = _float32()
-    cfg, _ = _tiny_family("float32")
+    config = families.float32(FAMILY)
+    cfg, _ = families.family(FAMILY, "float32")
     assert cfg["mtp_loss_weight"] == config.mtp_weight == 0.3
     params = hybrid.init_params(config, jax.random.key(0))
     params["experts"]["router"] = params["experts"]["router"] * 20.0
-    tokens, targets = _rows(config)
+    tokens, targets = families.rows(config.vocab_size)
     ref_cfg = dict(
         _reference_config(config), num_hidden_layers=3,
         first_k_dense_replace=1, num_nextn_predict_layers=1,
         experts_held=[4, 8], num_experts_per_tok=2, norm_topk_prob=True,
         routed_scaling_factor=2.5, n_routed_experts_published=16,
-        router_bias_seed=0, router_bias_std=0.05, mtp_loss_weight=0.3)
+        router_bias_seed=config.router_bias_seed,
+        router_bias_std=config.router_bias_std, mtp_loss_weight=0.3)
     with jax.default_matmul_precision("highest"):
         loss, counts = jax.jit(lambda p: hybrid.loss_and_counters(
             p, tokens, targets, config))(params)
@@ -253,9 +226,9 @@ def test_the_last_position_weighs_nothing_in_the_modules_loss():
     has no token two ahead: changing it moves ``loss_main`` and leaves
     ``loss_mtp`` where it was; the last target, which the position before
     it predicts two ahead, moves both."""
-    config = _float32()
+    config = families.float32(FAMILY)
     params = hybrid.init_params(config, jax.random.key(1))
-    tokens, targets = _rows(config, 1)
+    tokens, targets = families.rows(config.vocab_size, seed=1)
     losses = jax.jit(lambda t, y: hybrid.loss_and_counters(
         params, t, y, config)[1])
     base = losses(tokens, targets)
@@ -273,19 +246,20 @@ def test_the_last_position_weighs_nothing_in_the_modules_loss():
 
 
 def test_a_configuration_without_a_module_has_none_of_it():
-    config = _float32(mtp_depth=0)
+    config = families.float32(FAMILY, mtp_depth=0)
     params = hybrid.init_params(config, jax.random.key(0))
     assert "mtp" not in params and "mtp" not in hybrid.logical_axes(config)
     assert params["mla"]["wo"].shape[0] == 3
     assert params["experts"]["router"].shape[0] == 2
-    tokens, targets = _rows(config)
+    tokens, targets = families.rows(config.vocab_size)
     with first_call.noting() as notes:
         _, counts = jax.jit(lambda p: hybrid.loss_and_counters(
             p, tokens, targets, config))(params)
     assert set(counts) == {"moe_rows", "moe_moved"}
     assert "mtp_depth" not in notes
     # the module's rows are drawn after the layers': the layers' are the same
-    with_module = hybrid.init_params(_float32(), jax.random.key(0))
+    with_module = hybrid.init_params(families.float32(FAMILY),
+                                     jax.random.key(0))
     assert with_module["mla"]["wo"].shape[0] == 4
 
 
@@ -293,15 +267,15 @@ def test_the_modules_block_is_one_more_of_the_last_layer():
     """No field spells the block: it is the pattern's last two kinds.  Where
     that layer holds no experts the module adds a row to its own kinds'
     stacks and the pattern's expert layers keep their counters."""
-    assert _float32().mtp_kinds == "LE"
-    assert _float32(mtp_depth=0).mtp_kinds == ""
-    config = _float32(pattern="LELD")
+    assert families.float32(FAMILY).mtp_kinds == "LE"
+    assert families.float32(FAMILY, mtp_depth=0).mtp_kinds == ""
+    config = families.float32(FAMILY, pattern="LELD")
     assert config.mtp_kinds == "LD"
     assert [config.rows(kind) for kind in "LED"] == [3, 1, 2]
     params = hybrid.init_params(config, jax.random.key(0))
     assert params["dense"]["w_down"].shape[0] == 2
     assert params["experts"]["router"].shape[0] == 1
-    tokens, targets = _rows(config)
+    tokens, targets = families.rows(config.vocab_size)
     loss, counts = jax.jit(lambda p: hybrid.loss_and_counters(
         p, tokens, targets, config))(params)
     assert set(counts) == {"moe_rows", "moe_moved", "loss_main", "loss_mtp"}
@@ -312,52 +286,17 @@ def test_the_modules_block_is_one_more_of_the_last_layer():
 
 
 # ------------------------------------------------------ (4) the whole model
-@pytest.mark.parametrize("dtype,loss_tol,grad_tol", [
-    # the same mathematics in another order: float32 summation order only
-    ("float32", 1e-5, 2e-4),
-    # bf16 operands, residual stream and logits under the chip run's limits
-    ("bfloat16", LOSS_TOL, GRAD_TOL),
-], ids=["float32", "bfloat16"])
-def test_loss_and_gradients_match_the_plain_reference(dtype, loss_tol,
-                                                      grad_tol):
-    config, family = _tiny_family(dtype)
-    assert spec.load_module("models", "joyai_llm_flash").pattern(config) \
-        == "LDLELE"
-    params = jax.jit(family.init_fn)(jax.random.key(0))
-    # a router that prefers some experts, a softmax far from uniform
-    params["experts"]["router"] = params["experts"]["router"] * 8.0
-    for name in ("wq_b", "wkv_b"):
-        params["mla"][name] = params["mla"][name] * 5.0
-    rows = np.random.default_rng(0).integers(
-        0, family.vocab_size, (2, 129)).astype(np.int32)
-    tokens, targets = rows[:, :-1], rows[:, 1:]
-    loss, grads = jax.jit(jax.value_and_grad(family.loss_fn))(
-        params, tokens, targets)
-    ref_loss, ref_grads = jax.jit(jax.value_and_grad(
-        lambda p, t, y: family.reference_loss(p, t, y, 64)))(
-        params, tokens, targets)
-    assert _rel_err(loss, ref_loss) < loss_tol
-    errors = jax.tree.map(_rel_err, grads, ref_grads)
-    assert set(errors) == {"wte", "mla", "dense", "experts", "mtp",
-                           "final_norm", "lm_head"}
-    assert set(errors["mtp"]) == {"embed_norm", "hidden_norm", "w_eh",
-                                  "final_norm"}
-    for path, err in jax.tree_util.tree_flatten_with_path(errors)[0]:
-        assert err < grad_tol, (jax.tree_util.keystr(path), err)
-
-
 def test_the_pattern_trains_through_make_train_step():
     """``LDLELE`` with the module through ``make_train_step`` under
     ``jit_train_step``: the loss falls, and the step's counters hold the two
     losses beside the three expert layers' rows."""
-    config = dataclasses.replace(hybrid.HybridConfig.tiny_joyai(),
-                                 attn_impl="xla")
+    config = families.preset(FAMILY, attn_impl="xla")
     optimizer = hybrid.make_optimizer(learning_rate=3e-3)
     params = hybrid.init_params(config, jax.random.key(0))
     opt_state = optimizer.init(params)
     raw = hybrid.make_train_step(config, optimizer)
     step = jit_train_step(raw)
-    tokens, targets = _rows(config, 2)
+    tokens, targets = families.rows(config.vocab_size, seed=2)
     losses = []
     for _ in range(4):
         params, opt_state, loss = step(params, opt_state, tokens, targets)
@@ -374,58 +313,22 @@ def test_the_pattern_trains_through_make_train_step():
         rel=1e-3)
 
 
-def test_num_params_flops_and_the_first_call_record():
-    config = dataclasses.replace(hybrid.HybridConfig.tiny_joyai(),
-                                 attn_impl="xla")
-    shapes = jax.eval_shape(lambda: hybrid.init_params(config,
-                                                       jax.random.key(0)))
-    assert hybrid.num_params(config) == sum(
-        a.size for a in jax.tree.leaves(shapes))
-    D, S, H = 128, 128, 4
-    latent = D * 48 + 48 * H * 24 + D * 40 + 32 * H * 32 + H * 16 * D
-    experts = D * 16 + 3 * D * 48 * (1 + 2 * 4 / 16)
-    assert hybrid.flops_per_token(config) == 6.0 * (
-        4 * latent + 3 * D * 256 + 3 * experts + 2 * 1024 * D + 2 * D * D) \
-        + 3.0 * 4 * H * (24 + 16) * S
-    ids = jax.ShapeDtypeStruct((2, 128), jnp.int32)
-    with first_call.noting() as notes:
-        jax.eval_shape(lambda p, t: hybrid.loss_and_counters(
-            p, t, t, config), shapes, ids)
-    assert notes == {
-        "layer_kinds": "LDLELE", "mla_heads": 4, "mla_qk_head_dim": 24,
-        "mla_v_head_dim": 16, "mla_latents": (48, 32), "dense_width": 256,
-        "mtp_depth": 1, "mtp_weight": 0.3, "experts_held": 4,
-        "experts_total": 16, "router_scoring": "sigmoid",
-        "attn_positions": 128, "loss_positions": 128,
-        # q's and the rotary key's pass of each of the four latent layers
-        # (the module's among them), by the product: 24 and 8 lanes (PR 53)
-        "rope_kernel": False, "rope_calls": 8,
-        "remat_kept": [], "remat_kept_bytes": 0, "remat_room_bytes": None,
-        # the pattern's two expert layers' routing and the module's one's
-        "remat_routing_bytes": 3 * moe.routing_bytes(256, 16, 2),
-        # a window's products, gate / up and down, and their tiles (PR 50)
-        "gmm_tiles": {"64x128x48": (64, 128, 48), "64x48x128": (64, 48, 128)},
-        # off the chip a window returns by the gather (PR 57)
-        "moe_return": {"64x256x2x128": ("gather", None)}}
-
-
 def test_the_remat_rule_is_given_the_modules_sizes():
     """``_layer_sizes`` counts the module's two layers among the kept
     inputs and the candidates, and a third set of logits at the head."""
-    config = dataclasses.replace(hybrid.HybridConfig.tiny_joyai(),
-                                 attn_impl="xla")
+    config = families.preset(FAMILY, attn_impl="xla")
     plain = dataclasses.replace(config, mtp_depth=0)
 
     def sizes(config):
         shapes = jax.eval_shape(lambda: hybrid.init_params(
             config, jax.random.key(0)))
-        return hybrid._layer_sizes(shapes, (2, 128, 128), config)
+        return hybrid._layer_sizes(shapes, (2, 128, config.d_model), config)
 
     (with_module, _), (without, _) = sizes(config), sizes(plain)
     tokens = 256
-    qkv = tokens * 4 * (2 * 24 + 16) * 2
+    qkv = tokens * 2 * (2 * 24 + 16) * 2  # two heads: q and k of 24, v of 16
     shared = tokens * 2 * 48 * 2
-    dense = tokens * 2 * 256 * 2
+    dense = tokens * 2 * 128 * 2
     routing = moe.routing_bytes(tokens, 16, 2)
     assert dict(without) == {remat.QKV: 3 * qkv,
                              remat.GATE_UP: 2 * shared + dense,
@@ -435,78 +338,8 @@ def test_the_remat_rule_is_given_the_modules_sizes():
                                  remat.ROUTING: 3 * routing}
 
 
-# -------------------------------------------------- (5) the 8-bit control
-def test_the_control_is_refused():
-    """The reference on weights rounded to 8 bits (``tools/control.py``), in
-    the program's place, comes out as not correct at the seed's parameters
-    where the program itself passes, on the same rows, with room on both
-    sides of the tiny preset's limit."""
-    control = spec.load_module("tools", "control").control
-    config, family = _tiny_family()
-    # On the CPU over three seeds of uniform rows, S=128: the leaves' median
-    # error read 0.008-0.011 in the program and 0.060-0.061 in the control.
-    # The chip's readings at the cell's own size set the configuration's own
-    # limit (its ``check_why``).
-    limit = 0.025
-    mesh = make_mesh(MeshSpec(), jax.local_devices()[:1])
-    rows = np.random.default_rng(0).integers(
-        0, family.vocab_size, (1, 129)).astype(np.int32)
-    program = correct.at_the_seed(family, mesh, 0, rows, limit)
-    refused = correct.at_the_seed(control(family), mesh, 0, rows, limit)
-    assert program["ok"], program
-    assert not refused["ok"], refused
-    assert 2 * program["grad_norm_err_median"] < limit \
-        < refused["grad_norm_err_median"] / 2
-
-
-# --------------------------------- (6) nothing new on an older model's path
-def test_the_new_modules_load_with_the_first_model_that_holds_their_kind():
-    """``models/mla.py`` and ``models/dense.py`` load when a pattern with
-    ``L`` or ``D`` is built: not with ``ray_tpu``, ``ray_tpu.models.llama``
-    or ``ray_tpu.models.hybrid``, and not when the two older hybrid models
-    are initialised and traced; nor do those open a new scope or leave a
-    new counter."""
-    script = (
-        "import re, sys, jax, ray_tpu, ray_tpu.models.llama\n"
-        "from ray_tpu.models import hybrid\n"
-        "from ray_tpu.util import first_call\n"
-        "late = {'ray_tpu.models.mla', 'ray_tpu.models.dense'}\n"
-        "t = jax.ShapeDtypeStruct((2, 128), 'int32')\n"
-        "for c in (hybrid.HybridConfig.tiny(), "
-        "hybrid.HybridConfig.tiny_solar()):\n"
-        "    p = jax.eval_shape(lambda: hybrid.init_params(c, "
-        "jax.random.key(0)))\n"
-        "    hybrid.num_params(c); hybrid.flops_per_token(c)\n"
-        "    with first_call.noting() as notes:\n"
-        "        text = jax.jit(lambda p, t: hybrid.loss_and_counters(p, t, "
-        "t, c)).lower(p, t).as_text(debug_info=True)\n"
-        "    assert not {'mtp_depth', 'mla_heads', 'dense_width'} "
-        "& set(notes), notes\n"
-        "    for scope in ('latent', 'mtp', 'mtp_head'):\n"
-        "        assert not re.search(rf'[(/]{scope}[)/]', text), scope\n"
-        "    out = jax.eval_shape(lambda p, t: hybrid.loss_and_counters(p, "
-        "t, t, c), p, t)\n"
-        "    assert set(out[1]) == {'moe_rows', 'moe_moved'}, set(out[1])\n"
-        "assert not late & set(sys.modules), late & set(sys.modules)\n"
-        "hybrid.init_params(hybrid.HybridConfig.tiny_joyai(), "
-        "jax.random.key(0))\n"
-        "assert late <= set(sys.modules)\n")
-    done = subprocess.run([sys.executable, "-c", script], cwd=spec.ROOT,
-                          capture_output=True, text=True,
-                          env={"JAX_PLATFORMS": "cpu", "PATH": "/usr/bin"})
-    assert done.returncode == 0, done.stderr[-2000:]
-
-
 def test_the_registries_hold_the_new_scopes_and_counters():
+    """(That the lowered step holds them: ``tests/test_step_names.py``,
+    ``hybrid-mla``.)"""
     assert {"latent", "mtp", "mtp_head"} <= set(tracing.SCOPE_REGISTRY)
     assert {"loss_main", "loss_mtp"} <= set(tracing.STEP_COUNTER_REGISTRY)
-    config = dataclasses.replace(hybrid.HybridConfig.tiny_joyai(),
-                                 attn_impl="xla")
-    shapes = jax.eval_shape(lambda: hybrid.init_params(config,
-                                                       jax.random.key(0)))
-    ids = jax.ShapeDtypeStruct((2, 128), jnp.int32)
-    text = jax.jit(jax.grad(lambda p, t: hybrid.loss_fn(
-        p, t, t, config))).lower(shapes, ids).as_text(debug_info=True)
-    for scope in ("latent", "mtp", "mtp_head", "attn_kernel",
-                  "shared_expert"):
-        assert re.search(rf"[(/]{scope}[)/]", text), scope

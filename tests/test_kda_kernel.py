@@ -14,19 +14,14 @@ import numpy as np
 import pytest
 
 from benchmarks.reference import solar_open2 as reference
-from ray_tpu.models import hybrid
 from ray_tpu.ops import kda as kda_module
 from ray_tpu.ops import kda_kernel
 from ray_tpu.ops.kda import kda, kda_xla
-from ray_tpu.parallel import MeshSpec, make_mesh
 from ray_tpu.util import first_call
+from tests import families
+from tests.families import l2_err, out_and_grads, rel_err
 
 C, D = 64, 128
-
-
-def _rel_err(a, b):
-    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
-    return float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
 
 
 def _inputs(chunks, decay, b=2, H=2, seed=None):
@@ -40,13 +35,6 @@ def _inputs(chunks, decay, b=2, H=2, seed=None):
             jax.random.normal(k[2], (b, S, H, D)),
             -jax.nn.softplus(jax.random.normal(k[3], (b, S, H, D))) * decay,
             2.0 * jax.nn.sigmoid(jax.random.normal(k[4], (b, S, H)) + 1.0))
-
-
-def _out_and_grads(fn, args, dy):
-    def run(*args):
-        out, vjp = jax.vjp(fn, *args)
-        return out, vjp(dy)
-    return jax.jit(run)(*args)
 
 
 def _low(args):
@@ -70,18 +58,13 @@ def test_the_kernels_are_the_recurrence(chunks, heads, decay):
         assert float(jnp.min(sums)) < -500
     dy = jax.random.normal(jax.random.key(9), args[0].shape)
     with jax.default_matmul_precision("highest"):
-        got, grads = _out_and_grads(lambda *a: kda(*a, C), args, dy)
-        want, grads_ref = _out_and_grads(reference.recurrence, args, dy)
+        got, grads = out_and_grads(lambda *a: kda(*a, C), args, dy)
+        want, grads_ref = out_and_grads(reference.recurrence, args, dy)
     assert np.all(np.isfinite(got))
-    assert _rel_err(got, want) < 1e-5
+    assert rel_err(got, want) < 1e-5
     for name, g, g_ref in zip("qkvgb", grads, grads_ref):
         assert np.all(np.isfinite(g)), name
-        assert _rel_err(g, g_ref) < 1e-4, name
-
-
-def _l2_err(a, b):
-    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
-    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+        assert rel_err(g, g_ref) < 1e-4, name
 
 
 @pytest.mark.parametrize("decay", [0.3, 10.0])
@@ -96,17 +79,17 @@ def test_bf16_in_is_within_bf16s_rounding_and_no_further_than_the_xla_form(
     low = _low(_inputs(3, decay))
     exact = tuple(a.astype(jnp.float32) for a in low)
     dy = jax.random.normal(jax.random.key(9), low[0].shape, jnp.bfloat16)
-    want, grads_ref = _out_and_grads(reference.recurrence, exact,
+    want, grads_ref = out_and_grads(reference.recurrence, exact,
                                      dy.astype(jnp.float32))
-    got, grads = _out_and_grads(lambda *a: kda(*a, C), low, dy)
-    xla, grads_xla = _out_and_grads(lambda *a: kda_xla(*a, C), low, dy)
+    got, grads = out_and_grads(lambda *a: kda(*a, C), low, dy)
+    xla, grads_xla = out_and_grads(lambda *a: kda_xla(*a, C), low, dy)
     assert got.dtype == jnp.bfloat16
     for name, g, g_xla, g_ref, a in zip(
             "oqkvgb", (got,) + grads, (xla,) + grads_xla,
             (want,) + grads_ref, (low[0],) + low):
         assert g.dtype == a.dtype, name
-        assert _rel_err(g, g_ref) < 0.01, name
-        assert _l2_err(g, g_ref) <= 1.01 * _l2_err(g_xla, g_ref), name
+        assert rel_err(g, g_ref) < 0.01, name
+        assert l2_err(g, g_ref) <= 1.01 * l2_err(g_xla, g_ref), name
 
 
 @pytest.mark.parametrize("keep_states,heads", [(True, None), (False, None),
@@ -121,12 +104,12 @@ def test_the_kernels_against_the_xla_form(keep_states, heads):
     args = _inputs(3, 1.0, seed=11)
     dy = jax.random.normal(jax.random.key(3), args[0].shape)
     with jax.default_matmul_precision("highest"):
-        got, grads = _out_and_grads(lambda *a: kda_kernel.scan(
+        got, grads = out_and_grads(lambda *a: kda_kernel.scan(
             *a, C, heads, keep_states), args, dy)
-        want, grads_xla = _out_and_grads(lambda *a: kda_xla(*a, C), args, dy)
-    assert _rel_err(got, want) < 1e-5
+        want, grads_xla = out_and_grads(lambda *a: kda_xla(*a, C), args, dy)
+    assert rel_err(got, want) < 1e-5
     for name, g, g_xla in zip("qkvgb", grads, grads_xla):
-        assert _rel_err(g, g_xla) < 1e-4, name
+        assert rel_err(g, g_xla) < 1e-4, name
 
 
 def test_the_levels_pairs_tile_the_strict_lower_triangle():
@@ -144,7 +127,7 @@ def test_the_inverses_written_out_backward_is_autodiffs():
     with jax.default_matmul_precision("highest"):
         X, pull = jax.vjp(kda_module._unit_lower_inverse, N)
         got = kda_kernel.inverse_backward(X, dX)
-    assert _rel_err(got, jnp.tril(pull(dX)[0], -1)) < 1e-5
+    assert rel_err(got, jnp.tril(pull(dX)[0], -1)) < 1e-5
 
 
 def test_the_state_crosses_chunks_and_starts_a_row_at_zero():
@@ -165,21 +148,17 @@ def test_the_state_crosses_chunks_and_starts_a_row_at_zero():
         first = tuple(x[:, :C] for x in other)
         np.testing.assert_allclose(o[1, :C], scan(*first)[0], rtol=1e-6,
                                    atol=1e-6)
-        assert _rel_err(o, reference.recurrence(*both)) < 1e-5
+        assert rel_err(o, reference.recurrence(*both)) < 1e-5
     # position 0's output is ``beta (k . q) v`` of that position alone
     q, k, v, _, beta = both
     start = beta[:, 0, :, None] * jnp.sum(k[:, 0] * q[:, 0], -1,
                                           keepdims=True) * v[:, 0]
-    assert _rel_err(o[:, 0], start) < 1e-5
-
-
-def _mesh(**axes):
-    return make_mesh(MeshSpec(**axes), jax.devices()[:MeshSpec(**axes).size])
+    assert rel_err(o[:, 0], start) < 1e-5
 
 
 #: rows, positions, heads, head_dim, chunk; the mesh's axes
 CELL = (1, 8192, 8, 128, 64)
-TINY = hybrid.HybridConfig.tiny_solar()
+TINY = families.preset("solar_open2")
 PLACEMENTS = {
     "the-cell": (CELL, {}, "kernel"),
     "the-cell-on-one-device-of-a-mesh": (CELL, {"data": 1}, "kernel"),
@@ -214,7 +193,7 @@ def test_which_path_a_call_takes(name):
     and every device of the mesh can scan rows and heads of its own, the XLA
     form everywhere else."""
     (b, S, H, d, chunk), axes, want = PLACEMENTS[name]
-    mesh = _mesh(**axes).abstract_mesh if axes \
+    mesh = families.mesh(**axes).abstract_mesh if axes \
         else jax.sharding.get_abstract_mesh()
     assert kda_module.path((b, S, H, d), min(chunk, S), mesh) == want
 
@@ -232,12 +211,13 @@ def test_on_a_mesh_every_device_scans_its_own_rows_and_heads():
 
     with jax.default_matmul_precision("highest"):
         want, grads_xla = loss(kda_xla)(args)
-        with jax.set_mesh(_mesh(data=2, tensor=2)), first_call.noting() as notes:
+        with jax.set_mesh(families.mesh(data=2, tensor=2)), \
+                first_call.noting() as notes:
             got, grads = loss(kda)(args)
     assert notes == {"kda_scan_kernel": True, "kda_scan_grid": [1, 1, 2]}
     assert float(got) == pytest.approx(float(want), rel=1e-5)
     for name, g, g_xla in zip("qkvgb", grads, grads_xla):
-        assert _rel_err(g, g_xla) < 1e-4, name
+        assert rel_err(g, g_xla) < 1e-4, name
 
 
 def test_the_first_call_record_says_which_ran():

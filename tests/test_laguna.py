@@ -8,53 +8,34 @@ of the experts.
 The plain reference is ``benchmarks/reference/laguna.py``, the one copy
 (float32, masks as boolean arrays, the rotary by sliced halves, a loop over
 the held experts).  Everything runs on the CPU with seeded random weights at
-tiny sizes, attention on the einsum path unless a test says otherwise.
+tiny sizes, attention on the einsum path unless a test says otherwise.  What
+every family is held to is ``tests/test_families.py``'s, by the row
+``laguna``.
 """
 
 import dataclasses
-import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks.lib import correct, spec
+from benchmarks.lib import spec
 from benchmarks.reference import laguna as reference
 from benchmarks.reference.llama import _rmsnorm
 from ray_tpu.models import attn, hybrid, moe, window
 from ray_tpu.models.layers import Yarn, attention as attention_half, rope
 from ray_tpu.ops import attention, remat
-from ray_tpu.parallel import MeshSpec, make_mesh
-from ray_tpu.util import first_call, tracing
+from ray_tpu.util import first_call
+from tests import families
+from tests.families import rel_err
 
-#: benchmarks/lib/correct.py's, which the bf16 program is held to on the chip
-LOSS_TOL, GRAD_TOL = 1e-3, 0.75
+FAMILY = "laguna"
 
 #: the benchmark's configurations, each with a rehearsal preset
 CONFIGS = [c["name"] for c in spec.load_benchmark()["configs"]]
 
 
-def _rel_err(a, b):
-    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
-    return float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
-
-
-def _tiny_family(dtype="bfloat16", **changes):
-    config = dict(spec.load_json(spec.BENCH_DIR, "configs",
-                                 "tiny-laguna.json"), **changes)
-    config["options"] = {"attn_impl": "xla", "dtype": jnp.dtype(dtype),
-                         "logits_dtype": jnp.dtype(dtype)}
-    return config, spec.load_module("models", "laguna").build(config, 128)
-
-
-def _float32(**changes):
-    return dataclasses.replace(
-        hybrid.HybridConfig.tiny_laguna(), attn_impl="xla",
-        dtype=jnp.float32, logits_dtype=jnp.float32, **changes)
-
-
-# ------------------------------------------------------- (1) the band's mask
 def _allowed_by(run, S):
     """(S, S) booleans: the keys ``run(q, k, v)`` lets each query read.  With
     q = k = 0 every allowed key of a query gets the same weight, and with v
@@ -185,10 +166,10 @@ def test_rope_over_the_first_lanes_and_a_yarn_table(rotary, yarn):
         want, pull = jax.vjp(lambda x: reference.rotary(
             x, _rope_parameters(rotary, hd, theta, yarn)), x)
         got, ours_pull = jax.vjp(ours, x)
-        assert _rel_err(got, want) < 1e-5
-        assert _rel_err(ours_pull(do)[0], pull(do)[0]) < 1e-5
+        assert rel_err(got, want) < 1e-5
+        assert rel_err(ours_pull(do)[0], pull(do)[0]) < 1e-5
         # the backward is the rotation back, scaled as the forward is
-        assert _rel_err(ours_pull(got)[0][..., :rotary],
+        assert rel_err(ours_pull(got)[0][..., :rotary],
                         x[..., :rotary] * scale ** 2) < 1e-5
     assert np.array_equal(got[..., rotary:], x[..., rotary:])
     assert np.allclose(jnp.linalg.norm(got[..., :rotary], axis=-1),
@@ -234,7 +215,7 @@ def test_a_factor_of_one_over_all_lanes_is_todays_rope(dtype):
     assert np.allclose(table, plain, rtol=3e-7)
     with_table = jax.jit(
         lambda x: rope(x, 10000.0, 32, False, True, table, 1.0))(x)
-    assert _rel_err(with_table, today) < (1e-5 if dtype == "float32"
+    assert rel_err(with_table, today) < (1e-5 if dtype == "float32"
                                           else 1e-2)
 
 
@@ -245,7 +226,7 @@ def test_the_gate_is_one_scalar_a_head(kind):
     layer against the reference's mixer in float32, output and gradients of
     every leaf and the input, on the full kind (4 heads, half-rotary YaRN)
     and on the window kind (6 heads, 16 keys, the whole head rotating)."""
-    config = _float32()
+    config = families.float32(FAMILY)
     module = hybrid.KINDS[kind].module
     blk = jax.tree.map(lambda a: a[0] * 6.0, module.init_params(
         config, jax.random.key(0), 1, 0.02))
@@ -255,14 +236,14 @@ def test_the_gate_is_one_scalar_a_head(kind):
     assert blk["wg"].shape == (config.d_model, heads)
     x = jax.random.normal(jax.random.key(2), (2, 64, config.d_model))
     do = jax.random.normal(jax.random.key(3), x.shape)
-    cfg, _ = _tiny_family("float32")
+    cfg, _ = families.family(FAMILY, "float32")
     full, sliding = (cfg["rope_parameters"][t] for t in (
         "full_attention", "sliding_attention"))
     ref_cfg = {
         "layer_types": ["sliding_attention" if kind == "W"
                         else "full_attention"],
         "num_attention_heads_per_layer": [heads], "num_key_value_heads": 2,
-        "head_dim": 32, "sliding_window": config.window_keys,
+        "head_dim": config.head_dim, "sliding_window": config.window_keys,
         "gating_types": ["per_head"],
         "rope_parameters": {
             "full_attention": dict(
@@ -285,8 +266,8 @@ def test_the_gate_is_one_scalar_a_head(kind):
         got, grads = jax.jit(jax.value_and_grad(ours, (0, 1)))(blk, x)
         want, ref_grads = jax.jit(jax.value_and_grad(written_out, (0, 1)))(
             blk, x)
-    assert _rel_err(got, want) < 1e-5
-    errors = jax.tree.map(_rel_err, grads, ref_grads)
+    assert rel_err(got, want) < 1e-5
+    errors = jax.tree.map(rel_err, grads, ref_grads)
     assert set(errors[0]) == {"attn_norm", "wq", "wk", "wv", "wg", "wo"}
     for path, err in jax.tree_util.tree_flatten_with_path(errors)[0]:
         assert err < 2e-4, (jax.tree_util.keystr(path), err)
@@ -296,14 +277,16 @@ def test_one_attention_function_serves_both_kinds():
     """``models/window.py`` holds no layer of its own: its functions are
     ``models/attn.py``'s over the configuration ``as_attention`` gives, and
     both kinds' layers are ``layers.attention``."""
-    config = _float32()
+    config = families.float32(FAMILY)
     view = window.as_attention(config)
     assert (view.n_head, view.attn_window, view.rope_theta,
             view.rope_rotary, view.rope_yarn) == (6, 16, 10000.0, None, None)
     assert (config.n_head, config.attn_window) == (4, 0)
     assert window.as_attention(config) is view  # made once a configuration
     assert window.num_params(config) == attn.num_params(view) \
-        == 128 * 32 * (2 * 6 + 2 * 2) + 128 * 6 + 128
+        == 64 * 16 * (2 * 6 + 2 * 2) + 64 * 6 + 64
+    # charged the band's pairs, not the triangle's
+    assert window.mixer_flops(config, 128) < attn.mixer_flops(config, 128) / 2
     assert window.layer.__wrapped__ is attn.layer
     source = open(window.__file__).read()
     assert "def layer" not in source and "named_scope" not in source
@@ -317,7 +300,7 @@ def test_the_shares_of_the_experts_add_up_to_the_uncut_layer():
     once, sums to the reference's layer with every expert held."""
     from ray_tpu.models import experts
 
-    whole = _float32(experts_held=None)
+    whole = families.float32(FAMILY, experts_held=None)
     blk = jax.tree.map(lambda a: a[0], experts.init_params(
         whole, jax.random.key(0), 1, 0.02))
     blk["router"] = blk["router"] * 20.0
@@ -327,7 +310,7 @@ def test_the_shares_of_the_experts_add_up_to_the_uncut_layer():
                "router_scoring": "sigmoid"}
 
     def part(first, stop):
-        config = _float32(experts_held=range(first, stop))
+        config = families.float32(FAMILY, experts_held=range(first, stop))
         held = dict(blk, **{name: blk[name][first:stop]
                             for name in ("w_gate", "w_up", "w_down")})
         layer = experts.layer(config, experts.logical_axes(config), 0)
@@ -343,53 +326,19 @@ def test_the_shares_of_the_experts_add_up_to_the_uncut_layer():
         shared = shared_alone()
         h = _rmsnorm(x, blk["mlp_norm"], whole.rms_eps).reshape(128, -1)
         want = reference.experts(h, blk, ref_cfg).reshape(x.shape)
-    assert _rel_err(sum(parts) - 3 * shared, want) < 1e-4
-    assert all(_rel_err(p, want) > 0.05 for p in parts)  # no share is all
+    assert rel_err(sum(parts) - 3 * shared, want) < 1e-4
+    assert all(rel_err(p, want) > 0.05 for p in parts)  # no share is all
 
 
 # ------------------------------------------------------ (5) the whole model
-@pytest.mark.parametrize("dtype,loss_tol,grad_tol", [
-    # the same mathematics in another order: float32 summation order only
-    ("float32", 1e-5, 2e-4),
-    # bf16 operands, residual stream and logits under the chip run's limits
-    ("bfloat16", LOSS_TOL, GRAD_TOL),
-], ids=["float32", "bfloat16"])
-def test_loss_and_gradients_match_the_plain_reference(dtype, loss_tol,
-                                                      grad_tol):
-    config, family = _tiny_family(dtype)
-    assert spec.load_module("models", "laguna").pattern(config) \
-        == "*DWEWEWE*E"
-    params = jax.jit(family.init_fn)(jax.random.key(0))
-    # a router that prefers some experts, softmaxes far from uniform
-    params["experts"]["router"] = params["experts"]["router"] * 8.0
-    for stack in ("attn", "window"):
-        for name in ("wq", "wk", "wg"):
-            params[stack][name] = params[stack][name] * 5.0
-    rows = np.random.default_rng(0).integers(
-        0, family.vocab_size, (2, 129)).astype(np.int32)
-    tokens, targets = rows[:, :-1], rows[:, 1:]
-    loss, grads = jax.jit(jax.value_and_grad(family.loss_fn))(
-        params, tokens, targets)
-    ref_loss, ref_grads = jax.jit(jax.value_and_grad(
-        lambda p, t, y: family.reference_loss(p, t, y, 64)))(
-        params, tokens, targets)
-    assert _rel_err(loss, ref_loss) < loss_tol
-    errors = jax.tree.map(_rel_err, grads, ref_grads)
-    assert set(errors) == {"wte", "attn", "window", "dense", "experts",
-                           "final_norm", "lm_head"}
-    assert set(errors["window"]) == set(errors["attn"]) \
-        == {"attn_norm", "wq", "wk", "wv", "wg", "wo"}
-    for path, err in jax.tree_util.tree_flatten_with_path(errors)[0]:
-        assert err < grad_tol, (jax.tree_util.keystr(path), err)
-
-
 def test_the_softmax_reading_of_the_router_is_one_key():
     """``router_scoring`` is the configuration's statement of an assumption:
     under ``softmax`` the program and the reference still agree, and the
     loss is another."""
     losses = {}
     for scoring in ("sigmoid", "softmax"):
-        _, family = _tiny_family("float32", router_scoring=scoring)
+        _, family = families.family(
+            FAMILY, "float32", router_scoring=scoring)
         params = jax.jit(family.init_fn)(jax.random.key(0))
         params["experts"]["router"] = params["experts"]["router"] * 8.0
         rows = np.random.default_rng(1).integers(
@@ -397,116 +346,29 @@ def test_the_softmax_reading_of_the_router_is_one_key():
         ours = jax.jit(family.loss_fn)(params, rows[:, :-1], rows[:, 1:])
         want = jax.jit(lambda p, t, y: family.reference_loss(p, t, y, 64))(
             params, rows[:, :-1], rows[:, 1:])
-        assert _rel_err(ours, want) < 1e-5
+        assert rel_err(ours, want) < 1e-5
         losses[scoring] = float(ours)
     assert abs(losses["sigmoid"] - losses["softmax"]) > 1e-4
-
-
-def test_num_params_flops_and_the_first_call_record():
-    config = dataclasses.replace(hybrid.HybridConfig.tiny_laguna(),
-                                 attn_impl="xla")
-    shapes = jax.eval_shape(lambda: hybrid.init_params(config,
-                                                       jax.random.key(0)))
-    assert hybrid.num_params(config) == sum(
-        a.size for a in jax.tree.leaves(shapes))
-    D, S, hd, w = 128, 128, 32, 16
-    full = D * hd * (2 * 4 + 2 * 2) + D * 4
-    band = D * hd * (2 * 6 + 2 * 2) + D * 6
-    experts = D * 16 + 3 * D * 48 * (1 + 2 * 4 / 16)
-    # a window layer is charged the band's pairs, w - w (w - 1) / 2S a
-    # position, and not the triangle's S / 2
-    assert hybrid.flops_per_token(config) == 6.0 * (
-        2 * full + 3 * band + 3 * D * 256 + 4 * experts + 1024 * D) \
-        + 3.0 * (2 * 4.0 * 4 * hd * S / 2
-                 + 3 * 4.0 * 6 * hd * (w - w * (w - 1) / (2 * S)))
-    assert window.mixer_flops(config, S) < attn.mixer_flops(config, S) / 2
-    ids = jax.ShapeDtypeStruct((2, 128), jnp.int32)
-    with first_call.noting() as notes:
-        jax.eval_shape(lambda p, t: hybrid.loss_and_counters(
-            p, t, t, config), shapes, ids)
-    assert notes == {
-        "layer_kinds": "*DWEWEWE*E", "attn_positions": 128, "heads_held": 4,
-        "heads_total": 4, "attn_gate": "head", "rope_rotary_lanes": 16,
-        "rope_yarn_factor": 4.0, "attn_window": 16, "window_heads": 6,
-        "dense_width": 256, "experts_held": 4, "experts_total": 16,
-        "router_scoring": "sigmoid", "loss_positions": 128,
-        # q and k of each of the five attention layers in one call, by the
-        # product: heads of 32 lanes (PR 53)
-        "rope_kernel": False, "rope_calls": 5,
-        "remat_kept": [], "remat_kept_bytes": 0, "remat_room_bytes": None,
-        "remat_routing_bytes": 4 * moe.routing_bytes(256, 16, 2),
-        "gmm_tiles": {"64x128x48": (64, 128, 48), "64x48x128": (64, 48, 128)},
-        # off the chip a window returns by the gather (PR 57)
-        "moe_return": {"64x256x2x128": ("gather", None)}}
-    assert all(f"``{key}``" in first_call.__doc__ for key in notes)
 
 
 def test_the_remat_rule_is_given_the_window_kinds_sizes():
     """``_layer_sizes`` counts q, k and v of the window layers at their own
     head count beside the full layers'."""
-    config = dataclasses.replace(hybrid.HybridConfig.tiny_laguna(),
-                                 attn_impl="xla")
+    config = families.preset(FAMILY, attn_impl="xla")
     shapes = jax.eval_shape(lambda: hybrid.init_params(
         config, jax.random.key(0)))
-    candidates, _ = hybrid._layer_sizes(shapes, (2, 128, 128), config)
+    candidates, _ = hybrid._layer_sizes(shapes, (2, 128, config.d_model),
+                                        config)
     tokens = 256
+    # heads of 16: q's and twice the two key heads', bf16; a dense layer of
+    # 128 beside four shared experts of 48
     assert dict(candidates) == {
-        remat.QKV: tokens * 32 * 2 * (2 * (4 + 4) + 3 * (6 + 4)),
-        remat.GATE_UP: tokens * 2 * 2 * (4 * 48 + 256),
+        remat.QKV: tokens * 16 * 2 * (2 * (4 + 4) + 3 * (6 + 4)),
+        remat.GATE_UP: tokens * 2 * 2 * (4 * 48 + 128),
         remat.ROUTING: 4 * moe.routing_bytes(tokens, 16, 2)}
 
 
-def test_the_window_layers_run_under_their_own_scope():
-    assert "window" in tracing.SCOPE_REGISTRY
-    config = dataclasses.replace(hybrid.HybridConfig.tiny_laguna(),
-                                 attn_impl="xla")
-    shapes = jax.eval_shape(lambda: hybrid.init_params(config,
-                                                       jax.random.key(0)))
-    ids = jax.ShapeDtypeStruct((2, 128), jnp.int32)
-    text = jax.jit(jax.grad(lambda p, t: hybrid.loss_fn(
-        p, t, t, config))).lower(shapes, ids).as_text(debug_info=True)
-    for scope in ("attn/window", "window/attn_kernel", "attn/attn_kernel",
-                  "shared_expert", "moe_held"):
-        assert re.search(rf"[(/]{scope}[)/]", text), scope
-
-
-def test_hybrid_names_no_kind():
-    """``hybrid.py`` learns of the window kind by one line of ``KINDS``."""
-    source = open(hybrid.__file__).read()
-    assert "if kind ==" not in source and "window_attention" not in source
-    assert hybrid.KINDS["W"].stack == "window"
-
-
-# -------------------------------------------------- (6) the 8-bit control
-def test_the_control_is_refused():
-    """The reference on weights rounded to 8 bits (``tools/control.py``), in
-    the program's place, comes out as not correct at the seed's parameters,
-    by the median over the leaves and by the leaf limit alike, where the
-    program's median passes with room on both sides of the limit, on the
-    same rows.  (On the CPU over three seeds of uniform rows, S=128: the
-    median read 0.009-0.012 in the program and 0.063-0.064 in the control.
-    At this size an expert's gradient is a sum over a handful of rows, and
-    the held experts' leaves of the program read 0.04-0.19: the chip's
-    readings at the cell's own size set the configuration's limit, its
-    ``check_why``.)"""
-    control = spec.load_module("tools", "control").control
-    config, family = _tiny_family()
-    limit = 0.025
-    mesh = make_mesh(MeshSpec(), jax.local_devices()[:1])
-    rows = np.random.default_rng(0).integers(
-        0, family.vocab_size, (1, 129)).astype(np.int32)
-    program = correct.at_the_seed(family, mesh, 0, rows, limit)
-    refused = correct.at_the_seed(control(family), mesh, 0, rows, limit)
-    assert not refused["ok"], refused
-    assert 2 * program["grad_norm_err_median"] < limit \
-        < refused["grad_norm_err_median"] / 2
-    assert all(err < program["leaf_tol"]
-               for leaf, err in program["grad_norm_err_by_leaf"].items()
-               if "experts" not in leaf), program
-    assert refused["grad_norm_err_max"] > refused["leaf_tol"]
-
-
-# ------------------------- (7) the yardstick's mask areas, the tier-1 copy
+# ------------------------- (6) the yardstick's mask areas, the tier-1 copy
 def _allowed_by_the_program(kind_of_mask, S, **how):
     n = 2 * S if kind_of_mask == "block_diffusion" else S
     zeros = jnp.zeros((1, n, 1, 8), jnp.float32)

@@ -8,11 +8,11 @@ The plain reference is ``benchmarks/reference/lfm2_moe.py``, the one copy
 (float32, the convolution as three shifted multiply-adds, attention a block
 of queries at a time, a loop over the held experts).  Everything runs on the
 CPU with seeded random weights at tiny sizes, attention on the einsum path.
+What every family is held to is ``tests/test_families.py``'s, by the row
+``lfm2_moe``.
 """
 
 import dataclasses
-import hashlib
-import re
 
 import jax
 import jax.numpy as jnp
@@ -20,34 +20,14 @@ import numpy as np
 import pytest
 from jax import lax
 
-from benchmarks.lib import correct, spec
+from benchmarks.lib import spec
 from benchmarks.reference import lfm2_moe as reference
 from benchmarks.reference.llama import _rmsnorm
-from ray_tpu.models import attn, hybrid, mamba2, moe, shortconv
-from ray_tpu.parallel import MeshSpec, make_mesh
-from ray_tpu.util import first_call, tracing
+from ray_tpu.models import attn, hybrid, mamba2, shortconv
+from tests import families
+from tests.families import rel_err
 
-#: benchmarks/lib/correct.py's, which the bf16 program is held to on the chip
-LOSS_TOL, GRAD_TOL = 1e-3, 0.75
-
-
-def _rel_err(a, b):
-    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
-    return float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
-
-
-def _tiny_family(dtype="bfloat16", **changes):
-    config = dict(spec.load_json(spec.BENCH_DIR, "configs",
-                                 "tiny-lfm2.json"), **changes)
-    config["options"] = {"attn_impl": "xla", "dtype": jnp.dtype(dtype),
-                         "logits_dtype": jnp.dtype(dtype)}
-    return config, spec.load_module("models", "lfm2_moe").build(config, 128)
-
-
-def _float32(**changes):
-    return dataclasses.replace(
-        hybrid.HybridConfig.tiny_lfm2(), attn_impl="xla", dtype=jnp.float32,
-        logits_dtype=jnp.float32, **changes)
+FAMILY = "lfm2_moe"
 
 
 def _conv_layer(config, seed=0):
@@ -65,7 +45,7 @@ def test_the_mixer_is_the_references_three_shifted_multiply_adds(positions):
     """Forward and every gradient of a ``C`` layer against the reference's
     mixer under the same pre-norm, on rows of one position (two of the three
     taps read zeros), of fewer positions than taps and of many."""
-    config = _float32()
+    config = families.float32(FAMILY)
     blk, layer = _conv_layer(config)
     x = jax.random.normal(jax.random.key(2), (3, positions, config.d_model))
 
@@ -78,12 +58,12 @@ def test_the_mixer_is_the_references_three_shifted_multiply_adds(positions):
 
     probe = jax.random.normal(jax.random.key(3), x.shape)
     with jax.default_matmul_precision("highest"):
-        assert _rel_err(ours(x, blk), theirs(x, blk)) < 1e-5
+        assert rel_err(ours(x, blk), theirs(x, blk)) < 1e-5
         got, want = (jax.grad(lambda x, blk: jnp.sum(f(x, blk) * probe),
                               argnums=(0, 1))(x, blk)
                      for f in (ours, theirs))
     for path, err in jax.tree_util.tree_flatten_with_path(
-            jax.tree.map(_rel_err, got, want))[0]:
+            jax.tree.map(rel_err, got, want))[0]:
         assert err < 2e-5, (jax.tree_util.keystr(path), err)
 
 
@@ -148,17 +128,17 @@ def test_causal_conv_without_a_bias_is_laxs_convolution(taps):
             precision=lax.Precision.HIGHEST)
 
     probe = jax.random.normal(jax.random.key(2), x.shape)
-    assert _rel_err(mamba2.causal_conv(x, w, None), by_lax(x, w)) < 1e-5
+    assert rel_err(mamba2.causal_conv(x, w, None), by_lax(x, w)) < 1e-5
     got, want = (jax.grad(lambda x, w: jnp.sum(f(x, w) * probe),
                           argnums=(0, 1))(x, w)
                  for f in (lambda x, w: mamba2.causal_conv(x, w, None),
                            by_lax))
-    assert _rel_err(got[0], want[0]) < 1e-5
-    assert _rel_err(got[1], want[1]) < 1e-5
+    assert rel_err(got[0], want[0]) < 1e-5
+    assert rel_err(got[1], want[1]) < 1e-5
     b = jax.random.normal(jax.random.key(3), (6,))
-    assert _rel_err(mamba2.causal_conv(x, w, b), by_lax(x, w) + b) < 1e-5
+    assert rel_err(mamba2.causal_conv(x, w, b), by_lax(x, w) + b) < 1e-5
     db = jax.grad(lambda b: jnp.sum(mamba2.causal_conv(x, w, b) * probe))(b)
-    assert _rel_err(db, jnp.sum(probe, axis=(0, 1))) < 1e-5
+    assert rel_err(db, jnp.sum(probe, axis=(0, 1))) < 1e-5
 
 
 # ----------------------------------------------------- (3) the tied head
@@ -166,7 +146,7 @@ def test_the_tied_heads_embedding_gradient_is_the_gathers_plus_the_heads():
     """With ``tie_head`` no ``lm_head`` leaf exists, and ``wte``'s gradient
     is the sum of what the untied model, its head set to the embedding,
     gives ``wte`` (the gather's) and ``lm_head`` (the head's)."""
-    tied = _float32(pattern="CD*E")
+    tied = families.float32(FAMILY, pattern="CD*E")
     untied = dataclasses.replace(tied, tie_head=False)
     params = hybrid.init_params(tied, jax.random.key(0))
     assert "lm_head" not in params
@@ -186,10 +166,10 @@ def test_the_tied_heads_embedding_gradient_is_the_gathers_plus_the_heads():
         loss2, grads2 = jax.value_and_grad(hybrid.loss_fn)(
             both, tokens, targets, untied)
     assert float(loss) == pytest.approx(float(loss2), rel=1e-6)
-    assert _rel_err(grads["wte"], grads2["wte"] + grads2["lm_head"]) < 1e-5
+    assert rel_err(grads["wte"], grads2["wte"] + grads2["lm_head"]) < 1e-5
     # neither part alone is the gradient
-    assert _rel_err(grads["wte"], grads2["wte"]) > 0.1
-    assert _rel_err(grads["wte"], grads2["lm_head"]) > 0.01
+    assert rel_err(grads["wte"], grads2["wte"]) > 0.1
+    assert rel_err(grads["wte"], grads2["lm_head"]) > 0.01
 
 
 # -------------------------------------- (4) attention's QK-norm on this path
@@ -197,7 +177,7 @@ def test_the_attention_kind_norms_q_and_k_a_head():
     """``qk_norm="head"`` gives the kind one weight of ``head_dim`` for q and
     one for k a layer, counted in ``num_params``; without it the kind's
     parameters are what they were."""
-    config = _float32()
+    config = families.float32(FAMILY)
     plain = dataclasses.replace(config, qk_norm=False)
     params = attn.init_params(config, jax.random.key(0), 2, 0.02)
     before = attn.init_params(plain, jax.random.key(0), 2, 0.02)
@@ -219,7 +199,7 @@ def test_the_four_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
     expert held: no shared expert, nothing counted twice."""
     from ray_tpu.models import experts
 
-    whole = _float32(experts_held=None)
+    whole = families.float32(FAMILY, experts_held=None)
     blk = jax.tree.map(lambda a: a[0], experts.init_params(
         whole, jax.random.key(0), 1, 0.02))
     blk["router"] = blk["router"] * 20.0
@@ -230,7 +210,7 @@ def test_the_four_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
                "router_bias_std": 0.05}
 
     def part(first, stop):
-        config = _float32(experts_held=range(first, stop))
+        config = families.float32(FAMILY, experts_held=range(first, stop))
         held = dict(blk, **{name: blk[name][first:stop]
                             for name in ("w_gate", "w_up", "w_down")})
         layer = experts.layer(config, experts.logical_axes(config), 0)
@@ -242,171 +222,26 @@ def test_the_four_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
         want = reference.experts(h, blk, ref_cfg, 0).reshape(x.shape)
         unbiased = reference.experts(
             h, blk, dict(ref_cfg, router_bias_std=0.0), 0).reshape(x.shape)
-    assert _rel_err(sum(parts), want) < 1e-4
-    assert all(_rel_err(p, want) > 0.05 for p in parts)  # no share is all
-    assert _rel_err(unbiased, want) > 1e-3  # the bias picked some experts
+    assert rel_err(sum(parts), want) < 1e-4
+    assert all(rel_err(p, want) > 0.05 for p in parts)  # no share is all
+    assert rel_err(unbiased, want) > 1e-3  # the bias picked some experts
 
 
-# ------------------------------------------------------ (6) the whole model
-@pytest.mark.parametrize("dtype,loss_tol,grad_tol", [
-    # the same mathematics in another order: float32 summation order only
-    ("float32", 1e-5, 2e-4),
-    # bf16 operands, residual stream and logits under the chip run's limits
-    ("bfloat16", LOSS_TOL, GRAD_TOL),
-], ids=["float32", "bfloat16"])
-def test_loss_and_gradients_match_the_plain_reference(dtype, loss_tol,
-                                                      grad_tol):
-    config, family = _tiny_family(dtype)
-    module = spec.load_module("models", "lfm2_moe")
-    assert module.layers_run(config) == [0, 2, 3, 4, 5]
-    assert module.pattern(config) == "CD*ECE*ECE" \
-        == hybrid.HybridConfig.tiny_lfm2().pattern
-    params = jax.jit(family.init_fn)(jax.random.key(0))
-    # a router that prefers some experts, softmaxes far from uniform, norms
-    # and taps that are no identity
-    params["experts"]["router"] = params["experts"]["router"] * 8.0
-    for name in ("wq", "wk"):
-        params["attn"][name] = params["attn"][name] * 5.0
-    for stack, name in (("attn", "q_norm"), ("attn", "k_norm"),
-                        ("shortconv", "conv_norm")):
-        params[stack][name] = params[stack][name] + 0.2 * jax.random.normal(
-            jax.random.key(7), params[stack][name].shape)
-    params["shortconv"]["in_proj"] = params["shortconv"]["in_proj"] * 20.0
-    rows = np.random.default_rng(0).integers(
-        0, family.vocab_size, (2, 129)).astype(np.int32)
-    tokens, targets = rows[:, :-1], rows[:, 1:]
-    loss, grads = jax.jit(jax.value_and_grad(family.loss_fn))(
-        params, tokens, targets)
-    ref_loss, ref_grads = jax.jit(jax.value_and_grad(
-        lambda p, t, y: family.reference_loss(p, t, y, 64)))(
-        params, tokens, targets)
-    assert _rel_err(loss, ref_loss) < loss_tol
-    errors = jax.tree.map(_rel_err, grads, ref_grads)
-    assert set(errors) == {"wte", "attn", "shortconv", "dense", "experts",
-                           "final_norm"}
-    assert set(errors["shortconv"]) == {"conv_norm", "in_proj", "conv_w",
-                                        "out_proj"}
-    assert set(errors["attn"]) == {"attn_norm", "wq", "wk", "wv", "wo",
-                                   "q_norm", "k_norm"}
-    for path, err in jax.tree_util.tree_flatten_with_path(errors)[0]:
-        assert err < grad_tol, (jax.tree_util.keystr(path), err)
-
-
-def test_the_preset_is_the_rehearsal_file():
-    """``HybridConfig.tiny_lfm2()`` is what the family builds from
-    ``tiny-lfm2.json``."""
-    config = spec.load_json(spec.BENCH_DIR, "configs", "tiny-lfm2.json")
-    _, model = spec.load_module("models", "lfm2_moe").model_config(config,
-                                                                   128)
-    assert model == hybrid.HybridConfig.tiny_lfm2()
-
-
-def test_num_params_flops_and_the_first_call_record():
-    config = dataclasses.replace(hybrid.HybridConfig.tiny_lfm2(),
-                                 attn_impl="xla")
-    shapes = jax.eval_shape(lambda: hybrid.init_params(config,
-                                                       jax.random.key(0)))
-    assert hybrid.num_params(config) == sum(
-        a.size for a in jax.tree.leaves(shapes))
-    D, S, hd = 128, 128, 32
-    conv = 4 * D * D
-    full = D * hd * (2 * 4 + 2 * 2)
-    experts = D * 16 + 3 * D * 48 * 2 * 4 / 16
-    # the head once (the embedding is a gather); a convolution layer's taps
-    # and gates as 2 K + 2 FLOPs a channel, no S x S product
-    assert hybrid.flops_per_token(config) == 6.0 * (
-        3 * conv + 2 * full + 3 * D * 256 + 4 * experts + 1024 * D) \
-        + 3.0 * (2 * 4.0 * 4 * hd * S / 2 + 3 * (2 * 3 + 2) * D)
-    assert shortconv.mixer_flops(config, S) == 8 * D
-    assert shortconv.num_params(config) == conv + 3 * D + D
-    ids = jax.ShapeDtypeStruct((2, 128), jnp.int32)
-    with first_call.noting() as notes:
-        jax.eval_shape(lambda p, t: hybrid.loss_and_counters(
-            p, t, t, config), shapes, ids)
-    assert notes == {
-        "layer_kinds": "CD*ECE*ECE", "attn_positions": 128, "heads_held": 4,
-        "heads_total": 4, "attn_gate": False, "qk_norm": "head",
-        "dense_width": 256, "experts_held": 4, "experts_total": 16,
-        "router_scoring": "sigmoid", "loss_positions": 128,
-        "shortconv_taps": 3, "shortconv_width": 128, "shortconv_layers": 3,
-        # q and k of each of the two attention layers in one call, by the
-        # product: heads of 32 lanes
-        "rope_kernel": False, "rope_calls": 2,
-        "remat_kept": [], "remat_kept_bytes": 0, "remat_room_bytes": None,
-        "remat_routing_bytes": 4 * moe.routing_bytes(256, 16, 2),
-        "gmm_tiles": {"64x128x48": (64, 128, 48), "64x48x128": (64, 48, 128)},
-        # off the chip a window returns by the gather (PR 57)
-        "moe_return": {"64x256x2x128": ("gather", None)}}
-    assert all(f"``{key}``" in first_call.__doc__ for key in notes)
-
-
-def test_the_convolution_layers_run_under_their_own_scopes():
-    assert {"shortconv", "shortconv_gate"} <= set(tracing.SCOPE_REGISTRY)
-    config = dataclasses.replace(hybrid.HybridConfig.tiny_lfm2(),
-                                 attn_impl="xla")
-    shapes = jax.eval_shape(lambda: hybrid.init_params(config,
-                                                       jax.random.key(0)))
-    ids = jax.ShapeDtypeStruct((2, 128), jnp.int32)
-    text = jax.jit(jax.grad(lambda p, t: hybrid.loss_fn(
-        p, t, t, config))).lower(shapes, ids).as_text(debug_info=True)
-    for scope in ("shortconv", "shortconv/shortconv_gate", "attn_kernel",
-                  "moe_held"):
-        assert re.search(rf"[(/]{scope}[)/]", text), scope
-    assert "shared_expert" not in text
-
-
-def test_hybrid_names_no_kind():
-    """``hybrid.py`` learns of the convolution kind by one line of
-    ``KINDS``; the kind's convolution is ``mamba2.causal_conv``."""
-    source = open(hybrid.__file__).read()
-    assert "if kind ==" not in source and "gated_conv" not in source
-    assert hybrid.KINDS["C"].stack == "shortconv"
+# ----------------------------------------- (6) the kind and the file's cut
+def test_the_convolution_kind_counts_itself_and_borrows_its_convolution():
+    """The kind's taps and gates are 2 K + 2 FLOPs a channel and no S x S
+    product, its parameters the two projections, the taps and the norm; its
+    convolution is ``mamba2.causal_conv``."""
+    config = families.preset(FAMILY)
+    D = config.d_model
+    assert shortconv.mixer_flops(config, 128) == 8 * D
+    assert shortconv.num_params(config) == 4 * D * D + 3 * D + D
     assert hybrid.KINDS["C"].module is shortconv
     assert shortconv.causal_conv is mamba2.causal_conv
 
 
-# -------------------------------------------------- (7) the 8-bit control
-def test_the_control_is_refused():
-    """The reference on weights rounded to 8 bits (``tools/control.py``), in
-    the program's place, comes out as not correct at the seed's parameters
-    where the program's median passes with room on both sides of the limit,
-    on the same rows.  (The chip's readings at the cell's own size set the
-    configuration's limit, its ``check_why``.)"""
-    control = spec.load_module("tools", "control").control
-    config, family = _tiny_family()
-    limit = 0.03
-    mesh = make_mesh(MeshSpec(), jax.local_devices()[:1])
-    rows = np.random.default_rng(0).integers(
-        0, family.vocab_size, (1, 129)).astype(np.int32)
-    program = correct.at_the_seed(family, mesh, 0, rows, limit)
-    refused = correct.at_the_seed(control(family), mesh, 0, rows, limit)
-    assert not refused["ok"], refused
-    assert 2 * program["grad_norm_err_median"] < limit \
-        < refused["grad_norm_err_median"] / 2, (program, refused)
-
-
-# ----------------------- (8) the accepted hybrid steps' programs are untouched
-#: sha256 of the text jax lowers the two hybrid presets to that
-#: ``tests/test_nemotron_h.py`` does not pin, recorded on the parent of PR 56
-#: (as that file's ``LOWERED_STEPS``): the tied head, the attention kind's
-#: QK-norm, ``causal_conv``'s optional bias and the kind ``C`` trace nothing
-#: in a step that does not ask for them.
-LOWERED_STEPS = {
-    "tiny-joyai":
-        "7cb8b553df9b979565e3c60edfb55822845a5f682d8028618f2fe4b252be571f",
-    "tiny-laguna":
-        "e7ac8f3d993d39401b7abb897f8b439c66446ced01ce2ba9b4e6889fbe971200",
-}
-
-
-@pytest.mark.parametrize("name", sorted(LOWERED_STEPS))
-def test_the_accepted_hybrid_steps_lower_to_the_parents_text(name):
-    config = spec.load_json(spec.BENCH_DIR, "configs", name + ".json")
-    family = spec.load_module("models", config["family"]).build(config, 128)
-    optimizer = family.make_optimizer()
-    params = jax.eval_shape(family.init_fn, jax.random.key(0))
-    opt_state = jax.eval_shape(optimizer.init, params)
-    ids = jax.ShapeDtypeStruct((2, 128), jnp.int32)
-    text = jax.jit(family.make_train_step(optimizer)).lower(
-        params, opt_state, ids, ids).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == LOWERED_STEPS[name]
+def test_the_rehearsal_file_runs_the_layers_its_cut_names():
+    config = spec.load_json(spec.BENCH_DIR, "configs", "tiny-lfm2.json")
+    module = spec.load_module("models", FAMILY)
+    assert module.layers_run(config) == [0, 2, 3, 4, 5]
+    assert module.pattern(config) == "CD*ECE*ECE"

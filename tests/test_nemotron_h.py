@@ -1,7 +1,8 @@
 """NVIDIA Nemotron-3-Nano through ``models/hybrid.py``: a stack of Mamba-2
 (``models/mamba2.py`` over ``ops/ssd.py``), attention and expert layers
 (``models/layers.py``, ``models/moe.py`` with sigmoid scores, a selection
-bias, two-matrix relu^2 experts and a shared expert).
+bias, two-matrix relu^2 experts and a shared expert).  What every family is
+held to is ``tests/test_families.py``'s, by the row ``nemotron_h``.
 
 The plain reference is ``benchmarks/reference/nemotron_h.py``, the one copy
 (float32, the recurrence position by position, every held expert applied to
@@ -11,9 +12,6 @@ path than its kernel in interpret mode.
 """
 
 import dataclasses
-import hashlib
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -21,30 +19,15 @@ import numpy as np
 import optax
 import pytest
 
-from benchmarks.lib import correct, spec
 from benchmarks.reference import nemotron_h as reference
 from ray_tpu.models import experts, hybrid, mamba2, moe
 from ray_tpu.ops.ssd import ssd
 from ray_tpu.parallel import MeshSpec, make_mesh
 from ray_tpu.parallel.train_state import (create_sharded_state,
                                           jit_train_step)
-from ray_tpu.util import device_telemetry, first_call
-
-#: benchmarks/lib/correct.py's, which the bf16 program is held to on the chip
-LOSS_TOL, GRAD_TOL = 1e-3, 0.75
-
-
-def _rel_err(a, b):
-    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
-    return float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
-
-
-def _tiny_family(dtype="bfloat16", **changes):
-    config = dict(spec.load_json(spec.BENCH_DIR, "configs",
-                                 "tiny-nemotron-h.json"), **changes)
-    config["options"] = {"attn_impl": "xla", "dtype": jnp.dtype(dtype),
-                         "logits_dtype": jnp.dtype(dtype)}
-    return config, spec.load_module("models", "nemotron_h").build(config, 128)
+from ray_tpu.util import device_telemetry
+from tests import families
+from tests.families import rel_err
 
 
 # ------------------------------------------------------- (1) the chunked scan
@@ -86,13 +69,13 @@ def test_chunked_scan_is_the_recurrence(chunks):
     with jax.default_matmul_precision("highest"):
         got, vjp = jax.vjp(lambda a: _chunked(a, 8), a)
         want, vjp_ref = jax.vjp(_position_by_position, a)
-        assert _rel_err(got, want) < 1e-5
+        assert rel_err(got, want) < 1e-5
         dy = jax.random.normal(jax.random.key(9), want.shape)
         (grads,), (grads_ref,) = vjp(dy), vjp_ref(dy)
     for name in a:
         assert np.all(np.isfinite(grads[name])), name
         # float32 sums in another order; A_log's is one sum over everything
-        assert _rel_err(grads[name], grads_ref[name]) < 1e-3, name
+        assert rel_err(grads[name], grads_ref[name]) < 1e-3, name
 
 
 def test_scan_products_are_in_the_inputs_dtype():
@@ -102,12 +85,12 @@ def test_scan_products_are_in_the_inputs_dtype():
     low = dict(a, **{k: a[k].astype(jnp.bfloat16) for k in ("x", "B", "C")})
     got = _chunked(low, 8)
     assert got.dtype == jnp.bfloat16
-    assert _rel_err(got, _position_by_position(a)) < 0.05
+    assert rel_err(got, _position_by_position(a)) < 0.05
 
 
 # ------------------------------------------------------------- (2) the mixer
 def _mixer_parts(dtype=jnp.float32):
-    config = dataclasses.replace(hybrid.HybridConfig.tiny(), dtype=dtype)
+    config = families.preset("nemotron_h", dtype=dtype)
     blk = jax.tree.map(lambda a: a[0], mamba2.init_params(
         config, jax.random.key(0), 1, 0.02))
     # every vector away from its start, so that a lost one shows
@@ -130,7 +113,7 @@ def test_mixer_matches_the_reference():
         u = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True)
                               + config.rms_eps) * blk["ssm_norm"]
         want = reference.mamba(u, blk, cfg)
-    assert _rel_err(got, want) < 1e-5
+    assert rel_err(got, want) < 1e-5
 
 
 def test_the_convolution_is_causal():
@@ -205,9 +188,7 @@ def test_the_bias_is_no_leaf_and_survives_an_optimizer_step():
     """The selection bias is a function of the configuration: the parameter
     tree holds no leaf for it, so the optimizer has nothing to update, and
     after a step that moved every parameter the layers draw the same bias."""
-    config = dataclasses.replace(hybrid.HybridConfig.tiny(), attn_impl="xla",
-                                 dtype=jnp.float32,
-                                 logits_dtype=jnp.float32)
+    config = families.float32("nemotron_h")
     before = [experts.router_bias(config, i) for i in range(4)]
     assert all(b.shape == (config.n_experts,) and np.any(b) for b in before)
     assert not np.allclose(before[0], before[1])  # a draw a layer
@@ -216,10 +197,8 @@ def test_the_bias_is_no_leaf_and_survives_an_optimizer_step():
     assert (config.count("E"), config.n_experts) not in sizes
     optimizer = optax.adamw(1e-2)
     step = jax.jit(hybrid.make_train_step(config, optimizer))
-    ids = np.random.default_rng(0).integers(0, 1024, (2, 129)).astype(
-        np.int32)
-    moved, _, loss = step(params, optimizer.init(params), ids[:, :-1],
-                          ids[:, 1:])
+    tokens, targets = families.rows(config.vocab_size)
+    moved, _, loss = step(params, optimizer.init(params), tokens, targets)
     assert np.isfinite(float(loss))
     assert all(not np.array_equal(a, b) for a, b in zip(
         jax.tree.leaves(params), jax.tree.leaves(moved)))
@@ -230,7 +209,7 @@ def test_the_bias_is_no_leaf_and_survives_an_optimizer_step():
 def test_the_bias_changes_some_of_the_choices():
     """At the tiny preset's spread the bias changes some tokens' experts and
     leaves others': the mechanism is no no-op and does not take over."""
-    config, family = _tiny_family("float32")
+    config, family = families.family("nemotron_h", "float32")
     h = jax.random.normal(jax.random.key(0), (512, config["hidden_size"]))
     w = jax.random.normal(jax.random.key(1), (config["hidden_size"], 16)) \
         * 0.02 * np.sqrt(2688 / 64)  # the published width's spread of logits
@@ -279,231 +258,10 @@ def test_the_shares_add_up_to_the_uncut_layer(shares):
                 blk.update(shared_up=whole["shared_up"],
                            shared_down=whole["shared_down"])
             total = total + layer(blk, first)
-    assert _rel_err(total.reshape(-1, D), want) < 1e-5
+    assert rel_err(total.reshape(-1, D), want) < 1e-5
 
 
-# ------------------------------------------------------ (5) the whole model
-@pytest.mark.parametrize("dtype,loss_tol,grad_tol", [
-    # the same mathematics in another order: float32 summation order only
-    ("float32", 1e-5, 2e-4),
-    # bf16 operands, residual stream and logits under the chip run's limits
-    ("bfloat16", LOSS_TOL, GRAD_TOL),
-], ids=["float32", "bfloat16"])
-def test_loss_and_gradients_match_the_plain_reference(dtype, loss_tol,
-                                                      grad_tol):
-    config, family = _tiny_family(dtype)
-    assert config["hybrid_override_pattern"] == "MEMEM*EME"
-    params = jax.jit(family.init_fn)(jax.random.key(0))
-    # a router that prefers some experts and a scan whose decays matter
-    params["experts"]["router"] = params["experts"]["router"] * 20.0
-    params["ssm"]["in_proj"] = params["ssm"]["in_proj"] * 5.0
-    rows = np.random.default_rng(0).integers(
-        0, family.vocab_size, (2, 129)).astype(np.int32)
-    tokens, targets = rows[:, :-1], rows[:, 1:]
-    loss, grads = jax.jit(jax.value_and_grad(family.loss_fn))(
-        params, tokens, targets)
-    ref_loss, ref_grads = jax.jit(jax.value_and_grad(
-        lambda p, t, y: family.reference_loss(p, t, y, 64)))(
-        params, tokens, targets)
-    assert _rel_err(loss, ref_loss) < loss_tol
-    errors = jax.tree.map(_rel_err, grads, ref_grads)
-    assert set(errors) == {"wte", "ssm", "attn", "experts", "final_norm",
-                           "lm_head"}
-    for path, err in jax.tree_util.tree_flatten_with_path(errors)[0]:
-        assert err < grad_tol, (jax.tree_util.keystr(path), err)
-
-
-def test_counters_leave_the_step_stacked_by_expert_layer():
-    config = dataclasses.replace(hybrid.HybridConfig.tiny(), attn_impl="xla")
-    params = hybrid.init_params(config, jax.random.key(0))
-    ids = np.random.default_rng(1).integers(0, 1024, (2, 128)).astype(
-        np.int32)
-    _, counts = jax.jit(lambda p: hybrid.loss_and_counters(
-        p, ids, ids, config))(params)
-    assert counts["moe_rows"].shape == (4, 1, 4)   # E layers, shards, held
-    assert counts["moe_moved"].shape == (4, 1)
-    pairs = 2 * 128 * config.experts_per_token
-    assert np.all(np.asarray(counts["moe_rows"]).sum(-1) <= pairs)
-    assert np.all(np.asarray(counts["moe_moved"])
-                  % moe.window_rows(pairs) == 0)
-
-
-def test_num_params_and_the_first_call_record():
-    config = dataclasses.replace(hybrid.HybridConfig.tiny(), attn_impl="xla")
-    shapes = jax.eval_shape(lambda: hybrid.init_params(config,
-                                                       jax.random.key(0)))
-    assert hybrid.num_params(config) == sum(
-        a.size for a in jax.tree.leaves(shapes))
-    ids = jax.ShapeDtypeStruct((2, 128), jnp.int32)
-    with first_call.noting() as notes:
-        jax.eval_shape(lambda p, t: hybrid.loss_and_counters(
-            p, t, t, config), shapes, ids)
-    assert notes == {
-        "layer_kinds": "MEMEM*EME", "ssm_heads": 8, "ssm_state": 16,
-        "ssm_chunk": 32, "ssm_chunks": 8, "ssm_scan_kernel": False,
-        "ssm_scan_grid": None, "experts_held": 4,
-        "experts_total": 16, "router_scoring": "sigmoid",
-        "attn_positions": 128, "loss_positions": 128,
-        # the attention kind's own since PR 46, whatever else the pattern holds
-        "heads_held": 4, "heads_total": 4, "attn_gate": False,
-        "remat_kept": [], "remat_kept_bytes": 0, "remat_room_bytes": None,
-        # four expert layers' routing, kept under every policy (PR 48)
-        "remat_routing_bytes": 4 * moe.routing_bytes(256, 16, 2),
-        # a window's products, up and down, and the tiles they walk (PR 50)
-        "gmm_tiles": {"64x128x64": (64, 128, 64), "64x64x128": (64, 64, 128)},
-        # off the chip a window returns by the gather (PR 57)
-        "moe_return": {"64x256x2x128": ("gather", None)}}
-
-
-# -------------------------------------------------- (6) the 8-bit control
-def test_the_control_is_refused():
-    """The reference on weights rounded to 8 bits (``tools/control.py``), in
-    the program's place, comes out as not correct at the seed's parameters
-    where the program itself passes, on the same rows, with room on both
-    sides of the tiny preset's limit."""
-    control = spec.load_module("tools", "control").control
-    config, family = _tiny_family()
-    # On the CPU over four seeds of uniform rows, S=128: the leaves' median
-    # error read 0.0120-0.0124 in the program (largest leaf 0.048-0.107) and
-    # 0.102-0.109 in the control (largest leaf 0.41-0.50, over the 0.12 that
-    # three times the limit allows).  The chip's readings at the cell's own
-    # size set the configuration's own limit (its ``check_why``).
-    limit = 0.04
-    mesh = make_mesh(MeshSpec(), jax.local_devices()[:1])
-    for seed in (0, 1):
-        rows = np.random.default_rng(seed).integers(
-            0, family.vocab_size, (1, 129)).astype(np.int32)
-        program = correct.at_the_seed(family, mesh, seed, rows, limit)
-        refused = correct.at_the_seed(control(family), mesh, seed, rows,
-                                      limit)
-        assert program["ok"], program
-        assert not refused["ok"], refused
-        assert 2 * program["grad_norm_err_median"] < limit \
-            < refused["grad_norm_err_median"] / 2
-
-
-# ------------------------------ (7) the other models' programs are untouched
-#: sha256 of the text jax lowers each family's tiny train step to (no
-#: locations in it), recorded on the parent of PR 40: the two halves of
-#: ``llama._block`` moved into ``models/layers.py`` and ``models/moe.py``
-#: learned a second scoring, a second activation and a shared expert without
-#: one operation of these steps changing.  A change that means to alter one
-#: of these programs records the new hash here and says so.  **PR 48 meant
-#: to, in the four with experts**: what the router decided bears a name the
-#: checkpoint keeps, the ids are ``top_k``'s of a value without a gradient
-#: and the scores are read at them by a select and a sum (the forward's
-#: numbers and, in float32, every gradient are the parent's to the bit:
-#: ``tests/test_olmoe.py``, ``PERF.md`` PR 48); the two dense ones are the
-#: parent's.  **PR 55 meant to, in the three whose expert layers hold a
-#: share** (``tiny-sdar``, ``tiny-nemotron-h``, ``tiny-solar-open2``): the
-#: window's combine gathers slot-major, (k, N, D), and sums over axis 0
-#: (three steps of each on this CPU leave the parent's parameters and losses
-#: to the bit; ``tests/test_moe_combine_layout.py``); ``tiny-olmoe`` holds
-#: every expert and runs ``_combine``, untouched.
-LOWERED_STEPS = {
-    "tiny-llama":
-        "f6d4a6b1cbf541233f13675bccbe7766fcb6a630709a7aa7b7f50a0428b1fe2c",
-    "tiny-olmoe":
-        "115daacfda98cd00748642ce33854899b338aef11544f9ea9a8dcadda6abf25f",
-    "tiny-sdar":
-        "a8918c30e97c45dcea988163b92a015cf348df7dec773fcc53019d75b7e138b6",
-    "tiny-gpt2":
-        "86135e6f43a576200ef38b5a76d717607699853ed5341b6350fd1c81db4dbe04",
-    # the two hybrid presets, as PR 43 left them (added at PR 44: the tiny
-    # Mamba-2 sizes lie off the chip's tiles and take ``ops.ssd.ssd_xla``,
-    # whose lowered text is the parent's ``ssd``)
-    "tiny-nemotron-h":
-        "d911af43f10569b30e177e26e8d157abf9112a654b5409cbfe0bc5484e1f12c4",
-    "tiny-solar-open2":
-        "ba7f4d2464639435af96c62f55186c6f8fb59b856fd6788db50aa64331b4c1fa",
-}
-
-
-@pytest.mark.parametrize("name", sorted(LOWERED_STEPS))
-def test_the_older_models_lower_to_the_parents_text(name):
-    config = spec.load_json(spec.BENCH_DIR, "configs", name + ".json")
-    family = spec.load_module("models", config["family"]).build(config, 128)
-    optimizer = family.make_optimizer()
-    params = jax.eval_shape(family.init_fn, jax.random.key(0))
-    opt_state = jax.eval_shape(optimizer.init, params)
-    ids = jax.ShapeDtypeStruct((2, 128), jnp.int32)
-    text = jax.jit(family.make_train_step(optimizer)).lower(
-        params, opt_state, ids, ids).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == LOWERED_STEPS[name]
-
-
-# ------------------------------- (8) a kind is one entry of ``hybrid.KINDS``
-#: sha256 over the leaves of each hybrid preset's parameters at key 0 (path,
-#: dtype, shape, bytes; leaves in jax's order), recorded on the parent of
-#: PR 46, where ``hybrid.init_params`` drew every kind's stack itself: the
-#: kinds' modules draw from the same keys, so a cell's parameters stay the
-#: function of ``--seed`` and ``init_seed`` its warm-up was fitted to
-INIT_PARAMS = {
-    "tiny-nemotron-h":
-        "ebfc85ea46efc41f6519187746add9f4b03743061be81a10de24e0c863c87223",
-    "tiny-solar-open2":
-        "32dcf88a013bce5ad0b19cef861903d502cd126a2f676b45b698bcfd2b54a705",
-}
-
-
-@pytest.mark.parametrize("name", sorted(INIT_PARAMS))
-def test_the_parameters_are_the_parents_bit_for_bit(name):
-    config = spec.load_json(spec.BENCH_DIR, "configs", name + ".json")
-    family = spec.load_module("models", config["family"]).build(config, 128)
-    h = hashlib.sha256()
-    for path, leaf in jax.tree_util.tree_flatten_with_path(
-            jax.jit(family.init_fn)(jax.random.key(0)))[0]:
-        a = np.asarray(leaf)
-        for part in (jax.tree_util.keystr(path), str(a.dtype), str(a.shape)):
-            h.update(part.encode())
-        h.update(a.tobytes())
-    assert h.hexdigest() == INIT_PARAMS[name]
-
-
-def _cell_config(name):
-    """(the configuration a cell of the benchmark trains, its batch)."""
-    cell = spec.load_cell(spec.load_benchmark(), name)
-    config, traffic = cell["config_file"], cell["traffic_file"]
-    model = spec.load_module("models", config["family"]).model_config(
-        config, traffic["seq_len"])[1]
-    return model, (traffic["seqs_per_chip"] * cell["chips"],
-                   traffic["seq_len"])
-
-
-#: ``hybrid._layer_sizes`` on the parent of PR 46, (q/k/v bytes, gate/up
-#: bytes, the bound on the step's temporaries): what ``ops/remat.py`` decides
-#: from.  The kinds' ``layer_bytes`` carry the parent's terms over as they
-#: were, the two overstated ones with them (ROADMAP C15).  Behind them since
-#: PR 48 what the expert layers' routing takes (``moe.routing_bytes`` a
-#: layer), which the rule keeps whatever it decides: four layers each, of
-#: 256 tokens with 16 experts and 2 a token, of 16,384 with 128 and 6, of
-#: 8,192 with 320 and 8.
-LAYER_SIZES = {
-    "tiny": (lambda: (hybrid.HybridConfig.tiny(), (2, 128)),
-             (131072, 262144, 7640128), 16 * (256 * (16 + 10) + 16)),
-    "tiny_solar": (lambda: (hybrid.HybridConfig.tiny_solar(), (2, 128)),
-                   (65536, 196608, 5602244), 16 * (256 * (16 + 10) + 16)),
-    "nemotron-ep16-s8192": (lambda: _cell_config("nemotron-ep16-s8192"),
-                            (150994944, 486539264, 9288687104),
-                            16 * (16384 * (128 + 30) + 128)),
-    "solar-open2-ep40-tp8": (lambda: _cell_config("solar-open2-ep40-tp8"),
-                             (20971520, 167772160, 7130061200),
-                             16 * (8192 * (320 + 40) + 320)),
-}
-
-
-@pytest.mark.parametrize("name", sorted(LAYER_SIZES))
-def test_the_remat_rule_is_given_the_parents_sizes(name):
-    build, (qkv, gate_up, temporaries), routing = LAYER_SIZES[name]
-    config, (rows, seq_len) = build()
-    shapes = jax.eval_shape(lambda: hybrid.init_params(config,
-                                                       jax.random.key(0)))
-    assert hybrid._layer_sizes(
-        shapes, (rows, seq_len, config.d_model), config) == (
-        [("attn_qkv", qkv), ("mlp_gate_up", gate_up),
-         ("moe_routing", routing)], temporaries)
-
-
+# ------------------------------- (5) a kind is one entry of ``hybrid.KINDS``
 def _identity_kind():
     """A kind made here alone, as a module object with the interface of
     ``hybrid.KINDS``: ``x + scale * norm(x)``, one norm vector and one
@@ -542,8 +300,7 @@ def test_a_kind_is_a_module_and_one_line_of_kinds(monkeypatch):
     ``models/hybrid.py``."""
     monkeypatch.setitem(hybrid.KINDS, "I",
                         hybrid.Kind("identity", _identity_kind(), 6))
-    plain = dataclasses.replace(hybrid.HybridConfig.tiny(), attn_impl="xla",
-                                pattern="ME*E")
+    plain = families.preset("nemotron_h", attn_impl="xla", pattern="ME*E")
     config = dataclasses.replace(plain, pattern="MIE*IE")
     params = hybrid.init_params(config, jax.random.key(0))
     assert params["identity"]["id_norm"].shape == (2, config.d_model)
@@ -568,10 +325,9 @@ def test_a_kind_is_a_module_and_one_line_of_kinds(monkeypatch):
         jax.random.key(0), optimizer)
     step_fn = hybrid.make_train_step(config, optimizer)
     step = jit_train_step(step_fn, mesh=mesh)
-    ids = np.random.default_rng(0).integers(0, 1024, (2, 129)).astype(
-        np.int32)
+    tokens, targets = families.rows(config.vocab_size)
     device_telemetry.reset()
-    moved, _, loss = step(state, opt_state, ids[:, :-1], ids[:, 1:])
+    moved, _, loss = step(state, opt_state, tokens, targets)
     assert np.isfinite(float(loss))
     assert not np.array_equal(moved["identity"]["id_scale"],
                               params["identity"]["id_scale"])
@@ -579,21 +335,3 @@ def test_a_kind_is_a_module_and_one_line_of_kinds(monkeypatch):
     assert row["layer_kinds"] == "MIE*IE" and row["id_layers"] == 2
     # the expert layers' counters: layers, batch shards, held experts
     assert step_fn.counters["moe_rows"].shape == (2, 2, 4)
-
-
-def test_importing_llama_loads_no_state_space_module():
-    """``ops/ssd.py`` and ``models/mamba2.py`` load when a hybrid decoder
-    with ``M`` in its pattern is built, not with ``ray_tpu`` or
-    ``ray_tpu.models.llama``."""
-    script = ("import sys, ray_tpu, ray_tpu.models.llama\n"
-              "late = {'ray_tpu.ops.ssd', 'ray_tpu.models.mamba2', "
-              "'ray_tpu.models.hybrid'}\n"
-              "assert not late & set(sys.modules), late & set(sys.modules)\n"
-              "from ray_tpu.models import hybrid\n"
-              "hybrid.num_params(hybrid.HybridConfig.tiny())\n"
-              "assert late <= set(sys.modules)\n")
-    done = subprocess.run([sys.executable, "-c", script], capture_output=True,
-                          text=True, env={"JAX_PLATFORMS": "cpu",
-                                          "PATH": "/usr/bin:/bin"},
-                          cwd=spec.ROOT)
-    assert done.returncode == 0, done.stderr[-2000:]

@@ -16,7 +16,6 @@ import numpy as np
 import pytest
 from jax._src.ad_checkpoint import saved_residuals  # not re-exported in 0.9
 
-from benchmarks.reference import olmoe as reference
 from ray_tpu.models import llama, moe
 from ray_tpu.ops import remat
 from ray_tpu.ops.attention import save_splash_residuals
@@ -24,22 +23,8 @@ from ray_tpu.ops.grouped_matmul import grouped_matmul, tile_for
 from ray_tpu.parallel import MeshSpec, batch_sharding, make_mesh
 from ray_tpu.parallel.mesh import pytree_sharding
 from ray_tpu.util import first_call
-
-#: benchmarks/lib/correct.py's, which the bf16 program is held to on the chip
-LOSS_TOL, GRAD_TOL = 1e-3, 0.75
-
-
-def _published(config: llama.LlamaConfig):
-    """The keys the reference reads, as a published config.json names them."""
-    return {"hidden_size": config.d_model,
-            "num_attention_heads": config.n_head,
-            "num_key_value_heads": config.n_kv_head,
-            "num_experts": config.n_experts,
-            "num_experts_per_tok": config.experts_per_token,
-            "norm_topk_prob": config.norm_topk_prob,
-            "rms_norm_eps": config.rms_eps, "rope_theta": config.rope_theta,
-            "router_aux_loss_coef": config.router_aux_loss_coef,
-            "router_z_loss_coef": config.router_z_loss_coef}
+from tests import families
+from tests.families import rel_err
 
 
 def _tiny(**kw):
@@ -54,47 +39,17 @@ def _batch(config, rows=2, seed=1):
     return tokens[:, :-1], tokens[:, 1:]
 
 
-def _rel_err(a, b):
-    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
-    return float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
-
-
 # ------------------------------------------------ (a) against the reference
-@pytest.mark.parametrize("dtype,loss_tol,grad_tol", [
-    # float32 program against float32 reference: the same mathematics in
-    # another order (sorted rows and a grouped product against a masked sum
-    # over all experts), so only float32 summation order separates them.
-    ("float32", 1e-5, 1e-4),
-    # bf16 program (bf16 matmul operands, residual stream and logits;
-    # float32 router): inside the tolerances lib/correct.py holds the chip
-    # run to.  Its GRAD_TOL guards the mathematics, not the precision.
-    ("bfloat16", LOSS_TOL, GRAD_TOL),
-], ids=["float32", "bfloat16"])
-def test_loss_and_gradients_match_the_plain_reference(dtype, loss_tol,
-                                                      grad_tol):
-    dt = jnp.dtype(dtype)
-    config = _tiny(dtype=dt, logits_dtype=dt)
-    params = llama.init_params(config, jax.random.key(0))
-    # a router that prefers some experts, so the load is uneven
-    params["blocks"]["router"] = params["blocks"]["router"] * 20.0
-    tokens, targets = _batch(config)
-    loss, grads = jax.jit(jax.value_and_grad(
-        lambda p: llama.loss_fn(p, tokens, targets, config)))(params)
-    ref_loss, ref_grads = jax.jit(jax.value_and_grad(
-        lambda p: reference.loss(p, tokens, targets, _published(config),
-                                 q_block=config.seq_len)))(params)
-    assert _rel_err(loss, ref_loss) <= loss_tol
-    errs = jax.tree.map(_rel_err, grads, ref_grads)
-    assert set(errs["blocks"]) == {"attn_norm", "wq", "wk", "wv", "wo",
-                                   "q_norm", "k_norm", "mlp_norm", "router",
-                                   "w_gate", "w_up", "w_down"}
-    worst = max(jax.tree.leaves(errs))
-    assert worst <= grad_tol, errs
-    # the router losses are in the loss: without them it is smaller
+# (loss and gradients, float32 and bfloat16: ``tests/test_families.py``, the
+# row ``olmoe``)
+def test_the_router_losses_are_in_the_loss():
+    """Without the auxiliary and the z loss it is smaller."""
+    got = families.compared("olmoe", "float32")
+    config = families.float32("olmoe")
     bare = dataclasses.replace(config, router_aux_loss_coef=0.0,
                                router_z_loss_coef=0.0)
-    assert float(llama.loss_fn(params, tokens, targets, bare)) \
-        < float(loss) - 0.009
+    assert float(llama.loss_fn(got["params"], got["tokens"], got["targets"],
+                               bare)) < float(got["loss"]) - 0.009
 
 
 # ------------------------------------- (b) the layer against a Python loop
@@ -181,7 +136,7 @@ def test_expert_layer_gradients_match_a_per_token_loop(n_experts, k):
     _, want = _per_token_loop(x, weights, experts, blk, dy)
     for name, g in zip(("x", "weights", "w_gate", "w_up", "w_down"), got):
         assert np.all(np.isfinite(g)), name
-        assert _rel_err(g, want[name]) <= 1e-5, name
+        assert rel_err(g, want[name]) <= 1e-5, name
     # the zero weight's pair gives its expert's matrices and its token's
     # input nothing, and still has a gradient of its own: <out, dy>
     assert want["weights"][5, 1] != 0.0
@@ -341,7 +296,7 @@ def test_keeping_the_routing_changes_no_number(case):
         run(save_splash_residuals), run(remat.layer_policy([], 0)))
     assert float(loss) == float(want_loss)
     for got, ref in zip(jax.tree.leaves(grads), jax.tree.leaves(want)):
-        assert _rel_err(got, ref) <= 1e-6
+        assert rel_err(got, ref) <= 1e-6
 
 
 # ----------------------------------------------------------- (c) dropless
@@ -595,7 +550,7 @@ def test_four_devices_equal_one_and_only_weights_are_gathered(axis):
     compiled, args = compiled_on_mesh(config, params)
     loss4, grads4 = compiled(*args)
     assert float(loss4) == pytest.approx(float(loss1), rel=1e-5)
-    errs = jax.tree.map(_rel_err, grads4, grads1)
+    errs = jax.tree.map(rel_err, grads4, grads1)
     assert max(jax.tree.leaves(errs)) <= 1e-4, errs
 
     # What the expert layer and QK-norm add to the dense program's

@@ -13,11 +13,7 @@ import pytest
 
 from ray_tpu.ops import placement
 from ray_tpu.parallel import MeshSpec, make_mesh
-
-
-def _mesh(**axes):
-    return make_mesh(MeshSpec(**axes),
-                     jax.devices()[:math.prod(axes.values())])
+from tests import families
 
 
 #: ``MeshSpec`` names all six axes, those of size one too
@@ -43,7 +39,7 @@ def test_the_axes_that_cut_rows_and_heads(name):
     all the mesh's axes larger than one and divide them; any other axis, or
     a remainder, is refused (a caller with another path then takes it)."""
     axes, (rows, heads), want = MESHES[name]
-    mesh = _mesh(**axes).abstract_mesh if axes \
+    mesh = families.mesh(**axes).abstract_mesh if axes \
         else jax.sharding.get_abstract_mesh()
     assert placement.rows_and_heads(mesh, rows, heads) == want
     if want is not None:
@@ -90,7 +86,7 @@ def test_place_wraps_the_call_where_the_mesh_has_devices(name):
         jaxpr = jax.make_jaxpr(placed)(x, scale, bias)
         got = placed(x, scale, bias)
     else:
-        with jax.set_mesh(_mesh(**axes)):
+        with jax.set_mesh(families.mesh(**axes)):
             jaxpr = jax.make_jaxpr(placed)(x, scale, bias)
             got = jax.jit(placed)(x, scale, bias)
     rows = math.prod(axes.get(a, 1) for a in ("data", "fsdp"))

@@ -24,55 +24,22 @@ from benchmarks.reference import sdar as reference
 from ray_tpu.models import block_diffusion, llama, moe
 from ray_tpu.ops import attention
 from ray_tpu.ops.grouped_matmul import grouped_matmul
+from tests import families
+from tests.families import rel_err
 from tests.test_olmoe import _equations, _loops
 
-#: benchmarks/lib/correct.py's, which the bf16 program is held to on the chip
-LOSS_TOL, GRAD_TOL = 1e-3, 0.75
-
-
-def _rel_err(a, b):
-    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
-    return float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
-
-
-def _tiny_family(dtype="bfloat16", **options):
-    config = spec.load_json(spec.BENCH_DIR, "configs", "tiny-sdar.json")
-    config["options"] = {"attn_impl": "xla", "dtype": jnp.dtype(dtype),
-                         "logits_dtype": jnp.dtype(dtype), **options}
-    return config, spec.load_module("models", "sdar").build(config, 128)
-
-
 # ------------------------------------------------ (a) against the reference
-@pytest.mark.parametrize("dtype,loss_tol,grad_tol", [
-    # the same mathematics in another order: float32 summation order only
-    ("float32", 1e-5, 1e-4),
-    # bf16 operands, residual stream and logits under the chip run's limits
-    ("bfloat16", LOSS_TOL, GRAD_TOL),
-], ids=["float32", "bfloat16"])
-def test_loss_and_gradients_match_the_plain_reference(dtype, loss_tol,
-                                                      grad_tol):
-    config, family = _tiny_family(dtype)
-    params = jax.jit(family.init_fn)(jax.random.key(0))
-    # a router that prefers some experts, so the held share is uneven
-    params["blocks"]["router"] = params["blocks"]["router"] * 20.0
-    rows = np.random.default_rng(0).integers(
-        0, family.vocab_size, (2, 128)).astype(np.int32)
-    loss, grads = jax.jit(jax.value_and_grad(
-        lambda p: family.loss_fn(p, rows, None)))(params)
-    ref_loss, ref_grads = jax.jit(jax.value_and_grad(
-        lambda p: family.reference_loss(p, rows, None, 128)))(params)
-    assert _rel_err(loss, ref_loss) <= loss_tol
-    errs = jax.tree.map(_rel_err, grads, ref_grads)
-    assert set(errs["blocks"]) == {"attn_norm", "wq", "wk", "wv", "wo",
-                                   "q_norm", "k_norm", "mlp_norm", "router",
-                                   "w_gate", "w_up", "w_down"}
-    assert max(jax.tree.leaves(errs)) <= grad_tol, errs
-    # an untrained model reads ln V, whatever the weights of the loss
+# (loss and gradients, float32 and bfloat16: ``tests/test_families.py``, the
+# row ``sdar``)
+def test_an_untrained_model_reads_ln_v():
+    """Whatever the weights of the block-diffusion loss."""
+    config, _ = families.built("sdar", "float32")
+    ref_loss = families.compared("sdar", "float32")["ref_loss"]
     assert abs(float(ref_loss) - np.log(config["vocab_size"])) < 0.1
 
 
 def test_query_blocks_do_not_change_the_reference():
-    config, family = _tiny_family("float32")
+    config, family = families.family("sdar", "float32")
     params = jax.jit(family.init_fn)(jax.random.key(1))
     rows = np.random.default_rng(1).integers(0, 511, (1, 128)).astype(np.int32)
     whole = family.reference_loss(params, rows, None, 256)
@@ -226,7 +193,7 @@ def test_splash_kernel_agrees_with_the_einsum(monkeypatch, S, Bk):
     (a, ga), (b, gb) = run("xla"), run("splash")
     assert float(a) == pytest.approx(float(b), rel=1e-5)
     for x, y in zip(ga, gb):
-        assert _rel_err(y, x) < 1e-5
+        assert rel_err(y, x) < 1e-5
 
 
 @pytest.mark.parametrize("impl", ["ring", "ulysses", "flash"])
@@ -277,8 +244,8 @@ def test_the_shares_add_up_to_the_whole_layer(per_chip):
             part, _ = reference.moe(h[0], _share(blk, first, first + per_chip),
                                     dict(cfg, experts_held=[first,
                                                             first + per_chip]))
-        assert _rel_err(y[0], part) < 1e-5
-    assert _rel_err(total, whole) < 1e-5
+        assert rel_err(y[0], part) < 1e-5
+    assert rel_err(total, whole) < 1e-5
     assert losses == pytest.approx([float(balance)] * len(losses), rel=1e-5)
 
 
@@ -295,12 +262,12 @@ def test_an_absent_experts_rows_cost_no_product():
     out, vjp = jax.vjp(held, lhs, rhs)
     assert np.array_equal(np.asarray(out[:5]), np.zeros((5, 8)))
     assert np.array_equal(np.asarray(out[16:]), np.zeros((16, 8)))
-    assert _rel_err(out[5:16], lhs[5:16] @ rhs[1]) < 1e-5
+    assert rel_err(out[5:16], lhs[5:16] @ rhs[1]) < 1e-5
     dlhs, drhs = vjp(jnp.ones_like(out))
     assert not np.asarray(dlhs[:5]).any() and not np.asarray(dlhs[16:]).any()
     assert not np.asarray(drhs[0]).any() and not np.asarray(drhs[3]).any()
     assert not np.asarray(drhs[2]).any()  # held, and empty
-    assert _rel_err(drhs[1], lhs[5:16].T @ jnp.ones((11, 8))) < 1e-5
+    assert rel_err(drhs[1], lhs[5:16].T @ jnp.ones((11, 8))) < 1e-5
 
 
 # ------------------------------------------- (c2) the windows on the held run
@@ -566,7 +533,7 @@ def test_m_is_uniform_and_the_subset_too(Bk):
 
 
 def test_the_input_the_weights_and_the_mask_id():
-    config, family = _tiny_family()
+    config, family = families.family("sdar")
     gen = traffic_lib.make(
         spec.load_json(spec.BENCH_DIR, "traffic", "packed-s8192-b1.json"),
         vocab_size=family.vocab_size, eod_id=family.eod_id, global_batch=2,
@@ -664,7 +631,7 @@ def test_head_dim_and_per_head_qk_norm_by_hand(S, Bk):
     x = jax.random.normal(ks[2], (1, P, 256))
     with jax.default_matmul_precision("highest"):
         got, _ = llama._block(x, blk, config)
-    assert _rel_err(got[0], _by_hand(x[0], blk, config, S, Bk)) < 1e-4
+    assert rel_err(got[0], _by_hand(x[0], blk, config, S, Bk)) < 1e-4
 
 
 @pytest.mark.parametrize("impl,covering", [
@@ -684,7 +651,8 @@ def test_first_call_says_what_the_model_is(impl, covering):
     from ray_tpu.util import device_telemetry as dt
 
     dt.reset()
-    config, family = _tiny_family(attn_impl=impl)
+    config, family = families.family(
+        "sdar", options={"attn_impl": impl})
     optimizer = family.make_optimizer()
     params = jax.jit(family.init_fn)(jax.random.key(0))
     opt_state = jax.jit(optimizer.init)(params)

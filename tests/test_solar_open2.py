@@ -8,43 +8,25 @@ the experts.
 The plain reference is ``benchmarks/reference/solar_open2.py``, the one copy
 (float32, the recurrence position by position, every held expert applied to
 every position).  Everything runs on the CPU with seeded random weights at
-tiny sizes, attention on the einsum path.
+tiny sizes, attention on the einsum path.  What every family is held to is
+``tests/test_families.py``'s, by the row ``solar_open2``.
 """
 
 import dataclasses
 import functools
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks.lib import correct, spec
+from benchmarks.lib import spec
 from benchmarks.reference import solar_open2 as reference
 from ray_tpu.models import hybrid, kda as kda_model, moe
 from ray_tpu.models.layers import attention
 from ray_tpu.ops.kda import kda, kda_xla
-from ray_tpu.parallel import MeshSpec, make_mesh
-from ray_tpu.util import first_call
-
-#: benchmarks/lib/correct.py's, which the bf16 program is held to on the chip
-LOSS_TOL, GRAD_TOL = 1e-3, 0.75
-
-
-def _rel_err(a, b):
-    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
-    return float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
-
-
-def _tiny_family(dtype="bfloat16", **changes):
-    config = dict(spec.load_json(spec.BENCH_DIR, "configs",
-                                 "tiny-solar-open2.json"), **changes)
-    config["options"] = {"attn_impl": "xla", "dtype": jnp.dtype(dtype),
-                         "logits_dtype": jnp.dtype(dtype)}
-    return config, spec.load_module("models", "solar_open2").build(config,
-                                                                   128)
+from tests import families
+from tests.families import rel_err
 
 
 # ------------------------------------------------------- (1) the chunked scan
@@ -89,10 +71,10 @@ def test_chunked_scan_is_the_recurrence(chunks, chunk, decay, scan):
         got, grads = out_and_grads(lambda *a: scan(*a, chunk))
         want, grads_ref = out_and_grads(reference.recurrence)
     assert np.all(np.isfinite(got))
-    assert _rel_err(got, want) < 1e-5
+    assert rel_err(got, want) < 1e-5
     for name, g, g_ref in zip("qkvgb", grads, grads_ref):
         assert np.all(np.isfinite(g)), name
-        assert _rel_err(g, g_ref) < 1e-4, name
+        assert rel_err(g, g_ref) < 1e-4, name
 
 
 def test_scan_products_are_in_the_inputs_dtype():
@@ -102,7 +84,7 @@ def test_scan_products_are_in_the_inputs_dtype():
     low = tuple(a.astype(jnp.bfloat16) for a in (q, k, v))
     got = jax.jit(lambda *a: kda(*a, 32))(*low, g, beta)
     assert got.dtype == jnp.bfloat16
-    assert _rel_err(got, reference.recurrence(q, k, v, g, beta)) < 0.05
+    assert rel_err(got, reference.recurrence(q, k, v, g, beta)) < 0.05
 
 
 def test_the_state_is_zero_before_a_rows_first_position():
@@ -113,17 +95,16 @@ def test_the_state_is_zero_before_a_rows_first_position():
     with jax.default_matmul_precision("highest"):
         both = scan(q, k, v, g, beta)
         alone = scan(*(a[1:] for a in (q, k, v, g, beta)))
-    assert _rel_err(both[1:], alone) < 1e-6
+    assert rel_err(both[1:], alone) < 1e-6
     first = beta[:, 0, :, None] * jnp.sum(k[:, 0] * q[:, 0], -1,
                                           keepdims=True) * v[:, 0]
-    assert _rel_err(both[:, 0], first) < 1e-5
+    assert rel_err(both[:, 0], first) < 1e-5
 
 
 # ------------------------------------------------------------- (2) the mixer
 def _kda_parts(heads, dtype=jnp.float32, d_model=64):
-    config = dataclasses.replace(hybrid.HybridConfig.tiny_solar(),
-                                 kda_heads=heads, d_model=d_model,
-                                 dtype=dtype)
+    config = families.preset("solar_open2", kda_heads=heads,
+                             d_model=d_model, dtype=dtype)
     blk = jax.tree.map(lambda a: a[0], kda_model.init_params(
         config, jax.random.key(0), 1, 0.05))
     # every vector away from its start and the projections large enough
@@ -150,7 +131,7 @@ def test_mixer_matches_the_reference():
             x, blk, config, kda_model.logical_axes(config)))(x, blk) - x
         want = jax.jit(lambda x, blk: reference.kda(
             _normed(x, blk["kda_norm"], config.rms_eps), blk, cfg))(x, blk)
-    assert _rel_err(got, want) < 1e-5
+    assert rel_err(got, want) < 1e-5
 
 
 def test_beta_may_pass_one_and_the_decay_is_a_channels_own():
@@ -218,7 +199,7 @@ def test_the_kda_head_shares_add_up_to_the_uncut_mixer(shares):
                 name: _head_columns(name, a, share * held,
                                     (share + 1) * held, d)
                 for name, a in blk.items()})
-    assert _rel_err(total, want) < 1e-5
+    assert rel_err(total, want) < 1e-5
 
 
 @pytest.mark.parametrize("shares", [2, 4])
@@ -226,9 +207,8 @@ def test_the_attention_head_shares_add_up_to_the_uncut_layer(shares):
     """8 query heads over 4 KV heads, gated, no rotary, cut in ``shares``
     (a share: whole KV heads with the query heads that read them)."""
     H, KV, hd, D = 8, 4, 16, 64
-    config = dataclasses.replace(
-        hybrid.HybridConfig.tiny_solar(), n_head=H, n_kv_head=KV,
-        head_dim=hd, d_model=D, dtype=jnp.float32, attn_impl="xla")
+    config = families.float32("solar_open2", n_head=H, n_kv_head=KV,
+                              head_dim=hd, d_model=D)
     blk = jax.tree.map(lambda a: a[0] * 5.0, hybrid.init_params(
         config, jax.random.key(0))["attn"])
     assert set(blk) == {"attn_norm", "wq", "wk", "wv", "wo", "wg"}
@@ -253,10 +233,10 @@ def test_the_attention_head_shares_add_up_to_the_uncut_layer(shares):
                                        n_kv_head=k1 - k0)
             total = total + jax.jit(lambda mine: attention(
                 x, mine, part, axes))(mine) - x
-    assert _rel_err(total, want) < 1e-5
+    assert rel_err(total, want) < 1e-5
     # the gate is no no-op: without it the layer is another
     plain = dict(cfg, use_gqa_gate=False)
-    assert _rel_err(reference.attention(
+    assert rel_err(reference.attention(
         _normed(x, blk["attn_norm"], config.rms_eps), blk, plain, 64),
         want) > 0.1
 
@@ -309,128 +289,4 @@ def test_the_expert_shares_add_up_to_the_uncut_layer(family, E, k, shares):
                 blk.update({name: whole[name] for name in (
                     "shared_gate", "shared_up", "shared_down")})
             total = total + layer(blk, first)
-    assert _rel_err(total.reshape(-1, D), want) < 1e-5
-
-
-# ------------------------------------------------------ (4) the whole model
-@pytest.mark.parametrize("dtype,loss_tol,grad_tol", [
-    # the same mathematics in another order: float32 summation order only
-    ("float32", 1e-5, 2e-4),
-    # bf16 operands, residual stream and logits under the chip run's limits
-    ("bfloat16", LOSS_TOL, GRAD_TOL),
-], ids=["float32", "bfloat16"])
-def test_loss_and_gradients_match_the_plain_reference(dtype, loss_tol,
-                                                      grad_tol):
-    config, family = _tiny_family(dtype)
-    assert spec.load_module("models", "solar_open2").pattern(config) \
-        == "*EKEKEKE"
-    params = jax.jit(family.init_fn)(jax.random.key(0))
-    # a router that prefers some experts, decays and betas that matter
-    params["experts"]["router"] = params["experts"]["router"] * 20.0
-    for name in ("wq", "wk", "wv", "w_fb", "w_beta"):
-        params["kda"][name] = params["kda"][name] * 5.0
-    rows = np.random.default_rng(0).integers(
-        0, family.vocab_size, (2, 129)).astype(np.int32)
-    tokens, targets = rows[:, :-1], rows[:, 1:]
-    loss, grads = jax.jit(jax.value_and_grad(family.loss_fn))(
-        params, tokens, targets)
-    ref_loss, ref_grads = jax.jit(jax.value_and_grad(
-        lambda p, t, y: family.reference_loss(p, t, y, 64)))(
-        params, tokens, targets)
-    assert _rel_err(loss, ref_loss) < loss_tol
-    errors = jax.tree.map(_rel_err, grads, ref_grads)
-    assert set(errors) == {"wte", "kda", "attn", "experts", "final_norm",
-                           "lm_head"}
-    assert {"wg"} <= set(errors["attn"])
-    assert {"w_gate", "shared_gate"} <= set(errors["experts"])
-    for path, err in jax.tree_util.tree_flatten_with_path(errors)[0]:
-        assert err < grad_tol, (jax.tree_util.keystr(path), err)
-
-
-def test_counters_leave_the_step_stacked_by_expert_layer():
-    config = dataclasses.replace(hybrid.HybridConfig.tiny_solar(),
-                                 attn_impl="xla")
-    params = hybrid.init_params(config, jax.random.key(0))
-    ids = np.random.default_rng(1).integers(0, 1024, (2, 128)).astype(
-        np.int32)
-    _, counts = jax.jit(lambda p: hybrid.loss_and_counters(
-        p, ids, ids, config))(params)
-    assert counts["moe_rows"].shape == (4, 1, 4)   # E layers, shards, held
-    assert counts["moe_moved"].shape == (4, 1)
-
-
-def test_num_params_flops_and_the_first_call_record():
-    config = dataclasses.replace(hybrid.HybridConfig.tiny_solar(),
-                                 attn_impl="xla")
-    shapes = jax.eval_shape(lambda: hybrid.init_params(config,
-                                                       jax.random.key(0)))
-    assert hybrid.num_params(config) == sum(
-        a.size for a in jax.tree.leaves(shapes))
-    ids = jax.ShapeDtypeStruct((2, 128), jnp.int32)
-    with first_call.noting() as notes:
-        jax.eval_shape(lambda p, t: hybrid.loss_and_counters(
-            p, t, t, config), shapes, ids)
-    assert notes == {
-        "layer_kinds": "*EKEKEKE", "kda_heads": 2, "kda_head_dim": 16,
-        "kda_chunk": 32, "kda_chunks": 8, "kda_scan_kernel": False,
-        "kda_scan_grid": None, "heads_held": 2, "heads_total": 8,
-        "attn_gate": True, "experts_held": 4, "experts_total": 16,
-        "router_scoring": "sigmoid", "attn_positions": 128,
-        "loss_positions": 128,
-        "remat_kept": [], "remat_kept_bytes": 0, "remat_room_bytes": None,
-        "remat_routing_bytes": 4 * moe.routing_bytes(256, 16, 2),
-        # a window's products, gate / up and down, and their tiles (PR 50)
-        "gmm_tiles": {"64x128x48": (64, 128, 48), "64x48x128": (64, 48, 128)},
-        # off the chip a window returns by the gather (PR 57)
-        "moe_return": {"64x256x2x128": ("gather", None)}}
-
-
-# -------------------------------------------------- (5) the 8-bit control
-def test_the_control_is_refused():
-    """The reference on weights rounded to 8 bits (``tools/control.py``), in
-    the program's place, comes out as not correct at the seed's parameters
-    where the program itself passes, on the same rows, with room on both
-    sides of the tiny preset's limit."""
-    control = spec.load_module("tools", "control").control
-    config, family = _tiny_family()
-    # On the CPU over three seeds of uniform rows, S=128: the leaves' median
-    # error read 0.027-0.031 in the program (largest leaf 0.12-0.20) and
-    # 0.25-0.28 in the control (largest leaf 0.41-0.56, over the 0.24 that
-    # three times the limit allows).  The chip's readings at the cell's own
-    # size set the configuration's own limit (its ``check_why``).
-    limit = 0.08
-    mesh = make_mesh(MeshSpec(), jax.local_devices()[:1])
-    rows = np.random.default_rng(0).integers(
-        0, family.vocab_size, (1, 129)).astype(np.int32)
-    program = correct.at_the_seed(family, mesh, 0, rows, limit)
-    refused = correct.at_the_seed(control(family), mesh, 0, rows, limit)
-    assert program["ok"], program
-    assert not refused["ok"], refused
-    assert 2 * program["grad_norm_err_median"] < limit \
-        < refused["grad_norm_err_median"] / 2
-
-
-# --------------------------------- (6) nothing new on an older model's path
-def test_the_kda_modules_load_with_the_first_model_that_holds_k():
-    """``ops/kda.py`` and ``models/kda.py`` load when a pattern with ``K``
-    is built: not with ``ray_tpu``, ``ray_tpu.models.llama`` or
-    ``ray_tpu.models.hybrid``, and not when the other hybrid model is
-    initialised and traced."""
-    script = (
-        "import sys, jax, ray_tpu, ray_tpu.models.llama\n"
-        "from ray_tpu.models import hybrid\n"
-        "late = {'ray_tpu.ops.kda', 'ray_tpu.models.kda'}\n"
-        "c = hybrid.HybridConfig.tiny()\n"
-        "p = jax.eval_shape(lambda: hybrid.init_params(c, jax.random.key(0)))\n"
-        "t = jax.ShapeDtypeStruct((2, 128), 'int32')\n"
-        "hybrid.num_params(c); hybrid.flops_per_token(c)\n"
-        "jax.eval_shape(lambda p, t: hybrid.loss_fn(p, t, t, c), p, t)\n"
-        "assert not late & set(sys.modules), late & set(sys.modules)\n"
-        "hybrid.init_params(hybrid.HybridConfig.tiny_solar(), "
-        "jax.random.key(0))\n"
-        "assert late <= set(sys.modules)\n")
-    done = subprocess.run([sys.executable, "-c", script], capture_output=True,
-                          text=True, env={"JAX_PLATFORMS": "cpu",
-                                          "PATH": "/usr/bin:/bin"},
-                          cwd=spec.ROOT)
-    assert done.returncode == 0, done.stderr[-2000:]
+    assert rel_err(total.reshape(-1, D), want) < 1e-5

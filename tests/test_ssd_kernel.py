@@ -14,19 +14,14 @@ import numpy as np
 import pytest
 
 from benchmarks.reference import nemotron_h as reference
-from ray_tpu.models import hybrid
 from ray_tpu.ops import ssd as ssd_module
 from ray_tpu.ops import ssd_kernel
 from ray_tpu.ops.ssd import ssd, ssd_xla
-from ray_tpu.parallel import MeshSpec, make_mesh
 from ray_tpu.util import first_call
+from tests import families
+from tests.families import rel_err
 
 Q = 128
-
-
-def _rel_err(a, b):
-    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
-    return float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
 
 
 def _inputs(chunks, b=2, H=4, P=64, G=2, N=128, seed=None):
@@ -73,12 +68,12 @@ def test_the_kernels_are_the_recurrence(chunks):
     with jax.default_matmul_precision("highest"):
         got, vjp = jax.vjp(lambda a: _run(ssd, a), a)
         want, vjp_ref = jax.vjp(_position_by_position, a)
-        assert _rel_err(got, want) < 1e-5
+        assert rel_err(got, want) < 1e-5
         dy = jax.random.normal(jax.random.key(9), want.shape)
         (grads,), (grads_ref,) = vjp(dy), vjp_ref(dy)
     for name in a:
         assert np.all(np.isfinite(grads[name])), name
-        assert _rel_err(grads[name], grads_ref[name]) < 1e-3, name
+        assert rel_err(grads[name], grads_ref[name]) < 1e-3, name
 
 
 def test_bf16_in_is_within_bf16s_rounding_and_no_further_than_the_xla_form():
@@ -92,16 +87,16 @@ def test_bf16_in_is_within_bf16s_rounding_and_no_further_than_the_xla_form():
     got, vjp = jax.vjp(lambda a: _run(ssd, a), low)
     xla, vjp_xla = jax.vjp(lambda a: _run(ssd_xla, a), low)
     assert got.dtype == jnp.bfloat16
-    assert _rel_err(got, want) < 0.01
-    assert _rel_err(got, want) <= 1.1 * _rel_err(xla, want)
+    assert rel_err(got, want) < 0.01
+    assert rel_err(got, want) <= 1.1 * rel_err(xla, want)
     dy = jax.random.normal(jax.random.key(9), want.shape, jnp.bfloat16)
     (grads,), (grads_xla,) = vjp(dy), vjp_xla(dy)
     (grads_ref,) = vjp_ref(dy.astype(jnp.float32))
     for name in low:
         assert grads[name].dtype == low[name].dtype, name
-        err = _rel_err(grads[name], grads_ref[name])
+        err = rel_err(grads[name], grads_ref[name])
         assert err < 0.01, name
-        assert err <= 1.1 * _rel_err(grads_xla[name], grads_ref[name]) \
+        assert err <= 1.1 * rel_err(grads_xla[name], grads_ref[name]) \
             + 1e-6, name
 
 
@@ -119,9 +114,9 @@ def test_the_kernels_against_the_xla_form(keep_states):
         want, vjp_xla = jax.vjp(lambda a: _run(ssd_xla, a), a)
         dy = jax.random.normal(jax.random.key(3), want.shape)
         (grads,), (grads_xla,) = vjp(dy), vjp_xla(dy)
-    assert _rel_err(got, want) < 1e-5
+    assert rel_err(got, want) < 1e-5
     for name in a:
-        assert _rel_err(grads[name], grads_xla[name]) < 1e-3, name
+        assert rel_err(grads[name], grads_xla[name]) < 1e-3, name
 
 
 def test_the_state_crosses_chunks_and_starts_a_row_at_zero():
@@ -146,16 +141,12 @@ def test_the_state_crosses_chunks_and_starts_a_row_at_zero():
     slow = dict(both, dt=both["dt"] - 6.0)
     y = _run(ssd, slow)
     assert float(jnp.max(jnp.abs(y[0, 2 * Q:] - y[1, 2 * Q:]))) > 1e-2
-    assert _rel_err(y, _position_by_position(slow)) < 1e-5
-
-
-def _mesh(**axes):
-    return make_mesh(MeshSpec(**axes), jax.devices()[:MeshSpec(**axes).size])
+    assert rel_err(y, _position_by_position(slow)) < 1e-5
 
 
 #: rows, positions, heads, head_dim, groups, state, chunk; the mesh's axes
 CELL = (2, 8192, 64, 64, 8, 128, 128)
-TINY = hybrid.HybridConfig.tiny()
+TINY = families.preset("nemotron_h")
 PLACEMENTS = {
     "the-cell": (CELL, {}, "kernel"),
     "the-cell-on-one-device-of-a-mesh": (CELL, {"data": 1}, "kernel"),
@@ -189,7 +180,7 @@ def test_which_path_a_call_takes(name):
     and every device of the mesh can scan rows and groups of its own, the
     XLA form everywhere else."""
     (b, S, H, P, G, N, chunk), axes, want = PLACEMENTS[name]
-    mesh = _mesh(**axes).abstract_mesh if axes \
+    mesh = families.mesh(**axes).abstract_mesh if axes \
         else jax.sharding.get_abstract_mesh()
     assert ssd_module.path((b, S, H, P), (b, S, G, N), min(chunk, S),
                            mesh) == want
@@ -209,12 +200,13 @@ def test_on_a_mesh_every_device_scans_its_own_rows_and_groups():
 
     with jax.default_matmul_precision("highest"):
         want, grads_xla = loss(ssd_xla)(a)
-        with jax.set_mesh(_mesh(data=2, tensor=2)), first_call.noting() as notes:
+        with jax.set_mesh(families.mesh(data=2, tensor=2)), \
+                first_call.noting() as notes:
             got, grads = loss(ssd)(a)
     assert notes == {"ssm_scan_kernel": True, "ssm_scan_grid": [1, 1, 2]}
     assert float(got) == pytest.approx(float(want), rel=1e-5)
     for name in a:
-        assert _rel_err(grads[name], grads_xla[name]) < 1e-3, name
+        assert rel_err(grads[name], grads_xla[name]) < 1e-3, name
 
 
 def test_the_first_call_record_says_which_ran():

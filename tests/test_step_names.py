@@ -32,6 +32,7 @@ import pytest
 from ray_tpu.train import profiler as train_profiler
 from ray_tpu.util import device_telemetry as dt
 from ray_tpu.util import tracing
+from tests import families
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DATA = os.path.join(REPO, "tests", "data")
@@ -71,34 +72,34 @@ HYBRID_SCOPES = SSM_SCOPES | KDA_SCOPES | MLA_SCOPES | WINDOW_SCOPES \
     | CONV_SCOPES | GDN_SCOPES | {"shared_expert"}
 
 
+#: a hybrid step by what its pattern holds -> (the scopes only its kinds
+#: open, what else of the hybrid steps' it does not open: ``hybrid-conv`` has
+#: no shared expert beside its routed ones, ``hybrid-gdn`` is dense)
+OWN_SCOPES = {
+    "hybrid": (SSM_SCOPES, set()), "hybrid-kda": (KDA_SCOPES, set()),
+    "hybrid-mla": (MLA_SCOPES, set()), "hybrid-window": (WINDOW_SCOPES, set()),
+    "hybrid-conv": (CONV_SCOPES, {"shared_expert"}),
+    "hybrid-gdn": (GDN_SCOPES, {"shared_expert", "moe_held"} | MOE_SCOPES)}
+
+
 def _scopes_of(family):
-    if family == "hybrid":  # holds a share, trains next tokens
-        return set(tracing.SCOPE_REGISTRY) - {"experts", "noise"} \
-            - KDA_SCOPES - MLA_SCOPES - WINDOW_SCOPES - CONV_SCOPES \
-            - GDN_SCOPES
-    if family == "hybrid-kda":
-        return set(tracing.SCOPE_REGISTRY) - {"experts", "noise"} \
-            - SSM_SCOPES - MLA_SCOPES - WINDOW_SCOPES - CONV_SCOPES \
-            - GDN_SCOPES
-    if family == "hybrid-mla":
-        return set(tracing.SCOPE_REGISTRY) - {"experts", "noise"} \
-            - SSM_SCOPES - KDA_SCOPES - WINDOW_SCOPES - CONV_SCOPES \
-            - GDN_SCOPES
-    if family == "hybrid-window":
-        return set(tracing.SCOPE_REGISTRY) - {"experts", "noise"} \
-            - SSM_SCOPES - KDA_SCOPES - MLA_SCOPES - CONV_SCOPES \
-            - GDN_SCOPES
-    if family == "hybrid-conv":  # no shared expert beside its routed ones
-        return set(tracing.SCOPE_REGISTRY) - {"experts", "noise"} \
-            - SSM_SCOPES - KDA_SCOPES - MLA_SCOPES - WINDOW_SCOPES \
-            - GDN_SCOPES - {"shared_expert"}
-    if family == "hybrid-gdn":  # dense: no expert layer at all
-        return set(tracing.SCOPE_REGISTRY) - SDAR_SCOPES - MOE_SCOPES \
-            - (HYBRID_SCOPES - GDN_SCOPES)
+    if family in OWN_SCOPES:  # holds a share, trains next tokens
+        own, without = OWN_SCOPES[family]
+        return set(tracing.SCOPE_REGISTRY) - {"experts", "noise"} - without \
+            - (HYBRID_SCOPES - {"shared_expert"} - own)
     if family == "llama-sdar":
         return set(tracing.SCOPE_REGISTRY) - {"experts"} - HYBRID_SCOPES
     return set(tracing.SCOPE_REGISTRY) - SDAR_SCOPES - HYBRID_SCOPES - (
         set() if family == "llama-moe" else MOE_SCOPES)
+
+
+#: the hybrid steps by what their patterns hold -> the row of
+#: ``tests/families.py``: what nemotron-ep16-s8192, solar-open2-ep40-tp8,
+#: joyai-ep16-s8192, laguna-ep32-s8192, lfm2-ep4-s8192 and olmo-hybrid-s8192
+#: run
+HYBRID_ROWS = {"hybrid": "nemotron_h", "hybrid-kda": "solar_open2",
+               "hybrid-mla": "joyai_llm_flash", "hybrid-window": "laguna",
+               "hybrid-conv": "lfm2_moe", "hybrid-gdn": "olmo_hybrid"}
 
 
 def _family(name):
@@ -110,30 +111,10 @@ def _family(name):
         return llama, llama.LlamaConfig.tiny_moe()
     if name == "llama-sdar":  # what sdar-ep8-s8192 runs
         return llama, llama.LlamaConfig.tiny_sdar()
-    if name == "hybrid":  # what nemotron-ep16-s8192 runs
+    if name in HYBRID_ROWS:  # the rehearsal file of what the cell runs
         from ray_tpu.models import hybrid
 
-        return hybrid, hybrid.HybridConfig.tiny()
-    if name == "hybrid-kda":  # what solar-open2-ep40-tp8 runs
-        from ray_tpu.models import hybrid
-
-        return hybrid, hybrid.HybridConfig.tiny_solar()
-    if name == "hybrid-mla":  # what joyai-ep16-s8192 runs
-        from ray_tpu.models import hybrid
-
-        return hybrid, hybrid.HybridConfig.tiny_joyai()
-    if name == "hybrid-window":  # what laguna-ep32-s8192 runs
-        from ray_tpu.models import hybrid
-
-        return hybrid, hybrid.HybridConfig.tiny_laguna()
-    if name == "hybrid-conv":  # what lfm2-ep4-s8192 runs
-        from ray_tpu.models import hybrid
-
-        return hybrid, hybrid.HybridConfig.tiny_lfm2()
-    if name == "hybrid-gdn":  # what olmo-hybrid-s8192 runs
-        from ray_tpu.models import hybrid
-
-        return hybrid, hybrid.HybridConfig.tiny_olmo_hybrid()
+        return hybrid, families.preset(HYBRID_ROWS[name])
     config = gpt2.GPTConfig.tiny()
     if name == "gpt2-attn-outside-unrolled":  # what gpt2xl-s1024 runs
         import dataclasses
@@ -550,74 +531,128 @@ def test_ops_import_nothing_above_them():
 _REMAT = {"remat_kept", "remat_kept_bytes", "remat_room_bytes",
           "remat_routing_bytes"}  # the last since PR 48
 _STEP = {"remat_fallback", "grad_ring_products", "grad_ring_axis"}
-_ROPE = {"rope_kernel", "rope_calls"}  # where a preset rotates, since PR 53
-_LLAMA = _STEP | _REMAT | _ROPE | {
-    "experts_held", "experts_total", "block_length", "attn_positions",
-    "loss_positions"}
-_HYBRID = _STEP | _REMAT | {
-    "layer_kinds", "loss_positions", "attn_positions", "heads_held",
-    "heads_total", "attn_gate", "experts_held", "experts_total",
-    "router_scoring", "gmm_tiles",  # since PR 50
-    "moe_return"}  # since PR 57: every hybrid preset holds a share
-#: the keys of each tiny family's first-call record (no splash kernel on the
-#: CPU, so no ``attn_*`` geometry), recorded on PR 46's parent; since PR 46
-#: ``tiny-nemotron-h`` also carries the attention kind's ``heads_held``,
-#: ``heads_total`` and ``attn_gate``, which the parent noted only beside ``K``
-FIRST_CALL_KEYS = {
-    "tiny-gpt2": _STEP,
-    "tiny-llama": _LLAMA, "tiny-olmoe": _LLAMA | {"gmm_tiles"},
-    "tiny-sdar": _LLAMA | {"gmm_tiles", "moe_return"},
-    "tiny-nemotron-h": _HYBRID | {
-        "ssm_heads", "ssm_state", "ssm_chunk", "ssm_chunks",
-        "ssm_scan_kernel", "ssm_scan_grid"},
-    "tiny-solar-open2": _HYBRID | {
-        "kda_heads", "kda_head_dim", "kda_chunk", "kda_chunks",
-        "kda_scan_kernel", "kda_scan_grid"},
-    "tiny-laguna": _HYBRID | _ROPE | {
-        "dense_width", "rope_rotary_lanes", "rope_yarn_factor",
-        "attn_window", "window_heads"},
-    "tiny-lfm2": _HYBRID | _ROPE | {
-        "dense_width", "qk_norm", "shortconv_taps", "shortconv_width",
-        "shortconv_layers"},
-    # dense: no expert layer's keys, no rotary pass's
-    "tiny-olmo-hybrid": _STEP | _REMAT | {
-        "layer_kinds", "loss_positions", "attn_positions", "heads_held",
-        "heads_total", "attn_gate", "qk_norm", "dense_width", "gdn_heads",
-        "gdn_key_dim", "gdn_value_dim", "gdn_chunk", "gdn_chunks",
-        "gdn_scan_kernel", "gdn_scan_grid"},  # the last two since PR 59
-}
+PRESETS = sorted(name[:-len(".json")] for name in os.listdir(
+    os.path.join(REPO, "benchmarks", "configs")) if name.startswith("tiny-"))
 
 
-@pytest.mark.parametrize("name", sorted(FIRST_CALL_KEYS))
-def test_the_first_call_record_carries_the_parents_keys(name):
-    """What a ``TrainStep`` would record of each family's tiny step, from a
-    trace of it under the block its call opens; every key is listed in
-    ``util/first_call.py``'s docstring."""
+@pytest.mark.parametrize("name", PRESETS)
+def test_the_first_call_record_carries_what_every_step_notes(name):
+    """What a ``TrainStep`` would record of each rehearsal preset's step,
+    from a trace of it under the block its call opens: what every step
+    notes and, where the layers ask the remat rule (all but GPT-2's), what
+    it decided.  (The values a family is about are its row's in
+    ``tests/families.py``; that every key is one of ``first_call.KEYS`` is
+    the call sites' test below, which reaches the arms no CPU trace takes.)"""
     import jax
     import jax.numpy as jnp
 
-    from benchmarks.lib import spec
     from ray_tpu.ops import grouped_matmul
     from ray_tpu.parallel.train_state import _first_call_notes
-    from ray_tpu.util import first_call
 
-    config = spec.load_json(spec.BENCH_DIR, "configs", name + ".json")
-    family = spec.load_module("models", config["family"]).build(config, 128)
+    family = families.from_file(name)
     optimizer = family.make_optimizer()
     params = jax.eval_shape(family.init_fn, jax.random.key(0))
     ids = jax.ShapeDtypeStruct((2, 128), jnp.int32)
     with _first_call_notes() as notes:
         jax.eval_shape(family.make_train_step(optimizer), params,
                        jax.eval_shape(optimizer.init, params), ids, ids)
-    assert set(notes) == FIRST_CALL_KEYS[name]
-    assert all(f"``{key}``" in first_call.__doc__ for key in notes)
+    assert _STEP | (set() if name == "tiny-gpt2" else _REMAT) <= set(notes)
     assert notes["remat_fallback"] is False \
         and notes["grad_ring_products"] == 0
     if "gmm_tiles" in notes:  # a product's shapes -> the tile it walks
         assert all(grouped_matmul.tile_for(*map(int, shape.split("x"))) == tile
                    for shape, tile in notes["gmm_tiles"].items())
         assert len(notes["gmm_tiles"]) >= 2  # gate / up, and down
-    if name == "tiny-nemotron-h":
-        assert (notes["heads_held"], notes["heads_total"],
-                notes["attn_gate"]) == (4, 4, False)
-        assert notes["layer_kinds"] == "MEMEM*EME"
+
+
+@functools.cache
+def _noted_by_the_call_sites():
+    """(the literal keys of every ``first_call.note`` / ``entry`` / ``count``
+    / ``noting`` call under ``ray_tpu/``, the dicts such a call spreads, as
+    source text), read from the files as
+    :func:`test_ops_import_nothing_above_them` reads imports: that reaches
+    the kernels' arms the CPU never traces."""
+    import ast
+
+    literal, spread = set(), set()
+    for path in sorted(glob.glob(os.path.join(REPO, "ray_tpu", "**", "*.py"),
+                                 recursive=True)):
+        with open(path) as f:
+            tree = ast.parse(f.read())
+        for node in ast.walk(tree):
+            if not (isinstance(node, ast.Call)
+                    and isinstance(node.func, ast.Attribute)
+                    and isinstance(node.func.value, ast.Name)
+                    and node.func.value.id == "first_call"
+                    and node.func.attr in ("note", "entry", "count",
+                                           "noting")):
+                continue
+            if node.func.attr in ("entry", "count"):
+                assert isinstance(node.args[0], ast.Constant), (
+                    f"{path}:{node.lineno}: a computed key")
+                literal.add(node.args[0].value)
+            literal |= {kw.arg for kw in node.keywords if kw.arg}
+            spread |= {ast.unparse(kw.value) for kw in node.keywords
+                       if kw.arg is None}
+    return literal, spread
+
+
+@functools.cache
+def _spread_by_the_call_sites():
+    """The keys of the dicts the call sites spread, each made here as its
+    site makes it: the remat rule's decision, the splash kernel's counts
+    under each of its masks, the step's start, and every kind's
+    ``first_call_facts`` on a rehearsal preset that holds the kind."""
+    from ray_tpu.models import hybrid
+    from ray_tpu.ops import attention, grad_ring, remat
+
+    facts = set()
+    for name in families.HYBRID:
+        config = families.preset(name)
+        for entry in hybrid._kinds(config).values():
+            facts |= set(entry.module.first_call_facts(config, 2, 128))
+    masks = ({}, {"block_length": 4}, {"window": 16})
+    return {
+        "decision.attributes()": set(remat.choose(0, 0, [], 0).attributes()),
+        "counts": set().union(*(attention._splash_kernel(
+            256, 4, 64, True, **mask)[1] for mask in masks)),
+        "grad_ring.NO_RINGS": set(grad_ring.NO_RINGS),
+        "entry.module.first_call_facts(config, rows, S)": facts}
+
+
+def test_every_key_a_call_site_notes_is_in_the_table():
+    """``first_call.KEYS`` holds every key written under ``ray_tpu/``; a
+    call site that spreads a dict this test cannot make is named."""
+    from ray_tpu.util import first_call
+
+    literal, spread = _noted_by_the_call_sites()
+    made = _spread_by_the_call_sites()
+    assert spread == set(made)
+    assert literal - set(first_call.KEYS) == set()
+    for site, keys in made.items():
+        assert keys - set(first_call.KEYS) == set(), site
+
+
+def test_every_key_of_the_table_has_a_writer():
+    """A key that lost its writer leaves the table."""
+    from ray_tpu.util import first_call
+
+    literal, _ = _noted_by_the_call_sites()
+    written = literal.union(*_spread_by_the_call_sites().values())
+    assert set(first_call.KEYS) - written == set()
+    assert all(says.split(":")[0].split(" ")[0].endswith(".py")
+               and os.path.exists(os.path.join(
+                   REPO, "ray_tpu", says.split(":")[0].split(" ")[0]))
+               for says in first_call.KEYS.values())
+
+
+def test_the_docs_name_every_key_of_the_table():
+    """``docs/observability.md``'s table of the record points at
+    ``first_call.KEYS`` and names each of its keys."""
+    from ray_tpu.util import first_call
+
+    with open(os.path.join(REPO, "docs", "observability.md")) as f:
+        text = f.read()
+    assert "first_call.KEYS" in text
+    assert [key for key in first_call.KEYS if f"`{key}`" not in text] == []
+    assert "first_call.KEYS" in tracing.SPAN_REGISTRY["train.first_call"]
