@@ -440,11 +440,12 @@ def run_kernel(name: str, kernel_fn, reference_fn, args, tols,
 
 
 def kernels_phase(attn_shape, ring_shape, ssd_shape, kda_shape,
-                  rope_shape, gdn_shape) -> list:
+                  rope_shape, gdn_shape, conv_shape, gate_shape) -> list:
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.models import layers
+    from ray_tpu.models import layers, mamba2, shortconv
+    from ray_tpu.ops import conv_kernel
     from ray_tpu.ops.attention import causal_attention, splash_attention
     from ray_tpu.ops.gdn import gdn, gdn_xla
     from ray_tpu.ops.kda import kda, kda_xla
@@ -570,6 +571,37 @@ def kernels_phase(attn_shape, ring_shape, ssd_shape, kda_shape,
             layers._rope_product(x, 5e5, rotary, False, True) for x in xs)),
         [normal(20 + i, (b, S, H, hd)) for i, H in enumerate(heads)],
         (FWD_TOL,) * 2 + (GRAD_TOL,) * 2, want_mosaic=2))
+
+    # the short causal convolution's pass (``ops/conv_kernel.py``), values
+    # and the gradients of x, the taps and the bias, against
+    # ``causal_conv`` under its silu: a span inside the projection's
+    # output that leaves as three arrays, a call each way each
+    b, S, full, offset, widths, taps = conv_shape
+    span = sum(widths)
+    conv_args = [normal(30, (b, S, full)),
+                 jax.random.normal(jax.random.key(31), (taps, span)) * 0.5,
+                 jax.random.normal(jax.random.key(32), (span,))]
+    cuts = [sum(widths[:i]) for i in range(1, len(widths))]
+    rows.append(run_kernel(
+        f"causal conv fwd+grad {conv_shape}",
+        turned(lambda a: conv_kernel.conv(
+            *a, act=True, out_dtype=jnp.bfloat16, offset=offset,
+            widths=widths)),
+        turned(lambda a: tuple(jnp.split(jax.nn.silu(mamba2.causal_conv(
+            a[0][..., offset:offset + span], a[1], a[2])).astype(
+                jnp.bfloat16), cuts, axis=-1))),
+        conv_args, (FWD_TOL,) * len(widths) + (GRAD_TOL,) * 3,
+        want_mosaic=2 * len(widths)))
+
+    # its gated form over ``[B | C | u]`` against ``gated_conv``
+    b, S, width, taps = gate_shape
+    rows.append(run_kernel(
+        f"gated conv fwd+grad {gate_shape}",
+        turned(lambda a: (conv_kernel.gated(*a),)),
+        turned(lambda a: (shortconv.gated_conv(*a),)),
+        [normal(33, (b, S, 3 * width)),
+         jax.random.normal(jax.random.key(34), (taps, width)) * 0.5],
+        (FWD_TOL,) + (GRAD_TOL,) * 2, want_mosaic=2))
     return rows
 
 
@@ -625,7 +657,14 @@ def main() -> int:
             rope_shape=(1, 8192, (48, 8), 128, 64),
             # a gated-delta-net layer's scan in ``olmo-hybrid-s8192``: rows,
             # positions, heads, a head's keys, its values, chunk
-            gdn_shape=(1, 8192, 30, 96, 192, 64))
+            gdn_shape=(1, 8192, 30, 96, 192, 64),
+            # a Mamba-2 layer's convolution in ``nemotron-ep16-s8192``: rows,
+            # positions, the projection's width, where ``xBC`` starts in
+            # it, ``xs | B | C``, taps
+            conv_shape=(2, 8192, 10304, 4096, (4096, 1024, 1024), 4),
+            # a convolution layer's gate in ``lfm2-ep4-s8192``: rows,
+            # positions, a gate's width, taps
+            gate_shape=(2, 8192, 2048, 3))
         check_kernels_on_chip(kernels)
     finally:
         ray_tpu.shutdown()

@@ -52,7 +52,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models.kda import l2norm
 from ray_tpu.models.layers import dense, rmsnorm, stacked_normal
-from ray_tpu.models.mamba2 import causal_conv
+from ray_tpu.models.mamba2 import short_conv
 from ray_tpu.ops.gdn import gdn
 
 #: the kind reads ``norm_after`` (``hybrid.HybridConfig``)
@@ -175,7 +175,8 @@ def mixer(x, blk, config, axes):
         q, k, v = (dense(u, blk, name, axes, dt) for name in ("wq", "wk",
                                                               "wv"))
         with jax.named_scope("gdn_conv"):
-            q, k, v = (jax.nn.silu(causal_conv(a, blk["conv_" + name], None))
+            q, k, v = (short_conv(a, blk["conv_" + name],
+                                  dt if name == "v" else f32)
                        .reshape(B, S, H, -1)
                        for name, a in zip("qkv", (q, k, v)))
         q, k, v = ((l2norm(q) * dk ** -0.5).astype(dt), l2norm(k).astype(dt),

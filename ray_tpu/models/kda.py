@@ -44,7 +44,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models.layers import dense, rmsnorm, stacked_normal
-from ray_tpu.models.mamba2 import causal_conv
+from ray_tpu.models.mamba2 import short_conv
 from ray_tpu.ops.kda import SUB, kda
 
 #: under the square root of q's and k's L2 norms
@@ -174,8 +174,9 @@ def mixer(x, blk, config, axes):
         q, k, v = (dense(u, blk, name, axes, dt) for name in ("wq", "wk",
                                                               "wv"))
         with jax.named_scope("kda_conv"):
-            q, k, v = (jax.nn.silu(causal_conv(a, blk["conv_" + name],
-                                               no_bias)).reshape(B, S, H, d)
+            q, k, v = (short_conv(a, blk["conv_" + name],
+                                  dt if name == "v" else f32, no_bias)
+                       .reshape(B, S, H, d)
                        for name, a in zip("qkv", (q, k, v)))
         q, k, v = ((l2norm(q) * d ** -0.5).astype(dt), l2norm(k).astype(dt),
                    v.astype(dt))

@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.layers import dense, rmsnorm
+from ray_tpu.ops import conv_kernel
 from ray_tpu.ops.ssd import ssd
 
 
@@ -206,6 +207,18 @@ def _causal_conv_bwd(saved, dy):
 causal_conv.defvjp(_causal_conv_fwd, _causal_conv_bwd)
 
 
+def short_conv(x, w, out_dtype, xla_bias=None):
+    """``silu(causal_conv(x, w, None))`` of a mixer's q, k or v (kinds `K`,
+    `G`): ``ops/conv_kernel.py``'s pass where its rule says so, rounded once
+    to ``out_dtype`` (float32 where the caller goes on in float32, as q and k
+    under their norm); else XLA's form in float32, which the caller rounds.
+    ``xla_bias``: what XLA's form adds (kind `K`'s zeros, which its lowered
+    text holds)."""
+    if conv_kernel.engaged(x.shape, w.shape[0]):
+        return conv_kernel.conv(x, w, None, act=True, out_dtype=out_dtype)
+    return jax.nn.silu(causal_conv(x, w, xla_bias))
+
+
 def gated_norm(y, z, scale, groups: int, eps: float):
     """``rmsnorm(y * silu(z))`` over each of ``groups`` runs of the last
     axis by itself (``norm_before_gate`` false), one weight over them all;
@@ -230,9 +243,18 @@ def mixer(x, blk, config, axes):
         z, xBC, delta = jnp.split(
             zxbcdt, [w["inner"], w["inner"] + w["conv"]], axis=-1)
         with jax.named_scope("ssm_conv"):
-            xBC = jax.nn.silu(causal_conv(xBC, blk["conv_w"],
-                                          blk["conv_b"])).astype(dt)
-        xs, Bm, Cm = jnp.split(xBC, [w["inner"], w["inner"] + G * N], axis=-1)
+            spans = (w["inner"], G * N, G * N)
+            if conv_kernel.engaged(zxbcdt.shape, blk["conv_w"].shape[0],
+                                   w["inner"], spans):
+                # xBC where the projection wrote it: no slice's copy
+                xs, Bm, Cm = conv_kernel.conv(
+                    zxbcdt, blk["conv_w"], blk["conv_b"], act=True,
+                    out_dtype=dt, offset=w["inner"], widths=spans)
+            else:
+                xBC = jax.nn.silu(causal_conv(xBC, blk["conv_w"],
+                                              blk["conv_b"])).astype(dt)
+                xs, Bm, Cm = jnp.split(
+                    xBC, [w["inner"], w["inner"] + G * N], axis=-1)
         delta = jax.nn.softplus(delta.astype(jnp.float32) + blk["dt_bias"])
         with jax.named_scope("ssm_scan"):
             y = ssd(xs.reshape(B, S, H, P), delta, -jnp.exp(blk["A_log"]),

@@ -43,6 +43,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models.layers import dense, rmsnorm, stacked_normal
 from ray_tpu.models.mamba2 import causal_conv
+from ray_tpu.ops import conv_kernel
 
 
 def init_params(config, key, n: int, out_std: float) -> Dict[str, Any]:
@@ -143,7 +144,10 @@ def mixer(x, blk, config, axes):
         h = rmsnorm(x, blk["conv_norm"], config.rms_eps).astype(dt)
         bcu = dense(h, blk, "in_proj", axes, dt)
         with jax.named_scope("shortconv_gate"):
-            y = gated_conv(bcu, blk["conv_w"])
+            kernel = conv_kernel.engaged(bcu.shape, blk["conv_w"].shape[0],
+                                         gated=True)
+            y = (conv_kernel.gated if kernel else gated_conv)(
+                bcu, blk["conv_w"])
         return x + dense(y, blk, "out_proj", axes, dt)
 
 

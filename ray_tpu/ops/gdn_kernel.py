@@ -137,23 +137,25 @@ class _Columns:
 
 
 def _traced_once(fn):
-    """``fn`` of arrays, traced once a signature: a kernel's body calls it
-    once a head of its step, and every call after the first binds the
-    recorded equations again (30 heads unrolled op by op through ``jnp`` cost
-    the cell's first call 6 s of tracing on the chip machine's host; as a
-    jitted function a head cost the kernels' lowering 3 s, an equation of its
-    own to lower a head)."""
+    """``fn`` of arrays (or trees of them), traced once a signature: a
+    kernel's body calls it once a head of its step, and every call after the
+    first binds the recorded equations again (30 heads unrolled op by op
+    through ``jnp`` cost the cell's first call 6 s of tracing on the chip
+    machine's host; as a jitted function a head cost the kernels' lowering 3
+    s, an equation of its own to lower a head)."""
     traced = {}
 
     def call(*args):
-        key = tuple((a.shape, a.dtype) for a in args)
+        leaves, tree = jax.tree.flatten(args)
+        key = (tree, tuple((a.shape, a.dtype) for a in leaves))
         if key not in traced:
             traced[key] = jax.make_jaxpr(fn, return_shape=True)(
-                *(jax.ShapeDtypeStruct(*of) for of in key))
+                *jax.tree.unflatten(tree, [jax.ShapeDtypeStruct(*of)
+                                           for of in key[1]]))
         closed, shape = traced[key]
         return jax.tree.unflatten(
             jax.tree.structure(shape),
-            jax.core.eval_jaxpr(closed.jaxpr, closed.consts, *args))
+            jax.core.eval_jaxpr(closed.jaxpr, closed.consts, *leaves))
 
     return call
 

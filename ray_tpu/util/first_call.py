@@ -65,6 +65,10 @@ _NOTED = (
      "ops/rope_kernel.py or the product with a permutation, the calls traced "
      "(a layer's q and k are one, a scanned layer's once)",
      "rope_kernel rope_calls"),
+    ("ops/conv_kernel.py", "where a kind runs its short causal convolution "
+     "(M, K, G, C): the one Mosaic pass a direction or XLA's shifted "
+     "multiply-adds, the convolutions traced (M's xBC and C's gate one a "
+     "layer, K's and G's q, k and v one each)", "conv_kernel conv_calls"),
     ("models/llama.py", "the experts held of a layer's, a block-diffusion "
      "row's block (0: next-token), the positions attention and the loss "
      "run over", "experts_held experts_total block_length attn_positions "

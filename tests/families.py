@@ -173,7 +173,10 @@ FAMILIES: Dict[str, Family] = {row.name: row for row in (
             "attn_positions": 128, "loss_positions": 128,
             # the attention kind's own since PR 46, whatever else the
             # pattern holds
-            "heads_held": 4, "heads_total": 4, "attn_gate": False}),
+            "heads_held": 4, "heads_total": 4, "attn_gate": False,
+            # one convolution (xBC) each of the four M layers, XLA's form
+            # off the chip (PR 61)
+            "conv_kernel": "xla", "conv_calls": 4}),
     Family(
         "solar_open2", "tiny-solar-open2", "*EKEKEKE",
         # a router that prefers some experts, decays and betas that matter
@@ -192,7 +195,9 @@ FAMILIES: Dict[str, Family] = {row.name: row for row in (
             "kda_chunk": 32, "kda_chunks": 8, "kda_scan_kernel": False,
             "kda_scan_grid": None, "heads_held": 2, "heads_total": 8,
             "attn_gate": True, **_EXPERTS, "attn_positions": 128,
-            "loss_positions": 128}),
+            "loss_positions": 128,
+            # q, k and v of each of the three K layers (PR 61)
+            "conv_kernel": "xla", "conv_calls": 9}),
     Family(
         "joyai_llm_flash", "tiny-joyai", "LDLELE",
         # a router that prefers some experts, a softmax far from uniform
@@ -258,7 +263,9 @@ FAMILIES: Dict[str, Family] = {row.name: row for row in (
             "shortconv_layers": 3, "dense_width": 256,
             # q and k of each of the two attention layers in one call, by
             # the product: heads of 32 lanes
-            "rope_kernel": False, "rope_calls": 2},
+            "rope_kernel": False, "rope_calls": 2,
+            # the gate of each of the three C layers (PR 61)
+            "conv_kernel": "xla", "conv_calls": 3},
         flops=_lfm2_flops()),
     Family(
         "olmo_hybrid", "tiny-olmo-hybrid", "GDGDGD*D",
@@ -301,7 +308,9 @@ FAMILIES: Dict[str, Family] = {row.name: row for row in (
             "gdn_chunks": 2 * 128 // 32,
             "gdn_scan_kernel": False, "gdn_scan_grid": None,  # heads of 12
             "dense_width": 256, "attn_gate": False,
-            "remat_routing_bytes": 0},  # no layer routes
+            "remat_routing_bytes": 0,  # no layer routes
+            # q, k and v of each of the three G layers (PR 61)
+            "conv_kernel": "xla", "conv_calls": 9},
         flops=_olmo_hybrid_flops()),
     Family(
         "olmoe", "tiny-olmoe", None,

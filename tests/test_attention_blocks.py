@@ -476,3 +476,72 @@ def test_the_rotary_kernel_compiles_for_the_v5e(monkeypatch, one_chip):
             assert "convolution" not in text, name
     finally:
         jax.config.update("jax_enable_compilation_cache", cache)
+
+
+def test_the_conv_kernels_compile_for_the_v5e(monkeypatch, one_chip):
+    """The short causal convolution's pass (``ops/conv_kernel.py``) through
+    the four kinds' call sites at the shapes the cells hand them (the
+    state-space mixer's span inside its projection's output, the delta
+    rules' q or k in float32 and v rounded at 1024, 2880 and 5760 channels,
+    the gate over ``[B | C | u]``), forward and backward under a
+    ``jax.checkpoint``, compiled for a described v5e: a forward and a
+    backward call an array (alone the checkpoint's second forward is the
+    first's twin and the compiler runs it once), none refused for the scoped
+    VMEM the rule's blocks must fit, every one under the caller's scope.  In this file: one worker loads the TPU's compiler."""
+    from ray_tpu.models import mamba2, shortconv
+    from ray_tpu.ops import conv_kernel
+    from ray_tpu.parallel.train_state import classify_op_name
+
+    f32 = jnp.float32
+
+    def plain(out_dtype):
+        def run(x, w):
+            with jax.named_scope("gdn_conv"):
+                return mamba2.short_conv(x, w, out_dtype)
+        return run
+
+    def spans(x, w, b):
+        with jax.named_scope("ssm_conv"):
+            assert conv_kernel.engaged(x.shape, 4, 4096, (4096, 1024, 1024))
+            return conv_kernel.conv(x, w, b, act=True, out_dtype=jnp.bfloat16,
+                                    offset=4096, widths=(4096, 1024, 1024))
+
+    def gate(x, w):
+        with jax.named_scope("shortconv_gate"):
+            assert conv_kernel.engaged(x.shape, 3, gated=True)
+            return conv_kernel.gated(x, w)
+
+    #: name -> (the call site, its scope, the arrays' shapes, Mosaic calls)
+    shapes = {
+        "nemotron xBC": (spans, "ssm_conv", [
+            ((2, 8192, 10304), jnp.bfloat16), ((4, 6144), f32),
+            ((6144,), f32)], 6),
+        "solar q": (plain(f32), "gdn_conv", [
+            ((1, 8192, 1024), jnp.bfloat16), ((4, 1024), f32)], 2),
+        "olmo q": (plain(f32), "gdn_conv", [
+            ((1, 8192, 2880), jnp.bfloat16), ((4, 2880), f32)], 2),
+        "olmo v": (plain(jnp.bfloat16), "gdn_conv", [
+            ((1, 8192, 5760), jnp.bfloat16), ((4, 5760), f32)], 2),
+        "lfm2 gate": (gate, "shortconv_gate", [
+            ((2, 8192, 6144), jnp.bfloat16), ((3, 2048), f32)], 2),
+    }
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        for name, (site, scope, arrays, want) in shapes.items():
+            def both(*xs, site=site):
+                out, pull = jax.vjp(jax.checkpoint(site), *xs)
+                return pull(out)
+
+            xs = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+                  for shape, dtype in arrays]
+            text = jax.jit(both).lower(*xs).compile().as_text()
+            calls = [line for line in text.splitlines()
+                     if "tpu_custom_call" in line]
+            assert len(calls) == want, (name, len(calls))
+            assert all(classify_op_name(
+                line.split('op_name="')[1].split('"')[0])[1] == scope
+                for line in calls), name
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)

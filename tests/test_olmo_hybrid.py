@@ -181,11 +181,13 @@ def test_the_remat_rule_is_offered_a_pattern_without_experts():
 
 def test_the_delta_rule_kind_counts_itself_and_borrows_its_convolution():
     """The kind's parameters by hand; its convolution is
-    ``mamba2.causal_conv`` and its scan ``ops/gdn.py``'s."""
+    ``mamba2.short_conv`` (``causal_conv`` under its silu, or
+    ``ops/conv_kernel.py``'s pass: PR 61) and its scan ``ops/gdn.py``'s."""
     config = families.preset(FAMILY)
     D, H, dk, dv = 128, 4, 12, 24
     assert gdn.num_params(config) == D * H * (2 * dk + 3 * dv + 2) \
         + 4 * H * (2 * dk + dv) + 2 * H + dv + D
     assert hybrid.KINDS["G"].module is gdn
-    assert gdn.causal_conv is mamba2.causal_conv
-    assert "def causal_conv" not in open(gdn.__file__).read()
+    assert gdn.short_conv is mamba2.short_conv
+    assert "def causal_conv" not in open(gdn.__file__).read() \
+        and "def short_conv" not in open(gdn.__file__).read()
