@@ -17,7 +17,8 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.layers import feed_forward, stacked_normal
+from ray_tpu.models.layers import (feed_forward, feed_forward_branch,
+                                   stacked_normal)
 from ray_tpu.ops import remat
 
 #: the kind reads ``norm_after`` (``models/layers.py:feed_forward``)
@@ -76,3 +77,9 @@ def layer(config, axes, index: int):
     """Layer ``index`` of the kind as (x, its row of the stack) -> (x,
     None)."""
     return lambda x, blk: (feed_forward(x, blk, config, axes)[0], None)
+
+
+def branch(config, axes, index: int):
+    """Layer ``index`` without its residual add, as (u, its row of the
+    stack) -> (the MLP over ``norm(u)``, None)."""
+    return lambda u, blk: feed_forward_branch(u, blk, config, axes)

@@ -29,7 +29,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.models import moe
-from ray_tpu.models.layers import feed_forward, stacked_normal
+from ray_tpu.models.layers import (feed_forward, feed_forward_branch,
+                                   stacked_normal)
 from ray_tpu.ops import remat
 
 #: what ``expert_activation`` may name
@@ -136,14 +137,30 @@ def first_call_facts(config, rows: int, seq_len: int) -> Dict[str, Any]:
             "router_scoring": config.router_scoring}
 
 
+def _expert_layer(config, index: int) -> Dict[str, Any]:
+    """What ``moe.moe_mlp`` is handed for the ``index``-th expert layer."""
+    return dict(scoring=config.router_scoring,
+                bias=router_bias(config, index), scale=config.routed_scaling,
+                activation=ACTIVATIONS[config.expert_activation])
+
+
 def layer(config, axes, index: int):
     """Layer ``index`` of the kind as (x, its row of the stack) -> (x, the
     layer's counts: ``moe.moe_mlp``'s)."""
     def experts(x, blk):
-        x, (_, counts) = feed_forward(
-            x, blk, config, axes, scoring=config.router_scoring,
-            bias=router_bias(config, index), scale=config.routed_scaling,
-            activation=ACTIVATIONS[config.expert_activation])
+        x, (_, counts) = feed_forward(x, blk, config, axes,
+                                      **_expert_layer(config, index))
         return x, counts
+
+    return experts
+
+
+def branch(config, axes, index: int):
+    """Layer ``index`` without its residual add, as (u, its row of the
+    stack) -> (the experts' output over ``norm(u)``, the layer's counts)."""
+    def experts(u, blk):
+        y, (_, counts) = feed_forward_branch(u, blk, config, axes,
+                                             **_expert_layer(config, index))
+        return y, counts
 
     return experts

@@ -55,7 +55,23 @@ letter) answers, for a configuration with the fields it reads:
   of the stack) -> (x, the step counters it leaves or None);
 - ``NORM_AFTER = True`` where the kind reads ``norm_after`` (``*``, ``D``,
   ``G``); a configuration that sets it over any other kind is refused, so
-  no kind grows a path no model uses.
+  no kind grows a path no model uses;
+- ``branch(config, axes, index)`` where the kind has its layer without the
+  residual add too (``L``, ``D``, ``E``), as (u, its row of the stack) ->
+  (f(norm(u)), the step counters or None): what a residual of several
+  streams runs (below); a configuration with ``streams`` > 1 over any other
+  kind is refused likewise.
+
+**A residual of several streams** (``streams`` n > 1: manifold-constrained
+hyper-connections, ``models/streams.py``): the embedding is copied to n
+streams, every sub-layer reads one mix of them and writes its branch's
+output back to all under maps of its own (one more stack, ``hc``, a row a
+sub-layer, the prediction module's after the pattern's), and after the last
+layer the streams are summed for the final norm.  The residual add is then
+the write's and no kind's; with ``streams`` 1 none of it is traced and the
+add is each kind's own ``x + f(norm(x))``.  A prediction module reads the
+streams one by one (one ``hidden_norm``, one ``w_eh``), runs its block under
+maps too and sums the streams before its final norm.
 
 A chip may also hold a share of the **heads**: that is a smaller ``n_head``
 / ``n_kv_head`` / ``kda_heads`` (the matrices' columns for the heads held,
@@ -84,8 +100,9 @@ more row of its kinds' stacks.  A configuration without one (``mtp_depth``
 
 **Parameters.**  Layers of one kind share one stacked tree (``ssm``,
 ``kda``, ``attn``, ``experts``, ``mla``, ``dense``, ``window``,
-``shortconv``, ``gdn``, each leaf with its kind's layers in front, the prediction
-module's after the pattern's), so the
+``shortconv``, ``gdn``, each leaf with its kind's layers in front, the
+prediction module's after the pattern's; under ``streams`` > 1 also ``hc``,
+a row a sub-layer), so the
 optimizer, the sharding rules and a checkpoint see a stack a kind and not
 ``len(pattern)`` trees.  The stack runs unrolled: layer i takes row
 ``pattern[:i].count(kind)`` of its kind's stack.  (A pattern that repeats
@@ -111,6 +128,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models import streams
 from ray_tpu.models.layers import Yarn, mesh_axes, rmsnorm
 from ray_tpu.ops import remat
 from ray_tpu.ops.lm_head import lm_head_cross_entropy
@@ -234,6 +252,10 @@ class HybridConfig:
     mla_rope_theta: float = 10000.0
     #: the rotary pairs are lanes (2i, 2i + 1), not (i, i + half)
     mla_rope_interleave: bool = True
+    #: ``models/layers.py:Yarn``: the rotary lanes' stretch; None: none
+    mla_rope_yarn: Optional[Yarn] = None
+    #: the softmax's scale; None: ``(mla_nope_dim + mla_rope_dim) ** -0.5``
+    mla_sm_scale: Optional[float] = None
     # ``D``: models/dense.py
     dense_width: int = 256
     # ``W``: models/window.py, in the place of ``*``'s ``n_head``,
@@ -258,6 +280,13 @@ class HybridConfig:
     #: weight of a module's loss in the step's
     mtp_depth: int = 0
     mtp_weight: float = 0.3
+    #: the residual's streams (``models/streams.py``); 1: the plain residual
+    streams: int = 1
+    #: the turns that normalise a sub-layer's stream map, the eps added to a
+    #: row's or column's sum, and the clamp on its logits before ``exp``
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_clamp: Tuple[float, float] = (-30.0, 30.0)
 
     @property
     def held(self) -> range:
@@ -282,6 +311,12 @@ class HybridConfig:
         """Of the kind's stack: the pattern's layers, then the module's."""
         return self.count(kind) + self.mtp_kinds.count(kind)
 
+    @property
+    def sublayers(self) -> str:
+        """Every layer that runs, one letter each: the pattern's, then the
+        prediction module's."""
+        return self.pattern + self.mtp_kinds
+
     def __post_init__(self):
         assert self.pattern and set(self.pattern) <= set(KINDS), self.pattern
         if self.norm_after:
@@ -292,6 +327,15 @@ class HybridConfig:
                     f"norm_after over the kinds {deaf} of pattern "
                     f"{self.pattern!r}: their modules do not read it")
         assert self.mtp_depth in (0, 1), self.mtp_depth
+        assert self.streams >= 1, self.streams
+        if self.streams > 1:
+            whole = [kind for kind, entry in _kinds(self).items()
+                     if not hasattr(entry.module, "branch")]
+            if whole:
+                raise ValueError(
+                    f"streams {self.streams} over the kinds {whole} of "
+                    f"pattern {self.pattern!r}: their modules have no "
+                    "branch without the residual add")
         held = self.held
         assert held.step == 1 and 0 <= held.start < held.stop \
             <= self.n_experts, held
@@ -331,6 +375,10 @@ def init_params(config: HybridConfig, key) -> Dict[str, Any]:
             "embed_norm": jnp.ones((D,)), "hidden_norm": jnp.ones((D,)),
             "w_eh": norm(jax.random.fold_in(key, MTP_DRAW), (2 * D, D), std),
             "final_norm": jnp.ones((D,))}
+    if config.streams > 1:
+        params[streams.STACK] = streams.init_params(
+            config, jax.random.fold_in(key, streams.DRAW),
+            len(config.sublayers))
     return params
 
 
@@ -343,6 +391,8 @@ def logical_axes(config: HybridConfig) -> Dict[str, Any]:
     if config.mtp_depth:
         axes["mtp"] = {"embed_norm": ("norm",), "hidden_norm": ("norm",),
                        "w_eh": ("embed", None), "final_norm": ("norm",)}
+    if config.streams > 1:
+        axes[streams.STACK] = streams.logical_axes(config)
     return axes
 
 
@@ -350,8 +400,10 @@ def num_params(config: HybridConfig) -> int:
     D = config.d_model
     return (1 if config.tie_head else 2) * config.vocab_size * D + D \
         + sum(KINDS[kind].module.num_params(config)
-              for kind in config.pattern + config.mtp_kinds) \
-        + config.mtp_depth * (2 * D * D + 3 * D)
+              for kind in config.sublayers) \
+        + config.mtp_depth * (2 * D * D + 3 * D) \
+        + (config.streams > 1) * len(config.sublayers) \
+        * streams.num_params(config)
 
 
 def flops_per_token(config: HybridConfig) -> float:
@@ -361,14 +413,16 @@ def flops_per_token(config: HybridConfig) -> float:
     scan's products, either delta rule's, a short convolution's taps and
     gates).  The head counts once, tied or not: the embedding is a gather.
     A prediction module adds its block's layers, ``w_eh`` and a second pass
-    through the head."""
+    through the head.  Under ``streams`` n > 1 every sub-layer adds its
+    maps' product, and the module's ``w_eh`` meets the embedding's half once
+    and the hidden state's half a stream, ``(1 + n) D^2``."""
     routed = config.experts_per_token * len(config.held) / config.n_experts
-    layers = [KINDS[kind].module
-              for kind in config.pattern + config.mtp_kinds]
-    D = config.d_model
+    layers = [KINDS[kind].module for kind in config.sublayers]
+    D, n = config.d_model, config.streams
+    maps = len(layers) * streams.matmul_params(config) if n > 1 else 0
     return 6.0 * (sum(m.matmul_params(config, routed) for m in layers)
                   + (1 + config.mtp_depth) * config.vocab_size * D
-                  + config.mtp_depth * 2 * D * D) \
+                  + config.mtp_depth * (1 + n) * D * D + maps) \
         + 3.0 * sum(m.mixer_flops(config, config.seq_len) for m in layers)
 
 
@@ -390,7 +444,9 @@ def _layer_sizes(params, x_shape, config: HybridConfig):
     reserve the chip has no room for a rung, by 0.7 GiB, and a second trace
     later in the process, when 0.3 GiB more is in use, says the same (a
     bound of 7.65 kept q, k and v on the first trace and not on the second:
-    PERF.md, PR 40)."""
+    PERF.md, PR 40).  Under ``streams`` n > 1 a kept input is n times as
+    wide, the widest working set may be the maps' own, and the maps
+    (``remat.MAPS``) are a candidate in front of the ladder's."""
     mesh = jax.sharding.get_abstract_mesh()
     tensor = remat.axis_shards(mesh, "tensor")
     tokens = math.prod(x_shape[:2]) // remat.axis_shards(
@@ -401,17 +457,23 @@ def _layer_sizes(params, x_shape, config: HybridConfig):
             mesh, *mesh_axes(axes)), params, logical_axes(config))
     kinds = _kinds(config)
     stacks = sum(jax.tree.leaves(
-        {entry.stack: chips[entry.stack] for entry in kinds.values()}))
+        {entry.stack: chips[entry.stack] for entry in kinds.values()})) \
+        + sum(jax.tree.leaves(chips.get(streams.STACK, {})))
     total = sum(jax.tree.leaves(chips))
     casts = int(total * item / 4)
     sizes = {kind: entry.module.layer_bytes(config, tokens, x_shape[1],
                                             tensor, item)
              for kind, entry in kinds.items()}
     candidates = dict.fromkeys(remat.LADDER, 0)
-    layers = config.pattern + config.mtp_kinds
-    kept_inputs = len(layers) * tokens * config.d_model * item
-    for kind in layers:
-        _, kept, named = sizes[kind]
+    layers = config.sublayers
+    kept_inputs = len(layers) * config.streams * tokens * config.d_model \
+        * item
+    under_maps = []
+    if config.streams > 1:
+        sizes[streams.STACK] = streams.layer_bytes(config, tokens, item)
+        under_maps = [sizes[streams.STACK]] * len(layers)
+        candidates = {remat.MAPS: 0, **candidates}
+    for _, kept, named in [sizes[kind] for kind in layers] + under_maps:
         kept_inputs += kept
         for name, nbytes in named.items():
             candidates[name] = candidates.get(name, 0) + nbytes
@@ -434,16 +496,26 @@ def _placed(kinds: str) -> List[Tuple[str, int]]:
     return placed
 
 
-def _run(params, x, layers, config: HybridConfig, axes, policy):
+def _run(params, x, layers, config: HybridConfig, axes, policy,
+         first: int = 0):
     """``x`` through ``layers`` (:func:`_placed`'s pairs) -> (x, the step
-    counters of the layers that leave some, in order)."""
+    counters of the layers that leave some, in order).  Under ``streams``
+    > 1 ``x`` is the streams, n arrays (B, S, D), each layer its kind's branch
+    between the maps' read and write (``streams.layer``), and ``first`` the
+    row of ``hc`` that the first of ``layers`` takes."""
     counts = []
-    for kind, index in layers:
-        stack = KINDS[kind].stack
-        layer = KINDS[kind].module.layer(config, axes[stack], index)
+    for at, (kind, index) in enumerate(layers, first):
+        stack, module = KINDS[kind].stack, KINDS[kind].module
+        rows = [jax.tree.map(lambda a: a[index], params[stack])]
+        if config.streams > 1:
+            layer = streams.layer(
+                config, module.branch(config, axes[stack], index))
+            rows.append(jax.tree.map(lambda a: a[at], params[streams.STACK]))
+        else:
+            layer = module.layer(config, axes[stack], index)
         if config.remat:
             layer = jax.checkpoint(layer, policy=policy)
-        x, counted = layer(x, jax.tree.map(lambda a: a[index], params[stack]))
+        x, counted = layer(x, *rows)
         if counted is not None:
             counts.append(counted)
     return x, counts
@@ -452,8 +524,9 @@ def _run(params, x, layers, config: HybridConfig, axes, policy):
 def _stacked(counts) -> Dict[str, Any]:
     """The layers' counters, each stacked over the layers that leave it; an
     empty dict where none does."""
-    return {name: jnp.stack([c[name] for c in counts])
-            for name in (counts[0] if counts else ())}
+    names = dict.fromkeys(name for counted in counts for name in counted)
+    return {name: jnp.stack([c[name] for c in counts if name in c])
+            for name in names}
 
 
 def _head(params, config: HybridConfig):
@@ -468,17 +541,35 @@ def _predict_ahead(params, x, targets, run, config: HybridConfig):
     ahead, the module's layers' counters in order).  ``run``: a row's
     activations through the module's layers (:func:`_run` with the step's
     axes and policy).  The last position, which has no token two ahead,
-    goes through the block and weighs nothing."""
+    goes through the block and weighs nothing.  Under ``streams`` > 1 ``x``
+    is the streams: each is normed and joined with the embedding by the one
+    ``w_eh`` (whose embedding half multiplies once for all of them, the
+    product of the two side by side written as the sum of the halves'), the
+    block runs under maps of its own, and the streams are summed before the
+    module's final norm."""
     dt = config.dtype
     mtp = params["mtp"]
     B, S = targets.shape
     with jax.named_scope("mtp"):
         ahead = rmsnorm(params["wte"][targets].astype(dt), mtp["embed_norm"],
                         config.rms_eps).astype(dt)
-        here = rmsnorm(x, mtp["hidden_norm"], config.rms_eps).astype(dt)
-        z = jnp.concatenate([ahead, here], axis=-1) @ mtp["w_eh"].astype(dt)
+        if config.streams > 1:
+            first, second = jnp.split(mtp["w_eh"].astype(dt), 2)
+            once = jnp.matmul(ahead, first,
+                              preferred_element_type=jnp.float32)
+            z = tuple((once + jnp.matmul(
+                rmsnorm(stream, mtp["hidden_norm"],
+                        config.rms_eps).astype(dt), second,
+                preferred_element_type=jnp.float32)).astype(dt)
+                for stream in x)
+        else:
+            here = rmsnorm(x, mtp["hidden_norm"], config.rms_eps).astype(dt)
+            z = jnp.concatenate([ahead, here], axis=-1) \
+                @ mtp["w_eh"].astype(dt)
     z, counts = run(z)
     with jax.named_scope("mtp_head"):
+        if config.streams > 1:
+            z = sum(stream.astype(jnp.float32) for stream in z)
         z = rmsnorm(z, mtp["final_norm"], config.rms_eps).astype(dt)
         weights = jnp.broadcast_to(
             (jnp.arange(S) < S - 1) / (B * (S - 1)), (B, S))
@@ -503,7 +594,8 @@ def loss_and_counters(params, tokens, targets, config: HybridConfig):
     (layers, shards, held) and ``moe_moved`` (layers, shards), the
     prediction module's layer last; none for a pattern whose kinds leave
     none), and with a prediction module the two losses the scalar sums,
-    ``loss_main`` and ``loss_mtp``."""
+    ``loss_main`` and ``loss_mtp``; under ``streams`` > 1 every sub-layer's
+    ``mhc_sinkhorn_err``, (sub-layers,), the module's last."""
     rows, S = tokens.shape
     dt = config.dtype
     first_call.note(layer_kinds=config.pattern, loss_positions=S)
@@ -515,10 +607,16 @@ def loss_and_counters(params, tokens, targets, config: HybridConfig):
         if config.remat else None
     run = partial(_run, params, config=config, axes=logical_axes(config),
                   policy=policy)
-    placed = _placed(config.pattern + config.mtp_kinds)
+    placed = _placed(config.sublayers)
+    if config.streams > 1:
+        first_call.note(**streams.first_call_facts(config, len(placed)))
+        x = (x,) * config.streams
     x, counts = run(x, placed[:config.n_layer])
     with jax.named_scope("lm_head"):
-        hidden = rmsnorm(x, params["final_norm"], config.rms_eps).astype(dt)
+        last = x if config.streams == 1 \
+            else sum(stream.astype(jnp.float32) for stream in x)
+        hidden = rmsnorm(last, params["final_norm"],
+                         config.rms_eps).astype(dt)
     # between the norm and the head, where the older steps' text has it
     counts = _stacked(counts)
     with jax.named_scope("lm_head"):
@@ -529,8 +627,8 @@ def loss_and_counters(params, tokens, targets, config: HybridConfig):
         first_call.note(mtp_depth=config.mtp_depth,
                         mtp_weight=config.mtp_weight)
         ahead, more = _predict_ahead(
-            params, x, targets, partial(run, layers=placed[config.n_layer:]),
-            config)
+            params, x, targets, partial(run, layers=placed[config.n_layer:],
+                                        first=config.n_layer), config)
         for name, rows in _stacked(more).items():
             counts[name] = jnp.concatenate([counts[name], rows])
         counts.update({step_counter("loss_main"): loss,
@@ -542,7 +640,7 @@ def loss_and_counters(params, tokens, targets, config: HybridConfig):
 def make_train_step(config: HybridConfig, optimizer):
     """Pure (params, opt_state, tokens, targets) -> (params, opt_state, loss):
     parallel.train_state.make_train_step over this model's loss; the layers'
-    counters (the expert layers' ``moe_rows`` and ``moe_moved``) leave
-    through ``step.counters``."""
+    counters (the expert layers' ``moe_rows`` and ``moe_moved``, the stream
+    maps' ``mhc_sinkhorn_err``) leave through ``step.counters``."""
     return _make_train_step(partial(loss_and_counters, config=config),
                             optimizer, has_counters=True)

@@ -386,6 +386,31 @@ def attention(x, blk, config, axes):
         return x + out
 
 
+def _feed_forward_out(x, blk, config, axes, **expert_layer):
+    """The feed-forward half without its residual add, inside the caller's
+    ``mlp`` scope -> (its output, what the expert layer says of itself)."""
+    dt = config.dtype
+    after = getattr(config, "norm_after", False)
+    h = x if after else rmsnorm(x, blk["mlp_norm"], config.rms_eps)
+    if "router" in blk:
+        # the router reads the norm's float32 output, the experts its
+        # cast to the compute dtype
+        y, router_losses, counts = _moe.moe_mlp(
+            h, blk, experts_per_token=config.experts_per_token,
+            norm_topk_prob=config.norm_topk_prob, dtype=dt,
+            first_held=config.held.start, **expert_layer)
+        return y, (router_losses, counts)
+    h = h.astype(dt)
+    gate = checkpoint_name(dense(h, blk, "w_gate", axes, dt),
+                           remat.GATE_UP)
+    up = checkpoint_name(dense(h, blk, "w_up", axes, dt), remat.GATE_UP)
+    act = jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+    out = dense(act.astype(dt), blk, "w_down", axes, dt)
+    if after:
+        out = rmsnorm(out, blk["mlp_norm"], config.rms_eps).astype(dt)
+    return out, None
+
+
 def feed_forward(x, blk, config, axes, **expert_layer):
     """The feed-forward half.  -> (x + its output, what the expert layer
     says of itself): the second is ``None`` for a dense MLP; with experts
@@ -397,25 +422,15 @@ def feed_forward(x, blk, config, axes, **expert_layer):
     configuration has ``norm_after`` the dense MLP reads ``x`` itself and
     ``mlp_norm`` norms its output, ``x + norm(down(...))`` (no expert layer
     has it)."""
-    dt = config.dtype
-    after = getattr(config, "norm_after", False)
     with jax.named_scope("mlp"):
-        h = x if after else rmsnorm(x, blk["mlp_norm"], config.rms_eps)
-        if "router" in blk:
-            # the router reads the norm's float32 output, the experts its
-            # cast to the compute dtype
-            y, router_losses, counts = _moe.moe_mlp(
-                h, blk, experts_per_token=config.experts_per_token,
-                norm_topk_prob=config.norm_topk_prob, dtype=dt,
-                first_held=config.held.start, **expert_layer)
-            return x + y, (router_losses, counts)
-        h = h.astype(dt)
-        gate = checkpoint_name(dense(h, blk, "w_gate", axes, dt),
-                               remat.GATE_UP)
-        up = checkpoint_name(dense(h, blk, "w_up", axes, dt), remat.GATE_UP)
-        act = jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
-        out = dense(act.astype(dt), blk, "w_down", axes, dt)
-        if after:
-            out = rmsnorm(out, blk["mlp_norm"], config.rms_eps).astype(dt)
-        x = x + out
-    return x, None
+        out, said = _feed_forward_out(x, blk, config, axes, **expert_layer)
+        return x + out, said
+
+
+def feed_forward_branch(u, blk, config, axes, **expert_layer):
+    """:func:`feed_forward` without its residual add -> (its output over
+    ``norm(u)``, what the expert layer says of itself): what a residual of
+    several streams (``models/streams.py``) runs between its read and its
+    write."""
+    with jax.named_scope("mlp"):
+        return _feed_forward_out(u, blk, config, axes, **expert_layer)

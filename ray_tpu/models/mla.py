@@ -20,6 +20,16 @@ position and then ``r`` rotary ones, a v head ``dv`` lanes):
     o = softmax_causal(q k^T / sqrt(n + r)) v    dv wide
     out = x + concat_h(o) wo
 
+With ``mla_rope_yarn`` (a ``layers.Yarn``) the rotary frequencies are its
+table and cos and sin take its scale; ``mla_sm_scale`` states the softmax's
+scale where it is not ``(n + r)^-1/2`` (the family multiplies it by the
+square of YaRN's ``0.1 mscale_all_dim ln(factor) + 1``).  Without either
+nothing of that is traced.
+
+:func:`branch` is the mixer without its residual add, ``concat_h(o) wo``
+over ``norm(u)``: what a residual of several streams (``models/streams.py``)
+runs between its read and its write.
+
 The attention runs **unabsorbed**: k and v are made a head, as training has
 them; folding ``wkv_b`` into the query and caching the latent is a serving
 form (ROADMAP B).  The kernel takes v at its own head dimension
@@ -130,38 +140,57 @@ def first_call_facts(config, rows: int, seq_len: int) -> Dict[str, Any]:
             "mla_latents": (config.mla_q_latent, config.mla_kv_latent)}
 
 
-def mixer(x, blk, config, axes):
-    """``x + wo(...)``: the layer.  x: (B, S, D) in the compute dtype;
-    ``blk`` one layer of :func:`init_params`; ``axes`` of its stack."""
+def _wo_of(x, blk, config, axes):
+    """``wo(...)`` over ``norm(x)``, inside the caller's ``attn`` scope."""
     dt = config.dtype
     B, S, _ = x.shape
     H, nope, rot, dv = _dims(config)
     Lkv = config.mla_kv_latent
     turn = partial(rope, theta=config.mla_rope_theta,
                    interleave=config.mla_rope_interleave)
+    yarn = config.mla_rope_yarn
+    if yarn is not None:
+        turn = partial(turn, scale=yarn.scale,
+                       inv_freq=yarn.inv_freq(rot, config.mla_rope_theta))
+    h = rmsnorm(x, blk["attn_norm"], config.rms_eps).astype(dt)
+    with jax.named_scope("latent"):
+        c_q = rmsnorm(dense(h, blk, "wq_a", axes, dt), blk["q_norm"],
+                      config.rms_eps).astype(dt)
+        q = (c_q @ blk["wq_b"].astype(dt)).reshape(B, S, H, nope + rot)
+        q = turn(q, rotary=rot)
+        down = dense(h, blk, "wkv_a", axes, dt)
+        c_kv = rmsnorm(down[..., :Lkv], blk["kv_norm"],
+                       config.rms_eps).astype(dt)
+        k_rot = turn(down[..., Lkv:].reshape(B, S, 1, rot))
+        up = (c_kv @ blk["wkv_b"].astype(dt)).reshape(B, S, H, nope + dv)
+        k = jnp.concatenate(
+            [up[..., :nope], jnp.broadcast_to(k_rot, (B, S, H, rot))],
+            axis=-1)
+        v = up[..., nope:]
+    q, k, v = (checkpoint_name(a, remat.QKV) for a in (q, k, v))
+    attn = causal_attention(q, k, v, config.attn_impl, config.mla_sm_scale)
+    attn = attn.astype(dt).reshape(B, S, H * dv)
+    return dense(attn, blk, "wo", axes, dt)
+
+
+def mixer(x, blk, config, axes):
+    """``x + wo(...)``: the layer.  x: (B, S, D) in the compute dtype;
+    ``blk`` one layer of :func:`init_params`; ``axes`` of its stack."""
     with jax.named_scope("attn"):
-        h = rmsnorm(x, blk["attn_norm"], config.rms_eps).astype(dt)
-        with jax.named_scope("latent"):
-            c_q = rmsnorm(dense(h, blk, "wq_a", axes, dt), blk["q_norm"],
-                          config.rms_eps).astype(dt)
-            q = (c_q @ blk["wq_b"].astype(dt)).reshape(B, S, H, nope + rot)
-            q = turn(q, rotary=rot)
-            down = dense(h, blk, "wkv_a", axes, dt)
-            c_kv = rmsnorm(down[..., :Lkv], blk["kv_norm"],
-                           config.rms_eps).astype(dt)
-            k_rot = turn(down[..., Lkv:].reshape(B, S, 1, rot))
-            up = (c_kv @ blk["wkv_b"].astype(dt)).reshape(B, S, H, nope + dv)
-            k = jnp.concatenate(
-                [up[..., :nope], jnp.broadcast_to(k_rot, (B, S, H, rot))],
-                axis=-1)
-            v = up[..., nope:]
-        q, k, v = (checkpoint_name(a, remat.QKV) for a in (q, k, v))
-        attn = causal_attention(q, k, v, config.attn_impl)
-        attn = attn.astype(dt).reshape(B, S, H * dv)
-        return x + dense(attn, blk, "wo", axes, dt)
+        return x + _wo_of(x, blk, config, axes)
 
 
 def layer(config, axes, index: int):
     """Layer ``index`` of the kind as (x, its row of the stack) -> (x,
     None)."""
     return lambda x, blk: (mixer(x, blk, config, axes), None)
+
+
+def branch(config, axes, index: int):
+    """Layer ``index`` without its residual add, as (u, its row of the
+    stack) -> (``wo(...)`` over ``norm(u)``, None)."""
+    def wo_of(u, blk):
+        with jax.named_scope("attn"):
+            return _wo_of(u, blk, config, axes), None
+
+    return wo_of

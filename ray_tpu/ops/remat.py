@@ -83,6 +83,13 @@ GATE_UP = "mlp_gate_up"
 #: byte kept (``d_model`` a byte); q, k and v go first because they spare
 #: RoPE's passes besides and are a fifth of the bytes.
 LADDER = (QKV, GATE_UP)
+#: The stream maps of a sub-layer under hyper-connections
+#: (``models/streams.py``): the read's and the write's weights and the
+#: normalised stream map, float32, n^2 + 2n numbers a position.  No rung of
+#: :data:`LADDER`, which the one-stream models share: a model with streams
+#: offers it in front of the ladder (a hundredth of q, k and v's bytes, and
+#: it spares the norm over every stream, a product and the Sinkhorn turns).
+MAPS = "mhc_maps"
 #: What an expert layer's router decided (``models/moe.py``): its logits, the
 #: chosen experts and their weights, the pairs' sorted order with its inverse
 #: and group sizes, the weights in that order: (N, E), (N, k) and (N x k,)

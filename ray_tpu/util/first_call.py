@@ -76,6 +76,10 @@ _NOTED = (
     ("models/hybrid.py", "the pattern run, one letter a layer; with a "
      "prediction module its depth and its loss's weight",
      "layer_kinds mtp_depth mtp_weight"),
+    ("models/streams.py", "a residual of several streams: how many, the "
+     "turns that normalise a stream map, the sub-layers under maps (whether "
+     "their maps are kept for the backward: `mhc_maps` in remat_kept)",
+     "streams hc_sinkhorn_iters mhc_sublayers"),
     ("models/attn.py", "(*) the query heads held of the model's, the output "
      "gate; where only a head's first lanes rotate, under YaRN, with QK-norm",
      "heads_held heads_total attn_gate rope_rotary_lanes rope_yarn_factor "

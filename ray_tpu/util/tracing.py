@@ -235,6 +235,17 @@ SCOPE_REGISTRY: Dict[str, str] = {
            "kinds' own scopes",
     "mtp_head": "multi-token-prediction module: its final norm and its pass "
                 "through the shared head, logits and loss",
+    "mhc": "a sub-layer's hyper-connections over a residual of several "
+           "streams (models/streams.py), around the two scopes below; the "
+           "sub-layer's branch runs between the read and the write under "
+           "its kind's own scopes",
+    "mhc_maps": "hyper-connections, nested inside mhc: the norm over every "
+                "stream's lanes, the product with phi, the two sigmoids and "
+                "the Sinkhorn turns that normalise the stream map",
+    "mhc_mix": "hyper-connections, nested inside mhc: the read that mixes "
+               "the streams into the branch's input and the write that "
+               "mixes them among themselves and adds the branch's output "
+               "to each: the model's residual add",
     "optimizer": "optimizer.update + apply_updates (gradient clipping is "
                  "inside the optax chain, so inside the scope)",
 }
@@ -265,6 +276,12 @@ STEP_COUNTER_REGISTRY: Dict[str, str] = {
     "loss_mtp": "the same decoder: the module's cross-entropy of the token "
                 "two ahead, mean over the positions that have one, float32 "
                 "scalar; the step's loss adds mtp_weight times it",
+    "mhc_sinkhorn_err": "a residual of several streams (models/streams.py): "
+                        "the largest distance from 1 of a row or column sum "
+                        "of a sub-layer's normalised stream map over the "
+                        "step's positions, float32 (sub-layers,), a "
+                        "prediction module's last: whether the turns reach "
+                        "the manifold at the logits training drives them to",
 }
 
 

@@ -118,6 +118,17 @@ def _joyai_flops():
                   + 2 * D * D) + 3.0 * 4 * H * (24 + 16) * S
 
 
+def _xing4_flops():
+    D, S, H, n = 64, 128, 2, 4
+    latent = D * 48 + 48 * H * 24 + D * 40 + 32 * H * 32 + H * 16 * D
+    experts = D * 16 + 3 * D * 48 * (1 + 2 * 4 / 16)
+    maps = n * D * (n * n + 2 * n)
+    # tiny-joyai's layers; ``w_eh`` meets the embedding once and the hidden
+    # state a stream; eight sub-layers' maps
+    return 6.0 * (4 * latent + 3 * D * 128 + 3 * experts + 2 * 512 * D
+                  + (1 + n) * D * D + 8 * maps) + 3.0 * 4 * H * (24 + 16) * S
+
+
 def _laguna_flops():
     D, S, hd, w = 64, 128, 16, 16
     full = D * hd * (2 * 4 + 2 * 2) + D * 4
@@ -219,6 +230,34 @@ FAMILIES: Dict[str, Family] = {row.name: row for row in (
             # lanes (PR 53)
             "rope_kernel": False, "rope_calls": 8},
         flops=_joyai_flops()),
+    Family(
+        "xing4_0", "tiny-xing4", "LDLELE",
+        # joyai's, and maps off their start: logits a few units wide, a
+        # stream map far from the identity, a read and a write far from
+        # uniform
+        scale={"experts.router": 8.0, "mla.wq_b": 5.0, "mla.wkv_b": 5.0,
+               "hc.alpha": 100.0, "hc.phi": 5.0, "hc.base": 0.1},
+        noise=("hc.base",),
+        stacks=frozenset({"wte", "mla", "dense", "experts", "mtp", "hc",
+                          "final_norm", "lm_head"}),
+        leaves={"mtp": frozenset({"embed_norm", "hidden_norm", "w_eh",
+                                  "final_norm"}),
+                "hc": frozenset({"phi", "alpha", "base"})},
+        control=Control(0.025, program_ok=True),
+        scopes=("mhc", "mhc/mhc_maps", "mhc/mhc_mix", "attn/latent",
+                "moe_held", "shared_expert", "mtp",
+                "rematted_computation/mhc/mhc_maps"),
+        no_scopes=("ssm", "kda", "gdn", "window"),
+        registered=("mhc", "mhc_maps", "mhc_mix"),
+        first_call={
+            "layer_kinds": "LDLELE", "streams": 4, "hc_sinkhorn_iters": 20,
+            # the pattern's six and the module's two
+            "mhc_sublayers": 8, "mla_heads": 2, "mla_qk_head_dim": 24,
+            "mla_v_head_dim": 16, "mla_latents": (48, 32),
+            "dense_width": 128, "mtp_depth": 1, "mtp_weight": 0.3,
+            **_EXPERTS, "attn_positions": 128, "loss_positions": 128,
+            "rope_kernel": False, "rope_calls": 8},
+        flops=_xing4_flops()),
     Family(
         "laguna", "tiny-laguna", "*DWEWEWE*E",
         # a router that prefers some experts, softmaxes far from uniform

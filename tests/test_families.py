@@ -166,9 +166,11 @@ def test_counters_leave_the_step_stacked_by_expert_layer(family):
     layers, held = config.rows("E"), len(config.held)
     assert (layers, held) == (FAMILIES[family].pattern.count("E")
                               + config.mtp_depth, 4)
-    # a prediction module's two losses leave beside them, and only its
+    # a prediction module's two losses leave beside them, and only its; a
+    # residual of several streams' counter, and only its
     assert set(counts) == {"moe_rows", "moe_moved"} | (
-        {"loss_main", "loss_mtp"} if config.mtp_depth else set())
+        {"loss_main", "loss_mtp"} if config.mtp_depth else set()) | (
+        {"mhc_sinkhorn_err"} if config.streams > 1 else set())
     assert counts["moe_rows"].shape == (layers, 1, held)
     assert counts["moe_moved"].shape == (layers, 1)
     pairs = ids.size * config.experts_per_token

@@ -7,7 +7,8 @@ clock, and the step profiler's counters.
   own (the three expert-layer scopes are the llama step's with experts; the
   Mamba-2 scopes and the shared expert's are the hybrid step's, the KDA
   scopes those of a hybrid step whose pattern holds ``K``, the
-  gated-delta-net scopes those of one whose pattern holds ``G``);
+  gated-delta-net scopes those of one whose pattern holds ``G``, the three
+  of the hyper-connections those of one with ``streams`` > 1);
 * ``classify_op_name`` on real ``op_name`` strings of the compiled v5e steps
   (``tests/data/v5e_step_op_names.json``) and ``parse_anatomy`` on an
   excerpt of that module's text (``tests/data/v5e_step_excerpt.hlo.txt``);
@@ -68,8 +69,10 @@ WINDOW_SCOPES = {"window"}
 CONV_SCOPES = {"shortconv", "shortconv_gate"}
 #: scopes only a stack with gated-delta-net layers opens
 GDN_SCOPES = {"gdn", "gdn_conv", "gdn_scan"}
+#: scopes only a stack over a residual of several streams opens
+MHC_SCOPES = {"mhc", "mhc_maps", "mhc_mix"}
 HYBRID_SCOPES = SSM_SCOPES | KDA_SCOPES | MLA_SCOPES | WINDOW_SCOPES \
-    | CONV_SCOPES | GDN_SCOPES | {"shared_expert"}
+    | CONV_SCOPES | GDN_SCOPES | MHC_SCOPES | {"shared_expert"}
 
 
 #: a hybrid step by what its pattern holds -> (the scopes only its kinds
@@ -79,7 +82,8 @@ OWN_SCOPES = {
     "hybrid": (SSM_SCOPES, set()), "hybrid-kda": (KDA_SCOPES, set()),
     "hybrid-mla": (MLA_SCOPES, set()), "hybrid-window": (WINDOW_SCOPES, set()),
     "hybrid-conv": (CONV_SCOPES, {"shared_expert"}),
-    "hybrid-gdn": (GDN_SCOPES, {"shared_expert", "moe_held"} | MOE_SCOPES)}
+    "hybrid-gdn": (GDN_SCOPES, {"shared_expert", "moe_held"} | MOE_SCOPES),
+    "hybrid-mhc": (MLA_SCOPES | MHC_SCOPES, set())}
 
 
 def _scopes_of(family):
@@ -95,11 +99,12 @@ def _scopes_of(family):
 
 #: the hybrid steps by what their patterns hold -> the row of
 #: ``tests/families.py``: what nemotron-ep16-s8192, solar-open2-ep40-tp8,
-#: joyai-ep16-s8192, laguna-ep32-s8192, lfm2-ep4-s8192 and olmo-hybrid-s8192
-#: run
+#: joyai-ep16-s8192, laguna-ep32-s8192, lfm2-ep4-s8192, olmo-hybrid-s8192
+#: and xing4-ep8-s4096 run
 HYBRID_ROWS = {"hybrid": "nemotron_h", "hybrid-kda": "solar_open2",
                "hybrid-mla": "joyai_llm_flash", "hybrid-window": "laguna",
-               "hybrid-conv": "lfm2_moe", "hybrid-gdn": "olmo_hybrid"}
+               "hybrid-conv": "lfm2_moe", "hybrid-gdn": "olmo_hybrid",
+               "hybrid-mhc": "xing4_0"}
 
 
 def _family(name):
@@ -150,7 +155,7 @@ def _tiny_step(name="llama"):
 @pytest.mark.parametrize("family", ["llama", "llama-moe", "llama-sdar",
                                     "hybrid", "hybrid-kda", "hybrid-mla",
                                     "hybrid-window", "hybrid-conv",
-                                    "hybrid-gdn",
+                                    "hybrid-gdn", "hybrid-mhc",
                                     "gpt2", "gpt2-attn-outside-unrolled"])
 def test_lowered_step_holds_every_registered_scope(family):
     import jax
@@ -233,7 +238,7 @@ def test_parse_anatomy_on_v5e_module_excerpt():
 @pytest.mark.parametrize("family", ["llama", "llama-moe", "llama-sdar",
                                     "hybrid", "hybrid-kda", "hybrid-mla",
                                     "hybrid-window", "hybrid-conv",
-                                    "hybrid-gdn",
+                                    "hybrid-gdn", "hybrid-mhc",
                                     "gpt2-attn-outside-unrolled"])
 def test_anatomy_of_a_tiny_cpu_step_end_to_end(family):
     from ray_tpu.parallel.train_state import PHASES
@@ -603,7 +608,7 @@ def _spread_by_the_call_sites():
     site makes it: the remat rule's decision, the splash kernel's counts
     under each of its masks, the step's start, and every kind's
     ``first_call_facts`` on a rehearsal preset that holds the kind."""
-    from ray_tpu.models import hybrid
+    from ray_tpu.models import hybrid, streams
     from ray_tpu.ops import attention, grad_ring, remat
 
     facts = set()
@@ -617,7 +622,9 @@ def _spread_by_the_call_sites():
         "counts": set().union(*(attention._splash_kernel(
             256, 4, 64, True, **mask)[1] for mask in masks)),
         "grad_ring.NO_RINGS": set(grad_ring.NO_RINGS),
-        "entry.module.first_call_facts(config, rows, S)": facts}
+        "entry.module.first_call_facts(config, rows, S)": facts,
+        "streams.first_call_facts(config, len(placed))": set(
+            streams.first_call_facts(families.preset("xing4_0"), 8))}
 
 
 def test_every_key_a_call_site_notes_is_in_the_table():
