@@ -30,6 +30,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models.layers import attention, stacked_normal
 from ray_tpu.ops import remat
+from ray_tpu.ops.attention import dq_partial_bytes
 
 #: the kind reads ``norm_after`` (``models/layers.py:attention``)
 NORM_AFTER = True
@@ -113,16 +114,28 @@ def layer_bytes(config, tokens: int, seq_len: int, tensor: int,
                 itemsize: int):
     """For ``hybrid._layer_sizes``, a chip's bytes of one layer over
     ``tokens`` positions with the heads cut ``tensor`` ways: (its working
-    set: six arrays as wide as the heads and the gate at its own width; what
+    set: three arrays as wide as the heads in the kernel's layout (a head's
+    lanes padded to the chip's tiles of 128), the gate at its own width and the
+    dq partials of the splash call's fused backward, one of q's size for
+    every kv block of the row, ``attention.dq_partial_bytes``; what
     it keeps for the backward beside its input: the kernel's output and
-    log-sum-exp; the ladder's candidates it names: q, k and v)."""
+    log-sum-exp; the rung it names: q, k and v, which spare their three
+    products and, where the layer has them, the QK-norm's and the rotary
+    passes over q and k)."""
     width = config.n_head * config.head_dim // tensor
     qkv_width = (config.n_head + 2 * config.n_kv_head) * config.head_dim \
         // tensor
     gate = _gate_width(config) // tensor if config.attn_gate else 0
-    return (tokens * (6 * width + gate) * itemsize,
+    padded = config.n_head // tensor * -(-config.head_dim // 128) * 128
+    turned = (qkv_width + width) // 2  # q and k
+    passes = 2 * bool(config.qk_norm) + 2 * (config.rope_theta is not None)
+    return (tokens * (3 * padded + gate) * itemsize + dq_partial_bytes(
+        tokens, seq_len, config.n_head // tensor, config.head_dim, itemsize,
+        config.attn_impl, config.attn_window),
             tokens * (width * itemsize + config.n_head // tensor * 4),
-            {remat.QKV: tokens * qkv_width * itemsize})
+            {remat.QKV: (tokens * qkv_width * itemsize, remat.spared(
+                flops=2.0 * tokens * config.d_model * qkv_width,
+                moved=tokens * passes * turned * itemsize))})
 
 
 def first_call_facts(config, rows: int, seq_len: int) -> Dict[str, Any]:

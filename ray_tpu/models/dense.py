@@ -62,11 +62,12 @@ def layer_bytes(config, tokens: int, seq_len: int, tensor: int,
     """For ``hybrid._layer_sizes``, a chip's bytes of one layer over
     ``tokens`` positions with the width cut ``tensor`` ways: (its working
     set: the gate and up products, their activation and the three
-    cotangents; nothing kept for the backward beside its input; the
-    ladder's candidates it names: the gate and up products)."""
+    cotangents; nothing kept for the backward beside its input; the rung it
+    names: the gate and up products, which spare those two products)."""
     width = config.dense_width // tensor
     return (6 * tokens * width * itemsize, 0,
-            {remat.GATE_UP: 2 * tokens * width * itemsize})
+            {remat.GATE_UP: (2 * tokens * width * itemsize, remat.spared(
+                flops=2.0 * tokens * config.d_model * 2 * width))})
 
 
 def first_call_facts(config, rows: int, seq_len: int) -> Dict[str, Any]:

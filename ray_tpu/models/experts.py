@@ -116,19 +116,30 @@ def layer_bytes(config, tokens: int, seq_len: int, tensor: int,
     """For ``hybrid._layer_sizes``, a chip's bytes of one layer over
     ``tokens`` positions with the shared expert's width cut ``tensor`` ways:
     (its working set: the shared expert's products, their activation and the
-    cotangents, and four copies of the (position, expert) rows; nothing kept
-    for the backward beside its input; what it names: of the ladder the
-    shared expert's up, and gate, product, and the routing, which no rule
-    decides on)."""
-    shared = config.shared_width
-    return (tokens * (3 * _matrices(config) * shared // tensor * itemsize
-                      + 4 * config.experts_per_token * config.d_model
-                      * itemsize),
-            0,
-            {remat.GATE_UP: tokens * (_matrices(config) - 1) * shared
-             // tensor * itemsize,
-             remat.ROUTING: moe.routing_bytes(tokens, config.n_experts,
-                                              config.experts_per_token)})
+    cotangents, and four copies of the rows the layer moves at once, each
+    with the routed experts' products and their cotangents beside it: every
+    (position, expert) pair where all experts are held, one window of the
+    held run (``moe.window_rows``) where the chip holds a share; what stands
+    from its forward to its backward beside its input: the held experts'
+    matrices in the compute dtype, which the compiled step casts once; what
+    it names: of the rungs the shared expert's up, and gate, product (and,
+    every expert held, the routed experts' over every pair; a share's run
+    inside its loop keeps nothing past its pass), which spare those
+    products, and the routing, which no rule decides on)."""
+    shared, m = config.shared_width // tensor, _matrices(config)
+    pairs = tokens * config.experts_per_token
+    whole = len(config.held) == config.n_experts
+    moved = pairs if whole else moe.window_rows(pairs)
+    kept_wide = (m - 1) * (shared * tokens + config.d_ff * pairs * whole)
+    named = {remat.ROUTING: (moe.routing_bytes(
+        tokens, config.n_experts, config.experts_per_token), 0.0)}
+    if kept_wide:
+        named = {remat.GATE_UP: (kept_wide * itemsize, remat.spared(
+            flops=2.0 * config.d_model * kept_wide)), **named}
+    return (tokens * 3 * m * shared * itemsize
+            + moved * (4 * config.d_model + 2 * m * config.d_ff) * itemsize,
+            len(config.held) * m * config.d_model * config.d_ff * itemsize,
+            named)
 
 
 def first_call_facts(config, rows: int, seq_len: int) -> Dict[str, Any]:

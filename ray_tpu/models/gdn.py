@@ -50,10 +50,15 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
+from jax.ad_checkpoint import checkpoint_name
+
 from ray_tpu.models.kda import l2norm
 from ray_tpu.models.layers import dense, rmsnorm, stacked_normal
 from ray_tpu.models.mamba2 import short_conv
+from ray_tpu.ops import remat
 from ray_tpu.ops.gdn import gdn
+from ray_tpu.ops.gdn import path as gdn_path
+from ray_tpu.ops.kda import SUB
 
 #: the kind reads ``norm_after`` (``hybrid.HybridConfig``)
 NORM_AFTER = True
@@ -137,23 +142,58 @@ def mixer_flops(config, seq_len: int) -> float:
     return 2.0 * H * (C * (1.5 * dk + dv) + 3 * dk * dv)
 
 
+def _scan_path(config, tokens: int, seq_len: int) -> str:
+    """``ops.gdn.path`` for a chip's ``tokens`` under the ambient mesh."""
+    mesh, rows = remat.rows_under_mesh(tokens, seq_len)
+    H, dk, dv = _dims(config)
+    return gdn_path((rows, seq_len, H, dk), (rows, seq_len, H, dv),
+                    min(config.gdn_chunk, seq_len), mesh)
+
+
 def layer_bytes(config, tokens: int, seq_len: int, tensor: int,
                 itemsize: int):
     """For ``hybrid._layer_sizes``, a chip's bytes of one layer over
-    ``tokens`` positions with the heads cut ``tensor`` ways: (its working
-    set; nothing kept for the backward beside its input; no candidate of the
-    ladder: what the scan keeps is bounded by the layer's own pass).  The
-    working set a position and a head: q and k with their scaled copies
-    (``Kbar``, ``Qbar``, ``K e^{G_C - G}``, ``T Kbar``) and cotangents, ten
-    arrays dk wide; v, the convolution's float32 v, ``T V``, ``U``, o, the
-    gate and their cotangents, twelve dv wide; and the chunk's ``Gamma``,
-    ``A``, ``B`` and ``T`` in float32 with ``B`` and ``T`` once more in the
-    compute dtype, each with a cotangent."""
+    ``tokens`` positions with the heads cut ``tensor`` ways, under the path
+    ``ops.gdn.path`` picks here: (its working set; nothing kept for the
+    backward beside its input; the rungs it names).  The rungs: the three
+    projections the convolutions read (``remat.CONV_IN``; keeping them
+    spares their products) and the chunks' inverse (``remat.INVERSE``: ``T``
+    in the compute dtype and, with the kernels, the float32 ``X`` their
+    backward reads beside it; keeping it spares the substitution's ``SUB``
+    dependent steps and the joins, passes bound by memory over the (C x C)
+    float32 blocks).  The working set a position and a head, with the
+    kernels (``ops/gdn_kernel.py``: ``Gamma``, ``B``, the scaled copies and
+    the state stay in VMEM): q, k, v as projected, after the convolution
+    and laid out for the kernels, with their cotangents, some four arrays
+    dk wide and six dv wide at a time (o, the gate and theirs among them);
+    ``A`` and ``X`` in float32 and ``T`` twice in the compute dtype; each
+    chunk's incoming state in float32, its value channels
+    padded to the chip's tiles of 128 lanes.  With XLA's form: q and k with
+    their scaled copies (``Kbar``, ``Qbar``, ``K e^{G_C - G}``, ``T Kbar``)
+    and cotangents, ten arrays dk wide; v, the convolution's float32 v, ``T
+    V``, ``U``, o, the gate and their cotangents, twelve dv wide; and the
+    chunk's ``Gamma``, ``A``, ``B`` and ``T`` in float32 with ``B`` and
+    ``T`` once more in the compute dtype, each with a cotangent."""
     H, dk, dv = _dims(config)
+    H //= tensor
     chunk = min(config.gdn_chunk, seq_len)
-    return (tokens * H * (10 * dk * itemsize + 12 * dv * itemsize
-                          + 2 * chunk * (4 * 4 + 2 * itemsize)) // tensor,
-            0, {})
+    if _scan_path(config, tokens, seq_len) == "kernel":
+        working = H * ((4 * dk + 6 * dv) * itemsize
+                       + chunk * (2 * 4 + 2 * itemsize)
+                       + dk * -(-dv // 128) * 128 * 4 // chunk)
+        inverse = H * chunk * (4 + itemsize)
+    else:
+        working = H * (10 * dk * itemsize + 12 * dv * itemsize
+                       + 2 * chunk * (4 * 4 + 2 * itemsize))
+        inverse = H * chunk * itemsize
+    sub = min(SUB, chunk)
+    passes = 2 * (sub + 3 * int(math.log2(chunk // sub)))
+    wide = H * (2 * dk + dv)
+    return (tokens * working, 0, {
+        remat.INVERSE: (tokens * inverse, remat.spared(
+            moved=passes * tokens * H * chunk * 4)),
+        remat.CONV_IN: (tokens * wide * itemsize, remat.spared(
+            flops=2.0 * tokens * config.d_model * wide))})
 
 
 def first_call_facts(config, rows: int, seq_len: int) -> Dict[str, Any]:
@@ -172,8 +212,9 @@ def mixer(x, blk, config, axes):
     with jax.named_scope("gdn"):
         u = x if config.norm_after else rmsnorm(
             x, blk["gdn_norm"], config.rms_eps).astype(dt)
-        q, k, v = (dense(u, blk, name, axes, dt) for name in ("wq", "wk",
-                                                              "wv"))
+        q, k, v = (checkpoint_name(dense(u, blk, name, axes, dt),
+                                   remat.CONV_IN)
+                   for name in ("wq", "wk", "wv"))
         with jax.named_scope("gdn_conv"):
             q, k, v = (short_conv(a, blk["conv_" + name],
                                   dt if name == "v" else f32)

@@ -46,9 +46,11 @@ letter) answers, for a configuration with the fields it reads:
   pre-norm included; ``mixer_flops(config, seq_len)``: the forward FLOPs a
   position that are no matrix it meets (causal attention, a scan);
 - ``layer_bytes(config, tokens, seq_len, tensor, itemsize)``: a chip's bytes
-  of one layer for :func:`_layer_sizes`, (its working set, what it keeps for
-  the backward beside its input, {a name of ``ops.remat``'s it bears, a rung
-  of the ladder or ``ROUTING``: the bytes});
+  of one layer for :func:`_layer_sizes`, under the implementation its ops
+  pick for these shapes and the ambient mesh (``ops.ssd.path`` and its
+  like): (its working set, what it keeps for the backward beside its input,
+  {a name of ``ops.remat``'s it bears: (the bytes, the forward work keeping
+  them spares, ``remat.spared``)}; ``ROUTING`` spares nothing, it is kept);
 - ``first_call_facts(config, rows, seq_len)``: what it notes for the
   first-call record (``util/first_call.py``);
 - ``layer(config, axes, index)``: layer ``index`` of the kind as (x, its row
@@ -110,11 +112,14 @@ would scan over its period; the published one does not repeat evenly, and
 the cut a chip trains is one stretch of it.)
 
 **What each layer keeps for the backward** (``ops/remat.py``): every layer
-runs under ``jax.checkpoint`` with the one policy the rule gives this step;
-the kinds name the arrays worth keeping, as ``llama.py`` names its own, and
-:func:`_layer_sizes` hands the rule each kind's sizes.  Beside its input and
-the splash kernel's residuals a layer always keeps what its router decided
-(``remat.ROUTING``, ``models/moe.py``): a step routes once.
+runs under a ``jax.checkpoint`` of its own, with the policy the rule's
+decision gives that layer (``Decision.policy``): the kinds name the arrays
+worth keeping, as ``llama.py`` names its own, :func:`_layer_sizes` hands
+the rule each kind's rungs (bytes and spared work a layer, how many layers
+name them), and a rung is kept for as many of its kind's layers as fit, the
+first ones.  Beside its input and the splash kernel's residuals a layer
+always keeps what its router decided (``remat.ROUTING``,
+``models/moe.py``): a step routes once.
 """
 
 from __future__ import annotations
@@ -166,6 +171,13 @@ KINDS = {"M": Kind("ssm", "ray_tpu.models.mamba2", 2),
          "W": Kind("window", "ray_tpu.models.window", 8),
          "C": Kind("shortconv", "ray_tpu.models.shortconv", 9),
          "G": Kind("gdn", "ray_tpu.models.gdn", 10)}
+
+#: What the compiler's heap loses between buffers of different lifetimes (a
+#: layer's dq partials and products against the long-lived gradients and kept
+#: inputs), a sub-layer, over the fullest moment :func:`_layer_sizes` adds
+#: up: the seven hybrid cells' compiled steps read 3 to 28 MiB a sub-layer
+#: over that moment (PERF.md, PR 63), the cells of fourteen most in all.
+PACKING = 40 << 20
 
 #: folded into ``init_params``' key for the prediction module's ``w_eh``
 MTP_DRAW = 47
@@ -428,25 +440,29 @@ def flops_per_token(config: HybridConfig) -> float:
 
 def _layer_sizes(params, x_shape, config: HybridConfig):
     """What ``ops.remat`` needs to know of ``params`` (arrays or shapes) and
-    activations of ``x_shape`` (B, S, D), every size a chip's, as
-    ``llama._layer_sizes`` gives them: (the ladder's candidates as (name,
-    bytes), a bound on the step's own temporaries).  The candidates: what
-    the pattern's layers name of each rung (``layer_bytes`` of their kinds),
-    and behind them, where a layer routes, what the layers name ``ROUTING``.
-    The bound is the larger of two moments.  Inside the layers: the stacks'
-    gradients in float32, every weight's cast to the compute dtype, each
-    layer's kept input and what its kind keeps beside it, and the widest
-    layer's working set (two kinds overstate theirs, each in its own
-    ``layer_bytes``: ROADMAP C15).  Around the head: the logits and their
-    cotangent beside the same casts and inputs.  Held against the v5e
-    compiler for the benchmark's cell (9 layers, 2 x 8192 tokens) it reads
-    8.65 GiB for 6.37 of temporaries: beside 6.21 GiB of state and the
-    reserve the chip has no room for a rung, by 0.7 GiB, and a second trace
-    later in the process, when 0.3 GiB more is in use, says the same (a
-    bound of 7.65 kept q, k and v on the first trace and not on the second:
-    PERF.md, PR 40).  Under ``streams`` n > 1 a kept input is n times as
-    wide, the widest working set may be the maps' own, and the maps
-    (``remat.MAPS``) are a candidate in front of the ladder's."""
+    activations of ``x_shape`` (B, S, D), every size a chip's: (the rungs
+    the layers name, ``remat.Rung``s, one for each kind and name with the
+    number of the kind's layers, and behind them, where a layer routes,
+    ``ROUTING`` likewise; a bound on the step's own temporaries without any
+    of them).  The bound is the fullest moment of the step, and the step
+    runs unrolled, so the moments are the layers' own: while layer i runs
+    backwards the gradients of the layers after it stand in float32 (and
+    the head's and the embedding's), the layers up to it still hold their
+    kept input and what their kinds keep beside it (an expert layer its
+    held matrices in the compute dtype), and layer i has its working set,
+    each kind's as its ``layer_bytes`` states it for the path its ops take
+    here (the kernels' where they run: boundary states and the scan's
+    inputs, not XLA's (chunk x chunk) arrays).  Before the first of them,
+    around the head: every layer's kept arrays, the logits and their
+    cotangent and the head's gradient.  After the last: every gradient.
+    Over the fullest of them :data:`PACKING` a sub-layer.
+    Held against the v5e compiler's
+    ``memory_analysis()`` for the seven hybrid cells it lies over the
+    compiled step's temporaries by less than 0.5 GiB
+    (``tests/test_remat.py::test_the_bound_lies_over_the_compilers``).
+    Under ``streams`` n > 1 a kept input is n times as wide, the widest
+    working set may be the maps' own, and the maps (``remat.MAPS``) are a
+    rung of the sub-layers' own group."""
     mesh = jax.sharding.get_abstract_mesh()
     tensor = remat.axis_shards(mesh, "tensor")
     tokens = math.prod(x_shape[:2]) // remat.axis_shards(
@@ -456,34 +472,41 @@ def _layer_sizes(params, x_shape, config: HybridConfig):
         lambda a, axes: 4 * a.size // remat.axis_shards(
             mesh, *mesh_axes(axes)), params, logical_axes(config))
     kinds = _kinds(config)
-    stacks = sum(jax.tree.leaves(
-        {entry.stack: chips[entry.stack] for entry in kinds.values()})) \
-        + sum(jax.tree.leaves(chips.get(streams.STACK, {})))
     total = sum(jax.tree.leaves(chips))
-    casts = int(total * item / 4)
-    sizes = {kind: entry.module.layer_bytes(config, tokens, x_shape[1],
-                                            tensor, item)
-             for kind, entry in kinds.items()}
-    candidates = dict.fromkeys(remat.LADDER, 0)
     layers = config.sublayers
-    kept_inputs = len(layers) * config.streams * tokens * config.d_model \
-        * item
-    under_maps = []
+    # a group of layers: (how many they are, their layer_bytes)
+    groups = {kind: (layers.count(kind), entry.module.layer_bytes(
+        config, tokens, x_shape[1], tensor, item))
+        for kind, entry in kinds.items()}
+    maps = (0, 0, {})
     if config.streams > 1:
-        sizes[streams.STACK] = streams.layer_bytes(config, tokens, item)
-        under_maps = [sizes[streams.STACK]] * len(layers)
-        candidates = {remat.MAPS: 0, **candidates}
-    for _, kept, named in [sizes[kind] for kind in layers] + under_maps:
-        kept_inputs += kept
-        for name, nbytes in named.items():
-            candidates[name] = candidates.get(name, 0) + nbytes
-    in_the_layers = stacks + casts + kept_inputs \
-        + max(working for working, _, _ in sizes.values())
-    # with a prediction module the first pass's logits wait for the second's
-    at_the_head = (total - stacks) + casts + kept_inputs \
-        + (2 + config.mtp_depth) * tokens \
-        * config.vocab_size // tensor * jnp.dtype(config.logits_dtype).itemsize
-    return list(candidates.items()), max(in_the_layers, at_the_head)
+        maps = streams.layer_bytes(config, tokens, item)
+        groups = {streams.STACK: (len(layers), maps), **groups}
+    rungs = [remat.Rung(name, nbytes, spares, n, group)
+             for group, (n, (_, _, named)) in groups.items()
+             for name, (nbytes, spares) in named.items()]
+    # the routing last, where the rule's callers look for it
+    rungs.sort(key=lambda rung: rung.name == remat.ROUTING)
+
+    hc = sum(jax.tree.leaves(chips.get(streams.STACK, {}))) // len(layers)
+    x = config.streams * tokens * config.d_model * item
+    # a kind's layer: (its gradients, what it keeps, its working set)
+    a_layer = {kind: (sum(jax.tree.leaves(chips[kinds[kind].stack]))
+                      // config.rows(kind) + hc, x + kept + maps[1],
+                      working + maps[0])
+               for kind, (_, (working, kept, _)) in groups.items()
+               if kind in kinds}
+    grads, kept, working = zip(*(a_layer[kind] for kind in layers))
+    outside = total - sum(grads)
+    moments = [  # with a prediction module the first pass's logits wait
+        sum(kept) + outside // (1 if config.tie_head else 2)
+        + (2 + config.mtp_depth) * tokens * config.vocab_size // tensor
+        * jnp.dtype(config.logits_dtype).itemsize,
+        total]
+    for i in range(len(layers)):
+        moments.append(outside + sum(grads[i + 1:]) + sum(kept[:i + 1])
+                       + working[i])
+    return rungs, max(moments) + len(layers) * PACKING
 
 
 def _placed(kinds: str) -> List[Tuple[str, int]]:
@@ -496,10 +519,12 @@ def _placed(kinds: str) -> List[Tuple[str, int]]:
     return placed
 
 
-def _run(params, x, layers, config: HybridConfig, axes, policy,
+def _run(params, x, layers, config: HybridConfig, axes, decision,
          first: int = 0):
     """``x`` through ``layers`` (:func:`_placed`'s pairs) -> (x, the step
-    counters of the layers that leave some, in order).  Under ``streams``
+    counters of the layers that leave some, in order), each layer under a
+    ``jax.checkpoint`` whose policy is ``decision``'s for that layer
+    (``remat.Decision``; None: no checkpoint).  Under ``streams``
     > 1 ``x`` is the streams, n arrays (B, S, D), each layer its kind's branch
     between the maps' read and write (``streams.layer``), and ``first`` the
     row of ``hc`` that the first of ``layers`` takes."""
@@ -507,14 +532,16 @@ def _run(params, x, layers, config: HybridConfig, axes, policy,
     for at, (kind, index) in enumerate(layers, first):
         stack, module = KINDS[kind].stack, KINDS[kind].module
         rows = [jax.tree.map(lambda a: a[index], params[stack])]
+        bears = [(kind, index)]
         if config.streams > 1:
             layer = streams.layer(
                 config, module.branch(config, axes[stack], index))
             rows.append(jax.tree.map(lambda a: a[at], params[streams.STACK]))
+            bears.append((streams.STACK, at))
         else:
             layer = module.layer(config, axes[stack], index)
-        if config.remat:
-            layer = jax.checkpoint(layer, policy=policy)
+        if decision is not None:
+            layer = jax.checkpoint(layer, policy=decision.policy(*bears))
         x, counted = layer(x, *rows)
         if counted is not None:
             counts.append(counted)
@@ -540,7 +567,7 @@ def _predict_ahead(params, x, targets, run, config: HybridConfig):
     it) -> (the mean over i < S - 1 of the cross-entropy of the token two
     ahead, the module's layers' counters in order).  ``run``: a row's
     activations through the module's layers (:func:`_run` with the step's
-    axes and policy).  The last position, which has no token two ahead,
+    axes and decision).  The last position, which has no token two ahead,
     goes through the block and weighs nothing.  Under ``streams`` > 1 ``x``
     is the streams: each is normed and joined with the embedding by the one
     ``w_eh`` (whose embedding half multiplies once for all of them, the
@@ -603,10 +630,10 @@ def loss_and_counters(params, tokens, targets, config: HybridConfig):
         first_call.note(**entry.module.first_call_facts(config, rows, S))
     with jax.named_scope("embed"):
         x = params["wte"][tokens].astype(dt)
-    policy = remat.layer_policy(*_layer_sizes(params, x.shape, config)) \
+    decision = remat.decide(*_layer_sizes(params, x.shape, config)) \
         if config.remat else None
     run = partial(_run, params, config=config, axes=logical_axes(config),
-                  policy=policy)
+                  decision=decision)
     placed = _placed(config.sublayers)
     if config.streams > 1:
         first_call.note(**streams.first_call_facts(config, len(placed)))

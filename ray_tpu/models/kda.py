@@ -42,10 +42,13 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models.layers import dense, rmsnorm, stacked_normal
 from ray_tpu.models.mamba2 import short_conv
+from ray_tpu.ops import remat
 from ray_tpu.ops.kda import SUB, kda
+from ray_tpu.ops.kda import path as kda_path
 
 #: under the square root of q's and k's L2 norms
 L2_EPS = 1e-6
@@ -131,24 +134,40 @@ def mixer_flops(config, seq_len: int) -> float:
     return 2.0 * H * (2.5 * C * d + 3 * d * d)
 
 
+def _scan_path(config, tokens: int, seq_len: int) -> str:
+    """``ops.kda.path`` for a chip's ``tokens`` under the ambient mesh."""
+    mesh, rows = remat.rows_under_mesh(tokens, seq_len)
+    return kda_path((rows, seq_len, config.kda_heads, config.kda_head_dim),
+                    min(config.kda_chunk, seq_len), mesh)
+
+
 def layer_bytes(config, tokens: int, seq_len: int, tensor: int,
                 itemsize: int):
     """For ``hybrid._layer_sizes``, a chip's bytes of one layer over
-    ``tokens`` positions with the heads cut ``tensor`` ways: (its working
-    set; nothing kept for the backward beside its input; no candidate of the
-    ladder: what the scan keeps is bounded by the layer's own pass).  The
-    working set a position: eight arrays as wide as the heads (q, k, v, o,
-    the two gates, the decay's log and its cumulative sum in float32 counted
-    twice) and, a head, what ``ops/kda.py`` builds: the explicit decays
+    ``tokens`` positions with the heads cut ``tensor`` ways, under the path
+    ``ops.kda.path`` picks here: (its working set; nothing kept for the
+    backward beside its input; the rung it names: the three projections the
+    convolutions read, ``remat.CONV_IN``, which spares their products).
+    The working set a position: eight arrays as wide as the heads (q, k, v,
+    o, the two gates, the decay's log and its cumulative sum in float32
+    counted twice) and, a head, the chunk's three (chunk x chunk) matrices
+    and what else the scan builds: with the kernels (``ops/kda_kernel.py``)
+    each chunk's incoming state alone, the explicit decays and the scaled
+    copies of q and k living in VMEM; with XLA's form the explicit decays
     inside a sub-chunk (SUB x d float32, two copies live), the keys scaled
-    for each later sub-chunk, five more scaled copies of q and k, and the
-    chunk's three (chunk x chunk) matrices."""
+    for each later sub-chunk and five more scaled copies of q and k."""
     d, chunk = config.kda_head_dim, min(config.kda_chunk, seq_len)
     sub = min(SUB, chunk)
+    if _scan_path(config, tokens, seq_len) == "kernel":
+        built = d * d * itemsize // chunk
+    else:
+        built = 2 * sub * d * 4 + (chunk // sub + 5) * d * itemsize
+    wide = 3 * config.kda_heads * d // tensor
     return (tokens * (config.kda_heads * (
-        d * (8 * itemsize + 2 * 4) + 2 * sub * d * 4
-        + (chunk // sub + 5) * d * itemsize + 3 * chunk * (4 + itemsize)))
-        // tensor, 0, {})
+        d * (8 * itemsize + 2 * 4) + built + 3 * chunk * (4 + itemsize)))
+        // tensor, 0,
+        {remat.CONV_IN: (tokens * wide * itemsize, remat.spared(
+            flops=2.0 * tokens * config.d_model * wide))})
 
 
 def first_call_facts(config, rows: int, seq_len: int) -> Dict[str, Any]:
@@ -171,8 +190,9 @@ def mixer(x, blk, config, axes):
     no_bias = jnp.zeros((H * d,), f32)
     with jax.named_scope("kda"):
         u = rmsnorm(x, blk["kda_norm"], config.rms_eps).astype(dt)
-        q, k, v = (dense(u, blk, name, axes, dt) for name in ("wq", "wk",
-                                                              "wv"))
+        q, k, v = (checkpoint_name(dense(u, blk, name, axes, dt),
+                                   remat.CONV_IN)
+                   for name in ("wq", "wk", "wv"))
         with jax.named_scope("kda_conv"):
             q, k, v = (short_conv(a, blk["conv_" + name],
                                   dt if name == "v" else f32, no_bias)

@@ -42,9 +42,11 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models.layers import dense, rmsnorm
-from ray_tpu.ops import conv_kernel
+from ray_tpu.ops import conv_kernel, remat
+from ray_tpu.ops.ssd import path as ssd_path
 from ray_tpu.ops.ssd import ssd
 
 
@@ -130,29 +132,42 @@ def mixer_flops(config, seq_len: int) -> float:
     return 2.0 * (G * Q * N / 2 + H * Q * P / 2 + 2 * H * P * N)
 
 
+def _scan_path(config, tokens: int, seq_len: int) -> str:
+    """``ops.ssd.path`` for a chip's ``tokens`` under the ambient mesh."""
+    mesh, rows = remat.rows_under_mesh(tokens, seq_len)
+    H, G = config.ssm_heads, config.ssm_groups
+    return ssd_path((rows, seq_len, H, config.ssm_head_dim),
+                    (rows, seq_len, G, config.ssm_state),
+                    min(config.ssm_chunk, seq_len), mesh)
+
+
 def layer_bytes(config, tokens: int, seq_len: int, tensor: int,
                 itemsize: int):
     """For ``hybrid._layer_sizes``, a chip's bytes of one layer over
-    ``tokens`` positions with the inner width cut ``tensor`` ways: (its
-    working set; nothing kept for the backward beside its input; no
-    candidate of the ladder: where ``ops/ssd.py`` takes its kernels the scan
-    keeps its inputs and each chunk's incoming state, 16 KiB a token at 64
-    heads of 64 over a state of 128 in chunks of 128, from the layer's
-    second forward to its backward; the XLA form's intermediates are the
-    (chunk x chunk) decays, 32 KiB a token a float32 copy: neither is worth
-    a rung).  The working set: six arrays as wide as ``in_proj``'s output
-    and, a head and a chunk position, the scan's (chunk x chunk) decays, two
-    float32 and a compute-dtype copy each way (the compiler fuses the rest
-    of them away; **where the scan runs as ``ops/ssd_kernel.py``'s kernels
-    they never reach HBM and the term overstates the layer by what it
-    counts, 2.7 GB in the benchmark's cell, against 0.27 GB of boundary
-    states: left as it is by PR 44, since the bound it feeds decides what
-    ``ops/remat.py`` keeps and a change there is ROADMAP C15's**)."""
-    return (tokens * (6 * widths(config)["in_proj"] // tensor * itemsize
-                      + config.ssm_heads // tensor
-                      * min(config.ssm_chunk, seq_len)
-                      * 2 * (2 * 4 + itemsize)),
-            0, {})
+    ``tokens`` positions with the inner width cut ``tensor`` ways, under
+    the path ``ops.ssd.path`` picks here: (its working set; nothing kept for
+    the backward beside its input; the rung it names: ``in_proj``'s output,
+    ``[z | xBC | dt]`` (``remat.SSM_IN``), which spares the product that
+    made it).  The working set: four arrays as wide as ``in_proj``'s output
+    (the projection, its cotangent and the passes between them in the
+    compute dtype and float32) and what the scan leaves in HBM.  With the
+    kernels (``ops/ssd_kernel.py``) that is each chunk's incoming state, a
+    head (P x N in the compute dtype), the cumulative sums and ``delta`` in
+    two layouts; the (chunk x chunk) decays live in VMEM.  With XLA's form
+    it is those decays a head and a chunk position, two float32 and a
+    compute-dtype copy each way (the compiler fuses the rest of them
+    away)."""
+    w = widths(config)
+    H, chunk = config.ssm_heads // tensor, min(config.ssm_chunk, seq_len)
+    if _scan_path(config, tokens, seq_len) == "kernel":
+        scan = H * (config.ssm_head_dim * config.ssm_state * itemsize
+                    // chunk + 6 * 4)
+    else:
+        scan = H * chunk * 2 * (2 * 4 + itemsize)
+    wide = w["in_proj"] // tensor
+    return (tokens * (4 * wide * itemsize + scan), 0,
+            {remat.SSM_IN: (tokens * wide * itemsize, remat.spared(
+                flops=2.0 * tokens * config.d_model * wide))})
 
 
 def first_call_facts(config, rows: int, seq_len: int) -> Dict[str, Any]:
@@ -239,7 +254,8 @@ def mixer(x, blk, config, axes):
     w = widths(config)
     with jax.named_scope("ssm"):
         u = rmsnorm(x, blk["ssm_norm"], config.rms_eps).astype(dt)
-        zxbcdt = dense(u, blk, "in_proj", axes, dt)
+        zxbcdt = checkpoint_name(dense(u, blk, "in_proj", axes, dt),
+                                 remat.SSM_IN)
         z, xBC, delta = jnp.split(
             zxbcdt, [w["inner"], w["inner"] + w["conv"]], axis=-1)
         with jax.named_scope("ssm_conv"):

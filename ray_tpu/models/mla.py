@@ -58,7 +58,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models.layers import dense, rmsnorm, rope, stacked_normal
 from ray_tpu.ops import remat
-from ray_tpu.ops.attention import causal_attention
+from ray_tpu.ops.attention import causal_attention, dq_partial_bytes
 
 
 def _dims(config):
@@ -121,16 +121,30 @@ def layer_bytes(config, tokens: int, seq_len: int, tensor: int,
                 itemsize: int):
     """For ``hybrid._layer_sizes``, a chip's bytes of one layer over
     ``tokens`` positions with the heads cut ``tensor`` ways: (its working
-    set: q and k with their cotangents, v and the output with theirs, and
-    ``wkv_b``'s product before it is cut into k and v; what it keeps for the
+    set: q and k with their cotangents, v and the output with theirs,
+    ``wkv_b``'s product before it is cut into k and v, and the dq partials
+    of the splash call's fused backward, ``attention.dq_partial_bytes``;
+    what it keeps for the
     backward beside its input: the kernel's output and log-sum-exp; the
-    ladder's candidates it names: q, k and v)."""
+    rungs it names: the two normed latents and the rotary key,
+    ``remat.LATENTS``, which spare the two products down and the norms'
+    passes, and q, k and v, which spare the two products up from the
+    latents and the rotary passes over q and k)."""
     H, nope, rot, dv = _dims(config)
     heads = H // tensor
     qk, v = heads * (nope + rot), heads * dv
-    return (tokens * (4 * qk + 4 * v + heads * (nope + dv)) * itemsize,
+    Lq, Lkv, D = config.mla_q_latent, config.mla_kv_latent, config.d_model
+    latents = Lq + Lkv + rot
+    return (tokens * (4 * qk + 4 * v + heads * (nope + dv)) * itemsize
+            + dq_partial_bytes(tokens, seq_len, heads, nope + rot, itemsize,
+                               config.attn_impl),
             tokens * (v * itemsize + heads * 4),
-            {remat.QKV: tokens * (2 * qk + v) * itemsize})
+            {remat.LATENTS: (tokens * latents * itemsize, remat.spared(
+                flops=2.0 * tokens * D * latents,
+                moved=tokens * (2 * latents * (4 + itemsize)))),
+             remat.QKV: (tokens * (2 * qk + v) * itemsize, remat.spared(
+                 flops=2.0 * tokens * (Lq * qk + Lkv * heads * (nope + dv)),
+                 moved=tokens * 2 * 2 * qk * itemsize))})
 
 
 def first_call_facts(config, rows: int, seq_len: int) -> Dict[str, Any]:
@@ -154,14 +168,17 @@ def _wo_of(x, blk, config, axes):
                        inv_freq=yarn.inv_freq(rot, config.mla_rope_theta))
     h = rmsnorm(x, blk["attn_norm"], config.rms_eps).astype(dt)
     with jax.named_scope("latent"):
-        c_q = rmsnorm(dense(h, blk, "wq_a", axes, dt), blk["q_norm"],
-                      config.rms_eps).astype(dt)
+        c_q = checkpoint_name(
+            rmsnorm(dense(h, blk, "wq_a", axes, dt), blk["q_norm"],
+                    config.rms_eps).astype(dt), remat.LATENTS)
         q = (c_q @ blk["wq_b"].astype(dt)).reshape(B, S, H, nope + rot)
         q = turn(q, rotary=rot)
         down = dense(h, blk, "wkv_a", axes, dt)
-        c_kv = rmsnorm(down[..., :Lkv], blk["kv_norm"],
-                       config.rms_eps).astype(dt)
-        k_rot = turn(down[..., Lkv:].reshape(B, S, 1, rot))
+        c_kv = checkpoint_name(
+            rmsnorm(down[..., :Lkv], blk["kv_norm"],
+                    config.rms_eps).astype(dt), remat.LATENTS)
+        k_rot = checkpoint_name(turn(down[..., Lkv:].reshape(B, S, 1, rot)),
+                                remat.LATENTS)
         up = (c_kv @ blk["wkv_b"].astype(dt)).reshape(B, S, H, nope + dv)
         k = jnp.concatenate(
             [up[..., :nope], jnp.broadcast_to(k_rot, (B, S, H, rot))],

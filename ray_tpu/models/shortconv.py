@@ -40,10 +40,11 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models.layers import dense, rmsnorm, stacked_normal
 from ray_tpu.models.mamba2 import causal_conv
-from ray_tpu.ops import conv_kernel
+from ray_tpu.ops import conv_kernel, remat
 
 
 def init_params(config, key, n: int, out_std: float) -> Dict[str, Any]:
@@ -89,13 +90,20 @@ def mixer_flops(config, seq_len: int) -> float:
 def layer_bytes(config, tokens: int, seq_len: int, tensor: int,
                 itemsize: int):
     """For ``hybrid._layer_sizes``, a chip's bytes of one layer over
-    ``tokens`` positions with the width cut ``tensor`` ways: (its working
-    set: ``[B | C | u]`` and its cotangent in the compute dtype and, a
-    channel, the padded product, the taps' sum and their two cotangents in
-    float32; nothing kept for the backward beside its input; no candidate
-    of the ladder)."""
+    ``tokens`` positions with the width cut ``tensor`` ways, under the form
+    ``conv_kernel.path`` picks here: (its working set: ``[B | C | u]`` and
+    its cotangent in the compute dtype and, with XLA's form, a channel, the
+    padded product, the taps' sum and their two cotangents in float32,
+    which the kernel's pass keeps in VMEM; nothing kept for the backward
+    beside its input; the rung it names: ``[B | C | u]``,
+    ``remat.CONV_IN``, which spares ``in_proj``'s product)."""
     width = config.d_model // tensor
-    return (tokens * width * (2 * 3 * itemsize + 4 * 4), 0, {})
+    mesh, rows = remat.rows_under_mesh(tokens, seq_len)
+    kernel = conv_kernel.path((rows, seq_len, 3 * config.d_model),
+                              config.conv_taps, mesh, gated=True) == "kernel"
+    return (tokens * width * (2 * 3 * itemsize + (0 if kernel else 4 * 4)),
+            0, {remat.CONV_IN: (tokens * 3 * width * itemsize, remat.spared(
+                flops=2.0 * tokens * config.d_model * 3 * width))})
 
 
 def first_call_facts(config, rows: int, seq_len: int) -> Dict[str, Any]:
@@ -142,7 +150,8 @@ def mixer(x, blk, config, axes):
     dt = config.dtype
     with jax.named_scope("shortconv"):
         h = rmsnorm(x, blk["conv_norm"], config.rms_eps).astype(dt)
-        bcu = dense(h, blk, "in_proj", axes, dt)
+        bcu = checkpoint_name(dense(h, blk, "in_proj", axes, dt),
+                              remat.CONV_IN)
         with jax.named_scope("shortconv_gate"):
             kernel = conv_kernel.engaged(bcu.shape, blk["conv_w"].shape[0],
                                          gated=True)

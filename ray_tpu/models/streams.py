@@ -131,12 +131,18 @@ def num_params(config) -> int:
 def layer_bytes(config, tokens: int, itemsize: int):
     """For ``hybrid._layer_sizes``, a chip's bytes of one sub-layer's maps,
     read and write over ``tokens`` positions: (their working set: the
-    streams' cotangent coming in, the one going out and a float32 pass of
-    one of them between; what is kept beside the layer's input: nothing; the
-    candidate it names: the maps)."""
-    wide = tokens * config.streams * config.d_model
-    return (wide * (2 * itemsize + 4), 0,
-            {remat.MAPS: tokens * maps_width(config) * 4})
+    streams' cotangent coming in, the one going out and a pass of one of
+    them between, in the compute dtype; what is kept beside the layer's
+    input: nothing; the rung it names: the maps, which spare the norm's pass
+    over every stream, the product that makes their logits and the Sinkhorn
+    turns' passes over the n x n of them)."""
+    n, D = config.streams, config.d_model
+    wide = tokens * n * D
+    return (wide * 3 * itemsize, 0,
+            {remat.MAPS: (tokens * maps_width(config) * 4, remat.spared(
+                flops=2.0 * wide * maps_width(config),
+                moved=wide * itemsize + 2 * config.hc_sinkhorn_iters
+                * tokens * n * n * 4))})
 
 
 def first_call_facts(config, sublayers: int) -> Dict[str, Any]:

@@ -279,6 +279,18 @@ def splash_blocks(kv_len: int, head_dim: int, window: int = 0) -> SplashBlocks:
     return SplashBlocks(block, block, 512, block, block, 512).capped(kv_len)
 
 
+def dq_partial_bytes(tokens: int, seq_len: int, heads: int, head_dim: int,
+                     itemsize: int, impl: str, window: int = 0) -> int:
+    """What the splash call's fused backward writes beside dk and dv, for
+    ``ops/remat.py``'s bound: a dq partial of q's shape for every kv block
+    of the row (:func:`splash_blocks`' ``kv_bwd``), the head's lanes padded
+    to the chip's tiles of 128; 0 under another implementation."""
+    if not scales_q(impl):
+        return 0
+    blocks = seq_len // splash_blocks(seq_len, head_dim, window).kv_bwd
+    return tokens * heads * -(-head_dim // 128) * 128 * itemsize * blocks
+
+
 def _splash_kernel(seq_len: int, n_heads: int, head_dim: int, causal: bool,
                    block_length: int = 0,
                    blocks: Optional[SplashBlocks] = None, window: int = 0):

@@ -86,8 +86,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
-from ray_tpu.ops import gdn_kernel
+from ray_tpu.ops import gdn_kernel, remat
 from ray_tpu.ops.kda import _unit_lower_inverse
 from ray_tpu.ops.placement import place, rows_and_heads
 from ray_tpu.util import first_call
@@ -160,7 +161,7 @@ def gdn_xla(q, k, v, g, beta, chunk: int):
     # --- the triangular system a chunk and a head
     T = _unit_lower_inverse(jnp.where(s < t, A, 0.0) * bc[..., None]) \
         * bc[..., None, :]
-    T = T.astype(dt)
+    T = checkpoint_name(T.astype(dt), remat.INVERSE)
     decayed = jnp.exp(G)[..., None]                       # e^{G_t}
     q32, k32 = qc.astype(f32), kc.astype(f32)
     k_bar, q_bar = (k32 * decayed).astype(dt), (q32 * decayed).astype(dt)

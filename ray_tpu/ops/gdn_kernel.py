@@ -66,9 +66,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.ops import remat
 from ray_tpu.ops.kda import _unit_lower_inverse
 from ray_tpu.ops.kda_kernel import _rows, inverse_backward, within_chunks
 from ray_tpu.ops.ssd_kernel import LANES, _F32, _NT, _TN, _dot, _interpret
@@ -543,8 +545,12 @@ def _forward(q, k, v, g, beta, chunk, heads):
     # columns and never enters a kernel
     beta = jnp.moveaxis(beta.astype(_F32).reshape(b, S // chunk, chunk, H),
                         3, 2)
-    X = _unit_lower_inverse(A * beta[..., None])
-    T = (X * beta[..., None, :]).astype(q.dtype)
+    # what the layer's checkpoint may keep (``ops/remat.py``): the backward
+    # reads both, and a second forward then runs no substitution
+    X = checkpoint_name(_unit_lower_inverse(A * beta[..., None]),
+                        remat.INVERSE)
+    T = checkpoint_name((X * beta[..., None, :]).astype(q.dtype),
+                        remat.INVERSE)
     o, incoming = outputs_forward(q, k, v, g_col, g_row, T, chunk, heads, H)
     return _unlay(o, H), (q, k, v, g_col, g_row, beta, A, X, T, incoming)
 

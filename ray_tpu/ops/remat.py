@@ -8,10 +8,16 @@ kept (:data:`ROUTING`: a few MB a layer, which a full-precision product, a
 ``top_k`` and three sorts would make again), and the rest of the layer is run
 a second time in the backward.  Where the chip has memory to spare that second
 run is 10-14 % of the step paid for bytes nobody uses.  The models name the
-arrays worth keeping (``jax.ad_checkpoint.checkpoint_name``), in the order of
-:data:`LADDER`; :func:`layer_policy` keeps as many rungs as fit and hands back
-the checkpoint policy.  There is no option: the answer follows from sizes the
-code can observe.
+arrays worth keeping (``jax.ad_checkpoint.checkpoint_name``) and say, from
+shapes, what each takes a layer and what forward work keeping it spares
+(:class:`Rung`); :func:`choose` climbs the rungs in the order of spared work
+a byte and keeps each for as many of the layers that name it as fit.  A
+scanned stack (``models/llama.py``) is one layer to the rule, all or nothing,
+and its two rungs cost the same a byte, so their order is :data:`LADDER`'s; a
+stack that runs unrolled (``models/hybrid.py``) wraps each layer in a
+``jax.checkpoint`` of its own and asks the decision for each layer's policy
+(:meth:`Decision.policy`).  There is no option: the answer follows from sizes
+the code can observe.
 
 **What it reads, and when.**  :func:`device_memory` asks the process's local
 devices for ``memory_stats()`` while the loss is being *traced*, and takes the
@@ -21,7 +27,7 @@ state) is resident, so the difference is what the step's own temporaries may
 take.  Everything else is shapes: the candidates' bytes a chip and a bound on
 what the program needs without them (:func:`own_temporaries`).  A backend
 without memory statistics (the CPU; a compile for a described topology)
-answers ``None`` and the policy is the plain one, :data:`LADDER` unused.
+answers ``None`` and the policy is the plain one, no rung kept.
 
 **One program on every host.**  A step over a mesh of several processes is
 one SPMD program, and each process traces it for itself: were each to decide
@@ -47,17 +53,23 @@ rung in exactly the jobs that fill their chips: 34 s for the two-layer
 Mistral step against 20 s of whole set-up (PERF.md, PR 31).
 
 **Stability.**  The same function may be traced twice in a process (the train
-step, then a tool that lowers it again for its text); both must get the same
-program.  The rule's margins are hundreds of MB; a batch or a loss more or
-less in ``bytes_in_use`` does not move it.  :func:`fall_back` pins the plain
-policy for the rest of the process once a richer program was refused; the
-caller (``TrainStep``) does that across processes only for a refusal by the
-compiler, which every process gets alike.
+step, then a tool that lowers it again for its text, when the first
+program's batches and losses are resident too); both must get the same
+program, and a rule that keeps layer by layer has margins of tens of MB, not
+hundreds.  So :func:`decide` remembers what it answered under the question
+it hashes for the peers (the rungs, the bound, the mesh's shape) and answers
+a second trace of the same step in the same process from that memory,
+whatever ``bytes_in_use`` reads by then; every process of a job remembers
+alike, so a second trace asks no peer.  :func:`fall_back` pins the plain
+policy for the rest of the process once a richer program was refused, over
+any remembered answer; the caller (``TrainStep``) does that across processes
+only for a refusal by the compiler, which every process gets alike.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import logging
 import math
@@ -79,10 +91,23 @@ QKV = "attn_qkv"
 #: The MLP's two products ``h @ w_gate`` and ``h @ w_up`` in the compute dtype
 #: (with experts: the two grouped matmuls' outputs).
 GATE_UP = "mlp_gate_up"
-#: The order in which names are kept.  Both families cost the same FLOPs per
-#: byte kept (``d_model`` a byte); q, k and v go first because they spare
-#: RoPE's passes besides and are a fifth of the bytes.
+#: The order of the scanned stack's two rungs.  Both families cost the same
+#: FLOPs per byte kept (``d_model`` a byte); q, k and v go first because they
+#: spare RoPE's passes besides and are a fifth of the bytes.
 LADDER = (QKV, GATE_UP)
+#: A Mamba-2 layer's ``in_proj`` output, ``[z | xBC | dt]`` as the
+#: convolution and the gate read it (``models/mamba2.py``).
+SSM_IN = "ssm_in_proj"
+#: The projections a short convolution reads: q, k and v of kinds ``K`` and
+#: ``G`` before their taps, ``[B | C | u]`` of kind ``C``.
+CONV_IN = "conv_in_proj"
+#: The inverse of a gated-delta-net chunk's unit-triangular system
+#: (``ops/gdn.py``): ``T``, and on the kernels' path the float32 ``X`` it is
+#: a scaled copy of, which the backward reads too.
+INVERSE = "scan_inverse"
+#: A latent-attention layer's two normed latents and its rotary key
+#: (``models/mla.py``).
+LATENTS = "mla_latents"
 #: The stream maps of a sub-layer under hyper-connections
 #: (``models/streams.py``): the read's and the write's weights and the
 #: normalised stream map, float32, n^2 + 2n numbers a position.  No rung of
@@ -113,45 +138,122 @@ REMAT_FALLBACKS = metrics.Counter(
     "policy (ops/remat.py).")
 
 
+#: FLOPs a v5e's matrix unit does while its HBM moves one byte (197 TFLOP/s
+#: over 819 GB/s): what makes a memory-bound pass's bytes comparable with a
+#: product's FLOPs where rungs are ordered.  It orders; it sizes nothing.
+FLOPS_A_BYTE = 240
+
+
+def spared(flops: float = 0.0, moved: float = 0.0) -> float:
+    """The forward work that keeping an array spares, in FLOPs: the
+    products' own, and for a pass bound by memory the bytes it moves at
+    :data:`FLOPS_A_BYTE`."""
+    return flops + FLOPS_A_BYTE * moved
+
+
+@dataclasses.dataclass(frozen=True)
+class Rung:
+    """What some layers name under one name: a chip's ``nbytes`` of it a
+    layer, the forward work keeping one layer's spares (:func:`spared`),
+    how many ``layers`` name it and whose they are (``group``: a kind's
+    letter in ``models/hybrid.py``; a scanned stack is one layer of no
+    group, its bytes all its layers')."""
+    name: str
+    nbytes: int
+    spares: float = 0.0
+    layers: int = 1
+    group: str = ""
+
+    @property
+    def a_byte(self) -> float:
+        return self.spares / max(self.nbytes, 1)
+
+
 @dataclasses.dataclass(frozen=True)
 class Decision:
-    """One answer of the rule.  ``kept``: the rungs of :data:`LADDER` kept
-    (the splash residuals and :data:`ROUTING` are always kept and not
-    listed); ``kept_bytes``: their bytes a chip, all layers; ``room_bytes``:
-    what the rule saw free for them, after the program's own temporaries,
-    the routing and the reserve (``None``: the device reports no memory);
-    ``processes``: how many processes took it together (1: this process's
-    own program); ``routing_bytes``: what the layers' routing takes, a chip,
-    all layers (0: no layer routes)."""
-    kept: Tuple[str, ...] = ()
+    """One answer of the rule.  ``kept``: (group, name, the layers of the
+    group that keep it, the layers that name it) for every rung of which a
+    layer is kept, in the order they were taken (the splash residuals and
+    :data:`ROUTING` are always kept and not listed); ``kept_bytes``: their
+    bytes a chip; ``room_bytes``: what the rule saw free for them, after the
+    program's own temporaries, the routing and the reserve (``None``: the
+    device reports no memory); ``processes``: how many processes took it
+    together (1: this process's own program); ``routing_bytes``: what the
+    layers' routing takes, a chip, all layers (0: no layer routes)."""
+    kept: Tuple[Tuple[str, str, int, int], ...] = ()
     kept_bytes: int = 0
     room_bytes: Optional[int] = None
     processes: int = 1
     routing_bytes: int = 0
 
+    @property
+    def names(self) -> Tuple[str, ...]:
+        """The names of which any layer keeps some, each once."""
+        return tuple(dict.fromkeys(name for _, name, _, _ in self.kept))
+
+    def layers(self) -> List[Tuple[str, int, int]]:
+        """(name, layers that keep it, layers that name it), a name once:
+        its groups' counts summed over the groups the rule reached."""
+        counts: Dict[str, List[int]] = {}
+        for _, name, kept, of in self.kept:
+            row = counts.setdefault(name, [0, 0])
+            row[0] += kept
+            row[1] += of
+        return [(name, *row) for name, row in counts.items()]
+
     def attributes(self) -> Dict[str, object]:
         """What :func:`decide` notes of it (``util/first_call.py``)."""
-        return {"remat_kept": list(self.kept),
+        return {"remat_kept": [list(row) for row in self.layers()],
                 "remat_kept_bytes": self.kept_bytes,
                 "remat_room_bytes": self.room_bytes,
                 "remat_routing_bytes": self.routing_bytes}
 
+    def policy(self, *layers: Tuple[str, int]):
+        """The checkpoint policy of one layer: ``save_only_these_names(splash
+        residuals, the routing, *what this layer keeps)``.  ``layers``: the
+        (group, index within the group) it is, more than one where a layer
+        bears two groups' names (a kind's branch under the stream maps); a
+        rung kept for k layers is kept by the group's first k.  None given:
+        a scanned stack, every layer."""
+        layers = layers or (("", 0),)
+        return _policy(tuple(dict.fromkeys(
+            name for group, name, kept, _ in self.kept
+            if any(group == g and index < kept for g, index in layers))))
 
-def choose(limit: int, in_use: int, candidates: Sequence[Tuple[str, int]],
-           temporaries: int) -> Decision:
+
+@functools.lru_cache(maxsize=None)
+def _policy(names: Tuple[str, ...]):
+    """One policy object a set of names: layers that keep alike trace
+    alike, and jax shares what it traced of them."""
+    return jax.checkpoint_policies.save_only_these_names(
+        SPLASH_RESIDUALS, ROUTING, *names)
+
+
+def _rungs(candidates: Sequence) -> List[Rung]:
+    return [c if isinstance(c, Rung) else Rung(*c) for c in candidates]
+
+
+def choose(limit: int, in_use: int, candidates: Sequence, temporaries: int
+           ) -> Decision:
     """The rule, pure.  ``limit``, ``in_use``: the fullest chip's bytes;
-    ``candidates``: (name, bytes a chip over all layers) in ladder order;
-    ``temporaries``: the bound on what the program needs without them.  A
-    rung is kept only if it, every rung before it, the temporaries and the
-    reserve stay inside the limit; the first that does not fit ends the
-    climb."""
+    ``candidates``: :class:`Rung`s, or (name, bytes a chip over all layers)
+    for a stack that keeps all layers or none; ``temporaries``: the bound on
+    what the program needs without them.  The rungs are climbed in the order
+    of spared work a byte (equal ones in the order given, which is the
+    scanned stack's :data:`LADDER`); each is kept for as many of its layers
+    as fit beside every layer kept before, the temporaries and the reserve;
+    the first that is not kept whole ends the climb."""
     room = limit - in_use - temporaries - int(RESERVE_SHARE * limit)
+    rungs = sorted(_rungs(candidates), key=lambda r: -r.a_byte)
     kept, kept_bytes = [], 0
-    for name, nbytes in candidates:
-        if kept_bytes + nbytes > room:
+    for rung in rungs:
+        layers = min(rung.layers, max(room - kept_bytes, 0)
+                     // max(rung.nbytes, 1))
+        if layers:
+            kept.append((rung.group, rung.name, layers, rung.layers))
+            kept_bytes += layers * rung.nbytes
+        if layers < rung.layers:
             break
-        kept.append(name)
-        kept_bytes += nbytes
     return Decision(tuple(kept), kept_bytes, room)
 
 
@@ -181,6 +283,14 @@ def axis_shards(mesh, *names: str) -> int:
     if mesh.empty:
         return 1
     return math.prod(mesh.shape.get(a, 1) for a in names)
+
+
+def rows_under_mesh(tokens: int, seq_len: int):
+    """(the ambient mesh, the rows of ``seq_len`` positions a step holds
+    under it where a chip has ``tokens``): what an op's ``path`` asks of a
+    kind that sizes its layer for the rule."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return mesh, tokens * axis_shards(mesh, "data", "fsdp", "seq") // seq_len
 
 
 def own_temporaries(*, block_bytes: int, other_bytes: int, layer_bytes: int,
@@ -227,6 +337,8 @@ _lock = threading.Lock()
 _plain_only = False  # guarded_by: _lock
 #: how often each question was put to the peers (:func:`every_process`)
 _asked: Dict[str, int] = {}  # guarded_by: _lock
+#: what :func:`decide` answered each question from a device's report
+_decided: Dict[str, "Decision"] = {}  # guarded_by: _lock
 
 
 def every_process(report: Optional[Tuple[int, int]], question: str
@@ -270,7 +382,8 @@ def tracing_processes(mesh) -> int:
     return world
 
 
-def _job_memory(mesh, question_parts) -> Tuple[Optional[Tuple[int, int]], int]:
+def _job_memory(mesh, question: str
+                ) -> Tuple[Optional[Tuple[int, int]], int]:
     """(the fullest chip of every process that will run the step being
     traced under ``mesh``, how many processes that is)."""
     memory, processes = device_memory(), tracing_processes(mesh)
@@ -278,51 +391,65 @@ def _job_memory(mesh, question_parts) -> Tuple[Optional[Tuple[int, int]], int]:
         return memory, 1
     if mesh.empty:  # whose program this is, nothing here can tell
         return None, processes
-    question = hashlib.sha1(repr(question_parts).encode()).hexdigest()[:16]
     return fullest(every_process(memory, question)), processes
 
 
-def decide(candidates: Sequence[Tuple[str, int]],
-           temporaries: int) -> Decision:
-    """:func:`choose` for a layer whose named intermediates would take
-    ``candidates`` (name, bytes a chip over all layers: the ladder's rungs
-    in its order and, where a layer routes, :data:`ROUTING`, which is kept
-    whatever the answer and counts among what the step needs), in a step
-    that needs ``temporaries`` without them, from what the devices report
-    (those of every process under a mesh that spans several).  Notes the
+def decide(candidates: Sequence, temporaries: int) -> Decision:
+    """:func:`choose` for layers whose named intermediates are
+    ``candidates`` (:func:`choose`'s, and, where a layer routes, (``ROUTING``,
+    its bytes a chip over all layers), which is kept whatever the answer and
+    counts among what the step needs), in a step that needs ``temporaries``
+    without them, from what the devices report (those of every process
+    under a mesh that spans several).  A question answered once from a
+    device's report is answered from memory after that (the module's
+    Stability paragraph); :func:`fall_back` overrides both.  Notes the
     decision for the first-call record."""
-    routing = dict(candidates).get(ROUTING, 0)
-    candidates = [c for c in candidates if c[0] != ROUTING]
+    rungs = _rungs(candidates)
+    routing = sum(r.nbytes * r.layers for r in rungs if r.name == ROUTING)
+    candidates = tuple(r for r in rungs if r.name != ROUTING)
     temporaries += routing
     mesh = jax.sharding.get_abstract_mesh()
+    question = hashlib.sha1(repr(
+        (candidates, temporaries, tuple(mesh.shape.items()))
+    ).encode()).hexdigest()[:16]
     with _lock:
-        plain_only = _plain_only
-    memory, processes = (None, 1) if plain_only else _job_memory(
-        mesh, (tuple(candidates), temporaries, tuple(mesh.shape.items())))
-    decision = dataclasses.replace(
-        Decision() if memory is None
-        else choose(*memory, candidates, temporaries),
-        processes=processes, routing_bytes=routing)
+        plain_only, decision = _plain_only, _decided.get(question)
+    memory = None
+    if plain_only:
+        decision = Decision(routing_bytes=routing)
+    elif decision is None:
+        memory, processes = _job_memory(mesh, question)
+        decision = dataclasses.replace(
+            Decision() if memory is None
+            else choose(*memory, candidates, temporaries),
+            processes=processes, routing_bytes=routing)
+        if memory is not None:
+            # two traces of one question at once: the first answer stands
+            # for both.  The lock is not held while the peers are asked
+            # (``every_process`` takes it, and a peer may take minutes).
+            with _lock:
+                decision = _decided.setdefault(  # analysis: ignore[atomicity] setdefault: the first answer wins; the lock cannot span the peers' exchange
+                    question, decision)
     first_call.note(**decision.attributes())
     logger.info("remat: keeping %s for the backward (%d bytes a chip; room "
                 "%s; device memory %s; %d process(es)) beside %d bytes of "
-                "routing", decision.kept or "nothing more",
-                decision.kept_bytes, decision.room_bytes, memory, processes,
-                routing)
+                "routing", decision.layers() or "nothing more",
+                decision.kept_bytes, decision.room_bytes,
+                memory or "as first read", decision.processes, routing)
     return decision
 
 
-def layer_policy(candidates: Sequence[Tuple[str, int]], temporaries: int):
-    """The checkpoint policy of such a layer: ``save_only_these_names(splash
-    residuals, the routing, *what decide keeps)``."""
-    return jax.checkpoint_policies.save_only_these_names(
-        SPLASH_RESIDUALS, ROUTING, *decide(candidates, temporaries).kept)
+def layer_policy(candidates: Sequence, temporaries: int):
+    """The one checkpoint policy of a scanned stack's layers:
+    :meth:`Decision.policy` of what :func:`decide` keeps."""
+    return decide(candidates, temporaries).policy()
 
 
-def fall_back(kept: Sequence[str], reason: str) -> None:
-    """A program that kept ``kept`` was refused for memory: from here
-    on this process gets the plain policy, so a second trace of the same
-    step builds what the first ended up running."""
+def fall_back(kept: Sequence, reason: str) -> None:
+    """A program that kept ``kept`` (the record's ``remat_kept``) was refused
+    for memory: from here on this process gets the plain policy, whatever it
+    remembers, so a second trace of the same step builds what the first
+    ended up running."""
     global _plain_only
     with _lock:
         _plain_only = True
@@ -330,4 +457,6 @@ def fall_back(kept: Sequence[str], reason: str) -> None:
     logger.warning(
         "remat: the step that kept %s for the backward was refused for "
         "memory (%s); rebuilding it under the plain policy, which this "
-        "process keeps from here on", ", ".join(kept), reason)
+        "process keeps from here on",
+        ", ".join("%s (%d of %d layers)" % tuple(row) for row in kept),
+        reason)

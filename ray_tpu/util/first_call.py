@@ -33,7 +33,8 @@ from typing import Any, Dict, Iterator, List
 _NOTED = (
     ("parallel/train_state.py", "the step was refused for memory and rebuilt "
      "under the plain policy", "remat_fallback"),
-    ("ops/remat.py", "the ladder's rungs kept, their bytes, the room the rule "
+    ("ops/remat.py", "the rungs kept as [name, layers that keep it, layers "
+     "that name it], their bytes, the room the rule "
      "saw (None: the device reports no memory), the expert layers' routing "
      "(always kept; 0: no layer routes); absent where no layer asked the "
      "rule (GPT-2)", "remat_kept remat_kept_bytes remat_room_bytes "

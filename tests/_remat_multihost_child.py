@@ -70,9 +70,10 @@ def main() -> None:
     targets = dist.local_batch_to_global(mesh, local[:, 1:], axis="fsdp")
     params, opt_state, loss = step(params, opt_state, tokens, targets)
     (row,) = device_telemetry.first_calls("train_step")
-    print(f"RESULT {rank} {','.join(row['remat_kept']) or '-'} "
+    kept = [name for name, _, _ in row["remat_kept"]]
+    print(f"RESULT {rank} {','.join(kept) or '-'} "
           f"{remat.tracing_processes(mesh.abstract_mesh)} "
-          f"{','.join(alone.kept) or '-'} {float(loss):.6f}", flush=True)
+          f"{','.join(alone.names) or '-'} {float(loss):.6f}", flush=True)
     dist.shutdown()
 
 

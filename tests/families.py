@@ -396,6 +396,15 @@ def mesh(**axes):
     return make_mesh(MeshSpec(**axes), jax.devices()[:MeshSpec(**axes).size])
 
 
+def named(rungs) -> Dict[str, int]:
+    """What ``hybrid._layer_sizes``' rungs name: a chip's bytes of each name
+    over all the layers that name it."""
+    sizes: Dict[str, int] = {}
+    for rung in rungs:
+        sizes[rung.name] = sizes.get(rung.name, 0) + rung.nbytes * rung.layers
+    return sizes
+
+
 def out_and_grads(fn, args, dy):
     """(``fn(*args)``, its pull-back of ``dy``), jitted as one program."""
     def run(*args):

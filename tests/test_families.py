@@ -28,6 +28,7 @@ import pytest
 
 from benchmarks.lib import correct, spec
 from ray_tpu.models import hybrid, moe
+from ray_tpu.ops import remat
 from ray_tpu.parallel import MeshSpec, make_mesh
 from ray_tpu.util import first_call, tracing
 from tests import families
@@ -226,44 +227,55 @@ def _cell_config(name):
                    traffic["seq_len"])
 
 
-#: ``hybrid._layer_sizes`` on the parent of PR 46, (q/k/v bytes, gate/up
-#: bytes, the bound on the step's temporaries): what ``ops/remat.py`` decides
-#: from.  The kinds' ``layer_bytes`` carry the parent's terms over as they
-#: were, the two overstated ones with them (ROADMAP C15).  Behind them since
-#: PR 48 what the expert layers' routing takes (``moe.routing_bytes`` a
-#: layer), which the rule keeps whatever it decides: four layers each, of
-#: 256 tokens with 16 experts and 2 a token, of 16,384 with 128 and 6, of
-#: 8,192 with 320 and 8.  The two tiny rows are the rehearsal files at the
-#: widths the pins were recorded on (``HybridConfig``'s defaults, which the
-#: program's own presets of that day took).
+#: ``hybrid._layer_sizes``, pinned: (what the layers name, a chip's bytes
+#: of each name over the layers that name it; the bound on the step's
+#: temporaries): what ``ops/remat.py`` decides from.  Re-pinned by PR 63,
+#: which made the bound the fullest moment of the unrolled step under the
+#: path each kind's ops take (on this CPU XLA's forms; the two cells' rows
+#: likewise: the scans' kernels by their shapes, the convolution's pass
+#: only on the chip's backend) and gave the
+#: kinds their own rungs: ``in_proj``'s output of the four Mamba-2 layers,
+#: the three projections of the three KDA layers.  Behind them since PR 48
+#: what the expert layers' routing takes (``moe.routing_bytes`` a layer),
+#: which the rule keeps whatever it decides: four layers each, of 256 tokens
+#: with 16 experts and 2 a token, of 16,384 with 128 and 6, of 8,192 with
+#: 320 and 8.  The two tiny rows are the rehearsal files at the widths the
+#: pins were recorded on (``HybridConfig``'s defaults, which the program's
+#: own presets of that day took).
 LAYER_SIZES = {
     "tiny-nemotron-h": (lambda: (families.preset(
-                 "nemotron_h", vocab_size=1024, d_model=128, d_ff=64,
-                 shared_width=128), (2, 128)),
-             (131072, 262144, 7640128), 16 * (256 * (16 + 10) + 16)),
+        "nemotron_h", vocab_size=1024, d_model=128, d_ff=64,
+        shared_width=128), (2, 128)),
+        {"ssm_in_proj": 671744, "attn_qkv": 131072, "mlp_gate_up": 262144,
+         "moe_routing": 16 * (256 * (16 + 10) + 16)}, 383104544),
     "tiny-solar-open2": (lambda: (families.preset(
-                       "solar_open2", vocab_size=1024, d_model=128),
-                            (2, 128)),
-                   (65536, 196608, 5602244), 16 * (256 * (16 + 10) + 16)),
-    "nemotron-ep16-s8192": (lambda: _cell_config("nemotron-ep16-s8192"),
-                            (150994944, 486539264, 9288687104),
-                            16 * (16384 * (128 + 30) + 128)),
-    "solar-open2-ep40-tp8": (lambda: _cell_config("solar-open2-ep40-tp8"),
-                             (20971520, 167772160, 7130061200),
-                             16 * (8192 * (320 + 40) + 320)),
+        "solar_open2", vocab_size=1024, d_model=128), (2, 128)),
+        {"attn_qkv": 65536, "mlp_gate_up": 196608, "conv_in_proj": 147456,
+         "moe_routing": 16 * (256 * (16 + 10) + 16)}, 339937936),
+    "nemotron-ep16-s8192": (
+        lambda: _cell_config("nemotron-ep16-s8192"),
+        {"ssm_in_proj": 4 * 16384 * 10304 * 2, "attn_qkv": 150994944,
+         "mlp_gate_up": 486539264,
+         "moe_routing": 16 * (16384 * (128 + 30) + 128)}, 4488389376),
+    "solar-open2-ep40-tp8": (
+        lambda: _cell_config("solar-open2-ep40-tp8"),
+        {"attn_qkv": 20971520, "mlp_gate_up": 167772160,
+         "conv_in_proj": 3 * 8192 * 3 * 8 * 128 * 2,
+         "moe_routing": 16 * (8192 * (320 + 40) + 320)}, 4058932832),
 }
 
 
 @pytest.mark.parametrize("name", sorted(LAYER_SIZES))
 def test_the_remat_rule_is_given_the_parents_sizes(name):
-    build, (qkv, gate_up, temporaries), routing = LAYER_SIZES[name]
+    build, named, temporaries = LAYER_SIZES[name]
     config, (rows, seq_len) = build()
     shapes = jax.eval_shape(lambda: hybrid.init_params(config,
                                                        jax.random.key(0)))
-    assert hybrid._layer_sizes(
-        shapes, (rows, seq_len, config.d_model), config) == (
-        [("attn_qkv", qkv), ("mlp_gate_up", gate_up),
-         ("moe_routing", routing)], temporaries)
+    rungs, bound = hybrid._layer_sizes(
+        shapes, (rows, seq_len, config.d_model), config)
+    assert (families.named(rungs), bound) == (named, temporaries)
+    assert rungs[-1].name == "moe_routing" and all(
+        rung.layers == config.sublayers.count(rung.group) for rung in rungs)
 
 
 # ----------------------------------- (7) a kind is one entry of ``KINDS``
@@ -343,6 +355,7 @@ def test_a_kinds_module_answers_the_whole_interface(kind):
     assert module.mixer_flops(config, families.SEQ_LEN) >= 0
     working, kept, named = module.layer_bytes(config, 256, 128, 1, 2)
     assert working > 0 and kept >= 0 and all(
-        size >= 0 for size in named.values())
+        size > 0 and (spares > 0 or name == remat.ROUTING)
+        for name, (size, spares) in named.items())
     facts = module.first_call_facts(config, 2, families.SEQ_LEN)
     assert facts and set(facts) <= set(first_call.KEYS)

@@ -330,12 +330,20 @@ def test_the_remat_rule_is_given_the_modules_sizes():
     shared = tokens * 2 * 48 * 2
     dense = tokens * 2 * 128 * 2
     routing = moe.routing_bytes(tokens, 16, 2)
-    assert dict(without) == {remat.QKV: 3 * qkv,
-                             remat.GATE_UP: 2 * shared + dense,
-                             remat.ROUTING: 2 * routing}
-    assert dict(with_module) == {remat.QKV: 4 * qkv,
-                                 remat.GATE_UP: 3 * shared + dense,
-                                 remat.ROUTING: 3 * routing}
+    latents = tokens * (48 + 32 + 8) * 2  # the two latents, the rotary key
+    assert families.named(without) == {remat.GATE_UP: 2 * shared + dense,
+                                     remat.LATENTS: 3 * latents,
+                                     remat.QKV: 3 * qkv,
+                                     remat.ROUTING: 2 * routing}
+    assert families.named(with_module) == {remat.GATE_UP: 3 * shared + dense,
+                                         remat.LATENTS: 4 * latents,
+                                         remat.QKV: 4 * qkv,
+                                         remat.ROUTING: 3 * routing}
+    # the module's layers are rows of their kinds' groups
+    assert {(r.group, r.name): r.layers for r in with_module} == {
+        ("E", remat.GATE_UP): 3, ("E", remat.ROUTING): 3,
+        ("L", remat.LATENTS): 4, ("L", remat.QKV): 4,
+        ("D", remat.GATE_UP): 1}
 
 
 def test_the_registries_hold_the_new_scopes_and_counters():

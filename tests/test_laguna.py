@@ -362,7 +362,7 @@ def test_the_remat_rule_is_given_the_window_kinds_sizes():
     tokens = 256
     # heads of 16: q's and twice the two key heads', bf16; a dense layer of
     # 128 beside four shared experts of 48
-    assert dict(candidates) == {
+    assert families.named(candidates) == {
         remat.QKV: tokens * 16 * 2 * (2 * (4 + 4) + 3 * (6 + 4)),
         remat.GATE_UP: tokens * 2 * 2 * (4 * 48 + 128),
         remat.ROUTING: 4 * moe.routing_bytes(tokens, 16, 2)}

@@ -287,7 +287,8 @@ def _identity_kind():
         num_params=lambda config: config.d_model + 1,
         mixer_flops=lambda config, seq_len: 0.0,
         layer_bytes=lambda config, tokens, seq_len, tensor, itemsize: (
-            2 * tokens * config.d_model * itemsize, 0, {}),
+            2 * tokens * config.d_model * itemsize, 0,
+            {"id_scaled": (tokens * config.d_model * itemsize, 1.0)}),
         first_call_facts=lambda config, rows, seq_len: {"id_layers":
                                                         config.count("I")},
         layer=layer)

@@ -155,28 +155,39 @@ def test_num_params_and_flops_are_the_adapters_and_the_cost_files(name,
 
 
 def test_the_remat_rule_is_offered_a_pattern_without_experts():
-    """``_layer_sizes`` of a stack with no ``E``: the ladder's two rungs
-    from ``*`` (q, k, v) and the four ``D`` layers (gate and up), no
-    routing, and a bound that holds the widest layer's working set, the
-    ``G`` layer's among them."""
+    """``_layer_sizes`` of a stack with no ``E``: q, k and v from ``*``,
+    gate and up from the four ``D`` layers, the three ``G`` layers' inverse
+    and the projections their convolutions read, no routing, and a bound
+    that holds the widest layer's working set, the ``G`` layer's among
+    them (XLA's form here: heads of 12 under 24 are not the kernels')."""
     config = families.preset(FAMILY)
     shapes = jax.eval_shape(lambda: hybrid.init_params(config,
                                                        jax.random.key(0)))
     tokens, item = 2 * 128, 2
     candidates, bound = hybrid._layer_sizes(shapes, (2, 128, 128), config)
-    assert dict(candidates) == {
+    assert families.named(candidates) == {
         remat.QKV: tokens * 3 * 128 * item,
-        remat.GATE_UP: 4 * 2 * tokens * 256 * item}
+        remat.GATE_UP: 4 * 2 * tokens * 256 * item,
+        remat.INVERSE: 3 * tokens * 4 * 32 * item,
+        remat.CONV_IN: 3 * tokens * 4 * (2 * 12 + 24) * item}
+    assert {(rung.group, rung.layers) for rung in candidates} \
+        == {("*", 1), ("D", 4), ("G", 3)}
     working, kept, named = gdn.layer_bytes(config, tokens, 128, 1, item)
-    assert (kept, named) == (0, {})
+    assert kept == 0 and set(named) == {remat.INVERSE, remat.CONV_IN}
     assert working == tokens * 4 * (10 * 12 * 2 + 12 * 24 * 2
                                     + 2 * 32 * (16 + 4))
     assert working > dense.layer_bytes(config, tokens, 128, 1, item)[0]
     params = sum(4 * a.size for a in jax.tree.leaves(shapes))
-    assert bound > working + params * item // 4
-    # on a device that reports room the rule keeps both rungs
-    decision = remat.choose(1 << 30, 0, candidates, bound)
-    assert decision.kept == remat.LADDER
+    # after the last layer every gradient stands; a G layer's backward
+    # holds its working set beside the other layers'
+    assert bound > max(params, working) + 8 * hybrid.PACKING
+    # on a device that reports room the rule keeps every rung, the inverse
+    # first (it spares most a byte), every layer of each
+    decision = remat.choose(4 << 30, 0, candidates, bound)
+    assert decision.names[0] == remat.INVERSE
+    assert set(decision.names) == {remat.INVERSE, remat.QKV, remat.GATE_UP,
+                                   remat.CONV_IN}
+    assert all(kept == of for _, _, kept, of in decision.kept)
 
 
 def test_the_delta_rule_kind_counts_itself_and_borrows_its_convolution():

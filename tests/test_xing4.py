@@ -202,10 +202,10 @@ def test_one_stream_traces_none_of_it():
             counts = jax.eval_shape(lambda p, t: hybrid.loss_and_counters(
                 p, t, t, config)[1], shapes, ids)
         seen[n] = ("hc" in shapes, "mhc" in text, set(counts), notes)
-        names = [name for name, _ in hybrid._layer_sizes(
-            shapes, (2, families.SEQ_LEN, config.d_model), config)[0]]
+        names = list(families.named(hybrid._layer_sizes(
+            shapes, (2, families.SEQ_LEN, config.d_model), config)[0]))
         assert names == ([remat.MAPS] if n > 1 else []) + [
-            remat.QKV, remat.GATE_UP, remat.ROUTING]
+            remat.GATE_UP, remat.LATENTS, remat.QKV, remat.ROUTING]
     assert seen[1][:2] == (False, False)
     assert "mhc_sinkhorn_err" not in seen[1][2]
     assert not {"streams", "hc_sinkhorn_iters", "mhc_sublayers"} \
@@ -395,21 +395,23 @@ def test_the_remat_rule_is_given_the_streams_sizes():
             c, jax.random.key(0)))
         sizes[name] = hybrid._layer_sizes(
             shapes, (2, families.SEQ_LEN, c.d_model), c)
-    with_maps, without = dict(sizes["streams"][0]), dict(sizes["plain"][0])
+    with_maps, without = (families.named(sizes[name][0])
+                          for name in ("streams", "plain"))
     with_maps_bytes = with_maps.pop(remat.MAPS)
     assert with_maps_bytes == 8 * tokens * 24 * 4
     assert with_maps == without
-    hc = 8 * (4 * 64 * 24 + 3 + 24)
-    # the stack's float32 gradients and its casts, the wider inputs and, at
-    # this size, the widest working set: the streams' own
+    # around the head, this size's fullest moment: every sub-layer's kept
+    # input three streams wider, and the maps' float32 gradients
     kept = 8 * tokens * 64 * 2
-    assert sizes["streams"][1] - sizes["plain"][1] >= \
-        hc * (4 + 2) + 3 * kept
-    decision = remat.choose(16 << 30, 0, sizes["streams"][0][:-1],
-                            sizes["streams"][1])
-    assert decision.kept == (remat.MAPS,) + remat.LADDER
-    # room for the maps and not for q, k and v: the maps alone are kept
-    maps, qkv = with_maps_bytes, with_maps[remat.QKV]
-    tight = remat.choose(10 * (maps + qkv // 2) // 9, 0,
-                         [(remat.MAPS, maps), (remat.QKV, qkv)], 0)
-    assert tight.kept == (remat.MAPS,)
+    assert sizes["streams"][1] - sizes["plain"][1] >= 3 * kept
+    rungs = sizes["streams"][0][:-1]
+    decision = remat.choose(16 << 30, 0, rungs, sizes["streams"][1])
+    assert decision.names[0] == remat.MAPS and decision.kept[0] == (
+        "hc", remat.MAPS, 8, 8)
+    assert set(decision.names) == {remat.MAPS, remat.LATENTS, remat.QKV,
+                                   remat.GATE_UP}
+    # room for five sub-layers' maps and nothing behind them: the maps
+    # spare most a byte, and the climb ends at the rung not kept whole
+    a_layer = with_maps_bytes // 8
+    tight = remat.choose(10 * (5 * a_layer + a_layer // 2) // 9, 0, rungs, 0)
+    assert tight.kept == (("hc", remat.MAPS, 5, 8),)
