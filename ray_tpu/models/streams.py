@@ -57,6 +57,13 @@ rode in the branch's norm and the write in the next sub-layer's sums, and
 the trace read a twelfth of the mix's time; PERF.md, PR 62), and a kernel
 that takes a scope's place meets the same seams.
 
+**Two paths.**  Where ``ops/streams_kernel.py:path`` says so (on the chip,
+four streams, no mesh or one device, ``C`` whole lane tiles, the tokens whole
+blocks) a sub-layer's maps with its read, its write and their backwards are
+that module's four Mosaic passes over whole rows of the streams, between the
+same seams; the expressions below are the other path (the CPU, a mesh, the
+tests' oracle) and say what the kernels compute.
+
 Scopes: ``mhc`` holds all of it (and the counter's own sums), inside it
 ``mhc_maps`` (norm, product, sigmoids, turns) and ``mhc_mix`` (the read and
 the write); the branch runs between the two under its kind's own scopes.
@@ -78,7 +85,7 @@ import numpy as np
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
-from ray_tpu.ops import remat
+from ray_tpu.ops import remat, streams_kernel
 from ray_tpu.util.tracing import step_counter
 
 #: folded into ``hybrid.init_params``' key for the ``hc`` stack
@@ -135,9 +142,18 @@ def layer_bytes(config, tokens: int, itemsize: int):
     them between, in the compute dtype; what is kept beside the layer's
     input: nothing; the rung it names: the maps, which spare the norm's pass
     over every stream, the product that makes their logits and the Sinkhorn
-    turns' passes over the n x n of them)."""
+    turns' passes over the n x n of them).  Where the kernels run
+    (``ops/streams_kernel.py``) the working set is the write's backward's
+    (the cotangent coming in, ``y``'s and the share going out), and the maps
+    kept spare nothing: the second forward's one pass over the streams makes
+    them on the way to ``u``, which it reads the streams for anyway."""
     n, D = config.streams, config.d_model
     wide = tokens * n * D
+    if streams_kernel.path(tokens, D, n, itemsize,
+                           jax.sharding.get_abstract_mesh()) == "kernel":
+        return ((2 * wide + tokens * D) * itemsize, 0,
+                {remat.MAPS: (tokens * maps_width(config) * 4,
+                              remat.spared())})
     return (wide * 3 * itemsize, 0,
             {remat.MAPS: (tokens * maps_width(config) * 4, remat.spared(
                 flops=2.0 * wide * maps_width(config),
@@ -247,18 +263,26 @@ def layer(config, branch):
     it leaves: the branch's and ``mhc_sinkhorn_err``).  ``branch``: the
     kind's, (u, its row) -> (f(u), its counters or None)."""
     def hyper(X, blk, hc):
+        n = len(X)
+        kernel = streams_kernel.engaged(X)
         with jax.named_scope("mhc"):
             # one barrier for the three readers, so that the sum of their
             # cotangents is traced where they are and not out here
             X = lax.optimization_barrier(X)
-            H, res = maps(X, hc, config)
+            if kernel:  # the maps with the read; the write reads its X
+                H, u, X = streams_kernel.maps_read(X, hc, config)
+                H = checkpoint_name(H, remat.MAPS)
+                res = jnp.moveaxis(H[..., 2 * n:].reshape(-1, n, n), 0, -1)
+            else:
+                H, res = maps(X, hc, config)
             err = sinkhorn_err(res)
             H = lax.optimization_barrier(H)
-            u = lax.optimization_barrier(read(X, H))
+            u = lax.optimization_barrier(u if kernel else read(X, H))
         y, counted = branch(u, blk)
         with jax.named_scope("mhc"):
             X = lax.optimization_barrier(
-                write(X, lax.optimization_barrier(y), H))
+                (streams_kernel.write if kernel else write)(
+                    X, lax.optimization_barrier(y), H))
         return X, {**(counted or {}), step_counter("mhc_sinkhorn_err"): err}
 
     return hyper

@@ -81,6 +81,11 @@ _NOTED = (
      "turns that normalise a stream map, the sub-layers under maps (whether "
      "their maps are kept for the backward: `mhc_maps` in remat_kept)",
      "streams hc_sinkhorn_iters mhc_sublayers"),
+    ("ops/streams_kernel.py", "where a sub-layer's hyper-connections run: "
+     "the four Mosaic passes over whole rows of the streams or XLA's "
+     "expressions, the sub-layers traced (forward, a checkpoint's second "
+     "forward and the backward are one trace each)",
+     "streams_kernel mhc_calls"),
     ("models/attn.py", "(*) the query heads held of the model's, the output "
      "gate; where only a head's first lanes rotate, under YaRN, with QK-norm",
      "heads_held heads_total attn_gate rope_rotary_lanes rope_yarn_factor "

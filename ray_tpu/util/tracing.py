@@ -241,11 +241,15 @@ SCOPE_REGISTRY: Dict[str, str] = {
            "its kind's own scopes",
     "mhc_maps": "hyper-connections, nested inside mhc: the norm over every "
                 "stream's lanes, the product with phi, the two sigmoids and "
-                "the Sinkhorn turns that normalise the stream map",
+                "the Sinkhorn turns that normalise the stream map; where "
+                "the kernels run (ops/streams_kernel.py) what is left of "
+                "them outside: phi's cast and transpose, alpha's expansion, "
+                "the last sums of the parameters' cotangents",
     "mhc_mix": "hyper-connections, nested inside mhc: the read that mixes "
                "the streams into the branch's input and the write that "
                "mixes them among themselves and adds the branch's output "
-               "to each: the model's residual add",
+               "to each: the model's residual add; where the kernels run "
+               "their four passes, the maps made inside the read's",
     "optimizer": "optimizer.update + apply_updates (gradient clipping is "
                  "inside the optax chain, so inside the scope)",
 }

@@ -1,6 +1,6 @@
 """What the kernel sweeps share (``splash_block_sweep.py``,
 ``ssd_kernel_sweep.py``, ``kda_kernel_sweep.py``, ``gmm_tile_sweep.py``,
-``rope_pass_sweep.py``): the
+``rope_pass_sweep.py``, ``mhc_pass_sweep.py``): the
 arguments, the device (a chip, a described v5e to compile for, or the CPU's
 rehearsal), the three passes a scan is timed in, the comparison with a
 reference and the one timing loop.  What each sweeps stays in its file."""

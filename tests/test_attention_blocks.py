@@ -545,3 +545,53 @@ def test_the_conv_kernels_compile_for_the_v5e(monkeypatch, one_chip):
                 for line in calls), name
     finally:
         jax.config.update("jax_enable_compilation_cache", cache)
+
+
+@pytest.mark.parametrize("rows", [256, 128])
+def test_the_streams_kernels_compile_for_the_v5e(monkeypatch, one_chip, rows):
+    """A sub-layer's hyper-connections (``ops/streams_kernel.py``) through
+    ``streams.layer`` at ``xing4-ep8-s4096``'s shape, four streams of (2,
+    4096, 3584) in bf16, forward and backward under a ``jax.checkpoint``,
+    compiled for a described v5e at each row block of the rule: five Mosaic
+    calls (the maps with the read forward and in the checkpoint's second
+    forward, the write, the two backwards), none refused for the VMEM its
+    call states, every one under ``mhc_mix``.  In this file: one worker loads
+    the TPU's compiler."""
+    import types
+
+    from ray_tpu.models import streams
+    from ray_tpu.ops import streams_kernel
+    from ray_tpu.parallel.train_state import classify_op_name
+
+    config = types.SimpleNamespace(
+        streams=4, d_model=3584, hc_sinkhorn_iters=20, hc_eps=1e-6,
+        hc_clamp=(-10.0, 10.0), rms_eps=1e-6)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(streams_kernel, "BLOCKS", (rows,))
+    layer = jax.checkpoint(streams.layer(
+        config, lambda u, blk: (jnp.tanh(u) * blk, None)))
+
+    def both(X, blk, hc):
+        (new, _), pull = jax.vjp(lambda *a: layer(*a), X, blk, hc)
+        return pull((new, {k: jnp.ones_like(v) for k, v in
+                           jax.eval_shape(layer, X, blk, hc)[1].items()}))
+
+    def abstract(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    X = tuple(abstract((2, 4096, 3584), jnp.bfloat16) for _ in range(4))
+    hc = {"phi": abstract((4 * 3584, 24), jnp.float32),
+          "alpha": abstract((3,), jnp.float32),
+          "base": abstract((24,), jnp.float32)}
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = jax.jit(both).lower(
+            X, abstract((3584,), jnp.bfloat16), hc).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 5, len(calls)
+    assert all(classify_op_name(
+        line.split('op_name="')[1].split('"')[0])[1] == "mhc_mix"
+        for line in calls)
