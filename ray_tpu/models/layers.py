@@ -321,7 +321,9 @@ def attention(x, blk, config, axes):
     ``rope_rotary``, how many of a head's first lanes rotate (None: all);
     ``rope_yarn``, a :class:`Yarn` whose table and scale the rotary pass
     takes; ``norm_after``: the projections read ``x`` itself and
-    ``attn_norm`` norms ``wo``'s output, ``x + norm(wo(...))``."""
+    ``attn_norm`` norms ``wo``'s output, ``x + norm(wo(...))``;
+    ``sandwich_norm``: a second vector, ``attn_norm_2``, norms ``wo``'s
+    output besides the first's norm of its input, ``x + norm_2(wo(...))``."""
     dt = config.dtype
     after = getattr(config, "norm_after", False)
     B, S, D = x.shape
@@ -383,6 +385,8 @@ def attention(x, blk, config, axes):
         out = dense(attn, blk, "wo", axes, dt)
         if after:
             out = rmsnorm(out, blk["attn_norm"], config.rms_eps).astype(dt)
+        if getattr(config, "sandwich_norm", False):
+            out = rmsnorm(out, blk["attn_norm_2"], config.rms_eps).astype(dt)
         return x + out
 
 
@@ -408,6 +412,8 @@ def _feed_forward_out(x, blk, config, axes, **expert_layer):
     out = dense(act.astype(dt), blk, "w_down", axes, dt)
     if after:
         out = rmsnorm(out, blk["mlp_norm"], config.rms_eps).astype(dt)
+    if getattr(config, "sandwich_norm", False):
+        out = rmsnorm(out, blk["mlp_norm_2"], config.rms_eps).astype(dt)
     return out, None
 
 
@@ -420,8 +426,9 @@ def feed_forward(x, blk, config, axes, **expert_layer):
     ``mlp_norm`` and the SwiGLU's ``w_gate``, ``w_up``, ``w_down``, or what
     ``moe.moe_mlp`` reads, which is also handed ``expert_layer``.  Where the
     configuration has ``norm_after`` the dense MLP reads ``x`` itself and
-    ``mlp_norm`` norms its output, ``x + norm(down(...))`` (no expert layer
-    has it)."""
+    ``mlp_norm`` norms its output, ``x + norm(down(...))``; where it has
+    ``sandwich_norm``, ``mlp_norm_2`` norms the output besides (no expert
+    layer has either)."""
     with jax.named_scope("mlp"):
         out, said = _feed_forward_out(x, blk, config, axes, **expert_layer)
         return x + out, said

@@ -29,6 +29,27 @@ layers see the noised and the clean copy of a row, 2S positions under
 at the clean copy, and :func:`loss_fn` is the weighted cross-entropy of the
 noised copy's masked positions, the head run on those S positions only.
 
+A looped stack (Ouro-2.6B; ``models/looped.py``), three more published
+facts that default to the plain decoder: ``ut_steps``, how often a step runs
+the stack of layers, with one set of weights; ``sandwich_norm``, a second
+norm after each sub-layer (``attn_norm_2``, ``mlp_norm_2``); ``exit_beta``.
+Above one pass :func:`forward_hidden` runs the layers' ``lax.scan`` inside
+a ``lax.scan`` over the passes that closes over the one ``params["blocks"]``
+(one layer body to compile whatever ``ut_steps`` is: 13 s for the v5e at
+eight layers where the passes written out in a row took 15 and 0.4 GiB
+more), the shared final norm ends every pass and feeds the next, and the
+passes' normed states, (T, B, S, D), go to ``looped.loss_and_counters``:
+the shared head a pass at a time, an exit gate, and the expectation of the
+passes' cross-entropies over the exit pass.  What the loops stack: every
+pass stacks its own layers' inputs and kernel outputs, so a step holds ``T x
+L`` layer applications' (the outer scan stacks the inner scan's stacks), the
+backward walks them last pass first, and a block's gradient is the sum of
+its ``T`` passes' contributions, kept as a running sum beside the stacks the
+pass at hand fills.  What the checkpoint keeps a pass is what it keeps a
+layer below, ``T`` times: ``_layer_sizes`` sizes the ladder's rungs and the
+bound by ``n_layer x ut_steps`` applications.  With experts or a
+``block_length`` a loop is refused.
+
 What the layer's ``jax.checkpoint`` keeps: the layer's input ``x`` (whole-block
 remat) and, where the splash kernel runs, the kernel's attention output and
 log-sum-exp (``ops.attention.save_splash_residuals``), one more (B, S, D)
@@ -67,7 +88,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models import block_diffusion, moe
+from ray_tpu.models import block_diffusion, looped, moe
 from ray_tpu.models.layers import attention, feed_forward, mesh_axes
 from ray_tpu.models.layers import rmsnorm as _rmsnorm
 from ray_tpu.models.layers import rope as _rope  # noqa: F401
@@ -123,6 +144,15 @@ class LlamaConfig:
     mask_token_id: int = 0
     #: the noise is a function of a row's ids and this
     noise_seed: int = 0
+    # Published facts of a looped model (Ouro-2.6B; models/looped.py), each
+    # defaulting to the plain decoder.
+    #: how often a step runs the stack of layers, with one set of weights;
+    #: above 1 every pass ends in the final norm, the head and an exit gate
+    ut_steps: int = 1
+    #: a second norm after each sub-layer: ``x + norm_2(f(norm(x)))``
+    sandwich_norm: bool = False
+    #: the coefficient of the exit distribution's entropy in the loss
+    exit_beta: float = 0.0
 
     @property
     def held(self) -> range:
@@ -149,6 +179,13 @@ class LlamaConfig:
                            router_z_loss_coef=0.001)
 
     @staticmethod
+    def tiny_ouro() -> "LlamaConfig":
+        """Ouro's shape in small: MHA, sandwich norms, four passes."""
+        return LlamaConfig(vocab_size=1024, n_layer=2, n_head=4, n_kv_head=4,
+                           d_model=128, d_ff=384, seq_len=128, rms_eps=1e-6,
+                           ut_steps=4, sandwich_norm=True, exit_beta=0.1)
+
+    @staticmethod
     def tiny_sdar() -> "LlamaConfig":
         """SDAR's shape in small: heads of 64 at d_model / n_head = 32, GQA,
         per-head QK-norm, experts 2 and 3 of 8 held, blocks of 4."""
@@ -173,6 +210,12 @@ class LlamaConfig:
         assert (self.n_experts > 0) == (self.experts_per_token > 0)
         assert not self.block_length \
             or 0 <= self.mask_token_id < self.vocab_size
+        if self.ut_steps < 1 or self.ut_steps > 1 and (
+                self.n_experts or self.block_length):
+            raise ValueError(
+                f"ut_steps={self.ut_steps} with n_experts={self.n_experts}, "
+                f"block_length={self.block_length}: the loop over passes "
+                "runs a dense next-token decoder, at least once")
 
 
 def init_params(config: LlamaConfig, key) -> Dict[str, Any]:
@@ -180,7 +223,8 @@ def init_params(config: LlamaConfig, key) -> Dict[str, Any]:
     D, L, V = config.d_model, config.n_layer, config.vocab_size
     H, KV, hd, F = config.n_head, config.n_kv_head, config.head_dim, config.d_ff
     std = 0.02
-    resid_std = std / math.sqrt(2 * L)
+    # 2 L T residual branches: every pass adds a layer's two
+    resid_std = std / math.sqrt(2 * L * config.ut_steps)
     k_wte, k_blocks, k_head = jax.random.split(key, 3)
 
     def norm(key, shape, s):
@@ -209,13 +253,19 @@ def init_params(config: LlamaConfig, key) -> Dict[str, Any]:
     elif config.qk_norm:
         blocks["q_norm"] = jnp.ones((L, H * hd))
         blocks["k_norm"] = jnp.ones((L, KV * hd))
-    return {
+    if config.sandwich_norm:
+        blocks["attn_norm_2"] = jnp.ones((L, D))
+        blocks["mlp_norm_2"] = jnp.ones((L, D))
+    params = {
         "wte": norm(k_wte, (V, D), std),
         "blocks": blocks,
         "final_norm": jnp.ones((D,)),
         # Untied LM head (Llama convention; GPT-2 ties to wte).
         "lm_head": norm(k_head, (V, D), std),
     }
+    if config.ut_steps > 1:
+        params["exit_gate"] = looped.init_gate(D)
+    return params
 
 
 def logical_axes(config: LlamaConfig) -> Dict[str, Any]:
@@ -237,12 +287,18 @@ def logical_axes(config: LlamaConfig) -> Dict[str, Any]:
     if config.qk_norm:
         blocks["q_norm"] = (L, "norm")
         blocks["k_norm"] = (L, "norm")
-    return {
+    if config.sandwich_norm:
+        blocks["attn_norm_2"] = (L, "norm")
+        blocks["mlp_norm_2"] = (L, "norm")
+    axes = {
         "wte": ("vocab", "embed"),
         "blocks": blocks,
         "final_norm": ("norm",),
         "lm_head": ("vocab", "embed"),
     }
+    if config.ut_steps > 1:
+        axes["exit_gate"] = looped.GATE_AXES
+    return axes
 
 
 def _attn_params(config: LlamaConfig) -> int:
@@ -262,8 +318,13 @@ def num_params(config: LlamaConfig) -> int:
         attn += 2 * config.head_dim
     elif config.qk_norm:
         attn += (config.n_head + config.n_kv_head) * config.head_dim
-    per_block = 2 * D + attn + mlp
-    return 2 * V * D + L * per_block + D
+    per_block = (4 if config.sandwich_norm else 2) * D + attn + mlp
+    return 2 * V * D + L * per_block + D + _gate_params(config)
+
+
+def _gate_params(config: LlamaConfig) -> int:
+    """The exit gate's weight and bias, where the stack is looped."""
+    return config.d_model + 1 if config.ut_steps > 1 else 0
 
 
 def flops_per_token(config: LlamaConfig) -> float:
@@ -272,17 +333,21 @@ def flops_per_token(config: LlamaConfig) -> float:
     12 x width x S a layer (the whole square, as ever).  A block-diffusion
     row sends two positions a trained token through the layers and one
     through the head, and its attention is counted over the mask's own area,
-    S^2 + S x block_length a head."""
-    L, S = config.n_layer, config.seq_len
+    S^2 + S x block_length a head.  A looped stack meets its layers, the
+    final norm, the head and the gate ``ut_steps`` times a token, the
+    embedding once."""
+    L, S, T = config.n_layer, config.seq_len, config.ut_steps
     held = len(config.held)
     idle = held * (1 - config.experts_per_token / max(config.n_experts, 1)) \
         * 3 * config.d_model * config.d_ff * L
-    outside = (2 * config.vocab_size + 1) * config.d_model
+    every_pass = (config.vocab_size + 1) * config.d_model \
+        + _gate_params(config)
+    outside = config.vocab_size * config.d_model + every_pass
     layers = num_params(config) - outside - idle
     copies, area = (2, S + config.block_length) if config.block_length \
         else (1, S)
-    return 6.0 * (copies * layers + outside) \
-        + 12.0 * L * config.n_head * config.head_dim * area
+    return 6.0 * (T * copies * layers + outside + (T - 1) * every_pass) \
+        + 12.0 * T * L * config.n_head * config.head_dim * area
 
 
 def _block(x, blk, config: LlamaConfig):
@@ -312,7 +377,8 @@ def _layer_sizes(params, x_shape, config: LlamaConfig):
     ``create_sharded_state`` rules of their own gets the chip's share
     mis-sized, which the compiler's refusal and ``TrainStep``'s fallback
     then have to catch.  The scan stacks whatever is kept, so a candidate's
-    bytes are a layer's times ``n_layer``."""
+    bytes are a layer's times ``n_layer``, and a looped stack's
+    (``ut_steps``) that many passes' besides: every pass stacks its own."""
     mesh = jax.sharding.get_abstract_mesh()
     tensor = remat.axis_shards(mesh, "tensor")
     tokens = math.prod(x_shape[:2]) // remat.axis_shards(
@@ -338,7 +404,8 @@ def _layer_sizes(params, x_shape, config: LlamaConfig):
         n_head=config.n_head // tensor,
         mlp_width=mlp_width, vocab=config.vocab_size // tensor,
         itemsize=item,
-        logits_itemsize=jnp.dtype(config.logits_dtype).itemsize)
+        logits_itemsize=jnp.dtype(config.logits_dtype).itemsize,
+        passes=config.ut_steps)
     if config.n_experts:
         # what the bound does not know of: the expert layer moves every
         # (position, expert) pair as a row of d_model (models/moe.py): the
@@ -353,7 +420,8 @@ def _layer_sizes(params, x_shape, config: LlamaConfig):
         per_layer[remat.ROUTING] = moe.routing_bytes(
             tokens, config.n_experts, config.experts_per_token)
         names += (remat.ROUTING,)
-    return ([(name, config.n_layer * per_layer[name]) for name in names],
+    applications = config.n_layer * config.ut_steps
+    return ([(name, applications * per_layer[name]) for name in names],
             temporaries)
 
 
@@ -363,7 +431,8 @@ def forward_hidden(params: Dict[str, Any], tokens, config: LlamaConfig):
     third ``moe.moe_mlp``'s counts with the layers in front: ``moe_rows``
     (L, shards, H) int32 and, where the layers hold a share, ``moe_moved``
     (L, shards); both ``None`` (no scan output behind them) for a model
-    without experts."""
+    without experts.  A looped stack (``ut_steps`` > 1) hands back the
+    normed state after every pass, (T, B, S, D), the last pass last."""
     dt = config.dtype
     with jax.named_scope("embed"):
         x = params["wte"][tokens].astype(dt)
@@ -374,19 +443,35 @@ def forward_hidden(params: Dict[str, Any], tokens, config: LlamaConfig):
     if config.remat:
         layer = jax.checkpoint(
             layer, policy=_layer_policy(params, x.shape, config))
+
+    def final_norm(x):
+        with jax.named_scope("lm_head"):
+            return _rmsnorm(x, params["final_norm"],
+                            config.rms_eps).astype(dt)
+
+    if config.ut_steps > 1:
+        # The passes are an outer scan with the blocks closed over: one
+        # layer body to compile whatever ``ut_steps`` is, and the next pass
+        # reads the normed state.
+        def one_pass(x, _):
+            x = final_norm(lax.scan(layer, x, params["blocks"])[0])
+            return x, x
+
+        return lax.scan(one_pass, x, None, length=config.ut_steps)[1], \
+            None, None
     x, expert_layers = lax.scan(layer, x, params["blocks"])
     router_loss = counts = None
     if expert_layers is not None:
         (balance, z), counts = expert_layers  # (L,) each; moe_mlp's
         router_loss = config.router_aux_loss_coef * jnp.sum(balance) \
             + config.router_z_loss_coef * jnp.sum(z)
-    with jax.named_scope("lm_head"):
-        x = _rmsnorm(x, params["final_norm"], config.rms_eps).astype(dt)
-    return x, router_loss, counts
+    return final_norm(x), router_loss, counts
 
 
 def forward(params: Dict[str, Any], tokens, config: LlamaConfig):
     x = forward_hidden(params, tokens, config)[0]
+    if config.ut_steps > 1:  # the last pass's logits
+        x = x[-1]
     with jax.named_scope("lm_head"):
         return jnp.einsum("bsd,vd->bsv", x,
                           params["lm_head"].astype(config.dtype),
@@ -404,8 +489,9 @@ def loss_fn(params, tokens, targets, config: LlamaConfig):
 def loss_and_counters(params, tokens, targets, config: LlamaConfig):
     """-> (:func:`loss_fn`'s scalar, the step counters of
     ``tracing.STEP_COUNTER_REGISTRY`` this model has: with experts
-    ``moe_rows``, with a share of them held ``moe_moved`` too, else
-    none)."""
+    ``moe_rows``, with a share of them held ``moe_moved`` too; a looped
+    stack's ``loss_ut`` and ``ut_exit_mass``, its loss being
+    ``models/looped.py``'s expectation over the exit pass; else none)."""
     weights = None
     if config.block_length:
         targets = tokens
@@ -416,8 +502,11 @@ def loss_and_counters(params, tokens, targets, config: LlamaConfig):
                     experts_total=config.n_experts,
                     block_length=config.block_length,
                     attn_positions=tokens.shape[1],
-                    loss_positions=targets.shape[1])
+                    loss_positions=targets.shape[1],
+                    ut_steps=config.ut_steps)
     x, router_loss, counts = forward_hidden(params, tokens, config)
+    if config.ut_steps > 1:
+        return looped.loss_and_counters(x, params, targets, config)
     with jax.named_scope("lm_head"):
         if config.block_length:  # the head reads the noised copy, the first
             x = x[:, :targets.shape[1]]
@@ -432,9 +521,9 @@ def make_train_step(config: LlamaConfig, optimizer):
     """Pure (params, opt_state, tokens, targets) -> (params, opt_state, loss):
     parallel.train_state.make_train_step over this model's loss.  With
     experts the step also leaves ``moe_rows`` (and, a share of them held,
-    ``moe_moved``) in ``step.counters``; a dense model's step is the plain
-    one."""
-    if config.n_experts:
+    ``moe_moved``) in ``step.counters``, a looped stack's ``loss_ut`` and
+    ``ut_exit_mass``; a dense model's step is the plain one."""
+    if config.n_experts or config.ut_steps > 1:
         return _make_train_step(partial(loss_and_counters, config=config),
                                 optimizer, has_counters=True)
     return _make_train_step(partial(loss_fn, config=config), optimizer)

@@ -296,7 +296,8 @@ def rows_under_mesh(tokens: int, seq_len: int):
 def own_temporaries(*, block_bytes: int, other_bytes: int, layer_bytes: int,
                     sharded: bool, tokens: int, d_model: int, n_layer: int,
                     attn_width: int, n_head: int, mlp_width: int, vocab: int,
-                    itemsize: int, logits_itemsize: int) -> int:
+                    itemsize: int, logits_itemsize: int,
+                    passes: int = 1) -> int:
     """A bound, from shapes, on the temporaries of the step as it is without
     any rung of the ladder; every size a chip's.  ``block_bytes`` and
     ``other_bytes`` are the chip's share of the stacked layers' parameters
@@ -316,21 +317,46 @@ def own_temporaries(*, block_bytes: int, other_bytes: int, layer_bytes: int,
     - around the head: the logits and their cotangent, the same casts and
       stacks, and the rest's gradients in float32.
 
+    ``passes``: how often the step runs the stack (a looped model's
+    ``ut_steps``).  Every pass stacks its own layers' inputs and kernel
+    outputs, so what the scans stack is ``passes`` times a pass's, and the
+    backward scan holds :func:`_looped`'s besides.
+
     Held against the v5e compiler's buffer assignment for the benchmark's
     cells it reads 4.88 GiB for 4.56 and 4.50 (Mistral-7B at two layers),
     6.19 for 6.00 (twelve layers under ``fsdp=4``) and 3.72 for 2.39 (OLMoE
     at one layer, whose scan of one has no stacks) (PERF.md, PR 31).
     """
     cast = itemsize / 4
-    stacked = n_layer * tokens * (d_model * itemsize + attn_width * itemsize
-                                  + n_head * 4)
+    stacked = passes * n_layer * tokens * (
+        d_model * itemsize + attn_width * itemsize + n_head * 4)
     casts = int((block_bytes + other_bytes) * cast)
     working = 6 * tokens * (mlp_width + attn_width) * itemsize
     gathered = int(2 * layer_bytes * cast) if sharded else 0
     in_the_scan = block_bytes + casts + stacked + working + gathered
     at_the_head = other_bytes + casts + stacked \
         + 2 * tokens * vocab * logits_itemsize
+    if passes > 1:
+        in_the_scan += _looped(block_bytes, stacked // passes, passes,
+                               tokens * d_model, itemsize)
     return max(in_the_scan, at_the_head)
+
+
+def _looped(block_bytes: int, a_pass: int, passes: int, state: int,
+            itemsize: int) -> int:
+    """What a stack that is run ``passes`` times over (``models/looped.py``:
+    an outer scan over the passes around the layers' scan) holds inside the
+    backward scan beyond :func:`own_temporaries`' one pass: a second set of
+    the layers' float32 gradient stacks (the passes' running sum beside the
+    stacks the pass at hand fills); ``a_pass``, one pass's share of what the
+    forward stacked, sliced out of the passes' stack for the layers' scan;
+    and of each pass's normed ``state`` (tokens x d_model) the state and its
+    cotangent in the compute dtype and the final norm's float32 input and
+    cotangent.  The head's term is untouched: the head runs a pass at a
+    time and holds one pass's logits and their cotangent.  Held against the
+    v5e's buffer assignment for Ouro-2.6B at eight layers, four passes and
+    8192 tokens it reads 8.18 GiB for 7.97 (PERF.md, PR 65)."""
+    return block_bytes + a_pass + passes * state * (2 * itemsize + 2 * 4)
 
 
 _lock = threading.Lock()

@@ -72,8 +72,9 @@ _NOTED = (
      "layer, K's and G's q, k and v one each)", "conv_kernel conv_calls"),
     ("models/llama.py", "the experts held of a layer's, a block-diffusion "
      "row's block (0: next-token), the positions attention and the loss "
-     "run over", "experts_held experts_total block_length attn_positions "
-     "loss_positions"),
+     "run over, how often a step runs the stack (1: a plain decoder)",
+     "experts_held experts_total block_length attn_positions "
+     "loss_positions ut_steps"),
     ("models/hybrid.py", "the pattern run, one letter a layer; with a "
      "prediction module its depth and its loss's weight",
      "layer_kinds mtp_depth mtp_weight"),

@@ -228,7 +228,12 @@ SCOPE_REGISTRY: Dict[str, str] = {
     "noise": "block-diffusion training (models/block_diffusion.py): the "
              "draw of the masked positions, the noised copy, the "
              "concatenation with the clean one, the loss weights",
-    "lm_head": "final norm, logits, loss",
+    "lm_head": "final norm, logits, loss (a looped stack's, "
+               "models/looped.py: after every pass)",
+    "exit_gate": "a looped stack's exit gate (models/looped.py): the gate's "
+                 "product with each pass's normed state, the sigmoids, the "
+                 "exit distribution, its entropy and the combination of the "
+                 "passes' cross-entropies into the loss, all float32",
     "mtp": "multi-token-prediction module (models/hybrid.py): the norms of "
            "the next token's embedding and of the last layer's output, "
            "their join and its projection; the module's block opens its "
@@ -286,6 +291,14 @@ STEP_COUNTER_REGISTRY: Dict[str, str] = {
                         "step's positions, float32 (sub-layers,), a "
                         "prediction module's last: whether the turns reach "
                         "the manifold at the logits training drives them to",
+    "loss_ut": "a looped stack (models/looped.py): the mean cross-entropy "
+               "of each pass's head, float32 (ut_steps,); the step's loss "
+               "weighs them by the exit distribution",
+    "ut_exit_mass": "the same stack: the mean over the step's positions of "
+                    "the probability of leaving at each pass, float32 "
+                    "(ut_steps,), summing to one; an entry near 1 means the "
+                    "gate has collapsed onto one pass and the other heads "
+                    "train on nothing",
 }
 
 

@@ -140,9 +140,11 @@ def test_the_familys_kinds_run_under_their_own_scopes(family):
     row = FAMILIES[family]
     assert set(row.registered) <= set(tracing.SCOPE_REGISTRY)
     config = families.preset(family, attn_impl="xla")
-    shapes = jax.eval_shape(lambda: hybrid.init_params(config,
+    # ``models/hybrid.py`` or ``models/llama.py``: the configuration's
+    module = importlib.import_module(type(config).__module__)
+    shapes = jax.eval_shape(lambda: module.init_params(config,
                                                        jax.random.key(0)))
-    text = jax.jit(jax.grad(lambda p, t: hybrid.loss_fn(
+    text = jax.jit(jax.grad(lambda p, t: module.loss_fn(
         p, t, t, config))).lower(shapes, IDS).as_text(debug_info=True)
     for scope in row.scopes:
         assert re.search(rf"[(/]{scope}[)/]", text), scope

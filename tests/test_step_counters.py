@@ -452,6 +452,8 @@ def test_counter_names_are_under_the_registry_check():
               "                 step_counter('loss_main'): 0.0,\n"
               "                 step_counter('loss_mtp'): 0.0,\n"
               "                 step_counter('mhc_sinkhorn_err'): 0.0,\n"
+              "                 step_counter('loss_ut'): 0.0,\n"
+              "                 step_counter('ut_exit_mass'): 0.0,\n"
               "                 step_counter('moe_rowz'): rows}\n")
     module = core.SourceModule("fixture.py", "ray_tpu/models/fixture.py",
                                source)

@@ -8,7 +8,8 @@ clock, and the step profiler's counters.
   Mamba-2 scopes and the shared expert's are the hybrid step's, the KDA
   scopes those of a hybrid step whose pattern holds ``K``, the
   gated-delta-net scopes those of one whose pattern holds ``G``, the three
-  of the hyper-connections those of one with ``streams`` > 1);
+  of the hyper-connections those of one with ``streams`` > 1, the exit
+  gate's that of a llama step whose stack is looped);
 * ``classify_op_name`` on real ``op_name`` strings of the compiled v5e steps
   (``tests/data/v5e_step_op_names.json``) and ``parse_anatomy`` on an
   excerpt of that module's text (``tests/data/v5e_step_excerpt.hlo.txt``);
@@ -71,6 +72,8 @@ CONV_SCOPES = {"shortconv", "shortconv_gate"}
 GDN_SCOPES = {"gdn", "gdn_conv", "gdn_scan"}
 #: scopes only a stack over a residual of several streams opens
 MHC_SCOPES = {"mhc", "mhc_maps", "mhc_mix"}
+#: the scope only a looped stack opens (``models/looped.py``)
+LOOPED_SCOPES = {"exit_gate"}
 HYBRID_SCOPES = SSM_SCOPES | KDA_SCOPES | MLA_SCOPES | WINDOW_SCOPES \
     | CONV_SCOPES | GDN_SCOPES | MHC_SCOPES | {"shared_expert"}
 
@@ -90,11 +93,13 @@ def _scopes_of(family):
     if family in OWN_SCOPES:  # holds a share, trains next tokens
         own, without = OWN_SCOPES[family]
         return set(tracing.SCOPE_REGISTRY) - {"experts", "noise"} - without \
-            - (HYBRID_SCOPES - {"shared_expert"} - own)
+            - (HYBRID_SCOPES - {"shared_expert"} - own) - LOOPED_SCOPES
     if family == "llama-sdar":
-        return set(tracing.SCOPE_REGISTRY) - {"experts"} - HYBRID_SCOPES
+        return set(tracing.SCOPE_REGISTRY) - {"experts"} - HYBRID_SCOPES \
+            - LOOPED_SCOPES
     return set(tracing.SCOPE_REGISTRY) - SDAR_SCOPES - HYBRID_SCOPES - (
-        set() if family == "llama-moe" else MOE_SCOPES)
+        set() if family == "llama-moe" else MOE_SCOPES) - (
+        set() if family == "llama-ouro" else LOOPED_SCOPES)
 
 
 #: the hybrid steps by what their patterns hold -> the row of
@@ -116,6 +121,8 @@ def _family(name):
         return llama, llama.LlamaConfig.tiny_moe()
     if name == "llama-sdar":  # what sdar-ep8-s8192 runs
         return llama, llama.LlamaConfig.tiny_sdar()
+    if name == "llama-ouro":  # what ouro-l8-s4096 runs
+        return llama, llama.LlamaConfig.tiny_ouro()
     if name in HYBRID_ROWS:  # the rehearsal file of what the cell runs
         from ray_tpu.models import hybrid
 
@@ -153,6 +160,7 @@ def _tiny_step(name="llama"):
 
 # ------------------------------------------------------- names in the step
 @pytest.mark.parametrize("family", ["llama", "llama-moe", "llama-sdar",
+                                    "llama-ouro",
                                     "hybrid", "hybrid-kda", "hybrid-mla",
                                     "hybrid-window", "hybrid-conv",
                                     "hybrid-gdn", "hybrid-mhc",
@@ -236,6 +244,7 @@ def test_parse_anatomy_on_v5e_module_excerpt():
 
 
 @pytest.mark.parametrize("family", ["llama", "llama-moe", "llama-sdar",
+                                    "llama-ouro",
                                     "hybrid", "hybrid-kda", "hybrid-mla",
                                     "hybrid-window", "hybrid-conv",
                                     "hybrid-gdn", "hybrid-mhc",
